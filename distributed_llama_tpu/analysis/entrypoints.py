@@ -384,11 +384,11 @@ def signature_fingerprint(ep: EntryPoint) -> str:
 
 def make_jaxpr_for(ep: EntryPoint, x64: bool = False):
     """Trace the entry point to a ClosedJaxpr (no compilation). With
-    x64=True the trace runs under jax.experimental.enable_x64 so an
+    x64=True the trace runs under jax.enable_x64(True) so an
     accidental f64 promotion becomes VISIBLE as an f64 aval instead of
     being silently truncated to f32 by the global x64=off default."""
     if x64:
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             # re-cast inputs under the x64 regime: well-typed code keeps
             # every explicit dtype; only promotion leaks drift to f64
             return jax.make_jaxpr(ep.fn)(*ep.args)

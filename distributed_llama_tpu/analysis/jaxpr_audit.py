@@ -7,10 +7,10 @@ every sub-jaxpr under pjit/scan/while/cond/shard_map/custom-call — is
 walked for:
 
   DLG201  device-to-host transfer primitives (pure_callback, io_callback,
-          debug_callback, ...) — a host round-trip compiled INTO the step
+          debug_callback, debug_print, ...) — a host round-trip compiled INTO the step
           function stalls the TPU pipeline every token
   DLG202  float64 anywhere in the program. Traced under
-          jax.experimental.enable_x64 so promotion leaks are visible: with
+          jax.enable_x64(True) so promotion leaks are visible: with
           the production x64=off default JAX silently truncates them to
           f32, and the first time the flag flips (a debug session, a new
           deployment) the step function doubles its HBM traffic
@@ -47,8 +47,8 @@ from .findings import Finding
 
 # primitives that move data to the host (or schedule host execution)
 D2H_PRIMITIVES = {
-    "pure_callback", "io_callback", "debug_callback", "host_callback_call",
-    "outside_call", "device_get", "callback",
+    "pure_callback", "io_callback", "debug_callback", "debug_print",
+    "device_get", "callback",
 }
 
 # collective primitives that replicate data (vs reduce it)

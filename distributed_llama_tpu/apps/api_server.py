@@ -39,7 +39,6 @@ import time
 from http.server import (BaseHTTPRequestHandler, HTTPServer,
                          ThreadingHTTPServer)
 
-import jax
 import numpy as np
 
 from ..runtime.fleet import ShedReject
@@ -120,8 +119,13 @@ class ApiState:
                  draft: str | None = None, draft_len: int = 0,
                  kv_transfer: bool = False, tiers=None,
                  min_replicas: int = 0, max_replicas: int = 0,
-                 tenant_budgets: str | None = None):
+                 tenant_budgets: str | None = None,
+                 multihost: bool = False):
         self.engine = engine
+        # a --nnodes cluster root (serve() knows from the flag; asking
+        # jax.process_count() here would initialize a backend in the
+        # process tiers' front door, which must hold no device)
+        self.multihost = bool(multihost)
         self.tokenizer = tokenizer
         self.sampler = sampler
         self.model_name = model_name
@@ -234,13 +238,33 @@ class ApiState:
         self.tenant_ledger = None
 
     def build_info(self) -> dict:
-        """{version, jax, backend, mesh} — computed once (the backend
-        and mesh never change within a process), served on /healthz
-        (`build` block) and /metrics (`dllama_build_info`)."""
-        if self._build_info is None:
-            from ..runtime.profiler import build_info
+        """{version, jax, backend, device_kind, device_count, mesh} —
+        computed once (the backend and mesh never change within a
+        process), served on /healthz (`build` block) and /metrics
+        (`dllama_build_info`)."""
+        if self._build_info is not None:
+            return self._build_info
+        if self.replica_procs or self.replica_hosts:
+            # process tiers: the front door holds no device and must never
+            # initialize a backend (a parent that touched JAX holds the
+            # chip its worker needs) — relay the block a worker reported
+            # on its health PONG; until one has, say so
+            import jax
 
-            self._build_info = build_info(self.engine)
+            from .. import __version__
+
+            sup = self._scheduler
+            for h in (sup.replicas if sup is not None else ()):
+                worker = h.health_snapshot().get("build")
+                if worker:
+                    self._build_info = {**worker, "mesh": "front-door"}
+                    return self._build_info
+            return {"version": __version__, "jax": jax.__version__,
+                    "backend": "uninitialized", "device_kind": "",
+                    "device_count": 0, "mesh": "front-door"}
+        from ..runtime.profiler import build_info
+
+        self._build_info = build_info(self.engine)
         return self._build_info
 
     def scheduler(self):
@@ -400,7 +424,7 @@ def _completion_chunks(state: ApiState, body: dict):
     # position before any of its queries can attend them (the same
     # invariant decode overruns rely on, runtime/engine.py).
     lcp = 0
-    if jax.process_count() == 1:
+    if not state.multihost:
         # multi-host clusters skip reuse: it is only collective-safe while
         # every process's cached_tokens agree, and a worker-local failure
         # resync (apps/dllama.cmd_worker) legitimately clears one side —
@@ -1530,7 +1554,7 @@ def make_handler(state: ApiState):
             if "tenant" not in body and self.headers.get("X-Tenant"):
                 body["tenant"] = self.headers.get("X-Tenant")
 
-            multihost = jax.process_count() > 1
+            multihost = state.multihost
             use_sched = state.serve_batch > 0 and not multihost
             # legacy single-engine path: serialize under the engine lock,
             # CONTEXT-MANAGED — the old bare acquire()/release() pair
@@ -1677,7 +1701,7 @@ def serve(args) -> None:
         # by position, which is dp/sp/pp-agnostic only on paper, and tp
         # is what vocab sharding (ops/sharded_vocab.py) serves through.
         # Loud error beats a silently ignored flag for the rest.
-        if getattr(args, "nnodes", 1) > 1 or jax.process_count() > 1:
+        if getattr(args, "nnodes", 1) > 1:
             sys.exit("error: --serve-batch does not compose with --nnodes")
         if max(getattr(args, k, 1) for k in ("dp", "sp", "ep", "pp")) > 1:
             sys.exit("error: --serve-batch needs a single-process engine "
@@ -1953,6 +1977,19 @@ def serve(args) -> None:
         if not getattr(args, "model", None):
             sys.exit("error: --replica-procs workers load their own "
                      "weights and need --model")
+        # one process per chip: worker i is given chip i (runtime/
+        # replica_worker.chip_assignment_env), so on a TPU host more
+        # workers than chips can never all come up — refuse at start-up
+        # (counted from device nodes: the front door never asks JAX). Hosts
+        # without a TPU (chips == 0) and runs pinned off it are exempt.
+        from ..runtime.replica_worker import local_tpu_chips
+        chips = local_tpu_chips()
+        on_tpu = "tpu" in (os.environ.get("JAX_PLATFORMS") or "tpu")
+        most = max(int(replica_procs), max_reps)
+        if chips and on_tpu and most > chips:
+            sys.exit(f"error: --replica-procs/--max-replicas {most} "
+                     f"exceeds the {chips} TPU chip(s) on this host (each "
+                     "worker process owns exactly one chip)")
         from ..runtime.replica_worker import config_from_cli_args
         worker_config = config_from_cli_args(args, serve_batch)
 
@@ -2056,12 +2093,21 @@ def serve(args) -> None:
                      kv_transfer=kv_transfer, tiers=tiers,
                      min_replicas=getattr(args, "min_replicas", 0) or 0,
                      max_replicas=getattr(args, "max_replicas", 0) or 0,
-                     tenant_budgets=getattr(args, "tenant_budgets", None))
+                     tenant_budgets=getattr(args, "tenant_budgets", None),
+                     multihost=getattr(args, "nnodes", 1) > 1)
+    if serve_batch:
+        # build + warm the front door BEFORE listening: an engine that
+        # cannot be built (out of memory, a compile the chip refuses, a
+        # worker that dies at boot) must end the server with a traceback
+        # and a non-zero exit here — not surface as a 500 on the first
+        # request of a process that looked ready. ApiState itself stays
+        # lazy (library users and tests probe it unbuilt).
+        state.scheduler()
     if session and os.path.exists(session):
         load_server_session(state, session)
         print(f"💾 resumed session from {session} "
               f"({engine.pos} cached positions)")
-    if jax.process_count() > 1:
+    if state.multihost:
         # multihost api root: a lost worker means every future forward
         # would hang in an orphaned collective. Map the detection onto the
         # supervisor's BROKEN path first (structured cluster_peer_lost

@@ -15,11 +15,11 @@ import os
 
 import numpy as np
 
-_LIB_PATHS = (
-    os.path.join(os.path.dirname(__file__), "..", "native",
-                 "libdllama_native.so"),
-    "libdllama_native.so",
-)
+# ONLY the checkout's own build (`make -C native`): a bare library name
+# would let the system loader path supply a stale libdllama_native.so
+# (`*.so` is git-ignored, so nothing pins which build that would be)
+LIB_PATH = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",
+                        "native", "libdllama_native.so")
 
 _lib = None
 
@@ -28,46 +28,44 @@ def _load():
     global _lib
     if _lib is not None:
         return _lib
-    for p in _LIB_PATHS:
-        try:
-            lib = ctypes.CDLL(p)
-        except OSError:
-            continue
-        lib.dllama_tok_create.restype = ctypes.c_void_p
-        lib.dllama_tok_create.argtypes = [
-            ctypes.c_int32, ctypes.c_char_p,
-            ctypes.POINTER(ctypes.c_int32), ctypes.POINTER(ctypes.c_float),
-            ctypes.c_int32, ctypes.c_int32]
-        lib.dllama_tok_free.argtypes = [ctypes.c_void_p]
-        lib.dllama_tok_encode.restype = ctypes.c_int32
-        lib.dllama_tok_encode.argtypes = [
-            ctypes.c_void_p, ctypes.c_char_p, ctypes.c_int32,
-            ctypes.c_int32, ctypes.c_int32,
-            ctypes.POINTER(ctypes.c_int32), ctypes.c_int32]
-        lib.dllama_tok_decode_piece.restype = ctypes.c_int32
-        lib.dllama_tok_decode_piece.argtypes = [
-            ctypes.c_void_p, ctypes.c_int32, ctypes.c_int32,
-            ctypes.c_char_p, ctypes.c_int32]
-        lib.dllama_sampler_create.restype = ctypes.c_void_p
-        lib.dllama_sampler_create.argtypes = [
-            ctypes.c_int32, ctypes.c_float, ctypes.c_float, ctypes.c_uint64]
-        lib.dllama_sampler_free.argtypes = [ctypes.c_void_p]
-        lib.dllama_sampler_set_temp.argtypes = [ctypes.c_void_p, ctypes.c_float]
-        lib.dllama_sampler_set_seed.argtypes = [ctypes.c_void_p, ctypes.c_uint64]
-        lib.dllama_sampler_get_state.restype = ctypes.c_uint64
-        lib.dllama_sampler_get_state.argtypes = [ctypes.c_void_p]
-        lib.dllama_sampler_set_state.argtypes = [ctypes.c_void_p, ctypes.c_uint64]
-        lib.dllama_sampler_sample.restype = ctypes.c_int32
-        lib.dllama_sampler_sample.argtypes = [
-            ctypes.c_void_p, ctypes.POINTER(ctypes.c_float), ctypes.c_int32]
-        if hasattr(lib, "dllama_rng_fill_f32"):  # older .so builds lack it
-            lib.dllama_rng_fill_f32.restype = ctypes.c_uint64
-            lib.dllama_rng_fill_f32.argtypes = [
-                ctypes.c_uint64, ctypes.POINTER(ctypes.c_float),
-                ctypes.c_int64]
-        _lib = lib
-        return lib
-    return None
+    try:
+        lib = ctypes.CDLL(LIB_PATH)
+    except OSError:
+        return None
+    lib.dllama_tok_create.restype = ctypes.c_void_p
+    lib.dllama_tok_create.argtypes = [
+        ctypes.c_int32, ctypes.c_char_p,
+        ctypes.POINTER(ctypes.c_int32), ctypes.POINTER(ctypes.c_float),
+        ctypes.c_int32, ctypes.c_int32]
+    lib.dllama_tok_free.argtypes = [ctypes.c_void_p]
+    lib.dllama_tok_encode.restype = ctypes.c_int32
+    lib.dllama_tok_encode.argtypes = [
+        ctypes.c_void_p, ctypes.c_char_p, ctypes.c_int32,
+        ctypes.c_int32, ctypes.c_int32,
+        ctypes.POINTER(ctypes.c_int32), ctypes.c_int32]
+    lib.dllama_tok_decode_piece.restype = ctypes.c_int32
+    lib.dllama_tok_decode_piece.argtypes = [
+        ctypes.c_void_p, ctypes.c_int32, ctypes.c_int32,
+        ctypes.c_char_p, ctypes.c_int32]
+    lib.dllama_sampler_create.restype = ctypes.c_void_p
+    lib.dllama_sampler_create.argtypes = [
+        ctypes.c_int32, ctypes.c_float, ctypes.c_float, ctypes.c_uint64]
+    lib.dllama_sampler_free.argtypes = [ctypes.c_void_p]
+    lib.dllama_sampler_set_temp.argtypes = [ctypes.c_void_p, ctypes.c_float]
+    lib.dllama_sampler_set_seed.argtypes = [ctypes.c_void_p, ctypes.c_uint64]
+    lib.dllama_sampler_get_state.restype = ctypes.c_uint64
+    lib.dllama_sampler_get_state.argtypes = [ctypes.c_void_p]
+    lib.dllama_sampler_set_state.argtypes = [ctypes.c_void_p, ctypes.c_uint64]
+    lib.dllama_sampler_sample.restype = ctypes.c_int32
+    lib.dllama_sampler_sample.argtypes = [
+        ctypes.c_void_p, ctypes.POINTER(ctypes.c_float), ctypes.c_int32]
+    if hasattr(lib, "dllama_rng_fill_f32"):  # older .so builds lack it
+        lib.dllama_rng_fill_f32.restype = ctypes.c_uint64
+        lib.dllama_rng_fill_f32.argtypes = [
+            ctypes.c_uint64, ctypes.POINTER(ctypes.c_float),
+            ctypes.c_int64]
+    _lib = lib
+    return lib
 
 
 def available() -> bool:

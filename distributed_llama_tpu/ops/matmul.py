@@ -34,7 +34,13 @@ def local_matmul(
     """Single-device matmul core: Pallas fused Q40 kernel when the operands
     qualify, XLA dequant einsum otherwise. Shared by matmul() and the
     shard_map per-shard bodies (parallel/tp_q80.py) so the kernel
-    preconditions and fallback live in exactly one place."""
+    preconditions and fallback live in exactly one place. The fallback is
+    by design for segments of more than pallas_q40.MAX_T rows (FLOPs-
+    amortized prefill) and it is silent HERE — the compile ledger records
+    per minted executable which kernels are in it (runtime/profiler.
+    _kernels_in -> /stats `compiles.by_key[*].kernels`), which is where a
+    serving width that lost the kernel shows (e.g. --serve-batch 8 with
+    the default 256-wide chunk: 2048 rows)."""
     x = x.astype(compute_dtype)
     if isinstance(w, QuantizedTensor):
         if use_pallas:

@@ -42,16 +42,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# TPUCompilerParams is the pre-rename spelling on jaxlib 0.4.x (the CPU CI
-# pin); resolved once so a third rename fails loudly at import, not as a
-# NoneType call deep in a trace
-_COMPILER_PARAMS = (getattr(pltpu, "CompilerParams", None)
-                    or getattr(pltpu, "TPUCompilerParams", None))
-if _COMPILER_PARAMS is None:  # pragma: no cover
-    raise ImportError(
-        "jax.experimental.pallas.tpu exposes neither CompilerParams nor "
-        "TPUCompilerParams — unsupported jax version for the flash kernels")
-
 DEF_BLOCK_S = 512
 NEG_INF = -1e30
 F8_DTYPE = jnp.float8_e4m3fn
@@ -250,9 +240,10 @@ def flash_attention(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b * kvh, t * g, hs), q.dtype),
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
+        name="flash_attention",
     )(pos, qh, kh, vh)
 
     return (out.reshape(b, kvh, t, g, hs).transpose(0, 2, 1, 3, 4)

@@ -193,16 +193,45 @@ def _expert_kernel(e_ref, x_lo_ref, x_hi_ref, xsum_ref, packed_ref,
         out_dtype=out_dtype, scales_u16=scales_u16, mxu_bf16=mxu_bf16)
 
 
+# f32 unpack intermediates are the dominant VMEM consumers (~4 bytes per
+# packed byte each): a (td, m) packed tile past this many bytes overflows
+# the ~16 MB scoped-VMEM budget
+_TILE_BYTES_MAX = 2_300_000
+
+
 def _tile_d(d: int, m: int) -> int:
     """Output-dim tile: Mosaic wants the last block dim to be a multiple of
-    128 lanes OR the whole array dim — so tile by the largest divisor from
-    the candidate list whose f32 unpack intermediates (the dominant VMEM
-    consumers, ~4 bytes per packed byte each) stay within the ~16 MB
-    scoped-VMEM budget, else take d whole (grid of 1)."""
-    for t in TILE_D_CANDIDATES:
-        if d % t == 0 and t * m <= 2_300_000:
+    128 lanes OR the whole array dim. Preference order, every choice within
+    the scoped-VMEM budget (_TILE_BYTES_MAX packed bytes per tile):
+
+      1. the largest candidate that divides d (an exact grid);
+      2. d whole (grid of 1) when the entire (d, m) weight fits the budget
+         — small or narrow weights no candidate divides;
+      3. a candidate over a `cdiv` grid whose last block is ragged: the
+         largest one wasting at most d/16 padded rows, else the one that
+         pads least. tp row shards land here (Llama-2-7B w1/w3 at tp=4 ->
+         2752 rows, a 32000-vocab head -> 8000, Llama-3's -> 32064). The
+         ragged block is safe because output row i depends on weight row i
+         alone: the out-of-bounds rows Pallas pads in feed only output
+         columns that are dropped on writeback.
+
+    A shape that fits none of these raises — it must never fall through to
+    a whole-weight block the chip's compiler refuses."""
+    fits = [t for t in TILE_D_CANDIDATES if t * m <= _TILE_BYTES_MAX]
+    for t in fits:
+        if d % t == 0:
             return t
-    return d
+    if d * m <= _TILE_BYTES_MAX:
+        return d
+    if not fits:
+        raise ValueError(
+            f"q40 kernel: no output tile of a ({d}, {m}) packed weight fits "
+            f"the scoped-VMEM budget ({LANES} x {m} > {_TILE_BYTES_MAX})")
+    padded = {t: -(-d // t) * t - d for t in fits}
+    for t in fits:
+        if padded[t] * 16 <= d:
+            return t
+    return min(fits, key=lambda t: (padded[t], -t))
 
 
 def supports_pallas(w: QuantizedTensor, t: int = 1) -> bool:
@@ -248,7 +277,7 @@ def q40_matmul(
 
     packed2d = w.packed  # already stored flattened (d, m) — consumed in place
     td = _tile_d(d, m)
-    grid = (d // td,)
+    grid = (pl.cdiv(d, td),)
     scales_u16 = w.scales.dtype == jnp.uint16
     scales = w.scales if scales_u16 else w.scales.astype(jnp.float32)
     # multi-token chunks with a bf16 consumer take the bf16 MXU feed (see
@@ -274,6 +303,7 @@ def q40_matmul(
             transcendentals=0,
         ),
         interpret=interpret,
+        name="q40_matmul",
     )(x_lo, x_hi, xsum, packed2d, scales)
 
     return out.reshape(*lead, d)
@@ -321,7 +351,7 @@ def q40_expert_matmul(
                           scales_u16=scales_u16, mxu_bf16=mxu_bf16),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(d // td,),
+            grid=(pl.cdiv(d, td),),
             in_specs=[
                 pl.BlockSpec((t, m), lambda i, e_ref: (0, 0)),
                 pl.BlockSpec((t, m), lambda i, e_ref: (0, 0)),
@@ -338,6 +368,7 @@ def q40_expert_matmul(
             transcendentals=0,
         ),
         interpret=interpret,
+        name="q40_expert_matmul",
     )(e_arr, x_lo, x_hi, xsum, w.packed, scales)
 
     return out.reshape(*lead, d)

@@ -52,7 +52,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ..parallel.compat import shard_map
+from jax import shard_map
 from ..parallel.mesh import DP_AXIS
 
 

@@ -128,7 +128,7 @@ def ep_moe_ffn(
     dim bytes per expert stack) via the quantized two-shot exchange; the ep
     expert-sum hop stays exact.
     """
-    from .compat import shard_map
+    from jax import shard_map
 
     ep = mesh.shape.get(EP_AXIS, 1)
     tp = mesh.shape.get(TP_AXIS, 1)

@@ -220,7 +220,7 @@ def _pp_scaffold(mesh, layers, cfg, b):
     Inside the fully-manual region the layer math runs per-shard: the
     explicit shard_map wrappers must not re-enter (tp_mesh=None) and
     matmul/attention dispatch on manual_tp instead."""
-    from .compat import shard_map
+    from jax import shard_map
 
     from .mesh import DP_AXIS, EP_AXIS, SP_AXIS
 

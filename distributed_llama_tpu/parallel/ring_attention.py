@@ -80,9 +80,7 @@ def ring_attention_local(q, k, v, chunk_pos0, axis_name: str = SP_AXIS):
       (normally sp_index * T_local; passed in so prefill offsets compose).
     Returns (B, T_local, H, hs) attention output for the local chunk.
     """
-    from .compat import axis_size
-
-    n = axis_size(axis_name)  # static at trace time
+    n = lax.axis_size(axis_name)  # static at trace time
     idx = lax.axis_index(axis_name)
     b, t, h, hs = q.shape
     scale = 1.0 / (hs ** 0.5)
@@ -135,7 +133,7 @@ def sp_cache_attention(q, k_cache, v_cache, q_pos, mesh, axis_name: str = SP_AXI
     q_pos: (B, T) absolute positions (cache slots > q_pos are masked, so
     not-yet-written positions never contribute). Returns (B, T, H, hs).
     """
-    from .compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from .mesh import DP_AXIS, TP_AXIS
@@ -192,7 +190,7 @@ def ring_attention(q, k, v, mesh, pos0: int = 0, axis_name: str = SP_AXIS):
     sharding: sequence axis over sp, everything else replicated.
     """
     from jax.sharding import PartitionSpec as P
-    from .compat import shard_map
+    from jax import shard_map
 
     from .mesh import TP_AXIS
 

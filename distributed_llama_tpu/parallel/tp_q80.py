@@ -113,7 +113,7 @@ def tp_row_matmul(
     x is (B, n) or (B, T, n), replicated over tp (the reference likewise
     gives every node the full normed activation, ref: llama2-tasks.cpp:249).
     """
-    from .compat import shard_map
+    from jax import shard_map
 
     tp = mesh.shape.get(TP_AXIS, 1)
     tp_ax = TP_AXIS if tp > 1 else None
@@ -145,7 +145,7 @@ def tp_flash_attention(
     batch shards on dp, heads/kv-heads on tp (the reference's KvCacheSlice
     head split, ref: src/transformer.cpp:161-171). Pure shard-local —
     attention never mixes heads, so no collective is needed."""
-    from .compat import shard_map
+    from jax import shard_map
 
     from ..ops.pallas_attention import flash_attention
 
@@ -244,7 +244,7 @@ def tp_col_matmul(
     local (B_l, T_l, n/tp) x slice contracts with this shard's weight slice
     (Pallas fused Q40 kernel when use_pallas), and partials all-reduce.
     Output is (B, T, d), replicated over tp like GSPMD's own all-reduce."""
-    from .compat import shard_map
+    from jax import shard_map
 
     tp = mesh.shape[TP_AXIS]
     dp_ax, sp_ax = _batch_axes(mesh, x)

@@ -52,6 +52,7 @@ callable again). Docs: docs/observability.md ("Device tier").
 
 from __future__ import annotations
 
+import re
 import threading
 import time
 
@@ -72,8 +73,6 @@ def compile_key_str(key) -> str:
     ':' (nested tuples with 'x'); bare ints are forward-segment widths;
     anything outside [0-9A-Za-z_:.x-] flattens to '_' so the string is
     a clean Prometheus label value and JSONL field."""
-    import re
-
     if isinstance(key, tuple):
         s = ":".join(_key_elem(x) for x in key)
     elif isinstance(key, int):
@@ -108,14 +107,49 @@ class _CompileWatch:
         # mint outright rather than paying for it first
         COMPILES.pre_compile(eng, self._key)
         t0 = time.perf_counter()
+        kernels = _kernels_in(eng, self._fn, args)
         out = self._fn(*args)
         ms = (time.perf_counter() - t0) * 1e3
         self._done = True
-        COMPILES.record(eng, self._key, ms)
+        COMPILES.record(eng, self._key, ms, kernels=kernels)
         steps = getattr(eng, "_steps", None)
         if steps is not None and steps.get(self._key) is self:
             steps[self._key] = self._fn  # steady state: zero wrapper cost
         return out
+
+
+_KERNEL_NAME_RE = re.compile(r'@tpu_custom_call\(.*?kernel_name = "([^"]+)"')
+
+
+def kernel_call_sites(lowered_text: str) -> dict:
+    """{kernel name: call sites} of the Pallas TPU kernels
+    (`tpu_custom_call`s — q40_matmul, q40_expert_matmul, flash_attention)
+    in a lowered (StableHLO) module. Sites, not executions: jit emits one
+    function per distinct shape and a layer loop calls it many times —
+    presence is the signal."""
+    out: dict = {}
+    for name in _KERNEL_NAME_RE.findall(lowered_text):
+        out[name] = out.get(name, 0) + 1
+    return out
+
+
+def _kernels_in(engine, fn, args) -> dict | None:
+    """kernel_call_sites of the program lowered for these arguments: the
+    record that makes a silent trip to the XLA dequant path
+    (ops/matmul.local_matmul — `use_pallas` on but the operands do not
+    qualify, e.g. a prefill segment of more than pallas_q40.MAX_T rows)
+    visible per executable. None = not inspected: the engine runs without
+    compiled kernels (use_pallas off, or interpret mode) or `fn` is not a
+    jitted callable. The lowering shares jit's trace cache with the call
+    that follows, so it costs one extra jaxpr -> StableHLO pass per MINT,
+    never per step."""
+    if not getattr(engine, "use_pallas", False) or getattr(
+            engine, "pallas_interpret", False):
+        return None
+    lower = getattr(fn, "lower", None)
+    if lower is None:
+        return None
+    return kernel_call_sites(lower(*args).as_text())
 
 
 class CompileLedger:
@@ -167,7 +201,8 @@ class CompileLedger:
                 "docs/operations.md 'Recompile storms')",
                 retryable=False)
 
-    def record(self, engine, key, ms: float) -> None:
+    def record(self, engine, key, ms: float, *,
+               kernels: dict | None = None) -> None:
         ks = compile_key_str(key)
         warm = bool(getattr(engine, "_compile_warm", False))
         with self._lock:
@@ -183,17 +218,26 @@ class CompileLedger:
                 rec["count"] += 1
                 rec["ms"] = round(rec["ms"] + ms, 3)
                 rec["last_ms"] = round(ms, 3)
+                # Pallas kernels in the minted program (see
+                # _kernels_in; None = not inspected)
+                rec["kernels"] = kernels
         if TRACER.enabled:
             TRACER.event("compile", 0, key=ks, ms=round(ms, 3), warm=warm)
 
     def summary(self) -> dict:
         """The ``compiles`` /stats block (and the /metrics source)."""
+        from ..utils.compile_cache import COUNTS
+
         with self._lock:
             return {"total": self.total,
                     "total_ms": round(self.total_ms, 3),
                     "after_warmup": self.after_warmup,
                     "frozen": self.freeze,
                     "key_overflow": self.key_overflow,
+                    # persistent compilation cache traffic (zeros until
+                    # utils/compile_cache.ensure_compile_cache ran)
+                    "persistent_cache_hits": COUNTS["hits"],
+                    "persistent_cache_misses": COUNTS["misses"],
                     "by_key": {k: dict(v) for k, v in self.by_key.items()}}
 
     def reset(self) -> None:
@@ -249,18 +293,24 @@ def _tree_bytes(tree) -> int:
 
 
 def device_memory_stats():
-    """{bytes_in_use, bytes_limit} from the first local device, or None
-    where the backend has no allocator stats (CPU test runs)."""
+    """{bytes_in_use, bytes_limit, per_device_bytes_in_use} from the local
+    devices' allocators (in_use/limit are the first device's; the list
+    has every local device, in id order — a tp mesh should show ~equal
+    entries, a placement bug everything on device 0), or None where the
+    backend has no allocator stats (CPU test runs)."""
     import jax
 
     try:
-        ms = jax.local_devices()[0].memory_stats()
+        all_ms = [d.memory_stats() for d in jax.local_devices()]
     except Exception:  # noqa: BLE001 — backend-dependent surface
         return None
+    ms = all_ms[0] if all_ms else None
     if not ms or "bytes_in_use" not in ms:
         return None
     return {"bytes_in_use": int(ms["bytes_in_use"]),
-            "bytes_limit": int(ms.get("bytes_limit", 0)) or None}
+            "bytes_limit": int(ms.get("bytes_limit", 0)) or None,
+            "per_device_bytes_in_use": [int((m or {}).get("bytes_in_use", 0))
+                                        for m in all_ms]}
 
 
 def hbm_ledger(engine, prefix_cache=None, *, block_len: int | None = None,
@@ -368,6 +418,7 @@ def hbm_ledger(engine, prefix_cache=None, *, block_len: int | None = None,
         "per_block_bytes": per_block,
         "device_bytes_in_use": None,
         "device_bytes_limit": None,
+        "per_device_bytes_in_use": None,
         "unaccounted_bytes": None,
         "headroom_bytes": None,
         "slots_addable": None,
@@ -376,6 +427,7 @@ def hbm_ledger(engine, prefix_cache=None, *, block_len: int | None = None,
     if dev is not None:
         out["device_bytes_in_use"] = dev["bytes_in_use"]
         out["device_bytes_limit"] = dev["bytes_limit"]
+        out["per_device_bytes_in_use"] = dev.get("per_device_bytes_in_use")
         out["unaccounted_bytes"] = max(dev["bytes_in_use"] - accounted, 0)
         if dev["bytes_limit"]:
             free = max(dev["bytes_limit"] - dev["bytes_in_use"], 0)
@@ -492,6 +544,19 @@ def resolve_auto_shape(engine, *, serve_batch, prefix_blocks=0,
     knee = None
     knee_basis = "default_heuristic"
     if autotune is not None:
+        import jax
+
+        live = jax.default_backend()
+        if autotune.get("backend") != live:
+            # a knee measured on another backend says nothing about this
+            # one (the committed AUTOTUNE.json is a CPU run of the tiny
+            # preset): refuse it where it would be applied
+            raise ValueError(
+                f"autotune artifact was calibrated on backend "
+                f"{autotune.get('backend')!r} (model "
+                f"{autotune.get('model')!r}) but this engine runs on "
+                f"{live!r} — recalibrate with tools/autotune.py on this "
+                "backend, or omit --autotune for the default heuristic")
         k = (autotune.get("knee") or {}).get("knee_rows")
         if k:
             knee = int(k)
@@ -567,20 +632,21 @@ def mesh_label(mesh) -> str:
 
 def build_info(engine=None) -> dict:
     """The ``dllama_build_info`` label set / ``build`` healthz block:
-    package version, jax version, active backend, mesh shape. Works for
-    every tier including the weightless --replica-hosts front template
-    (engine may be a shape shim or None)."""
+    package version, jax version, active backend, the device as JAX
+    reports it (kind + count) and the mesh shape. INITIALIZES the backend
+    — only a process that owns its device may call this; the process
+    tiers' front door relays a worker's block instead
+    (apps/api_server.ApiState.build_info)."""
     import jax
 
     from .. import __version__
 
-    try:
-        backend = jax.default_backend()
-    except Exception:  # noqa: BLE001 — no backend initialized yet
-        backend = "uninitialized"
+    devices = jax.devices()
     return {"version": __version__,
             "jax": jax.__version__,
-            "backend": backend,
+            "backend": devices[0].platform,
+            "device_kind": devices[0].device_kind,
+            "device_count": len(devices),
             "mesh": mesh_label(getattr(engine, "mesh", None))}
 
 

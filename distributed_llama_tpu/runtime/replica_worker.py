@@ -228,6 +228,7 @@ class ReplicaServer:
         # double-count across a rolling restart (tests/test_router.py
         # pins the same contract for thread replicas)
         self._carry = {k: 0 for k in _COUNTER_KEYS}
+        self._build: dict | None = None  # build_info, computed once
         self._bind = (host, int(port))
         self._srv: socket.socket | None = None
         self._done = threading.Event()
@@ -592,7 +593,17 @@ class ReplicaServer:
                 # the disaggregation role — connect-mode routers learn it
                 # from here (spawn mode ships it in the worker config)
                 "tier": self.tier,
+                # this process owns its device: backend, device kind and
+                # count for the front door's /healthz build block
+                "build": self._build_block(sup),
                 "counters": counters}
+
+    def _build_block(self, sup) -> dict:
+        if self._build is None:
+            from .profiler import build_info
+
+            self._build = build_info(sup.engine)
+        return self._build
 
     def _summary(self) -> dict:
         with self._sup_lock:
@@ -671,8 +682,8 @@ def build_supervisor_factory(cfg: dict):
         spec = ModelSpec(**ts)
         host = random_tensors(spec, seed=int(cfg.get("seed", 0)),
                               scale=float(cfg.get("scale", 0.02)))
-        params = load_params(spec, host, mode=cfg.get("mode", "dense"),
-                             dtype=compute)
+        mode = cfg.get("mode", "dense")
+        params = load_params(spec, host, mode=mode, dtype=compute)
         model_fp = 0
     else:
         from ..io.model_file import content_fingerprint, read_spec
@@ -696,6 +707,8 @@ def build_supervisor_factory(cfg: dict):
         return Engine(spec, params, batch=batch, max_seq_len=max_seq,
                       compute_dtype=compute, cache_dtype=cache,
                       use_pallas=cfg.get("pallas"),
+                      activation_q80=(bool(cfg.get("activation_q80"))
+                                      and mode == "q40"),
                       model_fingerprint=model_fp)
 
     n_blocks = 0
@@ -755,6 +768,12 @@ def config_from_cli_args(args, serve_batch: int) -> dict:
         "compute_dtype": getattr(args, "compute_dtype", "bf16"),
         "cache_dtype": getattr(args, "cache_dtype", "bf16"),
         "pallas": getattr(args, "pallas", None),
+        # --buffer-float-type q80 (the CLI default): the Q80 activation
+        # round-trip apps/dllama.build_engine arms for Q40 models — a
+        # worker must compute what the single-supervisor tier computes
+        # from the same flags (and mint the same, cacheable, programs)
+        "activation_q80": getattr(args, "buffer_float_type",
+                                  "f32") == "q80",
         "prefix_cache": bool(getattr(args, "prefix_cache", False)),
         "prefix_blocks": int(getattr(args, "prefix_blocks", 0) or 0),
         "prefix_block_len": int(getattr(args, "prefix_block_len", None)
@@ -856,6 +875,11 @@ def main(argv: list[str] | None = None) -> int:
         profile_dir = os.path.join(
             profile_dir, f"worker-{cfg.get('fault_key') or os.getpid()}")
 
+    # one compile cache for every worker of this checkout: worker 1 and
+    # every respawn hit worker 0's compiles (utils/compile_cache.py)
+    from ..utils.compile_cache import ensure_compile_cache
+
+    ensure_compile_cache()
     sup_factory = build_supervisor_factory(cfg)
     server = ReplicaServer(sup_factory, host=args.host, port=args.port,
                            io_timeout=args.io_timeout,
@@ -1279,6 +1303,30 @@ class WorkerClient:
 # -- parent-side process spawn/monitor -------------------------------------
 
 
+def chip_assignment_env(rid: int) -> dict:
+    """Environment that gives local worker `rid` exactly ONE TPU chip
+    (chip index = replica id), in the variables the installed libtpu
+    honours: without them every worker process would try to open every
+    chip on the host, and only the first could. Harmless off-TPU (the CPU
+    backend never reads them)."""
+    return {"TPU_VISIBLE_CHIPS": str(int(rid)),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1"}
+
+
+def local_tpu_chips() -> int:
+    """TPU chips this host exposes to processes, counted from their device
+    nodes (`/dev/vfio/<n>` on v5e and newer, `/dev/accel<n>` before) WITHOUT
+    initializing a JAX backend — the process tiers' front door must hold no
+    device. Device nodes, not PCI ids: a one-chip slice of a four-chip
+    board lists four PCI functions and one node. 0 on a host with no
+    TPU."""
+    import glob
+
+    return (len(glob.glob("/dev/vfio/[0-9]*"))
+            + len(glob.glob("/dev/accel[0-9]*")))
+
+
 def classify_exit(rc: int | None) -> str:
     """Human- and machine-readable exit classification for the supervisor
     log and the per-replica /stats proc block. Negative returncodes are
@@ -1342,6 +1390,7 @@ class WorkerProc:
         pkg_root = os.path.dirname(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))))
         env["PYTHONPATH"] = pkg_root + os.pathsep + env.get("PYTHONPATH", "")
+        env.update(chip_assignment_env(self.rid))
         env.update(self._env)
         log = open(self.log_path, "ab")
         try:

@@ -581,6 +581,13 @@ class RemoteReplicaHandle:
 
     # -- supervision internals ---------------------------------------------
 
+    def health_snapshot(self) -> dict:
+        """The last PONG payload the monitor cached (state/load/counters
+        and the worker's `build` block — its backend and device facts,
+        which the front door relays because it may not ask JAX itself)."""
+        with self._lock:
+            return dict(self._health)
+
     def _refresh_health(self) -> None:
         with self._lock:
             epoch = self._fold_epoch

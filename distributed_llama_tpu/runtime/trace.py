@@ -571,6 +571,12 @@ def _add_device_blocks(p: _Prom, summary: dict,
             p.add(pre + "compile_ms", rec.get("ms"), lab,
                   type_="counter",
                   help_="Cumulative trace+compile wall ms by compile key")
+            for kname, n in (rec.get("kernels") or {}).items():
+                p.add(pre + "compile_kernel_calls", n,
+                      {**lab, "kernel": _esc(kname)}, type_="gauge",
+                      help_="Pallas kernel call sites in the minted "
+                            "executable by compile key (absent = not "
+                            "inspected)")
     hbm = summary.get("hbm")
     if hbm:
         for cat, field in (("weights", "weights_bytes"),

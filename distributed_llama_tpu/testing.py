@@ -4,7 +4,9 @@ One place for the end-to-end fixture the suite uses everywhere: a small
 random-weight Llama spec written to a real `.m` file plus a llama2.c-style
 byte-fallback tokenizer `.t` (vocab 288 = 3 specials + 256 byte tokens +
 fillers; byte b maps to token b+3), so CLI/API/cluster paths exercise the
-same file formats the reference consumes.
+same file formats the reference consumes. `write_synthetic_model` streams
+a random-but-valid `.m` of ANY size (chip_smoke.py's 7B file,
+tools/rehearse_70b.py's 70B-width one) without holding it in memory.
 """
 
 from __future__ import annotations
@@ -67,3 +69,36 @@ def write_fixture(dirpath, seed: int = 77, rng=None,
         vocab=byte_fallback_vocab(spec.vocab_size),
         scores=[0.0] * spec.vocab_size, bos_id=1, eos_id=2))
     return mpath, tpath
+
+
+def write_synthetic_model(path: str, spec: ModelSpec, seed: int) -> int:
+    """Stream random-but-valid tensors to `path` in exact plan order, one
+    tensor resident at a time: Q40 blocks get f16 scales in [0.005, 0.02]
+    + uniform nibble bytes; f32 tensors small gaussians (norm weights near
+    1). Returns the file size in bytes."""
+    import os
+
+    from .io.model_file import write_header
+    from .quants.types import BLOCK_SIZE, Q40_BLOCK_BYTES
+
+    rng = np.random.default_rng(seed)
+    with open(path, "wb") as f:
+        write_header(f, spec)
+        for name, shape, ftype in model_tensor_plan(spec):
+            n = int(np.prod(shape))
+            if ftype == FloatType.F32:
+                x = rng.standard_normal(n, dtype=np.float32) * 0.02
+                if "rms" in name:
+                    x += 1.0
+                f.write(x.tobytes())
+            elif ftype == FloatType.Q40:
+                nb = n // BLOCK_SIZE
+                raw = np.empty((nb, Q40_BLOCK_BYTES), np.uint8)
+                scales = rng.uniform(0.005, 0.02, nb).astype(np.float16)
+                raw[:, :2] = scales.reshape(nb, 1).view(np.uint8)
+                raw[:, 2:] = rng.integers(
+                    0, 256, (nb, Q40_BLOCK_BYTES - 2), dtype=np.uint8)
+                f.write(raw.tobytes())
+            else:
+                raise AssertionError(ftype)
+    return os.path.getsize(path)

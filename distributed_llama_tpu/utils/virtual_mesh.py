@@ -1,14 +1,13 @@
 """Virtual CPU mesh bootstrap — the ONE place the device-count convention
 lives.
 
-Three consumers need "N virtual CPU devices" before jax initializes a
-backend: tests/conftest.py (the 8-device SPMD test mesh), the dlgrind
-jaxpr audit (analysis/__main__.py traces mesh entry points), and
-__graft_entry__.py's multichip dryrun fallback on jax 0.4.x. XLA parses
-XLA_FLAGS once per process, so all of them must append the flag the same
-way and early; hand-rolled copies of this logic drifted — hence this
-module, which imports nothing heavy (NO jax) so it is safe to call before
-backend selection.
+Two consumers need "N virtual CPU devices" before jax is even imported:
+tests/conftest.py (the 8-device SPMD test mesh) and the dlgrind jaxpr audit
+(analysis/__main__.py traces mesh entry points). XLA parses XLA_FLAGS once
+per process, so both must append the flag the same way and early; hand-rolled
+copies of this logic drifted — hence this module, which imports nothing heavy
+(NO jax) so it is safe to call before backend selection. (Code that has
+already imported jax sets `jax_num_cpu_devices` instead.)
 """
 
 from __future__ import annotations
@@ -23,7 +22,7 @@ def ensure_virtual_cpu_devices(n: int = VIRTUAL_MESH_DEVICES) -> None:
 
     Takes effect only if no XLA backend has materialized yet (flags are
     parsed once per process); callers that can verify afterwards should
-    (see __graft_entry__.dryrun_multichip).
+    (tests/conftest.py asserts the device count).
     """
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:

@@ -318,7 +318,7 @@ def test_jaxpr_audit_detects_replication_leak():
     from jax.sharding import PartitionSpec as P
 
     from distributed_llama_tpu.parallel import make_mesh
-    from distributed_llama_tpu.parallel.compat import shard_map
+    from jax import shard_map
 
     mesh = make_mesh(tp=2, dp=1)
     x = jnp.ones((2, 8), jnp.float32)

@@ -1491,3 +1491,30 @@ def test_profiler_flags_rejected_without_serve_batch():
         dllama.main(["api", "--model", "m", "--tokenizer", "t",
                      "--serve-batch", "2", "--profile-sample", "0"])
     assert ">= 1" in str(ei.value)
+
+
+def test_api_engine_that_cannot_be_built_exits_nonzero(tmp_path):
+    """`dllama api --serve-batch` builds and warms its engine BEFORE it
+    listens: an engine that cannot be built (here: the injected
+    prefill_raise fault fires inside the warm-up; on a chip, an
+    out-of-memory or a refused compile) must end the server with a
+    traceback and a non-zero exit — not a process that looks ready and
+    500s, nor a rebuild loop that never becomes ready."""
+    import os
+    import subprocess
+    import sys
+
+    from distributed_llama_tpu.testing import write_fixture
+
+    mpath, tpath = write_fixture(tmp_path)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=repo,
+               DLLAMA_FAULTS="prefill_raise:times=0")
+    r = subprocess.run(
+        [sys.executable, "-m", "distributed_llama_tpu.apps.dllama", "api",
+         "--model", mpath, "--tokenizer", tpath, "--serve-batch", "2",
+         "--host", "127.0.0.1", "--port", "0"],
+        env=env, cwd=repo, capture_output=True, text=True, timeout=300)
+    assert r.returncode not in (0, None), r.stdout[-500:]
+    assert "FaultError" in r.stderr
+    assert "listening" not in r.stdout

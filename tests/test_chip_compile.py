@@ -1,0 +1,202 @@
+"""Compile the main path's kernels and step programs at REAL widths for a
+described (not attached) TPU v5e:2x2 — what the chip's compiler refuses,
+it refuses here, at no chip time (on-chip-measurement guide, section 2;
+the whole-model, full-depth version is tools/rehearse_chip_compile.py).
+
+Interpret-mode tests cannot see these failures: before this file existed,
+every tp=4 row shard whose row count no tile divides (2752, 8000, 32064
+rows) was one whole-weight block that overflowed scoped VMEM on silicon
+while every interpret test passed.
+
+ONE file, topology described inside a module-scoped fixture — only the
+xdist worker that is handed this file loads libtpu, and every worker
+collects the same tests. Nothing here runs on a device, so nothing here
+is a result or a time.
+"""
+
+import dataclasses
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(REPO, "tools"))
+
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler installed
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one (every rerun would warn and
+    # recompile): keep these compiles out of it
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def tp4(topo):
+    from distributed_llama_tpu.parallel.mesh import make_mesh
+
+    return make_mesh(tp=4, devices=topo.devices)
+
+
+def _struct(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _placed(tree, sharding):
+    return jax.tree_util.tree_map(
+        lambda s: _struct(s.shape, s.dtype, sharding), tree)
+
+
+def _has_kernel(compiled) -> bool:
+    return "tpu_custom_call" in compiled.as_text()
+
+
+# Llama-2-7B weight shapes (d rows, n contraction): w1 = w3, w2, wq = wo,
+# wcls; t = 1 is decode, t = 256 (= MAX_T) the widest kernel prefill
+@pytest.mark.parametrize("d,n,t", [
+    (11008, 4096, 1), (11008, 4096, 256),      # w1 / w3
+    (4096, 11008, 1), (4096, 11008, 256),      # w2
+    (4096, 4096, 1),                           # wq / wo
+    (32000, 4096, 1), (32000, 4096, 256),      # wcls
+])
+def test_q40_matmul_compiles_at_7b_widths(one_chip, d, n, t):
+    from rehearse_chip_compile import q40_struct
+
+    from distributed_llama_tpu.ops.pallas_q40 import q40_matmul
+
+    w = _placed(q40_struct(d, n), one_chip)
+    x = _struct((t, n), BF16, one_chip)
+    c = jax.jit(lambda x, w: q40_matmul(x, w, out_dtype=BF16)).lower(
+        x, w).compile()
+    assert _has_kernel(c)
+
+
+@pytest.mark.parametrize("d,n", [(14336, 4096), (4096, 14336)])
+def test_q40_expert_matmul_compiles_at_mixtral_widths(one_chip, d, n):
+    from rehearse_chip_compile import q40_struct
+
+    from distributed_llama_tpu.ops.pallas_q40 import q40_expert_matmul
+
+    w = _placed(q40_struct(8, d, n), one_chip)   # moe_up/gate | moe_down
+    x = _struct((1, n), BF16, one_chip)
+    e = _struct((), jnp.int32, one_chip)
+    c = jax.jit(lambda x, w, e: q40_expert_matmul(
+        x, w, e, out_dtype=BF16)).lower(x, w, e).compile()
+    assert _has_kernel(c)
+
+
+@pytest.mark.parametrize("b,t,cache_dtype", [
+    (8, 1, BF16),                   # batched decode
+    (1, 256, BF16),                 # 256-token prefill chunk
+    (8, 1, jnp.float8_e4m3fn),      # fp8 cache
+])
+def test_flash_attention_compiles(one_chip, b, t, cache_dtype):
+    from distributed_llama_tpu.ops.pallas_attention import flash_attention
+
+    h = kvh = 32
+    q = _struct((b, t, h, 128), BF16, one_chip)
+    kv = _struct((b, kvh, 1024, 128), cache_dtype, one_chip)
+    pos = _struct((b, t), jnp.int32, one_chip)
+    c = jax.jit(flash_attention).lower(q, kv, kv, pos).compile()
+    assert _has_kernel(c)
+
+
+# the row shards the chip's compiler refused before _tile_d bounded its
+# tile: Llama-2-7B w1/w3 (11008 -> 2752 per shard), a 32000-vocab head
+# (-> 8000) and Llama-3's 128256-vocab head (-> 32064)
+@pytest.mark.parametrize("d", [11008, 32000, 128256])
+def test_tp4_row_shard_compiles(tp4, d):
+    from rehearse_chip_compile import q40_struct
+
+    from distributed_llama_tpu.parallel.tp_q80 import (TpRowWeight,
+                                                       tp_row_matmul,
+                                                       tp_row_pspec)
+
+    assert (d // 4) % 128  # ragged: no lane-multiple tile divides the shard
+    w = TpRowWeight(q40_struct(d, 4096))
+    w = jax.tree_util.tree_map(
+        lambda s, ps: _struct(s.shape, s.dtype, NamedSharding(tp4, ps)),
+        w, tp_row_pspec(w))
+    x = _struct((8, 4096), BF16, NamedSharding(tp4, P()))
+    c = jax.jit(lambda x, w: tp_row_matmul(
+        x, w, tp4, compute_dtype=BF16)).lower(x, w).compile()
+    assert _has_kernel(c)
+
+
+def test_tp4_col_matmul_compiles_with_q80_reduce(tp4):
+    from rehearse_chip_compile import q40_struct
+
+    from distributed_llama_tpu.parallel.tp_q80 import (repack_col_tp,
+                                                       tp_col_matmul,
+                                                       tp_col_pspec)
+
+    w = jax.eval_shape(lambda: repack_col_tp(jax.tree_util.tree_map(
+        lambda s: jnp.zeros(s.shape, s.dtype), q40_struct(4096, 11008)), 4))
+    w = jax.tree_util.tree_map(
+        lambda s, ps: _struct(s.shape, s.dtype, NamedSharding(tp4, ps)),
+        w, tp_col_pspec(w))                      # w2, col-split
+    x = _struct((8, 1, 11008), BF16, NamedSharding(tp4, P(None, None, "tp")))
+    c = jax.jit(lambda x, w: tp_col_matmul(
+        x, w, tp4, compute_dtype=BF16, reduce="q80",
+        use_pallas=True)).lower(x, w).compile()
+    text = c.as_text()
+    assert _has_kernel(c)
+    assert "all-gather" in text and "all-to-all" in text  # the int8 exchange
+
+
+@pytest.mark.parametrize("model", ["llama2_7b", "mixtral_8x7b"])
+def test_whole_decode_step_compiles_at_published_widths(topo, model):
+    """One-chip `slot_decode_step` (B=8) at published widths, depth cut to
+    2 layers. Mixtral at B=8 is the all-experts branch of `_moe_ffn` —
+    what the first benchmark cells will serve."""
+    import rehearse_chip_compile as r
+
+    spec = dataclasses.replace(
+        {"llama2_7b": r.LLAMA2_7B, "mixtral_8x7b": r.MIXTRAL_8X7B}[model],
+        n_layers=2)
+    fn, args = r.abstract_step(spec, topo.devices, batch=8, t=1,
+                               seq_len=1024)
+    assert _has_kernel(fn.lower(*args).compile())
+
+
+def test_whole_tp4_q80_steps_compile(topo):
+    """The program `dllama api --tp 4 --buffer-float-type q80
+    --serve-batch 8` mints — decode and the 32-wide slot prefill — over the
+    four described chips, 7B widths cut to 2 layers."""
+    import rehearse_chip_compile as r
+
+    spec = dataclasses.replace(r.LLAMA2_7B, n_layers=2)
+    for t in (1, 32):
+        fn, args = r.abstract_step(spec, topo.devices, tp=4, batch=8, t=t,
+                                   seq_len=1024, q80=True)
+        rep = r.compile_report(fn, args)
+        # call SITES in the lowered module (jit dedups equal shapes):
+        # wq=wk=wv, wo, w1=w3, w2, wcls
+        assert rep["kernels"].get("q40_matmul", 0) >= 5, rep
+        assert rep["kernels"].get("flash_attention", 0) >= 1, rep
+        assert rep["collectives"]["all-to-all"] > 0, rep

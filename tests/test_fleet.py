@@ -716,10 +716,7 @@ def test_reap_mark_does_not_flip_readiness_process_tier(tmp_path):
                              n_kv_heads=2, vocab_size=128, seq_len=SEQ),
            "seed": 3, "scale": 0.05, "compute_dtype": "f32", "batch": 2,
            "serve": {"stall_timeout": 60.0}}
-    wenv = {"JAX_PLATFORMS": "cpu",
-            "JAX_COMPILATION_CACHE_DIR": os.path.join(
-                os.path.expanduser("~"), ".cache", "dllama_tpu_xla"),
-            "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "1.0"}
+    wenv = {"JAX_PLATFORMS": "cpu"}
 
     def mk(i):
         proc = WorkerProc(i, dict(cfg, fault_key=f"r{i}"),

@@ -121,7 +121,7 @@ def test_collective_counter_sees_known_program():
     extra reduction is NOT a reliable probe — XLA's all-reduce combiner
     merges independent reduces into one variadic op — so probe with known
     standalone programs instead.)"""
-    from distributed_llama_tpu.parallel.compat import shard_map
+    from jax import shard_map
 
     mesh = make_mesh(tp=2, dp=1)
 

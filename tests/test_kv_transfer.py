@@ -260,13 +260,7 @@ def test_wire_fill_parity_ledger_reconciles_exactly(tiny):
 # arming the global recv_stall/frame_truncate sites here counts ONLY the
 # test-side transfer calls — deterministic `after=` placement.
 
-_WORKER_ENV = {
-    "JAX_PLATFORMS": "cpu",
-    "JAX_COMPILATION_CACHE_DIR": __import__("os").path.join(
-        __import__("os").path.expanduser("~"), ".cache",
-        "dllama_tpu_xla"),
-    "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "1.0",
-}
+_WORKER_ENV = {"JAX_PLATFORMS": "cpu"}
 _WORKER_CFG = {"test_spec": SPEC_FIELDS, "seed": SEED, "scale": SCALE,
                "compute_dtype": "f32", "batch": 2,
                "prefix_cache": True, "prefix_blocks": 16,
@@ -382,7 +376,10 @@ def test_donor_hard_exit_mid_block_data_degrades(tiny, tmp_path):
         # stream yields no import), the fill degrades
         assert st.fills_ok == 0 and st.fill_fallbacks == 1
         assert ans in (-1, 3 * BL)  # EOF may land before or after ACK
-        assert time.perf_counter() and proc.poll() is not None
+        # the EOF can reach us a moment before the dying donor is
+        # waitable (its sockets close during exit): bounded wait
+        proc.proc.wait(timeout=10.0)
+        assert proc.poll() is not None
         from distributed_llama_tpu.runtime.replica_worker import \
             classify_exit
         assert classify_exit(proc.poll()) == "fault_exit"

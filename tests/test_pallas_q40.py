@@ -104,6 +104,59 @@ def test_supports_and_tiles():
     assert not supports_pallas(stacked)  # leading dims must be sliced first
 
 
+@pytest.mark.parametrize("d,m,want", [
+    (2752, 2048, 256),    # Llama-2-7B w1/w3 row shard at tp=4
+    (8000, 2048, 1024),   # 32000-vocab head row shard at tp=4
+    (32064, 2048, 1024),  # Llama-3 128256-vocab head row shard at tp=4
+    (2752, 5504, 256),    # wide contraction: only the small tiles fit
+])
+def test_tile_d_is_bounded_for_ragged_row_counts(d, m, want):
+    """Row counts no candidate divides (tp row shards) must get a bounded
+    lane-multiple tile over a cdiv grid — never the whole local weight as
+    one block, which the chip's compiler refuses (scoped VMEM)."""
+    from distributed_llama_tpu.ops.pallas_q40 import (LANES,
+                                                      _TILE_BYTES_MAX)
+
+    td = _tile_d(d, m)
+    assert td == want
+    assert td % LANES == 0 and td * m <= _TILE_BYTES_MAX and d % td != 0
+
+
+def test_tile_d_refuses_a_weight_no_tile_fits():
+    with pytest.raises(ValueError, match="scoped-VMEM"):
+        _tile_d(4097, 20000)  # 128 x 20000 packed bytes > the budget
+
+
+@pytest.mark.parametrize("t,out_dtype", [(1, jnp.float32),
+                                         (32, jnp.bfloat16)])
+def test_ragged_last_block_matches_dequant_oracle(rng, monkeypatch, t,
+                                                  out_dtype):
+    """A ragged cdiv grid (d = 2*256 + 240) in decode (f32) and sub-tiled
+    prefill (bf16) modes vs the XLA dequant path: the padded rows of the
+    last block must not leak into any kept output column."""
+    import distributed_llama_tpu.ops.pallas_q40 as q
+
+    d, n = 752, 512
+    # shrink the budget so the tiny test weight takes the ragged branch
+    monkeypatch.setattr(q, "_TILE_BYTES_MAX", 256 * (n // 2))
+    assert _tile_d(d, n // 2) == 256 and d % 256
+    qt = _qt(rng, d, n)
+    x = jnp.asarray(rng.standard_normal((t, n), dtype=np.float32))
+    ref = jnp.einsum("tn,dn->td", x,
+                     dequantize_q40_jax(qt, dtype=jnp.float32))
+    q40_matmul.clear_cache()
+    got = np.asarray(q40_matmul(x, qt, out_dtype=out_dtype, interpret=True),
+                     dtype=np.float32)
+    q40_matmul.clear_cache()
+    assert got.shape == (t, d) and np.isfinite(got).all()
+    if out_dtype == jnp.float32:
+        np.testing.assert_allclose(got, np.asarray(ref), atol=2e-4,
+                                   rtol=1e-4)
+    else:
+        np.testing.assert_allclose(got, np.asarray(ref), rtol=2 ** -6,
+                                   atol=2 ** -6 * np.abs(ref).max())
+
+
 @pytest.mark.parametrize("d", [256, 1024])
 def test_subtiled_bf16_prefill_matches_whole_tile(rng, d, monkeypatch):
     """The mxu_bf16 unpack/MXU interleave (t>=16, bf16 out, td=256 sub-tiled

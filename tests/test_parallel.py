@@ -116,7 +116,7 @@ def test_param_pspecs_cover_all_leaves():
 def test_q80_psum_matches_psum():
     """Quantized all-reduce ~ exact all-reduce (the reference's Q80 wire,
     ref: src/tasks.cpp:124-163)."""
-    from distributed_llama_tpu.parallel.compat import shard_map
+    from jax import shard_map
 
     mesh = make_mesh(tp=8)
     x = np.random.default_rng(0).standard_normal((8, 4, 64)).astype(np.float32)
@@ -142,7 +142,7 @@ def test_q80_psum_matches_psum():
 def test_q80_psum_2shot_matches_psum():
     """Two-shot quantized all-reduce ~ exact all-reduce; chunk-block-aligned
     path (the wire-efficient form of the reference's Q80 exchange)."""
-    from distributed_llama_tpu.parallel.compat import shard_map
+    from jax import shard_map
 
     from distributed_llama_tpu.parallel import q80_psum_2shot
 

@@ -439,7 +439,42 @@ def test_capture_writes_a_trace_and_refuses_concurrent(tmp_path):
 def test_build_info_shape(tiny):
     eng = _engine(tiny, batch=1)
     b = build_info(eng)
-    assert set(b) == {"version", "jax", "backend", "mesh"}
+    assert set(b) == {"version", "jax", "backend", "device_kind",
+                      "device_count", "mesh"}
     assert b["mesh"] == "single" and b["backend"] == "cpu"
+    # the device as JAX reports it — chip_smoke.py's last line comes from
+    # this block (conftest pins 8 virtual CPU devices)
+    assert b["device_kind"] == "cpu" and b["device_count"] == 8
     assert b["version"] and b["jax"]
     assert build_info(None)["mesh"] == "single"
+
+
+def test_ledger_records_kernels_per_executable_and_autotune_backend(tiny):
+    """Two silent-fallback guards (ISSUE 22): the compile ledger carries,
+    per minted executable, which Pallas kernels are in it (None = not
+    inspected: this CPU engine compiles no kernels), and an autotune
+    artifact calibrated on another backend is refused where it is
+    applied."""
+    import numpy as np
+
+    from distributed_llama_tpu.runtime.profiler import (_kernels_in,
+                                                        resolve_auto_shape)
+
+    COMPILES.reset()
+    eng = _engine(tiny, batch=2)
+    eng.slot_decode_step(np.zeros((2, 1), np.int32),
+                         np.full((2,), eng.seq_len, np.int32))
+    rec = COMPILES.summary()["by_key"]["slot_decode"]
+    assert rec["count"] == 1 and rec["kernels"] is None
+    assert not eng.use_pallas and _kernels_in(eng, None, ()) is None
+
+    art = {"kind": "dllama-autotune", "version": 1, "backend": "tpu",
+           "model": "7b", "knee": {"knee_rows": 8}, "decode_curve": []}
+    with pytest.raises(ValueError, match="calibrated on backend 'tpu'"):
+        resolve_auto_shape(eng, serve_batch="auto", autotune=art,
+                           device_stats=None)
+    ok = resolve_auto_shape(eng, serve_batch="auto",
+                            autotune=dict(art, backend="cpu"),
+                            device_stats=None)
+    assert ok["serve_batch"] == 8
+    COMPILES.reset()

@@ -71,14 +71,10 @@ CFG = {"test_spec": SPEC_FIELDS, "seed": SEED, "scale": SCALE,
        "serve": {"stall_timeout": 60.0},
        "trace": {"capacity": 2048}}
 
-# the worker subprocess environment: CPU jax, plus the parent's XLA
-# compilation cache so repeat spawns skip the compile cost
-WORKER_ENV = {
-    "JAX_PLATFORMS": "cpu",
-    "JAX_COMPILATION_CACHE_DIR": os.path.join(
-        os.path.expanduser("~"), ".cache", "dllama_tpu_xla"),
-    "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "1.0",
-}
+# the worker subprocess environment: CPU jax. Workers place their XLA
+# compilation cache with the same helper as this suite
+# (utils/compile_cache.py), so repeat spawns skip the compile cost
+WORKER_ENV = {"JAX_PLATFORMS": "cpu"}
 
 SPAWN_TIMEOUT = 120.0   # worker startup bound (import + build + warmup)
 # _wait's give-up ceiling. Nothing below asserts elapsed time against
@@ -603,3 +599,119 @@ def test_admin_profile_guarded_and_rmsg_profile_roundtrips(tmp_path,
             srv.shutdown()
     finally:
         router.close()
+
+
+# -- one process per chip (ISSUE 22) ----------------------------------------
+
+
+def test_front_door_serves_without_touching_a_jax_backend(tmp_path,
+                                                          monkeypatch):
+    """The process tiers' front door must hold no device: with every way
+    of reaching a JAX backend in THIS process made to raise, the real
+    ApiState + HTTP handler still boot a worker, serve a completion, and
+    report the WORKER's backend/device facts on /healthz (relayed from
+    its health PONG — the front door may not ask JAX)."""
+    import http.client
+    import json
+    from http.server import ThreadingHTTPServer
+
+    from jax._src import xla_bridge
+
+    from distributed_llama_tpu.apps.api_server import ApiState, make_handler
+    from distributed_llama_tpu.apps.dllama import FrontDoorTemplate
+    from distributed_llama_tpu.testing import tiny_spec, write_fixture
+    from distributed_llama_tpu.tokenizer import Tokenizer
+
+    def boom(*a, **kw):
+        raise AssertionError("the front door touched a JAX backend")
+
+    for name in ("get_backend", "backends", "_get_backend_uncached"):
+        monkeypatch.setattr(xla_bridge, name, boom)
+    _, tpath = write_fixture(tmp_path)
+    spec = tiny_spec()
+    tok = Tokenizer.from_file(tpath)
+    fields = dict(dim=spec.dim, hidden_dim=spec.hidden_dim,
+                  n_layers=spec.n_layers, n_heads=spec.n_heads,
+                  n_kv_heads=spec.n_kv_heads, vocab_size=spec.vocab_size,
+                  seq_len=spec.seq_len)
+    cfg = {"test_spec": fields, "seed": SEED, "scale": SCALE,
+           "compute_dtype": "f32", "batch": 2,
+           "serve": {"stall_timeout": 60.0}}
+    state = ApiState(FrontDoorTemplate(spec), tok,
+                     Sampler(tok.vocab_size, 0.0, 0.9, 1),
+                     model_name="procs", serve_batch=2, replica_procs=1,
+                     worker_config=cfg)
+    try:
+        assert state.build_info()["backend"] == "uninitialized"
+        srv = ThreadingHTTPServer(("127.0.0.1", 0), make_handler(state))
+        threading.Thread(target=srv.serve_forever, daemon=True).start()
+        try:
+            conn = http.client.HTTPConnection(*srv.server_address,
+                                              timeout=SPAWN_TIMEOUT)
+            conn.request("POST", "/v1/completions", body=json.dumps(
+                {"prompt": "hello", "max_tokens": 4,
+                 "temperature": 0.0}).encode(),
+                headers={"Content-Type": "application/json"})
+            resp = conn.getresponse()
+            body = json.loads(resp.read())
+            assert resp.status == 200, body
+            assert 1 <= body["usage"]["completion_tokens"] <= 4
+            conn.request("GET", "/healthz")
+            build = json.loads(conn.getresponse().read())["build"]
+            conn.close()
+        finally:
+            srv.shutdown()
+        # the worker's facts, not this process's (which may not be asked)
+        assert build["backend"] == "cpu" and build["device_kind"] == "cpu"
+        assert build["device_count"] >= 1 and build["mesh"] == "front-door"
+    finally:
+        if state._fleet is not None:
+            state._fleet.close()
+        if state._scheduler is not None:
+            state._scheduler.close()
+
+
+def test_worker_environment_carries_its_chip_assignment(tmp_path,
+                                                        monkeypatch):
+    """Worker i is given exactly chip i (in the variables libtpu honours);
+    without them N workers on an N-chip host would each try to open every
+    chip."""
+    import subprocess
+
+    seen = {}
+
+    class FakePopen:
+        def __init__(self, cmd, env=None, **kw):
+            seen["env"] = env
+
+    monkeypatch.setattr(subprocess, "Popen", FakePopen)
+    for rid in (0, 3):
+        WorkerProc(rid, dict(CFG), workdir=str(tmp_path)).spawn()
+        env = seen["env"]
+        assert env["TPU_VISIBLE_CHIPS"] == str(rid)
+        assert env["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+        assert env["TPU_PROCESS_BOUNDS"] == "1,1,1"
+    # an explicit per-worker env still wins (tests pin workers to the CPU)
+    WorkerProc(1, dict(CFG), workdir=str(tmp_path),
+               env={"TPU_VISIBLE_CHIPS": "7"}).spawn()
+    assert seen["env"]["TPU_VISIBLE_CHIPS"] == "7"
+
+
+def test_more_workers_than_chips_is_a_startup_error(tmp_path, monkeypatch):
+    """`--replica-procs N` with N > the host's TPU chips can never come
+    up (one chip per worker): refused at start-up on a TPU host, before
+    any engine work; a host without a TPU is exempt."""
+    from distributed_llama_tpu.apps import api_server
+    from distributed_llama_tpu.apps.dllama import build_argparser
+    from distributed_llama_tpu.runtime import replica_worker
+    from distributed_llama_tpu.testing import write_fixture
+
+    mpath, tpath = write_fixture(tmp_path)
+    args = build_argparser().parse_args(
+        ["api", "--model", mpath, "--tokenizer", tpath, "--serve-batch",
+         "2", "--replica-procs", "2"])
+    monkeypatch.setattr(replica_worker, "local_tpu_chips", lambda: 1)
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    with pytest.raises(SystemExit) as e:
+        api_server.serve(args)
+    assert "exceeds the 1 TPU chip" in str(e.value)

@@ -54,63 +54,24 @@ def spec70():
 
 
 def write_file(path: str) -> int:
-    """Stream random-but-valid tensors in exact plan order: Q40 blocks get
-    f16 scales in [0.005, 0.02] + uniform nibble bytes; f32 tensors small
-    gaussians (norm weights near 1). Returns total bytes."""
-    import numpy as np
+    """Stream a random-but-valid 70B-width `.m` (the writer chip_smoke.py
+    shares: distributed_llama_tpu/testing.write_synthetic_model). Returns
+    total bytes."""
+    from distributed_llama_tpu.testing import write_synthetic_model
 
-    from distributed_llama_tpu.io.model_file import (model_tensor_plan,
-                                                     write_header)
-    from distributed_llama_tpu.quants.types import (FloatType,
-                                                    Q40_BLOCK_BYTES,
-                                                    BLOCK_SIZE, batch_bytes)
-
-    spec = spec70()
-    rng = np.random.default_rng(70)
     t0 = time.time()
-    with open(path, "wb") as f:
-        write_header(f, spec)
-        for name, shape, ftype in model_tensor_plan(spec):
-            n = shape[-1]
-            d = int(np.prod(shape[:-1])) if len(shape) > 1 else 1
-            if ftype == FloatType.F32:
-                if name.startswith(("rms", "layers")) and "rms" in name:
-                    x = 1.0 + rng.standard_normal(d * n, dtype=np.float32) * 0.02
-                else:
-                    x = rng.standard_normal(d * n, dtype=np.float32) * 0.02
-                f.write(x.astype(np.float32).tobytes())
-            elif ftype == FloatType.Q40:
-                nb = (n // BLOCK_SIZE) * d
-                raw = np.empty((nb, Q40_BLOCK_BYTES), np.uint8)
-                scales = rng.uniform(0.005, 0.02, nb).astype(np.float16)
-                raw[:, :2] = scales.reshape(nb, 1).view(np.uint8)
-                raw[:, 2:] = rng.integers(0, 256, (nb, Q40_BLOCK_BYTES - 2),
-                                          dtype=np.uint8)
-                f.write(raw.tobytes())
-            else:
-                raise AssertionError(ftype)
-    size = os.path.getsize(path)
+    size = write_synthetic_model(path, spec70(), seed=70)
     print(f"wrote {path}: {size / 1e9:.2f} GB in {time.time() - t0:.0f}s")
     return size
 
 
 def run_config(cfg: str) -> None:
     """Subprocess body: load + lower + step + account for one mesh."""
-    # BEFORE importing jax: 16 virtual CPU devices via the shared
-    # XLA_FLAGS bootstrap (utils/virtual_mesh.py) — the
-    # jax_num_cpu_devices config option does not exist on the 0.4.x
-    # jaxlib this image pins, and XLA parses the flag once per process
-    from distributed_llama_tpu.utils.virtual_mesh import \
-        ensure_virtual_cpu_devices
-
-    ensure_virtual_cpu_devices(16)
+    # 16 virtual CPU devices, chosen before any backend exists
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", 16)
-    except AttributeError:  # jax 0.4.x: the XLA_FLAGS path above rules
-        pass
+    jax.config.update("jax_num_cpu_devices", 16)
     assert jax.device_count() == 16, jax.devices()
     import jax.numpy as jnp
     import numpy as np

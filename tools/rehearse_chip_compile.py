@@ -1,0 +1,215 @@
+"""Compile the serving step programs for a DESCRIBED v5e:2x2 topology — no
+chip attached, nothing runs (on-chip-measurement guide, section 2).
+
+    JAX_PLATFORMS=cpu python tools/rehearse_chip_compile.py            # 7B, 32 layers
+    JAX_PLATFORMS=cpu python tools/rehearse_chip_compile.py --layers 2
+
+What this shows that interpret-mode tests cannot: whether the TPU compiler
+accepts every kernel inside the WHOLE decode and slot-prefill programs the
+engine would mint (one chip, and tp=4 with the q80 and the exact reduce),
+what each takes per device (`memory_analysis`), and which kernels and
+collectives the compiler put in. A compile that passes is not a chip run:
+it says nothing about results or times.
+
+The engine builds its mesh from `jax.devices()` and places real arrays,
+which a described device cannot hold — so the helpers below rebuild the
+params pytree the way `Engine.__init__` does (fuse at tp == 1; repack col
+weights + wrap row weights at tp > 1; `param_pspecs` shardings) over
+`jax.eval_shape`d leaves, and jit the same `forward()` call
+`Engine.slot_decode_step` / `slot_prefill_chunk` wrap. `Engine` picks the
+kernel path from `jax.default_backend()`, which is `cpu` here: callers
+pass use_pallas=True. tests/test_chip_compile.py keeps a few of these
+compiles (cut to 2 layers) as tier-1 tests.
+"""
+
+from __future__ import annotations
+
+import argparse
+import os
+import sys
+import time
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
+from jax.sharding import SingleDeviceSharding  # noqa: E402
+
+from distributed_llama_tpu.models.spec import (ArchType, HiddenAct,  # noqa: E402
+                                               ModelSpec)
+from distributed_llama_tpu.quants.jax_codec import QuantizedTensor  # noqa: E402
+
+LLAMA2_7B = ModelSpec(arch=ArchType.LLAMA, dim=4096, hidden_dim=11008,
+                      n_layers=32, n_heads=32, n_kv_heads=32,
+                      vocab_size=32000, seq_len=2048,
+                      hidden_act=HiddenAct.SILU)
+MIXTRAL_8X7B = ModelSpec(arch=ArchType.MIXTRAL, dim=4096, hidden_dim=14336,
+                         n_layers=32, n_heads=32, n_kv_heads=8,
+                         vocab_size=32000, seq_len=2048,
+                         hidden_act=HiddenAct.SILU, n_experts=8,
+                         n_active_experts=2, rope_theta=1e6)
+
+
+def describe_topology():
+    from jax.experimental import topologies
+
+    return topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+
+
+def q40_struct(*shape: int) -> QuantizedTensor:
+    """Abstract Q40 weight of logical shape (..., d, n) in device layout."""
+    nb = shape[-1] // 32
+    return QuantizedTensor(
+        jax.ShapeDtypeStruct((*shape[:-1], 16 * nb), jnp.uint8),
+        jax.ShapeDtypeStruct((*shape[:-1], nb), jnp.uint16))
+
+
+def _zeros_q40(*shape: int) -> QuantizedTensor:
+    s = q40_struct(*shape)
+    return QuantizedTensor(jnp.zeros(s.packed.shape, jnp.uint8),
+                           jnp.zeros(s.scales.shape, jnp.uint16))
+
+
+def _loaded_params(spec: ModelSpec, dtype) -> dict:
+    """The pytree models/loader hands the engine (q40 mode), of zeros —
+    only ever built under jax.eval_shape."""
+    d, h, kv = spec.dim, spec.hidden_dim, spec.kv_dim
+    layers = []
+    for _ in range(spec.n_layers):
+        lw = {"rms_att": jnp.ones((d,), jnp.float32),
+              "rms_ffn": jnp.ones((d,), jnp.float32),
+              "wq": _zeros_q40(d, d), "wk": _zeros_q40(kv, d),
+              "wv": _zeros_q40(kv, d), "wo": _zeros_q40(d, d)}
+        if spec.is_moe:
+            e = spec.n_experts
+            lw.update(moe_router=jnp.zeros((e, d), dtype),
+                      moe_up=_zeros_q40(e, h, d),
+                      moe_gate=_zeros_q40(e, h, d),
+                      moe_down=_zeros_q40(e, d, h))
+        else:
+            lw.update(w1=_zeros_q40(h, d), w2=_zeros_q40(d, h),
+                      w3=_zeros_q40(h, d))
+        layers.append(lw)
+    return {"tok_emb": jnp.zeros((spec.vocab_size, d), dtype),
+            "layers": layers, "rms_final": jnp.ones((d,), jnp.float32),
+            "wcls": _zeros_q40(spec.vocab_size, d)}
+
+
+def abstract_step(spec: ModelSpec, devices, *, tp: int = 1, batch: int,
+                  t: int, seq_len: int, q80: bool = False,
+                  dtype=jnp.bfloat16):
+    """(jitted step, abstract args) for the slot program of shape (B, T):
+    T == 1 is `slot_decode_step`, T > 1 `slot_prefill_chunk_T`. `devices`
+    are described devices; tp > 1 lays them out as the engine's mesh."""
+    from distributed_llama_tpu.models.params import fuse_layer_weights
+    from distributed_llama_tpu.models.transformer import KVCache, forward
+    from distributed_llama_tpu.ops.sharded_vocab import vocab_shard_axes
+    from distributed_llama_tpu.parallel.mesh import make_mesh
+    from distributed_llama_tpu.parallel.sharding import (
+        cache_pspec, check_tp_constraints, param_pspecs, repack_col_weights,
+        wrap_row_weights)
+
+    mesh = vocab_axes = None
+    if tp > 1:
+        mesh = make_mesh(tp=tp, devices=devices[:tp])
+        vocab_axes = vocab_shard_axes(mesh, spec.vocab_size)
+        check_tp_constraints(spec, tp, q40=True)
+
+    def build():
+        params = _loaded_params(spec, dtype)
+        if tp == 1:
+            return fuse_layer_weights(params)
+        return wrap_row_weights(repack_col_weights(params, tp))
+
+    params = jax.eval_shape(build)
+    if tp == 1:
+        one = SingleDeviceSharding(devices[0])
+        place = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
+            tree)
+        params, cache_sh, rep = place(params), one, one
+    else:
+        specs = param_pspecs(params, vocab_axes or None)
+        params = jax.tree_util.tree_map(
+            lambda s, ps: jax.ShapeDtypeStruct(
+                s.shape, s.dtype, sharding=NamedSharding(mesh, ps)),
+            params, specs)
+        cache_sh = NamedSharding(mesh, cache_pspec())
+        rep = NamedSharding(mesh, P())
+    cache = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=cache_sh),
+        jax.eval_shape(lambda: KVCache.create(spec, batch, seq_len, dtype)))
+    tokens = jax.ShapeDtypeStruct((batch, t), jnp.int32, sharding=rep)
+    pos = jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=rep)
+    common = dict(  # Engine._forward_kwargs with the kernels on
+        activation_q80=q80, compute_dtype=dtype, use_pallas=True,
+        tp_mesh=mesh, tp_reduce="q80" if q80 else "exact",
+        vocab_mesh=mesh if vocab_axes else None,
+        vocab_axes=vocab_axes or ("tp",))
+
+    if t == 1:
+        def slot_decode_step(params, tokens, pos0, cache):
+            return forward(params, spec, tokens, pos0, cache, **common)
+
+        return (jax.jit(slot_decode_step, donate_argnums=(3,)),
+                (params, tokens, pos, cache))
+
+    def slot_prefill_chunk(params, tokens, pos0, logit_index, cache):
+        return forward(params, spec, tokens, pos0, cache,
+                       logit_index=logit_index, **common)
+
+    return (jax.jit(slot_prefill_chunk, donate_argnums=(4,)),
+            (params, tokens, pos, pos, cache))
+
+
+def compile_report(fn, args) -> dict:
+    """Compile for the described devices; what the compiler put in."""
+    import re
+
+    from distributed_llama_tpu.runtime.profiler import kernel_call_sites
+
+    t0 = time.time()
+    lowered = fn.lower(*args)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
+    return {
+        "compile_s": round(time.time() - t0, 1),
+        "tpu_custom_calls": text.count('custom_call_target="tpu_custom_call"'),
+        "kernels": kernel_call_sites(lowered.as_text()),
+        "collectives": {op: len(re.findall(rf"= \S+ {op}(?:-start)?\(", text))
+                        for op in ("all-reduce", "all-gather", "all-to-all",
+                                   "collective-permute")},
+        "argument_gib": round(mem.argument_size_in_bytes / 2**30, 2),
+        "temp_gib": round(mem.temp_size_in_bytes / 2**30, 2),
+        "alias_gib": round(mem.alias_size_in_bytes / 2**30, 2)}
+
+
+def main() -> None:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--layers", type=int, default=32)
+    ap.add_argument("--batch", type=int, default=8)
+    ap.add_argument("--chunk", type=int, default=32)
+    ap.add_argument("--seq-len", type=int, default=1024)
+    args = ap.parse_args()
+    import dataclasses
+
+    # never read back without a chip: keep these compiles out of the cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    devices = describe_topology().devices
+    spec = dataclasses.replace(LLAMA2_7B, n_layers=args.layers)
+    for tp, q80 in ((1, False), (4, True), (4, False)):
+        for t in (1, args.chunk):
+            fn, a = abstract_step(spec, devices, tp=tp, batch=args.batch,
+                                  t=t, seq_len=args.seq_len, q80=q80)
+            rep = compile_report(fn, a)
+            print(f"llama2-7b L={args.layers} tp={tp} "
+                  f"reduce={'q80' if q80 else 'exact'} B={args.batch} T={t}: "
+                  f"{rep}", flush=True)
+
+
+if __name__ == "__main__":
+    main()
