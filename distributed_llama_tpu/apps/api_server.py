@@ -152,8 +152,11 @@ class ApiState:
         # LEGACY tier's aggregate accept record (the scheduler tiers
         # carry theirs on ServeStats.spec) — attached to /stats and
         # /metrics in every tier, launch flags notwithstanding.
-        from ..runtime.stats import SpecStats
+        from ..runtime.stats import FrontDoorStats, SpecStats
 
+        # window counters of the scheduler-path completion generator
+        # (`frontdoor` /stats block): requests, ms to submit, ms tokenizing
+        self.frontdoor = FrontDoorStats()
         self.draft = draft
         self.draft_len = int(draft_len or 0)
         self._draft_model = None
@@ -592,56 +595,69 @@ def _sched_completion_chunks(state: ApiState, body: dict, chat: bool = True):
     on this path: slots are leased per request (the legacy single-engine
     path keeps the feature). Text-level stops cancel the request, freeing
     its slot immediately."""
+    from ..runtime.trace import TRACER
     from ..sampler import Sampler
 
-    tokenizer = state.tokenizer
-    sched = state.scheduler()
-    engine = sched.engine
-    if chat and not _raw_prompt_body(body):
-        prompt = build_chat_prompt(body.get("messages", []))
-        markers: tuple = CHAT_EOS_MARKERS
-    else:
-        prompt = body.get("prompt") or ""
-        markers = ()
-    max_tokens = int(body.get("max_tokens", 0) or 0)
-    stops = body.get("stop") or []
-    if isinstance(stops, str):
-        stops = [stops]
+    # `api.pre_submit`: the parsed request to submit() returned — chat
+    # template, tokenizer, shed ladder, enqueue. Always counted in the
+    # `frontdoor` /stats block; a span only under --trace or a capture.
+    t_pre = time.perf_counter()
+    sp = TRACER.span("api.pre_submit") if TRACER.spans else None
+    try:
+        tokenizer = state.tokenizer
+        sched = state.scheduler()
+        engine = sched.engine
+        if chat and not _raw_prompt_body(body):
+            prompt = build_chat_prompt(body.get("messages", []))
+            markers: tuple = CHAT_EOS_MARKERS
+        else:
+            prompt = body.get("prompt") or ""
+            markers = ()
+        max_tokens = int(body.get("max_tokens", 0) or 0)
+        stops = body.get("stop") or []
+        if isinstance(stops, str):
+            stops = [stops]
 
-    tokens = tokenizer.encode(prompt)
-    temp = (state.sampler.temperature if body.get("temperature") is None
-            else float(body["temperature"]))
-    with state.engine_lock:  # the shared stream is also the legacy path's
-        seed = (int(body["seed"]) if body.get("seed") is not None
-                else state.sampler.next_seed())
-    sampler = Sampler(tokenizer.vocab_size, temperature=temp,
-                      topp=state.sampler.topp, seed=seed)
-    limit = engine.seq_len - len(tokens) - 1
-    n_gen = min(max_tokens, limit) if max_tokens > 0 else limit
-    # the fleet brain's overload door (runtime/fleet.py): walk the shed
-    # ladder BEFORE submit — speculation off and max_tokens clamps are
-    # invisible degradation, prefix-only and shed raise ShedReject which
-    # the handler maps to a structured 429 (Retry-After from the live
-    # drain rate). Runs before any slot work, so a shed costs nothing.
-    tenant = body.get("tenant")
-    priority = str(body.get("priority") or "normal")
-    fleet = state.fleet()
-    if fleet is not None:
-        n_gen = fleet.admit(tenant=tenant, n_prompt=len(tokens),
-                            max_tokens=n_gen,
-                            prefix_hit=_prefix_would_hit(sched, tokens))
-    # PromptTooLong raises HERE (before any event) — the handler still
-    # turns it into a clean 400 through the queued/threaded path
-    kwargs = {}
-    if state.router_mode:
-        # multi-replica tier: the OpenAI `user` field (or an explicit
-        # `session`) keys replica stickiness, so a conversation keeps
-        # hitting the replica whose radix tree caches its history
-        session = body.get("session") or body.get("user")
-        if session is not None:
-            kwargs["session"] = str(session)
-    req = sched.submit(tokens, n_gen, sampler, eos_id=tokenizer.eos_id,
-                       tenant=tenant, priority=priority, **kwargs)
+        tokens = tokenizer.encode(prompt)
+        temp = (state.sampler.temperature if body.get("temperature") is None
+                else float(body["temperature"]))
+        with state.engine_lock:  # the shared stream is also the legacy path's
+            seed = (int(body["seed"]) if body.get("seed") is not None
+                    else state.sampler.next_seed())
+        sampler = Sampler(tokenizer.vocab_size, temperature=temp,
+                          topp=state.sampler.topp, seed=seed)
+        limit = engine.seq_len - len(tokens) - 1
+        n_gen = min(max_tokens, limit) if max_tokens > 0 else limit
+        # the fleet brain's overload door (runtime/fleet.py): walk the shed
+        # ladder BEFORE submit — speculation off and max_tokens clamps are
+        # invisible degradation, prefix-only and shed raise ShedReject which
+        # the handler maps to a structured 429 (Retry-After from the live
+        # drain rate). Runs before any slot work, so a shed costs nothing.
+        tenant = body.get("tenant")
+        priority = str(body.get("priority") or "normal")
+        fleet = state.fleet()
+        if fleet is not None:
+            n_gen = fleet.admit(tenant=tenant, n_prompt=len(tokens),
+                                max_tokens=n_gen,
+                                prefix_hit=_prefix_would_hit(sched, tokens))
+        # PromptTooLong raises HERE (before any event) — the handler still
+        # turns it into a clean 400 through the queued/threaded path
+        kwargs = {}
+        if state.router_mode:
+            # multi-replica tier: the OpenAI `user` field (or an explicit
+            # `session`) keys replica stickiness, so a conversation keeps
+            # hitting the replica whose radix tree caches its history
+            session = body.get("session") or body.get("user")
+            if session is not None:
+                kwargs["session"] = str(session)
+        req = sched.submit(tokens, n_gen, sampler, eos_id=tokenizer.eos_id,
+                           tenant=tenant, priority=priority, **kwargs)
+    finally:
+        # a bad temperature, a tokenizer error, a shed: the span closes
+        # on every way out, or its annotation stays entered on this thread
+        if sp is not None:
+            TRACER.end(sp)
+    state.frontdoor.note((time.perf_counter() - t_pre) * 1e3)
 
     scan = _piece_scanner(tokenizer, tokens[-1], markers, stops)
     emitted = 0
@@ -1053,6 +1069,7 @@ def make_handler(state: ApiState):
                 payload["fleet"] = (state._fleet.summary()
                                     if state._fleet is not None
                                     else FleetStats().summary())
+                payload["frontdoor"] = state.frontdoor.summary()
                 from ..runtime.trace import TRACER
                 if TRACER.enabled:
                     payload["trace"] = TRACER.summary()
@@ -1943,26 +1960,16 @@ def serve(args) -> None:
             decode_every=getattr(args, "trace_decode_every", None) or 8,
             sink_dir=getattr(args, "trace_dir", None))
     # device-tier observability (runtime/profiler.py): the recompile
-    # sentinel's freeze and the sampled attribution both hang off the
-    # slot scheduler (warmup arms the sentinel; the sampler hooks
-    # scheduler steps) — without --serve-batch they are dead flags
+    # sentinel's freeze hangs off the slot scheduler (warmup arms the
+    # sentinel) — without --serve-batch it is a dead flag
     freeze_compiles = bool(getattr(args, "freeze_compiles", False))
-    profile_sample = getattr(args, "profile_sample", None)
-    if (freeze_compiles or profile_sample is not None) and not serve_batch:
-        sys.exit("error: --freeze-compiles/--profile-sample require "
-                 "--serve-batch N (the sentinel arms at scheduler "
-                 "warmup; the sampler hooks scheduler steps)")
-    if profile_sample is not None and profile_sample < 1:
-        sys.exit("error: --profile-sample must be >= 1 (capture every "
-                 "Nth step; omit the flag to disable)")
-    if freeze_compiles or profile_sample:
-        from ..runtime.profiler import COMPILES, PROFILER
+    if freeze_compiles and not serve_batch:
+        sys.exit("error: --freeze-compiles requires --serve-batch N (the "
+                 "sentinel arms at scheduler warmup)")
+    if freeze_compiles:
+        from ..runtime.profiler import COMPILES
 
-        COMPILES.freeze = freeze_compiles
-        # on the process tier the WORKERS sample (config_from_cli_args
-        # ships both knobs); setting the parent too is harmless — it
-        # steps no scheduler
-        PROFILER.sample_every = int(profile_sample or 0)
+        COMPILES.freeze = True
     replica_hosts = None
     if replica_hosts_raw:
         replica_hosts = []

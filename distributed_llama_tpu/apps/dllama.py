@@ -418,7 +418,10 @@ def build_argparser() -> argparse.ArgumentParser:
     p.add_argument("--trace", action="store_true",
                    help="api mode: enable the flight recorder — per-"
                         "request lifecycle spans and the per-iteration "
-                        "step timeline, in a fixed-capacity ring served "
+                        "step timeline (each step record with its number, "
+                        "start and ms per scheduler span: sched.admit, "
+                        "sched.dispatch.*, sched.wait, sched.sample_emit, "
+                        "sched.publish), in a fixed-capacity ring served "
                         "by /admin/trace and the dllama_step_ms /metrics "
                         "family. Host-side; disabled it is a no-op "
                         "(docs/observability.md quantifies the well-"
@@ -453,15 +456,11 @@ def build_argparser() -> argparse.ArgumentParser:
                         "endpoint's whole-batch executables (warm those "
                         "shapes first or leave the freeze off; "
                         "docs/operations.md 'Recompile storms')")
-    p.add_argument("--profile-sample", type=int, default=None, metavar="N",
-                   help="api mode (needs --serve-batch): capture every "
-                        "Nth scheduler step under a short jax.profiler "
-                        "trace and attribute device ms per entry point "
-                        "(/stats device_time block, dllama_device_ms "
-                        "/metrics). Off by default — disabled it costs "
-                        "nothing, like --trace")
     p.add_argument("--profile-dir", default=None, metavar="DIR",
-                   help="where POST /admin/profile captures land "
+                   help="where POST /admin/profile captures land (a "
+                        "jax.profiler trace with the Python tracer off and "
+                        "the scheduler's spans as TraceAnnotations, with "
+                        "or without --trace) "
                         "(default: a fresh temp dir per capture; replica "
                         "workers write worker-rK/ subdirs)")
     # multi-host cluster flags (the reference's root + worker nodes,

@@ -373,79 +373,6 @@ def per_step_op_ms(trace_dir: str, markers: tuple = COLLECTIVE_MARKERS,
     return []
 
 
-def per_trace_attribution(trace_dir: str) -> tuple[dict, float]:
-    """ONE ProfileData walk returning both halves the sampled-step
-    ingest needs: ({module name: total device ms}, total collective
-    device ms). The separate :func:`per_module_ms` /
-    :func:`per_step_op_ms` entry points each re-parse the whole xplane
-    protobuf (tens of ms to seconds on a big trace) — the per-sample
-    ingest thread must not pay that twice for one capture. Returns
-    ({}, 0.0) when the trace has no device plane (CPU runs)."""
-    import glob
-
-    from jax.profiler import ProfileData
-
-    files = sorted(glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True))
-    if not files:
-        return {}, 0.0
-    pd = ProfileData.from_file(files[-1])
-    mods: dict[str, float] = {}
-    sync_ms = 0.0
-    for plane in pd.planes:
-        if not plane.name.startswith("/device:"):
-            continue
-        for ln in plane.lines:
-            if ln.name == "XLA Modules":
-                for e in ln.events:
-                    name = e.name.split("(")[0]
-                    if name.startswith("jit_"):
-                        name = name[4:]
-                    mods[name] = mods.get(name, 0.0) + e.duration_ns / 1e6
-            elif ln.name in ("XLA Ops", "Async XLA Ops"):
-                for e in ln.events:
-                    if any(m in e.name for m in COLLECTIVE_MARKERS):
-                        sync_ms += e.duration_ns / 1e6
-    return ({k: round(v, 4) for k, v in mods.items()},
-            round(sync_ms, 4))
-
-
-def per_module_ms(trace_dir: str) -> dict:
-    """Parse a jax.profiler trace into PER-ENTRY-POINT summed device time:
-    {module name: total ms across its executions in the trace}. The same
-    ProfileData walk as :func:`per_step_op_ms`, but keyed by module NAME
-    instead of bucketing op events into execution spans — this is the
-    attribution the sampled step profiler (runtime/profiler.py) records:
-    the engine names every jitted wrapper by role (``slot_decode_step``,
-    ``slot_prefill_chunk_16``, ``prefill_seg`` ... — Engine._compiled_step),
-    so the XLA Modules line's event names map straight onto serving
-    entry points. Returns {} when the trace has no device plane (CPU
-    backends emit host planes only) — the caller treats attribution as
-    best-effort."""
-    import glob
-
-    from jax.profiler import ProfileData
-
-    files = sorted(glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True))
-    if not files:
-        return {}
-    pd = ProfileData.from_file(files[-1])
-    out: dict[str, float] = {}
-    for plane in pd.planes:
-        if not plane.name.startswith("/device:"):
-            continue
-        for ln in plane.lines:
-            if ln.name != "XLA Modules":
-                continue
-            for e in ln.events:
-                # module names arrive as e.g. "jit_slot_decode_step(...)"
-                # or with an id suffix — strip to the stable stem
-                name = e.name.split("(")[0]
-                if name.startswith("jit_"):
-                    name = name[4:]
-                out[name] = out.get(name, 0.0) + e.duration_ns / 1e6
-    return {k: round(v, 4) for k, v in out.items()}
-
-
 def measure_allreduce_ms(mesh, payload_elems: int, iters: int = 16,
                          axes: tuple = ("tp",)) -> float:
     """Time one f32 all-reduce of `payload_elems` over the given mesh axes

@@ -32,16 +32,12 @@ hand-running ``jax.profiler`` offline. This module is the device half:
   * **On-demand capture** (:meth:`Profiler.capture`) — the
     ``POST /admin/profile?ms=`` body: one bounded ``jax.profiler`` trace
     written to a directory, refusals instead of concurrent captures
-    (``jax.profiler`` is process-global). ``RMSG_PROFILE`` relays the
-    verb into replica worker processes (per-worker capture dirs).
-  * **Sampled device-time attribution** (:meth:`Profiler.step_begin` /
-    ``step_end``) — every ``--profile-sample``-th scheduler step runs
-    under a short ``jax.profiler`` trace parsed by ``netstats``'
-    ProfileData reader into per-entry-point device ms (the engine's
-    role-specific wrapper names: ``slot_decode_step``,
-    ``slot_prefill_chunk_16``, ...). Disabled (the default) it is
-    allocation-free like the tracer: call sites guard on
-    ``PROFILER.sample_every`` before calling anything.
+    (``jax.profiler`` is process-global). The Python tracer is off, and
+    while the capture runs the tracer's spans (runtime/trace.py
+    ``SPAN_NAMES``) are written as ``TraceAnnotation``s, so the host
+    plane holds what the scheduler did on the device's clock.
+    ``RMSG_PROFILE`` relays the verb into replica worker processes
+    (per-worker capture dirs).
 
 Everything here is host code running strictly pre/post device dispatch —
 no jitted program changes, and the dlgrind fingerprint set is invariant
@@ -324,8 +320,7 @@ def hbm_ledger(engine, prefix_cache=None, *, block_len: int | None = None,
     fetch plus one (B, chunk, dim) activation segment):
 
       * ``weights_bytes``      — every LAYER/norm param leaf (quantized
-        tensors count their packed bytes). Cached on the engine: weights
-        never change size. NOTE: thread-tier replicas SHARE weight
+        tensors count their packed bytes). NOTE: thread-tier replicas SHARE weight
         buffers, so summing this across replica blocks multi-counts one
         allocation — the per-replica truth is kv+arena, the weights are
         per-process.
@@ -355,20 +350,24 @@ def hbm_ledger(engine, prefix_cache=None, *, block_len: int | None = None,
     ``slots_addable``/``prefix_blocks_addable`` = free HBM divided by
     those, when the backend reports a limit."""
     spec = engine.spec
-    weights = getattr(engine, "_hbm_weights_bytes", None)
-    vocab_b = getattr(engine, "_hbm_vocab_bytes", None)
-    if weights is None or vocab_b is None:
+    # shape-derived bytes, walked once per engine object: weights never
+    # change size, and the slot cache keeps its shapes for the engine's
+    # life (every step donates and replaces the arrays, never resizes
+    # them) — the walk is milliseconds over a sharded tree and would
+    # otherwise run, racing a donation, on every /stats and /metrics read
+    cached = getattr(engine, "_hbm_shape_bytes", None)
+    if cached is None:
         params = engine.params
-        vocab_b = _tree_bytes([params[k] for k in ("tok_emb", "wcls")
-                               if k in params])
-        weights = _tree_bytes({k: v for k, v in params.items()
-                               if k not in ("tok_emb", "wcls")})
+        cached = (_tree_bytes({k: v for k, v in params.items()
+                               if k not in ("tok_emb", "wcls")}),
+                  _tree_bytes([params[k] for k in ("tok_emb", "wcls")
+                               if k in params]),
+                  _tree_bytes(engine.cache))
         try:
-            engine._hbm_weights_bytes = weights
-            engine._hbm_vocab_bytes = vocab_b
+            engine._hbm_shape_bytes = cached
         except AttributeError:  # a read-only engine shim: skip the cache
             pass
-    kv = _tree_bytes(engine.cache)
+    weights, vocab_b, kv = cached
     arena = 0
     n_blocks = 0
     bl = block_len
@@ -650,135 +649,39 @@ def build_info(engine=None) -> dict:
             "mesh": mesh_label(getattr(engine, "mesh", None))}
 
 
-# -- sampled device-time attribution + on-demand capture --------------------
+# -- on-demand capture -------------------------------------------------------
 
-
-class DeviceTimeStats:
-    """Per-entry-point device-ms histograms fed by the sampled step
-    captures: {module name: bounded window of summed device ms within
-    one sampled step}. Module names are the engine's role-specific
-    wrapper names (``jit_slot_decode_step``...) as the XLA trace spells
-    them."""
-
-    def __init__(self, window: int = 512, max_keys: int = 64):
-        from collections import deque  # noqa: F401 — used below
-
-        self.window = int(window)
-        self.max_keys = int(max_keys)
-        self._lock = threading.Lock()
-        self._hist: dict[str, object] = {}  # dlrace: guarded-by(self._lock)
-        self.overflow = 0
-
-    def record(self, name: str, ms: float) -> None:
-        from collections import deque
-
-        with self._lock:
-            d = self._hist.get(name)
-            if d is None:
-                if len(self._hist) >= self.max_keys:
-                    self.overflow += 1
-                    return
-                d = self._hist[name] = deque(maxlen=self.window)
-            d.append(ms)
-
-    def summary(self) -> dict:
-        from .stats import percentile
-
-        with self._lock:
-            items = [(k, list(d)) for k, d in self._hist.items()]
-        out = {}
-        for name, xs in sorted(items, key=lambda kv: -len(kv[1])):
-            out[name] = {"n": len(xs),
-                         "p50_ms": round(percentile(xs, 50), 4),
-                         "mean_ms": round(sum(xs) / len(xs), 4)}
-        return out
-
-
-class SyncStats:
-    """Per-sampled-step device sync/compute split — the reference's
-    per-token I/T/S columns reborn for XLA (fed by
-    ``netstats.per_step_op_ms``: device time of collective ops —
-    all-reduce / all-gather / reduce-scatter / all-to-all /
-    collective-permute — bucketed per executed module, vs the module's
-    total device ms). One (sync_ms, device_ms, wall_ms) record per
-    sampled step; the summary is the ``sync`` half of the
-    ``device_time`` /stats block and the ``dllama_step_sync_ms`` /
-    ``dllama_step_sync_share`` /metrics families."""
-
-    def __init__(self, window: int = 512):
-        from collections import deque
-
-        self.window = int(window)
-        self._lock = threading.Lock()
-        self._sync = deque(maxlen=self.window)  # dlrace: guarded-by(self._lock)
-        self._device = deque(maxlen=self.window)  # dlrace: guarded-by(self._lock)
-        self._wall = deque(maxlen=self.window)  # dlrace: guarded-by(self._lock)
-
-    def record(self, sync_ms: float, device_ms: float,
-               wall_ms: float | None = None) -> None:
-        with self._lock:
-            self._sync.append(float(sync_ms))
-            self._device.append(float(device_ms))
-            if wall_ms is not None:
-                self._wall.append(float(wall_ms))
-
-    def summary(self) -> dict:
-        from .stats import percentile
-
-        with self._lock:
-            sync = list(self._sync)
-            dev = list(self._device)
-            wall = list(self._wall)
-        if not sync:
-            return {"n": 0}
-        rnd = lambda v: None if v is None else round(v, 4)  # noqa: E731
-        total_dev = sum(dev)
-        return {
-            "n": len(sync),
-            "sync_p50_ms": rnd(percentile(sync, 50)),
-            "sync_p99_ms": rnd(percentile(sync, 99)),
-            "device_p50_ms": rnd(percentile(dev, 50)),
-            # window-mean share, sums not means-of-ratios: a near-idle
-            # step's ratio must not swamp the loaded steps' story
-            "sync_share": rnd(sum(sync) / total_dev) if total_dev else None,
-            "wall_p50_ms": rnd(percentile(wall, 50)) if wall else None,
-        }
+# the host tracer level of a capture: 1 is the lowest that records the
+# program's own TraceAnnotations (runtime/trace.py SPAN_NAMES); it also
+# records jax's (`np.asarray(jax.Array)`, `PjitFunction(...)`). Level 2,
+# jax's default, added nothing the reduction reads (chip, PR 25).
+HOST_TRACER_LEVEL = 1
 
 
 class Profiler:
-    """On-demand jax.profiler capture + sampled per-step device-time
-    attribution (module singleton: ``PROFILER``).
-
-    Disabled (``sample_every == 0``, the default) the hot path pays ONE
-    attribute read per scheduler iteration — call sites guard with
-    ``if PROFILER.sample_every:`` before calling ``step_begin`` (the
-    tracer's guard-before-kwargs discipline; asserted allocation-free in
-    tests/test_profiler.py). Enabled, every Nth working step runs under
-    a short trace whose per-module device ms feed ``device_time``; the
-    N-1 unsampled steps pay one counter increment.
+    """On-demand jax.profiler capture (module singleton: ``PROFILER``).
 
     ``jax.profiler`` is process-global, so exactly one trace may run at
-    a time: ``capture()`` (the /admin/profile body) and a due step
-    sample contend on one flag — the loser skips, never blocks."""
+    a time: a second ``capture()`` is refused, never queued. While one
+    runs, ``TRACER.capturing`` is set and every span site of the
+    scheduler, the step loops and the front door writes a
+    ``TraceAnnotation``; otherwise those sites cost one attribute read."""
 
     def __init__(self):
-        self.sample_every = 0       # 0 = attribution off
-        self._n = 0                 # working-step counter (sampling phase)
-        self.sampled = 0            # sampled steps that produced a trace
-        self.sample_failures = 0    # start/stop/parse errors (backend-dep)
         self.captures = 0           # /admin/profile captures completed
-        self.device_time = DeviceTimeStats()
-        self.sync = SyncStats()     # sampled sync/compute split (dlwire)
         self._lock = threading.Lock()
         self._busy = False  # dlrace: guarded-by(self._lock)
-
-    # -- the /admin/profile body ----------------------------------------
 
     def capture(self, directory: str, ms: float) -> dict:
         """Write one jax.profiler trace of the next `ms` milliseconds to
         `directory` (created). Synchronous — the caller's thread sleeps
         out the window (the threaded HTTP server keeps serving), so a
-        200 means the trace is on disk. Returns {"dir", "ms"}; raises
+        200 means the trace is on disk. The Python tracer is off: with it
+        on, the stop froze serving for seconds and the host plane held
+        frames of every thread instead of the program's spans. Returns
+        {"dir", "ms", "t_start_mono", "t_stop_mono", "stop_ms"} — the
+        ``perf_counter`` instants between which the trace ran (the clock
+        of the tracer's ring records) and how long the stop took; raises
         RuntimeError("capture busy") when a trace is already running."""
         import os
 
@@ -791,120 +694,32 @@ class Profiler:
             self._busy = True
         try:
             os.makedirs(directory, exist_ok=True)
-            jax.profiler.start_trace(directory)
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            opts.host_tracer_level = HOST_TRACER_LEVEL
+            jax.profiler.start_trace(directory, profiler_options=opts)
+            t_start = time.perf_counter()
+            TRACER.set_capturing(True)
             try:
                 time.sleep(max(float(ms), 0.0) / 1e3)
             finally:
+                TRACER.set_capturing(False)
+                t_stop = time.perf_counter()
                 jax.profiler.stop_trace()
+            stop_ms = (time.perf_counter() - t_stop) * 1e3
             self.captures += 1
             if TRACER.enabled:
-                TRACER.event("profile", 0, dir=directory, ms=float(ms))
-            return {"dir": directory, "ms": float(ms)}
+                TRACER.event("profile", 0, dir=directory, ms=float(ms),
+                             t_start_mono=t_start, t_stop_mono=t_stop)
+            return {"dir": directory, "ms": float(ms),
+                    "t_start_mono": t_start, "t_stop_mono": t_stop,
+                    "stop_ms": round(stop_ms, 3)}
         finally:
             with self._lock:
                 self._busy = False
-
-    # -- sampled step attribution ----------------------------------------
-
-    def step_begin(self) -> str | None:
-        """Called at the top of a WORKING scheduler step (never idle
-        iterations) when sampling is on. Returns the capture dir when
-        THIS step is the sampled one, else None."""
-        self._n += 1
-        if self._n % self.sample_every:
-            return None
-        with self._lock:
-            if self._busy:
-                return None  # an /admin/profile capture owns the slot
-            self._busy = True
-        import tempfile
-
-        import jax
-
-        try:
-            d = tempfile.mkdtemp(prefix="dlprof-step-")
-            jax.profiler.start_trace(d)
-            return d
-        except Exception:  # noqa: BLE001 — backend without profiling
-            self.sample_failures += 1
-            with self._lock:
-                self._busy = False
-            return None
-
-    def step_end(self, directory: str, wall_ms: float | None = None) -> None:
-        """Stop the step trace, then hand parse + cleanup to a short
-        daemon thread: per_module_ms walks an xplane protobuf (tens of
-        ms to seconds on a big trace), and the scheduler thread calling
-        this must get back to serving — the sampled step's serving-side
-        cost is the capture itself, never the analysis. Parse errors
-        count, never raise — attribution is best-effort observability,
-        the step itself already succeeded. ``wall_ms`` is the sampled
-        step's host wall (rides the sync record so the report can show
-        device sync next to the step wall it lived in)."""
-        import jax
-
-        try:
-            jax.profiler.stop_trace()
-        except Exception:  # noqa: BLE001
-            self.sample_failures += 1
-            with self._lock:
-                self._busy = False
-            return
-        with self._lock:
-            self._busy = False
-        threading.Thread(target=self._ingest, args=(directory, wall_ms),
-                         name="dlprof-ingest", daemon=True).start()
-
-    def _ingest(self, directory: str, wall_ms: float | None = None) -> None:
-        import shutil
-
-        try:
-            from .netstats import per_trace_attribution
-
-            # ONE xplane walk for both halves (per-module device ms AND
-            # summed collective ms) — the separate parsers would each
-            # re-read the whole protobuf per sampled step
-            per_mod, sync_ms = per_trace_attribution(directory)
-            for name, ms in per_mod.items():
-                self.device_time.record(name, ms)
-            # the sync/compute split: collective device ms over total
-            # device ms for the sampled window. The parser returns
-            # empty on traces with no device plane (CPU runs) — the
-            # split is then honestly absent, never 0%.
-            device_ms = sum(per_mod.values())
-            if per_mod:
-                self.sync.record(sync_ms, device_ms, wall_ms)
-                if TRACER.enabled:
-                    TRACER.event(
-                        "sync", 0, sync_ms=round(sync_ms, 4),
-                        device_ms=round(device_ms, 4),
-                        wall_ms=(None if wall_ms is None
-                                 else round(wall_ms, 4)),
-                        share=(round(sync_ms / device_ms, 4)
-                               if device_ms else None))
-            self.sampled += 1
-        except Exception:  # noqa: BLE001 — malformed/absent trace plane
-            self.sample_failures += 1
-        finally:
-            shutil.rmtree(directory, ignore_errors=True)
-
-    def summary(self) -> dict:
-        """The ``device_time`` /stats block (present when sampling on)."""
-        return {"sample_every": self.sample_every,
-                "sampled_steps": self.sampled,
-                "sample_failures": self.sample_failures,
-                "captures": self.captures,
-                "by_entry": self.device_time.summary(),
-                "sync": self.sync.summary()}
 
     def reset(self) -> None:
-        self.sample_every = 0
-        self._n = 0
-        self.sampled = 0
-        self.sample_failures = 0
         self.captures = 0
-        self.device_time = DeviceTimeStats()
-        self.sync = SyncStats()
 
 
 PROFILER = Profiler()

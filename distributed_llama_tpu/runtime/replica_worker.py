@@ -805,11 +805,10 @@ def config_from_cli_args(args, serve_batch: int) -> dict:
             # orders its queue by the same weights/budgets
             "tenant_budgets": getattr(args, "tenant_budgets", None),
         },
-        # device-tier observability: the recompile sentinel freezes and
-        # the attribution sampler sample INSIDE each worker; /admin/
-        # profile captures land under per-worker subdirs of profile_dir
+        # device-tier observability: the recompile sentinel freezes
+        # INSIDE each worker; /admin/profile captures land under
+        # per-worker subdirs of profile_dir
         "freeze_compiles": bool(getattr(args, "freeze_compiles", False)),
-        "profile_sample": int(getattr(args, "profile_sample", 0) or 0),
         "profile_dir": getattr(args, "profile_dir", None),
         # flight recorder: workers trace whenever the parent does, so
         # span events exist on both sides of the process boundary
@@ -862,14 +861,12 @@ def main(argv: list[str] | None = None) -> int:
                          sink_dir=sink)
 
     # device-tier observability (runtime/profiler.py): the worker runs
-    # its own compile ledger / recompile sentinel and sampled device-time
-    # attribution — their blocks ride the stats reply like every other
-    # per-replica block
-    from .profiler import COMPILES, PROFILER
+    # its own compile ledger / recompile sentinel — its block rides the
+    # stats reply like every other per-replica block
+    from .profiler import COMPILES
 
     if cfg.get("freeze_compiles"):
         COMPILES.freeze = True
-    PROFILER.sample_every = int(cfg.get("profile_sample", 0) or 0)
     profile_dir = cfg.get("profile_dir")
     if profile_dir:
         profile_dir = os.path.join(

@@ -45,7 +45,7 @@ import threading
 import time
 
 from .scheduler import Scheduler
-from .stats import SupervisorStats
+from .stats import WINDOW_COUNTERS, SupervisorStats
 from .trace import TRACER
 
 READY = "ready"
@@ -56,7 +56,8 @@ CLOSED = "closed"
 
 _COUNTER_KEYS = ("requests_submitted", "requests_finished",
                  "requests_failed", "requests_expired",
-                 "requests_rejected", "tokens_out", "steps")
+                 "requests_rejected", "tokens_out", "steps",
+                 *WINDOW_COUNTERS)
 
 
 class EngineUnready(RuntimeError):
@@ -333,25 +334,26 @@ class EngineSupervisor:
             state = self._state
         out = sched.stats.summary()
         for k in _COUNTER_KEYS:
-            out[k] = (out.get(k, 0) + carry[k]
-                      + sum(getattr(d, k, 0) for d in dead))
+            # from the raw attributes, rounded once after the sum: a
+            # total of separately rounded floats could fall when a
+            # generation moves from live to dead
+            v = (getattr(sched.stats, k) + carry[k]
+                 + sum(getattr(d, k, 0) for d in dead))
+            out[k] = round(v, 3) if isinstance(v, float) else v
         out["state"] = state
         out["resilience"] = self.sup_stats.summary()
         # device-tier blocks (runtime/profiler.py): live-bytes by
-        # category for the CURRENT generation's engine + arena, the
-        # process compile ledger, and — when --profile-sample is on —
-        # the sampled per-entry-point device-time attribution. Cheap per
-        # scrape: weights bytes are cached on the engine, the rest are
-        # a handful of nbytes reads and dict copies.
-        from .profiler import COMPILES, PROFILER, hbm_ledger
+        # category for the CURRENT generation's engine + arena and the
+        # process compile ledger. Cheap per scrape: the shape-derived
+        # bytes are cached on the engine, the rest are a handful of
+        # nbytes reads and dict copies.
+        from .profiler import COMPILES, hbm_ledger
 
         try:
             out["hbm"] = hbm_ledger(sched.engine, sched.prefix_cache)
         except Exception:  # noqa: BLE001 — a mid-rebuild engine swap
             pass           # must never fail a stats scrape
         out["compiles"] = COMPILES.summary()
-        if PROFILER.sample_every:
-            out["device_time"] = PROFILER.summary()
         return out
 
     def _retry_after(self) -> float:
@@ -419,7 +421,7 @@ class EngineSupervisor:
                         # a real step succeeded post-recovery: streak over
                         self.sup_stats.consecutive_failures = 0
             if not did and not self._stop and gen == self._gen:
-                sched._wake.wait(timeout=0.05)
+                sched.idle_wait()
 
     def _watchdog(self) -> None:
         """Detect the stall no exception will ever report: a step body

@@ -65,7 +65,6 @@ from typing import Iterator
 import numpy as np
 
 from .faults import FAULTS
-from .profiler import PROFILER
 from .stats import RequestStats, ServeStats
 from .trace import TRACER
 
@@ -465,6 +464,12 @@ class Scheduler:
         # (a float store is atomic under the GIL) — a mutex-holding
         # borrow (exclusive()) therefore never looks like a stall.
         self._step_t0: float | None = None  # dlrace: guarded-by(self._mutex)
+        # the iteration's open `sched.step` span (runtime/trace.py), None
+        # unless --trace is on or a device capture is running; and the
+        # seconds this iteration has spent blocked in a device fetch.
+        # Both belong to the stepping thread alone.
+        self._span = None
+        self._step_wait = 0.0
         self._rid = 0  # dlrace: guarded-by(self._rid_lock)
         self._rid_lock = threading.Lock()
 
@@ -551,31 +556,25 @@ class Scheduler:
                                             for s in self.slots)
 
     def _step_locked(self) -> bool:
-        # sampled device-time attribution (runtime/profiler.py): every
-        # --profile-sample-th WORKING step runs under a short
-        # jax.profiler trace. Bracketed OUTSIDE the _step_t0 window:
-        # start_trace/stop_trace overhead (seconds on a cold profiler)
-        # must never read as step time, or the watchdog declares the
-        # sampled step a stall and the supervisor kills a healthy
-        # generation (observed live: first sample -> watchdog trip ->
-        # spurious recovery). Guard-before-call like the tracer:
-        # sampling off is one attribute read, no allocation; idle
-        # iterations never consume a sample.
-        prof = None
-        if PROFILER.sample_every and (
-                self._queue or any(s.req is not None for s in self.slots)):
-            prof = PROFILER.step_begin()
         self._step_t0 = time.perf_counter()  # watchdog heartbeat: in-step
+        self._step_wait = 0.0
         try:
             return self._step_body()
         finally:
-            # wall BEFORE clearing the heartbeat: the sampled step's host
-            # wall rides the sync/compute record (dlwire) so dlprof can
-            # show device collective ms against the step it lived in
-            wall_ms = (time.perf_counter() - self._step_t0) * 1e3
             self._step_t0 = None
-            if prof is not None:
-                PROFILER.step_end(prof, wall_ms)
+            if self._span is not None:  # a step that raised mid-phase
+                TRACER.end(self._span)
+                self._span = None
+
+    def idle_wait(self, timeout: float = 0.05) -> None:
+        """Block until a submit wakes the loop or `timeout` passes: the
+        wait of BOTH step loops (``_run`` here, the supervisor's
+        ``_loop``) when an iteration found no work — the `sched.idle_wait`
+        span, so an idle device reads as idle and not as host work."""
+        sp = TRACER.span("sched.idle_wait") if TRACER.spans else None
+        self._wake.wait(timeout=timeout)
+        if sp is not None:
+            TRACER.end(sp)
 
     def _step_body(self) -> bool:
         if not self._queue and all(s.req is None for s in self.slots):
@@ -585,6 +584,13 @@ class Scheduler:
             # the same process must never consume a globally-armed fault
             # out from under the one being tested)
             return False
+        # spans (runtime/trace.py): `sched.step` over the working
+        # iteration, its phases as children one after another. One
+        # attribute read unless --trace is on or a capture is running.
+        sp = None
+        if TRACER.spans:
+            sp = self._span = TRACER.span("sched.step")
+            TRACER.phase(sp, "sched.admit")
         # named fault sites (runtime/faults.py): no-ops unless armed; fired
         # BEFORE any device dispatch so injection never alters a jitted
         # program (the dlgrind fingerprints are injection-invariant)
@@ -652,24 +658,31 @@ class Scheduler:
                 self._decode_spec(dec)
             else:
                 self._decode(dec)
+        if sp is not None:
+            TRACER.end(sp)
+            self._span = None
+        # wall from the watchdog heartbeat t0: one clock, one read, for
+        # the window counters, the step timeline and the policy alike
+        wall_ms = (time.perf_counter() - self._step_t0) * 1e3
+        wait_ms = self._step_wait * 1e3
+        st = self.stats
+        st.busy_ms += wall_ms
+        st.wait_ms += wait_ms
+        st.host_ms += wall_ms - wait_ms
         if TRACER.enabled:
             # step timeline: batch composition + wall ms, the raw
             # measurement behind /metrics' dllama_step_ms and the bench
-            # step_timeline blocks (ROADMAP item 1's knee search). Wall
-            # from the watchdog heartbeat t0 — one clock, no extra read
-            # at step entry.
+            # step_timeline blocks (ROADMAP item 1's knee search), with
+            # the iteration's number, start and ms per phase
             TRACER.step(decode_rows=len(dec), prefill_rows=len(pre),
-                        chunk=cw,
-                        queue_depth=len(self._queue),
-                        wall_ms=(time.perf_counter()
-                                 - self._step_t0) * 1e3,
-                        key=self.fault_key)
+                        chunk=cw, queue_depth=len(self._queue),
+                        wall_ms=wall_ms, key=self.fault_key, n=st.steps,
+                        ts0=self._step_t0,
+                        phases=sp.phases if sp is not None else None)
         if self.admission is not None:
             # the same wall the timeline records is the policy's signal;
             # it adapts the NEXT iteration's width (never this one's)
-            self.admission.observe_step(
-                (time.perf_counter() - self._step_t0) * 1e3,
-                len(dec), len(pre))
+            self.admission.observe_step(wall_ms, len(dec), len(pre))
         return True
 
     def _expire_req(self, req: ServeRequest, code: str = "deadline",
@@ -712,10 +725,12 @@ class Scheduler:
             # the main cache's slot reuse
             s.draft_pos = 0
             s.toks = list(req.prompt)
+            queue_ms = (now - req.stats.t_submit) * 1e3
+            self.stats.admitted += 1
+            self.stats.queue_wait_ms_sum += queue_ms
             if TRACER.enabled:
                 TRACER.event("admit", req.trace_id, slot=s.idx,
-                             queue_ms=round(
-                                 (now - req.stats.t_submit) * 1e3, 3),
+                             queue_ms=round(queue_ms, 3),
                              key=self.fault_key)
             # slot "reset" is host-side bookkeeping ONLY — no cache zeroing
             # or reallocation. The new request's prefill/decode overwrites
@@ -750,28 +765,58 @@ class Scheduler:
         temperature as a traced input (greedy rows pass 1.0)."""
         eng = self.engine
         sv = getattr(eng, "sample_view", None)
+        if sv is not None:
+            temps = np.ones((eng.batch,), np.float32)
+            for s in rows:
+                t = getattr(s.req.sampler, "temperature", 0.0)
+                if t:
+                    temps[s.idx] = t
+        t0 = self._wait_begin()
         if sv is None:
             from .sampling import FullLogitsView
 
-            return FullLogitsView(eng.fetch_logits(logits))
-        temps = np.ones((eng.batch,), np.float32)
-        for s in rows:
-            t = getattr(s.req.sampler, "temperature", 0.0)
-            if t:
-                temps[s.idx] = t
-        return sv(logits, temps, self.sample_vocab)
+            view = FullLogitsView(eng.fetch_logits(logits))
+        else:
+            view = sv(logits, temps, self.sample_vocab)
+        self._wait_end(t0)
+        return view
+
+    def _wait_begin(self) -> float:
+        """Before a blocking device fetch (the logits, the candidate
+        summary, a verify step's argmax): opens the `sched.wait` span.
+        The calls that follow are the same with spans on and off. jax
+        wraps the fetch in an annotation of its own
+        (`np.asarray(jax.Array)`), nested in this span: in a capture the
+        wait for the program and the copy to the host both read under
+        that label."""
+        if self._span is not None:
+            TRACER.phase(self._span, "sched.wait")
+        return time.perf_counter()
+
+    def _wait_end(self, t0: float) -> None:
+        """After it: the fetch's wall is the iteration's share of
+        `wait_ms`; what follows is `sched.sample_emit` until the next
+        phase opens."""
+        self._step_wait += time.perf_counter() - t0
+        if self._span is not None:
+            TRACER.phase(self._span, "sched.sample_emit")
 
     def _prefill_chunk(self, rows: list[_Slot],
                        width: int | None = None) -> None:
         eng = self.engine
+        sp = self._span
+        if sp is not None:
+            TRACER.phase(sp, "sched.dispatch.prefill")
         b, c = eng.batch, int(width or self.chunk)
         tok = np.zeros((b, c), np.int32)
         pos = np.full((b,), eng.seq_len, np.int32)  # gated rows: writes drop
         lidx = np.zeros((b,), np.int32)
         finishing = []
+        self.stats.prefill_steps += 1
         for s in rows:
             n = min(c, len(s.req.prompt) - s.off)
             tok[s.idx, :n] = s.req.prompt[s.off:s.off + n]
+            self.stats.prefill_tokens += n
             if self.prefix_cache is not None:
                 # real (non-pad) tokens this forward actually prefills —
                 # the honest denominator for prefill_saved_frac
@@ -782,7 +827,7 @@ class Scheduler:
             lidx[s.idx] = n - 1
             if TRACER.enabled:
                 TRACER.event("prefill", s.req.trace_id, off=s.off, n=n,
-                             slot=s.idx)
+                             slot=s.idx, step=self.stats.steps)
             s.off += n
             if s.off == len(s.req.prompt):
                 finishing.append(s)
@@ -799,7 +844,11 @@ class Scheduler:
                 # (blocks are immutable once published; a re-publish of
                 # already-indexed blocks walks the tree and copies
                 # nothing)
+                if sp is not None:
+                    TRACER.phase(sp, "sched.publish")
                 self.prefix_cache.publish(s.idx, s.req.prompt)
+                if sp is not None:
+                    TRACER.phase(sp, "sched.sample_emit")
             if s.req.max_tokens <= 0:
                 # hard-cap contract, same as Engine.generate: the prefill
                 # ran, nothing is emitted
@@ -812,6 +861,10 @@ class Scheduler:
         # landing mid-step costs at most this one extra forward
         live = rows
         eng = self.engine
+        if self._span is not None:
+            TRACER.phase(self._span, "sched.dispatch.decode")
+        self.stats.decode_steps += 1
+        self.stats.decode_rows += len(live)
         tok = np.zeros((eng.batch, 1), np.int32)
         pos = np.full((eng.batch,), eng.seq_len, np.int32)
         for s in live:
@@ -866,6 +919,8 @@ class Scheduler:
                 rows.append((s, avail))
         if not rows:
             return
+        if self._span is not None:
+            TRACER.phase(self._span, "sched.dispatch.decode")
         tok = np.zeros((eng.batch, c), np.int32)
         pos = np.full((eng.batch,), eng.seq_len, np.int32)
         for s, avail in rows:
@@ -891,6 +946,10 @@ class Scheduler:
         from .speculative import count_accepted
 
         eng, k = self.engine, self.draft_len
+        if self._span is not None:
+            TRACER.phase(self._span, "sched.dispatch.decode")
+        self.stats.decode_steps += 1
+        self.stats.decode_rows += len(rows)
         spec_rows = [s for s in rows if self._spec_capable(s)]
         dtok = np.zeros((eng.batch,), np.int32)
         dpos = np.full((eng.batch,), eng.seq_len, np.int32)  # gated rows
@@ -917,7 +976,11 @@ class Scheduler:
             drafts[s.idx] = d
             tok[s.idx, 1:1 + len(d)] = d
             s.draft_pos = s.pos + k  # the scan wrote pos..pos+k-1
+        # the verify step returns the argmax on the host: dispatch and
+        # fetch in one call, so all of it counts as the wait
+        t0 = self._wait_begin()
         greedy, logits0 = eng.slot_verify_step(tok, pos, self.draft_vocab)
+        self._wait_end(t0)
         self._spec_stats.verify_forwards += 1
         nonspec = [s for s in rows if s.idx not in drafts]
         # position-0 sampling rides the sharded view like any decode
@@ -979,11 +1042,13 @@ class Scheduler:
             if TRACER.enabled:
                 TRACER.event("first_token", req.trace_id,
                              ttft_ms=round((now - req.stats.t_submit)
-                                           * 1e3, 3))
+                                           * 1e3, 3),
+                             step=self.stats.steps)
         elif TRACER.enabled and s.n_out % TRACER.decode_every == 0:
             # decode progress at a bounded cadence: a per-token event
             # would let one long stream flush the whole ring
-            TRACER.event("decode", req.trace_id, n_out=s.n_out)
+            TRACER.event("decode", req.trace_id, n_out=s.n_out,
+                         step=self.stats.steps)
         req.stats.n_out = s.n_out
         self.stats.tokens_out += 1
         req.events.put(("token", token))
@@ -1134,7 +1199,7 @@ class Scheduler:
                     self._abort_all(f"{type(e).__name__}: {e}")
                     did = False
             if not did and not self._stop:
-                self._wake.wait(timeout=0.05)
+                self.idle_wait()
 
     def _fail_req(self, req: ServeRequest, frame: dict) -> bool:
         """Terminal structured-error delivery for one request

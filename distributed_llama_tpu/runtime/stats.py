@@ -10,6 +10,7 @@ the blocking device time), and host overhead ms (sampling + bookkeeping).
 from __future__ import annotations
 
 import dataclasses
+import threading
 
 
 @dataclasses.dataclass
@@ -183,7 +184,6 @@ class StepTimelineStats:
     one fixed width — but a bound beats trusting that)."""
 
     def __init__(self, window: int = 4096, max_keys: int = 256):
-        import threading
         from collections import deque
 
         self.window = int(window)
@@ -228,6 +228,36 @@ class StepTimelineStats:
                 for k, v in self.summary().items()}
 
 
+# ServeStats' window counters, by /stats key (docs/observability.md and
+# PERF.md section 3 say which per-layer metric reads which)
+WINDOW_COUNTERS = ("admitted", "queue_wait_ms_sum", "prefill_steps",
+                   "prefill_tokens", "decode_steps", "decode_rows",
+                   "busy_ms", "wait_ms", "host_ms")
+
+
+class FrontDoorStats:
+    """The HTTP front door's window counters (apps/api_server.py, the
+    scheduler-path completion generator): requests that reached
+    ``submit``, and the host ms from the parsed request to ``submit``
+    returned — chat template, tokenizer, shed ladder, enqueue. The
+    `frontdoor` /stats block."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.requests = 0  # dlrace: guarded-by(self._lock)
+        self.pre_submit_ms_sum = 0.0  # dlrace: guarded-by(self._lock)
+
+    def note(self, pre_submit_ms: float) -> None:
+        with self._lock:  # handler threads: += is not atomic
+            self.requests += 1
+            self.pre_submit_ms_sum += pre_submit_ms
+
+    def summary(self) -> dict:
+        with self._lock:
+            return {"requests": self.requests,
+                    "pre_submit_ms_sum": round(self.pre_submit_ms_sum, 3)}
+
+
 @dataclasses.dataclass
 class ServeStats:
     """Scheduler-level serving counters: running totals plus BOUNDED
@@ -251,6 +281,19 @@ class ServeStats:
     requests_failed: int = 0
     requests_expired: int = 0
     requests_rejected: int = 0
+    # window counters: monotonic, always on, each incremented where the
+    # work happens (runtime/scheduler.py), so two /stats snapshots can be
+    # differenced over any window (WINDOW_COUNTERS below names them)
+    admitted: int = 0              # requests leased a slot (_admit)
+    queue_wait_ms_sum: float = 0.0  # submit -> admit, over those
+    prefill_steps: int = 0         # prefill-chunk programs dispatched
+    prefill_tokens: int = 0        # real prompt tokens in them (no pad
+    #                                rows; with or without --prefix-cache)
+    decode_steps: int = 0          # decode / verify programs dispatched
+    decode_rows: int = 0           # rows that decoded in them
+    busy_ms: float = 0.0           # wall of WORKING iterations only
+    wait_ms: float = 0.0           # of it: blocked in a device fetch
+    host_ms: float = 0.0           # busy less wait, summed per iteration
     # attached by the Scheduler when the radix prefix cache is on — its
     # summary rides the same /stats payload as a `prefix_cache` block
     prefix: PrefixCacheStats | None = None
@@ -293,6 +336,9 @@ class ServeStats:
             "max_queue_depth": max(self.queue_depth, default=0),
             "steps": self.steps,
         }
+        for k in WINDOW_COUNTERS:
+            v = getattr(self, k)
+            out[k] = round(v, 3) if isinstance(v, float) else v
         if self.prefix is not None:
             out["prefix_cache"] = self.prefix.summary()
         if self.admission is not None:
@@ -319,7 +365,6 @@ class WireStats:
 
     def __init__(self, window: int = 512, max_keys: int = 64,
                  recent: int = 32):
-        import threading
         from collections import deque  # noqa: F401 — used in rtt()
 
         self.window = int(window)
@@ -528,7 +573,6 @@ class KVTransferStats:
     #                                  QUERY miss answer (donor eviction)
 
     def __post_init__(self):
-        import threading
         from collections import deque
 
         # whole-fill wall ms (connect -> last block imported)
@@ -630,8 +674,6 @@ class FleetStats:
     clamped: int = 0           # admissions with max_tokens clamped
 
     def __post_init__(self):
-        import threading
-
         # shed rejections keyed by ladder reason ("shed"/"prefix_only")
         self.sheds_by_reason: dict[str, int] = {}
         # counter mutations ride this lock (the controller thread, its

@@ -92,15 +92,13 @@ EVENT_KINDS = (
     "spec",           # terminal speculative-decoding accept record for
     #                   one request (forwards, drafted, accepted) —
     #                   dlprof attributes verify-forward cost from it
-    "step",           # scheduler iteration (timeline record)
+    "step",           # scheduler iteration (timeline record: n, ts0,
+    #                   batch composition, ms, phases {span name: ms})
     "handshake",      # cluster control star formed (role, peers)
     "cluster_tick",   # one cluster protocol frame handled (phase, rank)
     #                   — the multihost worker's span unit
     "bcast",          # startup data-plane broadcast timed (what, ms,
     #                   bytes — bcast_spec / bcast_model_tensors)
-    "sync",           # sampled device sync/compute attribution: one
-    #                   sampled step's collective vs total device ms
-    #                   (runtime/profiler.py over netstats.per_step_op_ms)
     "compile",        # an executable was minted (key, ms, warm) —
     #                   runtime/profiler.CompileLedger
     "compile_after_warmup",  # the recompile sentinel fired (key, frozen)
@@ -183,6 +181,41 @@ class TraceSink:
                 self._fh = None
 
 
+# span names, stable: the scheduler's phase boundaries (runtime/
+# scheduler.py), the one idle wait of both step loops, and the front door
+# (apps/api_server.py). PERF.md section 3 and docs/observability.md list
+# what each covers and which metric reads it.
+SPAN_NAMES = (
+    "sched.step",              # one working iteration, parent of the rest
+    "sched.admit",             # fault sites, reap, admit, prefix lookup+seed
+    "sched.dispatch.prefill",  # numpy inputs -> the jitted call returned
+    "sched.dispatch.decode",   # the same for decode / draft / verify
+    "sched.wait",              # blocked in the logits/summary fetch
+    "sched.sample_emit",       # per-row sample + _emit
+    "sched.publish",           # prefix-arena publish of a finished prompt
+    "sched.idle_wait",         # Event.wait of Scheduler._run / the
+    #                            supervisor's loop: nothing to do
+    "api.pre_submit",          # request parsed -> sched.submit returned
+)
+
+
+class Span:
+    """One open span: a name, its start on ``perf_counter``, its parent
+    (None for a root) and, on a parent, the one open child and the summed
+    ms of the closed ones. Made by ``Tracer.span``/``Tracer.phase`` and
+    closed by ``Tracer.end`` — call sites guard on ``TRACER.spans``."""
+
+    __slots__ = ("name", "t0", "parent", "phases", "_child", "_ann")
+
+    def __init__(self, name: str, parent: "Span | None", ann):
+        self.name = name
+        self.t0 = time.perf_counter()
+        self.parent = parent
+        self.phases: dict[str, float] | None = None
+        self._child: Span | None = None
+        self._ann = ann
+
+
 class Tracer:
     """Host-side flight recorder (module singleton: ``TRACER``).
 
@@ -198,6 +231,12 @@ class Tracer:
 
     def __init__(self):
         self.enabled = False
+        # a device capture is running (runtime/profiler.Profiler.capture
+        # sets and clears it): spans are written as TraceAnnotations
+        self.capturing = False
+        # enabled or capturing: THE guard of every span site (one
+        # attribute read when both sinks are off)
+        self.spans = False
         self.decode_every = 8     # decode progress event cadence (tokens)
         self.sample = 1.0         # sink sampling rate (ring records all)
         self._capacity = 8192
@@ -261,6 +300,7 @@ class Tracer:
                                        max_files=sink_max_files)
             self._anchor()
             self.enabled = bool(enabled)
+            self.spans = self.enabled or self.capturing
 
     def reset(self) -> None:
         """Disable and drop all state (test teardown; bench row
@@ -268,6 +308,7 @@ class Tracer:
         reference."""
         with self._lock:
             self.enabled = False
+            self.spans = self.capturing
             self._ring = deque(maxlen=self._capacity)
             with self._span_lock:
                 self._spans = {}
@@ -321,16 +362,77 @@ class Tracer:
             except (OSError, ValueError):
                 self.dropped += 1
 
+    # -- spans ---------------------------------------------------------------
+    #
+    # One primitive, two sinks. While a device capture runs the span is a
+    # jax.profiler.TraceAnnotation: it lands in the .xplane.pb host plane
+    # on the profiler's clock, beside the device's "XLA Ops" line, on a
+    # server started without --trace too. With --trace a closed child adds
+    # its ms to its parent's `phases`, which the scheduler hands to
+    # step(). Call sites guard on `TRACER.spans`, so a server that is
+    # neither tracing nor being captured builds no Span and no annotation.
+
+    def set_capturing(self, on: bool) -> None:
+        with self._lock:
+            self.capturing = bool(on)
+            self.spans = self.enabled or self.capturing
+
+    def span(self, name: str, parent: Span | None = None) -> Span:
+        """Open one span (callers guard on ``spans``)."""
+        ann = None
+        if self.capturing:
+            from jax.profiler import TraceAnnotation
+
+            ann = TraceAnnotation(name)
+            ann.__enter__()
+        return Span(name, parent, ann)
+
+    def phase(self, parent: Span, name: str) -> None:
+        """Open `name` as the next child of `parent`, closing the child
+        that was open: the phases of one iteration follow each other."""
+        if parent._child is not None:
+            self.end(parent._child)
+        parent._child = self.span(name, parent)
+
+    def end(self, span: Span) -> float:
+        """Close a span (and its open child); returns its ms. A child's
+        ms are summed under its name in the parent's ``phases``."""
+        if span._child is not None:
+            self.end(span._child)
+        ms = (time.perf_counter() - span.t0) * 1e3
+        if span._ann is not None:
+            span._ann.__exit__(None, None, None)
+            span._ann = None
+        parent = span.parent
+        if parent is not None:
+            parent._child = None
+            if self.enabled:
+                if parent.phases is None:
+                    parent.phases = {}
+                parent.phases[span.name] = (
+                    parent.phases.get(span.name, 0.0) + ms)
+        return ms
+
     def step(self, *, decode_rows: int, prefill_rows: int, chunk: int,
-             queue_depth: int, wall_ms: float,
-             key: str | None = None) -> None:
+             queue_depth: int, wall_ms: float, key: str | None = None,
+             n: int | None = None, ts0: float | None = None,
+             phases: dict | None = None) -> None:
         """One scheduler iteration: ring record + the per-composition
-        histogram /metrics and the bench knee-search read."""
+        histogram /metrics and the bench knee-search read. `n` is the
+        iteration's number (the `step` field of the request events it
+        caused), `ts0` its start, `phases` the ms of its closed spans by
+        name — the step's own time is `ms` less their sum."""
         if not self.enabled:
             return
         rec = {"ts": time.perf_counter(), "kind": "step", "tid": 0,
                "dec": decode_rows, "pre": prefill_rows, "chunk": chunk,
                "queue": queue_depth, "ms": round(wall_ms, 4)}
+        if n is not None:
+            rec["n"] = n
+        if ts0 is not None:
+            rec["ts0"] = ts0
+        if phases:
+            rec["phases"] = {k: round(v, 4) for k, v in phases.items()}
         if key is not None:
             rec["key"] = key
         self._ring.append(rec)
@@ -453,6 +555,24 @@ _COUNTERS = (
      "Requests refused at submit (queue bound)"),
     ("tokens_out", "dllama_tokens_out_total", "Tokens emitted"),
     ("steps", "dllama_scheduler_steps_total", "Scheduler iterations"),
+    # window counters (runtime/stats.ServeStats): difference two scrapes
+    ("admitted", "dllama_admitted_total", "Requests leased a slot"),
+    ("queue_wait_ms_sum", "dllama_queue_wait_ms_total",
+     "Submit-to-admit wait, summed over admitted requests"),
+    ("prefill_steps", "dllama_prefill_steps_total",
+     "Prefill-chunk programs dispatched"),
+    ("prefill_tokens", "dllama_prefill_tokens_total",
+     "Real prompt tokens prefilled (pad rows excluded)"),
+    ("decode_steps", "dllama_decode_steps_total",
+     "Decode or verify programs dispatched"),
+    ("decode_rows", "dllama_decode_rows_total",
+     "Rows that decoded, summed over decode steps"),
+    ("busy_ms", "dllama_scheduler_busy_ms_total",
+     "Wall ms of working scheduler iterations"),
+    ("wait_ms", "dllama_scheduler_wait_ms_total",
+     "Of busy ms: blocked in a device fetch"),
+    ("host_ms", "dllama_scheduler_host_ms_total",
+     "Of busy ms: host work (busy less wait)"),
 )
 
 _GAUGES = (
@@ -553,10 +673,9 @@ def _add_block(p: _Prom, block: dict | None, table, *, type_: str,
 
 def _add_device_blocks(p: _Prom, summary: dict,
                        labels: dict | None = None) -> None:
-    """The device-tier families (runtime/profiler.py): compile ledger,
-    HBM ledger, sampled device-time attribution — rendered from the
-    same /stats blocks every tier already carries, top-level AND
-    per-replica (labelled)."""
+    """The device-tier families (runtime/profiler.py): compile ledger
+    and HBM ledger — rendered from the same /stats blocks every tier
+    already carries, top-level AND per-replica (labelled)."""
     pre = "dllama_replica_" if labels else "dllama_"
     comp = summary.get("compiles")
     if comp:
@@ -597,32 +716,6 @@ def _add_device_blocks(p: _Prom, summary: dict,
         p.add(pre + "hbm_prefix_blocks_addable",
               hbm.get("prefix_blocks_addable"), labels,
               help_="Prefix-arena blocks that still fit free HBM")
-    dev = summary.get("device_time")
-    if dev:
-        p.add(pre + "profile_sampled_steps_total",
-              dev.get("sampled_steps"), labels, type_="counter",
-              help_="Scheduler steps captured for device-time attribution")
-        for entry, rec in (dev.get("by_entry") or {}).items():
-            lab = {**(labels or {}), "entry": _esc(entry)}
-            p.add(pre + "device_ms", rec.get("p50_ms"),
-                  {**lab, "quantile": "0.5"},
-                  help_="Sampled per-step device ms by entry point")
-            p.add(pre + "device_samples_total", rec.get("n"), lab,
-                  type_="counter")
-        sync = dev.get("sync")
-        if sync and sync.get("n"):
-            # the reference's I/T/S split reborn: per sampled step,
-            # device collective (sync) ms vs total device ms
-            p.add(pre + "step_sync_ms", sync.get("sync_p50_ms"),
-                  {**(labels or {}), "quantile": "0.5"},
-                  help_="Sampled per-step device collective ms (the "
-                        "sync half of the sync/compute split)")
-            p.add(pre + "step_sync_ms", sync.get("sync_p99_ms"),
-                  {**(labels or {}), "quantile": "0.99"})
-            p.add(pre + "step_sync_share", sync.get("sync_share"),
-                  labels,
-                  help_="Collective share of sampled device step time "
-                        "(window mean)")
 
 
 _CLUSTER_COUNTERS = (

@@ -125,12 +125,11 @@ def test_goodput_splits_on_slo():
 # -- the wire report (dlwire) -----------------------------------------------
 
 
-def test_wire_report_merges_ledgers_sync_and_reconciles():
+def test_wire_report_merges_ledgers_and_reconciles():
     """wire_report: bench rows' wire blocks (both the {root,worker}
     bench-row shape and a raw WireStats summary) merge into per-peer
-    totals; `sync` trace events yield the window-sum share; every
-    reconcile entry is collected with the drift flag re-derived at the
-    25% bar."""
+    totals; every reconcile entry is collected with the drift flag
+    re-derived at the 25% bar."""
     row = {"wire": {
         "root": {"peers": {"1": {
             "tx": {"PING": {"frames": 4, "bytes": 96},
@@ -145,16 +144,12 @@ def test_wire_report_merges_ledgers_sync_and_reconciles():
                       "unit": "bytes", "drift_frac": 0.0}}}
     raw = {"wire": {"peers": {"2": {
         "tx": {"RUN": {"frames": 1, "bytes": 50}}}}}}
-    sync_events = [{"kind": "sync", "tid": 0, "ts_wall": 1.0 + i,
-                    "sync_ms": 1.0, "device_ms": 4.0} for i in range(3)]
-    w = dlprof.wire_report(sync_events, [row, raw])
+    w = dlprof.wire_report([], [row, raw])
     assert w["peers"]["root:peer1"]["tx_bytes"] == 216
     assert w["peers"]["root:peer1"]["rtt_ms"]["p99_ms"] == 2.0
     assert w["peers"]["worker:peer0"]["rx_bytes"] == 96
     assert w["peers"]["peer2"]["tx_bytes"] == 50
-    assert w["sync"] == {"sampled_steps": 3, "sync_p50_ms": 1.0,
-                         "sync_p99_ms": 1.0, "device_p50_ms": 4.0,
-                         "sync_share": 0.25}
+    assert "sync" not in w  # went with --profile-sample
     assert len(w["reconcile"]) == 1 and not w["drift"]
 
     # a stale artifact whose producer never flagged: the report
@@ -177,15 +172,40 @@ def test_wire_markdown_renders_peer_table_and_flags():
         "clock_offset_ms": 0.07}},
         "reconcile": {"measured": 140.0, "modeled": 100.0,
                       "unit": "bytes", "drift_frac": 0.4}}}
-    report = dlprof.analyze(
-        [{"kind": "sync", "tid": 0, "ts_wall": 1.0, "sync_ms": 2.0,
-          "device_ms": 10.0}], [row], wire=True)
+    report = dlprof.analyze([], [row], wire=True)
     md = dlprof.render_markdown(report)
     assert "## Wire (measured cluster plane)" in md
     assert "| peer1 | 250 |" in md
     assert "0.9/1.8" in md
-    assert "share 0.2" in md
     assert "DRIFTED" in md
+
+
+# -- the scheduler's phases (step records of runtime/trace.py) ---------------
+
+
+def test_host_phases_means_self_time_and_absence():
+    """host_phases: mean ms per iteration for every span name in the
+    step records' `phases`, `self` = ms less the phases, the host's part
+    = all but sched.wait; None when no record carries phases."""
+    evs = [{"kind": "step", "tid": 0, "ms": 50.0, "n": 1,
+            "phases": {"sched.admit": 1.0, "sched.dispatch.decode": 2.0,
+                       "sched.wait": 42.0, "sched.sample_emit": 4.0}},
+           {"kind": "step", "tid": 0, "ms": 150.0, "n": 2,
+            "phases": {"sched.admit": 3.0, "sched.dispatch.prefill": 5.0,
+                       "sched.dispatch.decode": 2.0, "sched.wait": 130.0,
+                       "sched.sample_emit": 6.0, "sched.publish": 2.0}},
+           {"kind": "step", "tid": 0, "ms": 9.0}]     # no phases: skipped
+    hp = dlprof.host_phases(evs)
+    assert hp["steps"] == 2 and hp["step_mean_ms"] == 100.0
+    assert hp["mean_ms"]["sched.wait"] == 86.0
+    assert hp["mean_ms"]["sched.sample_emit"] == 5.0
+    assert hp["mean_ms"]["sched.publish"] == 1.0
+    assert hp["mean_ms"]["self"] == 1.5          # (1 + 2) / 2
+    assert hp["host_mean_ms"] == 14.0            # 100 - 86
+    assert dlprof.host_phases([{"kind": "step", "ms": 1.0}]) is None
+    md = dlprof.render_markdown(dlprof.analyze(evs))
+    assert "## Scheduler iteration by phase" in md
+    assert "| sched.wait | 86.0 |" in md
 
 
 # -- end to end over a REAL scheduler trace ---------------------------------

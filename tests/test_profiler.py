@@ -1,6 +1,6 @@
 """Device-tier observability (runtime/profiler.py): the compile ledger +
-recompile sentinel, the HBM ledger, on-demand capture, sampled
-device-time attribution, and build info — the ISSUE 10 acceptance bars:
+recompile sentinel, the HBM ledger, on-demand capture, and build info —
+the ISSUE 10 acceptance bars:
 
   * ZERO post-warmup compiles across the legacy / supervisor / router
     serving paths on the existing traffic shapes (the runtime twin of
@@ -12,10 +12,8 @@ device-time attribution, and build info — the ISSUE 10 acceptance bars:
     compile runs;
   * the HBM ledger's slot/arena byte counts match the engine's
     allocated shapes EXACTLY on CPU-tiny (they are real ``nbytes``);
-  * profiler disabled is allocation-free on the hot path
-    (guard-before-call, the tracer's <50-blocks discipline) and the
-    per-step cost of the sampling guard is ≤ 2% of a real tiny-model
-    decode step (the least favorable denominator).
+  * with no capture running the span guard is allocation-free on the
+    hot path (guard-before-call, the tracer's <50-blocks discipline).
 """
 
 import json
@@ -283,7 +281,6 @@ def test_hbm_block_rides_supervisor_stats(tiny):
         assert s["hbm"]["kv_slot_bytes"] > 0
         assert s["hbm"]["prefix_arena_bytes"] > 0
         assert s["compiles"]["total"] >= 2  # the warmed serving set
-        assert "device_time" not in s       # sampling off => no block
     finally:
         sup.close()
 
@@ -292,12 +289,14 @@ def test_hbm_block_rides_supervisor_stats(tiny):
 
 
 def test_profiler_disabled_is_allocation_free():
-    assert PROFILER.sample_every == 0
+    """No capture running and no --trace: the guard every span site uses
+    (`if TRACER.spans:`) is one attribute read and allocates nothing."""
+    assert not TRACER.capturing and not TRACER.spans
 
     def guarded_loop(n):
         for _ in range(n):
-            if PROFILER.sample_every:  # the scheduler's guard pattern
-                PROFILER.step_begin()
+            if TRACER.spans:  # the scheduler's guard pattern
+                TRACER.end(TRACER.span("sched.step"))
 
     guarded_loop(10)  # warm code object/locals
     before = sys.getallocatedblocks()
@@ -306,126 +305,20 @@ def test_profiler_disabled_is_allocation_free():
     assert grew < 50, f"disabled guard allocated {grew} blocks"
 
 
-def test_sampling_guard_overhead_two_percent_of_decode_step(tiny):
-    """ISSUE 10 acceptance: attribution ENABLED costs ≤ 2% of a real
-    tiny-model decode step on the steps it does NOT sample (the common
-    case — the sampled step itself pays for its capture, which is the
-    point of sampling). Denominator = the real slot_decode_step, the
-    least favorable one."""
-    spec, _ = tiny
-    eng = _engine(tiny)
-    sched = Scheduler(eng, chunk=8)
-    sched.warmup()
-    req = sched.submit([1, 9, 23], 200, _greedy(spec))
-    times = []
-    sched.step()  # prefill + first token
-    for _ in range(30):
-        t0 = time.perf_counter()
-        sched.step()
-        times.append(time.perf_counter() - t0)
-    req.cancel()
-    sched.step()
-    sched.close()
-    step_ms = sorted(times)[len(times) // 2] * 1e3
-
-    PROFILER.sample_every = 1 << 30  # enabled; nothing actually samples
-    n = 20_000
-    t0 = time.perf_counter()
-    for _ in range(n):
-        if PROFILER.sample_every:
-            PROFILER.step_begin()
-    per_step_ms = (time.perf_counter() - t0) / n * 1e3
-    overhead = per_step_ms / step_ms
-    assert overhead <= 0.02, (
-        f"sampling guard costs {per_step_ms * 1e3:.2f} us/step = "
-        f"{overhead * 100:.3f}% of a {step_ms:.2f} ms decode step")
-
-
-# -- sampled attribution + capture ------------------------------------------
-
-
-def test_sampled_steps_feed_device_time_without_breaking_serving(tiny):
-    """--profile-sample N: every Nth working step runs under a short
-    jax.profiler trace; serving output is unchanged and the profiler
-    records the samples (per-entry attribution needs a device plane —
-    present on TPU/GPU; CPU traces may carry host planes only, so the
-    by_entry map is best-effort here and the SAMPLING machinery is what
-    this pins)."""
-    spec, _ = tiny
-    eng = _engine(tiny)
-    sched = Scheduler(eng, chunk=8)
-    sched.warmup()
-    PROFILER.sample_every = 3
-    req = sched.submit([1, 9, 23, 54, 7], 6, _greedy(spec))
-    while not req.finished.is_set():
-        sched.step()
-    toks = list(req.tokens(timeout=10.0))
-    sched.close()
-    assert len(toks) == 6
-    # ingest runs on a short daemon thread (the scheduler thread must
-    # get back to serving) — poll it in
-    end = time.perf_counter() + 30.0
-    while (PROFILER.sampled + PROFILER.sample_failures < 1
-           and time.perf_counter() < end):
-        time.sleep(0.02)
-    assert PROFILER.sampled + PROFILER.sample_failures >= 1
-    s = PROFILER.summary()
-    assert s["sample_every"] == 3
-    assert isinstance(s["by_entry"], dict)
-    json.dumps(s)
-
-
-def test_sync_stats_split_and_summary():
-    """dlwire sync/compute attribution: SyncStats records one
-    (collective ms, device ms, step wall ms) triple per sampled step;
-    the share is window-sums (an idle step's ratio must not swamp the
-    loaded ones), percentiles are nearest-rank, and an empty window
-    reports n=0 with no invented numbers."""
-    from distributed_llama_tpu.runtime.profiler import SyncStats
-
-    s = SyncStats()
-    assert s.summary() == {"n": 0}
-    # three sampled steps: 25% / 50% / 0% collective
-    s.record(2.0, 8.0, 9.0)
-    s.record(4.0, 8.0, 9.5)
-    s.record(0.0, 4.0)
-    out = s.summary()
-    assert out["n"] == 3
-    assert out["sync_p50_ms"] == 2.0
-    assert out["device_p50_ms"] == 8.0
-    assert out["sync_share"] == round(6.0 / 20.0, 4)
-    assert out["wall_p50_ms"] == 9.0  # 2 wall samples: nearest-rank
-    # p50 rounds to the LOWER observed value (stats.percentile, no
-    # interpolation — round(0.5) banker's-rounds to 0)
-    json.dumps(out)
-
-    # bounded window: old samples roll off
-    s2 = SyncStats(window=4)
-    for i in range(10):
-        s2.record(1.0, 2.0, 3.0)
-    assert s2.summary()["n"] == 4
-
-
-def test_profiler_summary_carries_sync_block():
-    """The `sync` half rides the device_time /stats block (and from
-    there the dllama_step_sync_* /metrics families) in every state —
-    empty (n=0) until a sampled step lands on a backend with a device
-    plane."""
-    s = PROFILER.summary()
-    assert s["sync"] == {"n": 0}
-    PROFILER.sync.record(1.5, 6.0, 7.0)
-    s = PROFILER.summary()
-    assert s["sync"]["n"] == 1 and s["sync"]["sync_share"] == 0.25
-    json.dumps(s)
-    PROFILER.reset()
-    assert PROFILER.summary()["sync"] == {"n": 0}
+# -- capture ----------------------------------------------------------------
 
 
 def test_capture_writes_a_trace_and_refuses_concurrent(tmp_path):
     d = str(tmp_path / "cap")
+    t0 = time.perf_counter()
     out = PROFILER.capture(d, ms=20)
     assert out["dir"] == d and os.path.isdir(d)
     assert PROFILER.captures == 1
+    # the instants of the trace on the ring's clock (perf_counter)
+    assert t0 <= out["t_start_mono"] < out["t_stop_mono"] <= (
+        time.perf_counter())
+    assert out["t_stop_mono"] - out["t_start_mono"] >= 0.02
+    assert out["stop_ms"] >= 0.0 and not TRACER.capturing
     # the busy refusal: hold the slot, expect the structured error
     PROFILER._busy = True
     with pytest.raises(RuntimeError, match="busy"):

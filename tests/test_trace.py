@@ -444,14 +444,13 @@ def test_render_prometheus_router_shape_valid():
     assert m["dllama_cluster_peers_lost_total"] == [(None, 1.0)]
 
 
-def test_render_prometheus_cluster_wire_and_sync_families():
+def test_render_prometheus_cluster_wire_families():
     """dlwire (ISSUE 12): the FULL ClusterStats counter set renders as
     tier-invariant dllama_cluster_* families (the old renderer exported
     only 3 of them), the measured wire ledger as
     dllama_wire_{bytes,frames}_total{peer,kind,dir} +
-    dllama_heartbeat_rtt_ms{peer} + the clock offset, the startup
-    broadcast timings, and the sampled sync/compute split as
-    dllama_step_sync_ms / dllama_step_sync_share."""
+    dllama_heartbeat_rtt_ms{peer} + the clock offset, and the startup
+    broadcast timings."""
     summary = {
         "requests_submitted": 1, "state": "ready",
         "cluster": {
@@ -468,13 +467,6 @@ def test_render_prometheus_cluster_wire_and_sync_families():
                 "rtt_ms": {"n": 6, "p50_ms": 0.9, "p99_ms": 1.8,
                            "mean_ms": 1.1, "recent": [0.9]},
                 "clock_offset_ms": 0.07, "best_rtt_ms": 0.7}}},
-        },
-        "device_time": {
-            "sample_every": 4, "sampled_steps": 3,
-            "by_entry": {"slot_decode_step": {"n": 3, "p50_ms": 2.0,
-                                              "mean_ms": 2.1}},
-            "sync": {"n": 3, "sync_p50_ms": 0.5, "sync_p99_ms": 0.8,
-                     "device_p50_ms": 2.0, "sync_share": 0.25},
         },
     }
     m = _parse_prometheus(render_prometheus(summary, model="tiny"))
@@ -504,10 +496,6 @@ def test_render_prometheus_cluster_wire_and_sync_families():
     assert rtt['peer="1",quantile="0.5"'] == 0.9
     assert rtt['peer="1",quantile="0.99"'] == 1.8
     assert m["dllama_cluster_clock_offset_ms"] == [('peer="1"', 0.07)]
-    # the sync/compute split (the reference's I/T/S reborn)
-    sync = dict(m["dllama_step_sync_ms"])
-    assert sync['quantile="0.5"'] == 0.5 and sync['quantile="0.99"'] == 0.8
-    assert m["dllama_step_sync_share"] == [(None, 0.25)]
 
 
 def test_ingest_rebases_cluster_node_spans_onto_one_timeline():

@@ -139,6 +139,34 @@ def merge_timelines(events: list[dict], bench_rows: list[dict]) -> dict:
     return out
 
 
+def host_phases(events: list[dict]) -> dict | None:
+    """Where a scheduler iteration's wall goes, from the `phases` of the
+    `step` records (runtime/trace.py SPAN_NAMES; ms a phase, by name):
+    mean ms per iteration for each phase and for `self` — the step's
+    ms less its phases (loop turnaround, bookkeeping between spans).
+    `sched.wait` is the device's part; the rest is the host's. None when
+    no step record carries phases (a trace older than the spans)."""
+    steps = [e for e in events
+             if e.get("kind") == "step" and isinstance(e.get("phases"), dict)]
+    if not steps:
+        return None
+    total: dict[str, float] = {}
+    for e in steps:
+        ph = e["phases"]
+        for name, ms in ph.items():
+            total[name] = total.get(name, 0.0) + float(ms)
+        total["self"] = (total.get("self", 0.0) + float(e.get("ms", 0.0))
+                         - sum(float(v) for v in ph.values()))
+    n = len(steps)
+    mean = {k: _rnd(v / n, 4) for k, v in sorted(total.items())}
+    return {"steps": n,
+            "step_mean_ms": _rnd(sum(float(e.get("ms", 0.0))
+                                     for e in steps) / n, 4),
+            "mean_ms": mean,
+            "host_mean_ms": _rnd(sum(v for k, v in total.items()
+                                     if k != "sched.wait") / n, 4)}
+
+
 # -- per-request critical path ----------------------------------------------
 
 _TERMINAL = ("finish", "error")
@@ -384,9 +412,7 @@ WIRE_DRIFT_FRAC = 0.25
 def wire_report(events: list[dict], bench_rows: list[dict]) -> dict | None:
     """The comms section: per-peer measured bytes/frames and RTT tails
     (from bench rows' ``wire`` blocks — the cluster chaos row, MULTICHIP
-    rows when silicon returns), the sampled device sync-vs-compute share
-    (from ``sync`` trace events — runtime/profiler.py's per-step
-    collective attribution), and every measured-vs-modeled
+    rows when silicon returns) and every measured-vs-modeled
     reconciliation found, drift flagged at >= 25% like the autotune knee
     check. None when no input carries wire data."""
     peers: dict[str, dict] = {}
@@ -439,23 +465,6 @@ def wire_report(events: list[dict], bench_rows: list[dict]) -> dict | None:
             if isinstance(sub, dict) and "peers" in sub:
                 eat_summary("kvx", sub)
 
-    syncs = [e for e in events if e.get("kind") == "sync"]
-    sync = None
-    if syncs:
-        sync_ms = [float(e.get("sync_ms") or 0.0) for e in syncs]
-        dev_ms = [float(e.get("device_ms") or 0.0) for e in syncs]
-        total_dev = sum(dev_ms)
-        sync = {
-            "sampled_steps": len(syncs),
-            "sync_p50_ms": _rnd(percentile(sync_ms, 50), 4),
-            "sync_p99_ms": _rnd(percentile(sync_ms, 99), 4),
-            "device_p50_ms": _rnd(percentile(dev_ms, 50), 4),
-            # window sums, not mean-of-ratios (an idle step's ratio must
-            # not swamp the loaded steps) — same rule as SyncStats
-            "sync_share": (_rnd(sum(sync_ms) / total_dev, 4)
-                           if total_dev else None),
-        }
-
     kvx = None
     if kvx_blocks:
         # sum the counters across blocks (a disaggregated bench row may
@@ -478,14 +487,14 @@ def wire_report(events: list[dict], bench_rows: list[dict]) -> dict | None:
         kvx["fill_hit_rate"] = (_rnd(kvx["fills_ok"] / req, 4)
                                 if req else None)
 
-    if not peers and sync is None and not reconciles and kvx is None:
+    if not peers and not reconciles and kvx is None:
         return None
     # re-derive the drift flag locally: committed artifacts may predate
     # the producer's threshold, and the report must flag consistently
     for rec in reconciles:
         if rec.get("drift_frac") is not None:
             rec["drift"] = rec["drift_frac"] >= WIRE_DRIFT_FRAC
-    return {"peers": peers, "sync": sync, "kv_transfer": kvx,
+    return {"peers": peers, "kv_transfer": kvx,
             "reconcile": reconciles,
             "drift": any(r.get("drift") for r in reconciles)}
 
@@ -554,6 +563,7 @@ def analyze(events: list[dict], bench_rows: list[dict] | None = None, *,
                    "bench_rows": len(bench_rows),
                    "compositions": len(timeline)},
         "requests": request_summary(paths),
+        "host_phases": host_phases(events),
         "critical_paths": paths,
         "step_curve": {
             "compositions": {f"dec{k[0]}_pre{k[1]}_c{k[2]}": v
@@ -593,6 +603,15 @@ def render_markdown(report: dict) -> str:
         lines.append(f"| {ph.removesuffix('_ms')} | {row['p50']} | "
                      f"{row['p99']} | {row['n']} |")
     lines.append("")
+
+    hp = report.get("host_phases")
+    if hp:
+        lines += ["## Scheduler iteration by phase", "",
+                  f"{hp['steps']} iterations, mean {hp['step_mean_ms']} ms; "
+                  f"host (all but `sched.wait`) {hp['host_mean_ms']} ms.",
+                  "", "| phase | mean ms / iteration |", "|---|---|"]
+        lines += [f"| {k} | {v} |" for k, v in hp["mean_ms"].items()]
+        lines.append("")
 
     sc = report["step_curve"]
     lines += ["## Batch-composition → ms/step", "",
@@ -663,13 +682,6 @@ def render_markdown(report: dict) -> str:
                     f"{rtt.get('p50_ms')}/{rtt.get('p99_ms')} | "
                     f"{rec.get('clock_offset_ms')} |")
             lines.append("")
-        sync = w.get("sync")
-        if sync:
-            lines += [f"Sync vs compute (sampled device steps, "
-                      f"n={sync['sampled_steps']}): collective p50 "
-                      f"{sync['sync_p50_ms']} ms of device p50 "
-                      f"{sync['device_p50_ms']} ms — **share "
-                      f"{sync['sync_share']}**.", ""]
         kvx = w.get("kv_transfer")
         if kvx:
             lines += ["### KV transfer", "",
@@ -792,9 +804,9 @@ def _selftest() -> int:
     assert drifted["drift"] and drifted["drift_frac"] == 1.0, drifted
     assert "Calibration drift" in render_markdown(r2)
 
-    # the wire section (dlwire): a bench row's measured cluster ledger +
-    # sampled sync events -> per-peer table, sync share, and the
-    # reconciliation — exact-match reads clean, a 30%-off model flags
+    # the wire section (dlwire): a bench row's measured cluster ledger
+    # -> per-peer table and the reconciliation — exact-match reads
+    # clean, a 30%-off model flags
     wire_row = {"metric": "wire-selftest", "wire": {
         "root": {"peers": {"1": {
             "tx": {"PING": {"frames": 5, "bytes": 120},
@@ -806,16 +818,12 @@ def _selftest() -> int:
         "reconcile": {"measured": 223.0, "modeled": 223.0,
                       "unit": "bytes", "drift_frac": 0.0,
                       "drift": False}}}
-    sync_events = [{"ts_wall": t, "kind": "sync", "tid": 0,
-                    "sync_ms": 2.0, "device_ms": 8.0, "share": 0.25}
-                   for _ in range(4)]
-    rw = analyze(events + sync_events, [bench_row, wire_row], wire=True)
+    rw = analyze(events, [bench_row, wire_row], wire=True)
     w = rw["wire"]
     assert w is not None and not w["drift"], w
     assert w["peers"]["root:peer1"]["tx_bytes"] == 343, w["peers"]
-    assert w["sync"]["sync_share"] == 0.25, w["sync"]
     md_w = render_markdown(rw)
-    assert "Wire (measured cluster plane)" in md_w and "0.25" in md_w
+    assert "Wire (measured cluster plane)" in md_w and "343" in md_w
     drifted_row = {"metric": "w2", "wire": {
         "reconcile": {"measured": 130.0, "modeled": 100.0,
                       "unit": "bytes", "drift_frac": 0.3, "drift": True}}}
@@ -860,7 +868,7 @@ def _selftest() -> int:
     assert rkd["drift"], rkd
 
     print("dlprof selftest: OK (knee=4, 3 spans, autotune drift check, "
-          "wire section + sync share + drift flag, KV transfer section, "
+          "wire section + drift flag, KV transfer section, "
           "report renders)")
     return 0
 
@@ -882,8 +890,7 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--wire", action="store_true",
                     help="add the measured cluster-plane comms section: "
                          "per-peer bytes + RTT tails from bench rows' "
-                         "`wire` blocks, device sync-vs-compute share "
-                         "from sampled `sync` trace events, and every "
+                         "`wire` blocks, and every "
                          "measured-vs-modeled reconciliation (drift "
                          "flagged at >= 25%%)")
     ap.add_argument("--slo-ttft-ms", type=float, default=500.0)
