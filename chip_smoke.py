@@ -570,8 +570,8 @@ def phase_serve(plan: Plan) -> dict:
                   "serve: HBM ledger has no device_bytes_in_use")
             for key in ("slot_decode", f"slot_prefill:{chunk}"):
                 ks = (comp["by_key"].get(key) or {}).get("kernels")
-                check(ks and ks.get("q40_matmul", 0) > 0
-                      and ks.get("flash_attention", 0) > 0,
+                check(ks and all(ks.get(k, 0) > 0 for k in (
+                          "q40_matmul", "flash_attention", "kv_cache_write")),
                       f"serve: executable {key!r} lacks the Pallas "
                       f"kernels: {ks}")
                 say(f"serve: {key}: kernels {json.dumps(ks)}")

@@ -116,7 +116,10 @@ def entry_points(max_devices: int | None = None,
 
     # -- continuous-batching scheduler hot path (runtime/scheduler.py) ----
     # slot_decode_step: (B, 1) tokens at per-row positions (the scatter
-    # cache-write path); gated rows pass pos == seq_len. Any host callback
+    # cache-write path: traced with the kernels off, as every entry point
+    # here is — on the chip ops/pallas_kv_write.kv_cache_write takes this
+    # write, bit-equal by tests/test_pallas_kv_write.py); gated rows pass
+    # pos == seq_len. Any host callback
     # or f64 traced into this program stalls EVERY serving step — the
     # audit is the CI gate the scheduler rides on.
     spec_s, params_s, tok_s, _, cache_s = build_forward_inputs(batch=4, t=1)

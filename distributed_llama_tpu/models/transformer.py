@@ -21,8 +21,12 @@ stacked array and back in (two 16 MB copies per layer per token at 7B), and
 copied+re-laid-out the packed weights before each Pallas call. Unrolling
 makes each layer's weights and cache standalone buffers: weights feed the
 kernel in place, and the per-layer cache arrays are donated and updated
-in place via dynamic_update_slice (the functional form of the reference's
-in-place cache write at src/llama2-tasks.cpp:38-44).
+in place (the functional form of the reference's in-place cache write at
+src/llama2-tasks.cpp:38-44): the batched step programs through the aliased
+`kv_cache_write` kernel (ops/pallas_kv_write.py), the shared-position
+paths through dynamic_update_slice. An XLA-level update of a few rows is
+in place only in name where the kernels run: layout assignment re-lays
+the whole operand around it (four cache-sized copies a layer on v5e).
 """
 
 from __future__ import annotations
@@ -56,7 +60,9 @@ class KVCache(NamedTuple):
 
     Separate per-layer buffers (not one stacked (L, ...) array) so that a
     donated cache is updated strictly in place — profiling showed XLA copies
-    stacked caches wholesale through scan/while carries. Head-major (KVH
+    stacked caches wholesale through scan/while carries — and each leaf is
+    the aliased in/out operand of the step programs' kv_cache_write kernel
+    (_scatter_cache_write). Head-major (KVH
     before S) so decode attention reads each head's keys sequentially;
     with S-major XLA picked a head-minor layout that ran the per-layer
     score contraction at ~75 GB/s instead of ~600."""
@@ -96,20 +102,45 @@ def _to_cache_dtype(x, dtype):
     return x.astype(dtype)
 
 
-def _scatter_cache_write(k_cache, v_cache, k, v, idx, write_gate):
-    """Drop-mode scatter of (B, T, KVH, hs) K/V at per-position indices
-    (B, T) into (B, KVH, S, hs) caches. write_gate (traced bool) pushes
-    gated-off writes to the out-of-bounds slot S, which scatter drops —
-    shared by the batched per-row write path and the manual-sp chunk-local
-    write path so the OOB-gating idiom cannot diverge."""
+def _scatter_cache_write(k_cache, v_cache, k, v, idx, write_gate,
+                         kernel_cfg=None):
+    """Write (B, T, KVH, hs) K/V at per-position indices (B, T) into
+    (B, KVH, S, hs) caches, dropping every index >= S. write_gate (traced
+    bool) pushes gated-off writes to the out-of-bounds slot S — shared by
+    the batched per-row write path and the manual-sp chunk-local write path
+    so the OOB-gating idiom cannot diverge.
+
+    kernel_cfg: the forward's cfg, passed by callers whose index rows are
+    CONTIGUOUS and start inside the cache or at S (idx[b] = pos[b] +
+    arange(T), pos[b] >= 0). With the kernels on and a context of whole row
+    tiles, the in-place `kv_cache_write` kernel then takes the write
+    (ops/pallas_kv_write.py: the XLA scatter costs four cache-sized layout
+    copies a layer); bit-equal to the drop-mode scatter, which stays for
+    every other caller, as decode_attention stays behind flash_attention."""
     oob = k_cache.shape[2]
     if write_gate is not None:
         idx = jnp.where(write_gate, idx, oob)
+    k = _to_cache_dtype(k, k_cache.dtype)
+    v = _to_cache_dtype(v, v_cache.dtype)
+    if kernel_cfg is not None and kernel_cfg.get("use_pallas"):
+        from ..ops.pallas_kv_write import kv_cache_write, kv_write_supported
+
+        if kv_write_supported(oob, k_cache.dtype):
+            interpret = kernel_cfg.get("pallas_interpret", False)
+            mesh = kernel_cfg.get("tp_mesh")
+            if mesh is not None and not kernel_cfg.get("manual_tp"):
+                # GSPMD can't partition a pallas_call: per shard, next to
+                # tp_flash_attention (inside the manual pp region the
+                # cache is already local)
+                from ..parallel.tp_q80 import tp_kv_cache_write
+
+                return tp_kv_cache_write(k_cache, v_cache, k, v, idx[:, 0],
+                                         mesh, interpret=interpret)
+            return kv_cache_write(k_cache, v_cache, k, v, idx[:, 0],
+                                  interpret=interpret)
     bidx = jnp.arange(k_cache.shape[0], dtype=jnp.int32)[:, None]
-    k_cache = k_cache.at[bidx, :, idx].set(
-        _to_cache_dtype(k, k_cache.dtype), mode="drop")
-    v_cache = v_cache.at[bidx, :, idx].set(
-        _to_cache_dtype(v, v_cache.dtype), mode="drop")
+    k_cache = k_cache.at[bidx, :, idx].set(k, mode="drop")
+    v_cache = v_cache.at[bidx, :, idx].set(v, mode="drop")
     return k_cache, v_cache
 
 
@@ -161,6 +192,9 @@ def _attention_block(x, lw, spec: ModelSpec, k_cache, v_cache, q_pos, cfg,
         # owned by other devices (and bubble-step writes, write_gate) are
         # pushed to the OOB slot — scatter drops them. Negative local
         # indices would WRAP, not drop, so they are clamped to OOB first.
+        # The scatter STAYS here: a row's window may start before this
+        # chunk and end inside it, which the kv_cache_write kernel's one
+        # start position per row cannot say.
         from ..parallel.mesh import SP_AXIS as _SP
         from ..parallel.ring_attention import sp_cache_attention_local
 
@@ -176,11 +210,14 @@ def _attention_block(x, lw, spec: ModelSpec, k_cache, v_cache, q_pos, cfg,
         # batched generation: each sequence writes at its own position
         # (net-new vs the reference's batch=1 — SURVEY.md §2.5 DP row).
         # Gated (pp off-turn) writes are pushed out of bounds and dropped
-        # by the scatter — cheaper than a read-modify-write, and XLA's
-        # partitioner handles the scatter where it miscompiles the
-        # equivalent gather under manual pp.
-        k_cache, v_cache = _scatter_cache_write(k_cache, v_cache, k, v,
-                                                q_pos, write_gate)
+        # — cheaper than a read-modify-write, and XLA's partitioner
+        # handles the scatter where it miscompiles the equivalent gather
+        # under manual pp. q_pos rows are contiguous, so the in-place
+        # kernel may take the write; a cache whose sequence GSPMD shards
+        # over sp keeps the scatter (a pallas_call would gather it whole).
+        k_cache, v_cache = _scatter_cache_write(
+            k_cache, v_cache, k, v, q_pos, write_gate,
+            kernel_cfg=cfg if sp_cache_mesh is None else None)
     else:
         pos0 = q_pos[:, 0]
         k_w = _to_cache_dtype(k.transpose(0, 2, 1, 3), k_cache.dtype)

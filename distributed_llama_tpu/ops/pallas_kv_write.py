@@ -1,0 +1,147 @@
+"""Pallas TPU kernel: write a step's new K/V rows into the slot cache IN PLACE.
+
+The functional form of the reference's in-place cache write
+(ref: src/llama2-tasks.cpp:38-44) for the batched, per-row-position step
+programs. The XLA form — a drop-mode scatter, `cache.at[b, :, idx].set(...)`
+— is correct but XLA's layout assignment re-lays the operand of ANY
+XLA-level update of a few rows (scatter and `dynamic_update_slice` alike):
+every layer's whole K and V cache went from layout {3,2,1,0} to {3,1,2,0}
+and back, four cache-sized copies a layer and 62 % of a Mistral-7B decode
+step on v5e (PERF.md section 6, PR 27). A `pallas_call` pins its operands
+to the row-major layout `flash_attention` already reads, and
+`input_output_aliases` makes the donated cache the output buffer, so the
+step programs keep no cache-shaped copy at all.
+
+Mosaic moves HBM in whole sublane tiles — `row_tile` rows of the sequence
+axis (8 for f32, 16 for bf16, 32 for fp8; a one-row DMA compiles only for
+f32), so the kernel is a read-modify-write of the aligned tiles a row's
+window `pos[b] .. pos[b]+T-1` touches: grid `(B, tiles a T-row window can
+touch)`, cache block `(1, KVH, R, hs)` in and out through ONE index map,
+body `out = where(position is in the window, new row, old row)`. A chunk's
+new rows arrive already placed at their offset inside those tiles
+(`_window_tokens`, a gather over the step's few rows in XLA; decode's
+single row needs none), token-major as the projection left them — a head
+is a lane slice — so the body is a select and is bit-exact for every
+value: a one-hot matmul would place rows as cheaply but turns one `inf`
+into NaN in its neighbours (0 * inf).
+
+In and out are the same buffer, and Pallas prefetches the next input block
+before the last output block is written back. That is safe because every
+visit writes its block's complete final content: distinct (b, tile) pairs
+are disjoint, and the visits the clamp at the context's last tile
+duplicates select from the same window tile, so they write equal bytes.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+
+def row_tile(dtype) -> int:
+    """Rows of the sequence axis in one packed sublane tile of `dtype`."""
+    return 32 // jnp.dtype(dtype).itemsize
+
+
+def kv_write_supported(seq_len: int, dtype) -> bool:
+    """Kernel precondition: whole row tiles cover the context (a ragged
+    last tile would need a masked DMA). Callers fall back to the scatter."""
+    return seq_len % row_tile(dtype) == 0
+
+
+def _n_tiles(t: int, r: int) -> int:
+    """Most R-row tiles a window of T rows can touch at any start."""
+    return (t + r - 2) // r + 1
+
+
+def _window_tokens(off, rows: int, t: int):
+    """(B, rows, 1) token index per window row: row b's token i sits at
+    window row off[b] + i; the other rows name some token and are never
+    selected."""
+    src = jnp.arange(rows, dtype=jnp.int32)[None, :] - off[:, None]
+    return jnp.clip(src, 0, t - 1)[:, :, None]
+
+
+def _kernel(pos_ref, kc_ref, vc_ref, kw_ref, vw_ref, ko_ref, vo_ref,
+            *, r, last, t):
+    b, j = pl.program_id(0), pl.program_id(1)
+    pos = pos_ref[b]
+    tile = jnp.clip(pos // r + j, 0, last)
+    kvh, _, hs = kc_ref.shape[1:]
+    row = tile * r + jax.lax.broadcasted_iota(jnp.int32, (r, hs), 0)
+    hit = (row >= pos) & (row < pos + t)
+    # the window's rows are token-major, (rows, KVH*hs): a head is a lane
+    # slice of them. Decode's one row broadcasts over the tile.
+    for c_ref, w_ref, o_ref in ((kc_ref, kw_ref, ko_ref),
+                                (vc_ref, vw_ref, vo_ref)):
+        for h in range(kvh):
+            o_ref[0, h] = jnp.where(hit, w_ref[0, :, h * hs:(h + 1) * hs],
+                                    c_ref[0, h])
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def kv_cache_write(
+    k_cache: jnp.ndarray,  # (B, KVH, S, hs)
+    v_cache: jnp.ndarray,  # (B, KVH, S, hs)
+    k_new: jnp.ndarray,    # (B, T, KVH, hs), already in the cache dtype
+    v_new: jnp.ndarray,    # (B, T, KVH, hs)
+    pos: jnp.ndarray,      # (B,) first position row b writes; >= 0
+    interpret: bool = False,
+):
+    """Row b's T new rows land at positions pos[b] .. pos[b]+T-1 of its
+    cache rows; positions >= S are dropped (a gated row passes pos[b] == S)
+    — what `.at[b, :, pos[b] + arange(T)].set(..., mode="drop")` does.
+    Returns the two caches, aliased onto the inputs."""
+    b, kvh, s, hs = k_cache.shape
+    t = k_new.shape[1]
+    r = row_tile(k_cache.dtype)
+    assert kv_write_supported(s, k_cache.dtype), (s, k_cache.dtype)
+    assert k_new.dtype == k_cache.dtype and v_new.dtype == v_cache.dtype
+    n = _n_tiles(t, r)
+    last = s // r - 1
+    pos = pos.astype(jnp.int32)
+    k_new = k_new.reshape(b, t, kvh * hs)
+    v_new = v_new.reshape(b, t, kvh * hs)
+
+    def cache_index(i, j, p):
+        return (i, 0, jnp.clip(p[i] // r + j, 0, last), 0)
+
+    if t == 1:
+        # decode: the row itself is the window, whichever tile it lands in
+        window_block = pl.BlockSpec((1, 1, kvh * hs),
+                                    lambda i, j, p: (i, 0, 0))
+    else:
+        src = _window_tokens(pos % r, n * r, t)
+        k_new = jnp.take_along_axis(k_new, src, axis=1)
+        v_new = jnp.take_along_axis(v_new, src, axis=1)
+
+        def window_index(i, j, p):
+            # the window tile that lands on the (clamped) cache tile; where
+            # the clamp leaves nothing to write, any tile does
+            first = p[i] // r
+            return (i, jnp.clip(jnp.clip(first + j, 0, last) - first,
+                                0, n - 1), 0)
+
+        window_block = pl.BlockSpec((1, r, kvh * hs), window_index)
+    cache_block = pl.BlockSpec((1, kvh, r, hs), cache_index)
+    shape = jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype)
+    return pl.pallas_call(
+        functools.partial(_kernel, r=r, last=last, t=t),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, n),
+            in_specs=[cache_block, cache_block, window_block, window_block],
+            out_specs=[cache_block, cache_block],
+        ),
+        out_shape=[shape, shape],
+        # operand 0 is pos; the caches are written where they stand
+        input_output_aliases={1: 0, 2: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="kv_cache_write",
+    )(pos, k_cache, v_cache, k_new, v_new)

@@ -197,6 +197,16 @@ def _expert_kernel(e_ref, x_lo_ref, x_hi_ref, xsum_ref, packed_ref,
 # packed byte each): a (td, m) packed tile past this many bytes overflows
 # the ~16 MB scoped-VMEM budget
 _TILE_BYTES_MAX = 2_300_000
+# The compiler's default scoped-VMEM limit, which _TILE_BYTES_MAX budgets
+# the TILES against. The whole-array activation panels (x_lo, x_hi, xsum)
+# are extra: XLA usually hands them over already in VMEM and they cost the
+# kernel nothing, but where its memory-space assignment decides otherwise
+# they land in the kernel's own scoped allocation — at 256 rows 4.2 MB
+# (m = 2048) to 14.7 MB (m = 7168), which took a Mixtral prefill program
+# to 19.2 MB and failed its compile once the step programs' schedule
+# changed (PERF.md section 6, PR 27). q40_matmul therefore asks for the
+# default plus its panels.
+_SCOPED_VMEM_DEFAULT = 16 * 2**20
 
 
 def _tile_d(d: int, m: int) -> int:
@@ -302,6 +312,8 @@ def q40_matmul(
             bytes_accessed=d * m + d * nb * 2 + 2 * t * m * 4 + t * d * 4,
             transcendentals=0,
         ),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_SCOPED_VMEM_DEFAULT + 4 * t * (2 * m + nb)),
         interpret=interpret,
         name="q40_matmul",
     )(x_lo, x_hi, xsum, packed2d, scales)

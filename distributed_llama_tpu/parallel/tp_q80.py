@@ -14,13 +14,15 @@ Two things GSPMD cannot express live here:
 
 2. **Pallas kernels on multi-device meshes.** GSPMD cannot auto-partition
    a `pallas_call` over sharded operands, so the fused Q40 kernel
-   (ops/pallas_q40.py) and flash decode attention (ops/pallas_attention.py)
-   would otherwise force the slower XLA-dequant path whenever the mesh has
-   more than one device. `tp_row_matmul` / `tp_col_matmul(use_pallas=True)`
-   / `tp_flash_attention` run the kernels per-shard inside `shard_map`:
-   row-split weights need no communication at all (each shard produces its
-   output rows), col-split partial sums reduce with an exact psum (default)
-   or the quantized exchange, and attention shards over (dp, kv-heads).
+   (ops/pallas_q40.py), flash decode attention (ops/pallas_attention.py)
+   and the in-place cache write (ops/pallas_kv_write.py) would otherwise
+   force the slower XLA paths whenever the mesh has more than one device.
+   `tp_row_matmul` / `tp_col_matmul(use_pallas=True)` /
+   `tp_flash_attention` / `tp_kv_cache_write` run the kernels per-shard
+   inside `shard_map`: row-split weights need no communication at all
+   (each shard produces its output rows), col-split partial sums reduce
+   with an exact psum (default) or the quantized exchange, and attention
+   and the cache write shard over (dp, kv-heads).
 
 Layout: a col-split weight (wo, w2, moe_down — ref ColMatmulSlice,
 src/transformer.cpp:48-76) is repacked host/device-side into a stacked
@@ -132,6 +134,15 @@ def tp_row_matmul(
     return fn(x, w)
 
 
+def _batch_head_axes(mesh, batch: int):
+    """(dp axis or None, tp axis or None) for shard-local attention-side
+    kernels: batch shards on dp when it divides, (kv) heads on tp."""
+    dp = mesh.shape.get(DP_AXIS, 1)
+    tp = mesh.shape.get(TP_AXIS, 1)
+    return (DP_AXIS if dp > 1 and batch % dp == 0 else None,
+            TP_AXIS if tp > 1 else None)
+
+
 def tp_flash_attention(
     q: jnp.ndarray,        # (B, T, H, hs)
     k_cache: jnp.ndarray,  # (B, KVH, S, hs)
@@ -149,11 +160,7 @@ def tp_flash_attention(
 
     from ..ops.pallas_attention import flash_attention
 
-    b = q.shape[0]
-    dp = mesh.shape.get(DP_AXIS, 1)
-    tp = mesh.shape.get(TP_AXIS, 1)
-    dp_ax = DP_AXIS if dp > 1 and b % dp == 0 else None
-    tp_ax = TP_AXIS if tp > 1 else None
+    dp_ax, tp_ax = _batch_head_axes(mesh, q.shape[0])
 
     def body(q_l, k_l, v_l, pos_l):
         return flash_attention(q_l, k_l, v_l, pos_l, interpret=interpret)
@@ -164,6 +171,35 @@ def tp_flash_attention(
                   P(dp_ax, tp_ax, None, None), P(dp_ax, None)),
         out_specs=P(dp_ax, None, tp_ax, None), check_vma=False)
     return fn(q, k_cache, v_cache, q_pos)
+
+
+def tp_kv_cache_write(
+    k_cache: jnp.ndarray,  # (B, KVH, S, hs)
+    v_cache: jnp.ndarray,  # (B, KVH, S, hs)
+    k_new: jnp.ndarray,    # (B, T, KVH, hs), in the cache dtype
+    v_new: jnp.ndarray,    # (B, T, KVH, hs)
+    pos: jnp.ndarray,      # (B,)
+    mesh,
+    *,
+    interpret: bool = False,
+):
+    """kv_cache_write over a (dp, tp) mesh, the caches under the specs
+    tp_flash_attention reads them with: every shard writes its own batch
+    rows' kv heads in place. Shard-local, no collective."""
+    from jax import shard_map
+
+    from ..ops.pallas_kv_write import kv_cache_write
+
+    dp_ax, tp_ax = _batch_head_axes(mesh, k_cache.shape[0])
+    cache, new = P(dp_ax, tp_ax, None, None), P(dp_ax, None, tp_ax, None)
+
+    def body(kc, vc, kn, vn, pos_l):
+        return kv_cache_write(kc, vc, kn, vn, pos_l, interpret=interpret)
+
+    fn = shard_map(body, mesh=mesh,
+                   in_specs=(cache, cache, new, new, P(dp_ax)),
+                   out_specs=(cache, cache), check_vma=False)
+    return fn(k_cache, v_cache, k_new, v_new, pos)
 
 
 def repack_col_tp(w, tp: int) -> TpColWeight:

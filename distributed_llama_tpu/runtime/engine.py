@@ -1450,11 +1450,13 @@ class Engine:
                            logit_index: np.ndarray) -> jax.Array:
         """One chunked-prefill forward over the batched cache: row r writes
         its (B, C) chunk's K/V at absolute offsets pos[r]..pos[r]+C-1 via
-        the per-row scatter path, without disturbing any other row. Rows
+        the per-row write path, without disturbing any other row. Rows
         not prefilling this call are GATED OFF by passing pos[r] ==
-        seq_len: their write indices land out of bounds and the drop-mode
-        scatter discards them (models/transformer._scatter_cache_write),
-        so a gated row's cache — mid-decode or idle — is untouched.
+        seq_len: their write positions land out of bounds and are dropped
+        (models/transformer._scatter_cache_write: the in-place
+        kv_cache_write kernel with the kernels on, the drop-mode scatter
+        otherwise), so a gated row's cache — mid-decode or idle — is
+        untouched.
         Returns (B, vocab) logits read at per-row `logit_index` within the
         chunk (only rows finishing their prompt this chunk are consumed;
         the scheduler skips the D2H fetch entirely for mid-prompt chunks).
@@ -1494,8 +1496,8 @@ class Engine:
 
     def slot_decode_step(self, tokens: np.ndarray, pos: np.ndarray) -> jax.Array:
         """One decode step for the slot scheduler: row r feeds tokens[r]
-        at its own absolute position pos[r] (per-row scatter write,
-        donated cache). Rows without a decode token this step pass pos[r]
+        at its own absolute position pos[r] (per-row write, in place in
+        the donated cache). Rows without a decode token this step pass pos[r]
         == seq_len — their write drops out of bounds and their logits row
         is ignored. One compilation key total ("slot_decode"); self.pos is
         untouched (per-slot positions are the scheduler's)."""
@@ -1794,7 +1796,7 @@ class Engine:
             # rows feed [cur] + draft, padded to 1 + k_max with cur (the
             # padding's K/V writes sit beyond the accepted prefix and are
             # overwritten before any later query attends them; rows at the
-            # context edge rely on the scatter's drop-mode OOB writes)
+            # context edge rely on the cache write dropping OOB positions)
             seg = np.empty((b, 1 + k_max), np.int32)
             for i, d in enumerate(drafts):
                 seg[i, 0] = cur[i]
@@ -1962,7 +1964,7 @@ class Engine:
         while any(alive(i) for i in range(b)):
             tokv = jnp.asarray(cur[:, None])
             # exhausted rows clamp their (ignored) write to the last slot so
-            # the scatter stays in bounds; their outputs stopped already
+            # the write stays in bounds; their outputs stopped already
             posv = jnp.asarray(np.minimum(pos, self.seq_len - 1))
             if self._token_sharding is not None:
                 tokv = jax.device_put(tokv, self._token_sharding)

@@ -119,7 +119,8 @@ _KERNEL_NAME_RE = re.compile(r'@tpu_custom_call\(.*?kernel_name = "([^"]+)"')
 
 def kernel_call_sites(lowered_text: str) -> dict:
     """{kernel name: call sites} of the Pallas TPU kernels
-    (`tpu_custom_call`s — q40_matmul, q40_expert_matmul, flash_attention)
+    (`tpu_custom_call`s — q40_matmul, q40_expert_matmul, flash_attention,
+    kv_cache_write)
     in a lowered (StableHLO) module. Sites, not executions: jit emits one
     function per distinct shape and a layer loop calls it many times —
     presence is the signal."""

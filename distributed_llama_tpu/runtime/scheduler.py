@@ -14,7 +14,9 @@ device program is one of exactly two executables —
 
 Rows not participating in a call are gated off by passing position ==
 seq_len: their cache writes drop out of bounds (models/transformer's
-drop-mode scatter) and their logits are never read. This replaces the
+per-row write: the in-place kv_cache_write kernel on the chip, the
+drop-mode scatter without the kernels) and their logits are never read.
+This replaces the
 static batch endpoint's regime — all prompts in one request, serial
 prefill, every slot held until the slowest row drains — with
 iteration-level admission: a finished row's slot is handed to the next

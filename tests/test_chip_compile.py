@@ -184,6 +184,62 @@ def test_whole_decode_step_compiles_at_published_widths(topo, model):
     assert _has_kernel(fn.lower(*args).compile())
 
 
+@pytest.mark.parametrize("t", [1, 32], ids=["decode", "chunk32"])
+@pytest.mark.parametrize("model", ["mistral_7b", "mixtral_8x7b"])
+def test_step_programs_keep_no_cache_sized_copy(topo, model, t):
+    """The benchmark's two step programs (B=8, S=4096; depth cut to 2) write
+    K/V through the in-place `kv_cache_write` kernel and hold NO `copy` of a
+    whole cache leaf. With the drop-mode scatter (or a loop of
+    dynamic_update_slices) XLA re-laid every layer's K and V around the
+    update, four 64 MiB copies a layer — 62 % of a decode step on the chip
+    (PERF.md section 6, PR 27). This is the guard against their return."""
+    import rehearse_chip_compile as r
+
+    from distributed_llama_tpu.runtime.profiler import kernel_call_sites
+
+    spec = dataclasses.replace(
+        {"mistral_7b": r.MISTRAL_7B, "mixtral_8x7b": r.MIXTRAL_8X7B}[model],
+        n_layers=2)
+    fn, args = r.abstract_step(spec, topo.devices, batch=8, t=t,
+                               seq_len=4096)
+    lowered = fn.lower(*args)
+    assert kernel_call_sites(lowered.as_text()).get("kv_cache_write", 0) >= 1
+    copies = r.cache_shaped_copies(lowered.compile().as_text(),
+                                   args[-1].k[0].shape)
+    assert not copies, copies
+
+
+def test_served_mixtral_prefill_chunk_fits_scoped_vmem(topo):
+    """`mixtral-8x7b-12l`'s prefill program AS SERVED (12 layers, the Q80
+    activation round trip on). With the cache copies gone XLA placed one
+    expert matmul's 256-row activation panels in the kernel's own scoped
+    VMEM, 19.2 MB against the 16 MB default, and the compile failed on the
+    chip, at this depth only; q40_matmul now asks for its panels
+    (ops/pallas_q40._SCOPED_VMEM_DEFAULT)."""
+    import rehearse_chip_compile as r
+
+    spec = dataclasses.replace(r.MIXTRAL_8X7B, n_layers=12)
+    fn, args = r.abstract_step(spec, topo.devices, batch=8, t=32,
+                               seq_len=4096, q80=True)
+    assert _has_kernel(fn.lower(*args).compile())
+
+
+@pytest.mark.parametrize("t", [1, 32, 256])
+@pytest.mark.parametrize("cache_dtype", [BF16, jnp.float32,
+                                         jnp.float8_e4m3fn])
+def test_kv_cache_write_compiles(one_chip, t, cache_dtype):
+    """Every row tile (f32 8, bf16 16, fp8 32) and window width the serving
+    programs use; Llama-2-7B's 32 kv heads are the widest block."""
+    from distributed_llama_tpu.ops.pallas_kv_write import kv_cache_write
+
+    cache = _struct((8, 32, 4096, 128), cache_dtype, one_chip)
+    new = _struct((8, t, 32, 128), cache_dtype, one_chip)
+    pos = _struct((8,), jnp.int32, one_chip)
+    c = jax.jit(kv_cache_write, donate_argnums=(0, 1)).lower(
+        cache, cache, new, new, pos).compile()
+    assert _has_kernel(c)
+
+
 def test_whole_tp4_q80_steps_compile(topo):
     """The program `dllama api --tp 4 --buffer-float-type q80
     --serve-batch 8` mints — decode and the 32-wide slot prefill — over the
@@ -199,4 +255,5 @@ def test_whole_tp4_q80_steps_compile(topo):
         # wq=wk=wv, wo, w1=w3, w2, wcls
         assert rep["kernels"].get("q40_matmul", 0) >= 5, rep
         assert rep["kernels"].get("flash_attention", 0) >= 1, rep
+        assert rep["kernels"].get("kv_cache_write", 0) >= 1, rep
         assert rep["collectives"]["all-to-all"] > 0, rep

@@ -45,6 +45,10 @@ LLAMA2_7B = ModelSpec(arch=ArchType.LLAMA, dim=4096, hidden_dim=11008,
                       n_layers=32, n_heads=32, n_kv_heads=32,
                       vocab_size=32000, seq_len=2048,
                       hidden_act=HiddenAct.SILU)
+MISTRAL_7B = ModelSpec(arch=ArchType.LLAMA, dim=4096, hidden_dim=14336,
+                       n_layers=32, n_heads=32, n_kv_heads=8,
+                       vocab_size=32000, seq_len=4096,
+                       hidden_act=HiddenAct.SILU, rope_theta=1e6)
 MIXTRAL_8X7B = ModelSpec(arch=ArchType.MIXTRAL, dim=4096, hidden_dim=14336,
                          n_layers=32, n_heads=32, n_kv_heads=8,
                          vocab_size=32000, seq_len=2048,
@@ -163,6 +167,17 @@ def abstract_step(spec: ModelSpec, devices, *, tp: int = 1, batch: int,
 
     return (jax.jit(slot_prefill_chunk, donate_argnums=(4,)),
             (params, tokens, pos, pos, cache))
+
+
+def cache_shaped_copies(compiled_text: str, leaf_shape) -> list[str]:
+    """The `copy` instructions of a compiled module whose result has the
+    (per-device) cache leaf's shape: XLA re-laying a whole K or V cache
+    around an update of a few rows (PERF.md section 6, PR 27)."""
+    import re
+
+    dims = re.escape(f"[{','.join(map(str, leaf_shape))}]")
+    return [ln.strip() for ln in compiled_text.splitlines()
+            if re.search(rf"= \w+{dims}\S* copy\(", ln)]
 
 
 def compile_report(fn, args) -> dict:
