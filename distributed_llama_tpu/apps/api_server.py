@@ -1070,6 +1070,10 @@ def make_handler(state: ApiState):
                                     if state._fleet is not None
                                     else FleetStats().summary())
                 payload["frontdoor"] = state.frontdoor.summary()
+                from ..runtime.profiler import PROFILER
+                if PROFILER.last_counters is not None:
+                    # the serving counters at the last capture's two ends
+                    payload["capture"] = PROFILER.last_counters
                 from ..runtime.trace import TRACER
                 if TRACER.enabled:
                     payload["trace"] = TRACER.summary()
@@ -1459,8 +1463,15 @@ def make_handler(state: ApiState):
             base = state.profile_dir or tempfile.mkdtemp(prefix="dlprof-")
             target = os.path.join(base,
                                   f"profile-{int(time.time() * 1e3):x}")
+            def counters() -> dict:
+                from ..runtime.stats import WINDOW_COUNTERS
+
+                now = sup.summary() if hasattr(sup, "summary") else {}
+                return {k: now[k] for k in ("steps", *WINDOW_COUNTERS)
+                        if k in now}
+
             try:
-                out = PROFILER.capture(target, ms)
+                out = PROFILER.capture(target, ms, counters)
             except RuntimeError as e:  # a capture is already running
                 self._json(409, {"error": str(e)}, retry_after=ms / 1e3)
                 return

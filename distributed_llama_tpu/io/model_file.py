@@ -59,6 +59,29 @@ _KEYS = {
     "weights_float_type": 13,
 }
 
+# SARVAM_MLA's header keys (ModelSpec field -> key), written after the
+# fourteen above and only for that architecture. A float travels as the
+# int32 with its float32 bits (rope_theta above keeps the reference's int).
+_MLA_KEYS = {
+    "kv_lora_rank": 14, "qk_nope_head_dim": 15, "qk_rope_head_dim": 16,
+    "v_head_dim": 17, "n_dense_layers": 18, "dense_hidden_dim": 19,
+    "n_shared_experts": 20, "n_routed_experts": 21, "expert_offset": 22,
+    "routed_scaling": 23, "rms_eps": 24, "rope_factor": 25,
+    "rope_orig_len": 26, "rope_beta_fast": 27, "rope_beta_slow": 28,
+    "rope_mscale": 29, "rope_mscale_all_dim": 30,
+}
+_MLA_FLOAT_KEYS = frozenset((
+    "routed_scaling", "rms_eps", "rope_factor", "rope_beta_fast",
+    "rope_beta_slow", "rope_mscale", "rope_mscale_all_dim"))
+
+
+def _f32_bits(x: float) -> int:
+    return struct.unpack("<i", struct.pack("<f", x))[0]
+
+
+def _bits_f32(i: int) -> float:
+    return struct.unpack("<f", struct.pack("<i", i))[0]
+
 
 @dataclasses.dataclass
 class HostTensor:
@@ -93,6 +116,9 @@ def model_tensor_plan(spec: ModelSpec) -> Iterator[tuple[str, tuple[int, ...], F
     Shapes are (d, n) = (out_dim, in_dim) for matmul weights.
     """
     wt = spec.weights_float_type
+    if spec.is_mla:
+        yield from _mla_tensor_plan(spec)
+        return
     yield "tok_emb", (spec.vocab_size, spec.dim), FloatType.F32
     for l in range(spec.n_layers):
         p = f"layers.{l}."
@@ -117,6 +143,47 @@ def model_tensor_plan(spec: ModelSpec) -> Iterator[tuple[str, tuple[int, ...], F
             yield p + "rms_ffn2", (spec.dim,), FloatType.F32
     yield "rms_final", (spec.dim,), FloatType.F32
     yield "wcls", (spec.vocab_size, spec.dim), wt
+
+
+def _mla_tensor_plan(spec: ModelSpec):
+    """SARVAM_MLA's file order. Per layer: wq (H x (d_n + d_r) rows), wkva
+    (the latent's r rows, then the rope key's d_r), wkvb (per head d_n key
+    rows then d_v value rows, over the latent), wo (over H x d_v); then w1
+    w2 w3 of the dense width (the leading n_dense_layers) or moe_router
+    (router_width rows), moe_bias (f32, used for the choice only), the HELD
+    experts' up gate down, and the shared expert's sh_w1 (gate) sh_w2
+    (down) sh_w3 (up) at n_shared_experts x hidden_dim; then rms_att,
+    rms_ffn and rms_kv (the latent's norm), f32."""
+    wt, d, h = spec.weights_float_type, spec.dim, spec.n_heads
+    r, hid = spec.kv_lora_rank, spec.hidden_dim
+    yield "tok_emb", (spec.vocab_size, d), FloatType.F32
+    for l in range(spec.n_layers):
+        p = f"layers.{l}."
+        yield p + "wq", (h * spec.head_size, d), wt
+        yield p + "wkva", (r + spec.qk_rope_head_dim, d), wt
+        yield p + "wkvb", (h * (spec.qk_nope_head_dim + spec.v_head_dim), r), wt
+        yield p + "wo", (d, h * spec.v_head_dim), wt
+        if spec.is_dense_layer(l):
+            yield p + "w1", (spec.dense_hidden_dim, d), wt
+            yield p + "w2", (d, spec.dense_hidden_dim), wt
+            yield p + "w3", (spec.dense_hidden_dim, d), wt
+        else:
+            yield p + "moe_router", (spec.router_width, d), wt
+            yield p + "moe_bias", (spec.router_width,), FloatType.F32
+            for e in range(spec.n_experts):
+                yield p + f"experts.{e}.up", (hid, d), wt
+                yield p + f"experts.{e}.gate", (hid, d), wt
+                yield p + f"experts.{e}.down", (d, hid), wt
+            if spec.n_shared_experts:
+                sh = spec.n_shared_experts * hid
+                yield p + "sh_w1", (sh, d), wt
+                yield p + "sh_w2", (d, sh), wt
+                yield p + "sh_w3", (sh, d), wt
+        yield p + "rms_att", (d,), FloatType.F32
+        yield p + "rms_ffn", (d,), FloatType.F32
+        yield p + "rms_kv", (r,), FloatType.F32
+    yield "rms_final", (d,), FloatType.F32
+    yield "wcls", (spec.vocab_size, d), wt
 
 
 def _tensor_bytes(shape: tuple[int, ...], ftype: FloatType) -> int:
@@ -145,7 +212,7 @@ def read_spec(path: str, weights_float_type: FloatType | None = None) -> ModelSp
             header_size = struct.unpack("<i", f.read(4))[0]
             data = f.read(header_size - 8)
             n_kv = len(data) // 8
-            inv = {v: k for k, v in _KEYS.items()}
+            inv = {v: k for k, v in {**_KEYS, **_MLA_KEYS}.items()}
             for i in range(n_kv):
                 k, v = struct.unpack_from("<ii", data, i * 8)
                 fields[inv[k]] = v
@@ -180,6 +247,8 @@ def read_spec(path: str, weights_float_type: FloatType | None = None) -> ModelSp
         rope_theta=rope_theta,
         weights_float_type=wt,
         version=version,
+        **{k: (_bits_f32(fields[k]) if k in _MLA_FLOAT_KEYS else fields[k])
+           for k in _MLA_KEYS if k in fields},
     )
     spec.validate()
     object.__setattr__(spec, "_header_size", header_size)
@@ -267,6 +336,11 @@ def write_header(f, spec: ModelSpec) -> None:
     data = b""
     for key, value in params.items():
         data += struct.pack("<ii", _KEYS[key], value)
+    if spec.is_mla:
+        for key, k in _MLA_KEYS.items():
+            value = getattr(spec, key)
+            data += struct.pack("<ii", k, _f32_bits(value)
+                                if key in _MLA_FLOAT_KEYS else value)
     f.write(struct.pack("<i", MAGIC_KV))
     f.write(struct.pack("<i", 8 + len(data)))
     f.write(data)

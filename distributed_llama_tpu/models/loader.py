@@ -460,6 +460,8 @@ def load_params_streamed(
         peak = max(peak, live)
         dest, stage = target(t.name)
         group = _fuse_group(key) if fuse else None
+        if group == "wqkv" and spec.is_mla:
+            group = None  # wq stands alone: no wk/wv to fuse it with
 
         if group is not None:
             gk = f"{t.name.rsplit('.', 1)[0]}.{group}"
@@ -475,7 +477,7 @@ def load_params_streamed(
                 live -= sum(_host_bytes(x) for x in ts)
             continue
 
-        if key.startswith("moe_") and key != "moe_router":
+        if key.startswith("moe_") and key not in ("moe_router", "moe_bias"):
             # experts stream in (up, gate, down) x E order; stack per role
             gk = f"{t.name.rsplit('.', 2)[0]}.{key}"
             pending.setdefault(gk, []).append(t)
@@ -488,7 +490,18 @@ def load_params_streamed(
                 live -= sum(_host_bytes(x) for x in ts)
             continue
 
-        if key in ("rms_att", "rms_ffn", "rms_moe", "rms_ffn2", "rms_final"):
+        if key == "wkvb":
+            # the latent's up-projection serves absorbed attention as two
+            # dense per-head operands (models/params.split_wkvb)
+            from .params import split_wkvb
+
+            assert stage is None, "SARVAM_MLA does not support --pp"
+            for name, half in zip(("w_uk", "w_uv"),
+                                  split_wkvb(spec, t.to_f32())):
+                arr = placer.dense(name, half)
+                dest[name] = arr.astype(dtype) if dtype != jnp.float32 else arr
+        elif key in ("rms_att", "rms_ffn", "rms_moe", "rms_ffn2", "rms_final",
+                     "rms_kv", "moe_bias"):
             if stage is not None:  # per-layer norms stack too, kept f32
                 pp_stack.add(dest, key, stage, "dense", dtype, [t],
                              keep_f32=True)

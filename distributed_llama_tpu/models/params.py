@@ -40,6 +40,19 @@ def _to_q40_host(x: np.ndarray) -> HostTensor:
     return t
 
 
+def split_wkvb(spec: ModelSpec, wkvb: np.ndarray):
+    """The latent's up-projection (H x (d_n + d_v), r), dequantized, as the
+    two operands of absorbed attention: W_uk (H, d_n, r), which folds into
+    the query, and W_uv (H, d_v, r), which unfolds the attended latent.
+    Kept dense in the compute dtype: they are batched per-head
+    contractions, not Q40 row matmuls, and the packed wkvb is dropped."""
+    per = wkvb.reshape(spec.n_heads,
+                       spec.qk_nope_head_dim + spec.v_head_dim,
+                       spec.kv_lora_rank)
+    return (np.ascontiguousarray(per[:, :spec.qk_nope_head_dim]),
+            np.ascontiguousarray(per[:, spec.qk_nope_head_dim:]))
+
+
 def load_params(
     spec: ModelSpec,
     tensors: dict[str, HostTensor],
@@ -85,9 +98,27 @@ def load_params(
         if spec.arch == ArchType.GROK1:
             lw["rms_moe"] = dev(f"layers.{l}.rms_moe", tensors[f"layers.{l}.rms_moe"].to_f32())
             lw["rms_ffn2"] = dev(f"layers.{l}.rms_ffn2", tensors[f"layers.{l}.rms_ffn2"].to_f32())
-        for w in ("wq", "wk", "wv", "wo"):
-            lw[w] = weight(tensors[f"layers.{l}.{w}"], f"layers.{l}.{w}")
-        if spec.is_moe:
+        if spec.is_mla:
+            lw["rms_kv"] = dev(f"layers.{l}.rms_kv",
+                               tensors[f"layers.{l}.rms_kv"].to_f32())
+            for w in ("wq", "wkva", "wo"):
+                lw[w] = weight(tensors[f"layers.{l}.{w}"], f"layers.{l}.{w}")
+            w_uk, w_uv = split_wkvb(
+                spec, tensors[f"layers.{l}.wkvb"].to_f32())
+            lw["w_uk"] = dev(f"layers.{l}.w_uk", w_uk.astype(dtype))
+            lw["w_uv"] = dev(f"layers.{l}.w_uv", w_uv.astype(dtype))
+        else:
+            for w in ("wq", "wk", "wv", "wo"):
+                lw[w] = weight(tensors[f"layers.{l}.{w}"],
+                               f"layers.{l}.{w}")
+        if not spec.is_dense_layer(l):
+            if spec.is_mla:
+                lw["moe_bias"] = dev(f"layers.{l}.moe_bias",
+                                     tensors[f"layers.{l}.moe_bias"].to_f32())
+                if spec.n_shared_experts:
+                    for w in ("sh_w1", "sh_w2", "sh_w3"):
+                        lw[w] = weight(tensors[f"layers.{l}.{w}"],
+                                       f"layers.{l}.{w}")
             lw["moe_router"] = dev(
                 f"layers.{l}.moe_router",
                 tensors[f"layers.{l}.moe_router"].to_f32().astype(dtype))
@@ -126,7 +157,7 @@ def fuse_layer_weights(params: dict) -> dict:
     are actually freed even while the caller still holds the params dict
     (at 7B Q40 they are ~2.5 GB of HBM)."""
     for lw in params["layers"]:
-        if "wq" in lw:
+        if "wq" in lw and "wk" in lw:  # SARVAM_MLA has wq and no wk/wv
             lw["wqkv"] = _concat_weights([lw.pop("wq"), lw.pop("wk"), lw.pop("wv")])
         if "w1" in lw:
             lw["w13"] = _concat_weights([lw.pop("w1"), lw.pop("w3")])

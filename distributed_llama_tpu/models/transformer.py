@@ -1,4 +1,4 @@
-"""Unified transformer forward for LLAMA / MIXTRAL / GROK1.
+"""Unified transformer forward for LLAMA / MIXTRAL / GROK1 / SARVAM_MLA.
 
 One jittable segment-forward covers both prefill (T tokens at once — net-new
 vs the reference, which feeds the prompt token-by-token) and decode (T=1).
@@ -77,15 +77,19 @@ class KVCache(NamedTuple):
         (pp, B, KVH, S, hs), the stage axis sharded over pp so each device
         stores only its own layers' cache (parallel/pp.py)."""
         s = seq_len or spec.seq_len
-        shape = (batch, spec.n_kv_heads, s, spec.head_size)
+        shape = (batch, spec.n_kv_heads, s, spec.cache_head_size)
         n = spec.n_layers
         if pp > 1:
             assert n % pp == 0, (n, pp)
             shape = (pp,) + shape
             n = n // pp
+        # the latent cache (SARVAM_MLA) is ONE leaf a layer, (B, 1, S,
+        # r + d_r): the normed latent is key and value at once, so `v` is
+        # empty
         return cls(
             tuple(jnp.zeros(shape, dtype) for _ in range(n)),
-            tuple(jnp.zeros(shape, dtype) for _ in range(n)),
+            tuple(jnp.zeros(shape, dtype) for _ in range(n))
+            if spec.cache_v_head_size else (),
         )
 
 
@@ -121,7 +125,8 @@ def _scatter_cache_write(k_cache, v_cache, k, v, idx, write_gate,
     if write_gate is not None:
         idx = jnp.where(write_gate, idx, oob)
     k = _to_cache_dtype(k, k_cache.dtype)
-    v = _to_cache_dtype(v, v_cache.dtype)
+    if v_cache is not None:    # None: a cache of one leaf (the latent cache)
+        v = _to_cache_dtype(v, v_cache.dtype)
     if kernel_cfg is not None and kernel_cfg.get("use_pallas"):
         from ..ops.pallas_kv_write import kv_cache_write, kv_write_supported
 
@@ -140,8 +145,86 @@ def _scatter_cache_write(k_cache, v_cache, k, v, idx, write_gate,
                                   interpret=interpret)
     bidx = jnp.arange(k_cache.shape[0], dtype=jnp.int32)[:, None]
     k_cache = k_cache.at[bidx, :, idx].set(k, mode="drop")
-    v_cache = v_cache.at[bidx, :, idx].set(v, mode="drop")
+    if v_cache is not None:
+        v_cache = v_cache.at[bidx, :, idx].set(v, mode="drop")
     return k_cache, v_cache
+
+
+def _mla_attention_block(x, lw, spec: ModelSpec, cache, q_pos, cfg,
+                         per_row_pos=False, write_gate=None):
+    """Latent attention in the absorbed form, for decode and chunks alike.
+
+    u = norm(x); q = Wq u, per head [q_n ; q_r]; [c ; k_r] = Wkva u;
+    c~ = norm(c; g_kv); q_r, k_r rotated (ONE k_r for all heads). The cache
+    row is [c~ ; rot(k_r)]. Absorbed: q^_h = W_uk,h^T q_n,h, score =
+    (q^_h . c~ + q_r,h . k_r) * scale, o^_h = sum a c~, o_h = W_uv,h o^_h —
+    the per-head keys and values W_kvb c~ are never built, so a cached
+    token costs its 2 * (r + d_r + r) FLOPs a head and no up-projection.
+    Returns (wo projection not yet added to the residual, new cache leaf).
+    """
+    from ..ops.rope import rope_yarn
+
+    b, t, _ = x.shape
+    h, r = spec.n_heads, spec.kv_lora_rank
+    d_n, d_r = spec.qk_nope_head_dim, spec.qk_rope_head_dim
+    eps = spec.norm_eps
+
+    u = rmsnorm(x, lw["rms_att"], eps)
+    q = matmul(u, lw["wq"], **cfg).reshape(b, t, h, d_n + d_r)
+    kva = matmul(u, lw["wkva"], **cfg)                       # (B, T, r + d_r)
+    c = rmsnorm(kva[..., :r], lw["rms_kv"], eps)
+    q_r = rope_yarn(q[..., d_n:], q_pos, spec)
+    k_r = rope_yarn(kva[..., None, r:], q_pos, spec)[..., 0, :]
+    new = jnp.concatenate([c, k_r.astype(c.dtype)], axis=-1)[:, :, None, :]
+
+    with jax.named_scope("mla_absorb"):
+        q_abs = jnp.einsum("bthn,hnr->bthr", q[..., :d_n], lw["w_uk"],
+                           preferred_element_type=q.dtype)
+        q_abs = jnp.concatenate([q_abs, q_r], axis=-1)
+    scale = spec.attn_softmax_scale
+    # a mesh (dp replicas of the batch) keeps the XLA forms: GSPMD cannot
+    # partition a pallas_call, and nothing here is split over tp
+    if (per_row_pos and cfg.get("use_pallas") and cfg.get("tp_mesh") is None
+            and _mla_kernels_ok(t, h, cache.shape[2])):
+        # both kernels take the leaf as the TPU holds it: sequence minor,
+        # a token a column (ops/pallas_kv_write.py says why); these two
+        # transposes are bitcasts of that layout
+        from ..ops.pallas_attention import mla_attention
+        from ..ops.pallas_kv_write import kv_cache_write_seq_minor
+
+        assert write_gate is None
+        interpret = cfg.get("pallas_interpret", False)
+        cache_t = kv_cache_write_seq_minor(
+            cache.transpose(0, 1, 3, 2), _to_cache_dtype(new, cache.dtype),
+            q_pos[:, 0], interpret=interpret)
+        att = mla_attention(q_abs, cache_t, q_pos, v_width=r, scale=scale,
+                            interpret=interpret)
+        cache = cache_t.transpose(0, 1, 3, 2)
+    else:
+        if per_row_pos:
+            cache, _ = _scatter_cache_write(cache, None, new, None, q_pos,
+                                            write_gate)
+        else:
+            assert write_gate is None
+            zero = jnp.int32(0)
+            cache = lax.dynamic_update_slice(
+                cache, _to_cache_dtype(new.transpose(0, 2, 1, 3),
+                                       cache.dtype),
+                (zero, zero, q_pos[0, 0], zero))
+        att = decode_attention(q_abs, cache, cache[..., :r], q_pos,
+                               scale=scale)                  # (B, T, H, r)
+    with jax.named_scope("mla_absorb"):
+        o = jnp.einsum("bthr,hvr->bthv", att, lw["w_uv"],
+                       preferred_element_type=x.dtype)
+    out = matmul(o.reshape(b, t, h * spec.v_head_dim), lw["wo"], **cfg)
+    return out, cache
+
+
+def _mla_kernels_ok(t: int, h: int, seq_len: int) -> bool:
+    from ..ops.pallas_attention import mla_supported
+    from ..ops.pallas_kv_write import kv_write_seq_minor_supported
+
+    return mla_supported(t, h) and kv_write_seq_minor_supported(seq_len)
 
 
 def _attention_block(x, lw, spec: ModelSpec, k_cache, v_cache, q_pos, cfg,
@@ -321,19 +404,39 @@ def _moe_ffn(xb, lw, spec: ModelSpec, cfg):
     b, t, d = xb.shape
     k_active = spec.n_active_experts
 
-    router_logits = matmul(xb, lw["moe_router"], **cfg)  # (B, T, E)
-    probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
-    top_p, top_idx = lax.top_k(probs, k_active)           # (B, T, K)
-    weights = top_p / top_p.sum(axis=-1, keepdims=True)   # ref: grok1-tasks.cpp:99-114
+    # experts held here: all the router's, or (SARVAM_MLA) the share
+    # [expert_offset, expert_offset + n_experts) of a wider router, whose
+    # other experts live on other chips and add nothing here
+    held_share = spec.router_width != spec.n_experts
+    with jax.named_scope("moe_router"):
+        router_logits = matmul(xb, lw["moe_router"], **cfg)  # (B, T, E)
+        if "moe_bias" in lw:
+            # sigmoid scores; the bias picks the experts and stays out of
+            # the weights, which are the scores normalised over ALL the
+            # chosen (held or not) times the routed scaling factor
+            probs = jax.nn.sigmoid(router_logits.astype(jnp.float32))
+            _, top_idx = lax.top_k(probs + lw["moe_bias"], k_active)
+            top_p = jnp.take_along_axis(probs, top_idx, axis=-1)
+            weights = (top_p / top_p.sum(axis=-1, keepdims=True)
+                       * spec.routed_scaling)
+        else:
+            probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
+            top_p, top_idx = lax.top_k(probs, k_active)           # (B, T, K)
+            weights = top_p / top_p.sum(axis=-1, keepdims=True)   # ref: grok1-tasks.cpp:99-114
 
     def scatter_weights():
         # (B, T, E) dense scatter of the normalized top-k weights (0 for
-        # inactive experts) — shared by the ep and dense-prefill paths
-        return jnp.zeros_like(probs).at[
+        # inactive experts) — shared by the ep and dense-prefill paths;
+        # cut to the held experts' columns where this chip holds a share
+        dense = jnp.zeros_like(probs).at[
             jnp.arange(b)[:, None, None],
             jnp.arange(t)[None, :, None],
             top_idx,
         ].set(weights)
+        if held_share:
+            dense = dense[..., spec.expert_offset:
+                          spec.expert_offset + spec.n_experts]
+        return dense
 
     from ..parallel.ep_moe import EpRowWeight, ep_moe_ffn
 
@@ -372,7 +475,15 @@ def _moe_ffn(xb, lw, spec: ModelSpec, cfg):
         hb = apply_hidden_act(gate, spec.hidden_act) * up
         return matmul(hb, w_down, **cfg)
 
-    if t == 1 and b == 1:
+    def with_shared(acc):
+        # the shared expert(s): every token, unweighted, every chip alike
+        if "sh_w1" not in lw:
+            return acc
+        with jax.named_scope("moe_shared"):
+            return acc + _dense_ffn(xb, {"w1": lw["sh_w1"], "w2": lw["sh_w2"],
+                                         "w3": lw["sh_w3"]}, spec, cfg)
+
+    if t == 1 and b == 1 and not held_share:
         # decode: gather only the K active experts' weights (the reference
         # likewise computes just the active experts — grok1-tasks.cpp:128-143)
         from ..ops.matmul import fused_expert_matmul
@@ -399,7 +510,7 @@ def _moe_ffn(xb, lw, spec: ModelSpec, cfg):
                     xb,
                 )
             acc = acc + weights[..., ae, None].astype(out.dtype) * out
-        return acc
+        return with_shared(acc)
 
     # prefill: dense all-expert compute, mask by routing weights
     e_weights = scatter_weights()
@@ -412,9 +523,10 @@ def _moe_ffn(xb, lw, spec: ModelSpec, cfg):
         return acc + e_weights[..., e, None].astype(out.dtype) * out
 
     acc = jnp.zeros((b, t, d), xb.dtype)
-    for e in range(spec.n_experts):
-        acc = all_experts(e, acc)
-    return acc
+    with jax.named_scope("moe_routed"):
+        for e in range(spec.n_experts):
+            acc = all_experts(e, acc)
+    return with_shared(acc)
 
 
 def _take_expert(w, e):
@@ -436,6 +548,17 @@ def _take_expert(w, e):
 
 def _layer(x, lw, spec: ModelSpec, k_cache, v_cache, q_pos, cfg, sp_mesh=None,
            sp_cache_mesh=None, per_row_pos=False, write_gate=None):
+    if spec.is_mla:
+        # pre-norm serial block over latent attention; the FFN is dense in
+        # the leading layers (w1 or fused w13 present) and experts after
+        assert sp_mesh is None and sp_cache_mesh is None
+        attn_out, k_cache = _mla_attention_block(
+            x, lw, spec, k_cache, q_pos, cfg, per_row_pos=per_row_pos,
+            write_gate=write_gate)
+        x = x + attn_out.astype(x.dtype)
+        xb = rmsnorm(x, lw["rms_ffn"], spec.norm_eps)
+        ffn = _moe_ffn if "moe_router" in lw else _dense_ffn
+        return x + ffn(xb, lw, spec, cfg).astype(x.dtype), k_cache, None
     attn_out, k_cache, v_cache = _attention_block(
         x, lw, spec, k_cache, v_cache, q_pos, cfg, sp_mesh=sp_mesh,
         sp_cache_mesh=sp_cache_mesh, per_row_pos=per_row_pos,
@@ -553,14 +676,17 @@ def forward(
         v_all = []
         for l in range(spec.n_layers):
             x, k_new, v_new = _layer(x, params["layers"][l], spec,
-                                     cache.k[l], cache.v[l], q_pos, cfg,
+                                     cache.k[l],
+                                     cache.v[l] if cache.v else None,
+                                     q_pos, cfg,
                                      sp_mesh=sp_mesh,
                                      sp_cache_mesh=sp_cache_mesh,
                                      per_row_pos=per_row_pos)
             k_all.append(k_new)
-            v_all.append(v_new)
+            if v_new is not None:
+                v_all.append(v_new)
 
-    x = rmsnorm(x, params["rms_final"])  # ref: llama2-tasks.cpp:222-234
+    x = rmsnorm(x, params["rms_final"], spec.norm_eps)  # ref: llama2-tasks.cpp:222-234
     if not logits_for_all:
         if logit_index is None:
             x = x[:, -1, :]

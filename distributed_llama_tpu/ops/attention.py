@@ -29,10 +29,14 @@ def decode_attention(
     k_cache: jnp.ndarray,  # (B, KVH, S, hs) — cache already updated at query positions
     v_cache: jnp.ndarray,  # (B, KVH, S, hs)
     q_pos: jnp.ndarray,    # (B, T) absolute position of each query token
+    scale: float | None = None,
 ) -> jnp.ndarray:
     """Causal attention of T query tokens against the full cache.
 
-    Works for decode (T=1) and chunked prefill (T>1). Returns (B, T, H, hs).
+    Works for decode (T=1) and chunked prefill (T>1). Returns (B, T, H, hs)
+    (the last dim is v_cache's where that differs from the key's: latent
+    attention hands in its one leaf as keys and its leading columns as
+    values, with its own `scale`).
     """
     b, t, h, hs = q.shape
     kvh = k_cache.shape[1]
@@ -55,7 +59,10 @@ def decode_attention(
     # scores: (B, T, KVH, G, S)
     scores = jnp.einsum("btkgh,bksh->btkgs", qg, k_cache,
                         preferred_element_type=jnp.float32)
-    scores = scores / jnp.sqrt(jnp.float32(hs))
+    if scale is None:
+        scores = scores / jnp.sqrt(jnp.float32(hs))
+    else:
+        scores = scores * jnp.float32(scale)
     # causal mask: cache position s visible iff s <= q_pos
     mask = jnp.arange(s)[None, None, :] <= q_pos[..., None]  # (B, T, S)
     scores = jnp.where(mask[:, :, None, None, :], scores, NEG_INF)
@@ -63,4 +70,4 @@ def decode_attention(
     probs = probs / probs.sum(axis=-1, keepdims=True)
     out = jnp.einsum("btkgs,bksh->btkgh", probs.astype(k_cache.dtype), v_cache,
                      preferred_element_type=jnp.float32)
-    return out.reshape(b, t, h, hs).astype(q.dtype)
+    return out.reshape(b, t, h, v_cache.shape[-1]).astype(q.dtype)

@@ -14,18 +14,20 @@ from jax import lax
 RMS_EPS = 1e-5
 
 
-def rms_inv(x: jnp.ndarray) -> jnp.ndarray:
+def rms_inv(x: jnp.ndarray, eps: float = RMS_EPS) -> jnp.ndarray:
     """1/rms over the last axis, keepdims. (ref: src/funcs.cpp:94-123)"""
     xf = x.astype(jnp.float32)
     ms = jnp.mean(xf * xf, axis=-1, keepdims=True)
-    return lax.rsqrt(ms + RMS_EPS)
+    return lax.rsqrt(ms + eps)
 
 
-def rmsnorm(x: jnp.ndarray, weight: jnp.ndarray) -> jnp.ndarray:
-    """o = weight * (x / rms(x)) in f32, cast back to x.dtype.
+def rmsnorm(x: jnp.ndarray, weight: jnp.ndarray,
+            eps: float = RMS_EPS) -> jnp.ndarray:
+    """o = weight * (x / rms(x)) in f32, cast back to x.dtype; `eps` is the
+    model's own where its header states one (ModelSpec.norm_eps).
 
     (ref: src/funcs.cpp:125-145)
     """
     xf = x.astype(jnp.float32)
-    out = weight.astype(jnp.float32) * (rms_inv(xf) * xf)
+    out = weight.astype(jnp.float32) * (rms_inv(xf, eps) * xf)
     return out.astype(x.dtype)

@@ -250,6 +250,163 @@ def flash_attention(
             .reshape(b, t, h, hs))
 
 
+# -- latent (absorbed) attention over a one-leaf cache ------------------------
+
+# query rows (token x head) one grid step holds. A 32-token chunk of 64
+# heads is 2048 rows: flash_attention's one panel per head would not fit
+# VMEM (and MAX_Q_ROWS sends such a chunk to the dense path), so the rows
+# are tiled and every tile streams the cache once.
+MLA_ROW_TILE = 512
+_MLA_VMEM_BYTES = 48 * 2**20
+
+
+def mla_row_tile(rows: int) -> int:
+    if rows <= MLA_ROW_TILE:
+        return rows
+    for tr in (MLA_ROW_TILE, 256, 128):
+        if rows % tr == 0:
+            return tr
+    return 0
+
+
+def mla_supported(t: int, h: int) -> bool:
+    """Kernel precondition: the T x H query rows fall into whole tiles."""
+    return mla_row_tile(t * h) > 0
+
+
+def _mla_last(pos, i, *, tr, t, h, s):
+    """Last cache position row tile i of a slot attends. A gated slot
+    (pos >= S: its writes were dropped and its output is never read)
+    attends block 0 alone, unmasked, so that it costs one block and its
+    rows stay finite."""
+    last = pos + jnp.minimum(((i + 1) * tr - 1) // h, t - 1)
+    return jnp.where(pos >= s, 0, last)
+
+
+def _mla_kernel(pos_ref, q_ref, c_ref, out_ref, acc_ref, m_ref, l_ref,
+                *, sb, n_sb, tr, t, h, vw, scale, out_dtype):
+    """One (row tile, cache block) step of absorbed latent attention: a
+    cache row [c~ ; k_r] is the key of every head, and its first `vw`
+    columns are the value too. Same online softmax as _kernel."""
+    i, j = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+
+    pos = pos_ref[pl.program_id(0)]
+    last = _mla_last(pos, i, tr=tr, t=t, h=h, s=sb * n_sb)
+
+    @pl.when(j * sb <= last)
+    def _accumulate():
+        q = q_ref[0]                               # (TR, W)
+        c = c_ref[0]                               # (W, SB): a token a column
+        if c.dtype == F8_DTYPE:
+            c = _f8_bits_to(jax.lax.bitcast_convert_type(c, jnp.uint8),
+                            q.dtype)
+        elif c.dtype != q.dtype:
+            c = c.astype(q.dtype)
+        dot = functools.partial(
+            jax.lax.dot_general,
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.DEFAULT,
+        )
+        nn = (((1,), (0,)), ((), ()))
+        # the latent part (vw wide, whole lane tiles of q) and the rope
+        # key's narrow tail contract apart: each is a shape the MXU takes
+        scores = (dot(q[:, :vw], c[:vw], dimension_numbers=nn)
+                  + dot(q[:, vw:], c[vw:], dimension_numbers=nn)) * scale
+
+        row_pos = pos + (i * tr + jax.lax.broadcasted_iota(
+            jnp.int32, scores.shape, 0)) // h
+        s_pos = j * sb + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+        scores = jnp.where(s_pos <= row_pos, scores, NEG_INF)
+
+        m_prev = m_ref[:]
+        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(scores - m_new)
+        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        pv = dot(p.astype(c.dtype), c[:vw],
+                 dimension_numbers=(((1,), (1,)), ((), ())))
+        acc_ref[:] = acc_ref[:] * alpha + pv
+        m_ref[:] = m_new
+
+    @pl.when(j == n_sb - 1)
+    def _done():
+        out_ref[0] = (acc_ref[:] / l_ref[:]).astype(out_dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("v_width", "scale", "interpret"))
+def mla_attention(
+    q: jnp.ndarray,        # (B, T, H, W): absorbed queries [W_uk^T q_n ; q_r]
+    cache_t: jnp.ndarray,  # (B, 1, W, S): [normed latent ; rotated rope key]
+    #                        a COLUMN a token (the leaf as the TPU holds it,
+    #                        sequence minor: ops/pallas_kv_write.py)
+    q_pos: jnp.ndarray,    # (B, T) absolute positions, contiguous per row
+    *,
+    v_width: int,          # the latent's width: the value is its first rows
+    scale: float,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """Causal attention of T x H query rows over ONE latent cache leaf
+    (handed in sequence-minor, as kv_cache_write_seq_minor leaves it),
+    already written at the chunk's positions; returns the attended latents
+    (B, T, H, v_width), which W_uv unfolds outside. The cache is read up
+    to the last query position of each row tile (the clamp of
+    flash_attention) once per tile."""
+    b, t, h, w = q.shape
+    s = cache_t.shape[3]
+    assert cache_t.shape[1:3] == (1, w), cache_t.shape
+    rows = t * h
+    tr = mla_row_tile(rows)
+    assert tr > 0, (t, h)
+    sb = _block_s(s)
+    n_sb = s // sb
+
+    from .attention import is_narrow_cache
+
+    if not is_narrow_cache(cache_t.dtype):
+        q = q.astype(cache_t.dtype)
+    qh = q.reshape(b, rows, w)
+    ch = cache_t.reshape(b, w, s)
+    pos = q_pos[:, 0].astype(jnp.int32)
+
+    def c_index(bi, i, j, pos_ref):
+        last = _mla_last(pos_ref[bi], i, tr=tr, t=t, h=h, s=s)
+        return (bi, 0, jnp.minimum(j, last // sb))
+
+    out = pl.pallas_call(
+        functools.partial(
+            _mla_kernel, sb=sb, n_sb=n_sb, tr=tr, t=t, h=h, vw=v_width,
+            scale=scale, out_dtype=q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, rows // tr, n_sb),
+            in_specs=[
+                pl.BlockSpec((1, tr, w), lambda bi, i, j, p: (bi, i, 0)),
+                pl.BlockSpec((1, w, sb), c_index),
+            ],
+            out_specs=pl.BlockSpec((1, tr, v_width),
+                                   lambda bi, i, j, p: (bi, i, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((tr, v_width), jnp.float32),
+                pltpu.VMEM((tr, 1), jnp.float32),
+                pltpu.VMEM((tr, 1), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, rows, v_width), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_MLA_VMEM_BYTES),
+        interpret=interpret,
+        name="mla_attention",
+    )(pos, qh, ch)
+    return out.reshape(b, t, h, v_width)
+
+
 def flash_decode_attention(
     q: jnp.ndarray,        # (B, T=1, H, hs)
     k_cache: jnp.ndarray,

@@ -145,3 +145,86 @@ def kv_cache_write(
         interpret=interpret,
         name="kv_cache_write",
     )(pos, k_cache, v_cache, k_new, v_new)
+
+
+# -- a cache leaf whose SEQUENCE is the minor dimension ----------------------
+#
+# The latent cache's rows are 576 wide (SARVAM_MLA: 512 + 64), four and a
+# half lane tiles, and the TPU's own layout for a (B, 1, S, 576) array puts
+# S, not 576, in the lanes (no padding that way; row-major would pad every
+# row to 640). A pallas_call on the row-major view would make XLA re-lay
+# the whole leaf before and after it, the very copies this file removed.
+# So the step programs hand the kernels the leaf as XLA holds it, viewed
+# (B, KVH, W, S) — the transpose is a bitcast — and a token is a COLUMN:
+# the same read-modify-write, over 128-lane tiles of the sequence.
+
+LANES = 128
+
+
+def kv_write_seq_minor_supported(seq_len: int) -> bool:
+    return seq_len % LANES == 0
+
+
+def _seq_minor_kernel(pos_ref, c_ref, w_ref, o_ref, *, last, t):
+    b, j = pl.program_id(0), pl.program_id(1)
+    pos = pos_ref[b]
+    tile = jnp.clip(pos // LANES + j, 0, last)
+    lane = tile * LANES + jax.lax.broadcasted_iota(
+        jnp.int32, c_ref.shape[1:], 1)
+    hit = (lane >= pos) & (lane < pos + t)
+    # decode's one column broadcasts over the tile's lanes
+    o_ref[0] = jnp.where(hit, w_ref[0], c_ref[0])
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def kv_cache_write_seq_minor(
+    cache_t: jnp.ndarray,  # (B, KVH, W, S): a leaf, sequence minor
+    new: jnp.ndarray,      # (B, T, KVH, W), already in the cache dtype
+    pos: jnp.ndarray,      # (B,) first position row b writes; >= 0
+    interpret: bool = False,
+):
+    """kv_cache_write for ONE leaf held sequence-minor: row b's T new rows
+    become columns pos[b] .. pos[b]+T-1; positions >= S are dropped.
+    Returns the leaf, aliased onto the input."""
+    b, kvh, w, s = cache_t.shape
+    t = new.shape[1]
+    assert kv_write_seq_minor_supported(s), s
+    assert new.dtype == cache_t.dtype, (new.dtype, cache_t.dtype)
+    n = _n_tiles(t, LANES)
+    last = s // LANES - 1
+    pos = pos.astype(jnp.int32)
+    new = new.reshape(b, t, kvh * w).transpose(0, 2, 1)      # (B, KW, T)
+
+    def cache_index(i, j, p):
+        return (i, 0, jnp.clip(p[i] // LANES + j, 0, last))
+
+    if t == 1:
+        window_block = pl.BlockSpec((1, kvh * w, 1), lambda i, j, p: (i, 0, 0))
+    else:
+        src = _window_tokens(pos % LANES, n * LANES, t)      # (B, lanes, 1)
+        new = jnp.take_along_axis(new, src.transpose(0, 2, 1), axis=2)
+
+        def window_index(i, j, p):
+            first = p[i] // LANES
+            return (i, 0, jnp.clip(jnp.clip(first + j, 0, last) - first,
+                                   0, n - 1))
+
+        window_block = pl.BlockSpec((1, kvh * w, LANES), window_index)
+    cache_block = pl.BlockSpec((1, kvh * w, LANES), cache_index)
+    flat = cache_t.reshape(b, kvh * w, s)
+    out = pl.pallas_call(
+        functools.partial(_seq_minor_kernel, last=last, t=t),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, n),
+            in_specs=[cache_block, window_block],
+            out_specs=cache_block,
+        ),
+        out_shape=jax.ShapeDtypeStruct(flat.shape, flat.dtype),
+        input_output_aliases={1: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="kv_cache_write",
+    )(pos, flat, new)
+    return out.reshape(cache_t.shape)

@@ -56,6 +56,15 @@ _SPLIT = {
     "wo": "col",
     "w2": "col",
     "wcls": "row",  # vocab-sharded logits (net-new vs reference root-only wcls)
+    # SARVAM_MLA (single shard or dp only: Engine refuses it under tp/pp/sp)
+    "rms_kv": None,
+    "moe_bias": None,
+    "wkva": None,
+    "w_uk": None,
+    "w_uv": None,
+    "sh_w1": None,
+    "sh_w2": None,
+    "sh_w3": None,
 }
 
 

@@ -66,20 +66,27 @@ def seed_rows_from_blocks(cache: KVCache, arena_k, arena_v, row, block_ids
     from ..ops.pallas_attention import saturate_f8_nan_codes
 
     mb = block_ids.shape[0]
-    _, _, kvh, bl, hs = arena_k.shape
     z = jnp.int32(0)
     row = jnp.asarray(row, jnp.int32)
-    k_all, v_all = [], []
+    # a cache without V leaves (the latent cache) seeds its K leaves only;
+    # its V arena is zero wide
+    arenas = (arena_k, arena_v) if cache.v else (arena_k,)
+    new: tuple = ([], [])
     for l in range(len(cache.k)):
-        new = []
-        for arena, leaf in ((arena_k, cache.k[l]), (arena_v, cache.v[l])):
-            seg = arena[block_ids, l]                  # (MB, KVH, bl, hs)
+        for arena, leaves, out in zip(arenas, cache, new):
+            if arena.ndim == 3:     # flat blocks (Engine.new_prefix_arena)
+                kvh, hs = leaves[l].shape[1], leaves[l].shape[3]
+                bl = arena.shape[2] // hs
+                seg = arena[block_ids, l * kvh:(l + 1) * kvh].reshape(
+                    mb, kvh, bl, hs)
+            else:
+                _, _, kvh, bl, hs = arena.shape
+                seg = arena[block_ids, l]              # (MB, KVH, bl, hs)
             seg = seg.transpose(1, 0, 2, 3).reshape(1, kvh, mb * bl, hs)
-            seg = saturate_f8_nan_codes(seg.astype(leaf.dtype))
-            new.append(lax.dynamic_update_slice(leaf, seg, (row, z, z, z)))
-        k_all.append(new[0])
-        v_all.append(new[1])
-    return KVCache(tuple(k_all), tuple(v_all))
+            seg = saturate_f8_nan_codes(seg.astype(leaves[l].dtype))
+            out.append(lax.dynamic_update_slice(leaves[l], seg,
+                                                (row, z, z, z)))
+    return KVCache(tuple(new[0]), tuple(new[1]))
 
 
 def export_arena_block(arena_k, arena_v, src):
@@ -95,6 +102,17 @@ def export_arena_block(arena_k, arena_v, src):
             lax.dynamic_index_in_dim(arena_v, src, 0, keepdims=False))
 
 
+def flat_arena(spec: ModelSpec) -> bool:
+    """Whether the prefix arena holds a block's rows FLAT, (num_blocks,
+    layers x kv_heads, block_len x width): for a cache row that is not
+    whole 128-lane tiles (the latent cache's 576). The TPU's own layout
+    of the five-dimensional (num_blocks, layers, 1, 32, 576) puts
+    num_blocks in the lanes, and one block's write then strides over the
+    whole arena (2.85 ms a block on the v5e, 30 % of the device's time
+    under long prompts); flat, a block is contiguous."""
+    return spec.cache_head_size % 128 != 0
+
+
 def import_arena_block(arena_k, arena_v, k_blk, v_blk, dst):
     """Write one fetched block pair into arena slot ``dst`` — the traced
     body of ``Engine.slot_import_block``. The arenas are donated
@@ -103,12 +121,12 @@ def import_arena_block(arena_k, arena_v, k_blk, v_blk, dst):
     (seed_rows_from_blocks -> saturate_f8_nan_codes) runs when a slot is
     SEEDED from the block, so foreign bytes can never decode as finite
     480 in an attention read whatever their producer did."""
-    z = jnp.int32(0)
     dst = jnp.asarray(dst, jnp.int32)
-    return (lax.dynamic_update_slice(arena_k, k_blk[None],
-                                     (dst, z, z, z, z)),
-            lax.dynamic_update_slice(arena_v, v_blk[None],
-                                     (dst, z, z, z, z)))
+    return tuple(
+        lax.dynamic_update_slice(
+            arena, blk.reshape((1,) + arena.shape[1:]),   # flat arenas too
+            (dst,) + (jnp.int32(0),) * (arena.ndim - 1))
+        for arena, blk in ((arena_k, k_blk), (arena_v, v_blk)))
 
 
 class Engine:
@@ -173,6 +191,13 @@ class Engine:
             params = replicate_kv_heads(params, spec, tp)
             spec = dataclasses.replace(spec, n_kv_heads=tp)
         self.spec = spec
+        if spec.is_mla:
+            # one latent head and per-head absorb operands: nothing to
+            # split over tp, and the manual pp/sp/ep regions do not know
+            # the latent block; dp replicas are the way to more chips
+            assert mesh is None or all(
+                mesh.shape.get(a, 1) == 1 for a in ("tp", "pp", "sp", "ep")), (
+                "SARVAM_MLA runs on one shard (dp only)")
         # --buffer-float-type q80 with tp>1 => wo/w2 partial sums exchange
         # int8 blocks over ICI instead of the GSPMD-exact f32 all-reduce
         # (the reference's wire compression, ref: src/tasks.cpp:124-163)
@@ -387,8 +412,10 @@ class Engine:
             n_l = self.spec.n_layers
             if self._pp > 1:  # stage-stacked: n_layers/pp leaves (pp, ...)
                 n_l //= self._pp
-            shardings = KVCache((self._cache_sharding,) * n_l,
-                                (self._cache_sharding,) * n_l)
+            shardings = KVCache(
+                (self._cache_sharding,) * n_l,
+                (self._cache_sharding,) * (
+                    n_l if self.spec.cache_v_head_size else 0))
             self._mint("cache_maker", jax.jit(
                 lambda: KVCache.create(self.spec, self.batch, self.seq_len,
                                        self.cache_dtype, pp=self._pp),
@@ -422,8 +449,8 @@ class Engine:
             "tokens": np.asarray(tokens if tokens is not None else [],
                                  np.int32),
         }
-        for l in range(self.spec.n_layers):
-            for name, leaf in (("k", self.cache.k[l]), ("v", self.cache.v[l])):
+        for name, leaves in (("k", self.cache.k), ("v", self.cache.v)):
+            for l, leaf in enumerate(leaves):
                 arr = np.asarray(leaf[:, :, : self.pos, :])
                 if arr.dtype.itemsize == 1:
                     arr = arr.view(np.uint8)
@@ -466,8 +493,7 @@ class Engine:
         # cache rows are built ON DEVICE through the shared seeding
         # helper (_seed_jit / _seed_guard — one home for the
         # donation-safety fix and the f8 NaN-code guard)
-        shape = (self.batch, self.spec.n_kv_heads, self.seq_len,
-                 self.spec.head_size)
+        lead = (self.batch, self.spec.n_kv_heads, self.seq_len)
         # ledger-watched but NOT cached in _steps: each restore builds a
         # fresh closure (no reuse across calls is possible), so storing
         # it would only pin one dead executable per distinct pos for the
@@ -476,14 +502,13 @@ class Engine:
 
         build = COMPILES.watch(self, ("session_restore", pos),
                                self._seed_jit(
-            lambda pfx: jnp.zeros(shape, dt).at[:, :, :pos, :].set(
-                self._seed_guard(pfx)),
+            lambda pfx: jnp.zeros(lead + pfx.shape[3:], dt)
+            .at[:, :, :pos, :].set(self._seed_guard(pfx)),
             out_tree=0))
-        k_all, v_all = [], []
-        for l in range(self.spec.n_layers):
-            k_all.append(build(z[f"k{l}"].view(dt)))
-            v_all.append(build(z[f"v{l}"].view(dt)))
-        self.cache = KVCache(tuple(k_all), tuple(v_all))
+        self.cache = KVCache(*(
+            tuple(build(z[f"{name}{l}"].view(dt))
+                  for l in range(len(leaves)))
+            for name, leaves in (("k", self.cache.k), ("v", self.cache.v))))
         self.pos = pos
         return z["tokens"].tolist() if "tokens" in z.files else []
 
@@ -539,7 +564,9 @@ class Engine:
         sp = self.spec
         return [zlib.crc32(repr((sp.arch, sp.dim, sp.hidden_dim, sp.n_layers,
                                  sp.n_heads, sp.n_kv_heads,
-                                 sp.head_size)).encode()),
+                                 sp.head_size) + (
+                                     (sp.cache_head_size,)
+                                     if sp.is_mla else ())).encode()),
                 self.batch, self.seq_len,
                 zlib.crc32(jnp.dtype(self.cache_dtype).name.encode()),
                 self.model_fingerprint]
@@ -1587,14 +1614,27 @@ class Engine:
         empty tree (runtime/resilience.EngineSupervisor._make_sched)."""
         assert self._pp == 1, "prefix cache does not support --pp"
         assert num_blocks >= 1 and 1 <= block_len <= self.seq_len
-        shape = (num_blocks, self.spec.n_layers, self.spec.n_kv_heads,
-                 block_len, self.spec.head_size)
+        lead = (num_blocks, self.spec.n_layers, self.spec.n_kv_heads,
+                block_len)
+        # a cache without V leaves keeps its V arena, zero wide: every
+        # caller hands the pair on, and it holds no byte
+        shape = lead + (self.spec.cache_head_size,)
+        shape_v = lead + (self.spec.cache_v_head_size,)
+        if flat_arena(self.spec):
+            shape, shape_v = (
+                (num_blocks, sh[1] * sh[2], block_len * sh[4])
+                for sh in (shape, shape_v))
         dt = self.cache_dtype
         key = ("prefix_arena", shape)
         if key not in self._steps:
             self._mint(key, jax.jit(
-                lambda: (jnp.zeros(shape, dt), jnp.zeros(shape, dt))))
+                lambda: (jnp.zeros(shape, dt), jnp.zeros(shape_v, dt))))
         return self._steps[key]()
+
+    def _arena_block_len(self, arena_k) -> int:
+        if arena_k.ndim == 3:       # flat blocks: flat_arena()
+            return arena_k.shape[2] // self.spec.cache_head_size
+        return arena_k.shape[3]
 
     def slot_seed_prefix(self, arena_k, arena_v, row: int,
                          block_ids: np.ndarray) -> None:
@@ -1608,7 +1648,7 @@ class Engine:
         invariant and the f8 seeding guard; _seed_jit for the
         donation-safety/out_shardings discipline. Does not touch
         self.pos (per-slot positions are the scheduler's)."""
-        mb, bl = block_ids.shape[0], arena_k.shape[3]
+        mb, bl = block_ids.shape[0], self._arena_block_len(arena_k)
         key = ("slot_seed", mb, bl)
         if key not in self._steps:
             run = seed_rows_from_blocks
@@ -1628,21 +1668,24 @@ class Engine:
         executables however requests finish. The copied bytes came from
         this engine's own saturating cache writes — the NaN-code guard
         runs on the SEED side, where the producer cannot be trusted."""
-        bl = arena_k.shape[3]
-        kvh, hs = self.spec.n_kv_heads, self.spec.head_size
-        n_l = self.spec.n_layers
+        bl = self._arena_block_len(arena_k)
         key = ("slot_publish", bl)
         if key not in self._steps:
             def run(arena_k, arena_v, cache, row, off, dst):
                 z = jnp.int32(0)
                 outs = []
                 for arena, leaves in ((arena_k, cache.k), (arena_v, cache.v)):
+                    if not leaves:      # no V leaf: the zero-wide V arena
+                        outs.append(arena)
+                        continue
+                    kvh, hs = leaves[0].shape[1], leaves[0].shape[3]
                     blk = jnp.stack([
-                        lax.dynamic_slice(leaves[l], (row, z, off, z),
+                        lax.dynamic_slice(leaf, (row, z, off, z),
                                           (1, kvh, bl, hs))[0]
-                        for l in range(n_l)])       # (L, KVH, bl, hs)
+                        for leaf in leaves])        # (L, KVH, bl, hs)
                     outs.append(lax.dynamic_update_slice(
-                        arena, blk[None], (dst, z, z, z, z)))
+                        arena, blk.reshape((1,) + arena.shape[1:]),
+                        (dst,) + (z,) * (arena.ndim - 1)))
                 return tuple(outs)
 
             run.__name__ = "slot_publish_block"
@@ -1660,9 +1703,19 @@ class Engine:
         compile ledger like every serving executable and warmed by
         ``PrefixCache.warmup`` when transfer is enabled, so donor
         serving mints ZERO post-warmup keys."""
-        key = ("block_export", arena_k.shape[3])
+        bl = self._arena_block_len(arena_k)
+        key = ("block_export", bl)
         if key not in self._steps:
-            self._mint(key, jax.jit(export_arena_block))
+            run = export_arena_block
+            if arena_k.ndim == 3:
+                lead = (self.spec.n_layers, self.spec.n_kv_heads, bl)
+
+                def run(arena_k, arena_v, src):
+                    return tuple(
+                        blk.reshape(lead + (blk.shape[1] // bl,))
+                        for blk in export_arena_block(arena_k, arena_v, src))
+
+            self._mint(key, jax.jit(run))
         return self._steps[key](arena_k, arena_v, jnp.int32(src))
 
     def slot_import_block(self, arena_k, arena_v, k_blk, v_blk, dst: int):
@@ -1671,7 +1724,7 @@ class Engine:
         transfer plane. Arenas donated; one compilation key per block
         length ("block_import"). See import_arena_block for why the
         bytes land raw (the seed-side f8 guard owns trust)."""
-        key = ("block_import", arena_k.shape[3])
+        key = ("block_import", self._arena_block_len(arena_k))
         if key not in self._steps:
             self._mint(key, jax.jit(import_arena_block,
                                     donate_argnums=(0, 1)))

@@ -131,11 +131,23 @@ def _mk_acct(wire, peer: int, direction: str):
 
 
 def block_payload_bytes(n_layers: int, kv_heads: int, block_len: int,
-                        head_size: int, dtype) -> int:
+                        head_size: int, dtype,
+                        v_head_size: int | None = None) -> int:
     """One block's on-the-wire K+V payload bytes — exact arithmetic the
-    reconcile tests pin the measured ledger against."""
-    one = n_layers * kv_heads * block_len * head_size
-    return 2 * one * np.dtype(dtype).itemsize
+    reconcile tests pin the measured ledger against. `v_head_size` is the
+    V half's width where it is not the K half's (0: the latent cache, whose
+    block is its one leaf)."""
+    if v_head_size is None:
+        v_head_size = head_size
+    one = n_layers * kv_heads * block_len
+    return one * (head_size + v_head_size) * np.dtype(dtype).itemsize
+
+
+def spec_block_payload_bytes(spec, block_len: int, dtype) -> int:
+    """block_payload_bytes of a model's arena block."""
+    return block_payload_bytes(spec.n_layers, spec.n_kv_heads, block_len,
+                               spec.cache_head_size, dtype,
+                               spec.cache_v_head_size)
 
 
 # -- donor side -------------------------------------------------------------
@@ -186,9 +198,8 @@ class BlockDonor:
             eng = sched.engine
             dtype_code = DTYPE_CODES.get(
                 np.dtype(eng.cache_dtype).name, 0)
-            payload = block_payload_bytes(
-                eng.spec.n_layers, eng.spec.n_kv_heads, bl,
-                eng.spec.head_size, eng.cache_dtype)
+            payload = spec_block_payload_bytes(eng.spec, bl,
+                                               eng.cache_dtype)
             if n_match <= max(n_have, 0):
                 # nothing the requester lacks — the MISS answer. The
                 # router clears its stale shadow entry off this (the
@@ -197,7 +208,7 @@ class BlockDonor:
                     st.query_misses += 1
             _send_frame(conn, RMSG_BLOCK_ACK,
                         [n_match, bl, eng.spec.n_layers,
-                         eng.spec.n_kv_heads, eng.spec.head_size,
+                         eng.spec.n_kv_heads, eng.spec.cache_head_size,
                          dtype_code, payload],
                         timeout=self._io, acct=acct_tx)
             req = _recv_frame(conn, timeout=self._io,
@@ -243,7 +254,8 @@ class BlockDonor:
 
 def fetch_prefix(host: str, port: int, tokens: list[int], n_have: int, *,
                  block_len: int, block_shape: tuple, dtype,
-                 protocol_version: int, requester: int = 0,
+                 protocol_version: int, v_head_size: int | None = None,
+                 requester: int = 0,
                  io_timeout: float = 10.0, deadline_s: float = 15.0,
                  wire=None, peer: int = 0):
     """Fetch the whole blocks of ``tokens`` beyond ``n_have`` from the
@@ -293,9 +305,13 @@ def fetch_prefix(host: str, port: int, tokens: list[int], n_have: int, *,
                     f"{CODE_DTYPES.get(dtype_code)} != local "
                     f"{want_shape}/{np.dtype(dtype).name}")
             one = n_l * kvh * bl * hs * np.dtype(dtype).itemsize
-            if payload != 2 * one:
+            # the V half is as wide as the K half, or absent (v_head_size
+            # 0: the latent cache ships its one leaf)
+            v_hs = hs if v_head_size is None else int(v_head_size)
+            if payload != one + one // hs * v_hs:
                 raise KVTransferError(
-                    f"donor payload {payload} != modeled {2 * one}")
+                    f"donor payload {payload} != modeled "
+                    f"{one + one // hs * v_hs}")
             start = max(n_have, 0) // bl
             end = n_match // bl
             _send_frame(sock, RMSG_BLOCK_FETCH, [start, end],
@@ -325,7 +341,7 @@ def fetch_prefix(host: str, port: int, tokens: list[int], n_have: int, *,
                     n_l, kvh, bl, hs)
                 v = np.frombuffer(buf[one:],
                                   dtype=np.dtype(dtype)).reshape(
-                    n_l, kvh, bl, hs)
+                    n_l, kvh, bl, v_hs)
                 blocks.append((k, v))
             if len(blocks) != end - start:
                 raise KVTransferError(
@@ -375,7 +391,8 @@ def fill_from_wire(sched, tokens: list[int], host: str, port: int,
         n_match, start, blocks = fetch_prefix(
             host, port, tokens, n_have, block_len=pc.block_len,
             block_shape=(eng.spec.n_layers, eng.spec.n_kv_heads,
-                         pc.block_len, eng.spec.head_size),
+                         pc.block_len, eng.spec.cache_head_size),
+            v_head_size=eng.spec.cache_v_head_size,
             dtype=eng.cache_dtype, protocol_version=protocol_version,
             requester=requester, io_timeout=io_timeout,
             deadline_s=deadline_s, wire=st.wire, peer=donor_peer)
@@ -385,9 +402,8 @@ def fill_from_wire(sched, tokens: list[int], host: str, port: int,
                 st.fill_misses += 1
         if not blocks:
             return verdict
-        payload = block_payload_bytes(
-            eng.spec.n_layers, eng.spec.n_kv_heads, pc.block_len,
-            eng.spec.head_size, eng.cache_dtype)
+        payload = spec_block_payload_bytes(eng.spec, pc.block_len,
+                                           eng.cache_dtype)
         with st.lock:
             st.bytes_rx += payload * len(blocks)
         got = sched.kv_import_prefix(tokens, start, blocks)
@@ -457,11 +473,8 @@ def local_fill(donor_sup, target_sup, tokens: list[int], *, stats,
             with st.lock:
                 st.queries_served += 1
             start = n_have // bl
-            payload = block_payload_bytes(
-                sched_d.engine.spec.n_layers,
-                sched_d.engine.spec.n_kv_heads, bl,
-                sched_d.engine.spec.head_size,
-                sched_d.engine.cache_dtype)
+            payload = spec_block_payload_bytes(
+                sched_d.engine.spec, bl, sched_d.engine.cache_dtype)
             blocks = []
             for i in range(start, n_match // bl):
                 blocks.append(sched_d.kv_export_block(ids[i]))

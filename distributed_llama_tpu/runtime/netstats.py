@@ -201,8 +201,7 @@ def estimate_prefix_reuse(
     per_tok_kb = estimate_decode_wire(spec, mesh, q80=q80,
                                       act_bytes=act_bytes,
                                       batch=batch).sent_kb_per_token
-    copy_b = (2 * spec.n_layers * spec.n_kv_heads * spec.head_size
-              * cache_bytes)
+    copy_b = spec.cache_values_per_token * cache_bytes
     copied = tokens_saved if tokens_copied is None else tokens_copied
     return {
         "wire_saved_kb": round(per_tok_kb * tokens_saved, 3),
@@ -247,8 +246,7 @@ def estimate_block_transfer(
 
     bl = int(block_len)
     n_blocks = max(int(tokens), 0) // bl
-    per_block = int(2 * spec.n_layers * spec.n_kv_heads * bl
-                    * spec.head_size * cache_bytes)
+    per_block = int(spec.cache_values_per_token * bl * cache_bytes)
     data_bytes = n_blocks * frame_bytes(1, per_block)
     # HELLO [v] + QUERY [requester, n_have, *tokens] + FETCH [s, e] tx;
     # HELLO_ACK [5] + ACK [7] + END [1] rx — tiny next to the payload,

@@ -391,12 +391,11 @@ def hbm_ledger(engine, prefix_cache=None, *, block_len: int | None = None,
     logits_ws = (engine.batch * spec.vocab_size * 4 // n_vshards
                  + engine.batch * engine.prefill_chunk * spec.dim
                  * compute_itemsize)
+    per_token = spec.cache_values_per_token * cache_itemsize
     per_slot = (kv // engine.batch if engine.batch else 0) or (
-        2 * spec.n_layers * spec.n_kv_heads * engine.seq_len
-        * spec.head_size * cache_itemsize)
+        engine.seq_len * per_token)
     per_block = (arena // n_blocks) if n_blocks else (
-        2 * spec.n_layers * spec.n_kv_heads * int(bl or 32)
-        * spec.head_size * cache_itemsize)
+        int(bl or 32) * per_token)
     accounted = weights + vocab_b + kv + arena + logits_ws
     dev = (device_memory_stats() if device_stats is True
            else (device_stats or None))
@@ -670,12 +669,19 @@ class Profiler:
 
     def __init__(self):
         self.captures = 0           # /admin/profile captures completed
+        # the caller's counters at the last capture's two ends, /stats
+        # `capture`: what a reader needs to set the capture's device times
+        # against the work of the SAME seconds (a closed loop's contexts
+        # swing together, so a window's mean is not the capture's)
+        self.last_counters: dict | None = None
         self._lock = threading.Lock()
         self._busy = False  # dlrace: guarded-by(self._lock)
 
-    def capture(self, directory: str, ms: float) -> dict:
+    def capture(self, directory: str, ms: float, counters=None) -> dict:
         """Write one jax.profiler trace of the next `ms` milliseconds to
-        `directory` (created). Synchronous — the caller's thread sleeps
+        `directory` (created). `counters`: a callable giving the serving
+        counters, read as the trace starts and again as it stops (before
+        the export) into `last_counters`. Synchronous — the caller's thread sleeps
         out the window (the threaded HTTP server keeps serving), so a
         200 means the trace is on disk. The Python tracer is off: with it
         on, the stop froze serving for seconds and the host plane held
@@ -700,11 +706,15 @@ class Profiler:
             opts.host_tracer_level = HOST_TRACER_LEVEL
             jax.profiler.start_trace(directory, profiler_options=opts)
             t_start = time.perf_counter()
+            at_start = counters() if counters else None
             TRACER.set_capturing(True)
             try:
                 time.sleep(max(float(ms), 0.0) / 1e3)
             finally:
                 TRACER.set_capturing(False)
+                if counters:
+                    self.last_counters = {"start": at_start,
+                                          "stop": counters()}
                 t_stop = time.perf_counter()
                 jax.profiler.stop_trace()
             stop_ms = (time.perf_counter() - t_stop) * 1e3
@@ -721,6 +731,7 @@ class Profiler:
 
     def reset(self) -> None:
         self.captures = 0
+        self.last_counters = None
 
 
 PROFILER = Profiler()

@@ -212,13 +212,12 @@ class ReplicaServer:
             self.replica_index = 0
         pc = self.sup.prefix_cache
         if pc is not None:
-            from .kv_transfer import block_payload_bytes
+            from .kv_transfer import spec_block_payload_bytes
 
             eng = self.sup.engine
             self.kvx_stats.block_len = pc.block_len
-            self.kvx_stats.block_bytes = block_payload_bytes(
-                eng.spec.n_layers, eng.spec.n_kv_heads, pc.block_len,
-                eng.spec.head_size, eng.cache_dtype)
+            self.kvx_stats.block_bytes = spec_block_payload_bytes(
+                eng.spec, pc.block_len, eng.cache_dtype)
         self._donor = BlockDonor(lambda: self.sup, self.kvx_stats,
                                  fault_key=fault_key,
                                  io_timeout=self._io)

@@ -452,6 +452,9 @@ class Scheduler:
         self._mutex = threading.RLock()  # step()/exclusive() mutual excl.
         self._wake = threading.Event()
         self.stats = ServeStats()
+        self.stats.cache_bytes_per_token = (
+            engine.spec.cache_values_per_token
+            * np.dtype(engine.cache_dtype).itemsize)
         if prefix_cache is not None:
             self.stats.prefix = prefix_cache.stats
         self.stats.admission = self.admission  # None when no SLO is set
@@ -819,6 +822,8 @@ class Scheduler:
             n = min(c, len(s.req.prompt) - s.off)
             tok[s.idx, :n] = s.req.prompt[s.off:s.off + n]
             self.stats.prefill_tokens += n
+            self.stats.attn_pairs_prefill += n * s.off + n * (n + 1) // 2
+            self.stats.prefill_cached_tokens += s.off + n
             if self.prefix_cache is not None:
                 # real (non-pad) tokens this forward actually prefills —
                 # the honest denominator for prefill_saved_frac
@@ -872,6 +877,7 @@ class Scheduler:
         for s in live:
             tok[s.idx, 0] = s.last
             pos[s.idx] = s.pos
+            self.stats.attn_pairs_decode += s.pos + 1
         logits = eng.slot_decode_step(tok, pos)
         view = self._sample_view(logits, live)
         for s in live:
@@ -969,6 +975,7 @@ class Scheduler:
             # writes sit beyond the accepted prefix and are overwritten
             # before any later query attends them)
             pos[s.idx] = s.pos
+            self.stats.attn_pairs_decode += s.pos + 1
         for s in spec_rows:
             # the scan always proposes k (one compile key); clamp to the
             # row's budget/headroom — surplus drafts become padding

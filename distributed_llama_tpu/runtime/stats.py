@@ -232,7 +232,9 @@ class StepTimelineStats:
 # PERF.md section 3 say which per-layer metric reads which)
 WINDOW_COUNTERS = ("admitted", "queue_wait_ms_sum", "prefill_steps",
                    "prefill_tokens", "decode_steps", "decode_rows",
-                   "busy_ms", "wait_ms", "host_ms")
+                   "busy_ms", "wait_ms", "host_ms",
+                   "attn_pairs_decode", "attn_pairs_prefill",
+                   "prefill_cached_tokens")
 
 
 class FrontDoorStats:
@@ -294,6 +296,15 @@ class ServeStats:
     busy_ms: float = 0.0           # wall of WORKING iterations only
     wait_ms: float = 0.0           # of it: blocked in a device fetch
     host_ms: float = 0.0           # busy less wait, summed per iteration
+    # attention's work, counted on the host where the positions are built:
+    # (query token, cached position) pairs, position + 1 a real row or token
+    attn_pairs_decode: int = 0
+    attn_pairs_prefill: int = 0
+    prefill_cached_tokens: int = 0  # cache rows a chunk's real rows attend
+    #                                 (offset + tokens, summed over rows)
+    # gauge, set by the Scheduler: cache bytes one token holds over all
+    # layers (K and V leaves, or the latent cache's one leaf)
+    cache_bytes_per_token: int = 0
     # attached by the Scheduler when the radix prefix cache is on — its
     # summary rides the same /stats payload as a `prefix_cache` block
     prefix: PrefixCacheStats | None = None
@@ -339,6 +350,7 @@ class ServeStats:
         for k in WINDOW_COUNTERS:
             v = getattr(self, k)
             out[k] = round(v, 3) if isinstance(v, float) else v
+        out["cache_bytes_per_token"] = self.cache_bytes_per_token
         if self.prefix is not None:
             out["prefix_cache"] = self.prefix.summary()
         if self.admission is not None:

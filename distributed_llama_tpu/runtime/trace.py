@@ -573,6 +573,12 @@ _COUNTERS = (
      "Of busy ms: blocked in a device fetch"),
     ("host_ms", "dllama_scheduler_host_ms_total",
      "Of busy ms: host work (busy less wait)"),
+    ("attn_pairs_decode", "dllama_attn_pairs_decode_total",
+     "Cached positions attended, summed over decoding rows"),
+    ("attn_pairs_prefill", "dllama_attn_pairs_prefill_total",
+     "Cached positions attended, summed over real prefill tokens"),
+    ("prefill_cached_tokens", "dllama_prefill_cached_tokens_total",
+     "Cache rows read by prefill chunks, summed over their real rows"),
 )
 
 _GAUGES = (

@@ -29,6 +29,26 @@ def tiny_spec(weights_float_type: FloatType = FloatType.Q40,
     return ModelSpec(**base)
 
 
+def tiny_mla_spec(weights_float_type: FloatType = FloatType.Q40,
+                  **overrides) -> ModelSpec:
+    """SARVAM_MLA at a size a CPU holds: 4 heads over a 32-wide latent, one
+    leading dense layer, then layers of 4 held experts (of 8 routed over,
+    top 4) and a shared expert; yarn past an original context of 32."""
+    base = dict(
+        arch=ArchType.SARVAM_MLA, dim=64, hidden_dim=32, n_layers=3,
+        n_heads=4, n_kv_heads=1, vocab_size=288, seq_len=160,
+        hidden_act=HiddenAct.SILU, n_experts=4, n_active_experts=4,
+        weights_float_type=weights_float_type,
+        kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        v_head_dim=16, n_dense_layers=1, dense_hidden_dim=128,
+        n_shared_experts=1, n_routed_experts=8, expert_offset=0,
+        routed_scaling=2.5, rms_eps=1e-6, rope_factor=40.0, rope_orig_len=32,
+        rope_beta_fast=32.0, rope_beta_slow=1.0, rope_mscale=1.0,
+        rope_mscale_all_dim=1.0)
+    base.update(overrides)
+    return ModelSpec(**base)
+
+
 def free_port() -> int:
     """An OS-assigned free TCP port (shared by the cluster tests, the
     chaos harness spawners, and bench's cluster row — one home for the
@@ -90,12 +110,42 @@ def write_synthetic_model(path: str, spec: ModelSpec, seed: int) -> int:
                 x = rng.standard_normal(n, dtype=np.float32) * 0.02
                 if "rms" in name:
                     x += 1.0
+                elif name.endswith("moe_bias"):
+                    # a router with preferences (std 0.5 beside scores in
+                    # (0, 1)): with a bias of 0.02 every token's eighth
+                    # and ninth expert are a near-tie, rounding sends
+                    # thousands of tokens to other experts than the
+                    # reference's, and the logits agree to 0.3 whatever
+                    # the precision (PERF.md section 6, PR 30)
+                    x *= 25.0
                 f.write(x.tobytes())
             elif ftype == FloatType.Q40:
                 nb = n // BLOCK_SIZE
                 raw = np.empty((nb, Q40_BLOCK_BYTES), np.uint8)
-                scales = rng.uniform(0.005, 0.02, nb).astype(np.float16)
+                # SARVAM_MLA: scales at which attention scores have a
+                # std of ~3 over 4096 columns, where a check on the logits
+                # tells an fp8 cache from a bf16 one (below)
+                scales = rng.uniform(
+                    *((0.0035, 0.008) if spec.is_mla else (0.005, 0.02)),
+                    nb).astype(np.float16)
                 raw[:, :2] = scales.reshape(nb, 1).view(np.uint8)
+                if spec.is_mla:
+                    # nibbles 1..15, so that a weight (nibble - 8) x scale
+                    # has mean ZERO. Uniform bytes (below) give every
+                    # matrix a mean of -0.5 x scale, a rank-one part that
+                    # outweighs the random part at these widths: the
+                    # residual stream collapses onto +-ones, the logits
+                    # hardly depend on the prompt, and a check on them is
+                    # blind (an fp8 cache and a missing router bias both
+                    # passed; PERF.md section 6, PR 30, has the readings
+                    # of every scale range and bias tried). The older
+                    # architectures keep their bytes: their files, hashes
+                    # and verdicts are recorded.
+                    lo, hi = (rng.integers(1, 16, (nb, Q40_BLOCK_BYTES - 2),
+                                           dtype=np.uint8) for _ in "lh")
+                    raw[:, 2:] = lo | (hi << 4)
+                    f.write(raw.tobytes())
+                    continue
                 raw[:, 2:] = rng.integers(
                     0, 256, (nb, Q40_BLOCK_BYTES - 2), dtype=np.uint8)
                 f.write(raw.tobytes())

@@ -224,6 +224,51 @@ def test_served_mixtral_prefill_chunk_fits_scoped_vmem(topo):
     assert _has_kernel(fn.lower(*args).compile())
 
 
+@pytest.mark.parametrize("t", [1, 32], ids=["decode", "chunk32"])
+def test_served_sarvam_mla_step_programs_hold_their_kernels(topo, t):
+    """`sarvam-105b-ep8`'s two step programs AS SERVED (all 32 layers, B=8,
+    S=8192, the Q80 round trip on): each attends through `mla_attention` —
+    a 32-token chunk of 64 heads is 2048 query rows, which flash_attention's
+    one panel a head would send, silently, to the dense path — writes its
+    one latent leaf a layer through `kv_cache_write`, and holds no copy of
+    a cache leaf."""
+    import rehearse_chip_compile as r
+
+    from distributed_llama_tpu.runtime.profiler import kernel_call_sites
+
+    fn, args = r.abstract_step(r.SARVAM_105B_EP8, topo.devices, batch=8,
+                               t=t, seq_len=8192, q80=True)
+    cache = args[-1]
+    assert cache.v == () and cache.k[0].shape == (8, 1, 8192, 576)
+    lowered = fn.lower(*args)
+    sites = kernel_call_sites(lowered.as_text())
+    assert sites.get("mla_attention", 0) >= 1, sites
+    assert sites.get("kv_cache_write", 0) >= 1, sites
+    assert sites.get("q40_matmul", 0) >= 5, sites
+    assert "flash_attention" not in sites
+    compiled = lowered.compile()
+    assert not r.cache_shaped_copies(compiled.as_text(), cache.k[0].shape)
+
+
+@pytest.mark.parametrize("b,t", [(8, 1), (8, 32)])
+def test_mla_attention_compiles(one_chip, b, t):
+    from distributed_llama_tpu.ops.pallas_attention import mla_attention
+
+    from distributed_llama_tpu.ops.pallas_kv_write import \
+        kv_cache_write_seq_minor
+
+    q = _struct((b, t, 64, 576), BF16, one_chip)
+    cache_t = _struct((b, 1, 576, 8192), BF16, one_chip)   # sequence minor
+    pos = _struct((b, t), jnp.int32, one_chip)
+    c = jax.jit(lambda q, c, p: mla_attention(
+        q, c, p, v_width=512, scale=0.1)).lower(q, cache_t, pos).compile()
+    assert _has_kernel(c)
+    new = _struct((b, t, 1, 576), BF16, one_chip)
+    c = jax.jit(kv_cache_write_seq_minor, donate_argnums=0).lower(
+        cache_t, new, _struct((b,), jnp.int32, one_chip)).compile()
+    assert _has_kernel(c)
+
+
 @pytest.mark.parametrize("t", [1, 32, 256])
 @pytest.mark.parametrize("cache_dtype", [BF16, jnp.float32,
                                          jnp.float8_e4m3fn])

@@ -54,6 +54,17 @@ MIXTRAL_8X7B = ModelSpec(arch=ArchType.MIXTRAL, dim=4096, hidden_dim=14336,
                          vocab_size=32000, seq_len=2048,
                          hidden_act=HiddenAct.SILU, n_experts=8,
                          n_active_experts=2, rope_theta=1e6)
+# sarvam-105b as benchmark/configs/sarvam-105b-ep8.json serves it: one
+# chip's share of 8 (16 of 128 routed experts, an eighth of the vocabulary)
+SARVAM_105B_EP8 = ModelSpec(
+    arch=ArchType.SARVAM_MLA, dim=4096, hidden_dim=2048, n_layers=32,
+    n_heads=64, n_kv_heads=1, vocab_size=32768, seq_len=8192,
+    hidden_act=HiddenAct.SILU, n_experts=16, n_active_experts=8,
+    kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+    v_head_dim=128, n_dense_layers=1, dense_hidden_dim=16384,
+    n_shared_experts=1, n_routed_experts=128, routed_scaling=2.5,
+    rms_eps=1e-6, rope_factor=40.0, rope_orig_len=4096,
+    rope_mscale_all_dim=1.0)
 
 
 def describe_topology():
@@ -82,14 +93,34 @@ def _loaded_params(spec: ModelSpec, dtype) -> dict:
     only ever built under jax.eval_shape."""
     d, h, kv = spec.dim, spec.hidden_dim, spec.kv_dim
     layers = []
-    for _ in range(spec.n_layers):
+    for l in range(spec.n_layers):
         lw = {"rms_att": jnp.ones((d,), jnp.float32),
-              "rms_ffn": jnp.ones((d,), jnp.float32),
-              "wq": _zeros_q40(d, d), "wk": _zeros_q40(kv, d),
-              "wv": _zeros_q40(kv, d), "wo": _zeros_q40(d, d)}
-        if spec.is_moe:
+              "rms_ffn": jnp.ones((d,), jnp.float32)}
+        if spec.is_mla:
+            nh, r = spec.n_heads, spec.kv_lora_rank
+            lw.update(
+                rms_kv=jnp.ones((r,), jnp.float32),
+                wq=_zeros_q40(nh * spec.head_size, d),
+                wkva=_zeros_q40(r + spec.qk_rope_head_dim, d),
+                w_uk=jnp.zeros((nh, spec.qk_nope_head_dim, r), dtype),
+                w_uv=jnp.zeros((nh, spec.v_head_dim, r), dtype),
+                wo=_zeros_q40(d, nh * spec.v_head_dim))
+        else:
+            lw.update(wq=_zeros_q40(d, d), wk=_zeros_q40(kv, d),
+                      wv=_zeros_q40(kv, d), wo=_zeros_q40(d, d))
+        if spec.is_mla and spec.is_dense_layer(l):
+            hd = spec.dense_hidden_dim
+            lw.update(w1=_zeros_q40(hd, d), w2=_zeros_q40(d, hd),
+                      w3=_zeros_q40(hd, d))
+        elif spec.is_moe:
             e = spec.n_experts
-            lw.update(moe_router=jnp.zeros((e, d), dtype),
+            if spec.is_mla:
+                sh = spec.n_shared_experts * h
+                lw.update(moe_bias=jnp.zeros((spec.router_width,),
+                                             jnp.float32),
+                          sh_w1=_zeros_q40(sh, d), sh_w2=_zeros_q40(d, sh),
+                          sh_w3=_zeros_q40(sh, d))
+            lw.update(moe_router=jnp.zeros((spec.router_width, d), dtype),
                       moe_up=_zeros_q40(e, h, d),
                       moe_gate=_zeros_q40(e, h, d),
                       moe_down=_zeros_q40(e, d, h))
