@@ -397,9 +397,10 @@ def _moe_ffn(xb, lw, spec: ModelSpec, cfg):
     """Top-k routed expert FFN (ref: src/grok1-tasks.cpp:56-227).
 
     Router/top-k runs replicated (the reference runs it root-only and
-    broadcasts — ref: grok1-tasks.cpp:121-126). Decode (T==1) gathers only
-    the active experts' weights; prefill computes all experts densely and
-    masks — both compile to static shapes.
+    broadcasts — ref: grok1-tasks.cpp:121-126). One row (B == T == 1)
+    computes only its active experts; every other shape computes all held
+    experts for every row and masks — both compile to static shapes, and
+    both read an expert's weights where they lie (_expert_matmul).
     """
     b, t, d = xb.shape
     k_active = spec.n_active_experts
@@ -469,11 +470,11 @@ def _moe_ffn(xb, lw, spec: ModelSpec, cfg):
             reduce=cfg.get("tp_reduce", "exact"),
         ).astype(xb.dtype)
 
-    def expert_apply(w_up, w_gate, w_down, x_tok):
-        gate = matmul(x_tok, w_gate, **cfg)
-        up = matmul(x_tok, w_up, **cfg)
+    def expert_apply(e):
+        gate = _expert_matmul(xb, lw["moe_gate"], e, cfg)
+        up = _expert_matmul(xb, lw["moe_up"], e, cfg)
         hb = apply_hidden_act(gate, spec.hidden_act) * up
-        return matmul(hb, w_down, **cfg)
+        return _expert_matmul(hb, lw["moe_down"], e, cfg)
 
     def with_shared(acc):
         # the shared expert(s): every token, unweighted, every chip alike
@@ -483,55 +484,44 @@ def _moe_ffn(xb, lw, spec: ModelSpec, cfg):
             return acc + _dense_ffn(xb, {"w1": lw["sh_w1"], "w2": lw["sh_w2"],
                                          "w3": lw["sh_w3"]}, spec, cfg)
 
+    acc = jnp.zeros((b, t, d), xb.dtype)
     if t == 1 and b == 1 and not held_share:
-        # decode: gather only the K active experts' weights (the reference
-        # likewise computes just the active experts — grok1-tasks.cpp:128-143)
-        from ..ops.matmul import fused_expert_matmul
-
+        # one row: only its K active experts, by their traced indices (the
+        # reference likewise computes just the active experts —
+        # grok1-tasks.cpp:128-143)
         idx = top_idx.reshape(k_active)
-        acc = jnp.zeros((b, t, d), xb.dtype)
         for ae in range(k_active):  # K is tiny and static — unrolled
-            e = idx[ae]
-            # expert-indexed fused kernel when eligible: the kernel reads the
-            # active expert's packed bytes in place instead of paying a
-            # dynamic-slice HBM copy per expert per layer (pallas_q40.py)
-            out = None
-            gate = fused_expert_matmul(xb, lw["moe_gate"], e, **cfg)
-            up = (fused_expert_matmul(xb, lw["moe_up"], e, **cfg)
-                  if gate is not None else None)
-            if gate is not None and up is not None:
-                hb = apply_hidden_act(gate, spec.hidden_act) * up
-                out = fused_expert_matmul(hb, lw["moe_down"], e, **cfg)
-            if out is None:
-                out = expert_apply(
-                    _take_expert(lw["moe_up"], e),
-                    _take_expert(lw["moe_gate"], e),
-                    _take_expert(lw["moe_down"], e),
-                    xb,
-                )
+            out = expert_apply(idx[ae])
             acc = acc + weights[..., ae, None].astype(out.dtype) * out
         return with_shared(acc)
 
-    # prefill: dense all-expert compute, mask by routing weights
+    # every other shape, the served step programs' 8 and 256 rows among
+    # them: every held expert for every row, masked by the routing weights
     e_weights = scatter_weights()
-
-    def all_experts(e, acc):
-        up_e = _take_expert(lw["moe_up"], e)
-        gate_e = _take_expert(lw["moe_gate"], e)
-        down_e = _take_expert(lw["moe_down"], e)
-        out = expert_apply(up_e, gate_e, down_e, xb)
-        return acc + e_weights[..., e, None].astype(out.dtype) * out
-
-    acc = jnp.zeros((b, t, d), xb.dtype)
     with jax.named_scope("moe_routed"):
         for e in range(spec.n_experts):
-            acc = all_experts(e, acc)
+            out = expert_apply(e)
+            acc = acc + e_weights[..., e, None].astype(out.dtype) * out
     return with_shared(acc)
 
 
+def _expert_matmul(x, w, e, cfg):
+    """x @ W[e]^T against a stacked (E, d, n) leaf, `e` traced or a Python
+    integer. A plain single-shard Q40 stack of at most pallas_q40.MAX_T rows
+    is read IN PLACE by the expert-indexed kernel (ops/matmul.
+    fused_expert_matmul says which); anything else is sliced first."""
+    from ..ops.matmul import fused_expert_matmul
+
+    out = fused_expert_matmul(x, w, e, **cfg)
+    return matmul(x, _take_expert(w, e), **cfg) if out is None else out
+
+
 def _take_expert(w, e):
-    """Select expert e from a stacked (E, ...) weight (dense or Q40; for
-    TpColWeight the expert axis sits behind the tp stack axis)."""
+    """Select expert e from a stacked (E, ...) weight: an expert-sized copy
+    in HBM before the matmul may read it, so only what the in-place kernel
+    cannot take comes here (_expert_matmul) — tp wrappers (for TpColWeight
+    the expert axis sits behind the tp stack axis), unquantised stacks,
+    the XLA dequant path and segments of more than pallas_q40.MAX_T rows."""
     from ..parallel.tp_q80 import TpColWeight, TpRowWeight, take_expert_col
 
     if isinstance(w, TpColWeight):

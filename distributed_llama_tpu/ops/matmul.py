@@ -137,7 +137,7 @@ def matmul(
 def fused_expert_matmul(
     x: jnp.ndarray,
     w,                      # stacked (E, d, n) weight leaf
-    e: jnp.ndarray,         # traced i32 expert index
+    e,                      # i32 expert index, traced or a Python integer
     *,
     activation_q80: bool = False,
     compute_dtype=jnp.float32,
@@ -150,13 +150,17 @@ def fused_expert_matmul(
     manual_sp: int = 0,  # ignored — see matmul()
 ):
     """Expert-indexed matmul against a stacked (E, d, n) Q40 weight without
-    materializing the expert's slice (ops/pallas_q40.q40_expert_matmul).
+    materializing the expert's slice (ops/pallas_q40.q40_expert_matmul):
+    every expert matmul of models/transformer._moe_ffn comes here first
+    (_expert_matmul), at one row and at the served programs' 8 and 256. `e`
+    is a traced i32 or a Python integer.
 
-    Returns None when ineligible — plain-QuantizedTensor single-shard Q40
-    stacks only (which includes manual-region pp layers at tp == 1, where
-    the local stack is the whole weight); the caller falls back to
-    gather-then-matmul (which is also what the mesh paths' Tp/Ep wrappers
-    take)."""
+    Returns None when ineligible, and the choice rests on what the call can
+    see in its operands alone: a plain-QuantizedTensor single-shard Q40
+    stack (which includes manual-region pp layers at tp == 1, where the
+    local stack is the whole weight) and at most pallas_q40.MAX_T rows read
+    in place; the mesh paths' Tp/Ep wrappers, dense stacks, the XLA dequant
+    path and longer segments make the caller slice, then matmul()."""
     del tp_reduce, manual_tp
     if not (use_pallas and tp_mesh is None
             and isinstance(w, QuantizedTensor) and w.packed.ndim == 3):

@@ -204,8 +204,8 @@ _TILE_BYTES_MAX = 2_300_000
 # they land in the kernel's own scoped allocation — at 256 rows 4.2 MB
 # (m = 2048) to 14.7 MB (m = 7168), which took a Mixtral prefill program
 # to 19.2 MB and failed its compile once the step programs' schedule
-# changed (PERF.md section 6, PR 27). q40_matmul therefore asks for the
-# default plus its panels.
+# changed (PERF.md section 6, PR 27). Both entry points (_q40_call)
+# therefore ask for the default plus their panels.
 _SCOPED_VMEM_DEFAULT = 16 * 2**20
 
 
@@ -261,6 +261,76 @@ def _split_activation(x: jnp.ndarray, nb: int) -> tuple[jnp.ndarray, jnp.ndarray
     return x_lo, x_hi
 
 
+def _q40_call(x, w: QuantizedTensor, e, out_dtype, interpret):
+    """The pallas_call both entry points share: `w` is one (d, m) packed
+    weight (e None, the `_kernel` body) or an (E, d, m) stack whose expert
+    `e` rides in as the scalar-prefetch operand of the weight blocks' index
+    maps (`_expert_kernel`). Everything else — the activation split, the
+    tile, the blocks' memory space, the scoped-VMEM request — is one
+    decision for both."""
+    d, m = w.packed.shape[-2:]
+    nb = m // 16
+    n = nb * 32
+
+    lead = x.shape[:-1]
+    t = 1
+    for s in lead:
+        t *= s
+    x_lo, x_hi = _split_activation(x.reshape(t, n).astype(jnp.float32), nb)
+    xsum = (x_lo + x_hi).reshape(t, 16, nb).sum(axis=1)  # (t, nb) per-block sums
+
+    td = _tile_d(d, m)
+    scales_u16 = w.scales.dtype == jnp.uint16
+    scales = w.scales if scales_u16 else w.scales.astype(jnp.float32)
+    # multi-token chunks with a bf16 consumer take the bf16 MXU feed (see
+    # _dequant_dot); single-token decode and f32 consumers keep exact f32
+    mxu_bf16 = jnp.dtype(out_dtype) == jnp.bfloat16 and t >= 16
+
+    # index maps take the grid index and, in the expert call, the prefetched
+    # scalars' ref; the packed weight is already stored flattened (d, m) —
+    # consumed in place, and so is the stack: block (e, i, 0) of it
+    block = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
+    if e is None:
+        kernel, name, prefetched = _kernel, "q40_matmul", ()
+        w_block, w_at = (td,), lambda i: (i, 0)
+    else:
+        kernel, name = _expert_kernel, "q40_expert_matmul"
+        prefetched = (jnp.atleast_1d(e).astype(jnp.int32),)
+        w_block, w_at = (1, td), lambda i, e_ref: (e_ref[0], i, 0)
+    specs = dict(
+        grid=(pl.cdiv(d, td),),
+        in_specs=[
+            block((t, m), lambda i, *_: (0, 0)),
+            block((t, m), lambda i, *_: (0, 0)),
+            block((t, nb), lambda i, *_: (0, 0)),
+            block((*w_block, m), w_at),
+            block((*w_block, nb), w_at),
+        ],
+        out_specs=block((t, td), lambda i, *_: (0, i)),
+    )
+    if prefetched:
+        specs = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetched), **specs))
+
+    out = pl.pallas_call(
+        functools.partial(kernel, nb=nb, out_dtype=out_dtype,
+                          scales_u16=scales_u16, mxu_bf16=mxu_bf16),
+        **specs,
+        out_shape=jax.ShapeDtypeStruct((t, d), out_dtype),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * t * d * n,
+            bytes_accessed=d * m + d * nb * 2 + 2 * t * m * 4 + t * d * 4,
+            transcendentals=0,
+        ),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_SCOPED_VMEM_DEFAULT + 4 * t * (2 * m + nb)),
+        interpret=interpret,
+        name=name,
+    )(*prefetched, x_lo, x_hi, xsum, w.packed, scales)
+
+    return out.reshape(*lead, d)
+
+
 @functools.partial(jax.jit, static_argnames=("out_dtype", "interpret"))
 def q40_matmul(
     x: jnp.ndarray,
@@ -274,113 +344,31 @@ def q40_matmul(
     leading dims. Weight stays packed through HBM; dequant happens per-tile in
     VMEM fused into the MXU contraction.
     """
-    d, m = w.packed.shape
-    nb = m // 16
-    n = nb * 32
-
-    lead = x.shape[:-1]
-    t = 1
-    for s in lead:
-        t *= s
-    x_lo, x_hi = _split_activation(x.reshape(t, n).astype(jnp.float32), nb)
-    xsum = (x_lo + x_hi).reshape(t, 16, nb).sum(axis=1)  # (t, nb) per-block sums
-
-    packed2d = w.packed  # already stored flattened (d, m) — consumed in place
-    td = _tile_d(d, m)
-    grid = (pl.cdiv(d, td),)
-    scales_u16 = w.scales.dtype == jnp.uint16
-    scales = w.scales if scales_u16 else w.scales.astype(jnp.float32)
-    # multi-token chunks with a bf16 consumer take the bf16 MXU feed (see
-    # _kernel); single-token decode and f32 consumers keep exact f32
-    mxu_bf16 = jnp.dtype(out_dtype) == jnp.bfloat16 and t >= 16
-
-    out = pl.pallas_call(
-        functools.partial(_kernel, nb=nb, out_dtype=out_dtype,
-                          scales_u16=scales_u16, mxu_bf16=mxu_bf16),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((t, m), lambda i: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((t, m), lambda i: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((t, nb), lambda i: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((td, m), lambda i: (i, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((td, nb), lambda i: (i, 0), memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((t, td), lambda i: (0, i), memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((t, d), out_dtype),
-        cost_estimate=pl.CostEstimate(
-            flops=2 * t * d * n,
-            bytes_accessed=d * m + d * nb * 2 + 2 * t * m * 4 + t * d * 4,
-            transcendentals=0,
-        ),
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=_SCOPED_VMEM_DEFAULT + 4 * t * (2 * m + nb)),
-        interpret=interpret,
-        name="q40_matmul",
-    )(x_lo, x_hi, xsum, packed2d, scales)
-
-    return out.reshape(*lead, d)
+    return _q40_call(x, w, None, out_dtype, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("out_dtype", "interpret"))
 def q40_expert_matmul(
     x: jnp.ndarray,
     w: QuantizedTensor,    # stacked (E, d, m) packed / (E, d, nb) scales
-    e: jnp.ndarray,        # traced i32 expert index
+    e: jnp.ndarray,        # i32 expert index, traced or a Python integer
     out_dtype=jnp.float32,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """y[..., d] = sum_n x[..., n] * W[e, d, n] with the expert chosen by a
-    TRACED index — the MoE decode gather (models/transformer._moe_ffn; the
-    reference computes just the active experts the same way, ref:
+    """y[..., d] = sum_n x[..., n] * W[e, d, n]: every expert matmul of
+    models/transformer._moe_ffn on a plain single-shard stack — the served
+    all-experts loop at 8 and 256 rows (e a Python integer there) and the
+    one-row decode gather of the top-k experts (e traced; the reference
+    computes just the active experts the same way, ref:
     src/grok1-tasks.cpp:128-143).
 
     The expert index rides in as a scalar-prefetch operand and the block
     index maps offset straight into the (E, d, m) HBM stack, so the kernel
-    reads the active expert's packed bytes IN PLACE. The alternative —
+    reads the expert's packed bytes IN PLACE. The alternative —
     lax.dynamic_index_in_dim then q40_matmul — materializes a full HBM copy
-    of the expert's weight before the kernel can read it (read + write +
-    re-read = 3x the bytes of the decode-critical path).
+    of the expert's weight before the kernel can read it: a Pallas call
+    takes whole buffers, so the slice is read and written once more than
+    the matmul reads it (36 % of mixtral-8x7b-12l's device time, PERF.md
+    section 6, PR 31).
     """
-    n_e, d, m = w.packed.shape
-    nb = m // 16
-    n = nb * 32
-
-    lead = x.shape[:-1]
-    t = 1
-    for s in lead:
-        t *= s
-    x_lo, x_hi = _split_activation(x.reshape(t, n).astype(jnp.float32), nb)
-    xsum = (x_lo + x_hi).reshape(t, 16, nb).sum(axis=1)
-
-    td = _tile_d(d, m)
-    scales_u16 = w.scales.dtype == jnp.uint16
-    scales = w.scales if scales_u16 else w.scales.astype(jnp.float32)
-    mxu_bf16 = jnp.dtype(out_dtype) == jnp.bfloat16 and t >= 16
-    e_arr = jnp.atleast_1d(e).astype(jnp.int32)
-
-    out = pl.pallas_call(
-        functools.partial(_expert_kernel, nb=nb, out_dtype=out_dtype,
-                          scales_u16=scales_u16, mxu_bf16=mxu_bf16),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(pl.cdiv(d, td),),
-            in_specs=[
-                pl.BlockSpec((t, m), lambda i, e_ref: (0, 0)),
-                pl.BlockSpec((t, m), lambda i, e_ref: (0, 0)),
-                pl.BlockSpec((t, nb), lambda i, e_ref: (0, 0)),
-                pl.BlockSpec((1, td, m), lambda i, e_ref: (e_ref[0], i, 0)),
-                pl.BlockSpec((1, td, nb), lambda i, e_ref: (e_ref[0], i, 0)),
-            ],
-            out_specs=pl.BlockSpec((t, td), lambda i, e_ref: (0, i)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((t, d), out_dtype),
-        cost_estimate=pl.CostEstimate(
-            flops=2 * t * d * n,
-            bytes_accessed=d * m + d * nb * 2 + 2 * t * m * 4 + t * d * 4,
-            transcendentals=0,
-        ),
-        interpret=interpret,
-        name="q40_expert_matmul",
-    )(e_arr, x_lo, x_hi, xsum, w.packed, scales)
-
-    return out.reshape(*lead, d)
+    return _q40_call(x, w, e, out_dtype, interpret)

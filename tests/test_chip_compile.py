@@ -63,6 +63,31 @@ def tp4(topo):
     return make_mesh(tp=4, devices=topo.devices)
 
 
+@pytest.fixture(scope="module")
+def served_moe_step(topo):
+    """(spec, args, lowered, compiled) of an MoE configuration's step program
+    AS SERVED (B=8, the Q80 activation round trip on; `mixtral-8x7b-12l`
+    at its 12 layers and S=4096, `sarvam-105b-ep8` at all 32 and S=8192),
+    compiled once for the tests that read it."""
+    import rehearse_chip_compile as r
+
+    served = {"mixtral_8x7b_12l": (dataclasses.replace(r.MIXTRAL_8X7B,
+                                                       n_layers=12), 4096),
+              "sarvam_105b_ep8": (r.SARVAM_105B_EP8, 8192)}
+    made = {}
+
+    def step(model, t):
+        if (model, t) not in made:
+            spec, seq_len = served[model]
+            fn, args = r.abstract_step(spec, topo.devices, batch=8, t=t,
+                                       seq_len=seq_len, q80=True)
+            lowered = fn.lower(*args)
+            made[model, t] = (spec, args, lowered, lowered.compile())
+        return made[model, t]
+
+    return step
+
+
 def _struct(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
@@ -96,14 +121,17 @@ def test_q40_matmul_compiles_at_7b_widths(one_chip, d, n, t):
     assert _has_kernel(c)
 
 
+# t = 1 is the offline one-row gather; 8 and 256 (= MAX_T) are the served
+# step programs' rows, every expert for every row
+@pytest.mark.parametrize("t", [1, 8, 256])
 @pytest.mark.parametrize("d,n", [(14336, 4096), (4096, 14336)])
-def test_q40_expert_matmul_compiles_at_mixtral_widths(one_chip, d, n):
+def test_q40_expert_matmul_compiles_at_mixtral_widths(one_chip, d, n, t):
     from rehearse_chip_compile import q40_struct
 
     from distributed_llama_tpu.ops.pallas_q40 import q40_expert_matmul
 
     w = _placed(q40_struct(8, d, n), one_chip)   # moe_up/gate | moe_down
-    x = _struct((1, n), BF16, one_chip)
+    x = _struct((t, n), BF16, one_chip)
     e = _struct((), jnp.int32, one_chip)
     c = jax.jit(lambda x, w, e: q40_expert_matmul(
         x, w, e, out_dtype=BF16)).lower(x, w, e).compile()
@@ -209,23 +237,43 @@ def test_step_programs_keep_no_cache_sized_copy(topo, model, t):
     assert not copies, copies
 
 
-def test_served_mixtral_prefill_chunk_fits_scoped_vmem(topo):
+def test_served_mixtral_prefill_chunk_fits_scoped_vmem(served_moe_step):
     """`mixtral-8x7b-12l`'s prefill program AS SERVED (12 layers, the Q80
     activation round trip on). With the cache copies gone XLA placed one
     expert matmul's 256-row activation panels in the kernel's own scoped
     VMEM, 19.2 MB against the 16 MB default, and the compile failed on the
-    chip, at this depth only; q40_matmul now asks for its panels
-    (ops/pallas_q40._SCOPED_VMEM_DEFAULT)."""
-    import rehearse_chip_compile as r
-
-    spec = dataclasses.replace(r.MIXTRAL_8X7B, n_layers=12)
-    fn, args = r.abstract_step(spec, topo.devices, batch=8, t=32,
-                               seq_len=4096, q80=True)
-    assert _has_kernel(fn.lower(*args).compile())
+    chip, at this depth only; both Q40 entry points now ask for their
+    panels (ops/pallas_q40._SCOPED_VMEM_DEFAULT)."""
+    assert _has_kernel(served_moe_step("mixtral_8x7b_12l", 32)[-1])
 
 
 @pytest.mark.parametrize("t", [1, 32], ids=["decode", "chunk32"])
-def test_served_sarvam_mla_step_programs_hold_their_kernels(topo, t):
+@pytest.mark.parametrize("model", ["mixtral_8x7b_12l", "sarvam_105b_ep8"])
+def test_moe_step_programs_read_experts_in_place(served_moe_step, model, t):
+    """Both MoE configurations' two step programs AS SERVED run every
+    expert's gate, up and down through `q40_expert_matmul` on the stacked
+    leaf, and hold NO copy, slice or fusion whose result is one expert's
+    packed matrix or a packed stack. With `_take_expert` + `q40_matmul`
+    every step program read and wrote all of Mixtral's 10.1 GB of expert
+    weights once more than its matmuls did — 36 % of the device's time in
+    `mixtral-8x7b-12l.chat-steady`, more than the decode step's matmuls
+    (PERF.md section 6, PR 31). The twin of
+    `test_step_programs_keep_no_cache_sized_copy`."""
+    import rehearse_chip_compile as r
+
+    from distributed_llama_tpu.runtime.profiler import kernel_call_sites
+
+    spec, _, lowered, compiled = served_moe_step(model, t)
+    sites = kernel_call_sites(lowered.as_text())
+    # call SITES (jit dedups equal shapes): gate = up, and down
+    assert sites.get("q40_expert_matmul", 0) >= 2, sites
+    sliced = r.expert_sized_results(compiled.as_text(), spec)
+    assert not sliced, sliced[:4]
+
+
+@pytest.mark.parametrize("t", [1, 32], ids=["decode", "chunk32"])
+def test_served_sarvam_mla_step_programs_hold_their_kernels(served_moe_step,
+                                                            t):
     """`sarvam-105b-ep8`'s two step programs AS SERVED (all 32 layers, B=8,
     S=8192, the Q80 round trip on): each attends through `mla_attention` —
     a 32-token chunk of 64 heads is 2048 query rows, which flash_attention's
@@ -236,17 +284,14 @@ def test_served_sarvam_mla_step_programs_hold_their_kernels(topo, t):
 
     from distributed_llama_tpu.runtime.profiler import kernel_call_sites
 
-    fn, args = r.abstract_step(r.SARVAM_105B_EP8, topo.devices, batch=8,
-                               t=t, seq_len=8192, q80=True)
+    _, args, lowered, compiled = served_moe_step("sarvam_105b_ep8", t)
     cache = args[-1]
     assert cache.v == () and cache.k[0].shape == (8, 1, 8192, 576)
-    lowered = fn.lower(*args)
     sites = kernel_call_sites(lowered.as_text())
     assert sites.get("mla_attention", 0) >= 1, sites
     assert sites.get("kv_cache_write", 0) >= 1, sites
     assert sites.get("q40_matmul", 0) >= 5, sites
     assert "flash_attention" not in sites
-    compiled = lowered.compile()
     assert not r.cache_shaped_copies(compiled.as_text(), cache.k[0].shape)
 
 

@@ -44,6 +44,12 @@ def test_leading_dims_flattened(rng):
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-4, rtol=1e-4)
 
 
+def _stack(rng, n_e, d, n):
+    qts = [_qt(rng, d, n) for _ in range(n_e)]
+    return qts, QuantizedTensor(jnp.stack([q.packed for q in qts]),
+                                jnp.stack([q.scales for q in qts]))
+
+
 @pytest.mark.parametrize("e", [0, 2, 7])
 def test_expert_kernel_matches_sliced_oracle(rng, e):
     """The expert-indexed kernel (traced index into the (E, d, m) stack) must
@@ -51,13 +57,146 @@ def test_expert_kernel_matches_sliced_oracle(rng, e):
     from distributed_llama_tpu.ops.pallas_q40 import q40_expert_matmul
 
     n_e, d, n = 8, 256, 1024
-    qts = [_qt(rng, d, n) for _ in range(n_e)]
-    stack = QuantizedTensor(jnp.stack([q.packed for q in qts]),
-                            jnp.stack([q.scales for q in qts]))
+    qts, stack = _stack(rng, n_e, d, n)
     x = jnp.asarray(rng.standard_normal((1, n), dtype=np.float32))
     ref = jnp.einsum("tn,dn->td", x,
                      dequantize_q40_jax(qts[e], dtype=jnp.float32))
     got = q40_expert_matmul(x, stack, jnp.int32(e), interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               atol=2e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("e", [0, 3, 7], ids=["first", "middle", "last"])
+@pytest.mark.parametrize("t", [8, 256])
+def test_expert_kernel_equals_plain_kernel_at_served_rows(rng, t, e):
+    """At the served step programs' 8 and 256 (= MAX_T) rows the expert
+    kernel on the stack is BIT-equal to `q40_matmul` on the sliced expert,
+    with `e` the Python integer `_moe_ffn`'s all-experts loop passes: one
+    body (`_subtiled_write`), one call (`_q40_call`)."""
+    from distributed_llama_tpu.ops.pallas_q40 import q40_expert_matmul
+
+    qts, stack = _stack(rng, 8, 256, 512)
+    x = jnp.asarray(rng.standard_normal((t, 512), dtype=np.float32),
+                    jnp.bfloat16)
+    got = q40_expert_matmul(x, stack, e, out_dtype=jnp.bfloat16,
+                            interpret=True)
+    ref = q40_matmul(x, qts[e], out_dtype=jnp.bfloat16, interpret=True)
+    assert got.dtype == ref.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(ref, np.float32))
+
+
+def _jit_calls(fn) -> dict:
+    """How often fn's trace calls each jitted kernel entry point."""
+    import jax
+
+    calls = {}
+    for eqn in jax.make_jaxpr(fn)().eqns:
+        name = eqn.params.get("name", "")
+        if name.startswith("q40_"):
+            calls[name] = calls.get(name, 0) + 1
+    return calls
+
+
+def _moe_case(rng, held_share: bool):
+    """(spec, layer weights) of one MoE block at tiny widths: Mixtral's
+    shape (softmax router, top-2 of 8) or a held share with a shared expert
+    (sigmoid-bias router over 32, top-8, experts 8..23 held)."""
+    from distributed_llama_tpu.models.spec import (ArchType, HiddenAct,
+                                                   ModelSpec)
+
+    d, h = 256, 512
+    common = dict(dim=d, hidden_dim=h, n_layers=1, n_heads=4, n_kv_heads=4,
+                  vocab_size=64, seq_len=64, hidden_act=HiddenAct.SILU)
+    if held_share:
+        spec = ModelSpec(arch=ArchType.SARVAM_MLA, n_experts=16,
+                         n_active_experts=8, n_routed_experts=32,
+                         expert_offset=8, routed_scaling=2.5,
+                         n_shared_experts=1, kv_lora_rank=32,
+                         qk_nope_head_dim=16, qk_rope_head_dim=8,
+                         v_head_dim=16, **common)
+    else:
+        spec = ModelSpec(arch=ArchType.MIXTRAL, n_experts=8,
+                         n_active_experts=2, **common)
+    e = spec.n_experts
+    lw = {"moe_router": jnp.asarray(rng.standard_normal(
+              (spec.router_width, d), dtype=np.float32)),
+          "moe_up": _stack(rng, e, h, d)[1],
+          "moe_gate": _stack(rng, e, h, d)[1],
+          "moe_down": _stack(rng, e, d, h)[1]}
+    if held_share:
+        lw.update(moe_bias=jnp.asarray(0.5 * rng.standard_normal(
+                      spec.router_width, dtype=np.float32)),
+                  sh_w1=_qt(rng, h, d), sh_w2=_qt(rng, d, h),
+                  sh_w3=_qt(rng, h, d))
+    return spec, lw
+
+
+@pytest.mark.parametrize("q80", [True, False], ids=["q80", "plain"])
+@pytest.mark.parametrize("t", [1, 32], ids=["decode8x1", "chunk8x32"])
+@pytest.mark.parametrize("held_share", [False, True],
+                         ids=["top2of8", "held16top8shared"])
+def test_all_experts_loop_reads_in_place_bit_equal(rng, monkeypatch,
+                                                   held_share, t, q80):
+    """`_moe_ffn`'s all-experts loop at the served shapes (8 rows, and 8 x 32
+    = 256 kernel rows) calls the expert-indexed kernel on each stacked leaf
+    — gate, up and down of every held expert — and its output is BIT-equal
+    to slicing each expert out and calling `q40_matmul`, with the Q80
+    activation round trip on (as served) and off."""
+    import sys
+
+    from distributed_llama_tpu.models.transformer import _moe_ffn
+
+    # the module: `ops.matmul` the attribute is the function of that name
+    matmul_mod = sys.modules["distributed_llama_tpu.ops.matmul"]
+
+    spec, lw = _moe_case(rng, held_share)
+    xb = jnp.asarray(rng.standard_normal((8, t, spec.dim), dtype=np.float32),
+                     jnp.bfloat16)
+    cfg = dict(activation_q80=q80, compute_dtype=jnp.bfloat16,
+               use_pallas=True, tp_mesh=None, tp_reduce="exact",
+               pallas_interpret=True)
+
+    def run():  # a new function each time: traces are cached by function
+        return lambda: _moe_ffn(xb, lw, spec, cfg)
+
+    shared = {"q40_matmul": 3} if held_share else {}  # the shared expert's
+    assert _jit_calls(run()) == {"q40_expert_matmul": 3 * spec.n_experts,
+                                 **shared}
+    got = run()()
+
+    monkeypatch.setattr(matmul_mod, "fused_expert_matmul",
+                        lambda *a, **k: None)
+    assert _jit_calls(run()) == {
+        "q40_matmul": 3 * spec.n_experts + shared.get("q40_matmul", 0)}
+    sliced = run()()
+    assert got.dtype == sliced.dtype == jnp.bfloat16
+    assert float(jnp.abs(sliced.astype(jnp.float32)).max()) > 0
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(sliced, np.float32))
+
+
+def test_wrapped_stack_still_takes_the_slice(rng):
+    """A `TpColWeight` stack (the tp placements') is not the in-place
+    kernel's: `fused_expert_matmul` declines it, and `_expert_matmul`
+    slices the expert out (`_take_expert`) for the wrapper's own matmul."""
+    from distributed_llama_tpu.models.transformer import _expert_matmul
+    from distributed_llama_tpu.ops.matmul import fused_expert_matmul
+    from distributed_llama_tpu.parallel.tp_q80 import TpColWeight
+
+    n_e, d, n = 4, 128, 256
+    qts, stack = _stack(rng, n_e, d, n)
+    wrapped = TpColWeight(stack)  # shard-local, as inside a manual region
+    x = jnp.asarray(rng.standard_normal((8, 1, n), dtype=np.float32))
+    cfg = dict(activation_q80=False, compute_dtype=jnp.float32,
+               use_pallas=True, tp_mesh=None, tp_reduce="exact",
+               pallas_interpret=True, manual_tp=1)
+    assert fused_expert_matmul(x, wrapped, 2, **cfg) is None
+    assert _jit_calls(lambda: _expert_matmul(x, wrapped, 2, cfg)) == {
+        "q40_matmul": 1}
+    got = _expert_matmul(x, wrapped, 2, cfg)
+    ref = jnp.einsum("btn,dn->btd", x,
+                     dequantize_q40_jax(qts[2], dtype=jnp.float32))
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                atol=2e-4, rtol=1e-4)
 
@@ -68,9 +207,7 @@ def test_fused_expert_matmul_dispatch(rng):
     from distributed_llama_tpu.ops.matmul import fused_expert_matmul
 
     n_e, d, n = 4, 128, 256
-    qts = [_qt(rng, d, n) for _ in range(n_e)]
-    stack = QuantizedTensor(jnp.stack([q.packed for q in qts]),
-                            jnp.stack([q.scales for q in qts]))
+    qts, stack = _stack(rng, n_e, d, n)
     x = jnp.asarray(rng.standard_normal((1, 1, n), dtype=np.float32))
     got = fused_expert_matmul(x, stack, jnp.int32(3),
                               compute_dtype=jnp.float32, use_pallas=True,
@@ -202,9 +339,7 @@ def test_subtiled_expert_kernel_matches_whole_tile(rng, monkeypatch):
     from distributed_llama_tpu.ops.pallas_q40 import q40_expert_matmul
 
     n_e, d, n, t, e = 4, 256, 1024, 32, 2
-    qts = [_qt(rng, d, n) for _ in range(n_e)]
-    stack = QuantizedTensor(jnp.stack([qq.packed for qq in qts]),
-                            jnp.stack([qq.scales for qq in qts]))
+    qts, stack = _stack(rng, n_e, d, n)
     assert q._n_sub(_tile_d(d, stack.packed.shape[2]),
                     stack.packed.shape[2], True) == 8
     x = jnp.asarray(rng.standard_normal((t, n), dtype=np.float32))

@@ -211,6 +211,33 @@ def cache_shaped_copies(compiled_text: str, leaf_shape) -> list[str]:
             if re.search(rf"= \w+{dims}\S* copy\(", ln)]
 
 
+def expert_sized_results(compiled_text: str, spec: ModelSpec) -> list[str]:
+    """The instructions of a compiled module, those inside fusions too, whose
+    result is ONE EXPERT'S packed Q40 matrix — an expert sliced out of its
+    stacked (E, d, m) leaf into HBM before a kernel may read it, which cost
+    36 % of `mixtral-8x7b-12l`'s device time (PERF.md section 6, PR 31) —
+    or a whole packed stack. Not counted: parameters, and the compiler's own
+    prefetches into VMEM (`S(1)` results of copy-start/-done and of the
+    `ConcatBitcast` custom call of a sliced prefetch), which read a weight
+    once in place of the kernel's read — a stack, or a dense weight of an
+    expert's shape (sarvam's shared expert). The scales are not judged
+    here: a stack of them whose block count is not whole lane tiles
+    (`moe_down`) is re-laid once a layer, as a dense w2's are."""
+    import re
+
+    e, h, d = spec.n_experts, spec.hidden_dim, spec.dim
+    shapes = {f"[{h},{d // 2}]", f"[{d},{h // 2}]",
+              f"[{e},{h},{d // 2}]", f"[{e},{d},{h // 2}]"}
+    prefetch = ("custom-call", "copy-start", "copy-done")
+    found = []
+    for ln in compiled_text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?\S+ = u8(\[[\d,]*\])(\S*) ([\w-]+)\(", ln)
+        if m and m.group(1) in shapes and m.group(3) != "parameter" and not (
+                m.group(3) in prefetch and "S(1)" in m.group(2)):
+            found.append(ln.strip())
+    return found
+
+
 def compile_report(fn, args) -> dict:
     """Compile for the described devices; what the compiler put in."""
     import re
