@@ -167,7 +167,7 @@ class ApiState:
         # many KV slots: /v1/completions and /v1/chat/completions enqueue
         # onto it, and POST /v1/batch/completions borrows its engine.
         # Decode is weight-read-bound, so b live slots amortize one weight
-        # read per step (bench.py's continuous-batching row).
+        # read per step.
         self.serve_batch = serve_batch
         self.serve_chunk = serve_chunk  # prefill chunk; 0 = engine default
         # radix prefix cache (runtime/prefix_cache.py): cross-request KV
@@ -694,7 +694,7 @@ def _batch_completion_chunks(state: ApiState, body: dict):
     """POST /v1/batch/completions generator: up to serve_batch prompts
     decoded in ONE batched engine (net-new vs the reference's batch=1
     server — decode is weight-read-bound, so b rows amortize one weight
-    read; bench.py's _batch_row measures the aggregate-throughput win).
+    read).
 
     Yields ("piece", (row, piece)) events then one ("done", {...}) with
     per-row finish/usage. Per-request temperature/seed apply to the whole
@@ -809,9 +809,8 @@ def _batch_completion_chunks(state: ApiState, body: dict):
             if state.lookup_decode > 0 and sampler.temperature == 0.0:
                 # greedy batch requests SPECULATE
                 # (Engine.generate_batch_lookup — per-row drafts, one
-                # verify forward per step, exact per-row greedy parity;
-                # bench measured 368-407 aggregate tok/s vs 355
-                # plain-batch). Collected, not streamed: text-level stop
+                # verify forward per step, exact per-row greedy parity).
+                # Collected, not streamed: text-level stop
                 # sequences trim each row post-hoc — a stopped row may
                 # have burned some extra forwards, which multi-token
                 # accepts more than repay; the batch cache resets per
@@ -1752,7 +1751,7 @@ def serve(args) -> None:
     # SLO-aware admission + auto-sizing flags (runtime/scheduler.
     # AdmissionPolicy / runtime/profiler.resolve_auto_shape): dead-flag
     # discipline like every knob family above — an SLO nobody enforces
-    # or an artifact nobody reads must be a parse-time error
+    # must be a parse-time error
     slo_ttft = getattr(args, "slo_ttft_ms", None)
     slo_itl = getattr(args, "slo_itl_ms", None)
     if (slo_ttft is not None or slo_itl is not None) and not serve_batch:
@@ -1766,20 +1765,6 @@ def serve(args) -> None:
     prefix_blocks = getattr(args, "prefix_blocks", 0)
     auto_batch = serve_batch == "auto"
     auto_blocks = prefix_blocks == "auto"
-    autotune_file = getattr(args, "autotune", None)
-    if autotune_file and not (auto_batch or auto_blocks):
-        sys.exit("error: --autotune has no effect without --serve-batch "
-                 "auto or --prefix-blocks auto (tools/dlprof.py consumes "
-                 "the artifact offline)")
-    autotune_art = None
-    if autotune_file:
-        # a bad artifact must be a clear CLI error before any engine
-        # work, never a wrong silent batch size
-        from ..runtime.profiler import load_autotune
-        try:
-            autotune_art = load_autotune(autotune_file)
-        except (OSError, ValueError) as e:
-            sys.exit(f"error: --autotune {autotune_file}: {e}")
     if getattr(args, "prefix_cache", False) and not serve_batch:
         # the radix cache lives on the slot scheduler (the legacy path
         # keeps its own single-session prefix reuse) — loud error beats
@@ -1842,9 +1827,7 @@ def serve(args) -> None:
         # clearly at parse time instead of crashing mid-build
         sys.exit("error: --serve-batch/--prefix-blocks 'auto' need a "
                  "ledger-capable local engine; the process tier's "
-                 "workers own their engines — pass explicit sizes "
-                 "(calibrate with tools/autotune.py and use its "
-                 "recommendation)")
+                 "workers own their engines — pass explicit sizes")
     if not serve_batch and (
             replicas > 1 or process_tier
             or getattr(args, "retry_budget", None) is not None
@@ -2059,8 +2042,8 @@ def serve(args) -> None:
     autosize = None
     if auto_batch or auto_blocks:
         # resolve the sentinels against the REAL engine's ledger, once,
-        # before any scheduler exists: measured headroom capped by the
-        # calibrated (or default-heuristic) knee. The decision record is
+        # before any scheduler exists: the default-heuristic knee capped
+        # by measured headroom. The decision record is
         # logged here and exported on /stats + /metrics so an operator
         # can always see what was chosen and why.
         from ..runtime.profiler import resolve_auto_shape
@@ -2069,7 +2052,7 @@ def serve(args) -> None:
                 engine, serve_batch=serve_batch,
                 prefix_blocks=prefix_blocks,
                 prefix_block_len=prefix_block_len, replicas=replicas,
-                autotune=autotune_art, slo_itl_ms=slo_itl)
+                slo_itl_ms=slo_itl)
         except ValueError as e:
             sys.exit(f"error: {e}")
         serve_batch = autosize["serve_batch"]
@@ -2080,7 +2063,7 @@ def serve(args) -> None:
               + (f", --prefix-blocks {prefix_blocks} "
                  f"({autosize['prefix_blocks_basis']})"
                  if auto_blocks else "")
-              + f" — knee={inp['knee_rows']} [{inp['knee_basis']}], "
+              + f" — knee={inp['knee_rows']}, "
                 f"headroom_bytes={inp['headroom_bytes']}, "
                 f"slots_addable={inp['slots_addable']}")
     state = ApiState(engine, tokenizer, sampler,

@@ -34,8 +34,8 @@ import numpy as np
 
 def _int_or_auto(v: str):
     """argparse type for --serve-batch/--prefix-blocks: a plain int, or
-    the literal 'auto' — resolved at engine build from HBM-ledger
-    headroom capped by the calibrated batch knee (runtime/profiler.
+    the literal 'auto' — resolved at engine build from the default
+    batch knee capped by HBM-ledger headroom (runtime/profiler.
     resolve_auto_shape; docs/serving.md "Auto-sizing")."""
     s = v.strip().lower()
     if s == "auto":
@@ -195,8 +195,8 @@ def build_argparser() -> argparse.ArgumentParser:
                         "bound — B live slots amortize one weight read per "
                         "step for near-Bx aggregate tok/s; only the B-row "
                         "KV cache is new memory. 'auto' sizes B at startup "
-                        "from HBM-ledger headroom capped by the batch knee "
-                        "(--autotune artifact, or a conservative default) "
+                        "from a conservative default batch knee capped by "
+                        "HBM-ledger headroom "
                         "— the decision is logged and exported on /stats "
                         "(docs/serving.md 'Auto-sizing'). Single-process "
                         "engines only; --tp composes (the vocab-sharded "
@@ -232,14 +232,6 @@ def build_argparser() -> argparse.ArgumentParser:
                         "widens again when decode rows idle. Host-side "
                         "only: the width ladder is warmed up front, so "
                         "--freeze-compiles stays green while it adapts")
-    p.add_argument("--autotune", default=None, metavar="FILE",
-                   help="api mode, with --serve-batch auto or "
-                        "--prefix-blocks auto: AUTOTUNE.json calibration "
-                        "artifact (tools/autotune.py) supplying the "
-                        "measured batch knee that caps the auto-sizing; "
-                        "without it a conservative default knee applies. "
-                        "tools/dlprof.py consumes the same artifact "
-                        "offline to flag knee drift")
     # prefix-cache flags (api mode; runtime/prefix_cache.py,
     # docs/serving.md "Prefix caching")
     p.add_argument("--prefix-cache", action="store_true",

@@ -52,8 +52,8 @@ def _f8_bits_to(u8, out_dtype):
 
     Mosaic's own fp8 `astype` on v5e (no native fp8) lowers to a slow
     conversion that cost +0.74 ms/layer/token at 8k fill — the whole fp8
-    KV-cache regression of BENCH_r04 (tools/exp_f8_flash.py: astype 4.447
-    vs 3.686 ms/call for this decode, bit-exact). 16-bit vector shifts are
+    KV-cache regression recorded before PR 1 (tools/exp_f8_flash.py:
+    astype 4.447 vs 3.686 ms/call for this decode, bit-exact). 16-bit vector shifts are
     also unsupported, so the reassembly stays in 32-bit lanes: a normal
     number's f32 bits are sign<<31 | (exp+120)<<23 | mant<<20; subnormals
     (mag < 8) take an int->float ladder (value = mant * 2^-9, exact in
@@ -126,7 +126,7 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, out_ref, acc_ref, m_ref, l_ref,
             # e4m3 cache: HBM/VMEM/DMA stay narrow; reinterpret the block's
             # bits in-register (free) and do the exact upcast as cheap
             # 32-bit-lane VPU work before the dot (Mosaic's fp8 astype was
-            # the BENCH_r04 2.3x f8 stall; an XLA-side whole-cache bitcast
+            # a 2.3x f8 stall; an XLA-side whole-cache bitcast
             # materialized a copy per step and cost another ~50%)
             k = _f8_bits_to(jax.lax.bitcast_convert_type(k, jnp.uint8),
                             q.dtype)

@@ -2,9 +2,8 @@
 
 Drives the multihost control star (parallel/multihost.py RootLink /
 WorkerLink) WITHOUT a model, mesh, or jax.distributed cluster — pure
-host-side protocol — so the chaos tests (tests/test_cluster_chaos.py) and
-the bench cluster row (bench.py BENCH_CHAOS) can kill, stall, or corrupt
-either side of a real two-OS-process cluster and assert bounded detection
+host-side protocol — so the chaos tests (tests/test_cluster_chaos.py)
+can kill, stall, or corrupt either side of a real two-OS-process cluster and assert bounded detection
 in the NON-SLOW tier (no compiles, no fixtures; subprocess startup is the
 only cost).
 

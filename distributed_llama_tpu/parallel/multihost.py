@@ -151,8 +151,8 @@ class ClusterProtocolError(RuntimeError):
 
 def frame_bytes(n_ints: int, n_payload: int) -> int:
     """Exact on-the-wire size of one frame — header + 8 bytes per int +
-    payload. The reconciliation tests (and the bench cluster row) pin
-    the measured ledger against this arithmetic: the codec owns the
+    payload. The reconciliation tests pin the measured ledger against
+    this arithmetic: the codec owns the
     format, so the model lives next to it."""
     return _FRAME_HDR.size + 8 * int(n_ints) + int(n_payload)
 

@@ -3,9 +3,8 @@ own small KV cache, verified by the target engine's existing
 rejection-resampling machinery.
 
 Prompt-lookup speculation (runtime/speculative.py) only pays on
-repetitive text — its drafts come from the context's own n-grams, and the
-committed max-accept bench rows are best-case by construction (VERDICT
-#6). This module generalizes the win to ARBITRARY text by drafting from a
+repetitive text — its drafts come from the context's own n-grams. This
+module generalizes the win to ARBITRARY text by drafting from a
 real model:
 
   * **Self-draft (zero extra weights)** — the primary mode: the target

@@ -157,8 +157,7 @@ class Engine:
         force_mesh_kernels: bool = False,  # engage the shard_map kernel
         # path even on a 1-device mesh: the Pallas kernels then compile and
         # run INSIDE manual regions on whatever silicon is present — the
-        # single-chip proof of the multi-chip kernel path (VERDICT r4 #1;
-        # bench.py's shardmap variant row)
+        # single-chip proof of the multi-chip kernel path
         shard_vocab: bool | None = None,  # row-split tok_emb/wcls over the
         # vocab dim (ops/sharded_vocab.py): None = auto (on whenever the
         # mesh's tp axes divide the vocab — the replicated table was
@@ -290,7 +289,7 @@ class Engine:
             self._vocab_axes = ()
         self.shard_vocab = bool(self._vocab_axes)
         self.vocab_topk = int(vocab_topk)
-        # counters the /stats + bench rows surface: how often the sharded
+        # counters /stats surfaces: how often the sharded
         # fast path served a sample vs the replicated-row parity fallback
         self.vocab_sample_stats = {"sharded": 0, "fallback": 0}
 
@@ -2263,58 +2262,3 @@ class Engine:
             out.append([int(x) for x in row[row >= 0]])
         self.pos = int(min(lens.max() + n_steps, self.seq_len))
         return out
-
-    # -- on-device greedy decode loop (benchmark path) --------------------
-
-    def decode_greedy_device(self, first_token: int, n_tokens: int) -> tuple[np.ndarray, float]:
-        """Fully on-device greedy decode of n_tokens via lax.scan — no host
-        round-trip per token (net-new vs the reference's host loop; this is
-        the latency-optimal TPU decode path). Returns (tokens, seconds)."""
-
-        spec = self.spec
-        key = ("greedy", n_tokens)
-        if key not in self._steps:
-            common = self._forward_kwargs()
-
-            @partial(jax.jit, donate_argnums=(3,))
-            def run(params, tok0, pos0, cache):
-                def body(carry, _):
-                    tok, pos, cache = carry
-                    logits, cache = forward(
-                        params, spec, tok, pos, cache, **common)
-                    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                    return (nxt[:, None], pos + 1, cache), nxt
-
-                (_, _, cache), toks = jax.lax.scan(
-                    body, (tok0, pos0, cache), None, length=n_tokens)
-                return toks, cache
-
-            self._mint(key, run)
-            warm = True
-        else:
-            warm = False
-        run = self._steps[key]
-
-        tok0 = jnp.full((self.batch, 1), first_token, jnp.int32)
-        if self._token_sharding is not None:
-            tok0 = jax.device_put(tok0, self._token_sharding)
-
-        pos0 = jnp.int32(self.pos)
-
-        if warm:
-            # compile + warm (excluded from timing); caches are donated, so
-            # each call gets a fresh one. Repeat calls (bench best-of-N) hit
-            # the cached executable and skip this.
-            toks, _ = run(self.params, tok0, pos0, self._new_cache())
-            jax.block_until_ready(toks)
-
-        t0 = time.perf_counter()
-        toks, cache = run(self.params, tok0, pos0, self._new_cache())
-        # toks depends on every decode step: waiting on it ends the timed
-        # region; the fetch below is outside it
-        jax.block_until_ready(toks)
-        dt = time.perf_counter() - t0
-        toks_np = np.asarray(toks)  # dlgrind: ignore[DLG107]
-        self.cache = cache
-        self.pos += n_tokens
-        return toks_np, dt

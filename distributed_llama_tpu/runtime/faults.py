@@ -91,7 +91,7 @@ count-deterministically testable (tests/test_fleet.py):
                          (the abrupt-death shape at a protocol point)
 
 Arming is test-driven (``FAULTS.arm(...)``) or env-driven for subprocess
-harnesses (bench chaos rows, CI):
+harnesses (CI):
 
     DLLAMA_FAULTS="step_raise:after=40;times=1,slow_step:ms=50;times=0"
 

@@ -37,8 +37,7 @@ machinery:
 Every block frame is accounted in a dlwire ledger (stats.WireStats, per
 (peer, kind, dir)) from day one, so ``netstats.reconcile_wire`` closes
 measured-vs-modeled over block traffic at the same 25% bar as the
-cluster plane, and ``netstats.estimate_block_transfer`` models when a
-transfer pays against the re-prefill it replaces. ``dlprof --wire``
+cluster plane. ``dlprof --wire``
 renders the "KV transfer" section from these blocks.
 
 Thread model: the donor's export loop holds the donor scheduler's step

@@ -22,7 +22,7 @@ control plane's socket ledger (parallel/multihost.py → stats.WireStats)
 counts real bytes per (peer, kind, direction), :func:`per_step_op_ms`
 attributes real device collective ms per executed step from a profiler
 capture, and :func:`reconcile_wire` closes the loop — measured against
-modeled, drift flagged at ≥25% like the autotune knee check.
+modeled, drift flagged at ≥25%.
 """
 
 from __future__ import annotations
@@ -159,128 +159,8 @@ def estimate_serve_wire(
                         {k: v / occ for k, v in step.breakdown.items()})
 
 
-def estimate_prefix_reuse(
-    spec: ModelSpec,
-    mesh,
-    *,
-    tokens_saved: int,
-    tokens_copied: int | None = None,
-    cache_bytes: float = 2.0,
-    q80: bool = False,
-    act_bytes: int = 4,
-    batch: int = 1,
-) -> dict:
-    """Modeled cost/benefit of serving `tokens_saved` prompt tokens from
-    the radix prefix cache (runtime/prefix_cache.py) instead of
-    prefilling them.
-
-    A seeded token SKIPS its prefill forward entirely, so it saves the
-    full per-token collective payload of a forward — the same per-layer
-    reduces estimate_decode_wire models (prefill segments move the same
-    per-token bytes as decode; only the segment width batches them).
-    What it pays instead is a pure-HBM block copy that rides NO
-    collective: 2 (K and V) * layers * kv_heads * head_size *
-    cache_bytes per token COPIED — and `tokens_copied` is NOT
-    `tokens_saved`: Engine.slot_seed_prefix always gathers the FULL
-    fixed seed width (seq_len // block_len blocks, the price of keeping
-    ONE compilation key), so every hit copies ~seq_len tokens' worth of
-    K/V however short the match. Callers must pass the real figure
-    (hits * (seq_len // block_len) * block_len); it defaults to
-    tokens_saved only as the lower bound. This is why a deep context
-    with tiny matches can pay more HBM than it saves — and why the
-    bench row reports both numbers side by side.
-
-    The wire side is why prefix reuse is still a near-strict win on
-    meshes: the copy rides no collective, HBM bandwidth is orders of
-    magnitude above ICI for the same bytes, and on a single chip the
-    copy replaces whole forwards' weight reads + FLOPs.
-
-    Returns {"wire_saved_kb", "hbm_copy_kb", "kb_saved_per_token"} —
-    the bench's BENCH_PREFIX row reports these next to the measured
-    TTFT delta."""
-    per_tok_kb = estimate_decode_wire(spec, mesh, q80=q80,
-                                      act_bytes=act_bytes,
-                                      batch=batch).sent_kb_per_token
-    copy_b = spec.cache_values_per_token * cache_bytes
-    copied = tokens_saved if tokens_copied is None else tokens_copied
-    return {
-        "wire_saved_kb": round(per_tok_kb * tokens_saved, 3),
-        "hbm_copy_kb": round(copy_b * copied / 1024.0, 3),
-        "kb_saved_per_token": round(per_tok_kb, 4),
-    }
-
-
-def estimate_block_transfer(
-    spec: ModelSpec,
-    *,
-    tokens: int,
-    block_len: int,
-    cache_bytes: float = 2.0,
-    link_gbps: float | None = None,
-    prefill_tok_per_s: float | None = None,
-    mesh=None,
-    q80: bool = False,
-    batch: int = 1,
-) -> dict:
-    """Model one cross-replica KV block transfer (runtime/kv_transfer.py)
-    against the re-prefill it replaces — the "when does a fill pay"
-    arithmetic (docs/serving.md "KV block transfer").
-
-    The WIRE side is exact: ``tokens`` rounds down to whole blocks, each
-    block ships one RMSG_BLOCK_DATA frame of 2 (K and V) * layers *
-    kv_heads * block_len * head_size * cache_bytes payload plus the
-    framed-codec overhead (parallel/multihost.frame_bytes — the same
-    arithmetic the dlwire reconcile tests pin the measured ledger
-    against), bracketed by the HELLO/QUERY/ACK/FETCH/END frames. The
-    REPLACED side is the prefill forward those tokens would have run:
-    per-token collective bytes (estimate_decode_wire — prefill moves the
-    same per-token reduces as decode, batched by segment width) and, when
-    a measured ``prefill_tok_per_s`` is given, the wall time. With a
-    ``link_gbps`` both sides resolve to milliseconds and ``pays`` says
-    whether the transfer wins; without them the byte model stands alone
-    (``pays`` = None — never fabricated).
-
-    ``modeled_data_bytes`` is the exact figure ``reconcile_wire`` closes
-    against the measured BLOCK_DATA ledger entry at the 25% bar."""
-    from ..parallel.multihost import frame_bytes
-
-    bl = int(block_len)
-    n_blocks = max(int(tokens), 0) // bl
-    per_block = int(spec.cache_values_per_token * bl * cache_bytes)
-    data_bytes = n_blocks * frame_bytes(1, per_block)
-    # HELLO [v] + QUERY [requester, n_have, *tokens] + FETCH [s, e] tx;
-    # HELLO_ACK [5] + ACK [7] + END [1] rx — tiny next to the payload,
-    # counted so the model reconciles frame-exactly
-    overhead = (frame_bytes(1, 0) + frame_bytes(2 + int(tokens), 0)
-                + frame_bytes(2, 0) + frame_bytes(5, 0)
-                + frame_bytes(7, 0) + frame_bytes(1, 0))
-    out = {
-        "tokens": n_blocks * bl,
-        "n_blocks": n_blocks,
-        "block_payload_bytes": per_block,
-        "modeled_data_bytes": data_bytes,
-        "overhead_bytes": overhead,
-        "transfer_bytes": data_bytes + overhead,
-        "reprefill_wire_kb": round(
-            estimate_decode_wire(spec, mesh, q80=q80,
-                                 batch=batch).sent_kb_per_token
-            * n_blocks * bl, 3),
-        "transfer_ms": None, "reprefill_ms": None, "pays": None,
-    }
-    if link_gbps:
-        out["transfer_ms"] = round(
-            (data_bytes + overhead) * 8 / (link_gbps * 1e9) * 1e3, 3)
-    if prefill_tok_per_s:
-        out["reprefill_ms"] = round(
-            n_blocks * bl / prefill_tok_per_s * 1e3, 3)
-    if out["transfer_ms"] is not None and out["reprefill_ms"] is not None:
-        out["pays"] = out["transfer_ms"] < out["reprefill_ms"]
-    return out
-
-
-# measured-vs-modeled movement worth flagging, the same 25% bar the
-# autotune knee-drift check uses (tools/dlprof.py mirrors both — it must
-# run with no repo on the path; tests pin the mirrors against each other)
+# measured-vs-modeled movement worth flagging (tools/dlprof.py mirrors
+# it — it must run with no repo on the path; tests pin the mirror)
 WIRE_DRIFT_FRAC = 0.25
 
 

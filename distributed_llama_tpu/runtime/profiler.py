@@ -27,8 +27,8 @@ hand-running ``jax.profiler`` offline. This module is the device half:
     CPU test runs report the exact shape-derived bytes with device
     fields null), plus the headroom estimate — ``slots_addable`` /
     ``prefix_blocks_addable`` — that item 1's auto-sizing consumes.
-    Exported as ``dllama_hbm_bytes{category=}`` gauges, the ``hbm``
-    /stats block, and a block on every BENCH row.
+    Exported as ``dllama_hbm_bytes{category=}`` gauges and the ``hbm``
+    /stats block.
   * **On-demand capture** (:meth:`Profiler.capture`) — the
     ``POST /admin/profile?ms=`` body: one bounded ``jax.profiler`` trace
     written to a directory, refusals instead of concurrent captures
@@ -238,7 +238,7 @@ class CompileLedger:
                     "by_key": {k: dict(v) for k, v in self.by_key.items()}}
 
     def reset(self) -> None:
-        """Test/bench isolation; the singleton survives."""
+        """Test isolation; the singleton survives."""
         with self._lock:
             self.freeze = False
             self.total = 0
@@ -313,7 +313,7 @@ def device_memory_stats():
 def hbm_ledger(engine, prefix_cache=None, *, block_len: int | None = None,
                device_stats: dict | None | bool = True) -> dict:
     """Per-category live-bytes for one engine — the ``hbm`` block of
-    /stats and every BENCH row.
+    /stats.
 
     Categories, all derived from KNOWN allocated shapes (exact for
     weights / KV slots / arena — they are real array ``nbytes``;
@@ -439,83 +439,26 @@ def hbm_ledger(engine, prefix_cache=None, *, block_len: int | None = None,
 
 # -- auto-sizing (the measurement→decision half of ROADMAP item 1) ----------
 
-AUTOTUNE_VERSION = 1
-AUTOTUNE_KIND = "dllama-autotune"
-# heuristic knee when no calibration artifact is given: decode is
-# weight-read-bound, so batching keeps paying until KV traffic competes
-# with the weight read — 32 rows is the conservative cross-model default
-# the ladder bench rows support; calibrate with tools/autotune.py for the
-# real number on YOUR silicon (docs/serving.md "Auto-sizing")
+# heuristic knee: decode is weight-read-bound, so batching keeps paying
+# until KV traffic competes with the weight read — 32 rows is the
+# conservative cross-model default (docs/serving.md "Auto-sizing")
 DEFAULT_KNEE_ROWS = 32
-
-
-def validate_autotune(art) -> list[str]:
-    """Schema problems of one AUTOTUNE.json artifact (empty = valid).
-    Shared contract with tools/autotune.py (the producer) and
-    tools/dlprof.py (which re-validates standalone — it must run with no
-    repo on the path)."""
-    problems = []
-    if not isinstance(art, dict):
-        return ["not a JSON object"]
-    if art.get("kind") != AUTOTUNE_KIND:
-        problems.append(f"kind must be {AUTOTUNE_KIND!r}, "
-                        f"got {art.get('kind')!r}")
-    if art.get("version") != AUTOTUNE_VERSION:
-        problems.append(f"version must be {AUTOTUNE_VERSION}, "
-                        f"got {art.get('version')!r}")
-    knee = art.get("knee")
-    if not isinstance(knee, dict) or not knee.get("knee_rows"):
-        problems.append("missing knee.knee_rows (re-run the calibration "
-                        "with >= 1 measured batch size)")
-    if not isinstance(art.get("decode_curve"), list):
-        problems.append("missing decode_curve list")
-    return problems
-
-
-def load_autotune(path: str) -> dict:
-    """Read + validate an AUTOTUNE.json calibration artifact
-    (tools/autotune.py). Raises ValueError with every schema problem
-    named — a bad artifact must be a clear startup error, never a wrong
-    silent batch size."""
-    import json
-
-    with open(path) as f:
-        art = json.load(f)
-    problems = validate_autotune(art)
-    if problems:
-        raise ValueError("invalid autotune artifact: " + "; ".join(problems))
-    return art
 
 
 def resolve_auto_shape(engine, *, serve_batch, prefix_blocks=0,
                        prefix_block_len: int = 32, replicas: int = 1,
-                       autotune: dict | None = None,
                        default_knee: int = DEFAULT_KNEE_ROWS,
                        slo_itl_ms: float | None = None,
-                       itl_budget_frac: float = 0.2,
                        device_stats=True) -> dict:
     """Resolve the ``--serve-batch auto`` / ``--prefix-blocks auto``
-    sentinels at engine-build time: HBM-ledger headroom capped by the
-    calibrated batch knee (vLLM's size-from-measured-memory precedent
-    composed with the dlprof knee estimate).
+    sentinels at engine-build time: the batch knee capped by HBM-ledger
+    headroom (vLLM's size-from-measured-memory precedent).
 
-      * serve_batch  — the calibrated target capped by the slots the
+      * serve_batch  — `default_knee` rows capped by the slots the
         free HBM can hold, split across `replicas` (thread replicas
         share weights but each owns a B-row cache). Where the backend
-        reports no allocator stats (CPU), the target stands alone.
-        The target itself: the knee (where marginal throughput per
-        added row halves), RAISED to the largest measured batch whose
-        decode-step p50 still fits ``itl_budget_frac`` of
-        ``slo_itl_ms`` when an ITL SLO and a calibration curve are
-        both present — the knee is an EFFICIENCY floor, but an SLO
-        budget can afford capacity past it. The budget fraction is
-        deliberately small (default 0.2): a mixed iteration's wall is
-        the decode forward PLUS one (B, C) chunk forward (measured at
-        2-4 decode-forwards' cost — the artifact's
-        ``prefill_ms_by_width``), and the admission policy must be
-        able to hold the WIDEST rung without shrinking, p99 noise
-        included. This is the "re-derive with your own threshold" use
-        the knee estimator's curve exists for.
+        reports no allocator stats (CPU), the knee stands alone.
+        ``slo_itl_ms`` is recorded beside the decision, not applied.
       * prefix_blocks — the existing 2×B×context heuristic target,
         capped at HALF the blocks the free HBM could hold (the arena
         must not eat the headroom the slots were just granted).
@@ -527,56 +470,23 @@ def resolve_auto_shape(engine, *, serve_batch, prefix_blocks=0,
     owes the operator a clear startup error, not a crash mid-build.
 
     Returns the full decision record — chosen values, every input, and
-    the basis ("autotune" | "default_heuristic" | "hbm_cap" | "static")
-    — which the API server logs at startup and exports on /stats and
-    /metrics so an operator can always see WHAT was chosen and WHY."""
+    the basis ("default_heuristic" | "hbm_cap" | "context_heuristic" |
+    "static") — which the API server logs at startup and exports on
+    /stats and /metrics so an operator can always see WHAT was chosen
+    and WHY."""
     if getattr(engine, "params", None) is None or not hasattr(engine,
                                                               "cache"):
         raise ValueError(
             "auto sizing needs a ledger-capable local engine (the "
             "process tier's workers own their engines — pass explicit "
-            "sizes there; calibrate with tools/autotune.py and read the "
-            "recommendation)")
+            "sizes there)")
     ledger = hbm_ledger(engine, block_len=prefix_block_len,
                         device_stats=device_stats)
     replicas = max(int(replicas), 1)
-    knee = None
-    knee_basis = "default_heuristic"
-    if autotune is not None:
-        import jax
-
-        live = jax.default_backend()
-        if autotune.get("backend") != live:
-            # a knee measured on another backend says nothing about this
-            # one (the committed AUTOTUNE.json is a CPU run of the tiny
-            # preset): refuse it where it would be applied
-            raise ValueError(
-                f"autotune artifact was calibrated on backend "
-                f"{autotune.get('backend')!r} (model "
-                f"{autotune.get('model')!r}) but this engine runs on "
-                f"{live!r} — recalibrate with tools/autotune.py on this "
-                "backend, or omit --autotune for the default heuristic")
-        k = (autotune.get("knee") or {}).get("knee_rows")
-        if k:
-            knee = int(k)
-            knee_basis = "autotune"
-    if knee is None:
-        knee = int(default_knee)
-    target, target_basis = knee, knee_basis
-    rows_under_slo = None
-    if slo_itl_ms and autotune is not None:
-        budget = float(itl_budget_frac) * float(slo_itl_ms)
-        afford = [int(p["rows"]) for p in autotune.get("decode_curve") or ()
-                  if p.get("p50_ms") is not None and p["p50_ms"] <= budget]
-        if afford:
-            rows_under_slo = max(afford)
-            if rows_under_slo > target:
-                target, target_basis = rows_under_slo, "slo_curve"
+    knee = int(default_knee)
     inputs = {
         "knee_rows": knee,
-        "knee_basis": knee_basis,
         "slo_itl_ms": slo_itl_ms,
-        "rows_under_itl_slo": rows_under_slo,
         "replicas": replicas,
         "per_slot_bytes": ledger["per_slot_bytes"],
         "per_block_bytes": ledger["per_block_bytes"],
@@ -589,11 +499,11 @@ def resolve_auto_shape(engine, *, serve_batch, prefix_blocks=0,
         cap = None
         if ledger["slots_addable"] is not None:
             cap = max(int(ledger["slots_addable"]) // replicas, 1)
-        b = min(target, cap) if cap is not None else target
+        b = min(knee, cap) if cap is not None else knee
         out["serve_batch"] = max(int(b), 1)
         out["serve_batch_basis"] = ("hbm_cap"
-                                    if cap is not None and cap < target
-                                    else target_basis)
+                                    if cap is not None and cap < knee
+                                    else "default_heuristic")
     else:
         out["serve_batch"] = int(serve_batch)
         out["serve_batch_basis"] = "static"

@@ -618,9 +618,8 @@ class ReplicaServer:
         out["tier"] = self.tier
         if TRACER.enabled:
             # the step timeline is WORKER-local (the parent never sees
-            # our iterations) — ride it on the stats reply so the bench
-            # procs row and a curious operator get it across the
-            # boundary without a new verb
+            # our iterations) — ride it on the stats reply so an
+            # operator gets it across the boundary without a new verb
             out["step_timeline"] = TRACER.steps.summary_json()
             out["trace"] = TRACER.summary()
         return out
@@ -652,7 +651,7 @@ def build_supervisor_factory(cfg: dict):
         deterministic synthetic weights (models/params.random_tensors),
         so a parent process building the SAME spec/seed holds
         bit-identical params — the greedy-parity oracle for the
-        process-kill chaos tests and the bench row.
+        process-kill chaos tests.
       * ``model`` — a reference-format ``.m`` path, streamed exactly like
         the CLI loads it (each worker process owns its weights: process
         isolation trades the thread tier's shared buffers for a real

@@ -251,7 +251,7 @@ class EngineSupervisor:
             # a close that lands mid-rebuild must WAIT for the rebuild's
             # factory/warmup to notice _stop: a daemon thread still inside
             # an XLA compile when the interpreter finalizes is a segfault,
-            # not a clean exit (seen as intermittent rc=-11 in the bench
+            # not a clean exit (seen as intermittent rc=-11 in a
             # subprocess after a kill-then-close chaos pass)
             rebuild.join(timeout=max(end - time.perf_counter(), 1.0))
         if self._watchdog_thread.is_alive():
@@ -273,8 +273,8 @@ class EngineSupervisor:
         end = time.perf_counter() + timeout
         while time.perf_counter() < end:
             sched = self._sched
-            # lock-free busy check (has_work() takes the step mutex, which
-            # a wedged forward may hold forever)
+            # lock-free busy check (the step mutex is one a wedged
+            # forward may hold forever)
             if not sched._queue and all(s.req is None for s in sched.slots):
                 return True
             time.sleep(0.02)

@@ -49,8 +49,7 @@ the dlgrind fingerprint set is unchanged by construction.
 
 Chaos surface: each replica's scheduler carries ``fault_key="r{i}"``, so
 the ``replica_raise``/``replica_stall`` sites (runtime/faults.py) kill or
-wedge ONE replica deterministically mid-trace (tests/test_router.py, the
-``BENCH_ROUTER=1`` bench row).
+wedge ONE replica deterministically mid-trace (tests/test_router.py).
 """
 
 from __future__ import annotations

@@ -48,8 +48,8 @@ whole tree is invalidated whenever the engine generation dies
 (``_abort_all`` — the arena dies with the engine).
 
 Thread model: ``submit()`` is thread-safe; the step loop runs either on
-the ``start()`` background thread or synchronously via ``step()`` (tests,
-the bench). ``exclusive()`` drains all in-flight work and lends the
+the ``start()`` background thread or synchronously via ``step()``
+(tests). ``exclusive()`` drains all in-flight work and lends the
 batched engine to a legacy whole-batch caller (apps/api_server's
 /v1/batch/completions), so one process never holds two live batched
 caches.
@@ -550,15 +550,10 @@ class Scheduler:
         """One scheduling iteration: admit queued requests into free slots,
         run one chunked-prefill forward for prefilling rows, one decode
         step for decoding rows. Returns False when there was no work.
-        Synchronous entry point (tests/bench drive it directly; the
+        Synchronous entry point (tests drive it directly; the
         background thread calls the same body)."""
         with self._mutex:
             return self._step_locked()
-
-    def has_work(self) -> bool:
-        with self._mutex:
-            return bool(self._queue) or any(s.req is not None
-                                            for s in self.slots)
 
     def _step_locked(self) -> bool:
         self._step_t0 = time.perf_counter()  # watchdog heartbeat: in-step
@@ -676,9 +671,9 @@ class Scheduler:
         st.host_ms += wall_ms - wait_ms
         if TRACER.enabled:
             # step timeline: batch composition + wall ms, the raw
-            # measurement behind /metrics' dllama_step_ms and the bench
-            # step_timeline blocks (ROADMAP item 1's knee search), with
-            # the iteration's number, start and ms per phase
+            # measurement behind /metrics' dllama_step_ms (ROADMAP item
+            # 1's knee search), with the iteration's number, start and
+            # ms per phase
             TRACER.step(decode_rows=len(dec), prefill_rows=len(pre),
                         chunk=cw, queue_depth=len(self._queue),
                         wall_ms=wall_ms, key=self.fault_key, n=st.steps,

@@ -176,8 +176,8 @@ class StepTimelineStats:
     """Per-batch-composition step-duration histograms (owned by
     runtime/trace.Tracer): every scheduler iteration records its wall ms
     keyed by (decode_rows, prefill_rows, chunk) — the raw measurement the
-    batch-knee search (ROADMAP item 1) needs, the ``dllama_step_ms``
-    /metrics family, and the bench rows' ``step_timeline`` block.
+    batch-knee search (ROADMAP item 1) needs and the ``dllama_step_ms``
+    /metrics family.
     Bounded: ``window`` samples per composition, at most ``max_keys``
     distinct compositions (the composition space is small by
     construction — decode_rows and prefill_rows are <= batch, chunk is
@@ -222,8 +222,8 @@ class StepTimelineStats:
         return out
 
     def summary_json(self) -> dict:
-        """summary() with string keys ("dec4_pre1_c16") — the BENCH json
-        block (tuple keys do not survive json.dumps)."""
+        """summary() with string keys ("dec4_pre1_c16") — the worker's
+        stats reply (tuple keys do not survive json.dumps)."""
         return {f"dec{k[0]}_pre{k[1]}_c{k[2]}": v
                 for k, v in self.summary().items()}
 
@@ -324,8 +324,8 @@ class ServeStats:
         self.queue_depth = deque(maxlen=self.window)
 
     def summary(self) -> dict:
-        """JSON-ready snapshot (the API server's GET /stats and the bench's
-        Poisson-arrival row both emit this). Percentiles and occupancy
+        """JSON-ready snapshot (the API server's GET /stats emits
+        this). Percentiles and occupancy
         cover the sliding window; the totals are lifetime counters."""
         ttfts = [r.ttft_ms for r in self.requests if r.ttft_ms is not None]
         itls = [r.itl_ms for r in self.requests if r.itl_ms is not None]
@@ -443,15 +443,6 @@ class WireStats:
                        for kind in (dirs.get(direction) or {},)
                        for rec in kind.values())
 
-    def peer_bytes(self, peer: int, kind: str, direction: str) -> int:
-        """Exact measured bytes for one (peer, kind, dir) — the
-        reconciliation tests compare this against frame-size
-        arithmetic."""
-        with self._lock:
-            rec = ((self._counts.get(int(peer)) or {})
-                   .get(direction) or {}).get(kind)
-            return rec[1] if rec else 0
-
     def summary(self) -> dict:
         with self._lock:
             peers = {}
@@ -470,8 +461,8 @@ class WireStats:
                         "p50_ms": round(percentile(rtts, 50), 4),
                         "p99_ms": round(percentile(rtts, 99), 4),
                         "mean_ms": round(sum(rtts) / len(rtts), 4),
-                        # a short raw tail so offline consumers (the bench
-                        # cluster row's step_timeline) can re-histogram
+                        # a short raw tail so offline consumers can
+                        # re-histogram
                         "recent": [round(v, 4) for v in rtts[-self.recent:]],
                     }
                 off = self._offset.get(peer)
@@ -749,8 +740,8 @@ class ProcStats:
     runtime/router.RemoteReplicaHandle (local-spawn mode): every worker
     exit is CLASSIFIED (``classify_exit`` — ``signal:SIGKILL``,
     ``config_error``, ``fault_exit``, ...) and the respawn-to-routable
-    latency distribution is what the process-kill chaos tests and the
-    ``BENCH_ROUTER=1`` process row assert their bound against. Surfaced
+    latency distribution is what the process-kill chaos tests assert
+    their bound against. Surfaced
     as the ``proc`` block of each replica's /stats summary."""
 
     respawns: int = 0         # successful respawn-to-routable cycles
@@ -806,7 +797,7 @@ class SupervisorStats:
         from collections import deque
 
         # failure-detected -> ready-again latency, the recovery-time
-        # distribution the bench chaos row reports
+        # distribution /stats reports
         self.recovery_ms = deque(maxlen=1000)
 
     def summary(self) -> dict:

@@ -2,7 +2,7 @@
 
 The serving stack is five layers deep (router → worker process →
 supervisor → scheduler → engine) but until this module its only window
-was aggregate ``/stats`` snapshots: when the chaos bench SIGKILLs a
+was aggregate ``/stats`` snapshots: when a chaos test SIGKILLs a
 worker mid-stream nothing could reconstruct WHICH request died WHERE,
 and the batch-knee search (ROADMAP item 1) had no per-iteration data to
 mine. Orca frames scheduling as an iteration-level tradeoff — chunked-
@@ -226,7 +226,7 @@ class Tracer:
     to a bounded ring (``deque.append`` — atomic under the GIL, no lock
     on the hot path) and optionally persists sampled spans to the JSONL
     sink. ``step()`` additionally feeds the per-composition step-ms
-    histograms behind /metrics and the bench ``step_timeline`` blocks.
+    histograms behind /metrics.
     """
 
     def __init__(self):
@@ -303,9 +303,8 @@ class Tracer:
             self.spans = self.enabled or self.capturing
 
     def reset(self) -> None:
-        """Disable and drop all state (test teardown; bench row
-        isolation). The singleton survives — call sites keep their
-        reference."""
+        """Disable and drop all state (test teardown). The singleton
+        survives — call sites keep their reference."""
         with self._lock:
             self.enabled = False
             self.spans = self.capturing
@@ -418,7 +417,7 @@ class Tracer:
              n: int | None = None, ts0: float | None = None,
              phases: dict | None = None) -> None:
         """One scheduler iteration: ring record + the per-composition
-        histogram /metrics and the bench knee-search read. `n` is the
+        histogram /metrics reads. `n` is the
         iteration's number (the `step` field of the request events it
         caused), `ts0` its start, `phases` the ms of its closed spans by
         name — the step's own time is `ms` less their sum."""
@@ -516,9 +515,8 @@ class Tracer:
                 for e in self.by_id(tid)]
 
     def step_timeline(self) -> dict:
-        """Per-composition step-ms summary (p50/p99/mean/n) — the bench
-        ``step_timeline`` block and the /metrics ``dllama_step_ms``
-        family."""
+        """Per-composition step-ms summary (p50/p99/mean/n) — the
+        /metrics ``dllama_step_ms`` family."""
         return self.steps.summary()
 
     def summary(self) -> dict:
@@ -1060,8 +1058,6 @@ def render_prometheus(summary: dict | None, *, tracer: Tracer | None = None,
                       help_="Auto-resolved --prefix-blocks (arena blocks)")
             p.add("dllama_autosize_knee_rows",
                   (auto.get("inputs") or {}).get("knee_rows"),
-                  {"basis": _esc((auto.get("inputs") or {})
-                                 .get("knee_basis"))},
                   help_="Batch knee that capped the auto-sizing")
         _add_admission(p, summary.get("admission"))
         _add_spec(p, summary.get("spec"))

@@ -50,9 +50,8 @@ def tiny_mla_spec(weights_float_type: FloatType = FloatType.Q40,
 
 
 def free_port() -> int:
-    """An OS-assigned free TCP port (shared by the cluster tests, the
-    chaos harness spawners, and bench's cluster row — one home for the
-    bind-port-0 idiom)."""
+    """An OS-assigned free TCP port (shared by the cluster tests and the
+    chaos harness spawners — one home for the bind-port-0 idiom)."""
     import socket
 
     with socket.socket() as s:

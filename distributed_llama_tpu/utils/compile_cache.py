@@ -2,8 +2,8 @@
 directory is chosen.
 
 Every process that compiles calls `ensure_compile_cache()` once, before its
-first compile: the `dllama` CLI, replica workers, bench.py, chip_smoke.py's
-children and the test suite. The cache directory is part of the cache key,
+first compile: the `dllama` CLI, replica workers, chip_smoke.py's children
+and the test suite. The cache directory is part of the cache key,
 so it must be identical in every process that should share compiles — a
 replica worker, its respawns and the next boot of the same server all hit
 what the first one compiled.

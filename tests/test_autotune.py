@@ -1,13 +1,11 @@
-"""The closed batch-knee loop (ISSUE 11): calibration artifact contract,
-startup auto-sizing (``--serve-batch auto`` / ``--prefix-blocks auto``
-via runtime/profiler.resolve_auto_shape), and the SLO-aware self-tuning
+"""Startup auto-sizing (``--serve-batch auto`` / ``--prefix-blocks auto``
+via runtime/profiler.resolve_auto_shape) and the SLO-aware self-tuning
 admission policy (runtime/scheduler.AdmissionPolicy).
 
 The contracts under test:
 
   * auto-sizing NEVER exceeds what the HBM ledger says fits
-    (headroom-capped), never exceeds the calibrated knee without an SLO
-    budget that affords it (knee-capped / slo-curve-raised), and refuses
+    (headroom-capped), never exceeds the knee (knee-capped), and refuses
     a ledger-less engine with a clear error instead of crashing;
   * the adaptive chunk width converges to the ladder floor under a
     synthetic slow-step fault (the ``slow_step`` site) and recovers;
@@ -21,9 +19,6 @@ The contracts under test:
     rules), before any model load.
 """
 
-import os
-import sys
-
 import pytest
 
 jnp = pytest.importorskip("jax.numpy")
@@ -33,17 +28,12 @@ from distributed_llama_tpu.models import ArchType, HiddenAct, ModelSpec
 from distributed_llama_tpu.models.params import load_params, random_tensors
 from distributed_llama_tpu.runtime.engine import Engine
 from distributed_llama_tpu.runtime.faults import FAULTS
-from distributed_llama_tpu.runtime.profiler import (COMPILES, load_autotune,
-                                                    resolve_auto_shape,
-                                                    validate_autotune)
+from distributed_llama_tpu.runtime.profiler import (COMPILES,
+                                                    DEFAULT_KNEE_ROWS,
+                                                    resolve_auto_shape)
 from distributed_llama_tpu.runtime.scheduler import (AdmissionPolicy,
                                                      Scheduler, chunk_ladder)
 from distributed_llama_tpu.sampler import Sampler
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, os.path.join(REPO, "tools"))
-
-import dlprof  # noqa: E402
 
 SEQ = 64
 
@@ -62,50 +52,6 @@ def _greedy(spec):
     return Sampler(spec.vocab_size, temperature=0.0, topp=0.9, seed=1)
 
 
-def _artifact(knee_rows=4, curve=None):
-    return {"kind": "dllama-autotune", "version": 1, "model": "tiny",
-            "backend": "cpu", "created_unix": 0.0,
-            "decode_curve": curve if curve is not None else [],
-            "knee": {"knee_rows": knee_rows,
-                     "method": "marginal_throughput"}}
-
-
-# -- artifact contract ------------------------------------------------------
-
-
-def test_validators_agree_and_loader_refuses_garbage(tmp_path):
-    """The canonical validator (runtime/profiler — what --serve-batch
-    auto trusts) and dlprof's standalone mirror must accept and reject
-    the SAME artifacts (dlprof duplicates on purpose: it runs with no
-    repo on the path)."""
-    import json
-
-    good = _artifact()
-    bad_version = dict(good, version=99)
-    bad_kind = dict(good, kind="bogus")
-    kneeless = dict(good, knee={})
-    for art, ok in ((good, True), (bad_version, False), (bad_kind, False),
-                    (kneeless, False)):
-        assert (not validate_autotune(art)) is ok, art
-        assert (not dlprof.validate_autotune(art)) is ok, art
-    p = tmp_path / "AUTOTUNE.json"
-    p.write_text(json.dumps(bad_version))
-    with pytest.raises(ValueError, match="version"):
-        load_autotune(str(p))
-    p.write_text(json.dumps(good))
-    assert load_autotune(str(p))["knee"]["knee_rows"] == 4
-
-
-def test_committed_artifact_validates():
-    """The committed AUTOTUNE.json (the CPU-tiny calibration this PR
-    ships) must satisfy the loader contract its consumers trust."""
-    art = load_autotune(os.path.join(REPO, "AUTOTUNE.json"))
-    assert art["backend"] == "cpu" and art["model"] == "tiny"
-    assert art["knee"]["knee_rows"] >= 1
-    assert len(art["decode_curve"]) >= 5  # the committed grid is 2..128
-    assert art["prefill_ms_by_width"]  # the adaptive ladder was measured
-
-
 # -- auto-sizing ------------------------------------------------------------
 
 
@@ -118,58 +64,40 @@ def test_auto_batch_headroom_capped(tiny):
     per_slot = int(sum(x.nbytes for x in
                        __import__("jax").tree_util.tree_leaves(eng.cache)))
     dec = resolve_auto_shape(
-        eng, serve_batch="auto", autotune=_artifact(knee_rows=32),
+        eng, serve_batch="auto", default_knee=32,
         device_stats={"bytes_in_use": 0, "bytes_limit": 5 * per_slot})
     assert dec["serve_batch"] == 5
     assert dec["serve_batch_basis"] == "hbm_cap"
     assert dec["inputs"]["slots_addable"] == 5
     # replicas split the same headroom
     dec2 = resolve_auto_shape(
-        eng, serve_batch="auto", replicas=2,
-        autotune=_artifact(knee_rows=32),
+        eng, serve_batch="auto", replicas=2, default_knee=32,
         device_stats={"bytes_in_use": 0, "bytes_limit": 5 * per_slot})
     assert dec2["serve_batch"] == 2
 
 
 def test_auto_batch_knee_capped(tiny):
-    """With ample headroom the calibrated knee is the cap; without an
-    artifact the conservative default heuristic applies."""
+    """With ample headroom the knee is the cap; unset, it is the
+    conservative default."""
     spec, params = tiny
     eng = Engine(spec, params, batch=1, compute_dtype=jnp.float32,
                  cache_dtype=jnp.float32)
     dec = resolve_auto_shape(
-        eng, serve_batch="auto", autotune=_artifact(knee_rows=4),
+        eng, serve_batch="auto", default_knee=4,
         device_stats={"bytes_in_use": 0, "bytes_limit": 1 << 40})
     assert dec["serve_batch"] == 4
-    assert dec["serve_batch_basis"] == "autotune"
-    dec2 = resolve_auto_shape(eng, serve_batch="auto", autotune=None,
-                              device_stats=None)
-    from distributed_llama_tpu.runtime.profiler import DEFAULT_KNEE_ROWS
-
+    assert dec["serve_batch_basis"] == "default_heuristic"
+    assert dec["inputs"]["knee_rows"] == 4
+    dec2 = resolve_auto_shape(eng, serve_batch="auto", device_stats=None)
     assert dec2["serve_batch"] == DEFAULT_KNEE_ROWS
     assert dec2["serve_batch_basis"] == "default_heuristic"
-
-
-def test_auto_batch_slo_curve_raises_target(tiny):
-    """An ITL SLO budget can afford capacity past the knee: with the
-    curve showing batch 16 still under 0.2 x SLO, the target rises to
-    16 — and a static serve_batch passes through untouched."""
-    spec, params = tiny
-    eng = Engine(spec, params, batch=1, compute_dtype=jnp.float32,
-                 cache_dtype=jnp.float32)
-    curve = [{"rows": 4, "p50_ms": 10.0}, {"rows": 8, "p50_ms": 11.0},
-             {"rows": 16, "p50_ms": 14.0}, {"rows": 32, "p50_ms": 25.0}]
-    dec = resolve_auto_shape(
-        eng, serve_batch="auto", slo_itl_ms=80.0,
-        autotune=_artifact(knee_rows=8, curve=curve), device_stats=None)
-    assert dec["serve_batch"] == 16  # 14 ms <= 0.2*80; 25 ms is not
-    assert dec["serve_batch_basis"] == "slo_curve"
-    assert dec["inputs"]["rows_under_itl_slo"] == 16
-    static = resolve_auto_shape(
-        eng, serve_batch=6, slo_itl_ms=80.0,
-        autotune=_artifact(knee_rows=8, curve=curve), device_stats=None)
-    assert static["serve_batch"] == 6
-    assert static["serve_batch_basis"] == "static"
+    # a static serve_batch passes through untouched, the SLO recorded
+    static = resolve_auto_shape(eng, serve_batch=6, slo_itl_ms=80.0,
+                                device_stats=None)
+    assert (static["serve_batch"], static["serve_batch_basis"]) == (
+        6, "static")
+    assert static["prefix_blocks_basis"] == "static"
+    assert static["inputs"]["slo_itl_ms"] == 80.0
 
 
 def test_auto_prefix_blocks_capped(tiny):
@@ -183,8 +111,7 @@ def test_auto_prefix_blocks_capped(tiny):
                  * spec.head_size * 4)
     dec = resolve_auto_shape(
         eng, serve_batch=2, prefix_blocks="auto", prefix_block_len=bl,
-        autotune=_artifact(), device_stats={
-            "bytes_in_use": 0, "bytes_limit": 8 * per_block})
+        device_stats={"bytes_in_use": 0, "bytes_limit": 8 * per_block})
     assert dec["prefix_blocks"] == 4  # 8 addable // 2
     assert dec["prefix_blocks_basis"] == "hbm_cap"
     dec2 = resolve_auto_shape(eng, serve_batch=2, prefix_blocks="auto",
@@ -407,13 +334,9 @@ def test_slo_flags_rejected_without_serve_batch():
     assert "> 0" in str(ei.value)
 
 
-def test_auto_sentinels_validate_at_parse_time(tmp_path):
+def test_auto_sentinels_validate_at_parse_time():
     """'auto' parses (argparse type), garbage does not; auto on the
-    process tier is a clear error (no ledger-capable local engine);
-    --autotune without an auto sentinel is a dead flag; a bad artifact
-    is a startup error naming the problem."""
-    import json
-
+    process tier is a clear error (no ledger-capable local engine)."""
     ap = dllama.build_argparser()
     args = ap.parse_args(["api", "--serve-batch", "auto",
                           "--prefix-blocks", "AUTO"])
@@ -425,13 +348,3 @@ def test_auto_sentinels_validate_at_parse_time(tmp_path):
         dllama.main(["api", "--model", "m", "--tokenizer", "t",
                      "--serve-batch", "auto", "--replica-procs", "2"])
     assert "ledger-capable" in str(ei.value)
-    with pytest.raises(SystemExit) as ei:
-        dllama.main(["api", "--model", "m", "--tokenizer", "t",
-                     "--serve-batch", "2", "--autotune", "AUTOTUNE.json"])
-    assert "auto" in str(ei.value)
-    bad = tmp_path / "bad.json"
-    bad.write_text(json.dumps({"kind": "nope"}))
-    with pytest.raises(SystemExit) as ei:
-        dllama.main(["api", "--model", "m", "--tokenizer", "t",
-                     "--serve-batch", "auto", "--autotune", str(bad)])
-    assert "kind" in str(ei.value)

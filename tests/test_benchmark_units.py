@@ -1,0 +1,44 @@
+"""The benchmark's own unit tests (benchmark/tests/), collected by tier-1:
+the harness decides every PR, and `pytest tests/` does not look there.
+
+Each pure module is loaded by file path and its tests re-exported as
+``<module>__<test>``, parametrisation intact; nothing under benchmark/ is
+edited, and a test added to one of these modules is collected as it is.
+Left out are the five tests that boot a server child (test_rehearsal.py's
+four and the traced rehearsal of test_window_metrics.py): the child
+inherits tests/conftest.py's eight host devices where the cell is for one,
+and five server boots do not belong in a six-worker run; they run under
+`pytest benchmark/tests`, and tests/test_sarvam_mla_bench.py runs the whole
+command at tiny size here."""
+
+import importlib.util
+import os
+import sys
+
+BTESTS = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmark", "tests")
+# by plain name: test_window_metrics imports test_rehearsal's plan,
+# test_workmodel imports shape_tiny
+sys.path.insert(0, BTESTS)
+
+PURE = ("test_traffic", "test_workmodel", "test_window_metrics",
+        "test_tracereduce", "test_manifest", "test_metrics",
+        "test_reference")
+BOOTS_A_SERVER = {"test_traced_rehearsal_reports_the_counter_metrics"}
+
+
+def _load(name):
+    spec = importlib.util.spec_from_file_location(
+        "benchmark_tests." + name, os.path.join(BTESTS, name + ".py"))
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod
+    spec.loader.exec_module(mod)
+    return mod
+
+
+_load("conftest")  # puts benchmark/ and the repository on sys.path
+for _module in PURE:
+    for _name, _test in vars(_load(_module)).items():
+        if (_name.startswith("test_") and callable(_test)
+                and _name not in BOOTS_A_SERVER):
+            globals()[f"{_module}__{_name[5:]}"] = _test

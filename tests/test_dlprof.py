@@ -1,10 +1,7 @@
 """tools/dlprof.py — the offline capacity analyzer: knee math, span
 decomposition, timeline merging (worker prefixes), the end-to-end path
 over a REAL scheduler's --trace-dir sink, and the CLI smoke the CI main
-matrix runs (--selftest). The BENCH_SERVE=1 artifact acceptance bar
-(reproduce the curve from a real bench row's step_timeline) rides
-tests/test_bench_outage.py::test_serve_row_emits_valid_json, which
-already pays for the bench subprocess."""
+matrix runs (--selftest)."""
 
 import json
 import os

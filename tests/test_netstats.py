@@ -64,7 +64,7 @@ def test_reconcile_wire_golden_on_synthetic_ledger():
         w.account(1, "RUN", "tx", frame_bytes(_HEADER_LEN, n_pay))
     for _ in range(5):
         w.account(1, "PING", "tx", frame_bytes(1, 0))
-    measured = w.peer_bytes(1, "RUN", "tx")
+    measured = w.summary()["peers"]["1"]["tx"]["RUN"]["bytes"]
     modeled = sum(frame_bytes(_HEADER_LEN, n) for n in (4, 0, 9))
     r = reconcile_wire(measured, modeled)
     assert r["drift_frac"] == 0.0 and r["drift"] is False and \

@@ -342,16 +342,13 @@ def test_build_info_shape(tiny):
     assert build_info(None)["mesh"] == "single"
 
 
-def test_ledger_records_kernels_per_executable_and_autotune_backend(tiny):
-    """Two silent-fallback guards (ISSUE 22): the compile ledger carries,
+def test_ledger_records_kernels_per_executable(tiny):
+    """A silent-fallback guard (ISSUE 22): the compile ledger carries,
     per minted executable, which Pallas kernels are in it (None = not
-    inspected: this CPU engine compiles no kernels), and an autotune
-    artifact calibrated on another backend is refused where it is
-    applied."""
+    inspected: this CPU engine compiles no kernels)."""
     import numpy as np
 
-    from distributed_llama_tpu.runtime.profiler import (_kernels_in,
-                                                        resolve_auto_shape)
+    from distributed_llama_tpu.runtime.profiler import _kernels_in
 
     COMPILES.reset()
     eng = _engine(tiny, batch=2)
@@ -360,14 +357,4 @@ def test_ledger_records_kernels_per_executable_and_autotune_backend(tiny):
     rec = COMPILES.summary()["by_key"]["slot_decode"]
     assert rec["count"] == 1 and rec["kernels"] is None
     assert not eng.use_pallas and _kernels_in(eng, None, ()) is None
-
-    art = {"kind": "dllama-autotune", "version": 1, "backend": "tpu",
-           "model": "7b", "knee": {"knee_rows": 8}, "decode_curve": []}
-    with pytest.raises(ValueError, match="calibrated on backend 'tpu'"):
-        resolve_auto_shape(eng, serve_batch="auto", autotune=art,
-                           device_stats=None)
-    ok = resolve_auto_shape(eng, serve_batch="auto",
-                            autotune=dict(art, backend="cpu"),
-                            device_stats=None)
-    assert ok["serve_batch"] == 8
     COMPILES.reset()

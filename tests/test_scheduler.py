@@ -127,7 +127,7 @@ def test_prompt_too_long_and_empty_rejected(tiny):
         sched.submit(list(range(1, SEQ + 1)), 4, _greedy(spec))
     with pytest.raises(ValueError):
         sched.submit([], 4, _greedy(spec))
-    assert not sched.has_work()
+    assert not sched.step()  # nothing was queued: no work
 
 
 def test_budget_zero_prefills_and_emits_nothing(tiny):

@@ -265,8 +265,8 @@ def _serve(tiny, shard, temps, *, draft=True, prefix=True, freeze=False):
             smp = Sampler(spec.vocab_size, temps[i % len(temps)], 0.9,
                           seed=1000 + i, backend="python")
             reqs.append(sched.submit(p, 10, smp))
-        while sched.has_work():
-            sched.step()
+        while sched.step():
+            pass
         outs = [list(r.tokens()) for r in reqs]
         frozen_delta = COMPILES.after_warmup - frozen_before
     finally:

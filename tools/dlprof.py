@@ -7,8 +7,8 @@ Inputs (any combination; at least one):
   * ``--trace-dir DIR``  — the rotating JSONL the server writes under
     ``--trace-dir`` (worker subdirs included): request spans + per-step
     timeline events (docs/observability.md schema).
-  * ``--bench FILE``     — a bench.py artifact (the single JSON object a
-    run prints, or a committed ``BENCH_rXX.json``): every row's
+  * ``--bench FILE``     — a bench-row artifact (one JSON object with an
+    optional ``variants`` list, or a list of rows): every row's
     ``step_timeline`` block feeds the curve, its ``hbm`` block caps the
     recommendation.
 
@@ -36,7 +36,7 @@ trace + timeline and asserts the report parses with a non-null knee).
 
 Usage:
   python tools/dlprof.py --trace-dir /var/log/dllama-trace \\
-      --bench BENCH_r06.json --out report --slo-ttft-ms 500
+      --out report --slo-ttft-ms 500
 """
 
 from __future__ import annotations
@@ -95,9 +95,8 @@ _TL_KEY = re.compile(r"^(?:r\d+_)?dec(\d+)_pre(\d+)_c(\d+)$")
 
 
 def load_bench(path: str) -> list[dict]:
-    """bench.py artifact -> flat row list (the main row + its variants).
-    Accepts the one-object-per-run shape bench prints and committed
-    BENCH_rXX.json artifacts of the same shape."""
+    """Bench-row artifact -> flat row list (the main row + its
+    variants); a top-level list is taken as the rows themselves."""
     with open(path) as f:
         obj = json.load(f)
     if isinstance(obj, list):
@@ -289,9 +288,9 @@ def knee_estimate(curve: list[tuple[int, float]]) -> dict | None:
         b, ms = curve[0]
         return {"knee_rows": b, "method": "single_point",
                 "curve": table,
-                "note": "one composition measured — bench more batch "
-                        "sizes (BENCH_SERVE with a larger --serve-batch) "
-                        "to place the knee"}
+                "note": "one composition measured — serve at more batch "
+                        "sizes (a larger --serve-batch) to place the "
+                        "knee"}
     b0, ms0 = curve[0]
     per_row0 = (b0 / ms0) / b0          # rows/ms each small-batch row buys
     knee = b0
@@ -310,77 +309,6 @@ def knee_estimate(curve: list[tuple[int, float]]) -> dict | None:
             "note": None if saturated else
             f"throughput still scaling at rows={knee} — measure larger "
             "batches to find the true knee"}
-
-
-# -- the calibration artifact (tools/autotune.py) ---------------------------
-
-# duplicated from runtime/profiler.py on purpose: dlprof must run with NO
-# repo on the path (operators copy it next to an artifact — the same
-# reason percentile() above is local). tests/test_autotune.py pins the
-# two validators against each other so the contract cannot drift.
-AUTOTUNE_KIND = "dllama-autotune"
-AUTOTUNE_VERSION = 1
-DRIFT_FRAC = 0.25  # calibrated vs measured knee movement worth flagging
-
-
-def validate_autotune(art) -> list[str]:
-    """Schema problems of one AUTOTUNE.json artifact (empty = valid)."""
-    problems = []
-    if not isinstance(art, dict):
-        return ["not a JSON object"]
-    if art.get("kind") != AUTOTUNE_KIND:
-        problems.append(f"kind must be {AUTOTUNE_KIND!r}, "
-                        f"got {art.get('kind')!r}")
-    if art.get("version") != AUTOTUNE_VERSION:
-        problems.append(f"version must be {AUTOTUNE_VERSION}, "
-                        f"got {art.get('version')!r}")
-    knee = art.get("knee")
-    if not isinstance(knee, dict) or not knee.get("knee_rows"):
-        problems.append("missing knee.knee_rows (re-run the calibration "
-                        "with >= 1 measured batch size)")
-    if not isinstance(art.get("decode_curve"), list):
-        problems.append("missing decode_curve list")
-    return problems
-
-
-def load_autotune(path: str) -> dict:
-    with open(path) as f:
-        art = json.load(f)
-    problems = validate_autotune(art)
-    if problems:
-        raise ValueError("invalid autotune artifact: "
-                         + "; ".join(problems))
-    return art
-
-
-def autotune_comparison(knee: dict | None, art: dict) -> dict:
-    """Calibrated knee (AUTOTUNE.json) vs the knee measured from the
-    LIVE inputs of this report — the drift check an operator runs before
-    trusting yesterday's calibration: a knee moved >= DRIFT_FRAC means
-    the workload, model, or backend shifted enough that the auto-sized
-    batch is stale and tools/autotune.py should re-run."""
-    calibrated = int((art.get("knee") or {}).get("knee_rows") or 0)
-    measured = int(knee["knee_rows"]) if knee else None
-    drift_frac = None
-    drift = False
-    if measured is not None and calibrated:
-        drift_frac = abs(measured - calibrated) / calibrated
-        drift = drift_frac >= DRIFT_FRAC
-    return {
-        "calibrated_knee_rows": calibrated or None,
-        "calibrated_model": art.get("model"),
-        "calibrated_backend": art.get("backend"),
-        "calibrated_unix": art.get("created_unix"),
-        "measured_knee_rows": measured,
-        "drift_frac": _rnd(drift_frac, 4),
-        "drift": drift,
-        "note": ("no live decode compositions to compare against — "
-                 "feed --trace-dir or a bench artifact"
-                 if measured is None else
-                 (f"knee moved {drift_frac:.0%} from calibration "
-                  "(>= 25%): re-run tools/autotune.py and re-resolve "
-                  "--serve-batch auto" if drift else None)),
-    }
 
 
 def serve_batch_recommendation(knee: dict | None,
@@ -403,9 +331,9 @@ def serve_batch_recommendation(knee: dict | None,
 
 # -- the wire report (dlwire: measured cluster-plane comms) -----------------
 
-# mirrored from runtime/netstats.WIRE_DRIFT_FRAC on purpose (same reason
-# as the AUTOTUNE constants above: dlprof runs with no repo on the path);
-# tests pin the two against each other
+# mirrored from runtime/netstats.WIRE_DRIFT_FRAC on purpose (the same
+# reason percentile() above is local: dlprof runs with no repo on the
+# path); tests pin the two against each other
 WIRE_DRIFT_FRAC = 0.25
 
 
@@ -413,8 +341,8 @@ def wire_report(events: list[dict], bench_rows: list[dict]) -> dict | None:
     """The comms section: per-peer measured bytes/frames and RTT tails
     (from bench rows' ``wire`` blocks — the cluster chaos row, MULTICHIP
     rows when silicon returns) and every measured-vs-modeled
-    reconciliation found, drift flagged at >= 25% like the autotune knee
-    check. None when no input carries wire data."""
+    reconciliation found, drift flagged at >= 25%. None when no input
+    carries wire data."""
     peers: dict[str, dict] = {}
     reconciles: list[dict] = []
 
@@ -548,7 +476,7 @@ def tail_attribution(paths: list[dict], k: int = 5) -> list[dict]:
 
 def analyze(events: list[dict], bench_rows: list[dict] | None = None, *,
             slo_ttft_ms: float = 500.0, slo_itl_ms: float = 100.0,
-            autotune: dict | None = None, wire: bool = False) -> dict:
+            wire: bool = False) -> dict:
     bench_rows = bench_rows or []
     timeline = merge_timelines(events, bench_rows)
     paths = [p for p in (critical_path(s)
@@ -578,8 +506,6 @@ def analyze(events: list[dict], bench_rows: list[dict] | None = None, *,
         "tail": tail_attribution(paths),
         "hbm": hbm,
     }
-    if autotune is not None:
-        report["autotune"] = autotune_comparison(knee, autotune)
     if wire:
         report["wire"] = wire_report(events, bench_rows)
     return report
@@ -634,18 +560,6 @@ def render_markdown(report: dict) -> str:
         lines += ["", f"**Recommended `--serve-batch "
                       f"{rec['serve_batch']}`**{cap}."]
     lines.append("")
-
-    at = report.get("autotune")
-    if at:
-        lines += ["## Calibration drift (AUTOTUNE.json)", "",
-                  f"Calibrated knee {at['calibrated_knee_rows']} rows "
-                  f"({at['calibrated_model']}/{at['calibrated_backend']})"
-                  f" vs measured {at['measured_knee_rows']} — drift "
-                  f"{at['drift_frac']}"
-                  + (" ⚠️ **DRIFTED**" if at["drift"] else " (ok)")
-                  + ".", ""]
-        if at.get("note"):
-            lines += [f"_{at['note']}_", ""]
 
     g = report["goodput"]
     lines += ["## Goodput", "",
@@ -780,30 +694,6 @@ def _selftest() -> int:
     md = render_markdown(report)
     assert "Knee: 4 rows" in md, md
 
-    # the AUTOTUNE.json input path: a matching calibration reads clean, a
-    # knee that moved 2x flags drift in the report AND the markdown
-    art = {"kind": AUTOTUNE_KIND, "version": AUTOTUNE_VERSION,
-           "model": "selftest", "backend": "none", "created_unix": 0.0,
-           "decode_curve": [],
-           "knee": {"knee_rows": 4, "method": "marginal_throughput"}}
-    assert not validate_autotune(art), validate_autotune(art)
-    assert validate_autotune({"kind": "bogus"})  # bad artifact named
-    with tempfile.TemporaryDirectory() as d:
-        ap = os.path.join(d, "AUTOTUNE.json")
-        with open(ap, "w") as f:
-            json.dump(art, f)
-        with open(os.path.join(d, "trace-00000001.jsonl"), "w") as f:
-            for e in events:
-                f.write(json.dumps(e) + "\n")
-        r2 = analyze(load_trace_dir(d), [bench_row],
-                     autotune=load_autotune(ap))
-    at = r2["autotune"]
-    assert at["measured_knee_rows"] == 4 and not at["drift"], at
-    drifted = autotune_comparison({"knee_rows": 8},
-                                  dict(art, knee={"knee_rows": 4}))
-    assert drifted["drift"] and drifted["drift_frac"] == 1.0, drifted
-    assert "Calibration drift" in render_markdown(r2)
-
     # the wire section (dlwire): a bench row's measured cluster ledger
     # -> per-peer table and the reconciliation — exact-match reads
     # clean, a 30%-off model flags
@@ -867,7 +757,7 @@ def _selftest() -> int:
     rkd = analyze(events, [kvx_drift], wire=True)["wire"]
     assert rkd["drift"], rkd
 
-    print("dlprof selftest: OK (knee=4, 3 spans, autotune drift check, "
+    print("dlprof selftest: OK (knee=4, 3 spans, "
           "wire section + drift flag, KV transfer section, "
           "report renders)")
     return 0
@@ -881,12 +771,7 @@ def main(argv: list[str] | None = None) -> int:
                     help="server --trace-dir (rotating JSONL; worker "
                          "subdirs included)")
     ap.add_argument("--bench", action="append", default=[],
-                    help="bench.py artifact JSON (repeatable)")
-    ap.add_argument("--autotune", default=None, metavar="FILE",
-                    help="AUTOTUNE.json calibration artifact "
-                         "(tools/autotune.py): the report compares its "
-                         "calibrated knee against the live measured one "
-                         "and flags >= 25%% drift")
+                    help="bench-row artifact JSON (repeatable)")
     ap.add_argument("--wire", action="store_true",
                     help="add the measured cluster-plane comms section: "
                          "per-peer bytes + RTT tails from bench rows' "
@@ -910,21 +795,8 @@ def main(argv: list[str] | None = None) -> int:
     rows: list[dict] = []
     for b in args.bench:
         rows += load_bench(b)
-    art = None
-    if args.autotune:
-        try:
-            art = load_autotune(args.autotune)
-        except (OSError, ValueError) as e:
-            ap.error(f"--autotune {args.autotune}: {e}")
     report = analyze(events, rows, slo_ttft_ms=args.slo_ttft_ms,
-                     slo_itl_ms=args.slo_itl_ms, autotune=art,
-                     wire=args.wire)
-    at = report.get("autotune")
-    if at and at["drift"]:
-        print(f"dlprof: ⚠️ knee drift {at['drift_frac']:.0%} — calibrated "
-              f"{at['calibrated_knee_rows']} vs measured "
-              f"{at['measured_knee_rows']} rows (re-run tools/autotune.py)",
-              file=sys.stderr)
+                     slo_itl_ms=args.slo_itl_ms, wire=args.wire)
     w = report.get("wire")
     if w and w.get("drift"):
         print("dlprof: ⚠️ measured wire traffic drifted >= 25% from the "

@@ -1,6 +1,6 @@
 """Experiment: make the fp8 KV cache PAY in the flash kernel (VERDICT r4 #3).
 
-BENCH_r04 showed the f8 cache as a 2.3x decode REGRESSION (42.1 vs
+A pre-PR-1 record showed the f8 cache as a 2.3x decode REGRESSION (42.1 vs
 18.4 ms/token at 8k fill) even though the flash kernel upcasts per block
 in-kernel — Mosaic's e4m3->bf16 `astype` on v5e (no native fp8) lowers to
 slow element conversion. Candidates measured here, interleaved best-of-N:
@@ -24,7 +24,7 @@ best of 6 interleaved, dispatch-amortized x32):
   copy per step (f8 ratio 1.52x); moving the u8 reinterpret INSIDE the
   kernel (per block, in-register) fixed it. Final whole-model A/B at 7680
   fill: bf16 18.80 vs f8 18.88 ms/token — ratio 1.004, the r4 2.3x f8
-  regression is gone (BENCH_r04 42.1 -> 18.9). Promoted into
+  regression is gone (42.1 -> 18.9). Promoted into
   ops/pallas_attention.py (_f8_bits_to).
 """
 
