@@ -31,6 +31,14 @@ steps map to the same block, so the kernel reads ~pos bytes of cache, not
 the full preallocated seq_len (at 7B/seq 2048 that dead read was
 ~1 GB/token early in a session); the repeated block's scores are fully
 masked, and a pl.when skips its compute.
+
+A gated row (pos >= S: how the scheduler parks a slot that takes no part
+in a call; its cache writes drop and nothing reads its output) costs ONE
+block: `_last_attended` sends it to block 0 in the index map and the
+pl.when, unmasked, so its softmax state stays finite. The plain clamp
+would put pos == S in the LAST block, and every empty slot would stream
+the whole cache in every layer. What such a row still costs is its grid
+steps.
 """
 
 from __future__ import annotations
@@ -102,6 +110,18 @@ def saturate_f8_nan_codes(x):
 MAX_Q_ROWS = 1024
 
 
+def _last_attended(pos, last_tok, s):
+    """Last cache position a panel of query rows attends: `pos` is the
+    slot's first query position, `last_tok` the panel's last token within
+    the chunk, `s` the cache's length. The K/V index map clamps at its
+    block and the kernel skips the blocks past it. A gated slot
+    (pos >= S: its writes were dropped and its output is never read)
+    attends block 0 alone, unmasked, so that it costs one block and its
+    rows stay finite (skipping block 0 too would leave 0 / 0)."""
+    last = pos + last_tok
+    return jnp.where(pos >= s, 0, last)
+
+
 def _kernel(pos_ref, q_ref, k_ref, v_ref, out_ref, acc_ref, m_ref, l_ref,
             *, sb, n_sb, kvh, t, g, scale, out_dtype):
     j = pl.program_id(1)
@@ -117,7 +137,7 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, out_ref, acc_ref, m_ref, l_ref,
 
     # blocks entirely past the last query position are fully masked: their
     # K/V DMA was clamped away (see index maps) and their compute is skipped
-    @pl.when(j * sb <= pos + t - 1)
+    @pl.when(j * sb <= _last_attended(pos, t - 1, sb * n_sb))
     def _accumulate():
         q = q_ref[0]                               # (T*G, hs)
         k = k_ref[0]                               # (SB, hs)
@@ -218,7 +238,8 @@ def flash_attention(
         # clamp at the block containing the chunk's last query position:
         # steps past it re-map to the same block, so Mosaic elides their HBM
         # copy (the dead-read fix)
-        return (i, jnp.minimum(j, (pos_ref[i // kvh] + t - 1) // sb), 0)
+        last = _last_attended(pos_ref[i // kvh], t - 1, s)
+        return (i, jnp.minimum(j, last // sb), 0)
 
     out = pl.pallas_call(
         functools.partial(
@@ -275,12 +296,10 @@ def mla_supported(t: int, h: int) -> bool:
 
 
 def _mla_last(pos, i, *, tr, t, h, s):
-    """Last cache position row tile i of a slot attends. A gated slot
-    (pos >= S: its writes were dropped and its output is never read)
-    attends block 0 alone, unmasked, so that it costs one block and its
-    rows stay finite."""
-    last = pos + jnp.minimum(((i + 1) * tr - 1) // h, t - 1)
-    return jnp.where(pos >= s, 0, last)
+    """Last cache position row tile i of a slot attends: the tile's last
+    row belongs to token ((i + 1) * tr - 1) // h of the chunk."""
+    return _last_attended(
+        pos, jnp.minimum(((i + 1) * tr - 1) // h, t - 1), s)
 
 
 def _mla_kernel(pos_ref, q_ref, c_ref, out_ref, acc_ref, m_ref, l_ref,

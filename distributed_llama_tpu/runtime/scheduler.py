@@ -15,8 +15,9 @@ device program is one of exactly two executables —
 Rows not participating in a call are gated off by passing position ==
 seq_len: their cache writes drop out of bounds (models/transformer's
 per-row write: the in-place kv_cache_write kernel on the chip, the
-drop-mode scatter without the kernels) and their logits are never read.
-This replaces the
+drop-mode scatter without the kernels), their attention reads one cache
+block (ops/pallas_attention._last_attended) and their logits are never
+read; `gated_rows` counts them. This replaces the
 static batch endpoint's regime — all prompts in one request, serial
 prefill, every slot held until the slowest row drains — with
 iteration-level admission: a finished row's slot is handed to the next
@@ -813,6 +814,7 @@ class Scheduler:
         lidx = np.zeros((b,), np.int32)
         finishing = []
         self.stats.prefill_steps += 1
+        self.stats.gated_rows += b - len(rows)
         for s in rows:
             n = min(c, len(s.req.prompt) - s.off)
             tok[s.idx, :n] = s.req.prompt[s.off:s.off + n]
@@ -867,6 +869,7 @@ class Scheduler:
             TRACER.phase(self._span, "sched.dispatch.decode")
         self.stats.decode_steps += 1
         self.stats.decode_rows += len(live)
+        self.stats.gated_rows += eng.batch - len(live)
         tok = np.zeros((eng.batch, 1), np.int32)
         pos = np.full((eng.batch,), eng.seq_len, np.int32)
         for s in live:
@@ -953,6 +956,7 @@ class Scheduler:
             TRACER.phase(self._span, "sched.dispatch.decode")
         self.stats.decode_steps += 1
         self.stats.decode_rows += len(rows)
+        self.stats.gated_rows += eng.batch - len(rows)
         spec_rows = [s for s in rows if self._spec_capable(s)]
         dtok = np.zeros((eng.batch,), np.int32)
         dpos = np.full((eng.batch,), eng.seq_len, np.int32)  # gated rows

@@ -232,7 +232,7 @@ class StepTimelineStats:
 # PERF.md section 3 say which per-layer metric reads which)
 WINDOW_COUNTERS = ("admitted", "queue_wait_ms_sum", "prefill_steps",
                    "prefill_tokens", "decode_steps", "decode_rows",
-                   "busy_ms", "wait_ms", "host_ms",
+                   "gated_rows", "busy_ms", "wait_ms", "host_ms",
                    "attn_pairs_decode", "attn_pairs_prefill",
                    "prefill_cached_tokens")
 
@@ -293,6 +293,9 @@ class ServeStats:
     #                                rows; with or without --prefix-cache)
     decode_steps: int = 0          # decode / verify programs dispatched
     decode_rows: int = 0           # rows that decoded in them
+    gated_rows: int = 0            # rows passed at pos == seq_len, over the
+    #                                target's prefill-chunk, decode and verify
+    #                                programs (a draft model's: not counted)
     busy_ms: float = 0.0           # wall of WORKING iterations only
     wait_ms: float = 0.0           # of it: blocked in a device fetch
     host_ms: float = 0.0           # busy less wait, summed per iteration

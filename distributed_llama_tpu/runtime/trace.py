@@ -565,6 +565,9 @@ _COUNTERS = (
      "Decode or verify programs dispatched"),
     ("decode_rows", "dllama_decode_rows_total",
      "Rows that decoded, summed over decode steps"),
+    ("gated_rows", "dllama_gated_rows_total",
+     "Rows passed at pos == seq_len, summed over the target model's "
+     "prefill, decode and verify programs"),
     ("busy_ms", "dllama_scheduler_busy_ms_total",
      "Wall ms of working scheduler iterations"),
     ("wait_ms", "dllama_scheduler_wait_ms_total",

@@ -7,7 +7,8 @@ import pytest
 
 from distributed_llama_tpu.ops.attention import decode_attention
 from distributed_llama_tpu.ops.pallas_attention import (
-    flash_attention, flash_decode_attention, flash_supported)
+    F8_DTYPE, _last_attended, _mla_last, flash_attention,
+    flash_decode_attention, flash_supported)
 
 
 @pytest.mark.parametrize("b,h,kvh,s,pos", [
@@ -91,3 +92,84 @@ def test_flash_decode_bf16():
     assert got.dtype == jnp.bfloat16
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), atol=5e-2, rtol=5e-2)
+
+
+GATED_LAYOUTS = {"first": (True, True, False, False),
+                 "last": (False, False, True, True),
+                 "interleaved": (True, False, True, False)}
+
+
+@pytest.mark.parametrize("layout", GATED_LAYOUTS)
+@pytest.mark.parametrize("cache_dtype", [jnp.bfloat16, F8_DTYPE],
+                         ids=["bf16", "f8"])
+@pytest.mark.parametrize("t", [1, 32])
+def test_flash_gated_rows_cost_block_zero_and_leave_live_rows(
+        t, cache_dtype, layout):
+    """The scheduler parks a slot that takes no part in a call at
+    pos == S. Such a row attends block 0 alone, so it stays finite
+    whatever the rest of its cache holds; a live row's panel is its own,
+    so it equals the oracle and, bit for bit, the same call with the
+    gated slots given a live position."""
+    b, h, kvh, s, hs = 4, 8, 2, 1536, 128  # three 512-blocks
+    gated = np.asarray(GATED_LAYOUTS[layout])
+    live = ~gated
+    rng = np.random.default_rng(t + len(layout))
+    q = jnp.asarray(rng.standard_normal((b, t, h, hs)), jnp.bfloat16)
+    k = jnp.asarray(rng.standard_normal((b, kvh, s, hs)), cache_dtype)
+    v = jnp.asarray(rng.standard_normal((b, kvh, s, hs)), cache_dtype)
+    # live rows: one inside block 0, one whose chunk ends in the last block
+    pos0 = np.where(gated, s, 0)
+    pos0[live] = [100, s - t]
+    q_pos = jnp.asarray(pos0[:, None] + np.arange(t)[None, :], jnp.int32)
+
+    got = np.asarray(flash_attention(q, k, v, q_pos, interpret=True),
+                     np.float32)
+    # the oracle in float32: XLA's CPU backend has no batched bf16 dot
+    f32 = lambda x: x.astype(jnp.float32)  # noqa: E731
+    want = np.asarray(decode_attention(f32(q), f32(k), f32(v), q_pos))
+    np.testing.assert_allclose(got[live], want[live], atol=5e-2, rtol=5e-2)
+    assert np.isfinite(got).all()
+
+    all_live = np.where(gated, 700, pos0)
+    q_live = jnp.asarray(all_live[:, None] + np.arange(t)[None, :], jnp.int32)
+    twin = np.asarray(flash_attention(q, k, v, q_live, interpret=True),
+                      np.float32)
+    np.testing.assert_array_equal(got[live], twin[live])
+
+    # every block past block 0 of the gated slots poisoned: untouched
+    poison = jnp.asarray(gated)[:, None, None, None] & (
+        jnp.arange(s) >= 512)[None, None, :, None]
+    nan = jnp.asarray(jnp.nan, cache_dtype)
+    kp, vp = jnp.where(poison, nan, k), jnp.where(poison, nan, v)
+    assert np.isnan(np.asarray(kp, np.float32)).any()
+    again = np.asarray(flash_attention(q, kp, vp, q_pos, interpret=True),
+                       np.float32)
+    np.testing.assert_array_equal(again, got)
+
+
+@pytest.mark.parametrize("t", [1, 32])
+def test_last_attended_gates_at_seq_len_and_clamps_below_it(t):
+    """The one statement of the gate both kernels' index maps and
+    pl.whens share: pos >= S -> position 0, so block 0 for every grid
+    step j; pos < S -> the panel's last query position, as before."""
+    s, sb = 4096, 512
+    j = np.arange(s // sb)
+    for pos in (s, s + 5):
+        last = int(_last_attended(jnp.int32(pos), t - 1, s))
+        assert last == 0
+        assert (np.minimum(j, last // sb) == 0).all()
+        assert (j * sb <= last).tolist() == [True] + [False] * (len(j) - 1)
+    for pos in (0, 511, 512, 3000, s - t, s - 1):  # the last two: last block
+        last = int(_last_attended(jnp.int32(pos), t - 1, s))
+        assert last == pos + t - 1
+        np.testing.assert_array_equal(np.minimum(j, last // sb),
+                                      np.minimum(j, (pos + t - 1) // sb))
+
+    # mla_attention's row tiles: tile i of TR rows ends at token
+    # ((i + 1) * TR - 1) // H of the chunk, capped at the chunk's last
+    h, tr = 64, min(512, t * 64)
+    for i in range(t * h // tr):
+        tok = min(((i + 1) * tr - 1) // h, t - 1)
+        kw = dict(tr=tr, t=t, h=h, s=s)
+        assert int(_mla_last(jnp.int32(s), i, **kw)) == 0
+        assert int(_mla_last(jnp.int32(3000), i, **kw)) == 3000 + tok

@@ -15,8 +15,10 @@ jnp = pytest.importorskip("jax.numpy")
 
 from distributed_llama_tpu.models import ArchType, HiddenAct, ModelSpec
 from distributed_llama_tpu.models.params import load_params, random_tensors
+from distributed_llama_tpu.runtime.draft import DraftModel
 from distributed_llama_tpu.runtime.engine import Engine
 from distributed_llama_tpu.runtime.scheduler import PromptTooLong, Scheduler
+from distributed_llama_tpu.runtime.stats import WINDOW_COUNTERS
 from distributed_llama_tpu.sampler import Sampler
 
 SEQ = 64
@@ -116,6 +118,50 @@ def test_parity_eos_early_finish(tiny):
     assert r0.finish_reason == "stop"
     assert _drain(r1) == _oracle(spec, params, p1, 5)
     assert max(sched.stats.occupancy) == 1
+
+
+@pytest.mark.parametrize("draft", [False, True], ids=["plain", "verify"])
+def test_gated_rows_counts_the_slots_outside_every_program(tiny, draft):
+    """`gated_rows`: rows passed at pos == seq_len, over every prefill
+    chunk and decode (or verify) program — batch x programs less the rows
+    that took part — and monotonic like every window counter."""
+    spec, params = tiny
+    batch, chunk = 4, 4
+    eng = Engine(spec, params, batch=batch, compute_dtype=jnp.float32,
+                 cache_dtype=jnp.float32)
+    kw = dict(draft_factory=lambda e: DraftModel.self_draft(e, 1),
+              draft_len=2, draft_vocab=spec.vocab_size) if draft else {}
+    sched = Scheduler(eng, chunk=chunk, **kw)
+    assert "gated_rows" in WINDOW_COUNTERS
+    p0 = [1, 9, 23, 54, 7, 88, 101, 5, 61, 17, 3]   # 3 chunks
+    p1 = [2, 40, 77, 12, 9]                          # 2 chunks
+    r0 = sched.submit(p0, 10, _greedy(spec))
+    snaps = [sched.stats.summary()]
+    for _ in range(5):
+        sched.step()
+        snaps.append(sched.stats.summary())
+    r1 = sched.submit(p1, 4, _greedy(spec))          # joins mid-decode
+    for _ in range(500):
+        if r0.finished.is_set() and r1.finished.is_set():
+            break
+        sched.step()
+        snaps.append(sched.stats.summary())
+    assert _drain(r0) == _oracle(spec, params, p0, 10)
+    assert _drain(r1) == _oracle(spec, params, p1, 4)
+
+    s = snaps[-1]
+    # a prompt's row takes part in one chunk program per chunk
+    prefill_rows = sum(-(-len(p) // chunk) for p in (p0, p1))
+    programs = s["prefill_steps"] + s["decode_steps"]
+    assert s["gated_rows"] == batch * programs - prefill_rows - s["decode_rows"]
+    assert s["gated_rows"] >= 2 * programs          # never more than 2 live
+    if not draft:
+        assert s["decode_rows"] == s["tokens_out"] - s["admitted"] == 12
+        # the first five iterations held r0 alone: 3 chunks, 2 decode steps
+        assert snaps[5]["gated_rows"] == (3 + 2) * (batch - 1)
+    for a, b in zip(snaps, snaps[1:]):
+        assert b["gated_rows"] >= a["gated_rows"]
+    sched.close()
 
 
 def test_prompt_too_long_and_empty_rejected(tiny):
