@@ -1274,6 +1274,35 @@ def cmd_worker(args) -> None:
             mh.set_phase("idle")
 
 
+def _refuse_for_state(args) -> None:
+    """A model that keeps a recurrent state a slot, asked to run with
+    something that holds rows of a cache only: exit at start-up with the
+    one message (models/spec.STATE_REFUSALS), from the file's header,
+    before anything is loaded."""
+    import os
+
+    model = getattr(args, "model", None)
+    if not model or not os.path.exists(model):
+        return          # the loader says what is wrong with the path
+    from ..io.model_file import read_spec
+
+    try:
+        spec = read_spec(model)
+    except (ValueError, KeyError, OSError):
+        return          # not a header this reader knows: the loader's error
+    asked = {
+        "prefix_cache": getattr(args, "prefix_cache", False),
+        "kv_transfer": getattr(args, "kv_transfer", False),
+        "speculation": args.draft or args.lookup_decode,
+        "parallel": max(args.tp, args.pp, args.sp, args.ep, args.nnodes) > 1,
+        "session": args.session,
+    }
+    for what, on in asked.items():
+        why = on and spec.refusal(what)
+        if why:
+            sys.exit("error: " + why)
+
+
 def main(argv: list[str] | None = None) -> None:
     args = build_argparser().parse_args(argv)
     if args.workers:
@@ -1316,6 +1345,7 @@ def main(argv: list[str] | None = None) -> None:
         if args.device_sampling:
             sys.exit("error: --draft is host-loop decoding; it does "
                      "not compose with --device-sampling")
+    _refuse_for_state(args)
     if (getattr(args, "shard_vocab", "auto") == "on" and args.tp <= 1
             and args.nnodes <= 1):
         # dead-flag discipline: an explicit "on" needs a tp mesh to split

@@ -25,7 +25,7 @@ from typing import Iterator
 
 import numpy as np
 
-from ..models.spec import ArchType, HiddenAct, ModelSpec
+from ..models.spec import ArchType, HiddenAct, LayerKind, ModelSpec
 from ..quants.types import BLOCK_SIZE, FloatType, batch_bytes
 from ..quants.numpy_codec import (
     dequantize_q40,
@@ -74,6 +74,15 @@ _MLA_FLOAT_KEYS = frozenset((
     "routed_scaling", "rms_eps", "rope_factor", "rope_beta_fast",
     "rope_beta_slow", "rope_mscale", "rope_mscale_all_dim"))
 
+# OLMO_HYBRID's header keys, written after the fourteen and only for that
+# architecture: rms_eps (key 24, as above), the DELTA layer's sizes, and
+# the layer kinds as DATA, one (key _MIXER_KEY0 + l, LayerKind) pair a layer.
+_HYBRID_KEYS = {
+    "lin_heads": 31, "lin_k_head_dim": 32, "lin_v_head_dim": 33,
+    "lin_conv_width": 34, "lin_beta_scale": 35,
+}
+_MIXER_KEY0 = 1000
+
 
 def _f32_bits(x: float) -> int:
     return struct.unpack("<i", struct.pack("<f", x))[0]
@@ -118,6 +127,9 @@ def model_tensor_plan(spec: ModelSpec) -> Iterator[tuple[str, tuple[int, ...], F
     wt = spec.weights_float_type
     if spec.is_mla:
         yield from _mla_tensor_plan(spec)
+        return
+    if spec.arch == ArchType.OLMO_HYBRID:
+        yield from _hybrid_tensor_plan(spec)
         return
     yield "tok_emb", (spec.vocab_size, spec.dim), FloatType.F32
     for l in range(spec.n_layers):
@@ -186,6 +198,49 @@ def _mla_tensor_plan(spec: ModelSpec):
     yield "wcls", (spec.vocab_size, d), wt
 
 
+def _hybrid_tensor_plan(spec: ModelSpec):
+    """OLMO_HYBRID's file order. A DELTA layer: wq wk (H x d_k rows), wv
+    and wg (the output gate; H x d_v), wa and wb (H rows: decay and beta),
+    wo (over H x d_v), then f32: conv_w (taps x [q ; k ; v] channels, tap
+    j weighs the row `taps - 1 - j` tokens back), a_log, dt_bias (H) and
+    rms_o (d_v, the gated output norm of a head). An ATTENTION layer: wq
+    wk wv wo, then rms_q and rms_k (f32, the full projected width). Both:
+    w1 w2 w3, then rms_att and rms_ffn, the norms on the two sublayers'
+    OUTPUTS."""
+    wt, d, hid = spec.weights_float_type, spec.dim, spec.hidden_dim
+    h, dk, dv = spec.lin_heads, spec.lin_k_head_dim, spec.lin_v_head_dim
+    yield "tok_emb", (spec.vocab_size, d), FloatType.F32
+    for l, kind in enumerate(spec.layer_kinds):
+        p = f"layers.{l}."
+        if kind == LayerKind.DELTA:
+            yield p + "wq", (h * dk, d), wt
+            yield p + "wk", (h * dk, d), wt
+            yield p + "wv", (h * dv, d), wt
+            yield p + "wg", (h * dv, d), wt
+            yield p + "wa", (h, d), wt
+            yield p + "wb", (h, d), wt
+            yield p + "wo", (d, h * dv), wt
+            yield p + "conv_w", (spec.lin_conv_width,
+                                 spec.lin_conv_dim), FloatType.F32
+            yield p + "a_log", (h,), FloatType.F32
+            yield p + "dt_bias", (h,), FloatType.F32
+            yield p + "rms_o", (dv,), FloatType.F32
+        else:
+            yield p + "wq", (d, d), wt
+            yield p + "wk", (spec.kv_dim, d), wt
+            yield p + "wv", (spec.kv_dim, d), wt
+            yield p + "wo", (d, d), wt
+            yield p + "rms_q", (d,), FloatType.F32
+            yield p + "rms_k", (spec.kv_dim,), FloatType.F32
+        yield p + "w1", (hid, d), wt
+        yield p + "w2", (d, hid), wt
+        yield p + "w3", (hid, d), wt
+        yield p + "rms_att", (d,), FloatType.F32
+        yield p + "rms_ffn", (d,), FloatType.F32
+    yield "rms_final", (d,), FloatType.F32
+    yield "wcls", (spec.vocab_size, d), wt
+
+
 def _tensor_bytes(shape: tuple[int, ...], ftype: FloatType) -> int:
     n = shape[-1]
     d = int(np.prod(shape[:-1])) if len(shape) > 1 else 1
@@ -212,10 +267,18 @@ def read_spec(path: str, weights_float_type: FloatType | None = None) -> ModelSp
             header_size = struct.unpack("<i", f.read(4))[0]
             data = f.read(header_size - 8)
             n_kv = len(data) // 8
-            inv = {v: k for k, v in {**_KEYS, **_MLA_KEYS}.items()}
+            inv = {v: k for k, v in
+                   {**_KEYS, **_MLA_KEYS, **_HYBRID_KEYS}.items()}
+            mixers: dict[int, int] = {}
             for i in range(n_kv):
                 k, v = struct.unpack_from("<ii", data, i * 8)
-                fields[inv[k]] = v
+                if k >= _MIXER_KEY0:
+                    mixers[k - _MIXER_KEY0] = v
+                else:
+                    fields[inv[k]] = v
+            if mixers:
+                fields["mixers"] = tuple(mixers[l]
+                                         for l in range(len(mixers)))
             rope_theta = float(fields.pop("rope_theta", 10000))
             hidden_act = HiddenAct(fields.pop("hidden_act", int(HiddenAct.SILU)))
             version = fields.pop("version", 0)
@@ -248,7 +311,7 @@ def read_spec(path: str, weights_float_type: FloatType | None = None) -> ModelSp
         weights_float_type=wt,
         version=version,
         **{k: (_bits_f32(fields[k]) if k in _MLA_FLOAT_KEYS else fields[k])
-           for k in _MLA_KEYS if k in fields},
+           for k in (*_MLA_KEYS, *_HYBRID_KEYS, "mixers") if k in fields},
     )
     spec.validate()
     object.__setattr__(spec, "_header_size", header_size)
@@ -341,6 +404,13 @@ def write_header(f, spec: ModelSpec) -> None:
             value = getattr(spec, key)
             data += struct.pack("<ii", k, _f32_bits(value)
                                 if key in _MLA_FLOAT_KEYS else value)
+    if spec.arch == ArchType.OLMO_HYBRID:
+        data += struct.pack("<ii", _MLA_KEYS["rms_eps"],
+                            _f32_bits(spec.rms_eps))
+        for key, k in _HYBRID_KEYS.items():
+            data += struct.pack("<ii", k, getattr(spec, key))
+        for l, kind in enumerate(spec.layer_kinds):
+            data += struct.pack("<ii", _MIXER_KEY0 + l, int(kind))
     f.write(struct.pack("<i", MAGIC_KV))
     f.write(struct.pack("<i", 8 + len(data)))
     f.write(data)

@@ -1,3 +1,3 @@
-from .spec import ArchType, HiddenAct, ModelSpec
+from .spec import ArchType, HiddenAct, LayerKind, ModelSpec
 
-__all__ = ["ArchType", "HiddenAct", "ModelSpec"]
+__all__ = ["ArchType", "HiddenAct", "LayerKind", "ModelSpec"]

@@ -490,6 +490,20 @@ def load_params_streamed(
                 live -= sum(_host_bytes(x) for x in ts)
             continue
 
+        if key in ("wa", "wb"):
+            # decay and beta rows: ONE dense (2H, d) leaf, wa's rows first
+            # (models/params.load_params)
+            gk = f"{t.name.rsplit('.', 1)[0]}.w_ab"
+            pending.setdefault(gk, []).append(t)
+            if len(pending[gk]) == 2:
+                ts = pending.pop(gk)
+                arr = placer.dense("w_ab", np.concatenate(
+                    [x.to_f32() for x in ts]))
+                dest["w_ab"] = (arr.astype(dtype) if dtype != jnp.float32
+                                else arr)
+                live -= sum(_host_bytes(x) for x in ts)
+            continue
+
         if key == "wkvb":
             # the latent's up-projection serves absorbed attention as two
             # dense per-head operands (models/params.split_wkvb)
@@ -501,7 +515,8 @@ def load_params_streamed(
                 arr = placer.dense(name, half)
                 dest[name] = arr.astype(dtype) if dtype != jnp.float32 else arr
         elif key in ("rms_att", "rms_ffn", "rms_moe", "rms_ffn2", "rms_final",
-                     "rms_kv", "moe_bias"):
+                     "rms_kv", "moe_bias", "rms_q", "rms_k", "rms_o",
+                     "conv_w", "a_log", "dt_bias"):
             if stage is not None:  # per-layer norms stack too, kept f32
                 pp_stack.add(dest, key, stage, "dense", dtype, [t],
                              keep_f32=True)

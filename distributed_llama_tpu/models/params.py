@@ -31,7 +31,7 @@ from ..io.model_file import HostTensor, model_tensor_plan
 from ..quants.jax_codec import QuantizedTensor
 from ..quants.numpy_codec import quantize_q40
 from ..quants.types import FloatType
-from .spec import ArchType, ModelSpec
+from .spec import ArchType, LayerKind, ModelSpec
 
 
 def _to_q40_host(x: np.ndarray) -> HostTensor:
@@ -98,7 +98,17 @@ def load_params(
         if spec.arch == ArchType.GROK1:
             lw["rms_moe"] = dev(f"layers.{l}.rms_moe", tensors[f"layers.{l}.rms_moe"].to_f32())
             lw["rms_ffn2"] = dev(f"layers.{l}.rms_ffn2", tensors[f"layers.{l}.rms_ffn2"].to_f32())
-        if spec.is_mla:
+        if spec.layer_kinds[l] == LayerKind.DELTA:
+            for w in ("wq", "wk", "wv", "wg", "wo"):
+                lw[w] = weight(tensors[f"layers.{l}.{w}"], f"layers.{l}.{w}")
+            # decay and beta rows: two thin projections, dense on the device
+            lw["w_ab"] = dev(f"layers.{l}.w_ab", np.concatenate(
+                [tensors[f"layers.{l}.{w}"].to_f32()
+                 for w in ("wa", "wb")]).astype(dtype))
+            for w in ("conv_w", "a_log", "dt_bias", "rms_o"):
+                lw[w] = dev(f"layers.{l}.{w}",
+                            tensors[f"layers.{l}.{w}"].to_f32())
+        elif spec.is_mla:
             lw["rms_kv"] = dev(f"layers.{l}.rms_kv",
                                tensors[f"layers.{l}.rms_kv"].to_f32())
             for w in ("wq", "wkva", "wo"):
@@ -111,6 +121,10 @@ def load_params(
             for w in ("wq", "wk", "wv", "wo"):
                 lw[w] = weight(tensors[f"layers.{l}.{w}"],
                                f"layers.{l}.{w}")
+            if spec.post_norm:
+                for w in ("rms_q", "rms_k"):
+                    lw[w] = dev(f"layers.{l}.{w}",
+                                tensors[f"layers.{l}.{w}"].to_f32())
         if not spec.is_dense_layer(l):
             if spec.is_mla:
                 lw["moe_bias"] = dev(f"layers.{l}.moe_bias",

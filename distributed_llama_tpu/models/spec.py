@@ -23,6 +23,42 @@ class ArchType(enum.IntEnum):
     # over a leading dense layer and sigmoid-bias-routed experts with a
     # shared expert; not a reference-engine architecture
     SARVAM_MLA = 0xABCD03
+    # gated-delta-rule layers (a per-slot recurrent state and a short
+    # convolution) beside full-attention layers without rotation, the
+    # norms on each sublayer's OUTPUT; not a reference-engine architecture
+    OLMO_HYBRID = 0xABCD04
+
+
+class LayerKind(enum.IntEnum):
+    """What a layer's mixer is, and so what a slot remembers in it: K/V
+    rows (ATTENTION), one latent row (LATENT), or a recurrent state and
+    the convolution's tail (DELTA). The per-layer description every cache
+    maker, loader plan, forward and byte ledger reads."""
+
+    ATTENTION = 0
+    LATENT = 1
+    DELTA = 2
+
+
+# What assumes ROWS of a cache and cannot hold a recurrent state yet. Each is
+# refused at start-up with its message (apps/dllama.main before a model is
+# loaded; Engine, PrefixCache and Scheduler for a library caller), as
+# SARVAM_MLA is refused under tp. CHANGES.md (PR 34) says what lifts each.
+STATE_REFUSALS = {
+    "prefix_cache": "--prefix-cache: a cached prefix of this model is a "
+                    "snapshot of every DELTA layer's state at a block "
+                    "boundary, and the arena holds rows of a cache only",
+    "speculation": "--draft / --lookup-decode: a verify step takes a "
+                   "rejected draft back by position, and a recurrent state "
+                   "cannot be taken back",
+    "kv_transfer": "--kv-transfer: a block frame carries rows of a cache; "
+                   "a recurrent state has no frame",
+    "parallel": "--tp / --pp / --sp / --ep / --nnodes > 1: the recurrent "
+                "state and its kernels run on one shard (dp replicas are "
+                "the way to more chips)",
+    "session": "--session: a session file holds the rows of a cache up to "
+               "a position, not a recurrent state",
+}
 
 
 class HiddenAct(enum.IntEnum):
@@ -67,6 +103,89 @@ class ModelSpec:
     rope_beta_slow: float = 1.0
     rope_mscale: float = 1.0
     rope_mscale_all_dim: float = 0.0
+    # -- OLMO_HYBRID only (header keys of their own, as above) -------------
+    mixers: tuple = ()             # LayerKind a layer, from the header;
+    #                                () where every layer is the same kind
+    lin_heads: int = 0             # key and value heads of a DELTA layer
+    lin_k_head_dim: int = 0        # d_k: q and k of a head
+    lin_v_head_dim: int = 0        # d_v: v, gate and output of a head
+    lin_conv_width: int = 0        # taps of the causal depthwise convolution
+    lin_beta_scale: int = 1        # 2: beta in (0, 2), negative eigenvalues
+
+    @property
+    def layer_kinds(self) -> tuple:
+        """LayerKind of every layer: data where the header carries it, the
+        architecture's one kind everywhere else."""
+        if self.mixers:
+            return tuple(LayerKind(m) for m in self.mixers)
+        kind = LayerKind.LATENT if self.is_mla else LayerKind.ATTENTION
+        return (kind,) * self.n_layers
+
+    @property
+    def cache_index(self) -> tuple:
+        """Layer -> index of its leaves among the leaves of its kind
+        (KVCache holds one leaf set a KIND: rows for the layers that
+        attend, state and tail for the DELTA layers)."""
+        seen: dict = {}
+        out = []
+        for kind in self.layer_kinds:
+            rows = kind != LayerKind.DELTA
+            out.append(seen.get(rows, 0))
+            seen[rows] = out[-1] + 1
+        return tuple(out)
+
+    @property
+    def n_cache_layers(self) -> int:
+        """Layers that keep ROWS of a cache (all but the DELTA ones)."""
+        return sum(k != LayerKind.DELTA for k in self.layer_kinds)
+
+    @property
+    def n_state_layers(self) -> int:
+        return self.n_layers - self.n_cache_layers
+
+    @property
+    def has_state(self) -> bool:
+        return self.n_state_layers > 0
+
+    def refusal(self, what: str) -> str | None:
+        """Why this model cannot run with `what` (a key of STATE_REFUSALS)
+        asked for; None where it can."""
+        if not self.has_state:
+            return None
+        return (f"{self.arch.name} keeps a recurrent state a slot and does "
+                f"not run with {STATE_REFUSALS[what]}")
+
+    def refuse(self, what: str) -> None:
+        """ValueError with that message, for a library caller."""
+        why = self.refusal(what)
+        if why:
+            raise ValueError(why)
+
+    @property
+    def post_norm(self) -> bool:
+        """Norms on each sublayer's output (h = x + norm(mixer(x))) and
+        q, k normed at full width, instead of a norm on its input."""
+        return self.arch == ArchType.OLMO_HYBRID
+
+    @property
+    def lin_conv_dim(self) -> int:
+        """Channels of a DELTA layer's convolution: [q ; k ; v]."""
+        return self.lin_heads * (2 * self.lin_k_head_dim
+                                 + self.lin_v_head_dim)
+
+    def state_bytes_per_slot(self, cache_itemsize: int) -> int:
+        """Bytes a slot holds over all DELTA layers, whatever its context:
+        the float32 state and the convolution's tail, which is kept in the
+        cache dtype but never narrower than bf16 (the ONE statement of that
+        rule: KVCache.create asks `tail_itemsize`)."""
+        n = self.n_state_layers
+        state = n * self.lin_heads * self.lin_k_head_dim * self.lin_v_head_dim
+        tail = n * max(self.lin_conv_width - 1, 0) * self.lin_conv_dim
+        return state * 4 + tail * self.tail_itemsize(cache_itemsize)
+
+    @staticmethod
+    def tail_itemsize(cache_itemsize: int) -> int:
+        return max(cache_itemsize, 2)
 
     @property
     def is_mla(self) -> bool:
@@ -95,9 +214,9 @@ class ModelSpec:
 
     @property
     def cache_values_per_token(self) -> int:
-        """Cache VALUES a token holds over all layers (times the cache
-        dtype's item size: bytes)."""
-        return self.n_layers * self.n_kv_heads * (
+        """Cache VALUES a token holds over the layers that HAVE a cache
+        (times the cache dtype's item size: bytes)."""
+        return self.n_cache_layers * self.n_kv_heads * (
             self.cache_head_size + self.cache_v_head_size)
 
     @property
@@ -146,6 +265,13 @@ class ModelSpec:
                     <= self.router_width), "held experts outside the router"
             assert self.n_active_experts <= self.router_width
             return
+        if self.mixers:
+            assert len(self.mixers) == self.n_layers, "one kind a layer"
+        if self.has_state:
+            assert min(self.lin_heads, self.lin_k_head_dim,
+                       self.lin_v_head_dim) > 0
+            assert self.lin_conv_width >= 2
+            assert self.lin_beta_scale in (1, 2)
         if self.arch in (ArchType.GROK1, ArchType.MIXTRAL):
             # MoE archs without experts would fail deep inside the forward
             # (missing moe_router); reject at spec level instead

@@ -1,4 +1,5 @@
-"""Unified transformer forward for LLAMA / MIXTRAL / GROK1 / SARVAM_MLA.
+"""Unified transformer forward for LLAMA / MIXTRAL / GROK1 / SARVAM_MLA /
+OLMO_HYBRID.
 
 One jittable segment-forward covers both prefill (T tokens at once — net-new
 vs the reference, which feeds the prompt token-by-token) and decode (T=1).
@@ -43,7 +44,7 @@ from ..ops.matmul import matmul
 from ..ops.norms import rmsnorm
 from ..ops.rope import apply_rope
 from ..quants.jax_codec import QuantizedTensor
-from .spec import ArchType, ModelSpec
+from .spec import ArchType, LayerKind, ModelSpec
 
 GROK_INPUT_SCALE = 78.38367176906169      # ref: src/grok1-tasks.cpp:13
 GROK_LOGIT_SCALE = 0.5773502691896257     # ref: src/grok1-tasks.cpp:271
@@ -56,7 +57,13 @@ def _flash_ok(t: int, h: int, kvh: int) -> bool:
 
 
 class KVCache(NamedTuple):
-    """Per-layer KV cache: tuples of L arrays, each (B, KVH, S, hs).
+    """A slot batch's memory of the past, one leaf SET a layer kind
+    (ModelSpec.layer_kinds; a layer finds its leaves at
+    ModelSpec.cache_index): `k` and `v`, one (B, KVH, S, hs) array for
+    every layer that attends rows; `s` and `conv`, for every DELTA layer
+    its float32 state (B, H, d_k, d_v) and the last taps - 1 rows the
+    convolution has seen (B, taps - 1, channels). A DELTA layer holds NO
+    context-sized leaf.
 
     Separate per-layer buffers (not one stacked (L, ...) array) so that a
     donated cache is updated strictly in place — profiling showed XLA copies
@@ -69,6 +76,8 @@ class KVCache(NamedTuple):
 
     k: tuple
     v: tuple
+    s: tuple = ()
+    conv: tuple = ()
 
     @classmethod
     def create(cls, spec: ModelSpec, batch: int, seq_len: int | None = None,
@@ -78,7 +87,7 @@ class KVCache(NamedTuple):
         stores only its own layers' cache (parallel/pp.py)."""
         s = seq_len or spec.seq_len
         shape = (batch, spec.n_kv_heads, s, spec.cache_head_size)
-        n = spec.n_layers
+        n = spec.n_cache_layers
         if pp > 1:
             assert n % pp == 0, (n, pp)
             shape = (pp,) + shape
@@ -86,10 +95,21 @@ class KVCache(NamedTuple):
         # the latent cache (SARVAM_MLA) is ONE leaf a layer, (B, 1, S,
         # r + d_r): the normed latent is key and value at once, so `v` is
         # empty
+        # the convolution's tail keeps the projection's own values: never
+        # narrower than bf16, whatever the rows are kept in
+        item = jnp.dtype(dtype).itemsize
+        tail_dtype = dtype if spec.tail_itemsize(item) == item else jnp.bfloat16
+        m = spec.n_state_layers
         return cls(
             tuple(jnp.zeros(shape, dtype) for _ in range(n)),
             tuple(jnp.zeros(shape, dtype) for _ in range(n))
             if spec.cache_v_head_size else (),
+            tuple(jnp.zeros((batch, spec.lin_heads, spec.lin_k_head_dim,
+                             spec.lin_v_head_dim), jnp.float32)
+                  for _ in range(m)),
+            tuple(jnp.zeros((batch, spec.lin_conv_width - 1,
+                             spec.lin_conv_dim), tail_dtype)
+                  for _ in range(m)),
         )
 
 
@@ -249,7 +269,10 @@ def _attention_block(x, lw, spec: ModelSpec, k_cache, v_cache, q_pos, cfg,
         # attention are per-head, so only the reshape bookkeeping changes.
         h, kvh = h // f, kvh // f
 
-    xb = rmsnorm(x, lw["rms_att"])  # ref: llama2-tasks.cpp:10-21
+    if spec.post_norm:
+        xb = x          # the norm sits on the sublayer's OUTPUT (_layer)
+    else:
+        xb = rmsnorm(x, lw["rms_att"])  # ref: llama2-tasks.cpp:10-21
     if "wqkv" in lw:
         # fused QKV projection (single-shard path): one kernel call, one
         # shared activation prep, deeper DMA pipeline
@@ -262,8 +285,16 @@ def _attention_block(x, lw, spec: ModelSpec, k_cache, v_cache, q_pos, cfg,
         k = matmul(xb, lw["wk"], **cfg).reshape(b, t, kvh, hs)
         v = matmul(xb, lw["wv"], **cfg).reshape(b, t, kvh, hs)
 
-    q = apply_rope(q, q_pos, spec.rope_theta, spec.arch)
-    k = apply_rope(k, q_pos, spec.rope_theta, spec.arch)
+    if spec.post_norm:
+        # q and k normed over the whole projected width, before the heads
+        q = rmsnorm(q.reshape(b, t, h * hs), lw["rms_q"],
+                    spec.norm_eps).reshape(b, t, h, hs)
+        k = rmsnorm(k.reshape(b, t, kvh * hs), lw["rms_k"],
+                    spec.norm_eps).reshape(b, t, kvh, hs)
+    if spec.rope_theta > 0:     # 0: no rotation (position reaches such a
+        # model's attention through its recurrent layers)
+        q = apply_rope(q, q_pos, spec.rope_theta, spec.arch)
+        k = apply_rope(k, q_pos, spec.rope_theta, spec.arch)
 
     # functional cache update at positions q_pos (contiguous per row:
     # pos[b]..pos[b]+T); cache is head-major (B, KVH, S, hs) — see KVCache
@@ -378,6 +409,86 @@ def _attention_block(x, lw, spec: ModelSpec, k_cache, v_cache, q_pos, cfg,
         att = decode_attention(q, k_cache, v_cache, q_pos)  # (B, T, H, hs)
     out = matmul(att.reshape(b, t, h * hs), lw["wo"], **cfg)
     return out, k_cache, v_cache
+
+
+class SegmentRows(NamedTuple):
+    """What a recurrent state has to know of a segment's rows and a cache
+    of rows never did (ops/pallas_delta_rule.py says why): n_valid (B,)
+    int32, the tokens of row b that advance its state — 0 for a gated row
+    (pos == the context), logit_index + 1 in a right-padded tail chunk,
+    else T; fresh (B,) bool, the row's segment starts at position 0, so
+    its state starts from zeros whatever the slot held."""
+
+    n_valid: jnp.ndarray
+    fresh: jnp.ndarray
+
+
+def _delta_block(x, lw, spec: ModelSpec, state, tail, rows: SegmentRows,
+                 cfg):
+    """Gated delta rule mixer: projections -> causal depthwise convolution
+    and SiLU on [q ; k ; v] -> the rule on unit-length q, k -> per-head
+    gated RMS norm -> output projection. Returns (the wo projection, not
+    yet normed or added to the residual, new state, new tail)."""
+    from ..ops.pallas_delta_rule import delta_rule
+
+    b, t, _ = x.shape
+    h, dk, dv = spec.lin_heads, spec.lin_k_head_dim, spec.lin_v_head_dim
+    taps, f32 = spec.lin_conv_width, jnp.float32
+    with jax.named_scope("gdn_proj"):
+        if "wqkv" in lw:
+            qkv = matmul(x, lw["wqkv"], **cfg)
+        else:
+            qkv = jnp.concatenate(
+                [matmul(x, lw[w], **cfg) for w in ("wq", "wk", "wv")], -1)
+        z = matmul(x, lw["wg"], **cfg)
+        ab = matmul(x, lw["w_ab"], **cfg).astype(f32)      # (B, T, 2H)
+    with jax.named_scope("gdn_conv"):
+        # row t of the output sees rows t .. t + taps - 1 of [tail ; qkv];
+        # the new tail is the last taps - 1 rows that COUNT
+        tail = jnp.where(rows.fresh[:, None, None], 0, tail)
+        xcat = jnp.concatenate([tail.astype(qkv.dtype), qkv], axis=1)
+        conv_w = lw["conv_w"]
+        y = sum(conv_w[j] * xcat[:, j:j + t].astype(f32)
+                for j in range(taps))
+        y = jax.nn.silu(y)
+        tail = jax.vmap(
+            lambda xc, n: lax.dynamic_slice_in_dim(xc, n, taps - 1, 0))(
+                xcat, rows.n_valid).astype(tail.dtype)
+    q = y[..., :h * dk].reshape(b, t, h, dk)
+    k = y[..., h * dk:2 * h * dk].reshape(b, t, h, dk)
+    v = y[..., 2 * h * dk:].reshape(b, t, h, dv)
+
+    def unit(u):
+        return u * lax.rsqrt(jnp.sum(u * u, -1, keepdims=True) + 1e-6)
+
+    with jax.named_scope("gdn_rule"):
+        g = -jnp.exp(lw["a_log"]) * jax.nn.softplus(ab[..., :h]
+                                                    + lw["dt_bias"])
+        beta = spec.lin_beta_scale * jax.nn.sigmoid(ab[..., h:])
+        o, state = delta_rule(
+            unit(q) * dk ** -0.5, unit(k), v, g, beta, state, rows.n_valid,
+            rows.fresh, use_pallas=bool(cfg.get("use_pallas")),
+            interpret=cfg.get("pallas_interpret", False))
+    with jax.named_scope("gdn_out"):
+        o = (rmsnorm(o, lw["rms_o"], spec.norm_eps)
+             * jax.nn.silu(z.reshape(b, t, h, dv).astype(f32)))
+        out = matmul(o.astype(x.dtype).reshape(b, t, h * dv), lw["wo"],
+                     **cfg)
+    return out, state, tail
+
+
+def _post_norm_tail(x, mix_out, lw, spec: ModelSpec, cfg):
+    """h = x + norm(mixer(x)); x' = h + norm(ffn(h)): both norms on the
+    sublayers' outputs."""
+    eps = spec.norm_eps
+    x = x + rmsnorm(mix_out, lw["rms_att"], eps).astype(x.dtype)
+    ffn = _dense_ffn(x, lw, spec, cfg)
+    return x + rmsnorm(ffn, lw["rms_ffn"], eps).astype(x.dtype)
+
+
+def _delta_layer(x, lw, spec: ModelSpec, state, tail, rows, cfg):
+    mix_out, state, tail = _delta_block(x, lw, spec, state, tail, rows, cfg)
+    return _post_norm_tail(x, mix_out, lw, spec, cfg), state, tail
 
 
 def _dense_ffn(xb, lw, spec: ModelSpec, cfg):
@@ -554,6 +665,9 @@ def _layer(x, lw, spec: ModelSpec, k_cache, v_cache, q_pos, cfg, sp_mesh=None,
         sp_cache_mesh=sp_cache_mesh, per_row_pos=per_row_pos,
         write_gate=write_gate)
 
+    if spec.post_norm:
+        return (_post_norm_tail(x, attn_out, lw, spec, cfg), k_cache,
+                v_cache)
     if spec.arch == ArchType.GROK1:
         # post-attention norm BEFORE residual add (ref: grok1-tasks.cpp:16-41)
         x = x + rmsnorm(attn_out, lw["rms_ffn"]).astype(x.dtype)
@@ -633,6 +747,8 @@ def forward(
     if spec.arch == ArchType.GROK1:
         x = x * GROK_INPUT_SCALE
 
+    s_all: list = []
+    conv_all: list = []
     per_row_pos = getattr(pos0, "ndim", 0) == 1
     if per_row_pos:
         q_pos = pos0[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
@@ -661,13 +777,23 @@ def forward(
         k_all, v_all = list(k_all), list(v_all)
     else:
         # statically unrolled layer loop (see module docstring for why not
-        # scan)
+        # scan); a layer takes the leaves of its KIND
         k_all = []
         v_all = []
+        kinds, at = spec.layer_kinds, spec.cache_index
+        rows = (_segment_rows(spec, cache, pos0, b, t, logit_index,
+                              logits_for_all) if spec.has_state else None)
         for l in range(spec.n_layers):
+            if kinds[l] == LayerKind.DELTA:
+                x, s_new, c_new = _delta_layer(
+                    x, params["layers"][l], spec, cache.s[at[l]],
+                    cache.conv[at[l]], rows, cfg)
+                s_all.append(s_new)
+                conv_all.append(c_new)
+                continue
             x, k_new, v_new = _layer(x, params["layers"][l], spec,
-                                     cache.k[l],
-                                     cache.v[l] if cache.v else None,
+                                     cache.k[at[l]],
+                                     cache.v[at[l]] if cache.v else None,
                                      q_pos, cfg,
                                      sp_mesh=sp_mesh,
                                      sp_cache_mesh=sp_cache_mesh,
@@ -687,4 +813,20 @@ def forward(
     logits = matmul(x, params["wcls"], **cfg).astype(jnp.float32)
     if spec.arch == ArchType.GROK1:
         logits = logits * GROK_LOGIT_SCALE  # ref: grok1-tasks.cpp:269-272
-    return logits, KVCache(tuple(k_all), tuple(v_all))
+    return logits, KVCache(tuple(k_all), tuple(v_all), tuple(s_all),
+                           tuple(conv_all))
+
+
+def _segment_rows(spec: ModelSpec, cache: KVCache, pos0, b: int, t: int,
+                  logit_index, logits_for_all: bool) -> SegmentRows:
+    """SegmentRows of one forward, from what every caller already passes:
+    the rows' first positions (a row at the context's end is gated, as
+    for the cache writes) and, in a right-padded segment, logit_index."""
+    gate_at = cache.k[0].shape[2] if cache.k else spec.seq_len
+    pos_rows = jnp.broadcast_to(jnp.asarray(pos0, jnp.int32), (b,))
+    n_tok = jnp.full((b,), t, jnp.int32)
+    if logit_index is not None and not logits_for_all:
+        n_tok = jnp.broadcast_to(
+            jnp.asarray(logit_index, jnp.int32) + 1, (b,))
+    n_valid = jnp.where(pos_rows < gate_at, n_tok, 0)
+    return SegmentRows(n_valid, (pos_rows == 0) & (n_valid > 0))

@@ -227,7 +227,19 @@ def _tile_d(d: int, m: int) -> int:
 
     A shape that fits none of these raises — it must never fall through to
     a whole-weight block the chip's compiler refuses."""
-    fits = [t for t in TILE_D_CANDIDATES if t * m <= _TILE_BYTES_MAX]
+    # Scales whose block count a row (m / 16) is not whole lane tiles cost
+    # more VMEM: pltpu.repeat lays its 16 copies side by side in the lanes,
+    # and copies that do not start on a lane tile are shifted through
+    # temporaries. Compiled for a described v5e at 8 rows, a (1024, 1920)
+    # tile (120 blocks) needs 17.8 MiB of scoped VMEM where (1024, 2048)
+    # needs 13.0, and (512, 1920) 8.4 where (512, 2048) needs 5.4; a
+    # (256, 7168) tile (448 blocks, 3.5 lane tiles) needs 11.2. The charge
+    # below, one f32 copy of the tile's scales (4 B for each 16 packed
+    # bytes, a quarter), is a BUDGET that sends the one overflowing tile a
+    # size down and moves no other (tests/test_pallas_q40.py pins every
+    # configuration's tiles); it is not a model of those temporaries
+    cost = m + m // 4 if (m // 16) % LANES else m
+    fits = [t for t in TILE_D_CANDIDATES if t * cost <= _TILE_BYTES_MAX]
     for t in fits:
         if d % t == 0:
             return t

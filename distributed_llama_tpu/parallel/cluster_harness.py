@@ -60,11 +60,17 @@ import time
 from . import multihost as mh
 
 
+_EMIT_LOCK = threading.Lock()
+
+
 def _emit(event: str, **fields) -> None:
     # the harness's whole OUTPUT is these JSON lines — host CLI, not
-    # kernel debug leftovers
-    print(json.dumps({"event": event, "t_wall": time.time(), **fields}),  # dlgrind: ignore[DLG106]
-          flush=True)
+    # kernel debug leftovers. One line a call: the detector's thread emits
+    # its loss while the main thread may be emitting "formed", and print
+    # writes the text and its newline apart
+    with _EMIT_LOCK:
+        print(json.dumps({"event": event, "t_wall": time.time(), **fields}),  # dlgrind: ignore[DLG106]
+              flush=True)
 
 
 def _emit_trace_dump(tid: int) -> None:

@@ -660,9 +660,16 @@ class RootLink(_LinkBase):
     def _lost(self, peer: _Peer, reason: str) -> None:
         peer.alive = False
         age = _now() - peer.last_seen
-        peer.close()
-        self._report_lost(
-            ClusterPeerLost(peer.rank, age, self.phase, reason))
+        # report, THEN close: the first report of a peer wins, and the
+        # thread that saw the fault must be the one to name it. Closed
+        # first, the heartbeat's next send fails on the descriptor this
+        # call just closed and its "send failed: Bad file descriptor"
+        # can be recorded before the receiver's "truncated frame"
+        try:
+            self._report_lost(
+                ClusterPeerLost(peer.rank, age, self.phase, reason))
+        finally:
+            peer.close()
 
     def broadcast(self, kind: int, ints, payload: bytes = b"") -> None:
         """Fan one protocol frame out to every worker (the reference

@@ -65,6 +65,15 @@ _SPLIT = {
     "sh_w1": None,
     "sh_w2": None,
     "sh_w3": None,
+    # OLMO_HYBRID (one shard or dp only, refused under tp/pp/sp/ep)
+    "wg": None,
+    "w_ab": None,
+    "conv_w": None,
+    "a_log": None,
+    "dt_bias": None,
+    "rms_o": None,
+    "rms_q": None,
+    "rms_k": None,
 }
 
 

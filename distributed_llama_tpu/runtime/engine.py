@@ -197,6 +197,9 @@ class Engine:
             assert mesh is None or all(
                 mesh.shape.get(a, 1) == 1 for a in ("tp", "pp", "sp", "ep")), (
                 "SARVAM_MLA runs on one shard (dp only)")
+        if mesh is not None and any(
+                mesh.shape.get(a, 1) > 1 for a in ("tp", "pp", "sp", "ep")):
+            spec.refuse("parallel")
         # --buffer-float-type q80 with tp>1 => wo/w2 partial sums exchange
         # int8 blocks over ICI instead of the GSPMD-exact f32 all-reduce
         # (the reference's wire compression, ref: src/tasks.cpp:124-163)
@@ -408,13 +411,17 @@ class Engine:
         # long-context caches). The jitted maker is built once: reset() is a
         # server hot path (per-request) and must not retrace.
         if "cache_maker" not in self._steps:
-            n_l = self.spec.n_layers
+            n_l = self.spec.n_cache_layers
             if self._pp > 1:  # stage-stacked: n_layers/pp leaves (pp, ...)
                 n_l //= self._pp
+            # a state and its tail split over dp with their rows
+            rows = (NamedSharding(self.mesh, P(DP_AXIS)),
+                    ) * self.spec.n_state_layers
             shardings = KVCache(
                 (self._cache_sharding,) * n_l,
                 (self._cache_sharding,) * (
-                    n_l if self.spec.cache_v_head_size else 0))
+                    n_l if self.spec.cache_v_head_size else 0),
+                rows, rows)
             self._mint("cache_maker", jax.jit(
                 lambda: KVCache.create(self.spec, self.batch, self.seq_len,
                                        self.cache_dtype, pp=self._pp),
@@ -441,6 +448,7 @@ class Engine:
         chat CLI stores its conversation so a resumed session can keep
         mining speculative drafts from pre-restart turns)."""
         assert self._pp == 1, "session save/restore does not support --pp"
+        self.spec.refuse("session")
         data: dict = {
             "pos": np.int64(self.pos),
             "cache_dtype": np.str_(jnp.dtype(self.cache_dtype).name),
@@ -474,6 +482,7 @@ class Engine:
         placement included) and sets pos. Returns the saved token history
         ([] for files saved without one)."""
         assert self._pp == 1, "session save/restore does not support --pp"
+        self.spec.refuse("session")
         z = np.load(path)
         saved, mine = list(z["config"]), self._session_fingerprint()
         # the weight-content element compares only when BOTH sides know it:
@@ -705,6 +714,8 @@ class Engine:
         so a new forward() knob is threaded exactly once."""
         if key in self._steps:
             return self._steps[key]
+        if logits_for_all:
+            self.spec.refuse("speculation")
 
         common = self._forward_kwargs()
         if with_logit_index:
@@ -1575,6 +1586,7 @@ class Engine:
 
         b, t = tokens.shape
         assert b == self.batch, (b, self.batch)
+        self.spec.refuse("speculation")
         key = ("slot_verify", t, int(n_vocab))
         if key not in self._steps:
             common = self._forward_kwargs()

@@ -90,6 +90,7 @@ class PrefixCache:
                  transfer: bool = False):
         assert num_blocks >= 1, num_blocks
         assert 1 <= block_len <= engine.seq_len, block_len
+        engine.spec.refuse("kv_transfer" if transfer else "prefix_cache")
         self.engine = engine
         # cross-replica KV block transfer (runtime/kv_transfer.py): when
         # armed, warmup() also compiles the block export/import

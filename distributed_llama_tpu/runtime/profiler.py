@@ -393,7 +393,8 @@ def hbm_ledger(engine, prefix_cache=None, *, block_len: int | None = None,
                  * compute_itemsize)
     per_token = spec.cache_values_per_token * cache_itemsize
     per_slot = (kv // engine.batch if engine.batch else 0) or (
-        engine.seq_len * per_token)
+        engine.seq_len * per_token
+        + spec.state_bytes_per_slot(cache_itemsize))
     per_block = (arena // n_blocks) if n_blocks else (
         int(bl or 32) * per_token)
     accounted = weights + vocab_b + kv + arena + logits_ws

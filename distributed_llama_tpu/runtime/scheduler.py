@@ -412,6 +412,8 @@ class Scheduler:
         # serves every slot; per-slot frontiers live on the slots.
         from .stats import SpecStats
 
+        if draft_factory:
+            engine.spec.refuse("speculation")
         self.draft = draft_factory(engine) if draft_factory else None
         self.draft_len = int(draft_len) if self.draft is not None else 0
         assert self.draft is None or self.draft_len >= 1, \
@@ -453,9 +455,11 @@ class Scheduler:
         self._mutex = threading.RLock()  # step()/exclusive() mutual excl.
         self._wake = threading.Event()
         self.stats = ServeStats()
+        itemsize = np.dtype(engine.cache_dtype).itemsize
         self.stats.cache_bytes_per_token = (
-            engine.spec.cache_values_per_token
-            * np.dtype(engine.cache_dtype).itemsize)
+            engine.spec.cache_values_per_token * itemsize)
+        self.stats.state_bytes_per_slot = (
+            engine.spec.state_bytes_per_slot(itemsize))
         if prefix_cache is not None:
             self.stats.prefix = prefix_cache.stats
         self.stats.admission = self.admission  # None when no SLO is set
@@ -814,6 +818,7 @@ class Scheduler:
         lidx = np.zeros((b,), np.int32)
         finishing = []
         self.stats.prefill_steps += 1
+        self.stats.prefill_rows += len(rows)
         self.stats.gated_rows += b - len(rows)
         for s in rows:
             n = min(c, len(s.req.prompt) - s.off)

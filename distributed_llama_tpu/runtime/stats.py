@@ -231,7 +231,8 @@ class StepTimelineStats:
 # ServeStats' window counters, by /stats key (docs/observability.md and
 # PERF.md section 3 say which per-layer metric reads which)
 WINDOW_COUNTERS = ("admitted", "queue_wait_ms_sum", "prefill_steps",
-                   "prefill_tokens", "decode_steps", "decode_rows",
+                   "prefill_tokens", "prefill_rows", "decode_steps",
+                   "decode_rows",
                    "gated_rows", "busy_ms", "wait_ms", "host_ms",
                    "attn_pairs_decode", "attn_pairs_prefill",
                    "prefill_cached_tokens")
@@ -291,6 +292,7 @@ class ServeStats:
     prefill_steps: int = 0         # prefill-chunk programs dispatched
     prefill_tokens: int = 0        # real prompt tokens in them (no pad
     #                                rows; with or without --prefix-cache)
+    prefill_rows: int = 0          # rows that prefilled, summed over them
     decode_steps: int = 0          # decode / verify programs dispatched
     decode_rows: int = 0           # rows that decoded in them
     gated_rows: int = 0            # rows passed at pos == seq_len, over the
@@ -305,9 +307,12 @@ class ServeStats:
     attn_pairs_prefill: int = 0
     prefill_cached_tokens: int = 0  # cache rows a chunk's real rows attend
     #                                 (offset + tokens, summed over rows)
-    # gauge, set by the Scheduler: cache bytes one token holds over all
-    # layers (K and V leaves, or the latent cache's one leaf)
+    # gauges, set by the Scheduler: cache bytes one token holds over the
+    # layers that HAVE a cache (K and V leaves, or the latent cache's one
+    # leaf), and the bytes a slot holds whatever its context (the DELTA
+    # layers' float32 state and convolution tail; 0 without such layers)
     cache_bytes_per_token: int = 0
+    state_bytes_per_slot: int = 0
     # attached by the Scheduler when the radix prefix cache is on — its
     # summary rides the same /stats payload as a `prefix_cache` block
     prefix: PrefixCacheStats | None = None
@@ -354,6 +359,7 @@ class ServeStats:
             v = getattr(self, k)
             out[k] = round(v, 3) if isinstance(v, float) else v
         out["cache_bytes_per_token"] = self.cache_bytes_per_token
+        out["state_bytes_per_slot"] = self.state_bytes_per_slot
         if self.prefix is not None:
             out["prefix_cache"] = self.prefix.summary()
         if self.admission is not None:

@@ -561,6 +561,8 @@ _COUNTERS = (
      "Prefill-chunk programs dispatched"),
     ("prefill_tokens", "dllama_prefill_tokens_total",
      "Real prompt tokens prefilled (pad rows excluded)"),
+    ("prefill_rows", "dllama_prefill_rows_total",
+     "Rows that prefilled, summed over prefill-chunk programs"),
     ("decode_steps", "dllama_decode_steps_total",
      "Decode or verify programs dispatched"),
     ("decode_rows", "dllama_decode_rows_total",

@@ -15,7 +15,7 @@ import numpy as np
 
 from .io import (TokenizerData, model_tensor_plan, write_model,
                  write_tokenizer_file)
-from .models import ArchType, HiddenAct, ModelSpec
+from .models import ArchType, HiddenAct, LayerKind, ModelSpec
 from .quants import FloatType
 
 
@@ -45,6 +45,24 @@ def tiny_mla_spec(weights_float_type: FloatType = FloatType.Q40,
         routed_scaling=2.5, rms_eps=1e-6, rope_factor=40.0, rope_orig_len=32,
         rope_beta_fast=32.0, rope_beta_slow=1.0, rope_mscale=1.0,
         rope_mscale_all_dim=1.0)
+    base.update(overrides)
+    return ModelSpec(**base)
+
+
+def tiny_hybrid_spec(weights_float_type: FloatType = FloatType.Q40,
+                     **overrides) -> ModelSpec:
+    """OLMO_HYBRID at a size a CPU holds: two periods of (DELTA x 3,
+    ATTENTION), 2 delta heads of 32 / 64 over a 4-tap convolution, 4
+    attention heads without rotation, beta in (0, 2)."""
+    period = (LayerKind.DELTA,) * 3 + (LayerKind.ATTENTION,)
+    base = dict(
+        arch=ArchType.OLMO_HYBRID, dim=64, hidden_dim=128, n_layers=8,
+        n_heads=4, n_kv_heads=4, vocab_size=288, seq_len=160,
+        hidden_act=HiddenAct.SILU, rope_theta=0.0,
+        weights_float_type=weights_float_type, rms_eps=1e-6,
+        mixers=tuple(int(k) for k in period * 2), lin_heads=2,
+        lin_k_head_dim=32, lin_v_head_dim=64, lin_conv_width=4,
+        lin_beta_scale=2)
     base.update(overrides)
     return ModelSpec(**base)
 
@@ -90,6 +108,25 @@ def write_fixture(dirpath, seed: int = 77, rng=None,
     return mpath, tpath
 
 
+# OLMO_HYBRID's synthetic draw, chosen so that a check on the logits can
+# see precision (PERF.md section 6, PR 34 after review, has every draw read
+# on the chip). Its norms sit on each sublayer's OUTPUT: with unit gains on
+# a 0.02 embedding every sublayer REPLACES a sixty-fourth of the stream and
+# the served path's own bf16 / Q80 rounding reads 0.13 at the logits,
+# hiding whatever a lower precision adds. A unit embedding and output
+# gains of a tenth (each sublayer moves the stream by about a tenth of its
+# norm, as a trained block does) put that floor at 0.03-0.05. q / k gains
+# of 4.5 in the full-attention layers give scores a std of ~20 (4.5^2:
+# peaked heads, as trained ones are): at a std of 1 over 2100 positions
+# softmax is a near-uniform average that forgives its terms, and fp8 rows
+# read as bf16 ones. The decay and step rows keep a and b at a std of ~0.4
+# on that stream.
+HYBRID_EMBEDDING_STD = 1.0
+HYBRID_NORM_GAINS = {"rms_att": 0.1, "rms_ffn": 0.1,
+                     "rms_q": 4.5, "rms_k": 4.5}
+HYBRID_DECAY_ROWS_SCALE = 0.1
+
+
 def write_synthetic_model(path: str, spec: ModelSpec, seed: int) -> int:
     """Stream random-but-valid tensors to `path` in exact plan order, one
     tensor resident at a time: Q40 blocks get f16 scales in [0.005, 0.02]
@@ -101,14 +138,34 @@ def write_synthetic_model(path: str, spec: ModelSpec, seed: int) -> int:
     from .quants.types import BLOCK_SIZE, Q40_BLOCK_BYTES
 
     rng = np.random.default_rng(seed)
+    hybrid = spec.arch == ArchType.OLMO_HYBRID
+    zero_mean = spec.is_mla or hybrid
     with open(path, "wb") as f:
         write_header(f, spec)
         for name, shape, ftype in model_tensor_plan(spec):
             n = int(np.prod(shape))
             if ftype == FloatType.F32:
                 x = rng.standard_normal(n, dtype=np.float32) * 0.02
+                if hybrid and name == "tok_emb":
+                    x *= HYBRID_EMBEDDING_STD / 0.02
                 if "rms" in name:
                     x += 1.0
+                    if hybrid:
+                        x *= HYBRID_NORM_GAINS.get(name.split(".")[-1], 1.0)
+                elif name.endswith("a_log"):
+                    # the published initialisation of the layer: A uniform
+                    # in (0, 16), so that with dt below heads forget over a
+                    # few tokens or over thousands
+                    x = np.log(rng.uniform(1e-3, 16.0, n)).astype(np.float32)
+                elif name.endswith("dt_bias"):
+                    # dt log-uniform in [0.001, 0.1], stored through the
+                    # inverse of softplus
+                    dt = np.exp(rng.uniform(np.log(1e-3), np.log(0.1), n))
+                    x = (dt + np.log(-np.expm1(-dt))).astype(np.float32)
+                elif name.endswith("conv_w"):
+                    # a depthwise convolution's default: uniform within
+                    # 1 / sqrt(taps)
+                    x = rng.uniform(-0.5, 0.5, n).astype(np.float32)
                 elif name.endswith("moe_bias"):
                     # a router with preferences (std 0.5 beside scores in
                     # (0, 1)): with a bias of 0.02 every token's eighth
@@ -126,9 +183,16 @@ def write_synthetic_model(path: str, spec: ModelSpec, seed: int) -> int:
                 # tells an fp8 cache from a bf16 one (below)
                 scales = rng.uniform(
                     *((0.0035, 0.008) if spec.is_mla else (0.005, 0.02)),
-                    nb).astype(np.float16)
+                    nb)
+                if hybrid and name.endswith((".wa", ".wb")):
+                    # decay and beta rows: small, so that the drawn a_log
+                    # and dt_bias set a head's memory and beta stays
+                    # inside (0, 2) (at the other matrices' scale both
+                    # saturate on every token)
+                    scales *= HYBRID_DECAY_ROWS_SCALE
+                scales = scales.astype(np.float16)
                 raw[:, :2] = scales.reshape(nb, 1).view(np.uint8)
-                if spec.is_mla:
+                if zero_mean:
                     # nibbles 1..15, so that a weight (nibble - 8) x scale
                     # has mean ZERO. Uniform bytes (below) give every
                     # matrix a mean of -0.5 x scale, a rank-one part that

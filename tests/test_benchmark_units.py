@@ -23,7 +23,7 @@ sys.path.insert(0, BTESTS)
 
 PURE = ("test_traffic", "test_workmodel", "test_window_metrics",
         "test_tracereduce", "test_manifest", "test_metrics",
-        "test_reference")
+        "test_reference", "test_olmo_hybrid")
 BOOTS_A_SERVER = {"test_traced_rehearsal_reports_the_counter_metrics"}
 
 

@@ -295,6 +295,40 @@ def test_served_sarvam_mla_step_programs_hold_their_kernels(served_moe_step,
     assert not r.cache_shaped_copies(compiled.as_text(), cache.k[0].shape)
 
 
+@pytest.mark.parametrize("t", [1, 32], ids=["decode", "chunk32"])
+def test_served_olmo_hybrid_step_programs_hold_their_kernels(topo, t):
+    """`olmo-hybrid-7b`'s two step programs at published widths (one period
+    of its pattern: three DELTA layers and one ATTENTION layer; B=8, S=8192,
+    the Q80 round trip on, the 100352-row head): the delta rule runs in its
+    kernel of that program (96- and 192-wide heads, a (6, 96, 192) state
+    block), the 8192-row leaves exist for the ATTENTION layer only, and the
+    program holds no copy of a state or a cache leaf."""
+    import rehearse_chip_compile as r
+
+    from distributed_llama_tpu.runtime.profiler import kernel_call_sites
+
+    spec = r.hybrid_layers(r.OLMO_HYBRID_7B, 1)
+    fn, args = r.abstract_step(spec, topo.devices, batch=8, t=t,
+                               seq_len=8192, q80=True)
+    cache = args[-1]
+    assert (len(cache.k), len(cache.v), len(cache.s), len(cache.conv)) == (
+        1, 1, 3, 3)
+    assert cache.k[0].shape == (8, 30, 8192, 128)
+    assert cache.s[0].shape == (8, 30, 96, 192)
+    assert cache.conv[0].shape == (8, 3, 11520)
+    lowered = fn.lower(*args)
+    sites = kernel_call_sites(lowered.as_text())
+    mine, other = (("delta_rule_decode", "delta_rule_chunk") if t == 1
+                   else ("delta_rule_chunk", "delta_rule_decode"))
+    assert sites.get(mine, 0) >= 1 and other not in sites, sites
+    assert sites.get("flash_attention", 0) >= 1, sites
+    assert sites.get("kv_cache_write", 0) >= 1, sites
+    assert sites.get("q40_matmul", 0) >= 5, sites
+    text = lowered.compile().as_text()
+    for leaf in (cache.k[0], cache.s[0]):
+        assert not r.cache_shaped_copies(text, leaf.shape)
+
+
 @pytest.mark.parametrize("b,t", [(8, 1), (8, 32)])
 def test_mla_attention_compiles(one_chip, b, t):
     from distributed_llama_tpu.ops.pallas_attention import mla_attention

@@ -259,6 +259,67 @@ def test_tile_d_is_bounded_for_ragged_row_counts(d, m, want):
     assert td % LANES == 0 and td * m <= _TILE_BYTES_MAX and d % td != 0
 
 
+# (rows d, contraction n) -> tile, for every Q40 matmul of a configuration:
+# whole on one chip, its rows split four ways and its contraction split four
+# ways (tp = 4). The values are the tiles of the tree BEFORE _tile_d charged
+# scale blocks that are not whole lane tiles (PR 34): that rule is a budget
+# found by one compile, not a model of the kernel's VMEM, so every tile it
+# must not move is pinned here. It moved one, olmo-hybrid-7b's head.
+TILES = {
+    "llama2-7b": {
+        (4096, 4096): 1024, (1024, 4096): 1024, (4096, 1024): 1024,
+        (12288, 4096): 1024, (3072, 4096): 1024, (12288, 1024): 1024,
+        (11008, 4096): 256, (2752, 4096): 256, (11008, 1024): 256,
+        (22016, 4096): 512, (5504, 4096): 128, (22016, 1024): 512,
+        (4096, 11008): 256, (1024, 11008): 256, (4096, 2752): 1024,
+        (32000, 4096): 256, (8000, 4096): 1024, (32000, 1024): 256},
+    "mistral-7b, mixtral-8x7b": {
+        (256, 4096): 256, (1024, 1024): 1024, (6144, 4096): 1024,
+        (1536, 4096): 512, (6144, 1024): 1024, (14336, 4096): 1024,
+        (3584, 4096): 512, (14336, 1024): 1024, (28672, 4096): 1024,
+        (7168, 4096): 1024, (28672, 1024): 1024, (4096, 14336): 256,
+        (1024, 14336): 256, (4096, 3584): 1024, (8, 4096): 8,
+        (2, 4096): 2, (8, 1024): 8},
+    "grok1": {
+        (6144, 6144): 512, (1536, 6144): 512, (6144, 1536): 1024,
+        (1024, 6144): 512, (256, 6144): 256, (1024, 1536): 1024,
+        (8192, 6144): 512, (2048, 6144): 512, (8192, 1536): 1024,
+        (32768, 6144): 512, (32768, 1536): 1024, (65536, 6144): 512,
+        (16384, 6144): 512, (65536, 1536): 1024, (6144, 32768): 128,
+        (1536, 32768): 128, (6144, 8192): 512, (131072, 6144): 512,
+        (131072, 1536): 1024, (8, 6144): 8},
+    "llama3-8b head": {
+        (128256, 4096): 256, (32064, 4096): 1024, (128256, 1024): 256},
+    "sarvam-105b-ep8": {
+        (12288, 4096): 1024, (3072, 4096): 1024, (12288, 1024): 1024,
+        (576, 4096): 576, (144, 4096): 144, (576, 1024): 576,
+        (4096, 8192): 512, (1024, 8192): 512, (4096, 2048): 1024,
+        (2048, 4096): 1024, (512, 4096): 512, (2048, 1024): 1024,
+        (1024, 2048): 1024, (4096, 512): 1024, (16384, 4096): 1024,
+        (16384, 1024): 1024, (32768, 4096): 1024, (8192, 4096): 1024,
+        (32768, 1024): 1024, (4096, 16384): 256, (1024, 16384): 256},
+    # 3840-wide contractions have 120 scale blocks a row and 11008-wide
+    # ones 344: not whole lane tiles. The head's (1024, 1920) tile needed
+    # 17.8 MiB of scoped VMEM at 8 rows; at 512 rows it fits
+    "olmo-hybrid-7b": {
+        (2880, 3840): 128, (720, 3840): 720, (2880, 960): 2880,
+        (5760, 3840): 128, (1440, 3840): 512, (5760, 960): 128,
+        (11520, 3840): 256, (11520, 960): 256, (3840, 5760): 256,
+        (960, 5760): 512, (3840, 1440): 256, (3840, 3840): 256,
+        (960, 3840): 960, (3840, 960): 256, (11008, 3840): 256,
+        (2752, 3840): 256, (11008, 960): 256, (22016, 3840): 512,
+        (5504, 3840): 128, (22016, 960): 512, (3840, 11008): 256,
+        (960, 11008): 256, (3840, 2752): 256, (100352, 3840): 512,
+        (25088, 3840): 512, (100352, 960): 1024},
+}
+
+
+@pytest.mark.parametrize("config", sorted(TILES))
+def test_tile_d_of_every_configurations_shapes_is_pinned(config):
+    got = {(d, n): _tile_d(d, n // 2) for d, n in TILES[config]}
+    assert got == TILES[config]
+
+
 def test_tile_d_refuses_a_weight_no_tile_fits():
     with pytest.raises(ValueError, match="scoped-VMEM"):
         _tile_d(4097, 20000)  # 128 x 20000 packed bytes > the budget
@@ -275,7 +336,9 @@ def test_ragged_last_block_matches_dequant_oracle(rng, monkeypatch, t,
 
     d, n = 752, 512
     # shrink the budget so the tiny test weight takes the ragged branch
-    monkeypatch.setattr(q, "_TILE_BYTES_MAX", 256 * (n // 2))
+    # (its 16 scale blocks a row are not whole lane tiles: a tile counts a
+    # quarter larger, _tile_d)
+    monkeypatch.setattr(q, "_TILE_BYTES_MAX", 320 * (n // 2))
     assert _tile_d(d, n // 2) == 256 and d % 256
     qt = _qt(rng, d, n)
     x = jnp.asarray(rng.standard_normal((t, n), dtype=np.float32))

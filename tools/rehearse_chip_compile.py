@@ -25,6 +25,7 @@ compiles (cut to 2 layers) as tier-1 tests.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import os
 import sys
 import time
@@ -38,7 +39,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
 from distributed_llama_tpu.models.spec import (ArchType, HiddenAct,  # noqa: E402
-                                               ModelSpec)
+                                               LayerKind, ModelSpec)
 from distributed_llama_tpu.quants.jax_codec import QuantizedTensor  # noqa: E402
 
 LLAMA2_7B = ModelSpec(arch=ArchType.LLAMA, dim=4096, hidden_dim=11008,
@@ -65,6 +66,21 @@ SARVAM_105B_EP8 = ModelSpec(
     n_shared_experts=1, n_routed_experts=128, routed_scaling=2.5,
     rms_eps=1e-6, rope_factor=40.0, rope_orig_len=4096,
     rope_mscale_all_dim=1.0)
+# Olmo-Hybrid-7B as benchmark/configs/olmo-hybrid-7b.json serves it: whole
+# on one chip, (DELTA x 3, ATTENTION) x 8
+OLMO_HYBRID_7B = ModelSpec(
+    arch=ArchType.OLMO_HYBRID, dim=3840, hidden_dim=11008, n_layers=32,
+    n_heads=30, n_kv_heads=30, vocab_size=100352, seq_len=8192,
+    hidden_act=HiddenAct.SILU, rope_theta=0.0, rms_eps=1e-6,
+    mixers=((int(LayerKind.DELTA),) * 3
+            + (int(LayerKind.ATTENTION),)) * 8, lin_heads=30, lin_k_head_dim=96,
+    lin_v_head_dim=192, lin_conv_width=4, lin_beta_scale=2)
+
+
+def hybrid_layers(spec: ModelSpec, periods: int) -> ModelSpec:
+    """The first `periods` periods of a hybrid's layer pattern."""
+    n = 4 * periods
+    return dataclasses.replace(spec, n_layers=n, mixers=spec.mixers[:n])
 
 
 def describe_topology():
@@ -96,7 +112,20 @@ def _loaded_params(spec: ModelSpec, dtype) -> dict:
     for l in range(spec.n_layers):
         lw = {"rms_att": jnp.ones((d,), jnp.float32),
               "rms_ffn": jnp.ones((d,), jnp.float32)}
-        if spec.is_mla:
+        if spec.layer_kinds[l] == LayerKind.DELTA:
+            nh, dk, dv = (spec.lin_heads, spec.lin_k_head_dim,
+                          spec.lin_v_head_dim)
+            lw.update(
+                wq=_zeros_q40(nh * dk, d), wk=_zeros_q40(nh * dk, d),
+                wv=_zeros_q40(nh * dv, d), wg=_zeros_q40(nh * dv, d),
+                wo=_zeros_q40(d, nh * dv),
+                w_ab=jnp.zeros((2 * nh, d), dtype),
+                conv_w=jnp.zeros((spec.lin_conv_width, spec.lin_conv_dim),
+                                 jnp.float32),
+                a_log=jnp.zeros((nh,), jnp.float32),
+                dt_bias=jnp.zeros((nh,), jnp.float32),
+                rms_o=jnp.ones((dv,), jnp.float32))
+        elif spec.is_mla:
             nh, r = spec.n_heads, spec.kv_lora_rank
             lw.update(
                 rms_kv=jnp.ones((r,), jnp.float32),
@@ -108,6 +137,9 @@ def _loaded_params(spec: ModelSpec, dtype) -> dict:
         else:
             lw.update(wq=_zeros_q40(d, d), wk=_zeros_q40(kv, d),
                       wv=_zeros_q40(kv, d), wo=_zeros_q40(d, d))
+            if spec.post_norm:
+                lw.update(rms_q=jnp.ones((d,), jnp.float32),
+                          rms_k=jnp.ones((kv,), jnp.float32))
         if spec.is_mla and spec.is_dense_layer(l):
             hd = spec.dense_hidden_dim
             lw.update(w1=_zeros_q40(hd, d), w2=_zeros_q40(d, hd),
