@@ -4,7 +4,8 @@ JSON object. Each child is a fresh process, because whoever imports JAX on a
 machine with a chip holds that chip until it exits.
 
   synth   numpy only (JAX_PLATFORMS=cpu): the configuration's `.m`/`.t`
-          files, by the program's own write_synthetic_model
+          files, by the benchmark's own draw (weights.py) and the
+          configuration's `weights_recipe`
   probe   holds the chip: which devices JAX sees
   check   holds the chip: the served step programs against the plain
           float32 reference
@@ -30,13 +31,15 @@ def spec_of(config: dict):
 def synth(p: dict) -> dict:
     from distributed_llama_tpu.io.tokenizer_file import (
         TokenizerData, write_tokenizer_file)
-    from distributed_llama_tpu.testing import (byte_fallback_vocab,
-                                               write_synthetic_model)
+    from distributed_llama_tpu.testing import byte_fallback_vocab
+
+    import weights
 
     spec = spec_of(p["config"])
     t0 = time.time()
-    size = write_synthetic_model(p["model"] + ".part", spec,
-                                 p["config"]["weights_seed"])
+    size = weights.write_model(p["model"] + ".part", spec,
+                               p["config"]["weights_seed"],
+                               p["config"].get("weights_recipe"))
     write_tokenizer_file(p["tokenizer"], TokenizerData(
         vocab=byte_fallback_vocab(spec.vocab_size),
         scores=[0.0] * spec.vocab_size, bos_id=1, eos_id=2))
@@ -125,11 +128,23 @@ def check(p: dict) -> dict:
                      "finite": bool(np.isfinite(lg).all()),
                      "rel_l2": rel_l2(lg, want[at]),
                      "argmax_agree": bool(lg.argmax() == want[at].argmax())})
-    worst = max(r["rel_l2"] for r in rows)
-    return {"rows": rows, "worst_rel_l2": worst,
+    # what is held to a limit: the worst row against `logit_tolerance`, or,
+    # for a configuration whose `check` says `"judge": "median"`, the median
+    # row against it and the worst against `check.worst_tolerance` (a router
+    # near-tie can send ONE row to another expert than the reference's;
+    # precision and a wrong step move every row)
+    values = {"worst": max(r["rel_l2"] for r in rows),
+              "median": float(np.median([r["rel_l2"] for r in rows]))}
+    chk = cfg.get("check", {})
+    limits = {"worst": cfg["logit_tolerance"]}
+    if chk.get("judge") == "median":
+        limits = {"median": cfg["logit_tolerance"],
+                  "worst": chk["worst_tolerance"]}
+    return {"rows": rows, "worst_rel_l2": values["worst"],
+            "median_rel_l2": values["median"], "limits": limits,
             "tolerance": cfg["logit_tolerance"],
             "ok": bool(all(r["finite"] for r in rows)
-                       and worst <= cfg["logit_tolerance"]),
+                       and all(values[k] <= v for k, v in limits.items())),
             "reference_seconds": round(time.time() - t0, 1),
             "device": device}
 

@@ -11,6 +11,13 @@ softmax). The router weights are Q40 in the `.m` format like every other
 matrix, and are decoded like them. Every expert is applied to every token
 and masked by its weight — plain, not fast; one expert's weights resident
 at a time.
+
+`forward(..., routing=[])` also appends, a layer, what the router decided:
+`top_i` (T, k), the chosen experts of every token, and `margin` (T,), the
+gap between the k-th and the (k+1)-th router logit (a token whose margin is
+within rounding of zero can be sent elsewhere by a bf16 program and still
+be served right). What the yardstick's tests and the controls count routing
+with; the logits do not depend on it.
 """
 
 from __future__ import annotations
@@ -24,7 +31,8 @@ from .blocks import (ModelFile, attention_block, head, highest, rms_norm,
 
 
 @highest
-def forward(model_path: str, tokens: np.ndarray) -> np.ndarray:
+def forward(model_path: str, tokens: np.ndarray,
+            routing: list | None = None) -> np.ndarray:
     """Logits (T, vocab) of every position of one sequence, float32."""
     mf = ModelFile(model_path)
     n_exp, k = mf.h["n_experts"], mf.h["n_active_experts"]
@@ -33,8 +41,13 @@ def forward(model_path: str, tokens: np.ndarray) -> np.ndarray:
         p = f"layers.{l}."
         x = attention_block(mf, l, x, rope_half_split)
         m = rms_norm(x, mf.tensor(p + "rms_ffn"))
-        probs = jax.nn.softmax(m @ mf.tensor(p + "moe_router").T, -1)
+        scores = m @ mf.tensor(p + "moe_router").T
+        probs = jax.nn.softmax(scores, -1)
         top_p, top_i = jax.lax.top_k(probs, k)
+        if routing is not None:
+            best = jax.lax.top_k(scores, k + 1)[0]
+            routing.append({"top_i": np.asarray(top_i),
+                            "margin": np.asarray(best[:, k - 1] - best[:, k])})
         top_p = top_p / top_p.sum(-1, keepdims=True)
         out = jnp.zeros_like(x)
         for e in range(n_exp):

@@ -115,7 +115,8 @@ def package_hash(plan: Plan) -> str:
     (this configuration's own, wherever it is kept) and of the check's
     lengths: a changed program, mapping, count or length computes its
     verdict anew."""
-    h = hashlib.sha256(json.dumps(plan.check_lengths).encode())
+    h = hashlib.sha256(json.dumps(
+        [plan.check_lengths, plan.config.get("check")]).encode())
     for root in (os.path.join(REPO, "distributed_llama_tpu"),
                  os.path.join(HERE, "reference"),
                  os.path.join(HERE, "shapes")):
@@ -127,7 +128,8 @@ def package_hash(plan: Plan) -> str:
                     h.update(os.path.relpath(path, REPO).encode())
                     with open(path, "rb") as f:
                         h.update(f.read())
-    for rel in ("children.py", "workmodel.py", plan.config.get("shape")):
+    for rel in ("children.py", "workmodel.py", "weights.py",
+                plan.config.get("shape")):
         if rel:
             with open(os.path.join(HERE, rel), "rb") as f:
                 h.update(f.read())
@@ -181,9 +183,8 @@ def prepare(plan: Plan) -> tuple[str, str, dict]:
     for r in verdict["rows"]:
         say(f"logits vs {cfg['reference']}: {r['step']} at {r['position']}: "
             f"rel_l2 {r['rel_l2']:.5f} argmax_agree {r['argmax_agree']}")
-    say(f"logits verdict: worst rel_l2 {verdict['worst_rel_l2']:.5f} against "
-        f"tolerance {verdict['tolerance']} -> "
-        f"{'ok' if verdict['ok'] else 'NOT ok'}")
+    say(f"logits verdict: worst rel_l2 {verdict['worst_rel_l2']:.5f}, limits "
+        f"{verdict['limits']} -> {'ok' if verdict['ok'] else 'NOT ok'}")
     return model, tok, verdict
 
 
@@ -384,10 +385,11 @@ def run(plan: Plan) -> dict:
 
     ill_formed = [r for r in recs if (r["error"] or "").startswith("ill-")]
     # every number `correct` rests on, beside its limit ("least": at least)
-    worst = verdict["worst_rel_l2"]
     compared = {
-        "logits_worst_rel_l2": {"value": worst if math.isfinite(worst)
-                                else None, "limit": verdict["tolerance"]},
+        **{f"logits_{k}_rel_l2": {
+            "value": verdict[f"{k}_rel_l2"]
+            if math.isfinite(verdict[f"{k}_rel_l2"]) else None, "limit": limit}
+           for k, limit in verdict["limits"].items()},
         "logits_rows_not_finite": {
             "value": sum(not r["finite"] for r in verdict["rows"]),
             "limit": 0},
@@ -433,8 +435,7 @@ def run(plan: Plan) -> dict:
            "peaks": (workmodel.load_peaks(device["kind"])
                      if plan.want_platform == "tpu" else None),
            "client": {"window_ok": ok,
-                      "all_ok": [r for r in recs if r["ok"]],
-                      "prompt_tokens": sum(r["prompt_tokens"] for r in ok)}}
+                      "all_ok": [r for r in recs if r["ok"]]}}
     result["metrics"] = layer_metrics(plan, ctx)
     if reduced.get("window_s"):
         device["busy_s"] = reduced["busy_s"]

@@ -76,22 +76,44 @@ def shapes(config: dict) -> dict:
         "cache_width": r + d_r, "latent": r, "heads": h}
 
 
-def matmul_work(config: dict, tokens: float, logit_rows: float = 1.0) -> dict:
+def moe(config: dict) -> dict:
+    """Layers with experts, and what every routing touches of the experts
+    HELD here: nothing, when a share is held (a token's top-k may all live
+    on other chips); top_k a token when all are."""
+    s = shapes(config)
+    whole = s["held"] == s["routed"]
+    return {"layers": s["layers"] - s["dense_layers"],
+            "floor": lambda tokens: {
+                "experts": float(min(s["top_k"], s["held"]))
+                if whole and tokens > 0 else 0.0,
+                "pairs": float(s["top_k"] * tokens) if whole else 0.0}}
+
+
+def matmul_work(config: dict, tokens: float, logit_rows: float = 1.0,
+                experts: float | None = None,
+                pairs: float | None = None) -> dict:
     """FLOPs and weight bytes one forward over `tokens` real tokens needs
-    for its Q40 matmuls; every weight that some token uses read once."""
+    for its Q40 matmuls; every weight that some token uses read once.
+    `experts`: distinct HELD experts a MoE layer read, `pairs`: (token, held
+    expert) pairs a MoE layer computed, as the step's tokens were routed (a
+    reader gives both); left out, the expectation under even routing, which
+    no reader charges a step by (workmodel.experts_touched says why)."""
     s = shapes(config)
     moe_layers = s["layers"] - s["dense_layers"]
     p_held = s["top_k"] / s["routed"]         # a token picks a given expert
-    held_per_token = s["held"] * p_held
-    held_touched = s["held"] * (1.0 - (1.0 - p_held) ** max(tokens, 0.0))
-    per_token = (s["layers"] * s["attention"]
-                 + s["dense_layers"] * s["dense_ffn"]
-                 + moe_layers * s["expert"] * (s["shared"] + held_per_token))
+    if pairs is None:
+        pairs = tokens * s["held"] * p_held
+    if experts is None:
+        experts = s["held"] * (1.0 - (1.0 - p_held) ** max(tokens, 0.0))
+    flop_vals = (tokens * (s["layers"] * s["attention"]
+                           + s["dense_layers"] * s["dense_ffn"]
+                           + moe_layers * s["expert"] * s["shared"])
+                 + moe_layers * s["expert"] * pairs)
     read = (s["layers"] * s["attention"]
             + s["dense_layers"] * s["dense_ffn"]
-            + moe_layers * s["expert"] * (s["shared"] + held_touched)
+            + moe_layers * s["expert"] * (s["shared"] + experts)
             + s["vocab"] * s["d"])
-    return {"flops": 2.0 * tokens * per_token
+    return {"flops": 2.0 * flop_vals
             + 2.0 * logit_rows * s["vocab"] * s["d"],
             "bytes": read * Q40_BYTES_PER_VALUE}
 

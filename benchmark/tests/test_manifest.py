@@ -51,10 +51,15 @@ def check_config(entry, cfg):
             assert re.match(r"^[A-Za-z0-9_.\-/]+\.py$", cfg[k]), cfg[k]
             assert ".." not in cfg[k] and not cfg[k].startswith("/")
     if "check" in cfg:
-        assert set(cfg["check"]) <= {"prompt_tokens", "decode_steps"}
-        assert all(isinstance(v, int) and v > 0 for v in cfg["check"].values())
+        lengths = {k: v for k, v in cfg["check"].items()
+                   if k in ("prompt_tokens", "decode_steps")}
+        assert set(cfg["check"]) - set(lengths) <= {"judge", "worst_tolerance"}
+        assert all(isinstance(v, int) and v > 0 for v in lengths.values())
         if "max_position_embeddings" in cfg:
-            assert sum(cfg["check"].values()) <= cfg["max_position_embeddings"]
+            assert sum(lengths.values()) <= cfg["max_position_embeddings"]
+        if "judge" in cfg["check"]:   # the median row, and a limit for the worst
+            assert cfg["check"]["judge"] == "median"
+            assert cfg["check"]["worst_tolerance"] > cfg["logit_tolerance"] > 0
 
 
 def test_keys_names_and_units():

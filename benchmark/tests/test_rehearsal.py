@@ -111,18 +111,17 @@ def test_a_configuration_brings_its_own_shape_and_check_lengths():
 
     # hand-made executions: 2 ms of kernel time in each decode program;
     # shape_tiny's 819e6 bytes are 1 ms at the table's 819 GB/s
-    snap = {"steps": 0, "tokens_out": 0}
     ctx = {"config": SHAPED, "peaks": {"bf16_flops_per_s": 197e12,
                                        "hbm_bytes_per_s": 819e9},
            "trace": {"executions": [
                {"module": "slot_decode_step",
                 "kernel_s": {"q40_matmul": 0.0015, "other": 0.0005}}] * 3},
-           "stats": {"window_start": snap,
-                     "window_end": {"steps": 10, "tokens_out": 30}},
-           "client": {"prompt_tokens": 0}}
+           "stats": {"trace_end": {"capture": {
+               "start": {"decode_steps": 0, "decode_rows": 0},
+               "stop": {"decode_steps": 10, "decode_rows": 30}}}}}
     got = trace_roofline.read(ctx, "decode", ["q40_matmul", "other"])
     assert got["value"] == pytest.approx(50.0)
-    assert "3.0 real tokens" in got["note"] and "memory-bound" in got["note"]
+    assert "3.00 real tokens" in got["note"] and "memory-bound" in got["note"]
 
 
 def test_the_command_fails_without_a_tpu(tmp_path):

@@ -46,8 +46,9 @@ def for_config(config: dict):
     """The module that knows this configuration's shape: the file its
     `shape` key names (a path inside benchmark/, kept with the benchmark and
     never imported by the program), which states `spec(config)`,
-    `matmul_work(config, tokens, logit_rows)` and `sizing(config)`; this
-    module, the default shape, when there is no such key."""
+    `matmul_work(config, tokens, logit_rows)` and `sizing(config)` (and,
+    with experts, `moe(config)` and `matmul_work`'s `experts=`, `pairs=`);
+    this module, the default shape, when there is no such key."""
     rel = config.get("shape")
     if rel is None:
         return sys.modules[__name__]
@@ -110,27 +111,61 @@ def expert_values(s: dict) -> int:
 
 
 def experts_touched(s: dict, tokens: float) -> float:
-    """Expected number of distinct experts `tokens` tokens route to, each
-    choosing top_k of `experts` uniformly (random weights route evenly)."""
+    """EXPECTED number of distinct experts `tokens` tokens route to, if each
+    chose top_k of `experts` uniformly. What even routing would touch: for
+    sizing arithmetic and for holding a draw's observed routing against
+    (PERF.md section 6, PR 37). No reader charges a step by it: uniform-byte
+    weights sent every token to one pair, and the expectation charged a
+    program for experts nobody chose (133.9 %; ledger, PR 35)."""
     e, k = s["experts"], s["top_k"]
     return e * (1.0 - (1.0 - k / e) ** max(tokens, 0.0))
 
 
-def matmul_work(config: dict, tokens: float, logit_rows: float = 1.0) -> dict:
+def moe(config: dict) -> dict | None:
+    """What a reader needs to know of a configuration's experts: how many
+    layers have them, and how many experts and (token, expert) pairs a
+    layer EVERY routing of `tokens` tokens touches (`floor`). None: no
+    experts. Here all the router's experts are held, so a token's top_k
+    pairs are arithmetic and one token already touches top_k experts."""
+    s = shapes(config)
+    if not s["experts"]:
+        return None
+    return {"layers": s["layers"],
+            "floor": lambda tokens: {
+                "experts": float(min(s["top_k"], s["experts"]))
+                if tokens > 0 else 0.0,
+                "pairs": float(s["top_k"] * tokens)}}
+
+
+def matmul_work(config: dict, tokens: float, logit_rows: float = 1.0,
+                experts: float | None = None,
+                pairs: float | None = None) -> dict:
     """FLOPs and weight bytes one forward over `tokens` real tokens (summed
     over the rows of the batch) needs for its Q40 matmuls: every token
     through the attention projections, the router and its top-k experts (or
     the dense FFN), `logit_rows` positions through the head; every weight
-    that some token uses read once."""
+    that some token uses read once. With experts: `experts` distinct experts
+    a layer read and `pairs` (token, expert) pairs a layer computed, as the
+    step's own tokens were routed (a reader gives both, observed or `moe`'s
+    floor); left out, the expectation under even routing."""
     s = shapes(config)
-    moe = s["experts"] > 0
-    ffn_vals_per_token = expert_values(s) * (s["top_k"] if moe else 1)
-    ffn_vals_read = expert_values(s) * (experts_touched(s, tokens) if moe else 1)
-    router = s["experts"] * s["d"] if moe else 0
+    has_experts = s["experts"] > 0
+    if has_experts:
+        if experts is None:
+            experts = experts_touched(s, tokens)
+        if pairs is None:
+            pairs = s["top_k"] * tokens
+        ffn_flop_vals = expert_values(s) * pairs
+        ffn_vals_read = expert_values(s) * experts
+    else:
+        ffn_flop_vals = expert_values(s) * tokens
+        ffn_vals_read = expert_values(s)
+    router = s["experts"] * s["d"] if has_experts else 0
     head = s["vocab"] * s["d"]
-    per_token = s["layers"] * (attention_values(s) + ffn_vals_per_token + router)
+    flop_vals = s["layers"] * (tokens * (attention_values(s) + router)
+                               + ffn_flop_vals)
     read = s["layers"] * (attention_values(s) + ffn_vals_read + router) + head
-    return {"flops": 2.0 * tokens * per_token + 2.0 * logit_rows * head,
+    return {"flops": 2.0 * flop_vals + 2.0 * logit_rows * head,
             "bytes": read * Q40_BYTES_PER_VALUE}
 
 
