@@ -40,7 +40,8 @@ from jax import lax
 
 from ..ops.activations import apply_hidden_act
 from ..ops.attention import decode_attention
-from ..ops.matmul import matmul
+from ..ops.matmul import (fused_expert_matmul, matmul,
+                          reads_experts_in_place)
 from ..ops.norms import rmsnorm
 from ..ops.rope import apply_rope
 from ..quants.jax_codec import QuantizedTensor
@@ -504,14 +505,25 @@ def _dense_ffn(xb, lw, spec: ModelSpec, cfg):
     return matmul(hb, lw["w2"], **cfg)
 
 
-def _moe_ffn(xb, lw, spec: ModelSpec, cfg):
+def _moe_ffn(xb, lw, spec: ModelSpec, cfg, n_valid=None, counts=None):
     """Top-k routed expert FFN (ref: src/grok1-tasks.cpp:56-227).
 
     Router/top-k runs replicated (the reference runs it root-only and
-    broadcasts — ref: grok1-tasks.cpp:121-126). One row (B == T == 1)
-    computes only its active experts; every other shape computes all held
-    experts for every row and masks — both compile to static shapes, and
-    both read an expert's weights where they lie (_expert_matmul).
+    broadcasts — ref: grok1-tasks.cpp:121-126). A (token, chosen expert)
+    pair is LIVE when the token is real (token j of row b iff j <
+    n_valid[b], SegmentRows; None: every token) and the expert is held
+    here. Where the expert stacks are the in-place kernel's
+    (ops/matmul.reads_experts_in_place: the served step programs), the
+    live pairs alone are computed, grouped by expert, one kernel call a
+    projection (_grouped_experts); a pad or gated token's routed output is
+    then zero, and nothing reads it. Anything else slices an expert at a
+    time (_expert_matmul): one row (B == T == 1) computes its active
+    experts, every other shape all held experts for every row, masked.
+    All compile to static shapes and give a live token the same bits.
+
+    counts: a list that receives this layer's (expert_reads, expert_pairs)
+    — distinct held experts with a live pair, and live pairs — as int32
+    scalars (forward sums them for the step programs' counters).
     """
     b, t, d = xb.shape
     k_active = spec.n_active_experts
@@ -535,6 +547,19 @@ def _moe_ffn(xb, lw, spec: ModelSpec, cfg):
             probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
             top_p, top_idx = lax.top_k(probs, k_active)           # (B, T, K)
             weights = top_p / top_p.sum(axis=-1, keepdims=True)   # ref: grok1-tasks.cpp:99-114
+
+    in_place = reads_experts_in_place(lw["moe_up"], b * t, **cfg)
+    if in_place or counts is not None:
+        held = top_idx - (spec.expert_offset if held_share else 0)
+        live = (held >= 0) & (held < spec.n_experts)              # (B, T, K)
+        if n_valid is not None:
+            live &= (jnp.arange(t)[None, :] < n_valid[:, None])[..., None]
+        # (B T K, E): pair p is live and its expert is held expert e
+        member = ((held[..., None] == jnp.arange(spec.n_experts))
+                  & live[..., None]).reshape(-1, spec.n_experts)
+        sizes = member.sum(axis=0, dtype=jnp.int32)               # (E,)
+        if counts is not None:
+            counts.append((jnp.sum(sizes > 0, dtype=jnp.int32), sizes.sum()))
 
     def scatter_weights():
         # (B, T, E) dense scatter of the normalized top-k weights (0 for
@@ -595,6 +620,11 @@ def _moe_ffn(xb, lw, spec: ModelSpec, cfg):
             return acc + _dense_ffn(xb, {"w1": lw["sh_w1"], "w2": lw["sh_w2"],
                                          "w3": lw["sh_w3"]}, spec, cfg)
 
+    if in_place:
+        with jax.named_scope("moe_routed"):
+            return with_shared(_grouped_experts(
+                xb, lw, spec, cfg, held, live, member, sizes, weights))
+
     acc = jnp.zeros((b, t, d), xb.dtype)
     if t == 1 and b == 1 and not held_share:
         # one row: only its K active experts, by their traced indices (the
@@ -606,8 +636,8 @@ def _moe_ffn(xb, lw, spec: ModelSpec, cfg):
             acc = acc + weights[..., ae, None].astype(out.dtype) * out
         return with_shared(acc)
 
-    # every other shape, the served step programs' 8 and 256 rows among
-    # them: every held expert for every row, masked by the routing weights
+    # every other shape: every held expert for every row, masked by the
+    # routing weights
     e_weights = scatter_weights()
     with jax.named_scope("moe_routed"):
         for e in range(spec.n_experts):
@@ -616,21 +646,133 @@ def _moe_ffn(xb, lw, spec: ModelSpec, cfg):
     return with_shared(acc)
 
 
-def _expert_matmul(x, w, e, cfg):
-    """x @ W[e]^T against a stacked (E, d, n) leaf, `e` traced or a Python
-    integer. A plain single-shard Q40 stack of at most pallas_q40.MAX_T rows
-    is read IN PLACE by the expert-indexed kernel (ops/matmul.
-    fused_expert_matmul says which); anything else is sliced first."""
-    from ..ops.matmul import fused_expert_matmul
+def _grouped_experts(xb, lw, spec: ModelSpec, cfg, held, live, member, sizes,
+                     weights):
+    """The routed experts' weighted sum over the step's LIVE (token,
+    expert) pairs alone, one ops/pallas_q40.q40_expert_matmul call a
+    projection. held, live (B, T, K): each pair's expert among those held
+    here and whether it counts; member (B T K, E) one-hot of the live
+    pairs; sizes (E,) their count an expert.
 
-    out = fused_expert_matmul(x, w, e, **cfg)
-    return matmul(x, _take_expert(w, e), **cfg) if out is None else out
+    The pairs are laid out by expert in row tiles (_pair_tiles;
+    expert_row_tile sizes the tile): an expert's group starts on a tile and
+    keeps its tokens' order, so a tile belongs to one expert and the used
+    tiles come first, in ascending expert order. rows x K / tile + E tiles
+    hold ANY routing (an expert adds at most one ragged tile); the buffer
+    the projections run over holds the tiles an EVEN routing needs
+    (`wave`), and a step whose routing needs more runs again over the next
+    tiles: one wave where every expert is held (Mixtral, Grok-1: the two
+    numbers are one), seldom a second where a share of a wide router is (a
+    wave is then an eighth of what any routing needs, and everything
+    outside the kernel costs by the buffer's rows).
+
+    A pair's three projections and its Q80 round trips are a row's own, so
+    a live token's contributions carry the bits the all-experts loop gives
+    them, and they are summed as it sums them: in ascending expert order
+    (so wave after wave), in the activations' dtype; its other experts add
+    exact zeros there and nothing here."""
+    from ..quants.jax_codec import dequantize_q80_jax, quantize_q80_jax
+
+    b, t, d = xb.shape
+    rows, k = b * t, spec.n_active_experts
+    tile, n_tiles, wave = _pair_layout(spec, rows)
+    dest, src, tile_expert, used = _pair_tiles(held, live, member, sizes,
+                                               tile, n_tiles)
+
+    x = xb.reshape(rows, d)
+    if cfg["activation_q80"]:  # once a token row, not once a pair
+        x = dequantize_q80_jax(*quantize_q80_jax(x),
+                               dtype=cfg["compute_dtype"])
+    # a token's K pairs in ascending expert order
+    order = jnp.argsort(held, axis=-1)                            # (B, T, K)
+    dest, live, weights = (jnp.take_along_axis(a, order, axis=-1)
+                           for a in (dest, live, weights))
+
+    def run_wave(w, acc):
+        first = w * wave
+        grouped = dict(cfg, used=jnp.clip(used - first, 0, wave),
+                       token_rows=rows)
+        experts = lax.dynamic_slice(tile_expert, (first,), (wave,))
+        x_w = x[lax.dynamic_slice(src, (first * tile,), (wave * tile,))]
+        once = dict(grouped, activation_q80=False)
+        gate = fused_expert_matmul(x_w, lw["moe_gate"], experts, **once)
+        up = fused_expert_matmul(x_w, lw["moe_up"], experts, **once)
+        hb = apply_hidden_act(gate, spec.hidden_act) * up
+        out = fused_expert_matmul(hb, lw["moe_down"], experts, **grouped)
+        # a pair outside this wave, or dead, adds nothing: its row may
+        # never have been written, so it is selected out, not zeroed
+        at = dest - first * tile
+        here = live & (at >= 0) & (at < wave * tile)
+        at = jnp.clip(at, 0, wave * tile - 1)
+        for j in range(k):  # K is tiny and static — unrolled
+            o = jnp.where(here[..., j, None], out[at[..., j]], 0)  # (B, T, d)
+            acc = acc + weights[..., j, None].astype(o.dtype) * o
+        return acc
+
+    acc = jnp.zeros((b, t, d), xb.dtype)
+    if wave == n_tiles:
+        return run_wave(0, acc)
+    return lax.fori_loop(0, -(-used // wave), run_wave, acc)
+
+
+def _pair_layout(spec: ModelSpec, rows: int) -> tuple[int, int, int]:
+    """(tile, n_tiles, wave) of _grouped_experts for a program of `rows`
+    token rows, from the program's shape alone: the rows of a row tile
+    (ops/pallas_q40.expert_row_tile of an even routing's group), the tiles
+    that hold ANY routing of rows x K pairs over the held experts (a whole
+    number of waves), and the tiles one pass of the three projections runs
+    over: what an EVEN routing needs plus a ragged tile an expert."""
+    from ..ops.pallas_q40 import expert_row_tile
+
+    k, n_e, width = spec.n_active_experts, spec.n_experts, spec.router_width
+    tile = expert_row_tile(rows * k / width)
+    n_tiles = min(rows * k // tile + n_e, n_e * -(-rows // tile))
+    wave = min(n_tiles, n_e + -(-rows * k * n_e // (width * tile)))
+    return tile, -(-n_tiles // wave) * wave, wave
+
+
+def _pair_tiles(held, live, member, sizes, tile: int, n_tiles: int):
+    """Where _grouped_experts puts each (token, expert) pair, from the
+    pairs' held expert and liveness (B, T, K), their one-hot (B T K, E) and
+    the groups' sizes (E,). Returns dest (B, T, K): the pair's row in the
+    buffer of n_tiles x tile rows — its expert's first tile, then its rank
+    among the expert's live pairs in token order — and n_tiles x tile, past
+    the end, for a dead pair; src (n_tiles x tile,): the token whose
+    activations a buffer row holds (token 0 where no pair lands: computed
+    if its tile is used, never read); tile_expert (n_tiles,): each tile's
+    expert, in ascending order over the used tiles, which come first;
+    used: how many they are. A tile is named for an expert only if a live
+    pair of that expert lies in it."""
+    n_e = sizes.shape[0]
+    e_of = jnp.clip(held, 0, n_e - 1).reshape(-1)                 # (P,)
+    member = member.astype(jnp.int32)
+    rank = jnp.take_along_axis(jnp.cumsum(member, axis=0) - member,
+                               e_of[:, None], axis=1)[:, 0]
+    tiles_of = -(-sizes // tile)
+    tile_end = jnp.cumsum(tiles_of)
+    dest = jnp.where(live.reshape(-1),
+                     ((tile_end - tiles_of) * tile)[e_of] + rank,
+                     n_tiles * tile)
+    token = jnp.arange(dest.shape[0], dtype=jnp.int32) // held.shape[-1]
+    src = jnp.zeros((n_tiles * tile,), jnp.int32).at[dest].set(
+        token, mode="drop")
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(tile_end, jnp.arange(n_tiles), side="right"),
+        n_e - 1).astype(jnp.int32)
+    return dest.reshape(held.shape), src, tile_expert, tile_end[-1]
+
+
+def _expert_matmul(x, w, e, cfg):
+    """x @ W[e]^T against a stacked (E, d, n) leaf the in-place kernel
+    cannot take, `e` traced or a Python integer: the expert is sliced out
+    (_take_expert) for the leaf's own matmul."""
+    return matmul(x, _take_expert(w, e), **cfg)
 
 
 def _take_expert(w, e):
     """Select expert e from a stacked (E, ...) weight: an expert-sized copy
     in HBM before the matmul may read it, so only what the in-place kernel
-    cannot take comes here (_expert_matmul) — tp wrappers (for TpColWeight
+    cannot take comes here (_moe_ffn) — tp wrappers (for TpColWeight
     the expert axis sits behind the tp stack axis), unquantised stacks,
     the XLA dequant path and segments of more than pallas_q40.MAX_T rows."""
     from ..parallel.tp_q80 import TpColWeight, TpRowWeight, take_expert_col
@@ -648,7 +790,8 @@ def _take_expert(w, e):
 
 
 def _layer(x, lw, spec: ModelSpec, k_cache, v_cache, q_pos, cfg, sp_mesh=None,
-           sp_cache_mesh=None, per_row_pos=False, write_gate=None):
+           sp_cache_mesh=None, per_row_pos=False, write_gate=None,
+           n_valid=None, moe_counts=None):
     if spec.is_mla:
         # pre-norm serial block over latent attention; the FFN is dense in
         # the leading layers (w1 or fused w13 present) and experts after
@@ -658,8 +801,9 @@ def _layer(x, lw, spec: ModelSpec, k_cache, v_cache, q_pos, cfg, sp_mesh=None,
             write_gate=write_gate)
         x = x + attn_out.astype(x.dtype)
         xb = rmsnorm(x, lw["rms_ffn"], spec.norm_eps)
-        ffn = _moe_ffn if "moe_router" in lw else _dense_ffn
-        return x + ffn(xb, lw, spec, cfg).astype(x.dtype), k_cache, None
+        ffn = (_moe_ffn(xb, lw, spec, cfg, n_valid, moe_counts)
+               if "moe_router" in lw else _dense_ffn(xb, lw, spec, cfg))
+        return x + ffn.astype(x.dtype), k_cache, None
     attn_out, k_cache, v_cache = _attention_block(
         x, lw, spec, k_cache, v_cache, q_pos, cfg, sp_mesh=sp_mesh,
         sp_cache_mesh=sp_cache_mesh, per_row_pos=per_row_pos,
@@ -672,13 +816,14 @@ def _layer(x, lw, spec: ModelSpec, k_cache, v_cache, q_pos, cfg, sp_mesh=None,
         # post-attention norm BEFORE residual add (ref: grok1-tasks.cpp:16-41)
         x = x + rmsnorm(attn_out, lw["rms_ffn"]).astype(x.dtype)
         xb = rmsnorm(x, lw["rms_moe"])          # ref: grok1-tasks.cpp:43-54
-        moe_out = _moe_ffn(xb, lw, spec, cfg)
+        moe_out = _moe_ffn(xb, lw, spec, cfg, n_valid, moe_counts)
         moe_out = rmsnorm(moe_out, lw["rms_ffn2"])  # ref: grok1-tasks.cpp:244-256
         x = x + moe_out.astype(x.dtype)
     elif spec.arch == ArchType.MIXTRAL:
         x = x + attn_out.astype(x.dtype)        # ref: mixtral-tasks.cpp:24
         xb = rmsnorm(x, lw["rms_ffn"])
-        x = x + _moe_ffn(xb, lw, spec, cfg).astype(x.dtype)
+        x = x + _moe_ffn(xb, lw, spec, cfg, n_valid,
+                         moe_counts).astype(x.dtype)
     else:
         x = x + attn_out.astype(x.dtype)        # ref: llama2-tasks.cpp:125-131
         xb = rmsnorm(x, lw["rms_ffn"])
@@ -709,6 +854,7 @@ def forward(
     logit_index=None,
     vocab_mesh=None,
     vocab_axes: tuple = ("tp",),
+    expert_counts: bool = False,
 ) -> tuple[jnp.ndarray, KVCache]:
     """Run T tokens through the model; returns (logits, updated cache).
 
@@ -731,6 +877,9 @@ def forward(
     gather + all-reduce, bit-identical to the replicated gather (zeros +
     one real contribution add exactly). The head (wcls) is row-split by
     its PartitionSpec independently of this knob.
+    expert_counts: also return, third, int32 (2,): the distinct held experts
+    some real token chose and the live (token, expert) pairs, summed over
+    the MoE layers (_moe_ffn; the served step programs' window counters).
     """
     cfg = dict(activation_q80=activation_q80, compute_dtype=compute_dtype,
                use_pallas=use_pallas, tp_mesh=tp_mesh, tp_reduce=tp_reduce,
@@ -749,6 +898,7 @@ def forward(
 
     s_all: list = []
     conv_all: list = []
+    moe_counts: list | None = [] if expert_counts else None
     per_row_pos = getattr(pos0, "ndim", 0) == 1
     if per_row_pos:
         q_pos = pos0[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
@@ -782,7 +932,8 @@ def forward(
         v_all = []
         kinds, at = spec.layer_kinds, spec.cache_index
         rows = (_segment_rows(spec, cache, pos0, b, t, logit_index,
-                              logits_for_all) if spec.has_state else None)
+                              logits_for_all)
+                if spec.has_state or spec.is_moe else None)
         for l in range(spec.n_layers):
             if kinds[l] == LayerKind.DELTA:
                 x, s_new, c_new = _delta_layer(
@@ -797,7 +948,10 @@ def forward(
                                      q_pos, cfg,
                                      sp_mesh=sp_mesh,
                                      sp_cache_mesh=sp_cache_mesh,
-                                     per_row_pos=per_row_pos)
+                                     per_row_pos=per_row_pos,
+                                     n_valid=(None if rows is None
+                                              else rows.n_valid),
+                                     moe_counts=moe_counts)
             k_all.append(k_new)
             if v_new is not None:
                 v_all.append(v_new)
@@ -813,8 +967,12 @@ def forward(
     logits = matmul(x, params["wcls"], **cfg).astype(jnp.float32)
     if spec.arch == ArchType.GROK1:
         logits = logits * GROK_LOGIT_SCALE  # ref: grok1-tasks.cpp:269-272
-    return logits, KVCache(tuple(k_all), tuple(v_all), tuple(s_all),
-                           tuple(conv_all))
+    cache = KVCache(tuple(k_all), tuple(v_all), tuple(s_all), tuple(conv_all))
+    if expert_counts:
+        # (a pp region's layers, traced elsewhere, are not counted)
+        return logits, cache, jnp.asarray(
+            [sum(c[i] for c in moe_counts) for i in range(2)], jnp.int32)
+    return logits, cache
 
 
 def _segment_rows(spec: ModelSpec, cache: KVCache, pos0, b: int, t: int,

@@ -14,6 +14,7 @@ rows), activations are (..., n), output is (..., d) = x @ W^T.
 
 from __future__ import annotations
 
+import math
 from typing import Union
 
 import jax.numpy as jnp
@@ -134,11 +135,30 @@ def matmul(
                         use_pallas=use_pallas, interpret=pallas_interpret)
 
 
+def reads_experts_in_place(w, rows: int, *, use_pallas: bool = False,
+                           tp_mesh=None, **_) -> bool:
+    """Whether a stacked (E, d, n) weight leaf is one the expert kernel
+    reads in place (ops/pallas_q40.q40_expert_matmul), which rests on what
+    a call can see in its operands alone: kernels on, no tp mesh, a
+    plain-QuantizedTensor single-shard Q40 stack (which includes
+    manual-region pp layers at tp == 1, where the local stack is the whole
+    weight) and at most pallas_q40.MAX_T token rows. The mesh paths' Tp/Ep
+    wrappers, dense stacks, the XLA dequant path and longer segments are
+    sliced an expert at a time (models/transformer._take_expert)."""
+    from .pallas_q40 import MAX_T
+
+    return bool(use_pallas and tp_mesh is None
+                and isinstance(w, QuantizedTensor) and w.packed.ndim == 3
+                and rows <= MAX_T)
+
+
 def fused_expert_matmul(
     x: jnp.ndarray,
     w,                      # stacked (E, d, n) weight leaf
-    e,                      # i32 expert index, traced or a Python integer
+    e,                      # i32 expert of each row tile of x, or of all x
     *,
+    used=None,              # i32 leading row tiles that hold a live row
+    token_rows: int | None = None,
     activation_q80: bool = False,
     compute_dtype=jnp.float32,
     use_pallas: bool = False,
@@ -150,31 +170,26 @@ def fused_expert_matmul(
     manual_sp: int = 0,  # ignored — see matmul()
 ):
     """Expert-indexed matmul against a stacked (E, d, n) Q40 weight without
-    materializing the expert's slice (ops/pallas_q40.q40_expert_matmul):
-    every expert matmul of models/transformer._moe_ffn comes here first
-    (_expert_matmul), at one row and at the served programs' 8 and 256. `e`
-    is a traced i32 or a Python integer.
+    materializing any expert's slice (ops/pallas_q40.q40_expert_matmul,
+    which says what `e`, `used` and `token_rows` are): the grouped path of
+    models/transformer._moe_ffn comes here once a projection with the
+    step's pair rows laid out in row tiles; a scalar `e` is one expert for
+    every row of x.
 
-    Returns None when ineligible, and the choice rests on what the call can
-    see in its operands alone: a plain-QuantizedTensor single-shard Q40
-    stack (which includes manual-region pp layers at tp == 1, where the
-    local stack is the whole weight) and at most pallas_q40.MAX_T rows read
-    in place; the mesh paths' Tp/Ep wrappers, dense stacks, the XLA dequant
-    path and longer segments make the caller slice, then matmul()."""
+    Returns None when reads_experts_in_place says the stack is not the
+    kernel's (`token_rows`, else x's own rows, against MAX_T)."""
     del tp_reduce, manual_tp
-    if not (use_pallas and tp_mesh is None
-            and isinstance(w, QuantizedTensor) and w.packed.ndim == 3):
+    rows = math.prod(x.shape[:-1]) if token_rows is None else token_rows
+    in_place = reads_experts_in_place(w, rows, use_pallas=use_pallas,
+                                      tp_mesh=tp_mesh)
+    if not in_place:
         return None
-    from .pallas_q40 import MAX_T, q40_expert_matmul
+    from .pallas_q40 import q40_expert_matmul
 
-    t = 1
-    for s in x.shape[:-1]:
-        t *= s
-    if t > MAX_T:
-        return None
     if activation_q80:  # same round-trip matmul() applies
         q, scales = quantize_q80_jax(x)
         x = dequantize_q80_jax(q, scales, dtype=compute_dtype)
-    return q40_expert_matmul(x.astype(compute_dtype), w, e,
+    return q40_expert_matmul(x.astype(compute_dtype), w, e, used,
                              out_dtype=compute_dtype,
-                             interpret=pallas_interpret)
+                             interpret=pallas_interpret,
+                             token_rows=token_rows)

@@ -182,15 +182,20 @@ def _kernel(x_lo_ref, x_hi_ref, xsum_ref, packed_ref, scales_ref, out_ref,
         out_dtype=out_dtype, scales_u16=scales_u16, mxu_bf16=mxu_bf16)
 
 
-def _expert_kernel(e_ref, x_lo_ref, x_hi_ref, xsum_ref, packed_ref,
-                   scales_ref, out_ref, *, nb, out_dtype, scales_u16,
-                   mxu_bf16):
-    del e_ref  # consumed by the index maps (expert selection)
-    _subtiled_write(
-        x_lo_ref[:], x_hi_ref[:], xsum_ref[:],
-        lambda sl: packed_ref[0, sl, :], lambda sl: scales_ref[0, sl, :],
-        out_ref,
-        out_dtype=out_dtype, scales_u16=scales_u16, mxu_bf16=mxu_bf16)
+def _expert_kernel(tiles_ref, used_ref, x_lo_ref, x_hi_ref, xsum_ref,
+                   packed_ref, scales_ref, out_ref, *, nb, out_dtype,
+                   scales_u16, mxu_bf16):
+    del tiles_ref  # consumed by the index maps (each row tile's expert)
+
+    # a row tile past the used ones holds no live pair: its index maps name
+    # the blocks already resident (_q40_call), and its body is skipped
+    @pl.when(pl.program_id(0) < used_ref[0])
+    def _():
+        _subtiled_write(
+            x_lo_ref[:], x_hi_ref[:], xsum_ref[:],
+            lambda sl: packed_ref[0, sl, :], lambda sl: scales_ref[0, sl, :],
+            out_ref,
+            out_dtype=out_dtype, scales_u16=scales_u16, mxu_bf16=mxu_bf16)
 
 
 # f32 unpack intermediates are the dominant VMEM consumers (~4 bytes per
@@ -273,11 +278,14 @@ def _split_activation(x: jnp.ndarray, nb: int) -> tuple[jnp.ndarray, jnp.ndarray
     return x_lo, x_hi
 
 
-def _q40_call(x, w: QuantizedTensor, e, out_dtype, interpret):
+def _q40_call(x, w: QuantizedTensor, e, out_dtype, interpret, used=None,
+              token_rows=None):
     """The pallas_call both entry points share: `w` is one (d, m) packed
-    weight (e None, the `_kernel` body) or an (E, d, m) stack whose expert
-    `e` rides in as the scalar-prefetch operand of the weight blocks' index
-    maps (`_expert_kernel`). Everything else — the activation split, the
+    weight (e None, the `_kernel` body) or an (E, d, m) stack read through
+    the scalar-prefetch operands of the blocks' index maps
+    (`_expert_kernel`): `e` names the expert of each ROW TILE of x (one
+    tile of all rows where it is a scalar), `used` how many leading tiles
+    hold a live row. Everything else — the activation split, the output
     tile, the blocks' memory space, the scoped-VMEM request — is one
     decision for both."""
     d, m = w.packed.shape[-2:]
@@ -295,35 +303,69 @@ def _q40_call(x, w: QuantizedTensor, e, out_dtype, interpret):
     scales_u16 = w.scales.dtype == jnp.uint16
     scales = w.scales if scales_u16 else w.scales.astype(jnp.float32)
     # multi-token chunks with a bf16 consumer take the bf16 MXU feed (see
-    # _dequant_dot); single-token decode and f32 consumers keep exact f32
-    mxu_bf16 = jnp.dtype(out_dtype) == jnp.bfloat16 and t >= 16
+    # _dequant_dot); single-token decode and f32 consumers keep exact f32.
+    # The PROGRAM's token rows decide, not the pair rows a grouped call
+    # lays them out in (token_rows)
+    mxu_bf16 = (jnp.dtype(out_dtype) == jnp.bfloat16
+                and (t if token_rows is None else token_rows) >= 16)
 
     # index maps take the grid index and, in the expert call, the prefetched
-    # scalars' ref; the packed weight is already stored flattened (d, m) —
+    # scalars' refs; the packed weight is already stored flattened (d, m) —
     # consumed in place, and so is the stack: block (e, i, 0) of it
     block = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
+    n_i = pl.cdiv(d, td)
     if e is None:
         kernel, name, prefetched = _kernel, "q40_matmul", ()
+        tm, grid = t, (n_i,)
         w_block, w_at = (td,), lambda i: (i, 0)
+        x_at, out_at = (lambda i, *_: (0, 0)), (lambda i, *_: (0, i))
     else:
         kernel, name = _expert_kernel, "q40_expert_matmul"
-        prefetched = (jnp.atleast_1d(e).astype(jnp.int32),)
-        w_block, w_at = (1, td), lambda i, e_ref: (e_ref[0], i, 0)
+        tiles = jnp.atleast_1d(e).astype(jnp.int32)
+        n_tiles = tiles.shape[0]
+        prefetched = (tiles, jnp.atleast_1d(
+            n_tiles if used is None else used).astype(jnp.int32))
+        tm, grid = t // n_tiles, (n_tiles, n_i)
+
+        # row tiles outermost: a tile's activations are fetched once and
+        # its expert's weight blocks stream past them. A tile past the used
+        # ones names the LAST block of the last used tile, which is what
+        # the step before it left resident, so it moves nothing (the rule
+        # ops/pallas_attention._last_attended gives a gated row)
+        def at(j, i, tiles_ref, used_ref):
+            live = j < used_ref[0]
+            j = jnp.maximum(jnp.minimum(j, used_ref[0] - 1), 0)
+            return j, jnp.where(live, i, n_i - 1), tiles_ref[j]
+
+        def w_at(j, i, *refs):
+            j, i, e_j = at(j, i, *refs)
+            return e_j, i, 0
+
+        def x_at(j, i, *refs):
+            return at(j, i, *refs)[0], 0
+
+        def out_at(j, i, *refs):
+            return at(j, i, *refs)[:2]
+
+        w_block = (1, td)
     specs = dict(
-        grid=(pl.cdiv(d, td),),
+        grid=grid,
         in_specs=[
-            block((t, m), lambda i, *_: (0, 0)),
-            block((t, m), lambda i, *_: (0, 0)),
-            block((t, nb), lambda i, *_: (0, 0)),
+            block((tm, m), x_at),
+            block((tm, m), x_at),
+            block((tm, nb), x_at),
             block((*w_block, m), w_at),
             block((*w_block, nb), w_at),
         ],
-        out_specs=block((t, td), lambda i, *_: (0, i)),
+        out_specs=block((tm, td), out_at),
     )
     if prefetched:
         specs = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetched), **specs))
 
+    # the activation panels: whole and fetched once in the dense call, one
+    # row tile double-buffered in the expert call
+    panels = 4 * tm * (2 * m + nb) * (1 if e is None else 2)
     out = pl.pallas_call(
         functools.partial(kernel, nb=nb, out_dtype=out_dtype,
                           scales_u16=scales_u16, mxu_bf16=mxu_bf16),
@@ -335,7 +377,7 @@ def _q40_call(x, w: QuantizedTensor, e, out_dtype, interpret):
             transcendentals=0,
         ),
         compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=_SCOPED_VMEM_DEFAULT + 4 * t * (2 * m + nb)),
+            vmem_limit_bytes=_SCOPED_VMEM_DEFAULT + panels),
         interpret=interpret,
         name=name,
     )(*prefetched, x_lo, x_hi, xsum, w.packed, scales)
@@ -359,22 +401,59 @@ def q40_matmul(
     return _q40_call(x, w, None, out_dtype, interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("out_dtype", "interpret"))
+# Most rows a row tile of a grouped expert call holds (expert_row_tile).
+# Measured on the chip (PERF.md section 6, PR 38): a tile is one pass over
+# its expert's weights and costs the same from 8 to 64 rows (the unpack
+# bounds it: 57 us at Mixtral's 14336 x 4096, 51 us at 8 rows), half as
+# much again at 128 and three times at 256, where the MXU does
+EXPERT_ROW_TILE = 64
+
+
+def expert_row_tile(group_rows: float) -> int:
+    """Rows of one row tile of a grouped expert call, from the rows an
+    expert's group holds when the program's tokens are all real and route
+    evenly (token rows x top-k / the router's width): the power of two at
+    or over it, so that a group is mostly ONE pass over its expert's
+    weights, within a sublane tile's 8 rows and EXPERT_ROW_TILE. 8 in the
+    served decode steps, 64 in Mixtral's 256-row chunk (top-2 of 8), 16 in
+    sarvam's (top-8 of 128): what is not kernel costs by the rows of tile
+    padding, an expert's ragged tile among them."""
+    tile = 8
+    while tile < min(group_rows, EXPERT_ROW_TILE):
+        tile *= 2
+    return tile
+
+
+@functools.partial(jax.jit, static_argnames=("out_dtype", "interpret",
+                                             "token_rows"))
 def q40_expert_matmul(
     x: jnp.ndarray,
     w: QuantizedTensor,    # stacked (E, d, m) packed / (E, d, nb) scales
-    e: jnp.ndarray,        # i32 expert index, traced or a Python integer
+    e: jnp.ndarray,        # i32 expert of each row tile of x (or of all x)
+    used: jnp.ndarray | None = None,  # i32 leading row tiles that are live
     out_dtype=jnp.float32,
     interpret: bool = False,
+    token_rows: int | None = None,
 ) -> jnp.ndarray:
-    """y[..., d] = sum_n x[..., n] * W[e, d, n]: every expert matmul of
-    models/transformer._moe_ffn on a plain single-shard stack — the served
-    all-experts loop at 8 and 256 rows (e a Python integer there) and the
-    one-row decode gather of the top-k experts (e traced; the reference
-    computes just the active experts the same way, ref:
-    src/grok1-tasks.cpp:128-143).
+    """y[r, d] = sum_n x[r, n] * W[e[r // tile], d, n]: every routed expert
+    matmul of models/transformer._moe_ffn on a plain single-shard stack,
+    ONE call a projection (the reference computes just the active experts
+    too, ref: src/grok1-tasks.cpp:128-143).
 
-    The expert index rides in as a scalar-prefetch operand and the block
+    x holds the step's live (token, expert) pairs sorted by expert, each
+    expert's group starting on a row tile of x.shape[0] / len(e) rows
+    (models/transformer._grouped_experts lays them out; expert_row_tile
+    sizes the tile). Tile j is
+    multiplied by expert e[j] alone; the first `used` tiles hold the live
+    pairs and the rest are skipped — no block of theirs is fetched, no body
+    runs — so an expert no live token chose is never read and a row is
+    computed for its own experts only. Rows of a used tile past its group's
+    end are computed and mean nothing; a skipped tile's rows are never
+    written. A scalar `e` is one tile of all rows: x @ W[e]^T.
+    `token_rows`, the PROGRAM's token rows, decides the operand feed
+    (_q40_call) whatever the pair rows number.
+
+    The tiles' experts ride in as scalar-prefetch operands and the block
     index maps offset straight into the (E, d, m) HBM stack, so the kernel
     reads the expert's packed bytes IN PLACE. The alternative —
     lax.dynamic_index_in_dim then q40_matmul — materializes a full HBM copy
@@ -383,4 +462,4 @@ def q40_expert_matmul(
     the matmul reads it (36 % of mixtral-8x7b-12l's device time, PERF.md
     section 6, PR 31).
     """
-    return _q40_call(x, w, e, out_dtype, interpret)
+    return _q40_call(x, w, e, out_dtype, interpret, used, token_rows)

@@ -371,6 +371,7 @@ class Engine:
         # serving set (runtime/profiler.py)
         self._steps: dict[int | tuple[str, int], Callable] = {}
         self._compile_warm = False
+        self._expert_counts: list = []  # (program, device counts) not yet taken
         self.cache = self._new_cache()
         self.pos = 0
 
@@ -1483,6 +1484,40 @@ class Engine:
 
     # -- continuous-batching slot steps (runtime/scheduler.py) ------------
 
+    @property
+    def _counts_experts(self) -> bool:
+        """Whether the two slot step programs count the experts they read
+        (forward's expert_counts): a model with experts whose layers are
+        traced in forward's own loop. Any other model's programs are
+        compiled with the argument at its default, exactly as before."""
+        return (self.spec.is_moe and self._pp_mesh is None
+                and not self._multihost)
+
+    def _note_expert_counts(self, program: str, counts=None) -> None:
+        """Keep a slot step program's (expert_reads, expert_pairs) on the
+        device, its copy to the host started, until take_expert_counts."""
+        if counts is not None:
+            counts.copy_to_host_async()
+            self._expert_counts.append((program, counts))
+
+    def take_expert_counts(self) -> list[tuple[str, int, int]]:
+        """(program, expert_reads, expert_pairs) of the slot step programs
+        dispatched since the last call that HAVE RUN, oldest first; one
+        still running, and those behind it, wait for the next call, so this
+        never blocks. After the scheduler has fetched a step's logits every
+        program dispatched before the fetch has run and its counts' copy,
+        started at dispatch, has landed: no round trip of their own. A
+        stretch of mid-prompt chunks fetches nothing, so the scheduler asks
+        after each of them too, and the counters trail the step counts by
+        the programs in flight, not by the stretch."""
+        n = 0
+        while (n < len(self._expert_counts)
+               and self._expert_counts[n][1].is_ready()):
+            n += 1
+        taken = self._expert_counts[:n]
+        del self._expert_counts[:n]
+        return [(program, *map(int, np.asarray(c))) for program, c in taken]
+
     def slot_prefill_chunk(self, tokens: np.ndarray, pos: np.ndarray,
                            logit_index: np.ndarray) -> jax.Array:
         """One chunked-prefill forward over the batched cache: row r writes
@@ -1512,7 +1547,8 @@ class Engine:
         assert b == self.batch, (b, self.batch)
         key = ("slot_prefill", c)
         if key not in self._steps:
-            common = self._forward_kwargs()
+            common = dict(self._forward_kwargs(),
+                          expert_counts=self._counts_experts)
 
             def run(params, tokens, pos0, logit_index, cache):
                 return forward(params, self.spec, tokens, pos0, cache,
@@ -1526,9 +1562,10 @@ class Engine:
             tok = jax.device_put(tok, self._token_sharding)
             posv = jax.device_put(posv,
                                   NamedSharding(self.mesh, P(DP_AXIS)))
-        logits, self.cache = self._steps[key](
+        logits, self.cache, *counts = self._steps[key](
             self.params, tok, posv, jnp.asarray(logit_index, jnp.int32),
             self.cache)
+        self._note_expert_counts("prefill", *counts)
         return logits
 
     def slot_decode_step(self, tokens: np.ndarray, pos: np.ndarray) -> jax.Array:
@@ -1542,7 +1579,8 @@ class Engine:
         assert b == self.batch and t == 1, (tokens.shape, self.batch)
         key = "slot_decode"
         if key not in self._steps:
-            common = self._forward_kwargs()
+            common = dict(self._forward_kwargs(),
+                          expert_counts=self._counts_experts)
 
             def run(params, tokens, pos0, cache):
                 return forward(params, self.spec, tokens, pos0, cache,
@@ -1556,8 +1594,9 @@ class Engine:
             tok = jax.device_put(tok, self._token_sharding)
             posv = jax.device_put(posv,
                                   NamedSharding(self.mesh, P(DP_AXIS)))
-        logits, self.cache = self._steps[key](self.params, tok, posv,
-                                              self.cache)
+        logits, self.cache, *counts = self._steps[key](
+            self.params, tok, posv, self.cache)
+        self._note_expert_counts("decode", *counts)
         return logits
 
     def slot_verify_step(self, tokens: np.ndarray, pos: np.ndarray,

@@ -805,6 +805,21 @@ class Scheduler:
         self._step_wait += time.perf_counter() - t0
         if self._span is not None:
             TRACER.phase(self._span, "sched.sample_emit")
+        self._count_experts()  # every program up to this fetch has run
+
+    def _count_experts(self) -> None:
+        """Add the expert counts of the step programs that have run since
+        the last call (Engine.take_expert_counts; never blocks): after
+        every logits fetch, and after a mid-prompt chunk, which fetches
+        nothing."""
+        take = getattr(self.engine, "take_expert_counts", None)
+        for program, reads, pairs in take() if take is not None else ():
+            if program == "decode":
+                self.stats.expert_reads_decode += reads
+                self.stats.expert_pairs_decode += pairs
+            else:
+                self.stats.expert_reads_prefill += reads
+                self.stats.expert_pairs_prefill += pairs
 
     def _prefill_chunk(self, rows: list[_Slot],
                        width: int | None = None) -> None:
@@ -842,6 +857,7 @@ class Scheduler:
                 finishing.append(s)
         logits = eng.slot_prefill_chunk(tok, pos, lidx)
         if not finishing:
+            self._count_experts()
             return  # mid-prompt chunk: no D2H fetch at all
         view = self._sample_view(logits, finishing)
         for s in finishing:

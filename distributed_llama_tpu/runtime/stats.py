@@ -235,7 +235,9 @@ WINDOW_COUNTERS = ("admitted", "queue_wait_ms_sum", "prefill_steps",
                    "decode_rows",
                    "gated_rows", "busy_ms", "wait_ms", "host_ms",
                    "attn_pairs_decode", "attn_pairs_prefill",
-                   "prefill_cached_tokens")
+                   "prefill_cached_tokens",
+                   "expert_reads_decode", "expert_reads_prefill",
+                   "expert_pairs_decode", "expert_pairs_prefill")
 
 
 class FrontDoorStats:
@@ -307,6 +309,16 @@ class ServeStats:
     attn_pairs_prefill: int = 0
     prefill_cached_tokens: int = 0  # cache rows a chunk's real rows attend
     #                                 (offset + tokens, summed over rows)
+    # the routed experts' work, counted ON THE DEVICE by the step programs
+    # (models/transformer._moe_ffn) and added once a program has run
+    # (Scheduler._count_experts): a READ is a held expert some real token
+    # chose in a MoE layer of a program, a PAIR a (real token, chosen held
+    # expert); both summed over layers and programs; 0 for a model without
+    # experts
+    expert_reads_decode: int = 0
+    expert_reads_prefill: int = 0
+    expert_pairs_decode: int = 0
+    expert_pairs_prefill: int = 0
     # gauges, set by the Scheduler: cache bytes one token holds over the
     # layers that HAVE a cache (K and V leaves, or the latent cache's one
     # leaf), and the bytes a slot holds whatever its context (the DELTA

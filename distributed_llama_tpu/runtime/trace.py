@@ -582,6 +582,17 @@ _COUNTERS = (
      "Cached positions attended, summed over real prefill tokens"),
     ("prefill_cached_tokens", "dllama_prefill_cached_tokens_total",
      "Cache rows read by prefill chunks, summed over their real rows"),
+    ("expert_reads_decode", "dllama_expert_reads_decode_total",
+     "Held experts some decoding row chose, summed over MoE layers and "
+     "decode steps"),
+    ("expert_reads_prefill", "dllama_expert_reads_prefill_total",
+     "Held experts some real prompt token chose, summed over MoE layers "
+     "and prefill chunks"),
+    ("expert_pairs_decode", "dllama_expert_pairs_decode_total",
+     "(decoding row, chosen held expert) pairs, summed over MoE layers"),
+    ("expert_pairs_prefill", "dllama_expert_pairs_prefill_total",
+     "(real prompt token, chosen held expert) pairs, summed over MoE "
+     "layers"),
 )
 
 _GAUGES = (

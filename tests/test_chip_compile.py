@@ -121,8 +121,7 @@ def test_q40_matmul_compiles_at_7b_widths(one_chip, d, n, t):
     assert _has_kernel(c)
 
 
-# t = 1 is the offline one-row gather; 8 and 256 (= MAX_T) are the served
-# step programs' rows, every expert for every row
+# one expert for every row of x (a scalar `e`): 1, 8 and 256 (= MAX_T) rows
 @pytest.mark.parametrize("t", [1, 8, 256])
 @pytest.mark.parametrize("d,n", [(14336, 4096), (4096, 14336)])
 def test_q40_expert_matmul_compiles_at_mixtral_widths(one_chip, d, n, t):
@@ -135,6 +134,37 @@ def test_q40_expert_matmul_compiles_at_mixtral_widths(one_chip, d, n, t):
     e = _struct((), jnp.int32, one_chip)
     c = jax.jit(lambda x, w, e: q40_expert_matmul(
         x, w, e, out_dtype=BF16)).lower(x, w, e).compile()
+    assert _has_kernel(c)
+
+
+# the served step programs' grouped calls: one pallas_call a projection
+# over the row tiles of the step's (token, expert) pairs
+@pytest.mark.parametrize("rows", [8, 256], ids=["decode8", "chunk256"])
+@pytest.mark.parametrize("model,d,n", [
+    ("mixtral_8x7b", 14336, 4096), ("mixtral_8x7b", 4096, 14336),
+    ("sarvam_105b_ep8", 2048, 4096), ("sarvam_105b_ep8", 4096, 2048)])
+def test_grouped_expert_tiles_compile(one_chip, model, d, n, rows):
+    """`q40_expert_matmul` over the row tiles `_pair_layout` gives both MoE
+    configurations' two step programs (Mixtral: 8 tiles of 8 rows and 16
+    of 64; sarvam-105b-ep8: 16 of 8 and a wave of 32 of 16), each tile's
+    expert and the used count prefetched, under the operand feed the
+    program's token rows decide."""
+    import rehearse_chip_compile as r
+    from rehearse_chip_compile import q40_struct
+
+    from distributed_llama_tpu.models.transformer import _pair_layout
+    from distributed_llama_tpu.ops.pallas_q40 import q40_expert_matmul
+
+    spec = getattr(r, model.upper())
+    assert {d, n} == {spec.dim, spec.hidden_dim}
+    tile, _, wave = _pair_layout(spec, rows)
+    w = _placed(q40_struct(spec.n_experts, d, n), one_chip)
+    x = _struct((wave * tile, n), BF16, one_chip)
+    e = _struct((wave,), jnp.int32, one_chip)
+    used = _struct((), jnp.int32, one_chip)
+    c = jax.jit(lambda x, w, e, used: q40_expert_matmul(
+        x, w, e, used, out_dtype=BF16, token_rows=rows)).lower(
+            x, w, e, used).compile()
     assert _has_kernel(c)
 
 

@@ -1,0 +1,311 @@
+"""The grouped expert path through the served step programs (Engine,
+Scheduler): what a whole prompt reads and writes, the four window counters
+against a count over the benchmark reference's routing, and that a model
+without experts compiles what it compiled before the path existed.
+
+The layer itself (`_moe_ffn` against the all-experts loop, the tile layout,
+the kernel's skipped tiles) is tests/test_pallas_q40.py's.
+"""
+
+import hashlib
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(REPO, "benchmark"))
+
+import weights  # noqa: E402  (the benchmark's draw)
+from reference import mixtral as ref  # noqa: E402
+from test_pallas_q40 import _all_experts_loop  # noqa: E402
+
+from distributed_llama_tpu.io.model_file import read_model  # noqa: E402
+from distributed_llama_tpu.models.params import (load_params,  # noqa: E402
+                                                 random_tensors)
+from distributed_llama_tpu.models.spec import ArchType  # noqa: E402
+from distributed_llama_tpu.runtime.engine import Engine  # noqa: E402
+from distributed_llama_tpu.runtime.scheduler import Scheduler  # noqa: E402
+from distributed_llama_tpu.runtime.stats import WINDOW_COUNTERS  # noqa: E402
+from distributed_llama_tpu.sampler import Sampler  # noqa: E402
+from distributed_llama_tpu.testing import (tiny_hybrid_spec,  # noqa: E402
+                                           tiny_spec)
+
+F32 = jnp.float32
+B, CHUNK, SEQ = 8, 8, 96
+
+
+@pytest.fixture(scope="module")
+def tiny_moe(tmp_path_factory):
+    """A MIXTRAL file of 2 layers, 8 experts top-2, drawn as the benchmark
+    draws `mixtral-8x7b-12l` (zero-mean nibbles: tokens spread over the
+    experts), its loaded leaves and two prompts."""
+    spec = tiny_spec(arch=ArchType.MIXTRAL, n_experts=8, n_active_experts=2,
+                     dim=128, hidden_dim=256, n_layers=2, n_heads=4,
+                     n_kv_heads=2, seq_len=SEQ, rope_theta=1e6)
+    path = str(tmp_path_factory.mktemp("moe") / "model.m")
+    weights.write_model(path, spec, 7, {"zero_mean": True})
+    spec, tensors = read_model(path)
+    params = load_params(spec, tensors, mode="q40", dtype=F32)
+    toks = np.random.default_rng(3).integers(3, spec.vocab_size, 64)
+    return path, spec, params, [int(t) for t in toks]
+
+
+def engine(spec, params, dtype=F32):
+    return Engine(spec, params, batch=B, compute_dtype=dtype,
+                  cache_dtype=dtype, use_pallas=True, pallas_interpret=True)
+
+
+def serve(eng, prompts: dict, n_decode: int, pad_token: int):
+    """Chunked slot prefill of {row: tokens} (rows of unequal length, so
+    that some are gated while others end on a right-padded tail chunk),
+    then n_decode greedy slot decode steps; pad positions and gated rows
+    hold `pad_token`. Returns the logits of every live row of every call
+    and the (row, position) pairs that were written."""
+    seq, logits, live = eng.seq_len, [], set()
+    longest = max(len(p) for p in prompts.values())
+    last = {}
+    for off in range(0, longest, CHUNK):
+        tok = np.full((B, CHUNK), pad_token, np.int32)
+        pos = np.full((B,), seq, np.int32)
+        lidx = np.zeros((B,), np.int32)
+        for row, p in prompts.items():
+            part = p[off:off + CHUNK]
+            if part:
+                tok[row, :len(part)] = part
+                pos[row], lidx[row] = off, len(part) - 1
+                live.update((row, off + j) for j in range(len(part)))
+        lg = np.asarray(eng.fetch_logits(
+            eng.slot_prefill_chunk(tok, pos, lidx)))
+        for row, p in prompts.items():
+            if p[off:off + CHUNK]:
+                logits.append(lg[row])
+                last[row] = lg[row]
+    at = {row: len(p) for row, p in prompts.items()}
+    for _ in range(n_decode):
+        tok = np.full((B, 1), pad_token, np.int32)
+        pos = np.full((B,), seq, np.int32)
+        for row in prompts:
+            tok[row, 0], pos[row] = int(np.argmax(last[row])), at[row]
+            live.add((row, at[row]))
+            at[row] += 1
+        lg = np.asarray(eng.fetch_logits(eng.slot_decode_step(tok, pos)))
+        for row in prompts:
+            logits.append(lg[row])
+            last[row] = lg[row]
+    return np.stack(logits), sorted(live)
+
+
+def cache_rows(eng, live):
+    rows, at = (np.asarray(x) for x in zip(*live))
+    return [np.asarray(leaf)[rows, :, at]
+            for leaf in (*eng.cache.k, *eng.cache.v)]
+
+
+def test_a_whole_prompt_reads_and_writes_what_the_all_experts_loop_does(
+        tiny_moe, monkeypatch):
+    """Three rows of 21, 13 and 3 tokens through chunks of 8 (gated rows,
+    right-padded tails) and three decode steps, five rows idle throughout.
+    What a pad position or a gated row holds (its routed output is zero in
+    the grouped programs, every held expert's in the loop's) reaches no
+    live row: the cache rows at live positions and the live rows' logits
+    are BIT-equal under another pad token. Against the all-experts loop's
+    programs they agree to float32 rounding and in every greedy token: two
+    COMPILED programs of the same arithmetic differ in the last bit where
+    the CPU compiler's fusions contract a multiply into an add, so bit
+    equality of what the two say is held operation by operation, in
+    tests/test_pallas_q40.py."""
+    _, spec, params, toks = tiny_moe
+    prompts = {0: toks[:21], 2: toks[21:34], 5: toks[34:37]}
+
+    eng = engine(spec, params)
+    got, live = serve(eng, prompts, 3, pad_token=0)
+    got_cache = cache_rows(eng, live)
+    assert eng.take_expert_counts()       # the grouped programs counted
+
+    other = engine(spec, params)
+    again, _ = serve(other, prompts, 3, pad_token=int(toks[40]))
+    np.testing.assert_array_equal(got, again)
+    for a, b in zip(got_cache, cache_rows(other, live)):
+        np.testing.assert_array_equal(a, b)
+
+    _all_experts_loop(monkeypatch)
+    loop = engine(spec, params)
+    want, _ = serve(loop, prompts, 3, pad_token=0)
+    assert np.abs(want).max() > 0.5
+    np.testing.assert_array_equal(got.argmax(-1), want.argmax(-1))
+    np.testing.assert_allclose(got, want, rtol=0, atol=2e-5)
+    for a, b in zip(got_cache, cache_rows(loop, live)):
+        np.testing.assert_allclose(a, b, rtol=0, atol=2e-5)
+
+
+def test_the_four_counters_count_the_references_routing(tiny_moe, tmp_path):
+    """Two requests through the Scheduler (chunks of 8, so tails are padded
+    and rows gated; then both decode, one longer than the other): between a
+    capture's two ends `/stats` `capture` carries `expert_reads_*` and
+    `expert_pairs_*`, and they equal a NumPy count over the reference's own
+    `top_i` (benchmark/reference/mixtral.py) of the tokens each dispatched
+    program really held: a read is an expert some real token of a program
+    chose in a layer, a pair a (real token, chosen expert)."""
+    from distributed_llama_tpu.runtime.profiler import PROFILER
+
+    path, spec, params, toks = tiny_moe
+    eng = engine(spec, params)
+    dispatched = []  # (program, {row: positions}) of every step program
+
+    def logged(program, fn):
+        def call(tokens, pos, *lidx):
+            width = np.asarray(lidx[0]) + 1 if lidx else np.ones(B, int)
+            dispatched.append((program, {
+                r: range(int(pos[r]), int(pos[r]) + int(width[r]))
+                for r in range(B) if pos[r] < eng.seq_len}))
+            return fn(tokens, pos, *lidx)
+        return call
+
+    eng.slot_prefill_chunk = logged("prefill", eng.slot_prefill_chunk)
+    eng.slot_decode_step = logged("decode", eng.slot_decode_step)
+    sched = Scheduler(eng, chunk=CHUNK)
+    greedy = lambda: Sampler(spec.vocab_size, temperature=0.0, topp=0.9,  # noqa: E731
+                             seed=1)
+    calls = []
+
+    def counters():
+        if calls:  # between the capture's two ends: the work
+            reqs = [sched.submit(toks[:21], 6, greedy()),
+                    sched.submit(toks[30:43], 3, greedy())]
+            for _ in range(400):
+                if all(r.finished.is_set() for r in reqs):
+                    break
+                sched.step()
+            calls.extend(reqs)
+        calls.append(None)
+        now = sched.stats.summary()   # as apps/api_server's /debug/profile
+        return {k: now[k] for k in ("steps", *WINDOW_COUNTERS) if k in now}
+
+    try:
+        PROFILER.capture(str(tmp_path), 1, counters)
+        ends = PROFILER.last_counters
+    finally:
+        PROFILER.reset()
+    a, b = (list(r.tokens(timeout=5.0)) for r in calls[1:3])
+    assert len(a) == 6 and len(b) == 3
+
+    # the reference's routing of each request's whole sequence, by slot:
+    # the scheduler gives the first request slot 0 and the second slot 1
+    seqs = {0: toks[:21] + a, 1: toks[30:43] + b}
+    top_i = {}
+    for row, seq in seqs.items():
+        routing = []
+        ref.forward(path, np.asarray(seq[:-1], np.int32), routing=routing)
+        assert min(r["margin"].min() for r in routing) > 1e-5
+        top_i[row] = [r["top_i"] for r in routing]      # a layer: (T, 2)
+    want = dict.fromkeys(("expert_reads_prefill", "expert_pairs_prefill",
+                          "expert_reads_decode", "expert_pairs_decode"), 0)
+    for program, held in dispatched:
+        assert set(held) <= set(seqs)
+        for layer in range(spec.n_layers):
+            chosen = np.concatenate([top_i[r][layer][list(at)]
+                                     for r, at in held.items()])
+            want[f"expert_reads_{program}"] += len(np.unique(chosen))
+            want[f"expert_pairs_{program}"] += chosen.size
+    assert want["expert_pairs_prefill"] == (21 + 13) * 2 * spec.n_layers
+    assert 0 < want["expert_reads_prefill"] < want["expert_pairs_prefill"]
+    assert 0 < want["expert_reads_decode"] <= want["expert_pairs_decode"]
+    got = {k: ends["stop"][k] - ends["start"][k] for k in want}
+    assert got == want
+    assert all(ends["start"][k] == 0 for k in want)
+
+
+def test_the_counters_keep_up_through_chunks_that_fetch_nothing(tiny_moe):
+    """A prompt of 45 tokens through chunks of 8 alone in the server: five
+    mid-prompt chunks fetch no logits and no decode step runs between them.
+    The counters are added from the programs that HAVE RUN, without a
+    fetch and without blocking, so they trail `prefill_steps` by the
+    program in flight and not by the stretch (a closed loop's eight
+    documents prefill for dozens of iterations on end: a capture's two ends
+    would else difference steps and experts of different programs)."""
+    _, spec, params, toks = tiny_moe
+    eng = engine(spec, params)
+    sched = Scheduler(eng, chunk=CHUNK)
+    req = sched.submit(toks[:45], 2, Sampler(spec.vocab_size, temperature=0.0,
+                                             topp=0.9, seed=1))
+    a_chunk = CHUNK * spec.n_active_experts * spec.n_layers
+    for i in range(1, 6):
+        sched.step()
+        assert sched.stats.prefill_steps == i and sched.stats.decode_steps == 0
+        assert (i - 1) * a_chunk <= sched.stats.expert_pairs_prefill <= i * a_chunk
+        jax.block_until_ready(eng.cache)       # chunk i has run
+    for _ in range(50):
+        if req.finished.is_set():
+            break
+        sched.step()
+    assert req.finished.is_set() and eng.take_expert_counts() == []
+    assert sched.stats.expert_pairs_prefill == 45 * 2 * spec.n_layers
+    assert sched.stats.expert_pairs_decode == 1 * 2 * spec.n_layers
+
+
+# sha256 (16 hex digits) of the text the slot step programs of the tiny
+# specs lower to, read on the tree BEFORE the grouped path and its counters
+# existed (commit 5e2fe7c, this container's jax): a model without experts
+# must compile exactly what it compiled then. A PR that changes the dense
+# path on purpose re-pins them, from the failure's message.
+PARENT_TEXT = {
+    ("LLAMA", False, "decode"): "ca9dbfd3d9d530cf",
+    ("LLAMA", False, "prefill"): "6f6b41e521545ef5",
+    ("LLAMA", True, "decode"): "4fb0a8bc402724e2",
+    ("LLAMA", True, "prefill"): "d760fddbff3e07d6",
+    ("OLMO_HYBRID", False, "decode"): "52f9fb7000fdeb40",
+    ("OLMO_HYBRID", False, "prefill"): "c8040defc4f2b4aa",
+    ("OLMO_HYBRID", True, "decode"): "06323757d9c50a83",
+    ("OLMO_HYBRID", True, "prefill"): "2b2ee5b53568d61c",
+}
+
+
+@pytest.fixture(scope="module")
+def lowered_steps():
+    made = {}
+
+    def steps(arch: str, kernels: bool) -> dict:
+        if (arch, kernels) not in made:
+            spec = tiny_spec() if arch == "LLAMA" else tiny_hybrid_spec()
+            params = load_params(
+                spec, random_tensors(spec, seed=1, scale=0.05), mode="q40",
+                dtype=F32)
+            eng = Engine(spec, params, batch=B, compute_dtype=F32,
+                         cache_dtype=F32, use_pallas=kernels,
+                         pallas_interpret=kernels)
+            i32 = lambda a: jnp.asarray(a, jnp.int32)  # noqa: E731
+            pos = np.full((B,), eng.seq_len, np.int32)
+            one, chunk = np.zeros((B, 1)), np.zeros((B, CHUNK))
+            eng.slot_decode_step(one, pos)             # mint both programs
+            eng.slot_prefill_chunk(chunk, pos, np.zeros(B))
+            assert eng.take_expert_counts() == []
+            made[arch, kernels] = {
+                "decode": eng._steps["slot_decode"].lower(
+                    eng.params, i32(one), i32(pos), eng.cache),
+                "prefill": eng._steps["slot_prefill", CHUNK].lower(
+                    eng.params, i32(chunk), i32(pos), i32(np.zeros(B)),
+                    eng.cache)}
+        return made[arch, kernels]
+
+    return steps
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("kernels", [False, True], ids=["xla", "kernels"])
+@pytest.mark.parametrize("arch", ["LLAMA", "OLMO_HYBRID"])
+def test_a_model_without_experts_lowers_to_the_parents_step_programs(
+        lowered_steps, arch, kernels, program):
+    """The two slot step programs of LLAMA and OLMO_HYBRID engines lower to
+    the SAME TEXT as before the grouped path: no counter leaves them (their
+    outputs are the logits and the cache's leaves), nothing is sorted or
+    counted in them."""
+    low = lowered_steps(arch, kernels)[program]
+    n_cache = 4 if arch == "LLAMA" else 16
+    assert len(jax.tree_util.tree_leaves(low.out_info)) == 1 + n_cache
+    text = low.as_text()
+    assert (hashlib.sha256(text.encode()).hexdigest()[:16]
+            == PARENT_TEXT[arch, kernels, program]), (arch, kernels, program)

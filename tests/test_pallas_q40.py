@@ -87,93 +87,246 @@ def test_expert_kernel_equals_plain_kernel_at_served_rows(rng, t, e):
 
 
 def _jit_calls(fn) -> dict:
-    """How often fn's trace calls each jitted kernel entry point."""
+    """How often fn's trace calls each jitted kernel entry point (call
+    sites: a loop's body counts once)."""
     import jax
 
     calls = {}
-    for eqn in jax.make_jaxpr(fn)().eqns:
-        name = eqn.params.get("name", "")
-        if name.startswith("q40_"):
-            calls[name] = calls.get(name, 0) + 1
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            name = eqn.params.get("name", "")
+            if name.startswith("q40_"):
+                calls[name] = calls.get(name, 0) + 1
+                continue
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)().jaxpr)
     return calls
 
 
-def _moe_case(rng, held_share: bool):
-    """(spec, layer weights) of one MoE block at tiny widths: Mixtral's
-    shape (softmax router, top-2 of 8) or a held share with a shared expert
-    (sigmoid-bias router over 32, top-8, experts 8..23 held)."""
+def _moe_case(rng, kind, unchosen=None):
+    """(spec, layer weights) of one MoE block at tiny widths: "mixtral"
+    (softmax router, top-2 of 8), "grok1" (the same router under GELU) or
+    "held" (a SARVAM_MLA-shaped held share with a shared expert: a
+    sigmoid-bias router over 128, top-8, experts 40..55 held and favoured
+    by the bias, so that a few rows choose some). Router logits are of
+    order one, as a trained router's: a token's weights are spread over its
+    experts. `unchosen`: a held expert whose router row is turned so that
+    no token chooses it."""
     from distributed_llama_tpu.models.spec import (ArchType, HiddenAct,
                                                    ModelSpec)
 
     d, h = 256, 512
     common = dict(dim=d, hidden_dim=h, n_layers=1, n_heads=4, n_kv_heads=4,
-                  vocab_size=64, seq_len=64, hidden_act=HiddenAct.SILU)
-    if held_share:
+                  vocab_size=64, seq_len=64)
+    if kind == "held":
         spec = ModelSpec(arch=ArchType.SARVAM_MLA, n_experts=16,
-                         n_active_experts=8, n_routed_experts=32,
-                         expert_offset=8, routed_scaling=2.5,
+                         n_active_experts=8, n_routed_experts=128,
+                         expert_offset=40, routed_scaling=2.5,
                          n_shared_experts=1, kv_lora_rank=32,
                          qk_nope_head_dim=16, qk_rope_head_dim=8,
-                         v_head_dim=16, **common)
+                         v_head_dim=16, hidden_act=HiddenAct.SILU, **common)
+    elif kind == "grok1":
+        spec = ModelSpec(arch=ArchType.GROK1, n_experts=8,
+                         n_active_experts=2, hidden_act=HiddenAct.GELU,
+                         **common)
     else:
         spec = ModelSpec(arch=ArchType.MIXTRAL, n_experts=8,
-                         n_active_experts=2, **common)
+                         n_active_experts=2, hidden_act=HiddenAct.SILU,
+                         **common)
     e = spec.n_experts
-    lw = {"moe_router": jnp.asarray(rng.standard_normal(
-              (spec.router_width, d), dtype=np.float32)),
-          "moe_up": _stack(rng, e, h, d)[1],
+    router = rng.standard_normal((spec.router_width, d),
+                                 dtype=np.float32) / np.sqrt(d)
+    lw = {"moe_up": _stack(rng, e, h, d)[1],
           "moe_gate": _stack(rng, e, h, d)[1],
           "moe_down": _stack(rng, e, d, h)[1]}
-    if held_share:
-        lw.update(moe_bias=jnp.asarray(0.5 * rng.standard_normal(
-                      spec.router_width, dtype=np.float32)),
+    if spec.router_width != e:
+        bias = 0.5 * rng.standard_normal(spec.router_width, dtype=np.float32)
+        bias[spec.expert_offset:spec.expert_offset + e] += 0.5
+        if unchosen is not None:
+            bias[spec.expert_offset + unchosen] = -1e4
+        lw.update(moe_bias=jnp.asarray(bias),
                   sh_w1=_qt(rng, h, d), sh_w2=_qt(rng, d, h),
                   sh_w3=_qt(rng, h, d))
+    elif unchosen is not None:
+        router[unchosen] = 0.0  # the caller's feature 0 is a positive constant
+        router[unchosen, 0] = -1e3
+    lw["moe_router"] = jnp.asarray(router)
     return spec, lw
+
+
+# rows of a served 8-slot program: whole, gated (n_valid 0), a right-padded
+# tail, one token
+_N_VALID = {1: (1, 0, 1, 0, 0, 1, 1, 0), 32: (32, 0, 27, 32, 0, 0, 1, 32)}
+_CFG = dict(activation_q80=True, compute_dtype=jnp.bfloat16, use_pallas=True,
+            tp_mesh=None, tp_reduce="exact", pallas_interpret=True)
+
+
+def _all_experts_loop(monkeypatch):
+    """Send `_moe_ffn` down the path of stacks the kernel cannot take."""
+    import sys
+
+    monkeypatch.setattr(
+        sys.modules["distributed_llama_tpu.models.transformer"],
+        "reads_experts_in_place", lambda *a, **k: False)
 
 
 @pytest.mark.parametrize("q80", [True, False], ids=["q80", "plain"])
 @pytest.mark.parametrize("t", [1, 32], ids=["decode8x1", "chunk8x32"])
-@pytest.mark.parametrize("held_share", [False, True],
-                         ids=["top2of8", "held16top8shared"])
-def test_all_experts_loop_reads_in_place_bit_equal(rng, monkeypatch,
-                                                   held_share, t, q80):
-    """`_moe_ffn`'s all-experts loop at the served shapes (8 rows, and 8 x 32
-    = 256 kernel rows) calls the expert-indexed kernel on each stacked leaf
-    — gate, up and down of every held expert — and its output is BIT-equal
-    to slicing each expert out and calling `q40_matmul`, with the Q80
-    activation round trip on (as served) and off."""
-    import sys
+@pytest.mark.parametrize("kind", ["mixtral", "grok1", "held"],
+                         ids=["top2of8", "grok1", "held16top8shared"])
+def test_grouped_experts_bit_equal_to_all_experts_loop(rng, monkeypatch,
+                                                       kind, t, q80):
+    """`_moe_ffn` at the served shapes (8 rows, and 8 x 32 = 256 kernel
+    rows), with gated rows, a right-padded tail chunk and an expert nobody
+    chose: ONE `q40_expert_matmul` a projection over the live (token,
+    expert) pairs, and every LIVE token's output BIT-equal to the
+    all-experts loop over slices + `q40_matmul`, with the Q80 activation
+    round trip on (as served) and off. Both run operation by operation
+    (`jax.disable_jit`: the grouped path's waves are a loop, and a compiled
+    loop body keeps float32 between bfloat16 operations where its fusions
+    happen to end), so what the two SAY is compared. A dead token's routed
+    output is zero (what is left of it is the shared expert's)."""
+    import jax
 
     from distributed_llama_tpu.models.transformer import _moe_ffn
 
-    # the module: `ops.matmul` the attribute is the function of that name
-    matmul_mod = sys.modules["distributed_llama_tpu.ops.matmul"]
-
-    spec, lw = _moe_case(rng, held_share)
+    spec, lw = _moe_case(rng, kind, unchosen=3)
     xb = jnp.asarray(rng.standard_normal((8, t, spec.dim), dtype=np.float32),
-                     jnp.bfloat16)
-    cfg = dict(activation_q80=q80, compute_dtype=jnp.bfloat16,
-               use_pallas=True, tp_mesh=None, tp_reduce="exact",
-               pallas_interpret=True)
+                     jnp.bfloat16).at[..., 0].set(8.0)
+    cfg = dict(_CFG, activation_q80=q80)
+    n_valid = jnp.asarray(_N_VALID[t], jnp.int32)
+    real = np.arange(t)[None, :] < np.asarray(n_valid)[:, None]
 
-    def run():  # a new function each time: traces are cached by function
-        return lambda: _moe_ffn(xb, lw, spec, cfg)
+    def run(nv, lw=lw):  # a new function each time: traces are cached
+        def ffn():
+            counts = []
+            return _moe_ffn(xb, lw, spec, cfg, nv, counts), counts[0]
+        return ffn
 
-    shared = {"q40_matmul": 3} if held_share else {}  # the shared expert's
-    assert _jit_calls(run()) == {"q40_expert_matmul": 3 * spec.n_experts,
-                                 **shared}
-    got = run()()
+    shared = {"q40_matmul": 3} if kind == "held" else {}
+    assert _jit_calls(run(n_valid)) == {"q40_expert_matmul": 3, **shared}
+    with jax.disable_jit():
+        got, (reads, pairs) = run(n_valid)()
+    got = np.asarray(got, np.float32)
+    assert 0 < reads <= spec.n_experts - 1          # expert 3: never read
+    if kind == "held":
+        assert reads <= pairs < real.sum() * spec.n_active_experts
+    else:
+        assert pairs == real.sum() * spec.n_active_experts
 
-    monkeypatch.setattr(matmul_mod, "fused_expert_matmul",
-                        lambda *a, **k: None)
-    assert _jit_calls(run()) == {
+    _all_experts_loop(monkeypatch)
+    assert _jit_calls(run(None)) == {
         "q40_matmul": 3 * spec.n_experts + shared.get("q40_matmul", 0)}
-    sliced = run()()
-    assert got.dtype == sliced.dtype == jnp.bfloat16
-    assert float(jnp.abs(sliced.astype(jnp.float32)).max()) > 0
-    np.testing.assert_array_equal(np.asarray(got, np.float32),
-                                  np.asarray(sliced, np.float32))
+    with jax.disable_jit():
+        loop = np.asarray(run(None)()[0], np.float32)
+    assert np.abs(loop[real]).max() > 0
+    np.testing.assert_array_equal(got[real], loop[real])
+    if kind == "held":
+        routed = {k: v for k, v in lw.items() if not k.startswith("sh_")}
+        monkeypatch.undo()
+        got = np.asarray(jax.jit(run(n_valid, routed))()[0], np.float32)
+    assert not got[~real].any()
+
+
+@pytest.mark.parametrize("tile", [8, 64])
+@pytest.mark.parametrize("case", ["ragged", "one_expert", "nothing_live"])
+def test_pair_tiles_name_no_expert_without_a_live_pair(rng, case, tile):
+    """`_pair_tiles` (the layout `_grouped_experts` hands the kernel): every
+    live pair has a row of its own in a tile of ITS expert, groups keep
+    token order, the used tiles come first in ascending expert order and
+    each holds a live pair, and no dead pair lands anywhere."""
+    from distributed_llama_tpu.models.transformer import _pair_tiles
+
+    rows, k, n_e = 256, 2, 8
+    held = np.stack([rng.permutation(12)[:k] - 2 for _ in range(rows)])
+    live = (held >= 0) & (held < n_e) & (rng.random((rows, 1)) < 0.6)
+    if case == "one_expert":
+        held[:, 0], held[:, 1] = 5, 11
+        live = (held == 5) & np.ones((rows, 1), bool)
+    if case == "nothing_live":
+        live[:] = False
+    member = (held[..., None] == np.arange(n_e)) & live[..., None]
+    sizes = member.reshape(-1, n_e).sum(0).astype(np.int32)
+    n_tiles = min(rows * k // tile + n_e, n_e * -(-rows // tile))
+    dest, src, tile_expert, used = (np.asarray(a) for a in _pair_tiles(
+        jnp.asarray(held.reshape(8, 32, k)),
+        jnp.asarray(live.reshape(8, 32, k)),
+        jnp.asarray(member.reshape(-1, n_e)), jnp.asarray(sizes),
+        tile, n_tiles))
+    dest = dest.reshape(rows, k)
+    assert used == sum(-(-int(n) // tile) for n in sizes) <= n_tiles
+    assert (dest[~live] == n_tiles * tile).all()
+    assert len(set(dest[live])) == live.sum()      # a row of its own
+    assert (dest[live] < used * tile).all()
+    np.testing.assert_array_equal(tile_expert[dest[live] // tile],
+                                  held[live])
+    np.testing.assert_array_equal(src[dest[live]],
+                                  np.nonzero(live)[0])
+    # used tiles: ascending experts, each with a live pair; no others named
+    assert (np.diff(tile_expert[:used]) >= 0).all()
+    assert set(tile_expert[:used]) == set(np.nonzero(sizes)[0])
+    assert set(dest[live] // tile) == set(range(used))
+    for e in np.nonzero(sizes)[0]:                 # token order in a group
+        assert (np.diff(dest[live & (held == e)]) == 1).all()
+
+
+@pytest.mark.parametrize("kind,rows,want", [
+    ("mixtral", 8, (8, 8, 8)), ("mixtral", 256, (64, 16, 16)),
+    ("grok1", 8, (8, 8, 8)), ("grok1", 256, (64, 16, 16)),
+    ("held", 8, (8, 16, 16)), ("held", 256, (16, 160, 32)),
+], ids=lambda v: str(v) if not isinstance(v, tuple) else "")
+def test_pair_layout_follows_the_programs_rows(rng, kind, rows, want):
+    """(row tile, tiles that hold any routing, tiles a wave) of the served
+    step programs: the tile is the sublane tile's 8 rows in a decode step
+    and the even-routing group's power of two in a chunk (256 x 2 / 8 = 64;
+    256 x 8 / 128 = 16); where every expert is held one wave holds any
+    routing, where 16 of 128 are a wave holds twice the even share."""
+    from distributed_llama_tpu.models.transformer import _pair_layout
+
+    spec, _ = _moe_case(rng, kind)
+    tile, n_tiles, wave = _pair_layout(spec, rows)
+    assert (tile, n_tiles, wave) == want
+    k, n_e = spec.n_active_experts, spec.n_experts
+    # any routing fits: every pair a row, and a ragged tile an expert
+    assert n_tiles * tile >= min(rows * k + n_e * (tile - 1),
+                                 n_e * -(-rows // tile) * tile)
+    assert n_tiles % wave == 0
+
+
+def test_skipped_tiles_are_neither_read_nor_written(rng):
+    """The kernel: a used tile equals a call of its own on its expert (bit
+    for bit) and the dequantized oracle (to rounding), under the operand
+    feed the PROGRAM's token rows decide; tiles past `used`, whatever
+    expert they name, are never written (the interpreter marks memory
+    nobody wrote with NaN)."""
+    from distributed_llama_tpu.ops.pallas_q40 import q40_expert_matmul
+
+    qts, stack = _stack(rng, 4, 256, 512)
+    x = jnp.asarray(rng.standard_normal((6 * 8, 512), dtype=np.float32),
+                    jnp.bfloat16)
+    tiles = jnp.asarray([0, 2, 2, 3, 3, 3], jnp.int32)
+
+    def call(x, e, used, rows):
+        return np.asarray(q40_expert_matmul(
+            x, stack, e, used, out_dtype=jnp.bfloat16, interpret=True,
+            token_rows=rows), np.float32)
+
+    feeds = {rows: call(x, tiles, jnp.int32(3), rows) for rows in (8, 256)}
+    assert not np.array_equal(feeds[8][:24], feeds[256][:24])
+    for rows, got in feeds.items():
+        for j, e in enumerate((0, 2, 2)):
+            tile = slice(8 * j, 8 * j + 8)
+            np.testing.assert_array_equal(got[tile],
+                                          call(x[tile], e, None, rows))
+            ref = jnp.einsum("tn,dn->td", x[tile].astype(jnp.float32),
+                             dequantize_q40_jax(qts[e], dtype=jnp.float32))
+            np.testing.assert_allclose(got[tile], np.asarray(ref),
+                                       atol=0.3, rtol=2e-2)
+        assert np.isnan(got[24:]).all()
+    assert np.isnan(call(x, tiles, jnp.int32(0), 8)).all()
 
 
 def test_wrapped_stack_still_takes_the_slice(rng):

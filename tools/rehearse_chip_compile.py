@@ -215,7 +215,8 @@ def abstract_step(spec: ModelSpec, devices, *, tp: int = 1, batch: int,
         activation_q80=q80, compute_dtype=dtype, use_pallas=True,
         tp_mesh=mesh, tp_reduce="q80" if q80 else "exact",
         vocab_mesh=mesh if vocab_axes else None,
-        vocab_axes=vocab_axes or ("tp",))
+        vocab_axes=vocab_axes or ("tp",),
+        expert_counts=spec.is_moe)  # Engine._counts_experts
 
     if t == 1:
         def slot_decode_step(params, tokens, pos0, cache):
@@ -248,7 +249,10 @@ def expert_sized_results(compiled_text: str, spec: ModelSpec) -> list[str]:
     result is ONE EXPERT'S packed Q40 matrix — an expert sliced out of its
     stacked (E, d, m) leaf into HBM before a kernel may read it, which cost
     36 % of `mixtral-8x7b-12l`'s device time (PERF.md section 6, PR 31) —
-    or a whole packed stack. Not counted: parameters, and the compiler's own
+    or a whole packed stack. Not counted: parameters, a loop's or a
+    branch's name for one of its operands (`get-tuple-element`, which moves
+    nothing: the grouped experts' waves carry the stacks into their loop by
+    reference, models/transformer._grouped_experts), and the compiler's own
     prefetches into VMEM (`S(1)` results of copy-start/-done and of the
     `ConcatBitcast` custom call of a sliced prefetch), which read a weight
     once in place of the kernel's read — a stack, or a dense weight of an
@@ -264,7 +268,8 @@ def expert_sized_results(compiled_text: str, spec: ModelSpec) -> list[str]:
     found = []
     for ln in compiled_text.splitlines():
         m = re.match(r"\s*(?:ROOT )?\S+ = u8(\[[\d,]*\])(\S*) ([\w-]+)\(", ln)
-        if m and m.group(1) in shapes and m.group(3) != "parameter" and not (
+        if m and m.group(1) in shapes and m.group(3) not in (
+                "parameter", "get-tuple-element") and not (
                 m.group(3) in prefetch and "S(1)" in m.group(2)):
             found.append(ln.strip())
     return found
