@@ -81,6 +81,18 @@ _HYBRID_KEYS = {
     "lin_heads": 31, "lin_k_head_dim": 32, "lin_v_head_dim": 33,
     "lin_conv_width": 34, "lin_beta_scale": 35,
 }
+# GRANITE_HYBRID's header keys, likewise: rms_eps and the held share (keys
+# 20-22 and 24, as above), the SSM layer's sizes, the four published
+# multipliers (floats, by their bits), and the layer kinds as data.
+_SSM_KEYS = {
+    "ssm_heads": 36, "ssm_head_dim": 37, "ssm_d_state": 38, "ssm_groups": 39,
+    "ssm_conv_width": 40, "ssm_conv_bias": 41, "embedding_scale": 42,
+    "residual_scale": 43, "attn_scale": 44, "logit_scale": 45,
+}
+_SSM_SHARED_KEYS = ("n_shared_experts", "n_routed_experts", "expert_offset",
+                    "rms_eps")
+_FLOAT_KEYS = _MLA_FLOAT_KEYS | frozenset((
+    "embedding_scale", "residual_scale", "attn_scale", "logit_scale"))
 _MIXER_KEY0 = 1000
 
 
@@ -130,6 +142,9 @@ def model_tensor_plan(spec: ModelSpec) -> Iterator[tuple[str, tuple[int, ...], F
         return
     if spec.arch == ArchType.OLMO_HYBRID:
         yield from _hybrid_tensor_plan(spec)
+        return
+    if spec.arch == ArchType.GRANITE_HYBRID:
+        yield from _granite_tensor_plan(spec)
         return
     yield "tok_emb", (spec.vocab_size, spec.dim), FloatType.F32
     for l in range(spec.n_layers):
@@ -241,6 +256,58 @@ def _hybrid_tensor_plan(spec: ModelSpec):
     yield "wcls", (spec.vocab_size, d), wt
 
 
+def _granite_tensor_plan(spec: ModelSpec):
+    """GRANITE_HYBRID's file order. An SSM layer: the input projection in
+    leaves whose rows tile, wz (the gate; d_inner rows), wx (d_inner), wbc
+    (B's G x N rows, then C's) and wdt (H rows), then wo (over d_inner),
+    then f32: conv_w (taps x [x ; B ; C] channels, tap j weighs the row
+    `taps - 1 - j` tokens back), conv_b (a channel; with ssm_conv_bias),
+    a_log, dt_bias and ssm_d (H: the decay, the step's bias and the skip)
+    and rms_o (d_inner, the gated output norm). An ATTENTION layer: wq wk
+    wv wo. Both: moe_router (router_width rows), the HELD experts' up gate
+    down, the shared expert's sh_w1 (gate) sh_w2 (down) sh_w3 (up) at
+    n_shared_experts x hidden_dim, then rms_att and rms_ffn, the norms on
+    the two sublayers' INPUTS."""
+    wt, d, hid = spec.weights_float_type, spec.dim, spec.hidden_dim
+    inner, h = spec.ssm_inner, spec.ssm_heads
+    yield "tok_emb", (spec.vocab_size, d), FloatType.F32
+    for l, kind in enumerate(spec.layer_kinds):
+        p = f"layers.{l}."
+        if kind == LayerKind.SSM:
+            yield p + "wz", (inner, d), wt
+            yield p + "wx", (inner, d), wt
+            yield p + "wbc", (2 * spec.ssm_groups * spec.ssm_d_state, d), wt
+            yield p + "wdt", (h, d), wt
+            yield p + "wo", (d, inner), wt
+            yield p + "conv_w", (spec.ssm_conv_width,
+                                 spec.ssm_conv_dim), FloatType.F32
+            if spec.ssm_conv_bias:
+                yield p + "conv_b", (spec.ssm_conv_dim,), FloatType.F32
+            yield p + "a_log", (h,), FloatType.F32
+            yield p + "dt_bias", (h,), FloatType.F32
+            yield p + "ssm_d", (h,), FloatType.F32
+            yield p + "rms_o", (inner,), FloatType.F32
+        else:
+            yield p + "wq", (d, d), wt
+            yield p + "wk", (spec.kv_dim, d), wt
+            yield p + "wv", (spec.kv_dim, d), wt
+            yield p + "wo", (d, d), wt
+        yield p + "moe_router", (spec.router_width, d), wt
+        for e in range(spec.n_experts):
+            yield p + f"experts.{e}.up", (hid, d), wt
+            yield p + f"experts.{e}.gate", (hid, d), wt
+            yield p + f"experts.{e}.down", (d, hid), wt
+        if spec.n_shared_experts:
+            sh = spec.n_shared_experts * hid
+            yield p + "sh_w1", (sh, d), wt
+            yield p + "sh_w2", (d, sh), wt
+            yield p + "sh_w3", (sh, d), wt
+        yield p + "rms_att", (d,), FloatType.F32
+        yield p + "rms_ffn", (d,), FloatType.F32
+    yield "rms_final", (d,), FloatType.F32
+    yield "wcls", (spec.vocab_size, d), wt
+
+
 def _tensor_bytes(shape: tuple[int, ...], ftype: FloatType) -> int:
     n = shape[-1]
     d = int(np.prod(shape[:-1])) if len(shape) > 1 else 1
@@ -268,7 +335,8 @@ def read_spec(path: str, weights_float_type: FloatType | None = None) -> ModelSp
             data = f.read(header_size - 8)
             n_kv = len(data) // 8
             inv = {v: k for k, v in
-                   {**_KEYS, **_MLA_KEYS, **_HYBRID_KEYS}.items()}
+                   {**_KEYS, **_MLA_KEYS, **_HYBRID_KEYS,
+                    **_SSM_KEYS}.items()}
             mixers: dict[int, int] = {}
             for i in range(n_kv):
                 k, v = struct.unpack_from("<ii", data, i * 8)
@@ -310,8 +378,9 @@ def read_spec(path: str, weights_float_type: FloatType | None = None) -> ModelSp
         rope_theta=rope_theta,
         weights_float_type=wt,
         version=version,
-        **{k: (_bits_f32(fields[k]) if k in _MLA_FLOAT_KEYS else fields[k])
-           for k in (*_MLA_KEYS, *_HYBRID_KEYS, "mixers") if k in fields},
+        **{k: (_bits_f32(fields[k]) if k in _FLOAT_KEYS else fields[k])
+           for k in (*_MLA_KEYS, *_HYBRID_KEYS, *_SSM_KEYS, "mixers")
+           if k in fields},
     )
     spec.validate()
     object.__setattr__(spec, "_header_size", header_size)
@@ -409,7 +478,14 @@ def write_header(f, spec: ModelSpec) -> None:
                             _f32_bits(spec.rms_eps))
         for key, k in _HYBRID_KEYS.items():
             data += struct.pack("<ii", k, getattr(spec, key))
-        for l, kind in enumerate(spec.layer_kinds):
+    if spec.arch == ArchType.GRANITE_HYBRID:
+        keys = {**{k: _MLA_KEYS[k] for k in _SSM_SHARED_KEYS}, **_SSM_KEYS}
+        for key, k in keys.items():
+            value = getattr(spec, key)
+            data += struct.pack("<ii", k, _f32_bits(value)
+                                if key in _FLOAT_KEYS else value)
+    if spec.arch in (ArchType.OLMO_HYBRID, ArchType.GRANITE_HYBRID):
+        for l, kind in enumerate(spec.layer_kinds):   # the kinds as data
             data += struct.pack("<ii", _MIXER_KEY0 + l, int(kind))
     f.write(struct.pack("<i", MAGIC_KV))
     f.write(struct.pack("<i", 8 + len(data)))

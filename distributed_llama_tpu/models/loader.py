@@ -352,7 +352,15 @@ def _fuse_group(key: str) -> str | None:
         return "wqkv"
     if key in ("w1", "w3"):
         return "w13"
+    if key in ("wz", "wx"):
+        return "wzx"
     return None
+
+
+# thin projections kept as ONE dense leaf of the compute dtype, their file
+# tensors' rows in file order (models/params.load_params): the DELTA
+# layer's decay and beta rows, the SSM layer's B | C and dt rows
+_DENSE_PAIRS = {"wa": "w_ab", "wb": "w_ab", "wbc": "w_bcdt", "wdt": "w_bcdt"}
 
 
 def _concat_host(ts: list[HostTensor], mode: str) -> list[HostTensor]:
@@ -490,17 +498,16 @@ def load_params_streamed(
                 live -= sum(_host_bytes(x) for x in ts)
             continue
 
-        if key in ("wa", "wb"):
-            # decay and beta rows: ONE dense (2H, d) leaf, wa's rows first
-            # (models/params.load_params)
-            gk = f"{t.name.rsplit('.', 1)[0]}.w_ab"
+        if key in _DENSE_PAIRS:
+            leaf = _DENSE_PAIRS[key]
+            gk = f"{t.name.rsplit('.', 1)[0]}.{leaf}"
             pending.setdefault(gk, []).append(t)
             if len(pending[gk]) == 2:
                 ts = pending.pop(gk)
-                arr = placer.dense("w_ab", np.concatenate(
+                arr = placer.dense(leaf, np.concatenate(
                     [x.to_f32() for x in ts]))
-                dest["w_ab"] = (arr.astype(dtype) if dtype != jnp.float32
-                                else arr)
+                dest[leaf] = (arr.astype(dtype) if dtype != jnp.float32
+                              else arr)
                 live -= sum(_host_bytes(x) for x in ts)
             continue
 
@@ -516,7 +523,7 @@ def load_params_streamed(
                 dest[name] = arr.astype(dtype) if dtype != jnp.float32 else arr
         elif key in ("rms_att", "rms_ffn", "rms_moe", "rms_ffn2", "rms_final",
                      "rms_kv", "moe_bias", "rms_q", "rms_k", "rms_o",
-                     "conv_w", "a_log", "dt_bias"):
+                     "conv_w", "a_log", "dt_bias", "conv_b", "ssm_d"):
             if stage is not None:  # per-layer norms stack too, kept f32
                 pp_stack.add(dest, key, stage, "dense", dtype, [t],
                              keep_f32=True)

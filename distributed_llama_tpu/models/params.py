@@ -108,6 +108,18 @@ def load_params(
             for w in ("conv_w", "a_log", "dt_bias", "rms_o"):
                 lw[w] = dev(f"layers.{l}.{w}",
                             tensors[f"layers.{l}.{w}"].to_f32())
+        elif spec.layer_kinds[l] == LayerKind.SSM:
+            for w in ("wz", "wx", "wo"):
+                lw[w] = weight(tensors[f"layers.{l}.{w}"], f"layers.{l}.{w}")
+            # B | C and dt rows: thin projections, one dense leaf
+            lw["w_bcdt"] = dev(f"layers.{l}.w_bcdt", np.concatenate(
+                [tensors[f"layers.{l}.{w}"].to_f32()
+                 for w in ("wbc", "wdt")]).astype(dtype))
+            for w in ("conv_w", "conv_b", "a_log", "dt_bias", "ssm_d",
+                      "rms_o"):
+                if f"layers.{l}.{w}" in tensors:    # conv_b: with a bias
+                    lw[w] = dev(f"layers.{l}.{w}",
+                                tensors[f"layers.{l}.{w}"].to_f32())
         elif spec.is_mla:
             lw["rms_kv"] = dev(f"layers.{l}.rms_kv",
                                tensors[f"layers.{l}.rms_kv"].to_f32())
@@ -129,10 +141,10 @@ def load_params(
             if spec.is_mla:
                 lw["moe_bias"] = dev(f"layers.{l}.moe_bias",
                                      tensors[f"layers.{l}.moe_bias"].to_f32())
-                if spec.n_shared_experts:
-                    for w in ("sh_w1", "sh_w2", "sh_w3"):
-                        lw[w] = weight(tensors[f"layers.{l}.{w}"],
-                                       f"layers.{l}.{w}")
+            if spec.n_shared_experts:
+                for w in ("sh_w1", "sh_w2", "sh_w3"):
+                    lw[w] = weight(tensors[f"layers.{l}.{w}"],
+                                   f"layers.{l}.{w}")
             lw["moe_router"] = dev(
                 f"layers.{l}.moe_router",
                 tensors[f"layers.{l}.moe_router"].to_f32().astype(dtype))
@@ -175,6 +187,8 @@ def fuse_layer_weights(params: dict) -> dict:
             lw["wqkv"] = _concat_weights([lw.pop("wq"), lw.pop("wk"), lw.pop("wv")])
         if "w1" in lw:
             lw["w13"] = _concat_weights([lw.pop("w1"), lw.pop("w3")])
+        if "wz" in lw:     # an SSM layer's gate and x: one call, 2x the grid
+            lw["wzx"] = _concat_weights([lw.pop("wz"), lw.pop("wx")])
     return params
 
 

@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import dataclasses
 import enum
+import math
 
 from ..quants.types import FloatType
 
@@ -27,17 +28,29 @@ class ArchType(enum.IntEnum):
     # convolution) beside full-attention layers without rotation, the
     # norms on each sublayer's OUTPUT; not a reference-engine architecture
     OLMO_HYBRID = 0xABCD04
+    # state-space (Mamba-2) layers beside full-attention layers without
+    # rotation, softmax-routed experts and a shared expert in EVERY layer
+    # under a pre-norm block, four published multipliers (embedding,
+    # residual, attention, logits); not a reference-engine architecture
+    GRANITE_HYBRID = 0xABCD05
 
 
 class LayerKind(enum.IntEnum):
     """What a layer's mixer is, and so what a slot remembers in it: K/V
     rows (ATTENTION), one latent row (LATENT), or a recurrent state and
-    the convolution's tail (DELTA). The per-layer description every cache
-    maker, loader plan, forward and byte ledger reads."""
+    the convolution's tail (DELTA: the gated delta rule; SSM: a Mamba-2
+    state-space mixer). The per-layer description every cache maker,
+    loader plan, forward and byte ledger reads."""
 
     ATTENTION = 0
     LATENT = 1
     DELTA = 2
+    SSM = 3
+
+    @property
+    def has_state(self) -> bool:
+        """A slot holds a state and a tail here, not rows of a cache."""
+        return self in (LayerKind.DELTA, LayerKind.SSM)
 
 
 # What assumes ROWS of a cache and cannot hold a recurrent state yet. Each is
@@ -46,7 +59,7 @@ class LayerKind(enum.IntEnum):
 # SARVAM_MLA is refused under tp. CHANGES.md (PR 34) says what lifts each.
 STATE_REFUSALS = {
     "prefix_cache": "--prefix-cache: a cached prefix of this model is a "
-                    "snapshot of every DELTA layer's state at a block "
+                    "snapshot of every state layer's state at a block "
                     "boundary, and the arena holds rows of a cache only",
     "speculation": "--draft / --lookup-decode: a verify step takes a "
                    "rejected draft back by position, and a recurrent state "
@@ -111,6 +124,19 @@ class ModelSpec:
     lin_v_head_dim: int = 0        # d_v: v, gate and output of a head
     lin_conv_width: int = 0        # taps of the causal depthwise convolution
     lin_beta_scale: int = 1        # 2: beta in (0, 2), negative eigenvalues
+    # -- GRANITE_HYBRID only (header keys of their own) --------------------
+    ssm_heads: int = 0             # H: heads of an SSM layer
+    ssm_head_dim: int = 0          # P: d_inner = H x P
+    ssm_d_state: int = 0           # N: a head's state is (P, N) float32
+    ssm_groups: int = 0            # G: B and C are shared by H / G heads
+    ssm_conv_width: int = 0        # taps of the causal depthwise convolution
+    ssm_conv_bias: int = 0         # 1: the convolution adds a bias a channel
+    # published multipliers, data and not `if arch ==` in forward; 1 (or,
+    # for the softmax scale, 0) leaves the program's text as it was
+    embedding_scale: float = 1.0   # x0 = scale x E[token]
+    residual_scale: float = 1.0    # on each sublayer's output, before the add
+    attn_scale: float = 0.0        # softmax scale; 0: head_size ** -0.5
+    logit_scale: float = 1.0       # on the head's output
 
     @property
     def layer_kinds(self) -> tuple:
@@ -129,15 +155,14 @@ class ModelSpec:
         seen: dict = {}
         out = []
         for kind in self.layer_kinds:
-            rows = kind != LayerKind.DELTA
-            out.append(seen.get(rows, 0))
-            seen[rows] = out[-1] + 1
+            out.append(seen.get(kind.has_state, 0))
+            seen[kind.has_state] = out[-1] + 1
         return tuple(out)
 
     @property
     def n_cache_layers(self) -> int:
-        """Layers that keep ROWS of a cache (all but the DELTA ones)."""
-        return sum(k != LayerKind.DELTA for k in self.layer_kinds)
+        """Layers that keep ROWS of a cache (all but the state layers)."""
+        return sum(not k.has_state for k in self.layer_kinds)
 
     @property
     def n_state_layers(self) -> int:
@@ -173,15 +198,41 @@ class ModelSpec:
         return self.lin_heads * (2 * self.lin_k_head_dim
                                  + self.lin_v_head_dim)
 
+    @property
+    def ssm_inner(self) -> int:
+        """d_inner: the width of an SSM layer's x, gate and output."""
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Channels of an SSM layer's convolution: [x ; B ; C]."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_d_state
+
+    def state_leaves(self, kind: LayerKind) -> tuple:
+        """What a slot keeps in ONE layer of a kind that has a state: the
+        float32 state's shape a slot, and the (rows, channels) of the
+        convolution's tail. The one statement KVCache.create and the byte
+        ledgers (state_bytes_per_slot, so /stats and the profiler) read."""
+        if kind == LayerKind.DELTA:
+            return ((self.lin_heads, self.lin_k_head_dim,
+                     self.lin_v_head_dim),
+                    (max(self.lin_conv_width - 1, 0), self.lin_conv_dim))
+        assert kind == LayerKind.SSM, kind
+        return ((self.ssm_heads, self.ssm_head_dim, self.ssm_d_state),
+                (max(self.ssm_conv_width - 1, 0), self.ssm_conv_dim))
+
     def state_bytes_per_slot(self, cache_itemsize: int) -> int:
-        """Bytes a slot holds over all DELTA layers, whatever its context:
+        """Bytes a slot holds over all state layers, whatever its context:
         the float32 state and the convolution's tail, which is kept in the
         cache dtype but never narrower than bf16 (the ONE statement of that
         rule: KVCache.create asks `tail_itemsize`)."""
-        n = self.n_state_layers
-        state = n * self.lin_heads * self.lin_k_head_dim * self.lin_v_head_dim
-        tail = n * max(self.lin_conv_width - 1, 0) * self.lin_conv_dim
-        return state * 4 + tail * self.tail_itemsize(cache_itemsize)
+        total = 0
+        for kind in self.layer_kinds:
+            if kind.has_state:
+                state, tail = self.state_leaves(kind)
+                total += (math.prod(state) * 4 + math.prod(tail)
+                          * self.tail_itemsize(cache_itemsize))
+        return total
 
     @staticmethod
     def tail_itemsize(cache_itemsize: int) -> int:
@@ -255,26 +306,34 @@ class ModelSpec:
     def validate(self) -> None:
         assert self.dim % self.n_heads == 0
         assert (self.dim * self.n_kv_heads) % self.n_heads == 0
+        if self.is_moe:
+            # the held experts, all the router's or a share of a wider one
+            # (whatever the architecture)
+            assert (self.expert_offset + self.n_experts
+                    <= self.router_width), "held experts outside the router"
+            assert 0 < self.n_active_experts <= self.router_width
         if self.is_mla:
             assert self.n_kv_heads == 1, "the latent cache has one head"
             assert min(self.kv_lora_rank, self.qk_nope_head_dim,
                        self.qk_rope_head_dim, self.v_head_dim) > 0
             assert self.qk_rope_head_dim % 2 == 0
             assert self.n_dense_layers == 0 or self.dense_hidden_dim > 0
-            assert (self.expert_offset + self.n_experts
-                    <= self.router_width), "held experts outside the router"
-            assert self.n_active_experts <= self.router_width
             return
         if self.mixers:
             assert len(self.mixers) == self.n_layers, "one kind a layer"
-        if self.has_state:
+        kinds = set(self.layer_kinds)
+        if LayerKind.DELTA in kinds:
             assert min(self.lin_heads, self.lin_k_head_dim,
                        self.lin_v_head_dim) > 0
             assert self.lin_conv_width >= 2
             assert self.lin_beta_scale in (1, 2)
+        if LayerKind.SSM in kinds:
+            assert min(self.ssm_heads, self.ssm_head_dim, self.ssm_d_state,
+                       self.ssm_groups) > 0
+            assert self.ssm_heads % self.ssm_groups == 0
+            assert self.ssm_conv_width >= 2
+            assert self.ssm_conv_bias in (0, 1)
         if self.arch in (ArchType.GROK1, ArchType.MIXTRAL):
             # MoE archs without experts would fail deep inside the forward
             # (missing moe_router); reject at spec level instead
             assert self.is_moe, f"{self.arch.name} requires n_experts > 0"
-        if self.is_moe:
-            assert 0 < self.n_active_experts <= self.n_experts

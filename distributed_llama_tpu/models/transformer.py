@@ -1,5 +1,5 @@
 """Unified transformer forward for LLAMA / MIXTRAL / GROK1 / SARVAM_MLA /
-OLMO_HYBRID.
+OLMO_HYBRID / GRANITE_HYBRID.
 
 One jittable segment-forward covers both prefill (T tokens at once — net-new
 vs the reference, which feeds the prompt token-by-token) and decode (T=1).
@@ -100,17 +100,16 @@ class KVCache(NamedTuple):
         # narrower than bf16, whatever the rows are kept in
         item = jnp.dtype(dtype).itemsize
         tail_dtype = dtype if spec.tail_itemsize(item) == item else jnp.bfloat16
-        m = spec.n_state_layers
+        leaves = [spec.state_leaves(k) for k in spec.layer_kinds
+                  if k.has_state]
         return cls(
             tuple(jnp.zeros(shape, dtype) for _ in range(n)),
             tuple(jnp.zeros(shape, dtype) for _ in range(n))
             if spec.cache_v_head_size else (),
-            tuple(jnp.zeros((batch, spec.lin_heads, spec.lin_k_head_dim,
-                             spec.lin_v_head_dim), jnp.float32)
-                  for _ in range(m)),
-            tuple(jnp.zeros((batch, spec.lin_conv_width - 1,
-                             spec.lin_conv_dim), tail_dtype)
-                  for _ in range(m)),
+            tuple(jnp.zeros((batch,) + state, jnp.float32)
+                  for state, _ in leaves),
+            tuple(jnp.zeros((batch,) + tail, tail_dtype)
+                  for _, tail in leaves),
         )
 
 
@@ -273,7 +272,7 @@ def _attention_block(x, lw, spec: ModelSpec, k_cache, v_cache, q_pos, cfg,
     if spec.post_norm:
         xb = x          # the norm sits on the sublayer's OUTPUT (_layer)
     else:
-        xb = rmsnorm(x, lw["rms_att"])  # ref: llama2-tasks.cpp:10-21
+        xb = rmsnorm(x, lw["rms_att"], spec.norm_eps)  # ref: llama2-tasks.cpp:10-21
     if "wqkv" in lw:
         # fused QKV projection (single-shard path): one kernel call, one
         # shared activation prep, deeper DMA pipeline
@@ -296,6 +295,9 @@ def _attention_block(x, lw, spec: ModelSpec, k_cache, v_cache, q_pos, cfg,
         # model's attention through its recurrent layers)
         q = apply_rope(q, q_pos, spec.rope_theta, spec.arch)
         k = apply_rope(k, q_pos, spec.rope_theta, spec.arch)
+    # a published softmax scale other than head^-1/2 (None: that one). The
+    # sp and tp paths below take none; a model that has one is refused there
+    scale = spec.attn_scale or None
 
     # functional cache update at positions q_pos (contiguous per row:
     # pos[b]..pos[b]+T); cache is head-major (B, KVH, S, hs) — see KVCache
@@ -318,6 +320,7 @@ def _attention_block(x, lw, spec: ModelSpec, k_cache, v_cache, q_pos, cfg,
         local = jnp.where(local < 0, s_local, local)
         k_cache, v_cache = _scatter_cache_write(k_cache, v_cache, k, v,
                                                 local, write_gate)
+        assert scale is None, "a softmax scale of its own under manual sp"
         att = sp_cache_attention_local(q, k_cache, v_cache, q_pos)
         out = matmul(att.reshape(b, t, h * hs), lw["wo"], **cfg)
         return out, k_cache, v_cache
@@ -363,6 +366,9 @@ def _attention_block(x, lw, spec: ModelSpec, k_cache, v_cache, q_pos, cfg,
         k_cache = jax.lax.with_sharding_constraint(k_cache, cs)
         v_cache = jax.lax.with_sharding_constraint(v_cache, cs)
 
+    assert scale is None or (sp_mesh is None and sp_cache_mesh is None
+                             and cfg.get("tp_mesh") is None), (
+        "a softmax scale of its own under sp or tp")
     if sp_mesh is not None:
         # sequence-parallel prefill: the segment starts at pos 0 and IS the
         # whole context so far, so attention runs q-chunk vs ring-rotating
@@ -390,7 +396,7 @@ def _attention_block(x, lw, spec: ModelSpec, k_cache, v_cache, q_pos, cfg,
 
             att = flash_attention(
                 q, k_cache, v_cache, q_pos,
-                interpret=cfg.get("pallas_interpret", False))
+                interpret=cfg.get("pallas_interpret", False), scale=scale)
         elif cfg.get("tp_mesh") is not None:
             # multi-device mesh: GSPMD can't partition a pallas_call, so the
             # kernel runs per-shard inside shard_map (dp on batch, tp on
@@ -405,9 +411,10 @@ def _attention_block(x, lw, spec: ModelSpec, k_cache, v_cache, q_pos, cfg,
 
             att = flash_attention(
                 q, k_cache, v_cache, q_pos,
-                interpret=cfg.get("pallas_interpret", False))
+                interpret=cfg.get("pallas_interpret", False), scale=scale)
     else:
-        att = decode_attention(q, k_cache, v_cache, q_pos)  # (B, T, H, hs)
+        att = decode_attention(q, k_cache, v_cache, q_pos,
+                               scale=scale)                # (B, T, H, hs)
     out = matmul(att.reshape(b, t, h * hs), lw["wo"], **cfg)
     return out, k_cache, v_cache
 
@@ -422,6 +429,26 @@ class SegmentRows(NamedTuple):
 
     n_valid: jnp.ndarray
     fresh: jnp.ndarray
+
+
+def _short_conv(xin, tail, lw, rows: SegmentRows, taps: int):
+    """The causal depthwise convolution of a state layer's mixer, then SiLU:
+    row t of the output sees rows t .. t + taps - 1 of [tail ; xin] (plus
+    the layer's conv_b where it has one); the new tail is the last taps - 1
+    rows that COUNT. Returns (float32 output, new tail)."""
+    t = xin.shape[1]
+    tail = jnp.where(rows.fresh[:, None, None], 0, tail)
+    xcat = jnp.concatenate([tail.astype(xin.dtype), xin], axis=1)
+    conv_w = lw["conv_w"]
+    y = sum(conv_w[j] * xcat[:, j:j + t].astype(jnp.float32)
+            for j in range(taps))
+    if "conv_b" in lw:
+        y = y + lw["conv_b"]
+    y = jax.nn.silu(y)
+    tail = jax.vmap(
+        lambda xc, n: lax.dynamic_slice_in_dim(xc, n, taps - 1, 0))(
+            xcat, rows.n_valid).astype(tail.dtype)
+    return y, tail
 
 
 def _delta_block(x, lw, spec: ModelSpec, state, tail, rows: SegmentRows,
@@ -444,17 +471,7 @@ def _delta_block(x, lw, spec: ModelSpec, state, tail, rows: SegmentRows,
         z = matmul(x, lw["wg"], **cfg)
         ab = matmul(x, lw["w_ab"], **cfg).astype(f32)      # (B, T, 2H)
     with jax.named_scope("gdn_conv"):
-        # row t of the output sees rows t .. t + taps - 1 of [tail ; qkv];
-        # the new tail is the last taps - 1 rows that COUNT
-        tail = jnp.where(rows.fresh[:, None, None], 0, tail)
-        xcat = jnp.concatenate([tail.astype(qkv.dtype), qkv], axis=1)
-        conv_w = lw["conv_w"]
-        y = sum(conv_w[j] * xcat[:, j:j + t].astype(f32)
-                for j in range(taps))
-        y = jax.nn.silu(y)
-        tail = jax.vmap(
-            lambda xc, n: lax.dynamic_slice_in_dim(xc, n, taps - 1, 0))(
-                xcat, rows.n_valid).astype(tail.dtype)
+        y, tail = _short_conv(qkv, tail, lw, rows, taps)
     q = y[..., :h * dk].reshape(b, t, h, dk)
     k = y[..., h * dk:2 * h * dk].reshape(b, t, h, dk)
     v = y[..., 2 * h * dk:].reshape(b, t, h, dv)
@@ -478,6 +495,73 @@ def _delta_block(x, lw, spec: ModelSpec, state, tail, rows: SegmentRows,
     return out, state, tail
 
 
+def _ssm_block(x, lw, spec: ModelSpec, state, tail, rows: SegmentRows, cfg):
+    """State-space (Mamba-2) mixer under a norm on its INPUT: projections
+    [z ; x ; B | C | dt] -> causal depthwise convolution (+ bias) and SiLU
+    on [x ; B ; C] -> the SSD recurrence, B and C shared by a group's heads
+    -> the skip D x -> gate, THEN one RMS norm over d_inner -> output
+    projection. Returns (the wo projection, not yet scaled or added to the
+    residual, new state, new tail): _delta_block's contract."""
+    from ..ops.pallas_ssd import ssd_scan
+
+    b, t, _ = x.shape
+    h, p, n = spec.ssm_heads, spec.ssm_head_dim, spec.ssm_d_state
+    inner, gn = spec.ssm_inner, spec.ssm_groups * spec.ssm_d_state
+    f32 = jnp.float32
+    u = rmsnorm(x, lw["rms_att"], spec.norm_eps)
+    with jax.named_scope("ssm_proj"):
+        if "wzx" in lw:
+            zx = matmul(u, lw["wzx"], **cfg)
+            z, xs = zx[..., :inner], zx[..., inner:]
+        else:
+            z, xs = matmul(u, lw["wz"], **cfg), matmul(u, lw["wx"], **cfg)
+        bcdt = matmul(u, lw["w_bcdt"], **cfg)              # (B, T, 2GN + H)
+    with jax.named_scope("ssm_conv"):
+        y, tail = _short_conv(
+            jnp.concatenate([xs, bcdt[..., :2 * gn]], axis=-1), tail, lw,
+            rows, spec.ssm_conv_width)
+    xh = y[..., :inner].reshape(b, t, h, p)
+    with jax.named_scope("ssm_scan"):
+        dt = jax.nn.softplus(bcdt[..., 2 * gn:].astype(f32) + lw["dt_bias"])
+        o, state = ssd_scan(
+            xh, dt, -jnp.exp(lw["a_log"]),
+            y[..., inner:inner + gn].reshape(b, t, spec.ssm_groups, n),
+            y[..., inner + gn:].reshape(b, t, spec.ssm_groups, n),
+            state, rows.n_valid, rows.fresh,
+            use_pallas=bool(cfg.get("use_pallas")),
+            interpret=cfg.get("pallas_interpret", False))
+        o = o + lw["ssm_d"][:, None] * xh
+    with jax.named_scope("ssm_out"):
+        o = o.reshape(b, t, inner) * jax.nn.silu(z.astype(f32))
+        out = matmul(rmsnorm(o, lw["rms_o"], spec.norm_eps).astype(x.dtype),
+                     lw["wo"], **cfg)
+    return out, state, tail
+
+
+# a state layer's mixer by its kind; both keep _delta_block's contract
+_STATE_MIXERS = {LayerKind.DELTA: _delta_block, LayerKind.SSM: _ssm_block}
+
+
+def _scaled(out, spec: ModelSpec):
+    """A sublayer's output times the published residual multiplier (1: the
+    output itself, and nothing enters the program)."""
+    if spec.residual_scale == 1.0:
+        return out
+    return out * jnp.asarray(spec.residual_scale, out.dtype)
+
+
+def _pre_norm_tail(x, mix_out, lw, spec: ModelSpec, cfg, n_valid=None,
+                   moe_counts=None):
+    """h = x + r mixer(norm(x)) (the mixer normed its own input); x' = h +
+    r ffn(norm(h)), the FFN dense or, where the layer has a router, the
+    routed experts (and the shared one, inside _moe_ffn)."""
+    x = x + _scaled(mix_out, spec).astype(x.dtype)
+    xb = rmsnorm(x, lw["rms_ffn"], spec.norm_eps)
+    ffn = (_moe_ffn(xb, lw, spec, cfg, n_valid, moe_counts)
+           if "moe_router" in lw else _dense_ffn(xb, lw, spec, cfg))
+    return x + _scaled(ffn, spec).astype(x.dtype)
+
+
 def _post_norm_tail(x, mix_out, lw, spec: ModelSpec, cfg):
     """h = x + norm(mixer(x)); x' = h + norm(ffn(h)): both norms on the
     sublayers' outputs."""
@@ -487,9 +571,17 @@ def _post_norm_tail(x, mix_out, lw, spec: ModelSpec, cfg):
     return x + rmsnorm(ffn, lw["rms_ffn"], eps).astype(x.dtype)
 
 
-def _delta_layer(x, lw, spec: ModelSpec, state, tail, rows, cfg):
-    mix_out, state, tail = _delta_block(x, lw, spec, state, tail, rows, cfg)
-    return _post_norm_tail(x, mix_out, lw, spec, cfg), state, tail
+def _state_layer(x, lw, spec: ModelSpec, kind: LayerKind, state, tail, rows,
+                 cfg, moe_counts=None):
+    """A layer that keeps a state: the mixer by the layer's KIND, the block
+    around it (norm placement, FFN kind, residual scale) by the spec, the
+    two chosen apart."""
+    mix_out, state, tail = _STATE_MIXERS[kind](x, lw, spec, state, tail,
+                                               rows, cfg)
+    if spec.post_norm:
+        return _post_norm_tail(x, mix_out, lw, spec, cfg), state, tail
+    return (_pre_norm_tail(x, mix_out, lw, spec, cfg, rows.n_valid,
+                           moe_counts), state, tail)
 
 
 def _dense_ffn(xb, lw, spec: ModelSpec, cfg):
@@ -818,17 +910,13 @@ def _layer(x, lw, spec: ModelSpec, k_cache, v_cache, q_pos, cfg, sp_mesh=None,
         xb = rmsnorm(x, lw["rms_moe"])          # ref: grok1-tasks.cpp:43-54
         moe_out = _moe_ffn(xb, lw, spec, cfg, n_valid, moe_counts)
         moe_out = rmsnorm(moe_out, lw["rms_ffn2"])  # ref: grok1-tasks.cpp:244-256
-        x = x + moe_out.astype(x.dtype)
-    elif spec.arch == ArchType.MIXTRAL:
-        x = x + attn_out.astype(x.dtype)        # ref: mixtral-tasks.cpp:24
-        xb = rmsnorm(x, lw["rms_ffn"])
-        x = x + _moe_ffn(xb, lw, spec, cfg, n_valid,
-                         moe_counts).astype(x.dtype)
-    else:
-        x = x + attn_out.astype(x.dtype)        # ref: llama2-tasks.cpp:125-131
-        xb = rmsnorm(x, lw["rms_ffn"])
-        x = x + _dense_ffn(xb, lw, spec, cfg).astype(x.dtype)
-    return x, k_cache, v_cache
+        return x + moe_out.astype(x.dtype), k_cache, v_cache
+    # LLAMA (dense; ref: llama2-tasks.cpp:125-131), MIXTRAL (experts; ref:
+    # mixtral-tasks.cpp:24), GRANITE_HYBRID (experts, a shared one, the
+    # residual multiplier): one pre-norm serial block, told apart by what
+    # the layer holds and the spec says
+    return (_pre_norm_tail(x, attn_out, lw, spec, cfg, n_valid, moe_counts),
+            k_cache, v_cache)
 
 
 def forward(
@@ -895,6 +983,8 @@ def forward(
         x = params["tok_emb"][tokens].astype(compute_dtype)  # ref: tasks.cpp:202-203
     if spec.arch == ArchType.GROK1:
         x = x * GROK_INPUT_SCALE
+    if spec.embedding_scale != 1.0:
+        x = x * jnp.asarray(spec.embedding_scale, x.dtype)
 
     s_all: list = []
     conv_all: list = []
@@ -935,10 +1025,10 @@ def forward(
                               logits_for_all)
                 if spec.has_state or spec.is_moe else None)
         for l in range(spec.n_layers):
-            if kinds[l] == LayerKind.DELTA:
-                x, s_new, c_new = _delta_layer(
-                    x, params["layers"][l], spec, cache.s[at[l]],
-                    cache.conv[at[l]], rows, cfg)
+            if kinds[l].has_state:
+                x, s_new, c_new = _state_layer(
+                    x, params["layers"][l], spec, kinds[l], cache.s[at[l]],
+                    cache.conv[at[l]], rows, cfg, moe_counts)
                 s_all.append(s_new)
                 conv_all.append(c_new)
                 continue
@@ -967,6 +1057,8 @@ def forward(
     logits = matmul(x, params["wcls"], **cfg).astype(jnp.float32)
     if spec.arch == ArchType.GROK1:
         logits = logits * GROK_LOGIT_SCALE  # ref: grok1-tasks.cpp:269-272
+    if spec.logit_scale != 1.0:
+        logits = logits * spec.logit_scale
     cache = KVCache(tuple(k_all), tuple(v_all), tuple(s_all), tuple(conv_all))
     if expert_counts:
         # (a pp region's layers, traced elsewhere, are not counted)

@@ -200,16 +200,18 @@ def flash_supported(t: int, h: int, kvh: int) -> bool:
     return t * (h // kvh) <= MAX_Q_ROWS
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "scale"))
 def flash_attention(
     q: jnp.ndarray,        # (B, T, H, hs) — rotated queries
     k_cache: jnp.ndarray,  # (B, KVH, S, hs)
     v_cache: jnp.ndarray,  # (B, KVH, S, hs)
     q_pos: jnp.ndarray,    # (B, T) absolute position of each query token
     interpret: bool = False,
+    scale: float | None = None,
 ) -> jnp.ndarray:
     """Causal attention of T query tokens against the cache; returns
-    (B, T, H, hs). Matches ops/attention.decode_attention semantics —
+    (B, T, H, hs). Matches ops/attention.decode_attention semantics,
+    `scale` too (None: head_size ** -0.5) —
     q_pos rows must be contiguous (pos0[b] + arange(T), which is how every
     engine path builds them — models/transformer.forward)."""
     b, t, h, hs = q.shape
@@ -244,7 +246,7 @@ def flash_attention(
     out = pl.pallas_call(
         functools.partial(
             _kernel, sb=sb, n_sb=n_sb, kvh=kvh, t=t, g=g,
-            scale=1.0 / (hs ** 0.5), out_dtype=q.dtype),
+            scale=scale or 1.0 / (hs ** 0.5), out_dtype=q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b * kvh, n_sb),

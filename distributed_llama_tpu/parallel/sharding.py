@@ -74,6 +74,13 @@ _SPLIT = {
     "rms_o": None,
     "rms_q": None,
     "rms_k": None,
+    # GRANITE_HYBRID (likewise)
+    "wz": None,
+    "wx": None,
+    "wzx": None,
+    "w_bcdt": None,
+    "conv_b": None,
+    "ssm_d": None,
 }
 
 

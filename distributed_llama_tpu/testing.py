@@ -67,6 +67,28 @@ def tiny_hybrid_spec(weights_float_type: FloatType = FloatType.Q40,
     return ModelSpec(**base)
 
 
+def tiny_granite_spec(weights_float_type: FloatType = FloatType.Q40,
+                      **overrides) -> ModelSpec:
+    """GRANITE_HYBRID at a size a CPU holds: two periods of (SSM x 2,
+    ATTENTION), 8 state-space heads of 16 over a state of 32 and a 4-tap
+    convolution with a bias, 4 query / 2 KV heads without rotation, in
+    every layer 4 held experts (of 8 routed over, top 4) and a shared
+    expert of twice an expert's width, the four multipliers away from 1."""
+    period = (LayerKind.SSM,) * 2 + (LayerKind.ATTENTION,)
+    base = dict(
+        arch=ArchType.GRANITE_HYBRID, dim=64, hidden_dim=32, n_layers=6,
+        n_heads=4, n_kv_heads=2, vocab_size=288, seq_len=160,
+        hidden_act=HiddenAct.SILU, rope_theta=0.0, n_experts=4,
+        n_active_experts=4, weights_float_type=weights_float_type,
+        n_shared_experts=2, n_routed_experts=8, expert_offset=0,
+        rms_eps=1e-5, mixers=tuple(int(k) for k in period * 2),
+        ssm_heads=8, ssm_head_dim=16, ssm_d_state=32, ssm_groups=1,
+        ssm_conv_width=4, ssm_conv_bias=1, embedding_scale=12.0,
+        residual_scale=0.22, attn_scale=0.0625, logit_scale=0.0625)
+    base.update(overrides)
+    return ModelSpec(**base)
+
+
 def free_port() -> int:
     """An OS-assigned free TCP port (shared by the cluster tests and the
     chaos harness spawners — one home for the bind-port-0 idiom)."""
@@ -139,7 +161,8 @@ def write_synthetic_model(path: str, spec: ModelSpec, seed: int) -> int:
 
     rng = np.random.default_rng(seed)
     hybrid = spec.arch == ArchType.OLMO_HYBRID
-    zero_mean = spec.is_mla or hybrid
+    granite = spec.arch == ArchType.GRANITE_HYBRID
+    zero_mean = spec.is_mla or hybrid or granite
     with open(path, "wb") as f:
         write_header(f, spec)
         for name, shape, ftype in model_tensor_plan(spec):
@@ -166,6 +189,10 @@ def write_synthetic_model(path: str, spec: ModelSpec, seed: int) -> int:
                     # a depthwise convolution's default: uniform within
                     # 1 / sqrt(taps)
                     x = rng.uniform(-0.5, 0.5, n).astype(np.float32)
+                elif name.endswith("ssm_d"):
+                    # the skip term's weight, initialised to ones as
+                    # published (conv_b keeps the small gaussian)
+                    x += 1.0
                 elif name.endswith("moe_bias"):
                     # a router with preferences (std 0.5 beside scores in
                     # (0, 1)): with a bias of 0.02 every token's eighth
@@ -184,11 +211,12 @@ def write_synthetic_model(path: str, spec: ModelSpec, seed: int) -> int:
                 scales = rng.uniform(
                     *((0.0035, 0.008) if spec.is_mla else (0.005, 0.02)),
                     nb)
-                if hybrid and name.endswith((".wa", ".wb")):
-                    # decay and beta rows: small, so that the drawn a_log
-                    # and dt_bias set a head's memory and beta stays
-                    # inside (0, 2) (at the other matrices' scale both
-                    # saturate on every token)
+                if ((hybrid and name.endswith((".wa", ".wb")))
+                        or (granite and name.endswith(".wdt"))):
+                    # decay and beta rows (the SSM layer's dt rows): small,
+                    # so that the drawn a_log and dt_bias set a head's
+                    # memory and beta stays inside (0, 2) (at the other
+                    # matrices' scale both saturate on every token)
                     scales *= HYBRID_DECAY_ROWS_SCALE
                 scales = scales.astype(np.float16)
                 raw[:, :2] = scales.reshape(nb, 1).view(np.uint8)

@@ -23,8 +23,14 @@ sys.path.insert(0, BTESTS)
 
 PURE = ("test_traffic", "test_workmodel", "test_window_metrics",
         "test_tracereduce", "test_manifest", "test_metrics",
-        "test_reference", "test_olmo_hybrid")
+        "test_reference", "test_olmo_hybrid", "test_granite_hybrid")
 BOOTS_A_SERVER = {"test_traced_rehearsal_reports_the_counter_metrics"}
+# pins the manifest at SIX cells with olmo's configuration last: a PR that
+# adds a cell cannot satisfy it and a model_config PR may edit no file under
+# benchmark/ (PR 39). Only that count is lost: test_granite_hybrid.py carries
+# every other assertion of it (the cells' order with the seventh, olmo's
+# published numbers, the delta_rule_* entries, the equal cell files)
+SUPERSEDED = {"test_the_manifest_has_six_cells_and_the_new_entries_come_last"}
 
 
 def _load(name):
@@ -40,5 +46,5 @@ _load("conftest")  # puts benchmark/ and the repository on sys.path
 for _module in PURE:
     for _name, _test in vars(_load(_module)).items():
         if (_name.startswith("test_") and callable(_test)
-                and _name not in BOOTS_A_SERVER):
+                and _name not in BOOTS_A_SERVER | SUPERSEDED):
             globals()[f"{_module}__{_name[5:]}"] = _test

@@ -359,6 +359,62 @@ def test_served_olmo_hybrid_step_programs_hold_their_kernels(topo, t):
         assert not r.cache_shaped_copies(text, leaf.shape)
 
 
+@pytest.mark.parametrize("t", [1, 32])
+def test_ssd_kernels_compile_at_published_widths(one_chip, t):
+    """ssd_decode (t = 1) and ssd_chunk at granite-4.0-h-small's sizes: 128
+    heads of 64 over a state of 128, one group, 8 rows; the state (8, 128,
+    64, 128) float32 donated and aliased."""
+    from distributed_llama_tpu.ops.pallas_ssd import ssd_scan
+
+    f32 = jnp.float32
+    args = [_struct(s, d, one_chip) for s, d in (
+        ((8, t, 128, 64), f32), ((8, t, 128), f32), ((128,), f32),
+        ((8, t, 1, 128), f32), ((8, t, 1, 128), f32),
+        ((8, 128, 64, 128), f32), ((8,), jnp.int32), ((8,), jnp.bool_))]
+    lowered = jax.jit(lambda *a: ssd_scan(*a, use_pallas=True),
+                      donate_argnums=5).lower(*args)
+    from distributed_llama_tpu.runtime.profiler import kernel_call_sites
+
+    mine = "ssd_decode" if t == 1 else "ssd_chunk"
+    assert kernel_call_sites(lowered.as_text()) == {mine: 1}
+    assert _has_kernel(lowered.compile())
+
+
+@pytest.mark.parametrize("t", [1, 32])
+def test_granite_hybrid_steps_compile_at_published_widths(topo, t):
+    """`granite-4.0-h-small-ep2`'s two step programs at published widths
+    (one SSM and one ATTENTION layer, each with its 36 held experts of 72
+    and the shared expert; B=8, S=8192, the Q80 round trip on, the
+    50176-row head): the scan runs in its kernel of that program, the
+    16384-row gate | x leaf and the 768-wide experts tile, and the program
+    holds no copy of a state or a cache leaf."""
+    import rehearse_chip_compile as r
+
+    from distributed_llama_tpu.runtime.profiler import kernel_call_sites
+
+    spec = dataclasses.replace(r.GRANITE_4_H_SMALL_EP2, n_layers=2,
+                               mixers=(3, 0))
+    fn, args = r.abstract_step(spec, topo.devices, batch=8, t=t,
+                               seq_len=8192, q80=True)
+    cache = args[-1]
+    assert (len(cache.k), len(cache.v), len(cache.s), len(cache.conv)) == (
+        1, 1, 1, 1)
+    assert cache.k[0].shape == (8, 8, 8192, 128)
+    assert cache.s[0].shape == (8, 128, 64, 128)
+    assert cache.conv[0].shape == (8, 3, 8448)
+    lowered = fn.lower(*args)
+    sites = kernel_call_sites(lowered.as_text())
+    mine, other = (("ssd_decode", "ssd_chunk") if t == 1
+                   else ("ssd_chunk", "ssd_decode"))
+    assert sites.get(mine, 0) >= 1 and other not in sites, sites
+    for k in ("flash_attention", "kv_cache_write", "q40_expert_matmul",
+              "q40_matmul"):
+        assert sites.get(k, 0) >= 1, sites
+    text = lowered.compile().as_text()
+    for leaf in (cache.k[0], cache.s[0]):
+        assert not r.cache_shaped_copies(text, leaf.shape)
+
+
 @pytest.mark.parametrize("b,t", [(8, 1), (8, 32)])
 def test_mla_attention_compiles(one_chip, b, t):
     from distributed_llama_tpu.ops.pallas_attention import mla_attention

@@ -486,11 +486,11 @@ def test_the_checks_controls_break_what_they_name(tiny, name, least):
 
     _, spec, params, tokens, want = tiny
     before = (dr.delta_rule, tr._segment_rows)
-    flags, change, patch = tool.controls()[name]
+    flags, change, param_change, patch = tool.controls({})[name]
     if name == "rows_fp8":
         assert flags == ["--cache-dtype", "f8"] and not change
         return
-    assert not flags
+    assert not flags and param_change is None
     with patch():
         got = slot_run(engine(dataclasses.replace(spec, **change), params),
                        tokens, 60, 8, row=1)

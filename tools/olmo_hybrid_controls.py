@@ -6,11 +6,14 @@ with ONE thing wrong. A control that reads `ok: true` is a fault the check
 cannot see.
 
     <chip tool> --chips 1 -- python tools/olmo_hybrid_controls.py \
-        [--model M --tokenizer T] [--only served rows_fp8 ...] [--out FILE]
+        [--model M --tokenizer T] [--only served rows_fp8 ...] \
+        [--seed-offsets 1 2 3] [--out FILE]
 
 Without --model the file is written first (the synth child's function, 49 s
-at real size). The weights are loaded and the reference computed once: the
-file and the check's tokens are the same for every control. Holds the chip.
+at real size). The weights are loaded once and the reference computed once a
+token seed (`weights_seed` + an offset; 1 is the run's own check): the file
+is the same for every control. Holds the chip. `run` is shared with
+tools/granite_hybrid_controls.py, which brings its own table.
 """
 
 from __future__ import annotations
@@ -46,8 +49,9 @@ def swapped(obj, name, value):
         setattr(obj, name, old)
 
 
-def controls():
-    """name -> (engine flags, spec change, context manager factory)."""
+def controls(cfg: dict) -> dict:
+    """name -> (engine flags, spec change, params change or None, context
+    manager factory)."""
     import jax
 
     import distributed_llama_tpu.models.transformer as tr
@@ -63,36 +67,51 @@ def controls():
         return o, jax.lax.reduce_precision(s, exponent_bits=8,
                                            mantissa_bits=7)
 
-    def rows_zeroed(spec, cache, pos0, b, t, li, lfa):
-        # every chunk program starts its rows from zeros
-        r = rows(spec, cache, pos0, b, t, li, lfa)
-        return tr.SegmentRows(r.n_valid,
-                              (r.n_valid > 0) if t > 1 else r.fresh)
-
-    def rows_pad(spec, cache, pos0, b, t, li, lfa):
-        # the pad tokens of the tail chunk advance the state
-        return rows(spec, cache, pos0, b, t, None, lfa)
-
     none = contextlib.nullcontext
     return {
-        "served": ([], {}, none),
-        "rows_fp8": (["--cache-dtype", "f8"], {}, none),
-        "state_bf16": ([], {}, lambda: swapped(dr, "delta_rule", rule_bf16)),
+        "served": ([], {}, None, none),
+        "rows_fp8": (["--cache-dtype", "f8"], {}, None, none),
+        "state_bf16": ([], {}, None,
+                       lambda: swapped(dr, "delta_rule", rule_bf16)),
         "state_zeroed_between_chunks":
-            ([], {}, lambda: swapped(tr, "_segment_rows", rows_zeroed)),
+            ([], {}, None,
+             lambda: swapped(tr, "_segment_rows", rows_zeroed(rows))),
         "pad_tokens_advance":
-            ([], {}, lambda: swapped(tr, "_segment_rows", rows_pad)),
-        "beta_without_2": ([], {"lin_beta_scale": 1}, none),
+            ([], {}, None,
+             lambda: swapped(tr, "_segment_rows", rows_pad(rows))),
+        "beta_without_2": ([], {"lin_beta_scale": 1}, None, none),
     }
 
 
-def main(argv=None) -> int:
-    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--config", default=os.path.join(
-        BENCH, "configs", "olmo-hybrid-7b.json"))
+def rows_zeroed(rows):
+    """_segment_rows whose every chunk program starts its rows from zeros."""
+    import distributed_llama_tpu.models.transformer as tr
+
+    def zeroed(spec, cache, pos0, b, t, li, lfa):
+        r = rows(spec, cache, pos0, b, t, li, lfa)
+        return tr.SegmentRows(r.n_valid,
+                              (r.n_valid > 0) if t > 1 else r.fresh)
+    return zeroed
+
+
+def rows_pad(rows):
+    """_segment_rows whose tail chunk's pad tokens advance the state."""
+    def pad(spec, cache, pos0, b, t, li, lfa):
+        return rows(spec, cache, pos0, b, t, None, lfa)
+    return pad
+
+
+def run(doc: str, config: str, controls, argv=None) -> int:
+    """Both tools' command: `controls(cfg)` is a tool's own table."""
+    ap = argparse.ArgumentParser(description=doc.split("\n\n")[0])
+    ap.add_argument("--config", default=os.path.join(BENCH, "configs",
+                                                     config + ".json"))
     ap.add_argument("--model")
     ap.add_argument("--tokenizer")
     ap.add_argument("--only", nargs="*")
+    ap.add_argument("--seed-offsets", type=int, nargs="+", default=[1],
+                    help="token seeds, as offsets from weights_seed (the "
+                         "run's own check uses 1)")
     ap.add_argument("--out")
     args = ap.parse_args(argv)
     with open(args.config) as f:
@@ -111,56 +130,70 @@ def main(argv=None) -> int:
 
     import importlib
 
+    import jax
     import jax.numpy as jnp
 
     import distributed_llama_tpu.apps.dllama as cli
     ref = importlib.import_module(cfg["reference"][:-3].replace("/", "."))
     forward, build, memo = ref.forward, cli.build_engine, {}
+    top_k = jax.lax.top_k
 
     def forward_once(path, tokens):
         key = (path, tokens.tobytes())
         if key not in memo:
-            memo[key] = forward(path, tokens)
+            with swapped(jax.lax, "top_k", top_k):   # never a control's
+                memo[key] = forward(path, tokens)
         return memo[key]
 
     def build_once(a):
         if "built" not in memo:
             memo["built"] = build(a)
         eng, tok, sampler = memo["built"]
-        over = memo["spec_change"]
+        spec_change, param_change = memo["change"]
         view = types.SimpleNamespace(**{k: getattr(eng, k)
                                         for k in BUILT_KEYS})
         view.cache_dtype = {"bf16": jnp.bfloat16, "f32": jnp.float32,
                             "f8": jnp.float8_e4m3fn}[a.cache_dtype]
-        view.spec = dataclasses.replace(eng.spec, **over)
+        view.spec = dataclasses.replace(eng.spec, **spec_change)
+        if param_change:
+            view.params = param_change(eng.params)
         return view, tok, sampler
 
     ref.forward, cli.build_engine = forward_once, build_once
     check = cfg.get("check", {})
-    payload = {"config": cfg, "model": args.model,
-               "tokenizer": args.tokenizer, "seed": cfg["weights_seed"] + 1,
-               "prompt_tokens": check.get("prompt_tokens", 100),
-               "decode_steps": check.get("decode_steps", 4)}
     out = {}
-    for name, (flags, spec_change, patch) in controls().items():
-        if args.only and name not in args.only:
-            continue
-        memo["spec_change"] = spec_change
-        with patch():
-            v = children.check(dict(payload, engine_flags=flags))
-        out[name] = {"ok": v["ok"], "worst_rel_l2": v["worst_rel_l2"],
-                     "tolerance": v["tolerance"],
-                     "rows": {r["position"]: r["rel_l2"] for r in v["rows"]}}
-        print(name, json.dumps(out[name]), flush=True)
-        gc.collect()
-    if args.out:
-        os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
-        with open(args.out, "w") as f:
-            json.dump(out, f, indent=1)
-    print(json.dumps({name: [v["ok"], round(v["worst_rel_l2"], 5)]
+    for offset in args.seed_offsets:
+        payload = {"config": cfg, "model": args.model,
+                   "tokenizer": args.tokenizer,
+                   "seed": cfg["weights_seed"] + offset,
+                   "prompt_tokens": check.get("prompt_tokens", 100),
+                   "decode_steps": check.get("decode_steps", 4)}
+        for name, (flags, spec_change, param_change,
+                   patch) in controls(cfg).items():
+            if args.only and name not in args.only:
+                continue
+            memo["change"] = (spec_change, param_change)
+            with patch():
+                v = children.check(dict(payload, engine_flags=flags))
+            key = name if offset == 1 else f"{name}+{offset}"
+            out[key] = {"ok": v["ok"], "worst_rel_l2": v["worst_rel_l2"],
+                        "median_rel_l2": v["median_rel_l2"],
+                        "limits": v["limits"],
+                        "rows": {r["position"]: r["rel_l2"]
+                                 for r in v["rows"]}}
+            print(key, json.dumps({k: out[key][k] for k in out[key]
+                                   if k != "rows"}), flush=True)
+            gc.collect()
+            if args.out:
+                os.makedirs(os.path.dirname(os.path.abspath(args.out)),
+                            exist_ok=True)
+                with open(args.out, "w") as f:
+                    json.dump(out, f, indent=1)
+    print(json.dumps({name: [v["ok"], round(v["worst_rel_l2"], 5),
+                             round(v["median_rel_l2"], 5)]
                       for name, v in out.items()}))
     return 0
 
 
 if __name__ == "__main__":
-    sys.exit(main())
+    sys.exit(run(__doc__, "olmo-hybrid-7b", controls))

@@ -77,9 +77,24 @@ OLMO_HYBRID_7B = ModelSpec(
     lin_v_head_dim=192, lin_conv_width=4, lin_beta_scale=2)
 
 
-def hybrid_layers(spec: ModelSpec, periods: int) -> ModelSpec:
+# granite-4.0-h-small as benchmark/configs/granite-4.0-h-small-ep2.json
+# serves it: one chip's share of 2 (36 of 72 routed experts, half the
+# vocabulary), (SSM x 5, ATTENTION, SSM x 4) x 4
+GRANITE_4_H_SMALL_EP2 = ModelSpec(
+    arch=ArchType.GRANITE_HYBRID, dim=4096, hidden_dim=768, n_layers=40,
+    n_heads=32, n_kv_heads=8, vocab_size=50176, seq_len=8192,
+    hidden_act=HiddenAct.SILU, rope_theta=0.0, rms_eps=1e-5, n_experts=36,
+    n_active_experts=10, n_shared_experts=2, n_routed_experts=72,
+    mixers=((int(LayerKind.SSM),) * 5 + (int(LayerKind.ATTENTION),)
+            + (int(LayerKind.SSM),) * 4) * 4,
+    ssm_heads=128, ssm_head_dim=64, ssm_d_state=128, ssm_groups=1,
+    ssm_conv_width=4, ssm_conv_bias=1, embedding_scale=12.0,
+    residual_scale=0.22, attn_scale=0.0078125, logit_scale=0.0625)
+
+
+def hybrid_layers(spec: ModelSpec, periods: int, period: int = 4) -> ModelSpec:
     """The first `periods` periods of a hybrid's layer pattern."""
-    n = 4 * periods
+    n = period * periods
     return dataclasses.replace(spec, n_layers=n, mixers=spec.mixers[:n])
 
 
@@ -125,6 +140,19 @@ def _loaded_params(spec: ModelSpec, dtype) -> dict:
                 a_log=jnp.zeros((nh,), jnp.float32),
                 dt_bias=jnp.zeros((nh,), jnp.float32),
                 rms_o=jnp.ones((dv,), jnp.float32))
+        elif spec.layer_kinds[l] == LayerKind.SSM:
+            nh, inner = spec.ssm_heads, spec.ssm_inner
+            lw.update(
+                wz=_zeros_q40(inner, d), wx=_zeros_q40(inner, d),
+                wo=_zeros_q40(d, inner),
+                w_bcdt=jnp.zeros((spec.ssm_conv_dim - inner + nh, d), dtype),
+                conv_w=jnp.zeros((spec.ssm_conv_width, spec.ssm_conv_dim),
+                                 jnp.float32),
+                conv_b=jnp.zeros((spec.ssm_conv_dim,), jnp.float32),
+                a_log=jnp.zeros((nh,), jnp.float32),
+                dt_bias=jnp.zeros((nh,), jnp.float32),
+                ssm_d=jnp.ones((nh,), jnp.float32),
+                rms_o=jnp.ones((inner,), jnp.float32))
         elif spec.is_mla:
             nh, r = spec.n_heads, spec.kv_lora_rank
             lw.update(
@@ -147,10 +175,11 @@ def _loaded_params(spec: ModelSpec, dtype) -> dict:
         elif spec.is_moe:
             e = spec.n_experts
             if spec.is_mla:
-                sh = spec.n_shared_experts * h
                 lw.update(moe_bias=jnp.zeros((spec.router_width,),
-                                             jnp.float32),
-                          sh_w1=_zeros_q40(sh, d), sh_w2=_zeros_q40(d, sh),
+                                             jnp.float32))
+            if spec.n_shared_experts:
+                sh = spec.n_shared_experts * h
+                lw.update(sh_w1=_zeros_q40(sh, d), sh_w2=_zeros_q40(d, sh),
                           sh_w3=_zeros_q40(sh, d))
             lw.update(moe_router=jnp.zeros((spec.router_width, d), dtype),
                       moe_up=_zeros_q40(e, h, d),
