@@ -43,9 +43,14 @@ Layout: QuantizedTensor packed is nibble-position-major, stored flattened
 (d, m) uint8 with lane order m = j*nb + b (see quants/jax_codec.py) — the
 kernel consumes the HBM buffer in place, no reshape/re-tile.
 Consequences inside the kernel:
-  * the per-block scale expansion s16[d, m] = s[d, m % nb] is a lane tile —
-    exactly `pltpu.repeat(s, 16)` (an element-wise repeat of the block-major
-    order would need a shape cast Mosaic cannot lower);
+  * the per-block scale expansion s16[d, m] = s[d, m % nb] lays a row's nb
+    scales 16 times side by side (an element-wise repeat of the block-major
+    order would need a shape cast Mosaic cannot lower): `pltpu.repeat(s,
+    16)`, whole-vreg copies where nb is whole lane tiles (a 4096-wide
+    contraction: 128 blocks) and a few aligned pieces a lane tile at 32 and
+    64; under 32 blocks, where every lane tile is five or more shifted
+    pieces, a product with a 0/1 matrix on the MXU, to the same bits
+    (_spread_scales, _spreads_on_mxu);
   * no weight shuffle is needed; instead the small activation is pre-split
     outside the kernel into matching lo/hi orders:
       x_lo[t, j*nb + b] = x[t, b*32 + j]       (low-nibble elements)
@@ -55,9 +60,11 @@ Consequences inside the kernel:
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -88,7 +95,73 @@ def _f16_bits_to_f32(u: jnp.ndarray) -> jnp.ndarray:
     return jnp.where(e == 0, sub, normal)
 
 
-def _dequant_dot(x_lo, x_hi, xsum, pk_u8, s_raw,
+def _spreads_on_mxu(nb: int) -> bool:
+    """Which spread of a row's block scales over the tile's lanes
+    (_spread_scales) a contraction of nb blocks takes: the MXU's where more
+    than four of the 16 copies share a lane tile, `pltpu.repeat`'s
+    otherwise. Read on the v5e at 4096 rows, both kernels, 8 rows in f32
+    and 64 / 256 in bf16 (`tools/microbench.py q40_shapes`; PERF.md
+    section 6, PR 40): at 12, 16, 24 and 30 blocks the MXU spread wins every
+    reading (1.2-2.8 x: repeat takes 17 us a 4096-row tile at 24 blocks
+    where the MXU takes 6.6); at 32 and 64, where repeat is four and two
+    aligned pieces a lane tile, it ties or loses (+6 to -28 % over two
+    calls); at 48 it wins 17-30 % at 8 rows and ties or loses under the
+    bf16 feed (+4 to -11 %)."""
+    return nb < LANES // 4
+
+
+def _spread_matrix(nb: int, bf16_parts: int):
+    """The 0/1 matrix R[k, m] = (k % nb == m % nb) that lays a row's nb
+    block scales, cut into `bf16_parts` bf16 terms side by side along k,
+    over lcm(nb, LANES) lanes (all 16 nb where that does not divide them):
+    the lane pattern m % nb repeats from there on. None where the shape
+    keeps pltpu.repeat. A constant operand of the call, fetched once (made
+    in the kernel from iotas and their remainders at every grid step it
+    read 0-13 % slower)."""
+    if not _spreads_on_mxu(nb):
+        return None
+    width = math.lcm(nb, LANES)
+    if (16 * nb) % width:
+        width = 16 * nb
+    k, m = np.arange(bf16_parts * nb), np.arange(width)
+    return jnp.asarray(k[:, None] % nb == m % nb, jnp.bfloat16)
+
+
+def _spread_scales(s, spread):
+    """s16[d, j*nb + b] = s[d, b]: a row's nb block scales laid 16 times
+    side by side, the packed tile's lane order.
+
+    Where nb is whole lane tiles that is `pltpu.repeat`, whole-vreg copies
+    (spread None). Where many copies share a lane tile (_spreads_on_mxu)
+    repeat assembles every lane tile of every row from shifted pieces of
+    an nb-lane strip and the shifts bound the kernel, so the MXU, idle at
+    few rows, places them: s is cut into bf16 terms that sum to it exactly (an
+    f16 scale's 11 significant bits are two terms of 8, a hand-built f32
+    scale's 24 three), laid side by side along the contraction, and
+    multiplied by _spread_matrix. Every output element is a sum of products
+    by 1.0 and 0.0 accumulated in f32: repeat's value (a scale of -0.0
+    comes out as +0.0, which no sum with a term that is not zero shows)."""
+    nb = s.shape[1]
+    if spread is None:
+        return pltpu.repeat(s, 16, axis=1)
+    # each term but the last is the 8 leading bits of what is left, cut by
+    # a mask: a float32 -> bf16 -> float32 round trip is one a compiler may
+    # remove; what is left for the last has 8 bits or fewer
+    parts, rest = [], s
+    for _ in range(spread.shape[0] // nb - 1):
+        head = jax.lax.bitcast_convert_type(
+            jax.lax.bitcast_convert_type(rest, jnp.int32) & -0x10000,
+            jnp.float32)
+        parts.append(head.astype(jnp.bfloat16))
+        rest = rest - head
+    parts.append(rest.astype(jnp.bfloat16))
+    one = jnp.dot(jnp.concatenate(parts, axis=1), spread,
+                  preferred_element_type=jnp.float32)
+    copies = 16 * nb // spread.shape[1]
+    return pltpu.repeat(one, copies, axis=1) if copies > 1 else one
+
+
+def _dequant_dot(x_lo, x_hi, xsum, pk_u8, s_raw, spread,
                  *, out_dtype, scales_u16, mxu_bf16):
     """The kernel math on loaded blocks: dequantize a (TD, M) packed tile in
     registers and contract with the pre-split activations. Activations must
@@ -108,7 +181,7 @@ def _dequant_dot(x_lo, x_hi, xsum, pk_u8, s_raw,
         s = _f16_bits_to_f32(s_raw.astype(jnp.int32))    # (TD, NB)
     else:
         s = s_raw                                        # f32 (hand-built)
-    s16 = pltpu.repeat(s, 16, axis=1)                    # lane-tile -> (TD, M)
+    s16 = _spread_scales(s, spread)                      # (TD, NB) -> (TD, M)
 
     # DEFAULT precision: single-pass MXU feed (HIGHEST = multi-pass f32
     # decomposition, measured ~5x slower for the whole kernel); operands are
@@ -156,12 +229,16 @@ def _n_sub(td: int, m: int, mxu_bf16: bool) -> int:
     return 8 if td * m <= (1 << 19) else 2
 
 
-def _subtiled_write(x_lo, x_hi, xsum, load_packed, load_scales, out_ref,
+def _subtiled_write(x_lo, x_hi, xsum, load_packed, load_scales, rest,
                     *, out_dtype, scales_u16, mxu_bf16):
     """Run _dequant_dot per 1/n_sub row slice of the packed tile, writing
     each output column slice as soon as its dot is issued. load_packed /
     load_scales map a row slice -> loaded sub-block (ref slicing stays at
-    the call site because the expert kernel's refs carry a leading dim)."""
+    the call site because the expert kernel's refs carry a leading dim);
+    `rest` is the call's last refs: the spread matrix where the shape takes
+    one (_spread_matrix), and the output."""
+    *spread, out_ref = rest
+    spread = spread[0][:] if spread else None
     td = out_ref.shape[-1]
     n_sub = _n_sub(td, x_lo.shape[-1], mxu_bf16)
     if mxu_bf16:
@@ -170,20 +247,20 @@ def _subtiled_write(x_lo, x_hi, xsum, load_packed, load_scales, out_ref,
     for i in range(n_sub):
         sl = slice(i * h, (i + 1) * h)
         out_ref[:, sl] = _dequant_dot(
-            x_lo, x_hi, xsum, load_packed(sl), load_scales(sl),
+            x_lo, x_hi, xsum, load_packed(sl), load_scales(sl), spread,
             out_dtype=out_dtype, scales_u16=scales_u16, mxu_bf16=mxu_bf16)
 
 
-def _kernel(x_lo_ref, x_hi_ref, xsum_ref, packed_ref, scales_ref, out_ref,
-            *, nb, out_dtype, scales_u16, mxu_bf16):
+def _kernel(x_lo_ref, x_hi_ref, xsum_ref, packed_ref, scales_ref, *rest,
+            nb, out_dtype, scales_u16, mxu_bf16):
     _subtiled_write(
         x_lo_ref[:], x_hi_ref[:], xsum_ref[:],
-        lambda sl: packed_ref[sl, :], lambda sl: scales_ref[sl, :], out_ref,
+        lambda sl: packed_ref[sl, :], lambda sl: scales_ref[sl, :], rest,
         out_dtype=out_dtype, scales_u16=scales_u16, mxu_bf16=mxu_bf16)
 
 
 def _expert_kernel(tiles_ref, used_ref, x_lo_ref, x_hi_ref, xsum_ref,
-                   packed_ref, scales_ref, out_ref, *, nb, out_dtype,
+                   packed_ref, scales_ref, *rest, nb, out_dtype,
                    scales_u16, mxu_bf16):
     del tiles_ref  # consumed by the index maps (each row tile's expert)
 
@@ -194,7 +271,7 @@ def _expert_kernel(tiles_ref, used_ref, x_lo_ref, x_hi_ref, xsum_ref,
         _subtiled_write(
             x_lo_ref[:], x_hi_ref[:], xsum_ref[:],
             lambda sl: packed_ref[0, sl, :], lambda sl: scales_ref[0, sl, :],
-            out_ref,
+            rest,
             out_dtype=out_dtype, scales_u16=scales_u16, mxu_bf16=mxu_bf16)
 
 
@@ -242,7 +319,10 @@ def _tile_d(d: int, m: int) -> int:
     # below, one f32 copy of the tile's scales (4 B for each 16 packed
     # bytes, a quarter), is a BUDGET that sends the one overflowing tile a
     # size down and moves no other (tests/test_pallas_q40.py pins every
-    # configuration's tiles); it is not a model of those temporaries
+    # configuration's tiles); it is not a model of those temporaries. A
+    # shape that spreads its scales on the MXU (under 32 blocks) has no
+    # such temporaries and is charged all the same: charged, its widest tile
+    # is 1024 x 620 bytes, under a third of the budget, and moves none
     cost = m + m // 4 if (m // 16) % LANES else m
     fits = [t for t in TILE_D_CANDIDATES if t * cost <= _TILE_BYTES_MAX]
     for t in fits:
@@ -348,6 +428,8 @@ def _q40_call(x, w: QuantizedTensor, e, out_dtype, interpret, used=None,
             return at(j, i, *refs)[:2]
 
         w_block = (1, td)
+    spread = _spread_matrix(nb, 2 if scales_u16 else 3)
+    consts = () if spread is None else (spread,)
     specs = dict(
         grid=grid,
         in_specs=[
@@ -356,6 +438,7 @@ def _q40_call(x, w: QuantizedTensor, e, out_dtype, interpret, used=None,
             block((tm, nb), x_at),
             block((*w_block, m), w_at),
             block((*w_block, nb), w_at),
+            *(block(c.shape, lambda *_: (0, 0)) for c in consts),
         ],
         out_specs=block((tm, td), out_at),
     )
@@ -380,7 +463,7 @@ def _q40_call(x, w: QuantizedTensor, e, out_dtype, interpret, used=None,
             vmem_limit_bytes=_SCOPED_VMEM_DEFAULT + panels),
         interpret=interpret,
         name=name,
-    )(*prefetched, x_lo, x_hi, xsum, w.packed, scales)
+    )(*prefetched, x_lo, x_hi, xsum, w.packed, scales, *consts)
 
     return out.reshape(*lead, d)
 

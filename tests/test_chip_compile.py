@@ -168,6 +168,122 @@ def test_grouped_expert_tiles_compile(one_chip, model, d, n, rows):
     assert _has_kernel(c)
 
 
+def _copy_results(compiled) -> list[str]:
+    """The result types of a compiled module's `copy` instructions."""
+    import re
+
+    return sorted(re.findall(r"= (\w+\[[\d,]*\])\S* copy(?:-start)?\(",
+                             compiled.as_text()))
+
+
+def _with_repeat_forced(monkeypatch, make):
+    """make() as the tree makes it and with `pltpu.repeat` forced on every
+    shape (the kernels' traces are cached by shape: dropped on both sides)."""
+    from distributed_llama_tpu.ops import pallas_q40 as q
+
+    made = []
+    for forced in (False, True):
+        if forced:
+            monkeypatch.setattr(q, "_spreads_on_mxu", lambda nb: False)
+        q.q40_matmul.clear_cache()
+        q.q40_expert_matmul.clear_cache()
+        # ONE call site: a kernel's text holds the stack it was traced under
+        made.append(make())
+    monkeypatch.undo()
+    q.q40_matmul.clear_cache()
+    q.q40_expert_matmul.clear_cache()
+    return made
+
+
+# the narrow down projections of one chip's programs:
+# granite-4.0-h-small-ep2's 36 held experts (24 scale blocks a row: spread
+# on the MXU; 36 tiles of 8 rows in the decode step, a wave of 56 of 64 in
+# a chunk) and its shared expert (48 blocks, 8 and 256 rows);
+# sarvam-105b-ep8's 16 held experts and its shared expert (64 blocks):
+# `pltpu.repeat` wins or ties those (PERF.md section 6, PR 40)
+@pytest.mark.parametrize("experts,n,tile,n_tiles,rows", [
+    (36, 768, 8, 36, 8), (36, 768, 64, 56, 256),
+    (None, 1536, 8, 1, 8), (None, 1536, 256, 1, 256),
+    (16, 2048, 8, 16, 8), (16, 2048, 16, 32, 256),
+    (None, 2048, 8, 1, 8), (None, 2048, 256, 1, 256)])
+def test_narrow_down_projections_compile_with_their_spread(
+        one_chip, monkeypatch, experts, n, tile, n_tiles, rows):
+    """Both kernels at 4096 rows over a 768-, 1536- and 2048-wide
+    contraction. Where the shape takes the MXU spread (768) the call
+    compiles within the scoped VMEM `_q40_call` asks for, with the spread
+    matrix as one more operand, and puts no copy in front of the kernel
+    that the call with `pltpu.repeat` does not have; the others are the
+    parent's text."""
+    from rehearse_chip_compile import q40_struct
+
+    from distributed_llama_tpu.ops import pallas_q40 as q
+
+    x = _struct((n_tiles * tile, n), BF16, one_chip)
+    if experts is None:
+        w = _placed(q40_struct(4096, n), one_chip)
+        args = (x, w)
+
+        def fn(x, w):
+            return q.q40_matmul(x, w, out_dtype=BF16)
+    else:
+        w = _placed(q40_struct(experts, 4096, n), one_chip)
+        args = (x, w, _struct((n_tiles,), jnp.int32, one_chip),
+                _struct((), jnp.int32, one_chip))
+
+        def fn(x, w, e, used):
+            return q.q40_expert_matmul(x, w, e, used, out_dtype=BF16,
+                                       token_rows=rows)
+
+    # a new function each time: jit caches a function's trace
+    own, forced = _with_repeat_forced(
+        monkeypatch, lambda: jax.jit(lambda *a: fn(*a)).lower(*args))
+    assert (own.as_text() != forced.as_text()) == (n == 768)
+    assert q._spreads_on_mxu(n // 32) == (n == 768)
+    own = own.compile()
+    assert _has_kernel(own)
+    if n == 768:
+        forced = forced.compile()
+        assert _copy_results(own) == _copy_results(forced)
+        assert (own.memory_analysis().temp_size_in_bytes
+                <= forced.memory_analysis().temp_size_in_bytes)
+
+
+@pytest.mark.parametrize("t", [1, 32], ids=["decode", "chunk32"])
+@pytest.mark.parametrize("model", ["mistral_7b", "mixtral_8x7b_12l",
+                                   "olmo_hybrid_7b", "sarvam_105b_ep8",
+                                   "granite_4_h_small_ep2"])
+def test_only_granites_step_programs_take_the_mxu_spread(
+        topo, monkeypatch, model, t):
+    """`mistral-7b`'s, `mixtral-8x7b-12l`'s, `olmo-hybrid-7b`'s and
+    `sarvam-105b-ep8`'s two step programs AS SERVED hold no contraction
+    under 32 scale blocks a row (theirs are 64, 120, 128, 344, 448 and
+    512): they lower to the same text with the MXU spread and with
+    `pltpu.repeat` forced everywhere. `granite-4.0-h-small-ep2`'s do not:
+    its 768-wide expert down projection (24 blocks) is the one shape of
+    the benchmark's programs that takes the MXU spread."""
+    import rehearse_chip_compile as r
+
+    spec, seq_len = {
+        "mistral_7b": (dataclasses.replace(r.MISTRAL_7B, n_layers=2), 4096),
+        "mixtral_8x7b_12l": (dataclasses.replace(r.MIXTRAL_8X7B,
+                                                 n_layers=12), 4096),
+        "olmo_hybrid_7b": (r.hybrid_layers(r.OLMO_HYBRID_7B, 1), 8192),
+        "sarvam_105b_ep8": (dataclasses.replace(r.SARVAM_105B_EP8,
+                                                n_layers=4), 8192),
+        "granite_4_h_small_ep2": (dataclasses.replace(
+            r.GRANITE_4_H_SMALL_EP2, n_layers=2, mixers=(3, 0)), 8192),
+    }[model]
+
+    def lower():
+        fn, args = r.abstract_step(spec, topo.devices, batch=8, t=t,
+                                   seq_len=seq_len, q80=True)
+        return fn.lower(*args).as_text()
+
+    own, forced = _with_repeat_forced(monkeypatch, lower)
+    assert "q40_matmul" in own
+    assert (own == forced) == (model != "granite_4_h_small_ep2")
+
+
 @pytest.mark.parametrize("b,t,cache_dtype", [
     (8, 1, BF16),                   # batched decode
     (1, 256, BF16),                 # 256-token prefill chunk

@@ -27,6 +27,7 @@ from distributed_llama_tpu.io.model_file import read_model  # noqa: E402
 from distributed_llama_tpu.models.params import (load_params,  # noqa: E402
                                                  random_tensors)
 from distributed_llama_tpu.models.spec import ArchType  # noqa: E402
+from distributed_llama_tpu.ops import pallas_q40  # noqa: E402
 from distributed_llama_tpu.runtime.engine import Engine  # noqa: E402
 from distributed_llama_tpu.runtime.scheduler import Scheduler  # noqa: E402
 from distributed_llama_tpu.runtime.stats import WINDOW_COUNTERS  # noqa: E402
@@ -254,27 +255,39 @@ def test_the_counters_keep_up_through_chunks_that_fetch_nothing(tiny_moe):
 # specs lower to, read on the tree BEFORE the grouped path and its counters
 # existed (commit 5e2fe7c, this container's jax): a model without experts
 # must compile exactly what it compiled then. A PR that changes the dense
-# path on purpose re-pins them, from the failure's message.
+# path on purpose re-pins them, from the failure's message. Kernels on, the
+# tiny contractions (2-8 scale blocks a row) spread their scales on the MXU
+# since PR 40: the pins under "repeat" are the PARENT's, read with
+# `pltpu.repeat` forced (nothing else in the text moved), those under True
+# are PR 40's own, of the MXU spread.
 PARENT_TEXT = {
     ("LLAMA", False, "decode"): "ca9dbfd3d9d530cf",
     ("LLAMA", False, "prefill"): "6f6b41e521545ef5",
-    ("LLAMA", True, "decode"): "4fb0a8bc402724e2",
-    ("LLAMA", True, "prefill"): "d760fddbff3e07d6",
+    ("LLAMA", "repeat", "decode"): "4fb0a8bc402724e2",
+    ("LLAMA", True, "decode"): "29e5980851adfca2",
+    ("LLAMA", "repeat", "prefill"): "d760fddbff3e07d6",
+    ("LLAMA", True, "prefill"): "84b9fa0239ef69df",
     ("OLMO_HYBRID", False, "decode"): "52f9fb7000fdeb40",
     ("OLMO_HYBRID", False, "prefill"): "c8040defc4f2b4aa",
-    ("OLMO_HYBRID", True, "decode"): "06323757d9c50a83",
-    ("OLMO_HYBRID", True, "prefill"): "2b2ee5b53568d61c",
+    ("OLMO_HYBRID", "repeat", "decode"): "06323757d9c50a83",
+    ("OLMO_HYBRID", True, "decode"): "049f19dac0dd4149",
+    ("OLMO_HYBRID", "repeat", "prefill"): "2b2ee5b53568d61c",
+    ("OLMO_HYBRID", True, "prefill"): "259a2aa37dfcc092",
     # the two architectures WITH experts that the benchmark runs, read on
     # commit 26394a7 (before GRANITE_HYBRID's block kinds and multipliers):
     # a pre-norm block whose multipliers are 1 compiles what it compiled
     ("MIXTRAL", False, "decode"): "2abb1048c22a432a",
     ("MIXTRAL", False, "prefill"): "826812d1438131aa",
-    ("MIXTRAL", True, "decode"): "f14b9fd15f1fdd5c",
-    ("MIXTRAL", True, "prefill"): "d5b0b94719fac3e7",
+    ("MIXTRAL", "repeat", "decode"): "f14b9fd15f1fdd5c",
+    ("MIXTRAL", True, "decode"): "c8b878c4c7e736a5",
+    ("MIXTRAL", "repeat", "prefill"): "d5b0b94719fac3e7",
+    ("MIXTRAL", True, "prefill"): "ab6e88d04010e6c9",
     ("SARVAM_MLA", False, "decode"): "7870fd495c410b5b",
     ("SARVAM_MLA", False, "prefill"): "260025de90698f07",
-    ("SARVAM_MLA", True, "decode"): "b48b5b664e87e75b",
-    ("SARVAM_MLA", True, "prefill"): "8aa08d0ea1b55faf",
+    ("SARVAM_MLA", "repeat", "decode"): "b48b5b664e87e75b",
+    ("SARVAM_MLA", True, "decode"): "816d518e01fac15d",
+    ("SARVAM_MLA", "repeat", "prefill"): "8aa08d0ea1b55faf",
+    ("SARVAM_MLA", True, "prefill"): "b7d4b1922cffb988",
 }
 TINY_SPECS = {
     "LLAMA": tiny_spec, "OLMO_HYBRID": tiny_hybrid_spec,
@@ -287,34 +300,50 @@ TINY_SPECS = {
 def lowered_steps():
     made = {}
 
-    def steps(arch: str, kernels: bool) -> dict:
+    def drop_traces():  # the kernels' traces are cached by shape alone
+        pallas_q40.q40_matmul.clear_cache()
+        pallas_q40.q40_expert_matmul.clear_cache()
+
+    def steps(arch: str, kernels) -> dict:
+        """`kernels`: False, True, or "repeat": the kernels with
+        `pltpu.repeat` forced on every Q40 shape, as the parent had them."""
         if (arch, kernels) not in made:
-            spec = TINY_SPECS[arch]()
-            params = load_params(
-                spec, random_tensors(spec, seed=1, scale=0.05), mode="q40",
-                dtype=F32)
-            eng = Engine(spec, params, batch=B, compute_dtype=F32,
-                         cache_dtype=F32, use_pallas=kernels,
-                         pallas_interpret=kernels)
-            i32 = lambda a: jnp.asarray(a, jnp.int32)  # noqa: E731
-            pos = np.full((B,), eng.seq_len, np.int32)
-            one, chunk = np.zeros((B, 1)), np.zeros((B, CHUNK))
-            eng.slot_decode_step(one, pos)             # mint both programs
-            eng.slot_prefill_chunk(chunk, pos, np.zeros(B))
-            assert bool(eng.take_expert_counts()) == spec.is_moe
-            made[arch, kernels] = {
-                "decode": eng._steps["slot_decode"].lower(
-                    eng.params, i32(one), i32(pos), eng.cache),
-                "prefill": eng._steps["slot_prefill", CHUNK].lower(
-                    eng.params, i32(chunk), i32(pos), i32(np.zeros(B)),
-                    eng.cache)}
+            with pytest.MonkeyPatch.context() as mp:
+                if kernels == "repeat":
+                    mp.setattr(pallas_q40, "_spreads_on_mxu",
+                               lambda nb: False)
+                drop_traces()
+                made[arch, kernels] = lower(arch, bool(kernels))
+                drop_traces()
         return made[arch, kernels]
+
+    def lower(arch: str, kernels: bool) -> dict:
+        spec = TINY_SPECS[arch]()
+        params = load_params(
+            spec, random_tensors(spec, seed=1, scale=0.05), mode="q40",
+            dtype=F32)
+        eng = Engine(spec, params, batch=B, compute_dtype=F32,
+                     cache_dtype=F32, use_pallas=kernels,
+                     pallas_interpret=kernels)
+        i32 = lambda a: jnp.asarray(a, jnp.int32)  # noqa: E731
+        pos = np.full((B,), eng.seq_len, np.int32)
+        one, chunk = np.zeros((B, 1)), np.zeros((B, CHUNK))
+        eng.slot_decode_step(one, pos)             # mint both programs
+        eng.slot_prefill_chunk(chunk, pos, np.zeros(B))
+        assert bool(eng.take_expert_counts()) == spec.is_moe
+        return {
+            "decode": eng._steps["slot_decode"].lower(
+                eng.params, i32(one), i32(pos), eng.cache),
+            "prefill": eng._steps["slot_prefill", CHUNK].lower(
+                eng.params, i32(chunk), i32(pos), i32(np.zeros(B)),
+                eng.cache)}
 
     return steps
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
-@pytest.mark.parametrize("kernels", [False, True], ids=["xla", "kernels"])
+@pytest.mark.parametrize("kernels", [False, True, "repeat"],
+                         ids=["xla", "kernels", "kernels-repeat"])
 @pytest.mark.parametrize("arch", ["LLAMA", "OLMO_HYBRID"])
 def test_a_model_without_experts_lowers_to_the_parents_step_programs(
         lowered_steps, arch, kernels, program):
@@ -331,7 +360,8 @@ def test_a_model_without_experts_lowers_to_the_parents_step_programs(
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
-@pytest.mark.parametrize("kernels", [False, True], ids=["xla", "kernels"])
+@pytest.mark.parametrize("kernels", [False, True, "repeat"],
+                         ids=["xla", "kernels", "kernels-repeat"])
 @pytest.mark.parametrize("arch", ["MIXTRAL", "SARVAM_MLA"])
 def test_a_model_with_experts_lowers_to_the_parents_step_programs(
         lowered_steps, arch, kernels, program):

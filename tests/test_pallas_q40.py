@@ -6,8 +6,9 @@ The kernel is the TPU-native analogue of the reference's Q40xQ80 SIMD matmul
 semantics of the reference decoder (ref: src/quants.cpp:166-179).
 """
 
-import numpy as np
+import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from distributed_llama_tpu.ops.pallas_q40 import q40_matmul, supports_pallas, _tile_d
@@ -464,6 +465,41 @@ TILES = {
         (5504, 3840): 128, (22016, 960): 512, (3840, 11008): 256,
         (960, 11008): 256, (3840, 2752): 256, (100352, 3840): 512,
         (25088, 3840): 512, (100352, 960): 1024},
+    # expert gate / up and down (768-wide experts), the shared expert's
+    # (1536), the state-space in projection (16768 rows, of which the
+    # kernel's gate | x leaf 16384) and out projection, wqkv and wo, the
+    # 50176-row head
+    "granite-4.0-h-small-ep2": {
+        (768, 4096): 256, (192, 4096): 192, (768, 1024): 256,
+        (4096, 768): 1024, (1024, 768): 1024, (4096, 192): 1024,
+        (1536, 4096): 512, (384, 4096): 128, (1536, 1024): 512,
+        (4096, 1536): 1024, (1024, 1536): 1024, (4096, 384): 1024,
+        (16768, 4096): 128, (4192, 4096): 256, (16768, 1024): 128,
+        (16384, 4096): 1024, (4096, 4096): 1024, (16384, 1024): 1024,
+        (4096, 8192): 512, (1024, 8192): 512, (4096, 2048): 1024,
+        (6144, 4096): 1024, (6144, 1024): 1024, (1024, 4096): 1024,
+        (4096, 1024): 1024, (50176, 4096): 1024, (12544, 4096): 256,
+        (50176, 1024): 1024},
+}
+
+# The pinned shapes whose block scales are spread over the lanes on the MXU
+# (`_spreads_on_mxu`: more than four copies of a row's blocks share a lane
+# tile, a contraction under 1024); every other shape keeps `pltpu.repeat`.
+# The choice is made by shape when a program is traced, in every call of
+# that shape or in none: this table is its record of engagement. One chip's
+# programs hold such a shape in ONE configuration, granite's expert down
+# projection (4096, 768); the rest are contraction shards of tp = 4.
+MXU_SPREAD = {
+    "llama2-7b": set(),
+    "mistral-7b, mixtral-8x7b": set(),
+    "grok1": set(),
+    "llama3-8b head": set(),
+    "sarvam-105b-ep8": {(4096, 512)},
+    "olmo-hybrid-7b": {
+        (2880, 960), (3840, 960), (5760, 960), (11008, 960), (11520, 960),
+        (22016, 960), (100352, 960)},
+    "granite-4.0-h-small-ep2": {
+        (4096, 768), (1024, 768), (4096, 192), (4096, 384)},
 }
 
 
@@ -471,6 +507,98 @@ TILES = {
 def test_tile_d_of_every_configurations_shapes_is_pinned(config):
     got = {(d, n): _tile_d(d, n // 2) for d, n in TILES[config]}
     assert got == TILES[config]
+
+
+@pytest.mark.parametrize("config", sorted(TILES))
+def test_which_pinned_shapes_spread_their_scales_on_the_mxu(config):
+    from distributed_llama_tpu.ops.pallas_q40 import _spreads_on_mxu
+
+    got = {(d, n) for d, n in TILES[config] if _spreads_on_mxu(n // 32)}
+    assert got == MXU_SPREAD[config]
+
+
+def _wide_scales(rng, scales, one_exponent, *shape):
+    """Scales over everything the spread has to place exactly: as f16 bits
+    every value but inf / nan (negatives, subnormals, the largest normal,
+    both zeros), as hand-built f32 all 24 bits of a significand. With
+    `one_exponent` every scale is +-(1 + m / 1024) / 32 in either form: all
+    11 bits of an f16 significand and nothing a sum could round."""
+    bits = rng.integers(0, 1 << 16, shape, dtype=np.uint16)
+    if one_exponent:
+        bits = bits & 0x83FF | 0x2800
+    else:
+        bits = np.where(bits & 0x7C00 == 0x7C00, bits & ~np.uint16(0x4000),
+                        bits)
+        planted = np.asarray([0x0001, 0x8001, 0x03FF, 0x83FF, 0x0400,
+                              0x7BFF, 0xFBFF, 0x0000, 0x8000, 0x3C00],
+                             np.uint16)
+        bits[..., :planted.size // 2, :2] = planted.reshape(-1, 2)
+    if scales == "u16":
+        return bits
+    f32 = bits.view(np.float16).astype(np.float32)
+    if not one_exponent:
+        f32[..., 8:, :] *= rng.standard_normal(f32[..., 8:, :].shape,
+                                               dtype=np.float32)
+    return f32
+
+
+@pytest.mark.parametrize("scales", ["u16", "f32"])
+@pytest.mark.parametrize("t", [1, 8, 64])
+@pytest.mark.parametrize("kernel", ["q40_matmul", "q40_expert_matmul"])
+@pytest.mark.parametrize("nb", [16, 24, 32, 48, 64])
+def test_mxu_spread_is_bit_equal_to_repeat(rng, monkeypatch, nb, kernel, t,
+                                           scales):
+    """The spread of a narrow row's block scales on the MXU against the
+    same call with `pltpu.repeat` forced: the same output to the last bit,
+    in both kernels, under the f32 feed (1 and 8 rows) and the bf16 feed
+    (64 rows of a 256-row program, the 256-row tile sub-tiled 8-way), for
+    f16-bit scales (two bf16 terms) and hand-built f32 ones (three).
+
+    At ONE row the CPU compiles the kernel's dots into loops fused with
+    whatever made their operands, and sums in another order by how the
+    scales were spread (equal spread, other rounding): there the scales
+    share one exponent and the activation is a few +-1, so that every sum
+    is exact in any order, and a scale placed wrongly in any of its 11 bits
+    still shows. The whole range of exponents is held at 8 and 64 rows."""
+    from distributed_llama_tpu.ops import pallas_q40 as q
+
+    d, n = 256, nb * 32
+    assert _tile_d(d, n // 2) == 256
+    # the spread is exact at every nb up to half a lane tile, the widest the
+    # issue measured; the predicate takes those where it is also faster
+    assert q._spreads_on_mxu(16) and q._spreads_on_mxu(24)
+    monkeypatch.setattr(q, "_spreads_on_mxu", lambda nb_: True)
+    w = QuantizedTensor(
+        jnp.asarray(rng.integers(0, 256, (2, d, n // 2), dtype=np.uint8)),
+        jnp.asarray(_wide_scales(rng, scales, t == 1, 2, d, nb)))
+    bf16_feed = t >= 16
+    assert q._n_sub(256, n // 2, bf16_feed) == (8 if bf16_feed else 1)
+    out_dtype = jnp.bfloat16 if bf16_feed else jnp.float32
+    x = rng.standard_normal((t, n), dtype=np.float32)
+    if t == 1:
+        x = np.sign(x) * (rng.random((t, n)) < 1 / 16)
+    x = jnp.asarray(x, jnp.bfloat16)
+
+    def call():
+        fn = getattr(q, kernel)
+        fn.clear_cache()
+        if kernel == "q40_matmul":
+            y = fn(x, w[1], out_dtype=out_dtype, interpret=True)
+        else:
+            y = fn(x, w, jnp.int32(1), out_dtype=out_dtype, interpret=True,
+                   token_rows=256 if bf16_feed else t)
+        fn.clear_cache()
+        return np.asarray(y, np.float32)
+
+    got = call()
+    monkeypatch.setattr(q, "_spreads_on_mxu", lambda nb_: False)
+    want = call()
+    assert np.isfinite(want).all() and np.abs(want).max() > 0
+    np.testing.assert_array_equal(got, want)
+    ref = jnp.einsum("tn,dn->td", x.astype(jnp.float32),
+                     dequantize_q40_jax(w[1], dtype=jnp.float32))
+    np.testing.assert_allclose(got, np.asarray(ref), rtol=2 ** -6,
+                               atol=2 ** -6 * np.abs(ref).max())
 
 
 def test_tile_d_refuses_a_weight_no_tile_fits():

@@ -6,11 +6,17 @@ benchmark runs its body R times inside one jit (outer lax.scan with a
 feedback dependency) at two values of R; the slope (t2-t1)/(R2-R1) is the
 true per-iteration time, free of the constant.
 
-Usage: python tools/microbench.py [all|gemv|gemv_q40|gemv_pallas|attn|cache]
+`q40_shapes` is the table that sets `ops/pallas_q40._spreads_on_mxu`: both
+Q40 kernels at the narrow contractions the configurations hold, each with
+the block scales spread by `pltpu.repeat` and on the MXU, on one chip in
+one process (PERF.md section 6, PR 40).
+
+Usage: python tools/microbench.py [all|gemv|gemv_q40|gemv_pallas|attn|cache|q40_shapes]
 """
 
 from __future__ import annotations
 
+import os
 import sys
 import time
 
@@ -18,7 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-sys.path.insert(0, "/root/repo")
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from distributed_llama_tpu.quants.jax_codec import QuantizedTensor, dequantize_q40_jax
 from distributed_llama_tpu.ops.attention import decode_attention
@@ -28,11 +34,11 @@ SEQ, KVH, HS = 2048, 32, 128
 R1, R2 = 4, 32  # wide spread: run-to-run jitter swamps small slopes
 
 
-def slope_time(make_run, *args):
+def slope_time(make_run, *args, reps=(R1, R2)):
     """make_run(reps) -> jitted fn; returns per-rep seconds via slope."""
     times = {}
-    for reps in (R1, R2):
-        fn = make_run(reps)
+    for r in reps:
+        fn = make_run(r)
         out = fn(*args)
         np.asarray(jax.tree.leaves(out)[0])  # warm/compile
         best = 1e9
@@ -41,8 +47,8 @@ def slope_time(make_run, *args):
             out = fn(*args)
             np.asarray(jax.tree.leaves(out)[0])
             best = min(best, time.perf_counter() - t0)
-        times[reps] = best
-    return (times[R2] - times[R1]) / (R2 - R1)
+        times[r] = best
+    return (times[reps[1]] - times[reps[0]]) / (reps[1] - reps[0])
 
 
 def _outer(body_scan, reps):
@@ -151,12 +157,121 @@ def bench_cache():
     print(f"cache update scan: {dt*1e3:.3f} ms/pass ({gb:.2f} GB buffer)")
 
 
+# scale blocks a row of the contractions the configurations and their tp
+# shards hold at or under half a lane tile (512, 768, 1024, 1536, 2048 wide:
+# granite's expert down 768 and shared down 1536, sarvam's expert and shared
+# down 2048), and the 4096-wide control, which takes pltpu.repeat either way
+Q40_NB = (16, 24, 32, 48, 64, 128)
+Q40_ROWS = 4096          # output rows of every shape in the table
+Q40_EXPERTS = 36         # granite-4.0-h-small-ep2's held experts a layer
+# (rows a tile, tiles, tiles used, the program's token rows): the grouped
+# call of a full decode step (f32 feed) and of a 256-row chunk (bf16 feed)
+Q40_FEEDS = {"8 rows f32": (8, 36, 24, 8), "64 rows bf16": (64, 56, 28, 256)}
+HBM_GBS = 819.0          # TPU v5e
+
+
+def _q40_wide_scales(rng, *shape):
+    """Packed bytes and f16 scale BITS over the whole f16 range but inf/nan
+    (negatives, subnormals, the largest normal): what the two spreads must
+    agree on to the last bit."""
+    nb = shape[-1] // 32
+    packed = rng.integers(0, 256, (*shape[:-1], 16 * nb), dtype=np.uint8)
+    bits = rng.integers(0, 1 << 16, (*shape[:-1], nb), dtype=np.uint16)
+    bits = np.where(bits & 0x7C00 == 0x7C00, bits & ~np.uint16(0x4000), bits)
+    return QuantizedTensor(jnp.asarray(packed), jnp.asarray(bits))
+
+
+def bench_q40_shapes():
+    """us a used row tile of `q40_expert_matmul` and us a call of
+    `q40_matmul`, scales spread by repeat | on the MXU, by the method of PR
+    39's diagnosis: calls chained in one program (slope_time), the same
+    program with NO tile used subtracted (its grid steps and the
+    activation's split are not the kernel's read), the used tiles' bytes
+    over what is left."""
+    from distributed_llama_tpu.ops import pallas_q40 as pq
+
+    own = pq._spreads_on_mxu
+    rng = np.random.default_rng(0)
+    bf16 = jnp.bfloat16
+
+    def row(kernel, nb, feed, weight_bytes, measure):
+        """One line of the table: measure() -> (us, output) under each
+        spread, forced on every nb up to half a lane tile; the kernels'
+        traces are cached by shape, so they are dropped at each switch."""
+        us, out = {}, {}
+        for mxu in (False, True):
+            pq._spreads_on_mxu = lambda nb_: mxu and nb_ <= pq.LANES // 2
+            pq.q40_matmul.clear_cache()
+            pq.q40_expert_matmul.clear_cache()
+            us[mxu], out[mxu] = measure()
+        gbs = {k: weight_bytes / v / 1e3 for k, v in us.items()}
+        print(f"{kernel} | {nb} | {nb * 32} | {feed} | {us[False]:.2f} | "
+              f"{us[True]:.2f} "
+              f"| {gbs[False]:.0f} ({100 * gbs[False] / HBM_GBS:.0f} %) "
+              f"| {gbs[True]:.0f} ({100 * gbs[True] / HBM_GBS:.0f} %) | "
+              f"{np.array_equal(out[False], out[True])}", flush=True)
+
+    print(f"{jax.devices()[0].device_kind}; predicate takes nb in "
+          f"{[nb for nb in Q40_NB if own(nb)]}")
+    print("kernel | nb | contraction | feed | repeat us | mxu us | "
+          "repeat GB/s (% of 819) | mxu GB/s (%) | bit-equal")
+    try:
+        for nb in Q40_NB:
+            n = nb * 32
+            w = _q40_wide_scales(rng, Q40_EXPERTS, Q40_ROWS, n)
+            weight_bytes = Q40_ROWS * (16 * nb + 2 * nb)   # of one expert
+            for feed, (tile, n_tiles, used, rows) in Q40_FEEDS.items():
+                x = jnp.asarray(rng.standard_normal((n_tiles * tile, n)),
+                                bf16)
+                e = jnp.asarray(np.sort(rng.choice(
+                    Q40_EXPERTS, n_tiles, replace=n_tiles > Q40_EXPERTS)),
+                    jnp.int32)
+
+                def call(x, w, u):
+                    return pq.q40_expert_matmul(x, w, e, u, out_dtype=bf16,
+                                                token_rows=rows)
+
+                def body(x, wu):
+                    return x + call(x, *wu)[:, :x.shape[1]] * bf16(1e-6)
+
+                def measure():
+                    t = [slope_time(lambda r: _outer(body, r),
+                                    (w, jnp.int32(u)), x) for u in (used, 0)]
+                    y = call(x, w, jnp.int32(used))[:used * tile]
+                    return ((t[0] - t[1]) / used * 1e6,
+                            np.asarray(y, np.float32))
+
+                row("q40_expert_matmul", nb,
+                    f"{feed}, {used} of {n_tiles} tiles used", weight_bytes,
+                    measure)
+            for t_rows in (8, 256):
+                x = jnp.asarray(rng.standard_normal((t_rows, n)), bf16)
+
+                def body(x, w):
+                    y = pq.q40_matmul(x, w, out_dtype=bf16)
+                    return x + y[:, :x.shape[1]] * bf16(1e-6)
+
+                def measure():   # 6-70 us a call: many repetitions
+                    us = slope_time(lambda r: _outer(body, r), w[0], x,
+                                    reps=(16, 256)) * 1e6
+                    y = pq.q40_matmul(x, w[0], out_dtype=bf16)
+                    return us, np.asarray(y, np.float32)
+
+                row("q40_matmul", nb, f"{t_rows} rows, a call", weight_bytes,
+                    measure)
+    finally:
+        pq._spreads_on_mxu = own
+        pq.q40_matmul.clear_cache()
+        pq.q40_expert_matmul.clear_cache()
+
+
 ALL = {
     "gemv": bench_gemv_dense,
     "gemv_q40": bench_gemv_q40,
     "gemv_pallas": bench_gemv_pallas,
     "attn": bench_attn,
     "cache": bench_cache,
+    "q40_shapes": bench_q40_shapes,
 }
 
 if __name__ == "__main__":
