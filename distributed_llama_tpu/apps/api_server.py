@@ -1071,8 +1071,10 @@ def make_handler(state: ApiState):
                 payload["frontdoor"] = state.frontdoor.summary()
                 from ..runtime.profiler import PROFILER
                 if PROFILER.last_counters is not None:
-                    # the serving counters at the last capture's two ends
-                    payload["capture"] = PROFILER.last_counters
+                    # the serving counters at the last capture's two ends,
+                    # and what that capture says of itself
+                    payload["capture"] = {**PROFILER.last_counters,
+                                          "report": PROFILER.last_report}
                 from ..runtime.trace import TRACER
                 if TRACER.enabled:
                     payload["trace"] = TRACER.summary()

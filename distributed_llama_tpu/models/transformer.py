@@ -189,13 +189,15 @@ def _mla_attention_block(x, lw, spec: ModelSpec, cache, q_pos, cfg,
     d_n, d_r = spec.qk_nope_head_dim, spec.qk_rope_head_dim
     eps = spec.norm_eps
 
-    u = rmsnorm(x, lw["rms_att"], eps)
-    q = matmul(u, lw["wq"], **cfg).reshape(b, t, h, d_n + d_r)
-    kva = matmul(u, lw["wkva"], **cfg)                       # (B, T, r + d_r)
-    c = rmsnorm(kva[..., :r], lw["rms_kv"], eps)
-    q_r = rope_yarn(q[..., d_n:], q_pos, spec)
-    k_r = rope_yarn(kva[..., None, r:], q_pos, spec)[..., 0, :]
-    new = jnp.concatenate([c, k_r.astype(c.dtype)], axis=-1)[:, :, None, :]
+    with jax.named_scope("attn_proj"):
+        u = rmsnorm(x, lw["rms_att"], eps)
+        q = matmul(u, lw["wq"], **cfg).reshape(b, t, h, d_n + d_r)
+        kva = matmul(u, lw["wkva"], **cfg)                   # (B, T, r + d_r)
+        c = rmsnorm(kva[..., :r], lw["rms_kv"], eps)
+        q_r = rope_yarn(q[..., d_n:], q_pos, spec)
+        k_r = rope_yarn(kva[..., None, r:], q_pos, spec)[..., 0, :]
+        new = jnp.concatenate([c, k_r.astype(c.dtype)],
+                              axis=-1)[:, :, None, :]
 
     with jax.named_scope("mla_absorb"):
         q_abs = jnp.einsum("bthn,hnr->bthr", q[..., :d_n], lw["w_uk"],
@@ -214,29 +216,36 @@ def _mla_attention_block(x, lw, spec: ModelSpec, cache, q_pos, cfg,
 
         assert write_gate is None
         interpret = cfg.get("pallas_interpret", False)
-        cache_t = kv_cache_write_seq_minor(
-            cache.transpose(0, 1, 3, 2), _to_cache_dtype(new, cache.dtype),
-            q_pos[:, 0], interpret=interpret)
-        att = mla_attention(q_abs, cache_t, q_pos, v_width=r, scale=scale,
-                            interpret=interpret)
-        cache = cache_t.transpose(0, 1, 3, 2)
+        with jax.named_scope("attn_cache"):
+            cache_t = kv_cache_write_seq_minor(
+                cache.transpose(0, 1, 3, 2),
+                _to_cache_dtype(new, cache.dtype), q_pos[:, 0],
+                interpret=interpret)
+        with jax.named_scope("attn_core"):
+            att = mla_attention(q_abs, cache_t, q_pos, v_width=r,
+                                scale=scale, interpret=interpret)
+        with jax.named_scope("attn_cache"):
+            cache = cache_t.transpose(0, 1, 3, 2)
     else:
-        if per_row_pos:
-            cache, _ = _scatter_cache_write(cache, None, new, None, q_pos,
-                                            write_gate)
-        else:
-            assert write_gate is None
-            zero = jnp.int32(0)
-            cache = lax.dynamic_update_slice(
-                cache, _to_cache_dtype(new.transpose(0, 2, 1, 3),
-                                       cache.dtype),
-                (zero, zero, q_pos[0, 0], zero))
-        att = decode_attention(q_abs, cache, cache[..., :r], q_pos,
-                               scale=scale)                  # (B, T, H, r)
+        with jax.named_scope("attn_cache"):
+            if per_row_pos:
+                cache, _ = _scatter_cache_write(cache, None, new, None,
+                                                q_pos, write_gate)
+            else:
+                assert write_gate is None
+                zero = jnp.int32(0)
+                cache = lax.dynamic_update_slice(
+                    cache, _to_cache_dtype(new.transpose(0, 2, 1, 3),
+                                           cache.dtype),
+                    (zero, zero, q_pos[0, 0], zero))
+        with jax.named_scope("attn_core"):
+            att = decode_attention(q_abs, cache, cache[..., :r], q_pos,
+                                   scale=scale)              # (B, T, H, r)
     with jax.named_scope("mla_absorb"):
         o = jnp.einsum("bthr,hvr->bthv", att, lw["w_uv"],
                        preferred_element_type=x.dtype)
-    out = matmul(o.reshape(b, t, h * spec.v_head_dim), lw["wo"], **cfg)
+    with jax.named_scope("attn_out"):
+        out = matmul(o.reshape(b, t, h * spec.v_head_dim), lw["wo"], **cfg)
     return out, cache
 
 
@@ -269,32 +278,34 @@ def _attention_block(x, lw, spec: ModelSpec, k_cache, v_cache, q_pos, cfg,
         # attention are per-head, so only the reshape bookkeeping changes.
         h, kvh = h // f, kvh // f
 
-    if spec.post_norm:
-        xb = x          # the norm sits on the sublayer's OUTPUT (_layer)
-    else:
-        xb = rmsnorm(x, lw["rms_att"], spec.norm_eps)  # ref: llama2-tasks.cpp:10-21
-    if "wqkv" in lw:
-        # fused QKV projection (single-shard path): one kernel call, one
-        # shared activation prep, deeper DMA pipeline
-        qkv = matmul(xb, lw["wqkv"], **cfg)
-        q = qkv[..., : h * hs].reshape(b, t, h, hs)
-        k = qkv[..., h * hs: (h + kvh) * hs].reshape(b, t, kvh, hs)
-        v = qkv[..., (h + kvh) * hs:].reshape(b, t, kvh, hs)
-    else:
-        q = matmul(xb, lw["wq"], **cfg).reshape(b, t, h, hs)
-        k = matmul(xb, lw["wk"], **cfg).reshape(b, t, kvh, hs)
-        v = matmul(xb, lw["wv"], **cfg).reshape(b, t, kvh, hs)
+    with jax.named_scope("attn_proj"):
+        if spec.post_norm:
+            xb = x          # the norm sits on the sublayer's OUTPUT (_layer)
+        else:
+            # ref: llama2-tasks.cpp:10-21
+            xb = rmsnorm(x, lw["rms_att"], spec.norm_eps)
+        if "wqkv" in lw:
+            # fused QKV projection (single-shard path): one kernel call, one
+            # shared activation prep, deeper DMA pipeline
+            qkv = matmul(xb, lw["wqkv"], **cfg)
+            q = qkv[..., : h * hs].reshape(b, t, h, hs)
+            k = qkv[..., h * hs: (h + kvh) * hs].reshape(b, t, kvh, hs)
+            v = qkv[..., (h + kvh) * hs:].reshape(b, t, kvh, hs)
+        else:
+            q = matmul(xb, lw["wq"], **cfg).reshape(b, t, h, hs)
+            k = matmul(xb, lw["wk"], **cfg).reshape(b, t, kvh, hs)
+            v = matmul(xb, lw["wv"], **cfg).reshape(b, t, kvh, hs)
 
-    if spec.post_norm:
-        # q and k normed over the whole projected width, before the heads
-        q = rmsnorm(q.reshape(b, t, h * hs), lw["rms_q"],
-                    spec.norm_eps).reshape(b, t, h, hs)
-        k = rmsnorm(k.reshape(b, t, kvh * hs), lw["rms_k"],
-                    spec.norm_eps).reshape(b, t, kvh, hs)
-    if spec.rope_theta > 0:     # 0: no rotation (position reaches such a
-        # model's attention through its recurrent layers)
-        q = apply_rope(q, q_pos, spec.rope_theta, spec.arch)
-        k = apply_rope(k, q_pos, spec.rope_theta, spec.arch)
+        if spec.post_norm:
+            # q and k normed over the whole projected width, before the heads
+            q = rmsnorm(q.reshape(b, t, h * hs), lw["rms_q"],
+                        spec.norm_eps).reshape(b, t, h, hs)
+            k = rmsnorm(k.reshape(b, t, kvh * hs), lw["rms_k"],
+                        spec.norm_eps).reshape(b, t, kvh, hs)
+        if spec.rope_theta > 0:     # 0: no rotation (position reaches such a
+            # model's attention through its recurrent layers)
+            q = apply_rope(q, q_pos, spec.rope_theta, spec.arch)
+            k = apply_rope(k, q_pos, spec.rope_theta, spec.arch)
     # a published softmax scale other than head^-1/2 (None: that one). The
     # sp and tp paths below take none; a model that has one is refused there
     scale = spec.attn_scale or None
@@ -315,107 +326,116 @@ def _attention_block(x, lw, spec: ModelSpec, k_cache, v_cache, q_pos, cfg,
         from ..parallel.mesh import SP_AXIS as _SP
         from ..parallel.ring_attention import sp_cache_attention_local
 
-        s_local = k_cache.shape[2]
-        local = q_pos - lax.axis_index(_SP) * s_local
-        local = jnp.where(local < 0, s_local, local)
-        k_cache, v_cache = _scatter_cache_write(k_cache, v_cache, k, v,
-                                                local, write_gate)
+        with jax.named_scope("attn_cache"):
+            s_local = k_cache.shape[2]
+            local = q_pos - lax.axis_index(_SP) * s_local
+            local = jnp.where(local < 0, s_local, local)
+            k_cache, v_cache = _scatter_cache_write(k_cache, v_cache, k, v,
+                                                    local, write_gate)
         assert scale is None, "a softmax scale of its own under manual sp"
-        att = sp_cache_attention_local(q, k_cache, v_cache, q_pos)
-        out = matmul(att.reshape(b, t, h * hs), lw["wo"], **cfg)
+        with jax.named_scope("attn_core"):
+            att = sp_cache_attention_local(q, k_cache, v_cache, q_pos)
+        with jax.named_scope("attn_out"):
+            out = matmul(att.reshape(b, t, h * hs), lw["wo"], **cfg)
         return out, k_cache, v_cache
-    if per_row_pos:
-        # batched generation: each sequence writes at its own position
-        # (net-new vs the reference's batch=1 — SURVEY.md §2.5 DP row).
-        # Gated (pp off-turn) writes are pushed out of bounds and dropped
-        # — cheaper than a read-modify-write, and XLA's partitioner
-        # handles the scatter where it miscompiles the equivalent gather
-        # under manual pp. q_pos rows are contiguous, so the in-place
-        # kernel may take the write; a cache whose sequence GSPMD shards
-        # over sp keeps the scatter (a pallas_call would gather it whole).
-        k_cache, v_cache = _scatter_cache_write(
-            k_cache, v_cache, k, v, q_pos, write_gate,
-            kernel_cfg=cfg if sp_cache_mesh is None else None)
-    else:
-        pos0 = q_pos[:, 0]
-        k_w = _to_cache_dtype(k.transpose(0, 2, 1, 3), k_cache.dtype)
-        v_w = _to_cache_dtype(v.transpose(0, 2, 1, 3), v_cache.dtype)
-        # index literals pinned to the position dtype: bare Python 0s trace
-        # as int64 under x64 and dynamic_(update_)slice rejects mixed index
-        # dtypes — int32 everywhere keeps the program x64-proof (dlgrind
-        # DLG202 traces entry points under enable_x64)
-        zero = jnp.int32(0)
-        start = (zero, zero, pos0[0], zero)
-        if write_gate is not None:
-            k_w = jnp.where(write_gate, k_w,
-                            lax.dynamic_slice(k_cache, start, k_w.shape))
-            v_w = jnp.where(write_gate, v_w,
-                            lax.dynamic_slice(v_cache, start, v_w.shape))
-        k_cache = lax.dynamic_update_slice(k_cache, k_w, start)
-        v_cache = lax.dynamic_update_slice(v_cache, v_w, start)
-    if sp_cache_mesh is not None:
-        # keep the cache sp-sharded through the functional update: during ring
-        # prefill the T-sharded K/V reshards into the S-sharded cache (one
-        # K/V-sized shuffle per layer); decode's single-position write lands
-        # in the owning shard. Per-device cache stays seq_len/sp.
-        from jax.sharding import NamedSharding
+    with jax.named_scope("attn_cache"):
+        if per_row_pos:
+            # batched generation: each sequence writes at its own position
+            # (net-new vs the reference's batch=1 — SURVEY.md §2.5 DP row).
+            # Gated (pp off-turn) writes are pushed out of bounds and dropped
+            # — cheaper than a read-modify-write, and XLA's partitioner
+            # handles the scatter where it miscompiles the equivalent gather
+            # under manual pp. q_pos rows are contiguous, so the in-place
+            # kernel may take the write; a cache whose sequence GSPMD shards
+            # over sp keeps the scatter (a pallas_call would gather it whole).
+            k_cache, v_cache = _scatter_cache_write(
+                k_cache, v_cache, k, v, q_pos, write_gate,
+                kernel_cfg=cfg if sp_cache_mesh is None else None)
+        else:
+            pos0 = q_pos[:, 0]
+            k_w = _to_cache_dtype(k.transpose(0, 2, 1, 3), k_cache.dtype)
+            v_w = _to_cache_dtype(v.transpose(0, 2, 1, 3), v_cache.dtype)
+            # index literals pinned to the position dtype: bare Python 0s trace
+            # as int64 under x64 and dynamic_(update_)slice rejects mixed index
+            # dtypes — int32 everywhere keeps the program x64-proof (dlgrind
+            # DLG202 traces entry points under enable_x64)
+            zero = jnp.int32(0)
+            start = (zero, zero, pos0[0], zero)
+            if write_gate is not None:
+                k_w = jnp.where(write_gate, k_w,
+                                lax.dynamic_slice(k_cache, start, k_w.shape))
+                v_w = jnp.where(write_gate, v_w,
+                                lax.dynamic_slice(v_cache, start, v_w.shape))
+            k_cache = lax.dynamic_update_slice(k_cache, k_w, start)
+            v_cache = lax.dynamic_update_slice(v_cache, v_w, start)
+        if sp_cache_mesh is not None:
+            # keep the cache sp-sharded through the functional update:
+            # during ring prefill the T-sharded K/V reshards into the
+            # S-sharded cache (one K/V-sized shuffle per layer); decode's
+            # single-position write lands in the owning shard. Per-device
+            # cache stays seq_len/sp.
+            from jax.sharding import NamedSharding
 
-        from ..parallel.sharding import cache_pspec
+            from ..parallel.sharding import cache_pspec
 
-        cs = NamedSharding(sp_cache_mesh, cache_pspec(sp=True))
-        k_cache = jax.lax.with_sharding_constraint(k_cache, cs)
-        v_cache = jax.lax.with_sharding_constraint(v_cache, cs)
+            cs = NamedSharding(sp_cache_mesh, cache_pspec(sp=True))
+            k_cache = jax.lax.with_sharding_constraint(k_cache, cs)
+            v_cache = jax.lax.with_sharding_constraint(v_cache, cs)
 
     assert scale is None or (sp_mesh is None and sp_cache_mesh is None
                              and cfg.get("tp_mesh") is None), (
         "a softmax scale of its own under sp or tp")
-    if sp_mesh is not None:
-        # sequence-parallel prefill: the segment starts at pos 0 and IS the
-        # whole context so far, so attention runs q-chunk vs ring-rotating
-        # k/v chunks instead of against the cache (net-new vs the reference —
-        # SURVEY.md §5.7)
-        from ..parallel.ring_attention import ring_attention
+    with jax.named_scope("attn_core"):
+        if sp_mesh is not None:
+            # sequence-parallel prefill: the segment starts at pos 0 and IS
+            # the whole context so far, so attention runs q-chunk vs
+            # ring-rotating k/v chunks instead of against the cache (net-new
+            # vs the reference — SURVEY.md §5.7)
+            from ..parallel.ring_attention import ring_attention
 
-        att = ring_attention(q, k, v, sp_mesh, pos0=0)
-    elif sp_cache_mesh is not None:
-        # sp-sharded cache: per-chunk flash stats + exact psum merge. Must
-        # outrank the pallas branch — the pallas kernel is not shard_map'd,
-        # so routing it an sp-sharded cache would all-gather the full
-        # sequence per layer and void the seq_len/sp memory scaling.
-        from ..parallel.ring_attention import sp_cache_attention
+            att = ring_attention(q, k, v, sp_mesh, pos0=0)
+        elif sp_cache_mesh is not None:
+            # sp-sharded cache: per-chunk flash stats + exact psum merge.
+            # Must outrank the pallas branch — the pallas kernel is not
+            # shard_map'd, so routing it an sp-sharded cache would all-gather
+            # the full sequence per layer and void the seq_len/sp memory
+            # scaling.
+            from ..parallel.ring_attention import sp_cache_attention
 
-        att = sp_cache_attention(q, k_cache, v_cache, q_pos, sp_cache_mesh)
-    elif cfg.get("use_pallas") and _flash_ok(t, h, kvh):
-        # decode (T=1) and chunked prefill (T>1) both take the flash kernel:
-        # online-softmax in VMEM instead of the dense path's (B,T,KVH,G,S)
-        # score materialization in HBM (ops/pallas_attention.py)
-        if cfg.get("manual_tp"):
-            # already inside the fully-manual pp region: heads are local,
-            # call the kernel directly (no shard_map entry)
-            from ..ops.pallas_attention import flash_attention
+            att = sp_cache_attention(q, k_cache, v_cache, q_pos, sp_cache_mesh)
+        elif cfg.get("use_pallas") and _flash_ok(t, h, kvh):
+            # decode (T=1) and chunked prefill (T>1) both take the flash
+            # kernel: online-softmax in VMEM instead of the dense path's
+            # (B,T,KVH,G,S) score materialization in HBM
+            # (ops/pallas_attention.py)
+            if cfg.get("manual_tp"):
+                # already inside the fully-manual pp region: heads are local,
+                # call the kernel directly (no shard_map entry)
+                from ..ops.pallas_attention import flash_attention
 
-            att = flash_attention(
-                q, k_cache, v_cache, q_pos,
-                interpret=cfg.get("pallas_interpret", False), scale=scale)
-        elif cfg.get("tp_mesh") is not None:
-            # multi-device mesh: GSPMD can't partition a pallas_call, so the
-            # kernel runs per-shard inside shard_map (dp on batch, tp on
-            # kv-heads — head-local, no collective)
-            from ..parallel.tp_q80 import tp_flash_attention
+                att = flash_attention(
+                    q, k_cache, v_cache, q_pos,
+                    interpret=cfg.get("pallas_interpret", False), scale=scale)
+            elif cfg.get("tp_mesh") is not None:
+                # multi-device mesh: GSPMD can't partition a pallas_call, so
+                # the kernel runs per-shard inside shard_map (dp on batch, tp
+                # on kv-heads — head-local, no collective)
+                from ..parallel.tp_q80 import tp_flash_attention
 
-            att = tp_flash_attention(
-                q, k_cache, v_cache, q_pos, cfg["tp_mesh"],
-                interpret=cfg.get("pallas_interpret", False))
+                att = tp_flash_attention(
+                    q, k_cache, v_cache, q_pos, cfg["tp_mesh"],
+                    interpret=cfg.get("pallas_interpret", False))
+            else:
+                from ..ops.pallas_attention import flash_attention
+
+                att = flash_attention(
+                    q, k_cache, v_cache, q_pos,
+                    interpret=cfg.get("pallas_interpret", False), scale=scale)
         else:
-            from ..ops.pallas_attention import flash_attention
-
-            att = flash_attention(
-                q, k_cache, v_cache, q_pos,
-                interpret=cfg.get("pallas_interpret", False), scale=scale)
-    else:
-        att = decode_attention(q, k_cache, v_cache, q_pos,
-                               scale=scale)                # (B, T, H, hs)
-    out = matmul(att.reshape(b, t, h * hs), lw["wo"], **cfg)
+            att = decode_attention(q, k_cache, v_cache, q_pos,
+                                   scale=scale)                # (B, T, H, hs)
+    with jax.named_scope("attn_out"):
+        out = matmul(att.reshape(b, t, h * hs), lw["wo"], **cfg)
     return out, k_cache, v_cache
 
 
@@ -472,14 +492,14 @@ def _delta_block(x, lw, spec: ModelSpec, state, tail, rows: SegmentRows,
         ab = matmul(x, lw["w_ab"], **cfg).astype(f32)      # (B, T, 2H)
     with jax.named_scope("gdn_conv"):
         y, tail = _short_conv(qkv, tail, lw, rows, taps)
-    q = y[..., :h * dk].reshape(b, t, h, dk)
-    k = y[..., h * dk:2 * h * dk].reshape(b, t, h, dk)
-    v = y[..., 2 * h * dk:].reshape(b, t, h, dv)
 
     def unit(u):
         return u * lax.rsqrt(jnp.sum(u * u, -1, keepdims=True) + 1e-6)
 
     with jax.named_scope("gdn_rule"):
+        q = y[..., :h * dk].reshape(b, t, h, dk)
+        k = y[..., h * dk:2 * h * dk].reshape(b, t, h, dk)
+        v = y[..., 2 * h * dk:].reshape(b, t, h, dv)
         g = -jnp.exp(lw["a_log"]) * jax.nn.softplus(ab[..., :h]
                                                     + lw["dt_bias"])
         beta = spec.lin_beta_scale * jax.nn.sigmoid(ab[..., h:])
@@ -508,8 +528,8 @@ def _ssm_block(x, lw, spec: ModelSpec, state, tail, rows: SegmentRows, cfg):
     h, p, n = spec.ssm_heads, spec.ssm_head_dim, spec.ssm_d_state
     inner, gn = spec.ssm_inner, spec.ssm_groups * spec.ssm_d_state
     f32 = jnp.float32
-    u = rmsnorm(x, lw["rms_att"], spec.norm_eps)
     with jax.named_scope("ssm_proj"):
+        u = rmsnorm(x, lw["rms_att"], spec.norm_eps)
         if "wzx" in lw:
             zx = matmul(u, lw["wzx"], **cfg)
             z, xs = zx[..., :inner], zx[..., inner:]
@@ -520,8 +540,8 @@ def _ssm_block(x, lw, spec: ModelSpec, state, tail, rows: SegmentRows, cfg):
         y, tail = _short_conv(
             jnp.concatenate([xs, bcdt[..., :2 * gn]], axis=-1), tail, lw,
             rows, spec.ssm_conv_width)
-    xh = y[..., :inner].reshape(b, t, h, p)
     with jax.named_scope("ssm_scan"):
+        xh = y[..., :inner].reshape(b, t, h, p)
         dt = jax.nn.softplus(bcdt[..., 2 * gn:].astype(f32) + lw["dt_bias"])
         o, state = ssd_scan(
             xh, dt, -jnp.exp(lw["a_log"]),
@@ -555,20 +575,22 @@ def _pre_norm_tail(x, mix_out, lw, spec: ModelSpec, cfg, n_valid=None,
     """h = x + r mixer(norm(x)) (the mixer normed its own input); x' = h +
     r ffn(norm(h)), the FFN dense or, where the layer has a router, the
     routed experts (and the shared one, inside _moe_ffn)."""
-    x = x + _scaled(mix_out, spec).astype(x.dtype)
-    xb = rmsnorm(x, lw["rms_ffn"], spec.norm_eps)
-    ffn = (_moe_ffn(xb, lw, spec, cfg, n_valid, moe_counts)
-           if "moe_router" in lw else _dense_ffn(xb, lw, spec, cfg))
-    return x + _scaled(ffn, spec).astype(x.dtype)
+    with jax.named_scope("block_tail"):
+        x = x + _scaled(mix_out, spec).astype(x.dtype)
+    ffn = _normed_ffn(x, lw, spec, cfg, n_valid, moe_counts)
+    with jax.named_scope("block_tail"):
+        return x + _scaled(ffn, spec).astype(x.dtype)
 
 
 def _post_norm_tail(x, mix_out, lw, spec: ModelSpec, cfg):
     """h = x + norm(mixer(x)); x' = h + norm(ffn(h)): both norms on the
     sublayers' outputs."""
     eps = spec.norm_eps
-    x = x + rmsnorm(mix_out, lw["rms_att"], eps).astype(x.dtype)
+    with jax.named_scope("block_tail"):
+        x = x + rmsnorm(mix_out, lw["rms_att"], eps).astype(x.dtype)
     ffn = _dense_ffn(x, lw, spec, cfg)
-    return x + rmsnorm(ffn, lw["rms_ffn"], eps).astype(x.dtype)
+    with jax.named_scope("block_tail"):
+        return x + rmsnorm(ffn, lw["rms_ffn"], eps).astype(x.dtype)
 
 
 def _state_layer(x, lw, spec: ModelSpec, kind: LayerKind, state, tail, rows,
@@ -584,17 +606,30 @@ def _state_layer(x, lw, spec: ModelSpec, kind: LayerKind, state, tail, rows,
                            moe_counts), state, tail)
 
 
+def _normed_ffn(x, lw, spec: ModelSpec, cfg, n_valid=None, moe_counts=None):
+    """A pre-norm block's FFN sublayer: the norm of the residual stream,
+    then the FFN dense or, where the layer has a router, the routed experts
+    (and the shared one, inside _moe_ffn)."""
+    with jax.named_scope("ffn"):
+        xb = rmsnorm(x, lw["rms_ffn"], spec.norm_eps)
+    if "moe_router" in lw:
+        return _moe_ffn(xb, lw, spec, cfg, n_valid, moe_counts)
+    return _dense_ffn(xb, lw, spec, cfg)
+
+
 def _dense_ffn(xb, lw, spec: ModelSpec, cfg):
     """SwiGLU FFN (ref: src/llama2-tasks.cpp:158-189)."""
-    if "w13" in lw:
-        h13 = matmul(xb, lw["w13"], **cfg)  # fused gate|up (single-shard path)
-        hd = h13.shape[-1] // 2
-        gate, up = h13[..., :hd], h13[..., hd:]
-    else:
-        gate = matmul(xb, lw["w1"], **cfg)
-        up = matmul(xb, lw["w3"], **cfg)
-    hb = apply_hidden_act(gate, spec.hidden_act) * up
-    return matmul(hb, lw["w2"], **cfg)
+    with jax.named_scope("ffn"):
+        if "w13" in lw:
+            # fused gate|up (single-shard path)
+            h13 = matmul(xb, lw["w13"], **cfg)
+            hd = h13.shape[-1] // 2
+            gate, up = h13[..., :hd], h13[..., hd:]
+        else:
+            gate = matmul(xb, lw["w1"], **cfg)
+            up = matmul(xb, lw["w3"], **cfg)
+        hb = apply_hidden_act(gate, spec.hidden_act) * up
+        return matmul(hb, lw["w2"], **cfg)
 
 
 def _moe_ffn(xb, lw, spec: ModelSpec, cfg, n_valid=None, counts=None):
@@ -642,16 +677,19 @@ def _moe_ffn(xb, lw, spec: ModelSpec, cfg, n_valid=None, counts=None):
 
     in_place = reads_experts_in_place(lw["moe_up"], b * t, **cfg)
     if in_place or counts is not None:
-        held = top_idx - (spec.expert_offset if held_share else 0)
-        live = (held >= 0) & (held < spec.n_experts)              # (B, T, K)
-        if n_valid is not None:
-            live &= (jnp.arange(t)[None, :] < n_valid[:, None])[..., None]
-        # (B T K, E): pair p is live and its expert is held expert e
-        member = ((held[..., None] == jnp.arange(spec.n_experts))
-                  & live[..., None]).reshape(-1, spec.n_experts)
-        sizes = member.sum(axis=0, dtype=jnp.int32)               # (E,)
-        if counts is not None:
-            counts.append((jnp.sum(sizes > 0, dtype=jnp.int32), sizes.sum()))
+        with jax.named_scope("moe_routed"):
+            held = top_idx - (spec.expert_offset if held_share else 0)
+            live = (held >= 0) & (held < spec.n_experts)          # (B, T, K)
+            if n_valid is not None:
+                live &= (jnp.arange(t)[None, :]
+                         < n_valid[:, None])[..., None]
+            # (B T K, E): pair p is live and its expert is held expert e
+            member = ((held[..., None] == jnp.arange(spec.n_experts))
+                      & live[..., None]).reshape(-1, spec.n_experts)
+            sizes = member.sum(axis=0, dtype=jnp.int32)           # (E,)
+            if counts is not None:
+                counts.append((jnp.sum(sizes > 0, dtype=jnp.int32),
+                               sizes.sum()))
 
     def scatter_weights():
         # (B, T, E) dense scatter of the normalized top-k weights (0 for
@@ -669,7 +707,7 @@ def _moe_ffn(xb, lw, spec: ModelSpec, cfg, n_valid=None, counts=None):
 
     from ..parallel.ep_moe import EpRowWeight, ep_moe_ffn
 
-    if isinstance(lw["moe_up"], EpRowWeight):
+    def ep_experts():
         # expert-parallel placement (ep mesh axis): each ep shard computes
         # only its local experts, masked by the scattered routing weights
         e_weights = scatter_weights()
@@ -698,6 +736,10 @@ def _moe_ffn(xb, lw, spec: ModelSpec, cfg, n_valid=None, counts=None):
             reduce=cfg.get("tp_reduce", "exact"),
         ).astype(xb.dtype)
 
+    if isinstance(lw["moe_up"], EpRowWeight):
+        with jax.named_scope("moe_routed"):
+            return ep_experts()
+
     def expert_apply(e):
         gate = _expert_matmul(xb, lw["moe_gate"], e, cfg)
         up = _expert_matmul(xb, lw["moe_up"], e, cfg)
@@ -712,29 +754,28 @@ def _moe_ffn(xb, lw, spec: ModelSpec, cfg, n_valid=None, counts=None):
             return acc + _dense_ffn(xb, {"w1": lw["sh_w1"], "w2": lw["sh_w2"],
                                          "w3": lw["sh_w3"]}, spec, cfg)
 
-    if in_place:
-        with jax.named_scope("moe_routed"):
-            return with_shared(_grouped_experts(
-                xb, lw, spec, cfg, held, live, member, sizes, weights))
-
-    acc = jnp.zeros((b, t, d), xb.dtype)
-    if t == 1 and b == 1 and not held_share:
-        # one row: only its K active experts, by their traced indices (the
-        # reference likewise computes just the active experts —
-        # grok1-tasks.cpp:128-143)
-        idx = top_idx.reshape(k_active)
-        for ae in range(k_active):  # K is tiny and static — unrolled
-            out = expert_apply(idx[ae])
-            acc = acc + weights[..., ae, None].astype(out.dtype) * out
-        return with_shared(acc)
-
-    # every other shape: every held expert for every row, masked by the
-    # routing weights
-    e_weights = scatter_weights()
-    with jax.named_scope("moe_routed"):
+    def sliced_experts():
+        acc = jnp.zeros((b, t, d), xb.dtype)
+        if t == 1 and b == 1 and not held_share:
+            # one row: only its K active experts, by their traced indices
+            # (the reference likewise computes just the active experts —
+            # grok1-tasks.cpp:128-143)
+            idx = top_idx.reshape(k_active)
+            for ae in range(k_active):  # K is tiny and static — unrolled
+                out = expert_apply(idx[ae])
+                acc = acc + weights[..., ae, None].astype(out.dtype) * out
+            return acc
+        # every other shape: every held expert for every row, masked by
+        # the routing weights
+        e_weights = scatter_weights()
         for e in range(spec.n_experts):
             out = expert_apply(e)
             acc = acc + e_weights[..., e, None].astype(out.dtype) * out
+        return acc
+
+    with jax.named_scope("moe_routed"):
+        acc = (_grouped_experts(xb, lw, spec, cfg, held, live, member, sizes,
+                                weights) if in_place else sliced_experts())
     return with_shared(acc)
 
 
@@ -773,8 +814,9 @@ def _grouped_experts(xb, lw, spec: ModelSpec, cfg, held, live, member, sizes,
 
     x = xb.reshape(rows, d)
     if cfg["activation_q80"]:  # once a token row, not once a pair
-        x = dequantize_q80_jax(*quantize_q80_jax(x),
-                               dtype=cfg["compute_dtype"])
+        with jax.named_scope("act_q80"):
+            x = dequantize_q80_jax(*quantize_q80_jax(x),
+                                   dtype=cfg["compute_dtype"])
     # a token's K pairs in ascending expert order
     order = jnp.argsort(held, axis=-1)                            # (B, T, K)
     dest, live, weights = (jnp.take_along_axis(a, order, axis=-1)
@@ -891,11 +933,11 @@ def _layer(x, lw, spec: ModelSpec, k_cache, v_cache, q_pos, cfg, sp_mesh=None,
         attn_out, k_cache = _mla_attention_block(
             x, lw, spec, k_cache, q_pos, cfg, per_row_pos=per_row_pos,
             write_gate=write_gate)
-        x = x + attn_out.astype(x.dtype)
-        xb = rmsnorm(x, lw["rms_ffn"], spec.norm_eps)
-        ffn = (_moe_ffn(xb, lw, spec, cfg, n_valid, moe_counts)
-               if "moe_router" in lw else _dense_ffn(xb, lw, spec, cfg))
-        return x + ffn.astype(x.dtype), k_cache, None
+        with jax.named_scope("block_tail"):
+            x = x + attn_out.astype(x.dtype)
+        ffn = _normed_ffn(x, lw, spec, cfg, n_valid, moe_counts)
+        with jax.named_scope("block_tail"):
+            return x + ffn.astype(x.dtype), k_cache, None
     attn_out, k_cache, v_cache = _attention_block(
         x, lw, spec, k_cache, v_cache, q_pos, cfg, sp_mesh=sp_mesh,
         sp_cache_mesh=sp_cache_mesh, per_row_pos=per_row_pos,
@@ -906,11 +948,15 @@ def _layer(x, lw, spec: ModelSpec, k_cache, v_cache, q_pos, cfg, sp_mesh=None,
                 v_cache)
     if spec.arch == ArchType.GROK1:
         # post-attention norm BEFORE residual add (ref: grok1-tasks.cpp:16-41)
-        x = x + rmsnorm(attn_out, lw["rms_ffn"]).astype(x.dtype)
-        xb = rmsnorm(x, lw["rms_moe"])          # ref: grok1-tasks.cpp:43-54
+        with jax.named_scope("block_tail"):
+            x = x + rmsnorm(attn_out, lw["rms_ffn"]).astype(x.dtype)
+        with jax.named_scope("ffn"):
+            xb = rmsnorm(x, lw["rms_moe"])      # ref: grok1-tasks.cpp:43-54
         moe_out = _moe_ffn(xb, lw, spec, cfg, n_valid, moe_counts)
-        moe_out = rmsnorm(moe_out, lw["rms_ffn2"])  # ref: grok1-tasks.cpp:244-256
-        return x + moe_out.astype(x.dtype), k_cache, v_cache
+        with jax.named_scope("block_tail"):
+            # ref: grok1-tasks.cpp:244-256
+            moe_out = rmsnorm(moe_out, lw["rms_ffn2"])
+            return x + moe_out.astype(x.dtype), k_cache, v_cache
     # LLAMA (dense; ref: llama2-tasks.cpp:125-131), MIXTRAL (experts; ref:
     # mixtral-tasks.cpp:24), GRANITE_HYBRID (experts, a shared one, the
     # residual multiplier): one pre-norm serial block, told apart by what
@@ -974,27 +1020,30 @@ def forward(
                pallas_interpret=pallas_interpret)
     b, t = tokens.shape
 
-    if vocab_mesh is not None:
-        from ..ops.sharded_vocab import embed_tokens_sharded
+    with jax.named_scope("embed"):
+        if vocab_mesh is not None:
+            from ..ops.sharded_vocab import embed_tokens_sharded
 
-        x = embed_tokens_sharded(params["tok_emb"], tokens, vocab_mesh,
-                                 tuple(vocab_axes), compute_dtype)
-    else:
-        x = params["tok_emb"][tokens].astype(compute_dtype)  # ref: tasks.cpp:202-203
-    if spec.arch == ArchType.GROK1:
-        x = x * GROK_INPUT_SCALE
-    if spec.embedding_scale != 1.0:
-        x = x * jnp.asarray(spec.embedding_scale, x.dtype)
+            x = embed_tokens_sharded(params["tok_emb"], tokens, vocab_mesh,
+                                     tuple(vocab_axes), compute_dtype)
+        else:
+            # ref: tasks.cpp:202-203
+            x = params["tok_emb"][tokens].astype(compute_dtype)
+        if spec.arch == ArchType.GROK1:
+            x = x * GROK_INPUT_SCALE
+        if spec.embedding_scale != 1.0:
+            x = x * jnp.asarray(spec.embedding_scale, x.dtype)
 
     s_all: list = []
     conv_all: list = []
     moe_counts: list | None = [] if expert_counts else None
     per_row_pos = getattr(pos0, "ndim", 0) == 1
-    if per_row_pos:
-        q_pos = pos0[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
-    else:
-        q_pos = pos0 + jnp.arange(t, dtype=jnp.int32)[None, :]
-        q_pos = jnp.broadcast_to(q_pos, (b, t))
+    with jax.named_scope("embed"):   # the segment's positions, with its rows
+        if per_row_pos:
+            q_pos = pos0[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
+        else:
+            q_pos = pos0 + jnp.arange(t, dtype=jnp.int32)[None, :]
+            q_pos = jnp.broadcast_to(q_pos, (b, t))
 
     if pp_mesh is not None:
         # layers placed in stages over pp (parallel/pp.py): long segments
@@ -1021,9 +1070,10 @@ def forward(
         k_all = []
         v_all = []
         kinds, at = spec.layer_kinds, spec.cache_index
-        rows = (_segment_rows(spec, cache, pos0, b, t, logit_index,
-                              logits_for_all)
-                if spec.has_state or spec.is_moe else None)
+        with jax.named_scope("embed"):
+            rows = (_segment_rows(spec, cache, pos0, b, t, logit_index,
+                                  logits_for_all)
+                    if spec.has_state or spec.is_moe else None)
         for l in range(spec.n_layers):
             if kinds[l].has_state:
                 x, s_new, c_new = _state_layer(
@@ -1046,24 +1096,28 @@ def forward(
             if v_new is not None:
                 v_all.append(v_new)
 
-    x = rmsnorm(x, params["rms_final"], spec.norm_eps)  # ref: llama2-tasks.cpp:222-234
-    if not logits_for_all:
-        if logit_index is None:
-            x = x[:, -1, :]
-        else:
-            x = jnp.take_along_axis(
-                x, jnp.broadcast_to(logit_index.reshape(-1, 1, 1),
-                                    (x.shape[0], 1, x.shape[-1])), axis=1)[:, 0]
-    logits = matmul(x, params["wcls"], **cfg).astype(jnp.float32)
-    if spec.arch == ArchType.GROK1:
-        logits = logits * GROK_LOGIT_SCALE  # ref: grok1-tasks.cpp:269-272
-    if spec.logit_scale != 1.0:
-        logits = logits * spec.logit_scale
+    with jax.named_scope("head"):
+        # ref: llama2-tasks.cpp:222-234
+        x = rmsnorm(x, params["rms_final"], spec.norm_eps)
+        if not logits_for_all:
+            if logit_index is None:
+                x = x[:, -1, :]
+            else:
+                x = jnp.take_along_axis(
+                    x, jnp.broadcast_to(logit_index.reshape(-1, 1, 1),
+                                        (x.shape[0], 1, x.shape[-1])),
+                    axis=1)[:, 0]
+        logits = matmul(x, params["wcls"], **cfg).astype(jnp.float32)
+        if spec.arch == ArchType.GROK1:
+            logits = logits * GROK_LOGIT_SCALE  # ref: grok1-tasks.cpp:269-272
+        if spec.logit_scale != 1.0:
+            logits = logits * spec.logit_scale
     cache = KVCache(tuple(k_all), tuple(v_all), tuple(s_all), tuple(conv_all))
     if expert_counts:
         # (a pp region's layers, traced elsewhere, are not counted)
-        return logits, cache, jnp.asarray(
-            [sum(c[i] for c in moe_counts) for i in range(2)], jnp.int32)
+        with jax.named_scope("moe_routed"):
+            return logits, cache, jnp.asarray(
+                [sum(c[i] for c in moe_counts) for i in range(2)], jnp.int32)
     return logits, cache
 
 

@@ -17,6 +17,7 @@ from __future__ import annotations
 import math
 from typing import Union
 
+import jax
 import jax.numpy as jnp
 
 from ..quants.jax_codec import QuantizedTensor, dequantize_q40_jax, quantize_q80_jax, dequantize_q80_jax
@@ -98,8 +99,10 @@ def matmul(
     psum — no shard_map entry (which cannot nest).
     """
     if activation_q80:
-        q, scales = quantize_q80_jax(x)
-        x = dequantize_q80_jax(q, scales, dtype=compute_dtype)
+        # a DEVICE_SCOPES name (models/scopes.py), nested under the caller's
+        with jax.named_scope("act_q80"):
+            q, scales = quantize_q80_jax(x)
+            x = dequantize_q80_jax(q, scales, dtype=compute_dtype)
     else:
         x = x.astype(compute_dtype)
 
@@ -187,8 +190,9 @@ def fused_expert_matmul(
     from .pallas_q40 import q40_expert_matmul
 
     if activation_q80:  # same round-trip matmul() applies
-        q, scales = quantize_q80_jax(x)
-        x = dequantize_q80_jax(q, scales, dtype=compute_dtype)
+        with jax.named_scope("act_q80"):
+            q, scales = quantize_q80_jax(x)
+            x = dequantize_q80_jax(q, scales, dtype=compute_dtype)
     return q40_expert_matmul(x.astype(compute_dtype), w, e, used,
                              out_dtype=compute_dtype,
                              interpret=pallas_interpret,

@@ -211,44 +211,14 @@ def per_step_op_ms(trace_dir: str, markers: tuple = COLLECTIVE_MARKERS,
     A "step" is one executed XLA module (the engine's jitted forward): the
     device plane's "XLA Modules" line has one event per execution, and each
     op event on the "XLA Ops"/"Async XLA Ops" lines is bucketed into the
-    module span containing it. Returns one float per module execution in
+    module span containing it (the program's one xplane walker,
+    runtime/profiler.walk_trace). Returns one float per module execution in
     timeline order; [] when the trace has no device plane (CPU runs) — the
     caller falls back to the microbench."""
-    import bisect
-    import glob
+    from .profiler import per_execution_ms, walk_trace
 
-    from jax.profiler import ProfileData
-
-    files = sorted(glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True))
-    if not files:
-        return []
-    pd = ProfileData.from_file(files[-1])
-    for plane in pd.planes:
-        if not plane.name.startswith("/device:"):
-            continue
-        lines = {ln.name: ln for ln in plane.lines}
-        mods = lines.get("XLA Modules")
-        if mods is None:
-            continue
-        spans = sorted(
-            (e.start_ns, e.end_ns) for e in mods.events
-            if module_hint is None or module_hint in e.name)
-        if not spans:
-            continue
-        starts = [s for s, _ in spans]
-        out = [0.0] * len(spans)
-        for ln_name in ("XLA Ops", "Async XLA Ops"):
-            ops = lines.get(ln_name)
-            if ops is None:
-                continue
-            for e in ops.events:
-                if not any(m in e.name for m in markers):
-                    continue
-                i = bisect.bisect_right(starts, e.start_ns) - 1
-                if i >= 0 and e.start_ns < spans[i][1]:
-                    out[i] += e.duration_ns / 1e6
-        return out
-    return []
+    trace = walk_trace(trace_dir, op_lines=("XLA Ops", "Async XLA Ops"))
+    return per_execution_ms(trace.get("devices", ()), markers, module_hint)
 
 
 def measure_allreduce_ms(mesh, payload_elems: int, iters: int = 16,

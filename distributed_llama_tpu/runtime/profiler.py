@@ -48,6 +48,8 @@ callable again). Docs: docs/observability.md ("Device tier").
 
 from __future__ import annotations
 
+import functools
+import os
 import re
 import threading
 import time
@@ -585,6 +587,9 @@ class Profiler:
         # against the work of the SAME seconds (a closed loop's contexts
         # swing together, so a window's mean is not the capture's)
         self.last_counters: dict | None = None
+        # the last capture's report of itself (capture_report), /stats
+        # `capture.report`: {"error": ...} where none could be made
+        self.last_report: dict | None = None
         self._lock = threading.Lock()
         self._busy = False  # dlrace: guarded-by(self._lock)
 
@@ -597,12 +602,13 @@ class Profiler:
         200 means the trace is on disk. The Python tracer is off: with it
         on, the stop froze serving for seconds and the host plane held
         frames of every thread instead of the program's spans. Returns
-        {"dir", "ms", "t_start_mono", "t_stop_mono", "stop_ms"} — the
-        ``perf_counter`` instants between which the trace ran (the clock
-        of the tracer's ring records) and how long the stop took; raises
+        {"dir", "ms", "t_start_mono", "t_stop_mono", "stop_ms", "report",
+        "report_ms"} — the ``perf_counter`` instants between which the
+        trace ran (the clock of the tracer's ring records), how long the
+        stop took, and what the capture says of itself (`make_report`,
+        after the export, in a child process; also `report.json` beside
+        the trace, and `last_report`) with the ms that took; raises
         RuntimeError("capture busy") when a trace is already running."""
-        import os
-
         import jax
 
         with self._lock:
@@ -629,13 +635,16 @@ class Profiler:
                 t_stop = time.perf_counter()
                 jax.profiler.stop_trace()
             stop_ms = (time.perf_counter() - t_stop) * 1e3
+            report, report_ms = make_report(directory)
+            self.last_report = report
             self.captures += 1
             if TRACER.enabled:
                 TRACER.event("profile", 0, dir=directory, ms=float(ms),
                              t_start_mono=t_start, t_stop_mono=t_stop)
             return {"dir": directory, "ms": float(ms),
                     "t_start_mono": t_start, "t_stop_mono": t_stop,
-                    "stop_ms": round(stop_ms, 3)}
+                    "stop_ms": round(stop_ms, 3), "report": report,
+                    "report_ms": round(report_ms, 3)}
         finally:
             with self._lock:
                 self._busy = False
@@ -643,6 +652,544 @@ class Profiler:
     def reset(self) -> None:
         self.captures = 0
         self.last_counters = None
+        self.last_report = None
 
 
 PROFILER = Profiler()
+
+
+# -- capture report ----------------------------------------------------------
+#
+# What a capture says of itself: the one xplane walker of the program. The
+# arithmetic is on plain lists (tests feed it hand-made events); only
+# `walk_trace` and `hlo_op_names` touch a file. Times are seconds on the
+# profiler's clock, which the device planes and the host planes share.
+
+REPORT_LIMIT_S = 60.0    # the report's child process (make_report)
+REPORT_MODULE = "distributed_llama_tpu.runtime.profiler"   # run as a script
+UNSCOPED = "unscoped"    # an op whose op_name carries no DEVICE_SCOPES name
+NO_SPAN = "no_span"      # idle time under none of the program's spans
+_PALLAS_TARGET = 'custom_call_target="tpu_custom_call"'
+
+
+def stem(name: str) -> str:
+    """`jit_slot_prefill_chunk_32(123)` -> `slot_prefill_chunk_32`;
+    `%copy_bitcast_fusion.12 = ...` -> `copy_bitcast_fusion` (an op event's
+    name is its whole HLO line: only the part before ` = ` counts)."""
+    name = name.split("(")[0].split(" = ")[0].lstrip("%").strip()
+    if name.startswith("jit_"):
+        name = name[4:]
+    return re.sub(r"\.\d+$", "", name) or "op"
+
+
+@functools.lru_cache(maxsize=None)   # a capture has a few thousand names
+def scope_path(op_name: str | None) -> str:
+    """A framework op name (`jit(slot_decode_step)/ffn/act_q80/jit(
+    quantize_q80_jax)/reduce_max`) cut down to the DEVICE_SCOPES names on
+    it, joined by `/`; UNSCOPED where it carries none."""
+    from ..models.scopes import DEVICE_SCOPES
+
+    if not op_name:
+        return UNSCOPED
+    # an op the compiler merged from two carries both names, `a;b`: the first
+    parts = op_name.split(";")[0].split("/")
+    # XLA's inliner may write a callee's whole path behind the call's
+    # (`jit(f)/moe_routed/jit(searchsorted)/jit(f)/moe_routed/...`): the
+    # path starts at the LAST mention of the program
+    last = len(parts) - 1 - parts[::-1].index(parts[0])
+    names = [part for part in parts[last:] if part in DEVICE_SCOPES]
+    return "/".join(names) or UNSCOPED
+
+
+def innermost(events: list, inside: dict | None = None) -> list:
+    """Who owns each instant that some event covers: (start, end, index)
+    segments, the index that of the event of `events` ((start, end, ...)
+    tuples) that started last among those open. Summed by index they are
+    each event's SELF time, its duration less what it contains, and all
+    together the union of the events: a `while` and the kernels of its
+    body, an async pair around other ops, a span and its phases. `inside`
+    receives {index: index of the event open when it started}."""
+    order = sorted(range(len(events)),
+                   key=lambda i: (events[i][0], -events[i][1]))
+    out, stack, at = [], [], 0.0
+
+    def run_to(upto: float) -> None:
+        nonlocal at
+        while stack:
+            end, i = stack[-1]
+            if end <= at:
+                stack.pop()
+                continue
+            if at >= upto:
+                return
+            cut = min(end, upto)
+            out.append((at, cut, i))
+            at = cut
+        at = max(at, upto)
+
+    for i in order:
+        start, end = events[i][0], events[i][1]
+        if end <= start:
+            continue
+        run_to(start)
+        if stack and inside is not None:
+            inside[i] = stack[-1][1]
+        stack.append((end, i))
+    run_to(float("inf"))
+    return out
+
+
+def _execution_of(starts: list, spans: list, t: float):
+    """Index of the (start, end, ...) span of sorted `spans` that holds
+    instant t (`starts`: their starts), or None."""
+    import bisect
+
+    i = bisect.bisect_right(starts, t) - 1
+    return i if i >= 0 and t < spans[i][1] else None
+
+
+def program_scopes(modules: list, ops: list) -> dict:
+    """{program: {"executions", "device_ms", "busy_ms", "scopes"}} of one
+    device. modules: (start, end, name) of the `XLA Modules` line, one an
+    execution; ops: (start, end, name, op_name) of the `XLA Ops` line,
+    op_name the framework's (None: the plane has none for it).
+
+    `scopes` is {scope path: {"kernel": {kernel: ms}, "xla": {stem: ms}}}:
+    mean SELF milliseconds an execution (`innermost`), a Pallas call under
+    its kernel's name, everything else under its HLO instruction's stem
+    (`copy`, `fusion`, `reshape`, `while`, ...). An op that names no line
+    and contains others (the compiler clones a loop and drops its name)
+    takes the scope its contents share. `busy_ms` is their sum, the union
+    of the program's ops; `device_ms` the execution's own length."""
+    mods = sorted(modules)
+    starts = [m[0] for m in mods]
+    self_s = [0.0] * len(ops)
+    parent_of: dict[int, int] = {}
+    for a, b, i in innermost(ops, parent_of):
+        self_s[i] += b - a
+    paths = [scope_path(op[3]) for op in ops]
+    contents: dict[int, list] = {}
+    for child, parent in parent_of.items():
+        if paths[parent] == UNSCOPED and paths[child] != UNSCOPED:
+            contents.setdefault(parent, []).append(paths[child].split("/"))
+    for parent, kids in contents.items():
+        # element-wise on lists of names: the scopes all its contents share
+        paths[parent] = "/".join(os.path.commonprefix(kids)) or UNSCOPED
+    out: dict = {}
+    for s, e, name in mods:
+        prog = out.setdefault(stem(name), {"executions": 0, "device_ms": 0.0,
+                                           "busy_ms": 0.0, "scopes": {}})
+        prog["executions"] += 1
+        prog["device_ms"] += (e - s) * 1e3
+    for i, op in enumerate(ops):
+        at = _execution_of(starts, mods, op[0])
+        if at is None or self_s[i] <= 0.0:
+            continue
+        prog = out[stem(mods[at][2])]
+        kernel = _PALLAS_TARGET in op[2]
+        kind = prog["scopes"].setdefault(paths[i], {"kernel": {}, "xla": {}})[
+            "kernel" if kernel else "xla"]
+        key = stem(op[2])
+        kind[key] = kind.get(key, 0.0) + self_s[i] * 1e3
+        prog["busy_ms"] += self_s[i] * 1e3
+    for prog in out.values():
+        n = prog["executions"]
+        prog["device_ms"] = round(prog["device_ms"] / n, 4)
+        prog["busy_ms"] = round(prog["busy_ms"] / n, 4)
+        for kinds in prog["scopes"].values():
+            for kind in kinds.values():
+                for key in kind:
+                    kind[key] = round(kind[key] / n, 4)
+    return out
+
+
+def idle_by_span(ops: list, window: tuple, spans: list) -> dict:
+    """The seconds of `window` in which no op of `ops` ran, split by
+    OVERLAP over the innermost of `spans` ((start, end, name): the
+    program's own, trace.SPAN_NAMES) open at that instant, NO_SPAN for the
+    rest: a gap that runs through three phases gives each its seconds, and
+    their parent only what lies between them."""
+    owner = innermost(spans)
+    totals: dict[str, float] = {}
+    k, at = 0, window[0]
+
+    def split(a: float, b: float) -> None:
+        nonlocal k
+        while k < len(owner) and owner[k][1] <= a:
+            k += 1
+        j = k
+        while a < b:
+            if j < len(owner) and owner[j][0] < b:
+                s, e, i = owner[j]
+                if s > a:
+                    totals[NO_SPAN] = totals.get(NO_SPAN, 0.0) + s - a
+                name = spans[i][2]
+                totals[name] = (totals.get(name, 0.0)
+                                + min(e, b) - max(s, a))
+                a = min(e, b)
+                j += 1
+            else:
+                totals[NO_SPAN] = totals.get(NO_SPAN, 0.0) + b - a
+                a = b
+
+    busy = sorted((max(op[0], window[0]), min(op[1], window[1]))
+                  for op in ops)
+    for s, e in busy:
+        if e <= s:
+            continue
+        if s > at:
+            split(at, s)
+        at = max(at, e)
+    if window[1] > at:
+        split(at, window[1])
+    return totals
+
+
+def report_events(modules: list, ops: list, spans: list) -> dict:
+    """The report of one device plane: `program_scopes` of its step
+    programs, and `idle_by_span` of the captured window (from the first op
+    or execution to the last). {} for a plane without events."""
+    edges = [(x[0], x[1]) for x in ops] + [(x[0], x[1]) for x in modules]
+    if not edges:
+        return {}
+    window = (min(s for s, _ in edges), max(e for _, e in edges))
+    idle = idle_by_span(ops, window, spans)
+    idle_s = sum(idle.values())
+    return {"window_s": round(window[1] - window[0], 6),
+            "busy_s": round(window[1] - window[0] - idle_s, 6),
+            "idle_s": round(idle_s, 6),
+            "idle": {k: round(v, 6) for k, v in sorted(
+                idle.items(), key=lambda kv: -kv[1])},
+            "programs": program_scopes(modules, ops)}
+
+
+def _varint(buf, i: int) -> tuple[int, int]:
+    out = shift = 0
+    while True:
+        b = buf[i]
+        i += 1
+        out |= (b & 0x7F) << shift
+        if b < 0x80:
+            return out, i
+        shift += 7
+
+
+def _fields(buf, i: int, end: int):
+    """(field number, value) of one protobuf message in buf[i:end]: a
+    varint's integer, a length-delimited field's (start, end) in buf;
+    fixed-width fields are skipped."""
+    while i < end:
+        tag, i = _varint(buf, i)
+        wire = tag & 7
+        if wire == 0:
+            v, i = _varint(buf, i)
+            yield tag >> 3, v
+        elif wire == 2:
+            n, i = _varint(buf, i)
+            yield tag >> 3, (i, i + n)
+            i += n
+        elif wire == 1:
+            i += 8
+        elif wire == 5:
+            i += 4
+        else:
+            raise ValueError(f"wire type {wire} at byte {i}")
+
+
+def _map_entry(buf, span: tuple) -> tuple[int, tuple]:
+    key, value = 0, (0, 0)
+    for f, v in _fields(buf, *span):
+        if f == 1:
+            key = v
+        elif f == 2:
+            value = v
+    return key, value
+
+
+def _packed_ints(buf, value) -> list:
+    """A repeated int64 field's values: one varint, or a packed run."""
+    if isinstance(value, int):
+        return [value]
+    out, i = [], value[0]
+    while i < value[1]:
+        v, i = _varint(buf, i)
+        out.append(v)
+    return out
+
+
+def _module_op_names(buf, span: tuple) -> dict:
+    """{instruction name: framework op name} of one HloProto (.hlo_module =
+    1; HloModuleProto .computations = 3; HloComputationProto .instructions
+    = 2, .is_fusion_computation = 7; HloInstructionProto .name = 1, .opcode
+    = 2, .metadata = 7 (OpMetadata .op_name = 2), .id = 35, .operand_ids =
+    36), over the computations whose instructions run as ops (not a
+    fusion's inside). An instruction that names no line of the program (the
+    compiler's own: a relayout `copy`, an async pair's `copy-done`, a
+    `bitcast`) takes the name of the first instruction that does among
+    those that read what it makes, breadth first through its users in its
+    computation; a `while`, `conditional` or `call` without a name keeps
+    none (program_scopes gives it the scope its contents share)."""
+    names: dict = {}
+    for f, module in _fields(buf, *span):
+        if f != 1:
+            continue
+        for g, comp in _fields(buf, *module):
+            if g != 3:
+                continue
+            rows, fused = [], False
+            for h, v in _fields(buf, *comp):
+                if h == 7:
+                    fused = bool(v)
+                elif h == 2:
+                    name = opcode = op_name = ""
+                    uid, operands = 0, []
+                    for k, w in _fields(buf, *v):
+                        if k == 1:
+                            name = bytes(buf[w[0]:w[1]]).decode()
+                        elif k == 2:
+                            opcode = bytes(buf[w[0]:w[1]]).decode()
+                        elif k == 7:
+                            for m, x in _fields(buf, *w):
+                                if m == 2:
+                                    op_name = bytes(buf[x[0]:x[1]]).decode(
+                                        "utf-8", "replace")
+                        elif k == 35:
+                            uid = w
+                        elif k == 36:
+                            operands += _packed_ints(buf, w)
+                    rows.append((uid, name, opcode, op_name, operands))
+            if fused:
+                continue
+            own = {uid: op_name for uid, _, _, op_name, _ in rows
+                   if op_name.startswith("jit(")}
+            users: dict = {}
+            for uid, _, _, _, operands in rows:
+                for o in operands:
+                    users.setdefault(o, []).append(uid)
+            for uid, name, opcode, _, _ in rows:
+                found = own.get(uid)
+                if found is None and opcode not in ("while", "conditional",
+                                                    "call"):
+                    seen, queue = {uid}, [uid]
+                    while queue and found is None and len(seen) < 256:
+                        for u in users.get(queue.pop(0), ()):
+                            if u in own:
+                                found = own[u]
+                                break
+                            if u not in seen:
+                                seen.add(u)
+                                queue.append(u)
+                if found is not None:
+                    names[name] = found
+    return names
+
+
+def hlo_op_names(path: str) -> dict:
+    """{program id: {HLO instruction name: framework op name}} of the step
+    programs a capture ran, from the compiled modules the `.xplane.pb`
+    itself carries (plane `/host:metadata`: one event metadata a program,
+    its id the program's, one bytes stat: the HloProto), where
+    `jax.named_scope` wrote the scopes as `op_name`; read off the wire
+    format (XSpace .planes = 1; XPlane .name = 2, .event_metadata = 4;
+    XEventMetadata .id = 1, .stats = 5; XStat .bytes_value = 6), since
+    jax's ProfileData shows neither a plane's metadata nor this one's
+    bytes. `_module_op_names` says what an op without a name of its own
+    is given."""
+    import mmap
+
+    out: dict = {}
+    with open(path, "rb") as f, mmap.mmap(f.fileno(), 0,
+                                          access=mmap.ACCESS_READ) as buf:
+        for f1, plane in _fields(buf, 0, len(buf)):
+            if f1 != 1:
+                continue
+            entries = [v for g, v in _fields(buf, *plane) if g == 4]
+            if not any(g == 2 and bytes(buf[v[0]:v[1]]) == b"/host:metadata"
+                       for g, v in _fields(buf, *plane)):
+                continue
+            for entry in entries:
+                _, meta = _map_entry(buf, entry)
+                program, proto = 0, None
+                for h, w in _fields(buf, *meta):
+                    if h == 1:
+                        program = w & (2 ** 64 - 1)
+                    elif h == 5:
+                        for j, x in _fields(buf, *w):
+                            if j == 6:
+                                proto = x
+                if proto is not None:
+                    out[program] = _module_op_names(buf, proto)
+    return out
+
+
+def make_report(directory: str, limit_s: float | None = None) -> tuple:
+    """(report, ms it took: also the report's `report_ms`) of the capture
+    under `directory`: `capture_report` in a CHILD process (this module as
+    a script, on the CPU, at most limit_s seconds, REPORT_LIMIT_S by
+    default), because a Python walk over 10^5-10^6 events here would share
+    the interpreter with the scheduler's thread; also written as
+    `report.json` there. A capture without a device plane
+    (a CPU run) starts no child. The report of a child that fails or runs
+    out of time is {"error": ...}: a capture never fails for its report."""
+    import json
+    import subprocess
+    import sys
+
+    t0 = time.perf_counter()
+    limit_s = REPORT_LIMIT_S if limit_s is None else limit_s
+    path = newest_xplane(directory)
+    try:
+        if path is None or not device_planes(path):
+            report = {"error": "no device plane in the capture"}
+        else:
+            root = os.path.dirname(os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__))))
+            env = dict(os.environ, JAX_PLATFORMS="cpu")
+            env["PYTHONPATH"] = root + os.pathsep + env.get("PYTHONPATH", "")
+            done = subprocess.run(
+                [sys.executable, "-m", REPORT_MODULE, directory], env=env,
+                capture_output=True, text=True, timeout=limit_s)
+            if done.returncode != 0:
+                raise RuntimeError(f"exit code {done.returncode}: "
+                                   + done.stderr.strip()[-400:])
+            report = json.loads(done.stdout.strip().splitlines()[-1])
+    except subprocess.TimeoutExpired:
+        report = {"error": f"no report within {limit_s:g} s"}
+    except Exception as e:  # noqa: BLE001 — whatever the child did
+        report = {"error": f"{type(e).__name__}: {e}"[:500]}
+    took_ms = (time.perf_counter() - t0) * 1e3
+    report["report_ms"] = round(took_ms, 3)   # /stats and the file say it too
+    try:
+        with open(os.path.join(directory, "report.json"), "w") as f:
+            json.dump(report, f)
+    except OSError:
+        pass
+    return report, took_ms
+
+
+def device_planes(path: str) -> list:
+    """Names of the `/device:` planes of an `.xplane.pb`, off the wire
+    format (the events are skipped whole)."""
+    import mmap
+
+    with open(path, "rb") as f, mmap.mmap(f.fileno(), 0,
+                                          access=mmap.ACCESS_READ) as buf:
+        names = [buf[v[0]:v[1]].decode("utf-8", "replace")
+                 for f1, plane in _fields(buf, 0, len(buf)) if f1 == 1
+                 for g, v in _fields(buf, *plane) if g == 2]
+    return [n for n in names if n.startswith("/device:")]
+
+
+def newest_xplane(trace_dir: str) -> str | None:
+    import glob
+
+    files = sorted(glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True))
+    return files[-1] if files else None
+
+
+def walk_trace(trace_dir: str, *, op_lines: tuple = ("XLA Ops",),
+               devices: int | None = None, host_names: tuple = ()) -> dict:
+    """The newest `.xplane.pb` under trace_dir as plain lists: {"file",
+    "devices": [{"plane", "modules": [(start, end, name)], "ops": [(start,
+    end, name)]}] (the `XLA Modules` line, one event an execution, and the
+    events of `op_lines`; the first `devices` device planes, None: all),
+    "host": [(start, end, name)] of the host planes' events named in
+    `host_names`}. {} where there is no file; no device entry for a trace
+    without a device plane (a CPU run)."""
+    from jax.profiler import ProfileData
+
+    path = newest_xplane(trace_dir)
+    if path is None:
+        return {}
+    out = {"file": path, "devices": [], "host": []}
+    wanted = set(host_names)
+    names: dict = {}    # an op's name is its whole HLO line: keep one copy
+
+    def events(line):
+        return [(e.start_ns / 1e9, e.end_ns / 1e9,
+                 names.setdefault(e.name, e.name)) for e in line.events]
+
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/device:"):
+            lines = {ln.name: ln for ln in plane.lines}
+            if "XLA Modules" not in lines or (
+                    devices is not None and len(out["devices"]) >= devices):
+                continue
+            out["devices"].append({
+                "plane": plane.name,
+                "modules": events(lines["XLA Modules"]),
+                "ops": [ev for n in op_lines if n in lines
+                        for ev in events(lines[n])]})
+        elif wanted and plane.name.startswith("/host:"):
+            for ln in plane.lines:
+                for e in ln.events:
+                    if e.name in wanted:
+                        out["host"].append((e.start_ns / 1e9, e.end_ns / 1e9,
+                                            e.name))
+    return out
+
+
+def per_execution_ms(devices: list, markers: tuple,
+                     module_hint: str | None = None) -> list:
+    """Summed device ms of the ops whose name holds a marker, one float an
+    execution, in timeline order, of the first device of `devices`
+    (walk_trace's) that ran a module whose name holds `module_hint` (None:
+    any); [] where none did."""
+    for dev in devices:
+        spans = sorted((s, e) for s, e, name in dev["modules"]
+                       if module_hint is None or module_hint in name)
+        if not spans:
+            continue
+        starts = [s for s, _ in spans]
+        out = [0.0] * len(spans)
+        for s, e, name in dev["ops"]:
+            if any(m in name for m in markers):
+                i = _execution_of(starts, spans, s)
+                if i is not None:
+                    out[i] += (e - s) * 1e3
+        return out
+    return []
+
+
+def _program_id(module: str) -> int:
+    """`jit_slot_decode_step(8808336842711562480)` -> that number, as the
+    plane's metadata has it under `program_id`; 0 where there is none."""
+    m = re.search(r"\((\d+)\)$", module)
+    return int(m.group(1)) & (2 ** 64 - 1) if m else 0
+
+
+def capture_report(trace_dir: str) -> dict:
+    """What a capture under trace_dir says of its FIRST device plane:
+    `report_events` of its executions, its ops (each with the framework op
+    name its program's compiled module holds for it, `hlo_op_names`) and
+    the host's spans of
+    trace.SPAN_NAMES. {"error": ...} for a trace without a file or without
+    a device plane (a CPU run)."""
+    from .trace import SPAN_NAMES
+
+    trace = walk_trace(trace_dir, devices=1, host_names=SPAN_NAMES)
+    if not trace.get("devices"):
+        return {"error": "no device plane" if trace
+                else "no .xplane.pb under the directory"}
+    dev = trace["devices"][0]
+    named = hlo_op_names(trace["file"])
+    mods = sorted(dev["modules"])
+    starts = [m[0] for m in mods]
+    programs = [named.get(_program_id(m[2]), {}) for m in mods]
+    ops = []
+    for s, e, name in dev["ops"]:
+        at = _execution_of(starts, mods, s)
+        ops.append((s, e, name, None if at is None else programs[at].get(
+            name.split(" = ")[0].lstrip("%"))))
+    out = report_events(mods, ops, trace["host"])
+    out["file"] = trace["file"]
+    out["plane"] = dev["plane"]
+    return out
+
+
+if __name__ == "__main__":
+    # the report's child (Profiler.capture): one JSON object on stdout
+    import json
+    import sys
+
+    print(json.dumps(capture_report(sys.argv[1])))

@@ -1260,12 +1260,15 @@ class WorkerClient:
                 ) -> dict | None:
         """RMSG_PROFILE: capture `ms` milliseconds of jax.profiler trace
         in the worker, into ITS capture dir. Synchronous — the deadline
-        covers the capture window plus slack; None when the worker is
+        covers the capture window, the report's limit and slack; the
+        reply holds the worker's own `report`; None when the worker is
         unreachable or the verb failed."""
         try:
+            from .profiler import REPORT_LIMIT_S
+
             frame = self._request(RMSG_PROFILE, [int(ms)],
-                                  timeout=(timeout
-                                           or float(ms) / 1e3 + 30.0))
+                                  timeout=(timeout or float(ms) / 1e3 + 30.0
+                                           + REPORT_LIMIT_S))
             if frame[0] != RMSG_OK:
                 return None
             out = json.loads(frame[2] or b"{}")

@@ -1159,10 +1159,13 @@ class Router:
     def profile(self, ms: float) -> dict | None:
         """Relay POST /admin/profile into REMOTE replica workers — all
         captures run CONCURRENTLY so every worker traces the same ms
-        window. Returns {"rK": {dir, ms} | None} per remote replica, or
-        None when this router has no remote replicas (thread replicas
+        window. Returns {"rK": {dir, ms, ..., report} | None} per remote
+        replica, each worker's own reply and report (nothing is merged),
+        or None when this router has no remote replicas (thread replicas
         share the parent's jax runtime — the HTTP handler captures
         locally instead)."""
+        from .profiler import REPORT_LIMIT_S
+
         remote = [h for h in self.replicas if hasattr(h, "client")]
         if not remote:
             return None
@@ -1176,7 +1179,7 @@ class Router:
         for t in threads:
             t.start()
         for t in threads:
-            t.join(timeout=float(ms) / 1e3 + 60.0)
+            t.join(timeout=float(ms) / 1e3 + 60.0 + REPORT_LIMIT_S)
         return out
 
     # -- rolling restart ---------------------------------------------------

@@ -70,6 +70,29 @@ def pytest_unconfigure(config):
         os._exit(_exit_status[0])
 
 
+@pytest.hookimpl(hookwrapper=True)
+def pytest_runtest_call(item):
+    """`@pytest.mark.limit_s(n)`: a test's own limit in seconds, on the
+    interval timer of the worker's main thread (where pytest runs a test);
+    a test that outlasts it fails there and does not hold the run."""
+    mark = item.get_closest_marker("limit_s")
+    if mark is None:
+        yield
+        return
+    import signal
+
+    def over(signum, frame):
+        pytest.fail(f"over its limit of {mark.args[0]} s", pytrace=False)
+
+    before = signal.signal(signal.SIGALRM, over)
+    signal.setitimer(signal.ITIMER_REAL, mark.args[0])
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, before)
+
+
 @pytest.fixture
 def rng():
     return np.random.default_rng(42)

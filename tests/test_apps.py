@@ -1459,6 +1459,16 @@ def test_api_admin_profile_captures_and_validates(sched_api_server,
     assert body["dir"].startswith(str(tmp_path / "prof"))
     import os
     assert os.path.isdir(body["dir"])
+    # the capture's report of itself: on this CPU there is no device plane,
+    # which it says, in the reply, in the file and in /stats `capture`
+    assert body["report"]["error"] and body["report_ms"] >= 0
+    with open(os.path.join(body["dir"], "report.json")) as f:
+        assert json.load(f) == body["report"]
+    conn = http.client.HTTPConnection(host, port, timeout=60)
+    conn.request("GET", "/stats")
+    capture = json.loads(conn.getresponse().read())["capture"]
+    assert capture["report"] == body["report"]
+    assert set(capture) == {"start", "stop", "report"}
 
     for bad in ("ms=zz", "ms=-5", "ms=0", "ms=900000"):
         status, body = post(f"/admin/profile?{bad}")

@@ -23,14 +23,21 @@ sys.path.insert(0, BTESTS)
 
 PURE = ("test_traffic", "test_workmodel", "test_window_metrics",
         "test_tracereduce", "test_manifest", "test_metrics",
-        "test_reference", "test_olmo_hybrid", "test_granite_hybrid")
+        "test_reference", "test_olmo_hybrid", "test_granite_hybrid",
+        "test_capture_report_metrics")
 BOOTS_A_SERVER = {"test_traced_rehearsal_reports_the_counter_metrics"}
 # pins the manifest at SIX cells with olmo's configuration last: a PR that
 # adds a cell cannot satisfy it and a model_config PR may edit no file under
 # benchmark/ (PR 39). Only that count is lost: test_granite_hybrid.py carries
 # every other assertion of it (the cells' order with the seventh, olmo's
 # published numbers, the delta_rule_* entries, the equal cell files)
-SUPERSEDED = {"test_the_manifest_has_six_cells_and_the_new_entries_come_last"}
+# PR 41 appends four `per_layer` entries, which a PR that adds to the
+# benchmark may put nowhere else; test_granite_hybrid.py's two tests of the
+# LAST five entries run unedited on the manifest without the four, from
+# test_capture_report_metrics.py, so only "nothing comes after" is lost
+SUPERSEDED = {"test_the_manifest_has_six_cells_and_the_new_entries_come_last",
+              "test_the_manifest_has_seven_cells_and_the_new_entries_come_last",
+              "test_olmos_entries_keep_their_places_and_their_keys"}
 
 
 def _load(name):

@@ -531,6 +531,51 @@ def test_granite_hybrid_steps_compile_at_published_widths(topo, t):
         assert not r.cache_shaped_copies(text, leaf.shape)
 
 
+@pytest.mark.parametrize("t", [1, 32], ids=["decode", "chunk32"])
+@pytest.mark.parametrize("model", ["mistral_7b", "sarvam_105b_ep8"])
+def test_the_device_scopes_change_no_compiled_program(topo, monkeypatch,
+                                                      model, t):
+    """A `jax.named_scope` is op metadata: the step program the chip's
+    compiler makes with the scopes of models/scopes.DEVICE_SCOPES is, but
+    for its `metadata={...}` and the source locations inside a Pallas
+    kernel's serialized body, the text it makes with every scope taken out
+    (two layers of Mistral-7B, and of sarvam's share one dense and two
+    experts-held layers with their wave loop; the Q80 round trip on)."""
+    import contextlib
+    import re
+
+    import rehearse_chip_compile as r
+
+    from distributed_llama_tpu.models.scopes import DEVICE_SCOPES
+
+    spec = {"mistral_7b": dataclasses.replace(r.MISTRAL_7B, n_layers=2),
+            "sarvam_105b_ep8": dataclasses.replace(r.SARVAM_105B_EP8,
+                                                   n_layers=3)}[model]
+
+    def compiled_text() -> str:
+        fn, args = r.abstract_step(spec, topo.devices, batch=8, t=t,
+                                   seq_len=4096, q80=True)
+        return fn.lower(*args).compile().as_text()
+
+    def bare(text: str) -> str:
+        text = re.sub(r",? ?metadata=\{[^}]*\}", "", text)
+        text = re.sub(r'"body":"[^"]*"', '"body":""', text)
+        return text[text.index("\n%"):]     # past the tables of locations
+
+    with_scopes = compiled_text()
+    named = set(re.findall(r'op_name="jit\([^"]*?/(\w+)/', with_scopes))
+    assert named & set(DEVICE_SCOPES) >= {"attn_proj", "attn_core", "head"}
+    real = jax.named_scope
+    monkeypatch.setattr(
+        jax, "named_scope",
+        lambda name: (contextlib.nullcontext() if name in DEVICE_SCOPES
+                      else real(name)))
+    without = compiled_text()
+    assert not set(re.findall(r'op_name="[^"]*?/(\w+)/', without)) & set(
+        DEVICE_SCOPES)
+    assert bare(with_scopes) == bare(without)
+
+
 @pytest.mark.parametrize("b,t", [(8, 1), (8, 32)])
 def test_mla_attention_compiles(one_chip, b, t):
     from distributed_llama_tpu.ops.pallas_attention import mla_attention
