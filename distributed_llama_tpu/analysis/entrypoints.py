@@ -150,6 +150,24 @@ def entry_points(max_devices: int | None = None,
         (params_c, tok_c, pos_c, lidx_c, cache_c),
         {"activation_elems": 4 * 8 * spec_c.dim, "dim": spec_c.dim}))
 
+    # slot_prefill_chunk_mapped: the same chunk with the slot map, as an
+    # engine whose scheduler chains rows mints it (every layer a dense K/V
+    # cache, no mesh: Engine.prefill_rows_per_slot): row r reads and writes
+    # cache slot slots[r]. A program of its own beside the map-less chunk,
+    # which the other engines keep running unchanged.
+    slots_c = jnp.arange(4, dtype=jnp.int32)
+
+    def slot_prefill_chunk_mapped(params, tok, pos, logit_index, cache,
+                                  slots):
+        return forward(params, spec_c, tok, pos, cache,
+                       logit_index=logit_index, compute_dtype=jnp.float32,
+                       slots=slots)
+
+    out.append(EntryPoint(
+        "slot_prefill_chunk_mapped", slot_prefill_chunk_mapped,
+        (params_c, tok_c, pos_c, lidx_c, cache_c, slots_c),
+        {"activation_elems": 4 * 8 * spec_c.dim, "dim": spec_c.dim}))
+
     # slot_seed_prefix: the radix prefix cache's admission-time seeding
     # (runtime/prefix_cache.py) — an on-device arena-block gather written
     # as a slot row's leading cache positions. Traced through the SAME

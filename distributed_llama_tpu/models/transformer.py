@@ -127,7 +127,7 @@ def _to_cache_dtype(x, dtype):
 
 
 def _scatter_cache_write(k_cache, v_cache, k, v, idx, write_gate,
-                         kernel_cfg=None):
+                         kernel_cfg=None, slots=None):
     """Write (B, T, KVH, hs) K/V at per-position indices (B, T) into
     (B, KVH, S, hs) caches, dropping every index >= S. write_gate (traced
     bool) pushes gated-off writes to the out-of-bounds slot S — shared by
@@ -140,7 +140,10 @@ def _scatter_cache_write(k_cache, v_cache, k, v, idx, write_gate,
     tiles, the in-place `kv_cache_write` kernel then takes the write
     (ops/pallas_kv_write.py: the XLA scatter costs four cache-sized layout
     copies a layer); bit-equal to the drop-mode scatter, which stays for
-    every other caller, as decode_attention stays behind flash_attention."""
+    every other caller, as decode_attention stays behind flash_attention.
+
+    slots: (B,) the cache slot row b writes (forward's `slots`); None is
+    the identity. Both forms take the same map."""
     oob = k_cache.shape[2]
     if write_gate is not None:
         idx = jnp.where(write_gate, idx, oob)
@@ -159,11 +162,13 @@ def _scatter_cache_write(k_cache, v_cache, k, v, idx, write_gate,
                 # cache is already local)
                 from ..parallel.tp_q80 import tp_kv_cache_write
 
+                assert slots is None, "a slot map on a mesh"
                 return tp_kv_cache_write(k_cache, v_cache, k, v, idx[:, 0],
                                          mesh, interpret=interpret)
             return kv_cache_write(k_cache, v_cache, k, v, idx[:, 0],
-                                  interpret=interpret)
-    bidx = jnp.arange(k_cache.shape[0], dtype=jnp.int32)[:, None]
+                                  slots=slots, interpret=interpret)
+    bidx = (jnp.arange(k_cache.shape[0], dtype=jnp.int32)
+            if slots is None else slots)[:, None]
     k_cache = k_cache.at[bidx, :, idx].set(k, mode="drop")
     if v_cache is not None:
         v_cache = v_cache.at[bidx, :, idx].set(v, mode="drop")
@@ -258,7 +263,7 @@ def _mla_kernels_ok(t: int, h: int, seq_len: int) -> bool:
 
 def _attention_block(x, lw, spec: ModelSpec, k_cache, v_cache, q_pos, cfg,
                      sp_mesh=None, sp_cache_mesh=None, per_row_pos=False,
-                     write_gate=None):
+                     write_gate=None, slots=None):
     """Norm -> QKV -> RoPE -> cache update -> attention -> output proj.
 
     Returns (attn_out, new_k_cache, new_v_cache). attn_out is the wo
@@ -267,9 +272,17 @@ def _attention_block(x, lw, spec: ModelSpec, k_cache, v_cache, q_pos, cfg,
     the existing values (pipeline parallelism runs every stage's layers on
     every device each iteration, but only the live stage may write its
     cache — parallel/pp.py).
+    slots: (B,) int32, forward's slot map: row b writes and attends the
+    cache rows of slot slots[b] (one chip, per-row positions). The write
+    comes first, so a row attends what the rows before it wrote for the
+    same slot in this very program.
     """
     b, t, d = x.shape
     h, kvh, hs = spec.n_heads, spec.n_kv_heads, spec.head_size
+    assert slots is None or (
+        per_row_pos and sp_mesh is None and sp_cache_mesh is None
+        and cfg.get("tp_mesh") is None and not cfg.get("manual_tp")
+        and not cfg.get("manual_sp")), "a slot map off the one-chip slot path"
     f = cfg.get("manual_tp") or 1
     if f > 1:
         # fully-manual pp region: this shard computes h/tp query heads and
@@ -350,7 +363,8 @@ def _attention_block(x, lw, spec: ModelSpec, k_cache, v_cache, q_pos, cfg,
             # over sp keeps the scatter (a pallas_call would gather it whole).
             k_cache, v_cache = _scatter_cache_write(
                 k_cache, v_cache, k, v, q_pos, write_gate,
-                kernel_cfg=cfg if sp_cache_mesh is None else None)
+                kernel_cfg=cfg if sp_cache_mesh is None else None,
+                slots=slots)
         else:
             pos0 = q_pos[:, 0]
             k_w = _to_cache_dtype(k.transpose(0, 2, 1, 3), k_cache.dtype)
@@ -430,7 +444,12 @@ def _attention_block(x, lw, spec: ModelSpec, k_cache, v_cache, q_pos, cfg,
 
                 att = flash_attention(
                     q, k_cache, v_cache, q_pos,
-                    interpret=cfg.get("pallas_interpret", False), scale=scale)
+                    interpret=cfg.get("pallas_interpret", False), scale=scale,
+                    slots=slots)
+        elif slots is not None:
+            # the XLA twin of the kernel's index map gathers the rows' slots
+            att = decode_attention(q, k_cache[slots], v_cache[slots], q_pos,
+                                   scale=scale)
         else:
             att = decode_attention(q, k_cache, v_cache, q_pos,
                                    scale=scale)                # (B, T, H, hs)
@@ -925,8 +944,9 @@ def _take_expert(w, e):
 
 def _layer(x, lw, spec: ModelSpec, k_cache, v_cache, q_pos, cfg, sp_mesh=None,
            sp_cache_mesh=None, per_row_pos=False, write_gate=None,
-           n_valid=None, moe_counts=None):
+           n_valid=None, moe_counts=None, slots=None):
     if spec.is_mla:
+        assert slots is None, "a slot map over the latent cache"
         # pre-norm serial block over latent attention; the FFN is dense in
         # the leading layers (w1 or fused w13 present) and experts after
         assert sp_mesh is None and sp_cache_mesh is None
@@ -941,7 +961,7 @@ def _layer(x, lw, spec: ModelSpec, k_cache, v_cache, q_pos, cfg, sp_mesh=None,
     attn_out, k_cache, v_cache = _attention_block(
         x, lw, spec, k_cache, v_cache, q_pos, cfg, sp_mesh=sp_mesh,
         sp_cache_mesh=sp_cache_mesh, per_row_pos=per_row_pos,
-        write_gate=write_gate)
+        write_gate=write_gate, slots=slots)
 
     if spec.post_norm:
         return (_post_norm_tail(x, attn_out, lw, spec, cfg), k_cache,
@@ -989,6 +1009,7 @@ def forward(
     vocab_mesh=None,
     vocab_axes: tuple = ("tp",),
     expert_counts: bool = False,
+    slots: jnp.ndarray | None = None,
 ) -> tuple[jnp.ndarray, KVCache]:
     """Run T tokens through the model; returns (logits, updated cache).
 
@@ -1014,7 +1035,15 @@ def forward(
     expert_counts: also return, third, int32 (2,): the distinct held experts
     some real token chose and the live (token, expert) pairs, summed over
     the MoE layers (_moe_ffn; the served step programs' window counters).
+    slots: (B,) int32, the cache slot row b reads and writes at pos0[b]
+    (per-row positions, one chip, every layer a dense K/V cache:
+    Engine.prefill_rows_per_slot). Rows of one slot at consecutive
+    segments prefill that slot several segments a program; every other op
+    is independent across rows, so the slot's logits are those of the same
+    segments one a program, bit for bit. None: row b is slot b.
     """
+    assert slots is None or (pp_mesh is None and not spec.has_state), (
+        "a slot map over a state layer or a pp mesh")
     cfg = dict(activation_q80=activation_q80, compute_dtype=compute_dtype,
                use_pallas=use_pallas, tp_mesh=tp_mesh, tp_reduce=tp_reduce,
                pallas_interpret=pallas_interpret)
@@ -1091,7 +1120,7 @@ def forward(
                                      per_row_pos=per_row_pos,
                                      n_valid=(None if rows is None
                                               else rows.n_valid),
-                                     moe_counts=moe_counts)
+                                     moe_counts=moe_counts, slots=slots)
             k_all.append(k_new)
             if v_new is not None:
                 v_all.append(v_new)

@@ -208,12 +208,18 @@ def flash_attention(
     q_pos: jnp.ndarray,    # (B, T) absolute position of each query token
     interpret: bool = False,
     scale: float | None = None,
+    slots: jnp.ndarray | None = None,  # (B,) the cache slot row b attends
 ) -> jnp.ndarray:
     """Causal attention of T query tokens against the cache; returns
     (B, T, H, hs). Matches ops/attention.decode_attention semantics,
     `scale` too (None: head_size ** -0.5) —
     q_pos rows must be contiguous (pos0[b] + arange(T), which is how every
-    engine path builds them — models/transformer.forward)."""
+    engine path builds them — models/transformer.forward).
+    slots: row b attends the cache rows of slot slots[b] (several rows may
+    be consecutive segments of one slot: each attends what the rows before
+    it wrote in the same program, and the mask hides what the rows after
+    it did); the K/V index map reads the map, no cache row is gathered or
+    copied. None, the identity, is the program it always was."""
     b, t, h, hs = q.shape
     kvh, s = k_cache.shape[1], k_cache.shape[2]
     g = h // kvh
@@ -236,26 +242,32 @@ def flash_attention(
     vh = v_cache.reshape(b * kvh, s, hs)
     pos = q_pos[:, 0].astype(jnp.int32)
 
-    def kv_index(i, j, pos_ref):
+    # index maps take the prefetched scalars last: (pos,), or (pos, slots)
+    def kv_index(i, j, pos_ref, *sl):
         # clamp at the block containing the chunk's last query position:
         # steps past it re-map to the same block, so Mosaic elides their HBM
         # copy (the dead-read fix)
         last = _last_attended(pos_ref[i // kvh], t - 1, s)
-        return (i, jnp.minimum(j, last // sb), 0)
+        head = sl[0][i // kvh] * kvh + i % kvh if sl else i
+        return (head, jnp.minimum(j, last // sb), 0)
 
+    kernel, scalars = _kernel, (pos,)
+    if slots is not None:
+        kernel, scalars = _mapped_kernel, (pos, slots.astype(jnp.int32))
     out = pl.pallas_call(
         functools.partial(
-            _kernel, sb=sb, n_sb=n_sb, kvh=kvh, t=t, g=g,
+            kernel, sb=sb, n_sb=n_sb, kvh=kvh, t=t, g=g,
             scale=scale or 1.0 / (hs ** 0.5), out_dtype=q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=len(scalars),
             grid=(b * kvh, n_sb),
             in_specs=[
-                pl.BlockSpec((1, t * g, hs), lambda i, j, p: (i, 0, 0)),
+                pl.BlockSpec((1, t * g, hs), lambda i, j, *_: (i, 0, 0)),
                 pl.BlockSpec((1, sb, hs), kv_index),
                 pl.BlockSpec((1, sb, hs), kv_index),
             ],
-            out_specs=pl.BlockSpec((1, t * g, hs), lambda i, j, p: (i, 0, 0)),
+            out_specs=pl.BlockSpec((1, t * g, hs),
+                                   lambda i, j, *_: (i, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((t * g, hs), jnp.float32),
                 pltpu.VMEM((t * g, 1), jnp.float32),
@@ -267,10 +279,16 @@ def flash_attention(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name="flash_attention",
-    )(pos, qh, kh, vh)
+    )(*scalars, qh, kh, vh)
 
     return (out.reshape(b, kvh, t, g, hs).transpose(0, 2, 1, 3, 4)
             .reshape(b, t, h, hs))
+
+
+def _mapped_kernel(pos_ref, slots_ref, *refs, **kw):
+    """`_kernel` under a slot map: only the K/V index map reads it."""
+    del slots_ref
+    _kernel(pos_ref, *refs, **kw)
 
 
 # -- latent (absorbed) attention over a one-leaf cache ------------------------

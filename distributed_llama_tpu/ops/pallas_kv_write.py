@@ -30,6 +30,23 @@ before the last output block is written back. That is safe because every
 visit writes its block's complete final content: distinct (b, tile) pairs
 are disjoint, and the visits the clamp at the context's last tile
 duplicates select from the same window tile, so they write equal bytes.
+
+With a slot map (`slots`, the prefill chunk program of an engine whose
+scheduler chains rows: runtime/scheduler.py) row b writes the cache rows
+of slot `slots[b]`, and several rows may name ONE slot at consecutive
+windows. The argument above must then hold for (slot, tile) pairs, and the
+map-less index map breaks it: a window that starts on a tile boundary
+touches one tile fewer than `_n_tiles` visits, the spare visit selects
+nothing and re-writes the OLD bytes of the tile after the window, which is
+the first tile of the next chained row. So with a map a row's visits stop
+at its own last tile (`_tile`, `own=True`: the spare visits duplicate that
+tile and write equal bytes), and the caller keeps three promises that
+`visited_blocks` lets a host-side test check without running a kernel
+(interpret mode walks the grid in order and can never show the race):
+chained rows start on multiples of a width that is whole tiles, so their
+windows share no tile; live rows of different slots are different slots;
+and a gated row (pos == S: it re-writes the old bytes of its slot's last
+tile) names a slot no live row names.
 """
 
 from __future__ import annotations
@@ -66,11 +83,48 @@ def _window_tokens(off, rows: int, t: int):
     return jnp.clip(src, 0, t - 1)[:, :, None]
 
 
+def _tile(p, j, *, r: int, t: int, last: int, own: bool):
+    """The cache tile visit j of a row whose window starts at p reads and
+    writes: tile p // r + j, held inside the context. own (a slot map is
+    passed): held inside the row's OWN window too, so that no visit lands
+    on the tile after it, which a chained row of the same slot may own.
+    The one place the kernel, its index maps and `visited_blocks` take the
+    tile from."""
+    return _window_tile(p, j, r=r, t=t, last=last, own=own)[1]
+
+
+def _window_tile(p, j, *, r: int, t: int, last: int, own: bool):
+    """(the window's first tile, `_tile`): the window's index map wants
+    both, and the map-less program's text is the operations written here,
+    in this order."""
+    first = p // r
+    tile = first + j
+    if own:
+        tile = jnp.minimum(tile, (p + t - 1) // r)
+    return first, jnp.clip(tile, 0, last)
+
+
+def visited_blocks(pos, slots, *, t: int, seq_len: int, dtype,
+                   own: bool = True):
+    """(B, visits, 2) int32: the (slot, tile) cache block each grid step of
+    a slot-mapped `kv_cache_write` reads and writes back, from the index
+    map itself (`_tile`); no kernel runs. Two rows that visit one block
+    write it twice, from copies that may both be in flight. own=False: the
+    tiles of the map-less index map, which chained rows could not use."""
+    r = row_tile(dtype)
+    pos = jnp.asarray(pos, jnp.int32)[:, None]
+    tiles = _tile(pos, jnp.arange(_n_tiles(t, r), dtype=jnp.int32)[None, :],
+                  r=r, t=t, last=seq_len // r - 1, own=own)
+    return jnp.stack(
+        [jnp.broadcast_to(jnp.asarray(slots, jnp.int32)[:, None],
+                          tiles.shape), tiles], axis=-1)
+
+
 def _kernel(pos_ref, kc_ref, vc_ref, kw_ref, vw_ref, ko_ref, vo_ref,
-            *, r, last, t):
+            *, r, last, t, own=False):
     b, j = pl.program_id(0), pl.program_id(1)
     pos = pos_ref[b]
-    tile = jnp.clip(pos // r + j, 0, last)
+    tile = _tile(pos, j, r=r, t=t, last=last, own=own)
     kvh, _, hs = kc_ref.shape[1:]
     row = tile * r + jax.lax.broadcasted_iota(jnp.int32, (r, hs), 0)
     hit = (row >= pos) & (row < pos + t)
@@ -90,12 +144,16 @@ def kv_cache_write(
     k_new: jnp.ndarray,    # (B, T, KVH, hs), already in the cache dtype
     v_new: jnp.ndarray,    # (B, T, KVH, hs)
     pos: jnp.ndarray,      # (B,) first position row b writes; >= 0
+    slots: jnp.ndarray | None = None,  # (B,) the cache slot row b writes
     interpret: bool = False,
 ):
     """Row b's T new rows land at positions pos[b] .. pos[b]+T-1 of its
     cache rows; positions >= S are dropped (a gated row passes pos[b] == S)
     — what `.at[b, :, pos[b] + arange(T)].set(..., mode="drop")` does.
-    Returns the two caches, aliased onto the inputs."""
+    slots: row b writes the cache rows of slot slots[b] instead of its own
+    (the module docstring says what the caller owes); None, the identity,
+    is the program it always was. Returns the two caches, aliased onto the
+    inputs."""
     b, kvh, s, hs = k_cache.shape
     t = k_new.shape[1]
     r = row_tile(k_cache.dtype)
@@ -107,44 +165,59 @@ def kv_cache_write(
     k_new = k_new.reshape(b, t, kvh * hs)
     v_new = v_new.reshape(b, t, kvh * hs)
 
-    def cache_index(i, j, p):
-        return (i, 0, jnp.clip(p[i] // r + j, 0, last), 0)
+    own = slots is not None
+    tiles = dict(r=r, t=t, last=last, own=own)
+
+    # index maps take the prefetched scalars last: (pos,), or (pos, slots)
+    def cache_index(i, j, p, *sl):
+        return (sl[0][i] if own else i, 0, _tile(p[i], j, **tiles), 0)
 
     if t == 1:
         # decode: the row itself is the window, whichever tile it lands in
         window_block = pl.BlockSpec((1, 1, kvh * hs),
-                                    lambda i, j, p: (i, 0, 0))
+                                    lambda i, j, *_: (i, 0, 0))
     else:
         src = _window_tokens(pos % r, n * r, t)
         k_new = jnp.take_along_axis(k_new, src, axis=1)
         v_new = jnp.take_along_axis(v_new, src, axis=1)
 
-        def window_index(i, j, p):
+        def window_index(i, j, p, *_):
             # the window tile that lands on the (clamped) cache tile; where
             # the clamp leaves nothing to write, any tile does
-            first = p[i] // r
-            return (i, jnp.clip(jnp.clip(first + j, 0, last) - first,
-                                0, n - 1), 0)
+            first, tile = _window_tile(p[i], j, **tiles)
+            return (i, jnp.clip(tile - first, 0, n - 1), 0)
 
         window_block = pl.BlockSpec((1, r, kvh * hs), window_index)
     cache_block = pl.BlockSpec((1, kvh, r, hs), cache_index)
     shape = jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype)
+    kernel = functools.partial(_kernel, r=r, last=last, t=t)
+    scalars = (pos,)
+    if own:
+        kernel = functools.partial(_mapped_kernel, r=r, last=last, t=t)
+        scalars = (pos, slots.astype(jnp.int32))
     return pl.pallas_call(
-        functools.partial(_kernel, r=r, last=last, t=t),
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=len(scalars),
             grid=(b, n),
             in_specs=[cache_block, cache_block, window_block, window_block],
             out_specs=[cache_block, cache_block],
         ),
         out_shape=[shape, shape],
-        # operand 0 is pos; the caches are written where they stand
-        input_output_aliases={1: 0, 2: 1},
+        # the scalars come first; the caches are written where they stand
+        input_output_aliases={len(scalars): 0, len(scalars) + 1: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name="kv_cache_write",
-    )(pos, k_cache, v_cache, k_new, v_new)
+    )(*scalars, k_cache, v_cache, k_new, v_new)
+
+
+def _mapped_kernel(pos_ref, slots_ref, *refs, **kw):
+    """`_kernel` under a slot map: the map moves blocks (the index maps
+    read it), the body only stops a row's visits at its own last tile."""
+    del slots_ref
+    _kernel(pos_ref, *refs, own=True, **kw)
 
 
 # -- a cache leaf whose SEQUENCE is the minor dimension ----------------------

@@ -356,6 +356,13 @@ class Engine:
             self._cache_sharding = None
             self._token_sharding = None
 
+        # whether the rows of the prefill chunk program follow a slot map
+        # (slot_prefill_chunk's `slots`; prefill_rows_per_slot says what
+        # it takes): decided here, once, from the layer kinds and the mesh
+        self._chunk_slot_map = (not spec.has_state and not spec.is_mla
+                                and self._token_sharding is None)
+        self._identity_map = None   # arange(batch), made at the first chunk
+
         # mesh spanning >1 process (jax.distributed): host code may only
         # fetch fully-replicated arrays, so logits are all-gathered to every
         # host before sampling (parallel/multihost.py)
@@ -1518,8 +1525,23 @@ class Engine:
         del self._expert_counts[:n]
         return [(program, *map(int, np.asarray(c))) for program, c in taken]
 
+    @property
+    def prefill_rows_per_slot(self) -> int:
+        """Most rows of one chunk program that may be consecutive segments
+        of ONE slot (the scheduler chains them through `slots`): `batch`
+        where the program's rows follow a slot map, which takes every
+        layer's cache being the dense K/V cache that `kv_cache_write` and
+        `flash_attention` address by slot (no state layer: a row's state
+        would have to reach the next row inside the program; no latent
+        cache) and rows that are not sharded (no mesh: dp splits the rows,
+        pp and sp trace other regions). Else 1: the chunk program takes no
+        map and row r is slot r. From the layer kinds and the mesh, once,
+        at boot: both step programs of such an engine are what they were."""
+        return self.batch if self._chunk_slot_map else 1
+
     def slot_prefill_chunk(self, tokens: np.ndarray, pos: np.ndarray,
-                           logit_index: np.ndarray) -> jax.Array:
+                           logit_index: np.ndarray,
+                           slots: np.ndarray | None = None) -> jax.Array:
         """One chunked-prefill forward over the batched cache: row r writes
         its (B, C) chunk's K/V at absolute offsets pos[r]..pos[r]+C-1 via
         the per-row write path, without disturbing any other row. Rows
@@ -1533,6 +1555,16 @@ class Engine:
         chunk (only rows finishing their prompt this chunk are consumed;
         the scheduler skips the D2H fetch entirely for mid-prompt chunks).
 
+        slots (B,), where prefill_rows_per_slot allows it: program row r
+        reads and writes cache slot slots[r] at pos[r], so several rows may
+        be consecutive segments of one slot (row r attends what rows before
+        it wrote in this program). The caller owes what
+        ops/pallas_kv_write.py's docstring lists: chained rows start on
+        multiples of C, and every row that is not live is gated and names a
+        slot no live row names. None is the identity, row r == slot r, and
+        enters the SAME program as an arange: one chunk executable an
+        engine, with or without chaining.
+
         The chunk width C is the ONLY compilation key
         (slot_prefill_chunk_C): the scheduler pads every tail chunk to a
         fixed C, so admission order/prompt lengths never mint new
@@ -1545,14 +1577,17 @@ class Engine:
         # any dispatch — arming it never alters the jitted program
         b, c = tokens.shape
         assert b == self.batch, (b, self.batch)
+        mapped = self._chunk_slot_map
+        assert mapped or slots is None, "this engine's chunk takes no map"
         key = ("slot_prefill", c)
         if key not in self._steps:
             common = dict(self._forward_kwargs(),
                           expert_counts=self._counts_experts)
 
-            def run(params, tokens, pos0, logit_index, cache):
+            def run(params, tokens, pos0, logit_index, cache, *slots):
                 return forward(params, self.spec, tokens, pos0, cache,
-                               logit_index=logit_index, **common)
+                               logit_index=logit_index, **common,
+                               slots=slots[0] if slots else None)
 
             run.__name__ = f"slot_prefill_chunk_{c}"
             self._mint(key, jax.jit(run, donate_argnums=(4,)))
@@ -1562,9 +1597,15 @@ class Engine:
             tok = jax.device_put(tok, self._token_sharding)
             posv = jax.device_put(posv,
                                   NamedSharding(self.mesh, P(DP_AXIS)))
+        the_map = ()
+        if mapped:
+            if self._identity_map is None:    # on the device once, not a call
+                self._identity_map = jnp.asarray(np.arange(b, dtype=np.int32))
+            the_map = (self._identity_map if slots is None
+                       else jnp.asarray(slots, jnp.int32),)
         logits, self.cache, *counts = self._steps[key](
             self.params, tok, posv, jnp.asarray(logit_index, jnp.int32),
-            self.cache)
+            self.cache, *the_map)
         self._note_expert_counts("prefill", *counts)
         return logits
 

@@ -199,6 +199,29 @@ def chunk_ladder(chunk: int, rungs: int = 4) -> list[int]:
     return ladder
 
 
+def chained_segments(left: int, chunk: int, most: int) -> int:
+    """Segments of `chunk` tokens that a slot prefilling ALONE takes in one
+    chunk program, of the `left` tokens its prompt still has, when a
+    program has `most` rows to give it: what is left is cut over the
+    fewest programs, EVENLY in whole segments (300 tokens at 8 x 32 are
+    160 + 140, not 256 + 44: the same iterations, and no chunk heavier
+    than it has to be beside the rows that decode)."""
+    segs = -(-left // chunk)
+    programs = -(-segs // most)
+    return -(-segs // programs)
+
+
+def chain_map(slot: int, k: int, batch: int) -> np.ndarray:
+    """The slot map of a chunk program whose rows 0..k-1 are consecutive
+    segments of `slot`: the rows left over (gated by their position) name
+    the OTHER slots, one each, so that the old bytes a gated row's cache
+    write puts back are never those of a tile a live row writes
+    (ops/pallas_kv_write.py)."""
+    return np.asarray(
+        [slot] * k + [i for i in range(batch) if i != slot][:batch - k],
+        np.int32)
+
+
 class AdmissionPolicy:
     """SLO-aware self-tuning admission: trade per-iteration chunked-
     prefill width against decode occupancy (Orca's iteration-level knob)
@@ -637,8 +660,7 @@ class Scheduler:
         # the one configured width when no SLO is set
         cw = (self.admission.width if self.admission is not None
               else self.chunk) if pre else 0
-        if pre:
-            self._prefill_chunk(pre, cw)
+        segments = self._prefill_chunk(pre, cw) if pre else 0
         # per-slot drafting (runtime/draft.py): the admission policy's
         # "degrade — no speculation" actuator gates every draft dispatch
         # — when the live ITL EWMA endangers the SLO, the scheduler
@@ -683,7 +705,8 @@ class Scheduler:
                         chunk=cw, queue_depth=len(self._queue),
                         wall_ms=wall_ms, key=self.fault_key, n=st.steps,
                         ts0=self._step_t0,
-                        phases=sp.phases if sp is not None else None)
+                        phases=sp.phases if sp is not None else None,
+                        segments=segments)
         if self.admission is not None:
             # the same wall the timeline records is the policy's signal;
             # it adapts the NEXT iteration's width (never this one's)
@@ -761,21 +784,23 @@ class Scheduler:
                 # overstate the denominator for requests cancelled or
                 # expired mid-prefill)
 
-    def _sample_view(self, logits, rows: list[_Slot]):
+    def _sample_view(self, logits, rows: list[_Slot], at=None):
         """Wrap one forward's on-device logits for host sampling
         (Engine.sample_view): vocab-sharded engines serve the rows from
         the tiny argmax/candidate summary instead of a (B, vocab)
         fetch; replicated engines (and duck-typed test engines) get the
         classic full-logits view. temps carries each sampling row's
-        temperature as a traced input (greedy rows pass 1.0)."""
+        temperature as a traced input (greedy rows pass 1.0). `at`: the
+        program row each slot's logits stand in (its own index unless a
+        chunk's rows were chained)."""
         eng = self.engine
         sv = getattr(eng, "sample_view", None)
         if sv is not None:
             temps = np.ones((eng.batch,), np.float32)
-            for s in rows:
+            for s, r in zip(rows, at or [s.idx for s in rows]):
                 t = getattr(s.req.sampler, "temperature", 0.0)
                 if t:
-                    temps[s.idx] = t
+                    temps[r] = t
         t0 = self._wait_begin()
         if sv is None:
             from .sampling import FullLogitsView
@@ -822,7 +847,16 @@ class Scheduler:
                 self.stats.expert_pairs_prefill += pairs
 
     def _prefill_chunk(self, rows: list[_Slot],
-                       width: int | None = None) -> None:
+                       width: int | None = None) -> int:
+        """One chunk program over the slots that prefill; returns its live
+        rows. Several slots: row s.idx carries slot s's next segment (the
+        identity map). ONE slot, on an engine whose chunk rows follow a
+        slot map (Engine.prefill_rows_per_slot), at the widest rung and on
+        a chunk boundary: rows 0..k-1 carry k consecutive segments of it
+        (`chained_segments`), each attending what the rows before it wrote,
+        and the rows left over, gated, name the other slots, so that no
+        row's cache write lands on a tile of the live slot
+        (ops/pallas_kv_write.py says why that matters)."""
         eng = self.engine
         sp = self._span
         if sp is not None:
@@ -831,13 +865,24 @@ class Scheduler:
         tok = np.zeros((b, c), np.int32)
         pos = np.full((b,), eng.seq_len, np.int32)  # gated rows: writes drop
         lidx = np.zeros((b,), np.int32)
+        slots = None
+        live = [(s, s.idx) for s in rows]           # (slot, program row)
+        if len(rows) == 1 and c == self.chunk and rows[0].off % c == 0:
+            s = rows[0]
+            k = chained_segments(len(s.req.prompt) - s.off, c,
+                                 getattr(eng, "prefill_rows_per_slot", 1))
+            if k > 1:
+                live = [(s, r) for r in range(k)]
+                slots = chain_map(s.idx, k, b)
         finishing = []
+        first = [s.off for s in rows]
         self.stats.prefill_steps += 1
         self.stats.prefill_rows += len(rows)
-        self.stats.gated_rows += b - len(rows)
-        for s in rows:
+        self.stats.prefill_segments += len(live)
+        self.stats.gated_rows += b - len(live)
+        for s, r in live:
             n = min(c, len(s.req.prompt) - s.off)
-            tok[s.idx, :n] = s.req.prompt[s.off:s.off + n]
+            tok[r, :n] = s.req.prompt[s.off:s.off + n]
             self.stats.prefill_tokens += n
             self.stats.attn_pairs_prefill += n * s.off + n * (n + 1) // 2
             self.stats.prefill_cached_tokens += s.off + n
@@ -847,20 +892,24 @@ class Scheduler:
                 self.prefix_cache.stats.tokens_prefilled += n
             # tail padding (token 0) writes land beyond the prompt and are
             # overwritten by decode before any later query attends them
-            pos[s.idx] = s.off
-            lidx[s.idx] = n - 1
-            if TRACER.enabled:
-                TRACER.event("prefill", s.req.trace_id, off=s.off, n=n,
-                             slot=s.idx, step=self.stats.steps)
+            pos[r] = s.off
+            lidx[r] = n - 1
             s.off += n
             if s.off == len(s.req.prompt):
-                finishing.append(s)
-        logits = eng.slot_prefill_chunk(tok, pos, lidx)
+                finishing.append((s, r))    # its LAST row's logits are read
+        if TRACER.enabled:
+            for s, off in zip(rows, first):
+                TRACER.event("prefill", s.req.trace_id, off=off,
+                             n=s.off - off, slot=s.idx,
+                             step=self.stats.steps)
+        logits = eng.slot_prefill_chunk(
+            tok, pos, lidx, *(() if slots is None else (slots,)))
         if not finishing:
             self._count_experts()
-            return  # mid-prompt chunk: no D2H fetch at all
-        view = self._sample_view(logits, finishing)
-        for s in finishing:
+            return len(live)  # mid-prompt chunk: no D2H fetch at all
+        view = self._sample_view(logits, [s for s, _ in finishing],
+                                 at=[r for _, r in finishing])
+        for s, r in finishing:
             s.pos = len(s.req.prompt)
             if self.prefix_cache is not None:
                 # publish the prompt's blocks the moment they are all
@@ -879,7 +928,8 @@ class Scheduler:
                 # ran, nothing is emitted
                 self._finish_slot(s, "length")
                 continue
-            self._emit(s, view.sample(s.req.sampler, s.idx))
+            self._emit(s, view.sample(s.req.sampler, r))
+        return len(live)
 
     def _decode(self, rows: list[_Slot]) -> None:
         # cancellations were reaped at the top of the iteration; a cancel

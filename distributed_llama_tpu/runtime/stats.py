@@ -231,8 +231,8 @@ class StepTimelineStats:
 # ServeStats' window counters, by /stats key (docs/observability.md and
 # PERF.md section 3 say which per-layer metric reads which)
 WINDOW_COUNTERS = ("admitted", "queue_wait_ms_sum", "prefill_steps",
-                   "prefill_tokens", "prefill_rows", "decode_steps",
-                   "decode_rows",
+                   "prefill_tokens", "prefill_rows", "prefill_segments",
+                   "decode_steps", "decode_rows",
                    "gated_rows", "busy_ms", "wait_ms", "host_ms",
                    "attn_pairs_decode", "attn_pairs_prefill",
                    "prefill_cached_tokens",
@@ -294,10 +294,15 @@ class ServeStats:
     prefill_steps: int = 0         # prefill-chunk programs dispatched
     prefill_tokens: int = 0        # real prompt tokens in them (no pad
     #                                rows; with or without --prefix-cache)
-    prefill_rows: int = 0          # rows that prefilled, summed over them
+    prefill_rows: int = 0          # SLOTS that prefilled, summed over them
+    prefill_segments: int = 0      # live program rows in them: a slot that
+    #                                prefills alone takes several, chained
+    #                                through the chunk's slot map (equal to
+    #                                prefill_rows where nothing chains)
     decode_steps: int = 0          # decode / verify programs dispatched
     decode_rows: int = 0           # rows that decoded in them
-    gated_rows: int = 0            # rows passed at pos == seq_len, over the
+    gated_rows: int = 0            # program rows passed at pos == seq_len
+    #                                (batch less the live ones), over the
     #                                target's prefill-chunk, decode and verify
     #                                programs (a draft model's: not counted)
     busy_ms: float = 0.0           # wall of WORKING iterations only

@@ -415,17 +415,22 @@ class Tracer:
     def step(self, *, decode_rows: int, prefill_rows: int, chunk: int,
              queue_depth: int, wall_ms: float, key: str | None = None,
              n: int | None = None, ts0: float | None = None,
-             phases: dict | None = None) -> None:
+             phases: dict | None = None,
+             segments: int | None = None) -> None:
         """One scheduler iteration: ring record + the per-composition
         histogram /metrics reads. `n` is the
         iteration's number (the `step` field of the request events it
         caused), `ts0` its start, `phases` the ms of its closed spans by
-        name — the step's own time is `ms` less their sum."""
+        name — the step's own time is `ms` less their sum. `segments`
+        (`seg`): the live rows of its chunk program, more than its `pre`
+        slots where a slot prefilled alone, chained."""
         if not self.enabled:
             return
         rec = {"ts": time.perf_counter(), "kind": "step", "tid": 0,
                "dec": decode_rows, "pre": prefill_rows, "chunk": chunk,
                "queue": queue_depth, "ms": round(wall_ms, 4)}
+        if segments is not None:
+            rec["seg"] = segments
         if n is not None:
             rec["n"] = n
         if ts0 is not None:
@@ -562,7 +567,10 @@ _COUNTERS = (
     ("prefill_tokens", "dllama_prefill_tokens_total",
      "Real prompt tokens prefilled (pad rows excluded)"),
     ("prefill_rows", "dllama_prefill_rows_total",
-     "Rows that prefilled, summed over prefill-chunk programs"),
+     "Slots that prefilled, summed over prefill-chunk programs"),
+    ("prefill_segments", "dllama_prefill_segments_total",
+     "Live rows of prefill-chunk programs: a slot that prefills alone "
+     "takes several consecutive segments of its prompt"),
     ("decode_steps", "dllama_decode_steps_total",
      "Decode or verify programs dispatched"),
     ("decode_rows", "dllama_decode_rows_total",

@@ -101,6 +101,12 @@ def _has_kernel(compiled) -> bool:
     return "tpu_custom_call" in compiled.as_text()
 
 
+def _cache_of(args):
+    """The KVCache among a step program's abstract arguments (the chunk
+    with a slot map takes the map after it)."""
+    return next(a for a in args if hasattr(a, "conv"))
+
+
 # Llama-2-7B weight shapes (d rows, n contraction): w1 = w3, w2, wq = wo,
 # wcls; t = 1 is decode, t = 256 (= MAX_T) the widest kernel prefill
 @pytest.mark.parametrize("d,n,t", [
@@ -379,8 +385,53 @@ def test_step_programs_keep_no_cache_sized_copy(topo, model, t):
     lowered = fn.lower(*args)
     assert kernel_call_sites(lowered.as_text()).get("kv_cache_write", 0) >= 1
     copies = r.cache_shaped_copies(lowered.compile().as_text(),
-                                   args[-1].k[0].shape)
+                                   _cache_of(args).k[0].shape)
     assert not copies, copies
+
+
+@pytest.mark.parametrize("model", ["mistral_7b", "mixtral_8x7b_12l"])
+def test_the_chunk_with_the_slot_map_holds_its_kernels_and_no_cache_copy(
+        topo, served_moe_step, model):
+    """The chunk program of the two configurations whose engines chain a
+    slot's segments (`mistral-7b`: two layers; `mixtral-8x7b-12l` AS SERVED;
+    B=8, S=4096, the Q80 round trip on) takes the slot map as a sixth
+    argument, as `abstract_step` decides with the engine's rule: the chip's
+    compiler accepts `kv_cache_write` and `flash_attention` with the second
+    prefetched scalar, every kernel the configuration lists is in the
+    program, and it holds NO `copy` of a cache leaf (PRs 27 and 30 each
+    found one round an update of a few rows). The map-less chunk, which
+    olmo's, sarvam's and granite's engines keep, compiles beside it."""
+    import rehearse_chip_compile as r
+
+    from distributed_llama_tpu.runtime.profiler import kernel_call_sites
+
+    if model == "mistral_7b":
+        spec = dataclasses.replace(r.MISTRAL_7B, n_layers=2)
+        fn, args = r.abstract_step(spec, topo.devices, batch=8, t=32,
+                                   seq_len=4096, q80=True)
+        lowered = fn.lower(*args)
+        compiled = lowered.compile()
+        kernels = {"q40_matmul", "flash_attention", "kv_cache_write"}
+        bare, bare_args = r.abstract_step(spec, topo.devices, batch=8, t=32,
+                                          seq_len=4096, q80=True,
+                                          slot_map=False)
+        assert len(bare_args) == 5
+        assert _has_kernel(bare.lower(*bare_args).compile())
+    else:
+        spec, args, lowered, compiled = served_moe_step(model, 32)
+        kernels = {"q40_matmul", "q40_expert_matmul", "flash_attention",
+                   "kv_cache_write"}
+    assert len(args) == 6 and args[5].shape == (8,)         # the map
+    sites = kernel_call_sites(lowered.as_text())
+    assert kernels <= set(sites), sites
+    assert not r.cache_shaped_copies(compiled.as_text(),
+                                     _cache_of(args).k[0].shape)
+    # and the models whose engines do not chain get no map
+    for other in (r.OLMO_HYBRID_7B, r.SARVAM_105B_EP8,
+                  r.GRANITE_4_H_SMALL_EP2):
+        assert len(r.abstract_step(
+            dataclasses.replace(other, n_layers=1, mixers=other.mixers[:1]),
+            topo.devices, batch=8, t=32, seq_len=8192)[1]) == 5
 
 
 def test_served_mixtral_prefill_chunk_fits_scoped_vmem(served_moe_step):

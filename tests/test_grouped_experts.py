@@ -221,8 +221,10 @@ def test_the_four_counters_count_the_references_routing(tiny_moe, tmp_path):
 
 
 def test_the_counters_keep_up_through_chunks_that_fetch_nothing(tiny_moe):
-    """A prompt of 45 tokens through chunks of 8 alone in the server: five
-    mid-prompt chunks fetch no logits and no decode step runs between them.
+    """Two prompts of 45 tokens through chunks of 8, side by side (one
+    alone would take a chunk program's other rows too, and be done in two):
+    five mid-prompt chunks fetch no logits and no decode step runs between
+    them.
     The counters are added from the programs that HAVE RUN, without a
     fetch and without blocking, so they trail `prefill_steps` by the
     program in flight and not by the stretch (a closed loop's eight
@@ -231,9 +233,10 @@ def test_the_counters_keep_up_through_chunks_that_fetch_nothing(tiny_moe):
     _, spec, params, toks = tiny_moe
     eng = engine(spec, params)
     sched = Scheduler(eng, chunk=CHUNK)
-    req = sched.submit(toks[:45], 2, Sampler(spec.vocab_size, temperature=0.0,
-                                             topp=0.9, seed=1))
-    a_chunk = CHUNK * spec.n_active_experts * spec.n_layers
+    reqs = [sched.submit(toks[at:at + 45], 2,
+                         Sampler(spec.vocab_size, temperature=0.0, topp=0.9,
+                                 seed=1)) for at in (0, 19)]
+    a_chunk = 2 * CHUNK * spec.n_active_experts * spec.n_layers
     for i in range(1, 6):
         sched.step()
         assert sched.stats.prefill_steps == i and sched.stats.decode_steps == 0
@@ -243,12 +246,14 @@ def test_the_counters_keep_up_through_chunks_that_fetch_nothing(tiny_moe):
         jax.block_until_ready((eng.cache,
                                [c for _, c in eng._expert_counts]))
     for _ in range(50):
-        if req.finished.is_set():
+        if all(r.finished.is_set() for r in reqs):
             break
         sched.step()
-    assert req.finished.is_set() and eng.take_expert_counts() == []
-    assert sched.stats.expert_pairs_prefill == 45 * 2 * spec.n_layers
-    assert sched.stats.expert_pairs_decode == 1 * 2 * spec.n_layers
+    assert all(r.finished.is_set() for r in reqs)
+    assert eng.take_expert_counts() == []
+    assert sched.stats.prefill_segments == sched.stats.prefill_rows == 12
+    assert sched.stats.expert_pairs_prefill == 2 * 45 * 2 * spec.n_layers
+    assert sched.stats.expert_pairs_decode == 2 * 1 * 2 * spec.n_layers
 
 
 # sha256 (16 hex digits) of the text the slot step programs of the tiny

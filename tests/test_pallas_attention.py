@@ -173,3 +173,75 @@ def test_last_attended_gates_at_seq_len_and_clamps_below_it(t):
         kw = dict(tr=tr, t=t, h=h, s=s)
         assert int(_mla_last(jnp.int32(s), i, **kw)) == 0
         assert int(_mla_last(jnp.int32(3000), i, **kw)) == 3000 + tok
+
+
+# -- the slot map: program row b attends cache slot slots[b] ------------------
+
+def _bits(x):
+    x = np.asarray(x)
+    return x.view({2: np.uint16, 4: np.uint32}[x.dtype.itemsize])
+
+
+@pytest.mark.parametrize("cache_dtype", [jnp.float32, jnp.bfloat16, F8_DTYPE],
+                         ids=["f32", "bf16", "f8"])
+@pytest.mark.parametrize("t", [1, 32])
+def test_a_slot_mapped_call_equals_the_call_on_the_gathered_slots(
+        t, cache_dtype):
+    """The K/V index map reads the map: bit for bit the map-less call on
+    caches gathered by it, gated rows (block 0 of the slot they name)
+    included, and the XLA twin's values."""
+    b, h, kvh, s, hs = 4, 8, 2, 1024, 128
+    rng = np.random.default_rng(t)
+    q = jnp.asarray(rng.standard_normal((b, t, h, hs)), jnp.bfloat16)
+    k = jnp.asarray(rng.standard_normal((b, kvh, s, hs)), cache_dtype)
+    v = jnp.asarray(rng.standard_normal((b, kvh, s, hs)), cache_dtype)
+    slots = jnp.asarray([2, 0, 3, 1], jnp.int32)
+    pos0 = jnp.asarray([5, s, 700, s - t], jnp.int32)
+    q_pos = pos0[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
+    got = flash_attention(q, k, v, q_pos, interpret=True, slots=slots)
+    want = flash_attention(q, k[slots], v[slots], q_pos, interpret=True)
+    assert np.array_equal(_bits(got), _bits(want))
+    live = np.asarray(pos0) < s
+    # the oracle in float32: XLA's CPU backend has no batched bf16 dot
+    f32 = lambda x: x.astype(jnp.float32)  # noqa: E731
+    oracle = decode_attention(f32(q), f32(k)[slots], f32(v)[slots], q_pos)
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32)[live], np.asarray(oracle, np.float32)[live],
+        atol=5e-2, rtol=5e-2)
+
+
+@pytest.mark.parametrize("cache_dtype", [jnp.float32, jnp.bfloat16, F8_DTYPE],
+                         ids=["f32", "bf16", "f8"])
+@pytest.mark.parametrize("start,live", [(0, 8), (480, 5), (1024 - 256, 8)])
+def test_rows_chained_on_one_slot_equal_as_many_calls_in_a_row(
+        start, live, cache_dtype):
+    """Eight rows as consecutive 32-token segments of ONE slot, whose cache
+    already holds all of them (the write precedes the attention inside a
+    layer), attend what each would attend in a call of its own, in which
+    the later segments are not written yet: the mask hides them, so the
+    outputs are equal bit for bit."""
+    b, t, h, kvh, s, hs, slot = 8, 32, 8, 2, 1024, 128, 5
+    rng = np.random.default_rng(start + live)
+    q = jnp.asarray(rng.standard_normal((b, t, h, hs)), jnp.bfloat16)
+    k = jnp.asarray(rng.standard_normal((b, kvh, s, hs)), cache_dtype)
+    v = jnp.asarray(rng.standard_normal((b, kvh, s, hs)), cache_dtype)
+    pos0 = np.full((b,), s, np.int32)
+    pos0[:live] = start + t * np.arange(live)
+    slots = np.asarray([slot] * live
+                       + [i for i in range(b) if i != slot][:b - live])
+    q_pos = jnp.asarray(pos0)[:, None] + jnp.arange(t, dtype=jnp.int32)[None]
+    got = flash_attention(q, k, v, q_pos, interpret=True,
+                          slots=jnp.asarray(slots, jnp.int32))
+    for r in range(live):
+        # the call of its own: row `slot` is the slot, as the scheduler
+        # packs a single segment; what lies past the segment is stale
+        one = np.full((b,), s, np.int32)
+        one[slot] = pos0[r]
+        stale = pos0[r] + t
+        k1 = k.at[slot, :, stale:].set(jnp.asarray(7.0, cache_dtype))
+        v1 = v.at[slot, :, stale:].set(jnp.asarray(-3.0, cache_dtype))
+        alone = flash_attention(
+            jnp.zeros_like(q).at[slot].set(q[r]), k1, v1,
+            jnp.asarray(one)[:, None] + jnp.arange(t, dtype=jnp.int32)[None],
+            interpret=True)
+        assert np.array_equal(_bits(got[r]), _bits(alone[slot])), r

@@ -196,3 +196,128 @@ def test_scheduler_streams_equal_with_kernel_and_with_scatter(monkeypatch):
     assert got == want and all(len(t) for t in got)
     assert all(np.array_equal(_bits(g), _bits(w))
                for g, w in zip(got_cache, want_cache))
+
+
+# -- the slot map: program row b writes cache slot slots[b] -------------------
+
+def _scatter_mapped(kc, vc, k, v, pos, slots):
+    idx = pos[:, None] + jnp.arange(k.shape[1], dtype=jnp.int32)[None, :]
+    return transformer._scatter_cache_write(kc, vc, k, v, idx, None,
+                                            slots=slots)
+
+
+@pytest.mark.parametrize("t", [1, 32])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, F8],
+                         ids=["f32", "bf16", "fp8"])
+def test_a_slot_mapped_write_equals_the_write_on_the_gathered_slots(dtype, t):
+    """Under a permutation the kernel leaves what the map-less call leaves
+    in the caches gathered by the map, and what the scatter, which takes
+    the same map, leaves: bit for bit."""
+    pos = _rows(dtype, t)
+    slots = jnp.array([4, 0, 5, 1, 3, 2], jnp.int32)
+    kc, vc, k, v = _inputs(dtype, t, pos.shape[0], seed=t + 3)
+    got = kv_cache_write(kc, vc, k, v, pos, slots=slots, interpret=True)
+    on_gathered = kv_cache_write(kc[slots], vc[slots], k, v, pos,
+                                 interpret=True)
+    assert _same_bits([c[slots] for c in got], on_gathered)
+    assert _same_bits(got, _scatter_mapped(kc, vc, k, v, pos, slots))
+
+
+@pytest.mark.parametrize("start,live", [(0, 8), (64, 5), (S - 256, 8),
+                                        (S - 96, 3)])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, F8],
+                         ids=["f32", "bf16", "fp8"])
+def test_rows_chained_on_one_slot_equal_as_many_calls_in_a_row(dtype, start,
+                                                               live):
+    """Eight rows (or fewer, the rest gated and naming the other slots) as
+    consecutive 32-token segments of ONE slot leave in its cache what as
+    many map-less calls, one segment a call, leave; every other slot keeps
+    its bits, so gated rows wrote nothing."""
+    from distributed_llama_tpu.runtime.scheduler import chain_map
+
+    b, t, slot = 8, 32, 3
+    kc, vc, k, v = _inputs(dtype, t, b, seed=start + live)
+    pos = np.full((b,), S, np.int32)
+    pos[:live] = start + t * np.arange(live)
+    slots = chain_map(slot, live, b)
+    got = kv_cache_write(kc, vc, k, v, jnp.asarray(pos),
+                         slots=jnp.asarray(slots), interpret=True)
+    want = (kc, vc)
+    for r in range(live):
+        one = np.full((b,), S, np.int32)
+        one[slot] = pos[r]
+        seg = [jnp.zeros_like(x).at[slot].set(x[r]) for x in (k, v)]
+        want = kv_cache_write(*want, *seg, jnp.asarray(one), interpret=True)
+    assert _same_bits(got, want)
+    others = np.arange(b) != slot
+    assert _same_bits([c[others] for c in got], [kc[others], vc[others]])
+    lo, hi = start, start + live * t
+    assert not np.array_equal(_bits(got[0][slot, :, lo:hi]),
+                              _bits(kc[slot, :, lo:hi]))
+    assert _same_bits([c[slot, :, :lo] for c in got],
+                      [kc[slot, :, :lo], vc[slot, :, :lo]])
+    assert _same_bits([c[slot, :, hi:] for c in got],
+                      [kc[slot, :, hi:], vc[slot, :, hi:]])
+
+
+def _collisions(pos, slots, dtype, t=32, own=True):
+    """(row, row, (slot, tile)) for every cache block two DISTINCT rows of
+    a slot-mapped kv_cache_write visit."""
+    blocks = np.asarray(pallas_kv_write.visited_blocks(
+        pos, slots, t=t, seq_len=S, dtype=dtype, own=own))
+    seen, found = {}, []
+    for row, visits in enumerate(blocks):
+        for blk in {tuple(int(x) for x in vis) for vis in visits}:
+            if blk in seen:
+                found.append((seen[blk], row, blk))
+            seen[blk] = row
+    return found
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, F8],
+                         ids=["f32", "bf16", "fp8"])
+def test_blocks_of_distinct_rows_are_disjoint_for_every_map_the_packer_makes(
+        dtype):
+    """The race no interpret-mode run can show (the grid runs in order
+    there): in and out are one buffer and the next block is fetched before
+    the last is written back, so two rows must never visit one (slot,
+    tile) block. Over the maps `Scheduler._prefill_chunk` can make (the
+    identity with any rows live at any position; k chained rows of any
+    slot from any multiple of the chunk, the rest gated on the other
+    slots) no two rows do. On the host: the index map's own function."""
+    from distributed_llama_tpu.runtime.scheduler import chain_map
+
+    b, t = 8, 32
+    starts = [0, t, 7 * t, S // 2, S - 8 * t, S - 3 * t, S - t]
+    for slot in (0, 3, 7):
+        for k in range(2, b + 1):
+            for start in starts:
+                if start + (k - 1) * t >= S:
+                    continue
+                pos = np.full((b,), S, np.int32)
+                pos[:k] = start + t * np.arange(k)
+                assert not _collisions(pos, chain_map(slot, k, b), dtype), (
+                    slot, k, start)
+    # the identity map: rows live at aligned and unaligned positions, on
+    # the context's last tile and straddling its end, or gated
+    rng = np.random.default_rng(0)
+    edge = [0, 1, 7, 16, 31, 33, S - 40, S - t, S - t + 1, S - 1, S]
+    for _ in range(64):
+        pos = rng.choice(edge + list(rng.integers(0, S, 4)), b)
+        assert not _collisions(pos, np.arange(b), dtype), pos
+    # and what the packer must never make is caught:
+    r = row_tile(dtype)
+    chained = np.full((b,), S, np.int32)
+    chained[:2] = [S - 2 * t, S - t]
+    # a gated row naming the live slot lands on the context's last tile
+    assert _collisions(chained, np.asarray([3, 3, 3, 0, 1, 2, 4, 5]), dtype)
+    # a chained start off the chunk shares a tile with its successor
+    off = np.full((b,), S, np.int32)
+    off[:2] = [r // 2, r // 2 + t]
+    assert _collisions(off, chain_map(3, 2, b), dtype)
+    # the map-less index map: an aligned row's spare visit is the next
+    # row's first tile (why a mapped call stops at the row's own last tile)
+    aligned = np.full((b,), S, np.int32)
+    aligned[:2] = [0, t]
+    assert _collisions(aligned, chain_map(3, 2, b), dtype, own=False)
+    assert not _collisions(aligned, chain_map(3, 2, b), dtype)

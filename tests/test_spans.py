@@ -345,11 +345,14 @@ def test_step_record_carries_n_ts0_phases_and_events_name_their_step(tiny):
     assert TRACER.spans
     sched = Scheduler(_engine(tiny), chunk=8)
     sched.warmup()
-    req = sched.submit(list(range(1, 12)), 6, _greedy(spec))
+    req = sched.submit(list(range(1, 20)), 6, _greedy(spec))
     _drain(sched, [req])
     sched.close()
     evs = TRACER.recent(0)
     steps = [e for e in evs if e["kind"] == "step"]
+    # a slot alone takes both rows of a chunk program: 2 segments, then 1
+    assert [(e["pre"], e["seg"]) for e in steps[:3]] == [(1, 2), (1, 1),
+                                                         (0, 0)]
     assert [e["n"] for e in steps] == list(range(1, len(steps) + 1))
     for e in steps:
         assert e["ts0"] <= e["ts"]
@@ -357,7 +360,7 @@ def test_step_record_carries_n_ts0_phases_and_events_name_their_step(tiny):
         ph = e["phases"]
         assert set(ph) <= set(SPAN_NAMES) and "sched.admit" in ph
         assert sum(ph.values()) <= e["ms"] + 0.01   # self time >= 0
-    # the first iteration prefills 8 of 11 tokens (no fetch), the second
+    # the first iteration prefills 16 of 19 tokens (no fetch), the second
     # finishes the prompt and samples, later ones decode
     assert "sched.dispatch.prefill" in steps[0]["phases"]
     assert "sched.wait" not in steps[0]["phases"]

@@ -218,7 +218,8 @@ def test_scheduler_records_span_and_step_timeline(tiny):
     TRACER.configure(capacity=4096, decode_every=2)
     eng = _engine(tiny)
     sched = Scheduler(eng, chunk=8)
-    req = sched.submit([1, 9, 23, 54, 7, 11, 40, 3, 15], 6, _greedy(spec))
+    req = sched.submit([1, 9, 23, 54, 7, 11, 40, 3, 15, 2, 8, 31, 5, 77, 6,
+                        19, 4], 6, _greedy(spec))
     while not req.finished.is_set():
         sched.step()
     sched.close()
@@ -235,8 +236,11 @@ def test_scheduler_records_span_and_step_timeline(tiny):
     assert kinds[-1] == "finish"
     fin = span[-1]
     assert fin["reason"] == "length" and fin["n_out"] == 6
-    # 9-token prompt at chunk 8 = exactly 2 prefill events
+    # 17-token prompt at chunk 8, alone on 2 rows: 3 segments, chained
+    # 2 + 1 = exactly 2 prefill events, one a chunk program
     assert kinds.count("prefill") == 2
+    assert [(e["off"], e["n"]) for e in span
+            if e["kind"] == "prefill"] == [(0, 16), (16, 1)]
     assert kinds.count("decode") >= 1  # cadence 2 over 6 tokens
     # timestamps are monotonic within the span (one clock domain)
     assert all(a["ts"] <= b["ts"] for a, b in zip(span, span[1:]))

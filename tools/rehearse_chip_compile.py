@@ -196,10 +196,13 @@ def _loaded_params(spec: ModelSpec, dtype) -> dict:
 
 def abstract_step(spec: ModelSpec, devices, *, tp: int = 1, batch: int,
                   t: int, seq_len: int, q80: bool = False,
-                  dtype=jnp.bfloat16):
+                  dtype=jnp.bfloat16, slot_map: bool | None = None):
     """(jitted step, abstract args) for the slot program of shape (B, T):
     T == 1 is `slot_decode_step`, T > 1 `slot_prefill_chunk_T`. `devices`
-    are described devices; tp > 1 lays them out as the engine's mesh."""
+    are described devices; tp > 1 lays them out as the engine's mesh.
+    slot_map: whether the chunk's rows follow a slot map (a last argument
+    `slots`); None decides as `Engine.__init__` does, from the layer kinds
+    and the mesh."""
     from distributed_llama_tpu.models.params import fuse_layer_weights
     from distributed_llama_tpu.models.transformer import KVCache, forward
     from distributed_llama_tpu.ops.sharded_vocab import vocab_shard_axes
@@ -254,12 +257,16 @@ def abstract_step(spec: ModelSpec, devices, *, tp: int = 1, batch: int,
         return (jax.jit(slot_decode_step, donate_argnums=(3,)),
                 (params, tokens, pos, cache))
 
-    def slot_prefill_chunk(params, tokens, pos0, logit_index, cache):
+    if slot_map is None:    # Engine._chunk_slot_map
+        slot_map = not spec.has_state and not spec.is_mla and tp == 1
+
+    def slot_prefill_chunk(params, tokens, pos0, logit_index, cache, *slots):
         return forward(params, spec, tokens, pos0, cache,
-                       logit_index=logit_index, **common)
+                       logit_index=logit_index, **common,
+                       slots=slots[0] if slots else None)
 
     return (jax.jit(slot_prefill_chunk, donate_argnums=(4,)),
-            (params, tokens, pos, pos, cache))
+            (params, tokens, pos, pos, cache) + ((pos,) if slot_map else ()))
 
 
 def cache_shaped_copies(compiled_text: str, leaf_shape) -> list[str]:
