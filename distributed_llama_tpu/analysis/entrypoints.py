@@ -168,6 +168,27 @@ def entry_points(max_devices: int | None = None,
         (params_c, tok_c, pos_c, lidx_c, cache_c, slots_c),
         {"activation_elems": 4 * 8 * spec_c.dim, "dim": spec_c.dim}))
 
+    # slot_prefill_chunk_state_mapped: the chunk with the slot map over
+    # state layers whose mixer chains (models/transformer.takes_slot_map:
+    # SSM; a tiny GRANITE_HYBRID): a row that continues the row before it
+    # starts from that row's final state and convolution tail inside the
+    # program (the XLA twin's row scan here; ssd_chunk on the chip).
+    from ..testing import tiny_granite_spec
+
+    spec_g, params_g, _, _, cache_g = build_forward_inputs(
+        tiny_granite_spec(seq_len=32), batch=4, t=8)
+
+    def slot_prefill_chunk_state_mapped(params, tok, pos, logit_index, cache,
+                                        slots):
+        return forward(params, spec_g, tok, pos, cache,
+                       logit_index=logit_index, compute_dtype=jnp.float32,
+                       slots=slots)
+
+    out.append(EntryPoint(
+        "slot_prefill_chunk_state_mapped", slot_prefill_chunk_state_mapped,
+        (params_g, tok_c, pos_c, lidx_c, cache_g, slots_c),
+        {"activation_elems": 4 * 8 * spec_g.dim, "dim": spec_g.dim}))
+
     # slot_seed_prefix: the radix prefix cache's admission-time seeding
     # (runtime/prefix_cache.py) — an on-device arena-block gather written
     # as a slot row's leading cache positions. Traced through the SAME

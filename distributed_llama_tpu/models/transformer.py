@@ -464,19 +464,39 @@ class SegmentRows(NamedTuple):
     int32, the tokens of row b that advance its state — 0 for a gated row
     (pos == the context), logit_index + 1 in a right-padded tail chunk,
     else T; fresh (B,) bool, the row's segment starts at position 0, so
-    its state starts from zeros whatever the slot held."""
+    its state starts from zeros whatever the slot held. Under a slot map
+    (forward's `slots`; None without one, and the next two with it):
+    chained (B,) bool, the row continues the row before it (the same slot,
+    both live), so it starts from that row's final state and tail, not the
+    slot's; last (B,) bool, a live row that no row continues, whose state
+    and tail are its slot's new ones."""
 
     n_valid: jnp.ndarray
     fresh: jnp.ndarray
+    slots: jnp.ndarray | None = None
+    chained: jnp.ndarray | None = None
+    last: jnp.ndarray | None = None
 
 
-def _short_conv(xin, tail, lw, rows: SegmentRows, taps: int):
+def _short_conv(xin, conv, lw, rows: SegmentRows, taps: int):
     """The causal depthwise convolution of a state layer's mixer, then SiLU:
     row t of the output sees rows t .. t + taps - 1 of [tail ; xin] (plus
     the layer's conv_b where it has one); the new tail is the last taps - 1
-    rows that COUNT. Returns (float32 output, new tail)."""
+    rows that COUNT. `conv` is the layer's tail leaf, a row a slot: without
+    a slot map row b's tail is conv[b]; with one it is conv[slots[b]] or,
+    for a row that continues the row before it, the last taps - 1 rows of
+    [that row's tail ; its inputs] (a whole segment, so its inputs alone
+    where T >= taps - 1), and a slot's last live row alone writes the leaf.
+    Returns (float32 output, the leaf's new value)."""
     t = xin.shape[1]
-    tail = jnp.where(rows.fresh[:, None, None], 0, tail)
+    tail = conv if rows.slots is None else conv[rows.slots]
+    tail = first = jnp.where(rows.fresh[:, None, None], 0, tail)
+    if rows.slots is not None:
+        for _ in range(-(-(taps - 1) // t)):    # once, where T >= taps - 1
+            left = jnp.concatenate([tail.astype(xin.dtype), xin],
+                                   axis=1)[:, -(taps - 1):].astype(tail.dtype)
+            tail = jnp.where(rows.chained[:, None, None],
+                             jnp.roll(left, 1, axis=0), first)
     xcat = jnp.concatenate([tail.astype(xin.dtype), xin], axis=1)
     conv_w = lw["conv_w"]
     y = sum(conv_w[j] * xcat[:, j:j + t].astype(jnp.float32)
@@ -487,7 +507,10 @@ def _short_conv(xin, tail, lw, rows: SegmentRows, taps: int):
     tail = jax.vmap(
         lambda xc, n: lax.dynamic_slice_in_dim(xc, n, taps - 1, 0))(
             xcat, rows.n_valid).astype(tail.dtype)
-    return y, tail
+    if rows.slots is None:
+        return y, tail
+    return y, conv.at[jnp.where(rows.last, rows.slots, conv.shape[0])].set(
+        tail, mode="drop")
 
 
 def _delta_block(x, lw, spec: ModelSpec, state, tail, rows: SegmentRows,
@@ -566,7 +589,7 @@ def _ssm_block(x, lw, spec: ModelSpec, state, tail, rows: SegmentRows, cfg):
             xh, dt, -jnp.exp(lw["a_log"]),
             y[..., inner:inner + gn].reshape(b, t, spec.ssm_groups, n),
             y[..., inner + gn:].reshape(b, t, spec.ssm_groups, n),
-            state, rows.n_valid, rows.fresh,
+            state, rows.n_valid, rows.fresh, rows.slots, rows.chained,
             use_pallas=bool(cfg.get("use_pallas")),
             interpret=cfg.get("pallas_interpret", False))
         o = o + lw["ssm_d"][:, None] * xh
@@ -579,6 +602,23 @@ def _ssm_block(x, lw, spec: ModelSpec, state, tail, rows: SegmentRows, cfg):
 
 # a state layer's mixer by its kind; both keep _delta_block's contract
 _STATE_MIXERS = {LayerKind.DELTA: _delta_block, LayerKind.SSM: _ssm_block}
+# the kinds whose mixer follows a slot map: its kernel hands a row's final
+# state to the row that continues it (ssd_chunk does; delta_rule_chunk not)
+_CHAINING_MIXERS = frozenset({LayerKind.SSM})
+
+
+def takes_slot_map(spec: ModelSpec, meshed: bool) -> bool:
+    """Whether the rows of a chunk program may follow a slot map (forward's
+    `slots`), so that consecutive rows prefill ONE slot: rows that are not
+    sharded (no mesh: dp splits the rows, pp and sp trace other regions),
+    every cache leaf the dense K/V cache that `kv_cache_write` and
+    `flash_attention` address by slot (not the latent one), and every state
+    layer of a kind whose mixer chains. From the layer kinds and the mesh,
+    never a model's name: the ONE place the rule is written
+    (Engine.prefill_rows_per_slot, forward, tools/rehearse_chip_compile)."""
+    return (not meshed and not spec.is_mla
+            and all(k in _CHAINING_MIXERS
+                    for k in spec.layer_kinds if k.has_state))
 
 
 def _scaled(out, spec: ModelSpec):
@@ -1036,14 +1076,19 @@ def forward(
     some real token chose and the live (token, expert) pairs, summed over
     the MoE layers (_moe_ffn; the served step programs' window counters).
     slots: (B,) int32, the cache slot row b reads and writes at pos0[b]
-    (per-row positions, one chip, every layer a dense K/V cache:
-    Engine.prefill_rows_per_slot). Rows of one slot at consecutive
-    segments prefill that slot several segments a program; every other op
-    is independent across rows, so the slot's logits are those of the same
-    segments one a program, bit for bit. None: row b is slot b.
+    (per-row positions, where takes_slot_map allows it). Rows of one slot
+    at consecutive segments prefill that slot several segments a program:
+    a row attends what the rows before it wrote, and in a state layer
+    starts from the final state and tail of the row it continues
+    (SegmentRows.chained); every other op is independent across rows, so
+    the slot's logits are those of the same segments one a program, bit
+    for bit. None: row b is slot b.
     """
-    assert slots is None or (pp_mesh is None and not spec.has_state), (
-        "a slot map over a state layer or a pp mesh")
+    assert slots is None or takes_slot_map(
+        spec, any(m is not None for m in (pp_mesh, tp_mesh, sp_mesh,
+                                          sp_cache_mesh))), (
+        "a slot map over a mesh, the latent cache or a mixer that does not "
+        "chain")
     cfg = dict(activation_q80=activation_q80, compute_dtype=compute_dtype,
                use_pallas=use_pallas, tp_mesh=tp_mesh, tp_reduce=tp_reduce,
                pallas_interpret=pallas_interpret)
@@ -1101,7 +1146,7 @@ def forward(
         kinds, at = spec.layer_kinds, spec.cache_index
         with jax.named_scope("embed"):
             rows = (_segment_rows(spec, cache, pos0, b, t, logit_index,
-                                  logits_for_all)
+                                  logits_for_all, slots)
                     if spec.has_state or spec.is_moe else None)
         for l in range(spec.n_layers):
             if kinds[l].has_state:
@@ -1151,10 +1196,12 @@ def forward(
 
 
 def _segment_rows(spec: ModelSpec, cache: KVCache, pos0, b: int, t: int,
-                  logit_index, logits_for_all: bool) -> SegmentRows:
+                  logit_index, logits_for_all: bool,
+                  slots=None) -> SegmentRows:
     """SegmentRows of one forward, from what every caller already passes:
     the rows' first positions (a row at the context's end is gated, as
-    for the cache writes) and, in a right-padded segment, logit_index."""
+    for the cache writes), in a right-padded segment logit_index, and the
+    slot map where the model has a state for it to reach."""
     gate_at = cache.k[0].shape[2] if cache.k else spec.seq_len
     pos_rows = jnp.broadcast_to(jnp.asarray(pos0, jnp.int32), (b,))
     n_tok = jnp.full((b,), t, jnp.int32)
@@ -1162,4 +1209,11 @@ def _segment_rows(spec: ModelSpec, cache: KVCache, pos0, b: int, t: int,
         n_tok = jnp.broadcast_to(
             jnp.asarray(logit_index, jnp.int32) + 1, (b,))
     n_valid = jnp.where(pos_rows < gate_at, n_tok, 0)
-    return SegmentRows(n_valid, (pos_rows == 0) & (n_valid > 0))
+    fresh = (pos_rows == 0) & (n_valid > 0)
+    if slots is None or not spec.has_state:
+        return SegmentRows(n_valid, fresh)
+    from ..ops.pallas_ssd import chained_rows, last_rows
+
+    chained = chained_rows(slots, n_valid)
+    return SegmentRows(n_valid, fresh, slots, chained,
+                       last_rows(chained, n_valid))

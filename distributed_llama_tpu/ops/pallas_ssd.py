@@ -32,6 +32,16 @@ and the gated-row block trick; the same SegmentRows contract):
   * tokens t >= n_valid[b] (the pad of a tail chunk) get dt = 0: they
     neither decay nor write. Their outputs are never read.
 
+With a slot map (`slots`, the chunk program's: models/transformer.forward)
+row r advances the state of slot slots[r], and a row that CONTINUES the row
+before it (`chained_rows`: the same slot, both live) starts from that row's
+final state inside the call: ssd_chunk walks its grid head blocks outermost
+and rows innermost, so a slot's consecutive rows name ONE state block, which
+stays in VMEM between their steps (the accumulator pattern: a chained row
+reads S_0 from the output block), is fetched once and written back once a
+call however many rows advance it. The caller owes: a slot's live rows are
+consecutive, every one but the last a whole chunk.
+
 Decode is the recurrence itself, a rank-one update a head on the VPU
 (`ssd_decode`), as `delta_rule_decode` is and for the same reason. Everything
 is float32, the matmuls at full precision: the state carries every earlier
@@ -82,18 +92,20 @@ def _spread(rows: int, cols: int, by_col: int):
     return ((c >= r * by_col) & (c < (r + 1) * by_col)).astype(jnp.float32)
 
 
-def _chunk_kernel(nv_ref, fresh_ref, row_ref, blk_ref, x_ref, b_ref, c_ref,
-                  dg_ref, grow_ref, end_ref, s_ref, o_ref, so_ref, *, heads,
-                  p):
-    """A chunk of C tokens, `heads` heads of one row a grid step."""
-    r = pl.program_id(0)
+def _chunk_kernel(nv_ref, fresh_ref, chain_ref, row_ref, slot_ref, x_ref,
+                  b_ref, c_ref, dg_ref, grow_ref, end_ref, s_ref, o_ref,
+                  so_ref, *, heads, p):
+    """A chunk of C tokens, `heads` heads of one row a grid step; the grid
+    is (head blocks, rows). A chained row's S_0 is what the step before it
+    left in the output block, which both steps name."""
+    r = pl.program_id(1)
 
     @pl.when(nv_ref[r] > 0)
     def _():
         c = x_ref.shape[1]
         hb, hp, hc = heads, heads * p, heads * c
         x, bm, cm = x_ref[0], b_ref[0], c_ref[0]       # (C, HP), (C, N) x 2
-        s0 = s_ref[0]                                  # (HP, N)
+        s0 = jnp.where(chain_ref[r] > 0, so_ref[0], s_ref[0])     # (HP, N)
         s0 = jnp.where(fresh_ref[r] > 0, jnp.zeros_like(s0), s0)
         dt, g = dg_ref[0, 0, :c], dg_ref[0, 0, c:]     # (C, HB) each
         g_end = g[c - 1:]                              # (1, HB)
@@ -124,7 +136,11 @@ def _chunk_kernel(nv_ref, fresh_ref, row_ref, blk_ref, x_ref, b_ref, c_ref,
         so_ref[0] = keep * s0 + _dot(x * lanes[2 * c:], bm,
                                      (((0,), (0,)), ((), ())))
 
-    _hand_back(nv_ref, row_ref, s_ref, so_ref)
+    # no live row in the whole call (a warm-up): every row looks at row 0's
+    # blocks, and row 0 hands each head block back as it came
+    @pl.when((nv_ref[r] == 0) & (row_ref[r] == r))
+    def _():
+        so_ref[...] = s_ref[...]
 
 
 def _step_kernel(nv_ref, fresh_ref, row_ref, blk_ref, xdt_ref, b_ref, c_ref,
@@ -156,15 +172,48 @@ def _step_kernel(nv_ref, fresh_ref, row_ref, blk_ref, xdt_ref, b_ref, c_ref,
     _hand_back(nv_ref, row_ref, s_ref, so_ref)
 
 
-def _call(body, name, operands, per_row, state, state_block, out_shape,
-          out_block, n_j, n_valid, fresh, interpret):
-    """The pallas_call both kernels share: grid (rows, head blocks); each
-    operand's block with the dimension its head block counts along (None:
-    one block a row); the state last and aliased onto the second output; a
-    gated row's blocks as _gated_blocks says."""
-    row, blk = _gated_blocks(n_valid > 0, n_j)
+def chained_rows(slots, n_valid):
+    """(B,) bool: row r CONTINUES row r - 1, the same slot and both live,
+    so its state and its convolution's tail start where that row's end."""
+    live = n_valid > 0
+    return jnp.concatenate([jnp.zeros((1,), bool), (slots[1:] == slots[:-1])
+                            & live[1:] & live[:-1]])
 
-    def spec(block, head_dim):
+
+def last_rows(chained, n_valid):
+    """(B,) bool: the live rows no row continues, a slot's LAST: what they
+    leave is their slot's new state and tail."""
+    return (n_valid > 0) & ~jnp.concatenate([chained[1:],
+                                             jnp.zeros((1,), bool)])
+
+
+def _call(body, name, operands, per_row, state, state_block, out_shape,
+          out_block, n_j, n_valid, fresh, interpret, chain=None):
+    """The pallas_call both kernels share: each operand's block with the
+    dimension its head block counts along (None: one block a row); the
+    state last and aliased onto the second output.
+
+    chain None (ssd_decode): grid (rows, head blocks), row r is slot r, a
+    gated row's blocks as _gated_blocks says.
+
+    chain (chained, slots) (ssd_chunk): grid (head blocks, rows), so that
+    the steps of a slot's consecutive rows follow each other and name one
+    state block, (slots[r], j). _gated_blocks' rule in this order and in
+    slot indices: a gated row's steps name, at the SAME head block, the
+    blocks of the nearest live row before it, else of the first live row
+    (else row 0's), and the state block of THAT row's slot: the block the
+    neighbouring step holds, so nothing is copied in or written back."""
+    row, blk = _gated_blocks(n_valid > 0, n_j)
+    if chain is None:
+        scalars = (n_valid, fresh.astype(jnp.int32), row, blk)
+        grid = (state.shape[0], n_j)
+    else:
+        chained, slots = chain
+        scalars = (n_valid, fresh.astype(jnp.int32),
+                   chained.astype(jnp.int32), row, slots[row])
+        grid = (n_j, n_valid.shape[0])
+
+    def spec(block, head_dim, of_state=False):
         def at(i, j, nv, fr, rw, bk):
             on = nv[i] > 0
             idx = [0] * len(block)
@@ -172,37 +221,49 @@ def _call(body, name, operands, per_row, state, state_block, out_shape,
             if head_dim is not None:
                 idx[head_dim] = jnp.where(on, j, bk[i])
             return tuple(idx)
-        return pl.BlockSpec(block, at)
+
+        def at_slot(j, i, nv, fr, ch, rw, sl):
+            idx = [0] * len(block)
+            idx[0] = (sl if of_state else rw)[i]
+            if head_dim is not None:
+                idx[head_dim] = j
+            return tuple(idx)
+        return pl.BlockSpec(block, at if chain is None else at_slot)
 
     operands = (*operands, state)
-    in_specs = [spec(b, d) for b, d in per_row] + [spec(state_block, 1)]
+    in_specs = [spec(b, d) for b, d in per_row] + [spec(state_block, 1, True)]
     out = jax.ShapeDtypeStruct(out_shape, jnp.float32)
     return pl.pallas_call(
         body,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=(state.shape[0], n_j),
+            num_scalar_prefetch=len(scalars),
+            grid=grid,
             in_specs=in_specs,
-            out_specs=[spec(*out_block), spec(state_block, 1)],
+            out_specs=[spec(*out_block), spec(state_block, 1, True)],
         ),
         out_shape=[out, jax.ShapeDtypeStruct(state.shape, state.dtype)],
-        # the four scalars come first; the state is written where it stands
-        input_output_aliases={3 + len(operands): 1},
+        # the scalars come first; the state is written where it stands
+        input_output_aliases={len(scalars) + len(operands) - 1: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name=name,
-    )(n_valid, fresh.astype(jnp.int32), row, blk, *operands)
+    )(*scalars, *operands)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def ssd_chunk(x, dt, g, bm, cm, state, n_valid, fresh,
-              interpret: bool = False):
+def ssd_chunk(x, dt, g, bm, cm, state, n_valid, fresh, slots=None,
+              chained=None, interpret: bool = False):
     """x (B, C, H, P), dt and g = dt A (B, C, H), both 0 past n_valid; bm,
-    cm (B, C, N); the state (B, H, P, N). Returns (y (B, C, H, P), the
-    state aliased onto its input)."""
+    cm (B, C, N); the state (slots, H, P, N); slots (B,) int32, the slot
+    whose state row r advances (None: row r's), and chained (B,) bool as
+    chained_rows gives it. Returns (y (B, C, H, P), the state aliased onto
+    its input)."""
     b, c, h, p = x.shape
     n = state.shape[-1]
+    if slots is None:
+        slots = jnp.arange(b, dtype=jnp.int32)
+        chained = jnp.zeros((b,), bool)
     hb = _head_block(h, HEAD_BLOCK)
     n_j = h // hb
     gsum = jnp.cumsum(g, axis=1)
@@ -215,8 +276,9 @@ def ssd_chunk(x, dt, g, bm, cm, state, n_valid, fresh,
         (x.reshape(b, c, h * p), bm, cm, dg, grow, end),
         [((1, c, hb * p), 2), ((1, c, n), None), ((1, c, n), None),
          ((1, 1, 2 * c, hb), 1), ((1, 1, 1, hb * c), 1), ((1, hb, n), 1)],
-        state.reshape(b, h * p, n), (1, hb * p, n), (b, c, h * p),
-        ((1, c, hb * p), 2), n_j, n_valid, fresh, interpret)
+        state.reshape(-1, h * p, n), (1, hb * p, n), (b, c, h * p),
+        ((1, c, hb * p), 2), n_j, n_valid, fresh, interpret,
+        chain=(chained, slots.astype(jnp.int32)))
     return y.reshape(b, c, h, p), s.reshape(state.shape)
 
 
@@ -239,16 +301,20 @@ def ssd_decode(x, dt, g, bm, cm, state, n_valid, fresh,
     return y[:, None], s
 
 
-def ssd_scan(x, dt, a, bm, cm, state, n_valid, fresh, *,
-             use_pallas: bool = False, interpret: bool = False):
+def ssd_scan(x, dt, a, bm, cm, state, n_valid, fresh, slots=None,
+             chained=None, *, use_pallas: bool = False,
+             interpret: bool = False):
     """Advance `state` (B, H, P, N) float32 by T tokens a row.
 
     x (B, T, H, P); dt (B, T, H), the step after its softplus; a (H,) < 0;
     bm, cm (B, T, G, N), a group's B and C; n_valid (B,) int32: the tokens
     of row b that count (0: a gated row); fresh (B,) bool: row b starts
-    from zeros. Returns (y (B, T, H, P) float32, WITHOUT the skip term D x,
-    new state). Rows of `y` past n_valid are not meaningful; a gated row's
-    are zeros."""
+    from zeros; slots (B,) int32 with chained = chained_rows(slots,
+    n_valid): row b advances state[slots[b]], from the row before's end
+    where it continues it (the module docstring says what the caller
+    owes); None: row b is slot b. Returns (y (B, T, H, P) float32, WITHOUT
+    the skip term D x, new state). Rows of `y` past n_valid are not
+    meaningful; a gated row's are zeros."""
     b, t, h, p = x.shape
     groups, n = bm.shape[2:]
     f32 = jnp.float32
@@ -258,14 +324,36 @@ def ssd_scan(x, dt, a, bm, cm, state, n_valid, fresh, *,
     x, bm, cm = (v.astype(f32) for v in (x, bm, cm))
     fresh = fresh & (n_valid > 0)
     live = (n_valid > 0)[:, None, None, None]
-    if use_pallas and ssd_supported(t, h, p, n, groups):
-        kernel = ssd_decode if t == 1 else ssd_chunk
-        y, state = kernel(x, dt, g, bm[:, :, 0], cm[:, :, 0], state,
-                          n_valid.astype(jnp.int32), fresh,
-                          interpret=interpret)
-    else:
+    kernels = use_pallas and ssd_supported(t, h, p, n, groups)
+    tight = (x, dt, g, bm[:, :, 0], cm[:, :, 0], state,
+             n_valid.astype(jnp.int32), fresh)
+    if kernels and t > 1:
+        y, state = ssd_chunk(*tight, slots, chained, interpret=interpret)
+    elif kernels and slots is None:
+        y, state = ssd_decode(*tight, interpret=interpret)
+    elif slots is None:
         y, state = _ssd_xla(x, dt, g, bm, cm, state, fresh)
+    else:   # ssd_decode takes no map: a one-token CHUNK under one, too
+        y, state = _ssd_xla_mapped(x, dt, g, bm, cm, state, fresh, slots,
+                                   chained, last_rows(chained, n_valid))
     return jnp.where(live, y, 0.0), state
+
+
+def _ssd_xla_mapped(x, dt, g, bm, cm, state, fresh, slots, chained, last):
+    """The XLA twin under a slot map: the rows one after another, each
+    _ssd_xla's algebra on its own, from its slot's state (gathered) or,
+    where it continues the row before, from that row's end; a slot's last
+    live row writes its state back."""
+    def row(prev, xs):
+        *ops, s0, fr, ch = xs
+        y, s1 = _ssd_xla(*(v[None] for v in ops),
+                         jnp.where(ch, prev, s0)[None], fr[None])
+        return s1[0], (y[0], s1[0])
+
+    _, (y, ends) = lax.scan(row, jnp.zeros_like(state[0]),
+                            (x, dt, g, bm, cm, state[slots], fresh, chained))
+    return y, state.at[jnp.where(last, slots, state.shape[0])].set(
+        ends, mode="drop")
 
 
 def _ssd_xla(x, dt, g, bm, cm, state, fresh):

@@ -27,7 +27,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.params import fuse_layer_weights
 from ..models.spec import ModelSpec
-from ..models.transformer import KVCache, forward
+from ..models.transformer import KVCache, forward, takes_slot_map
 from ..parallel.mesh import DP_AXIS, SP_AXIS
 from ..parallel.sharding import cache_pspec, check_tp_constraints, shard_params
 from ..sampler import Sampler
@@ -359,8 +359,8 @@ class Engine:
         # whether the rows of the prefill chunk program follow a slot map
         # (slot_prefill_chunk's `slots`; prefill_rows_per_slot says what
         # it takes): decided here, once, from the layer kinds and the mesh
-        self._chunk_slot_map = (not spec.has_state and not spec.is_mla
-                                and self._token_sharding is None)
+        self._chunk_slot_map = takes_slot_map(
+            spec, meshed=self._token_sharding is not None)
         self._identity_map = None   # arange(batch), made at the first chunk
 
         # mesh spanning >1 process (jax.distributed): host code may only
@@ -1529,14 +1529,13 @@ class Engine:
     def prefill_rows_per_slot(self) -> int:
         """Most rows of one chunk program that may be consecutive segments
         of ONE slot (the scheduler chains them through `slots`): `batch`
-        where the program's rows follow a slot map, which takes every
-        layer's cache being the dense K/V cache that `kv_cache_write` and
-        `flash_attention` address by slot (no state layer: a row's state
-        would have to reach the next row inside the program; no latent
-        cache) and rows that are not sharded (no mesh: dp splits the rows,
-        pp and sp trace other regions). Else 1: the chunk program takes no
-        map and row r is slot r. From the layer kinds and the mesh, once,
-        at boot: both step programs of such an engine are what they were."""
+        where the program's rows follow a slot map
+        (models/transformer.takes_slot_map is the rule: no mesh, no latent
+        cache, and every state layer of a kind whose mixer hands a row's
+        final state and tail to the row that continues it: SSM, not DELTA).
+        Else 1: the chunk program takes no map and row r is slot r. From
+        the layer kinds and the mesh, once, at boot: both step programs of
+        an engine that takes no map are what they were."""
         return self.batch if self._chunk_slot_map else 1
 
     def slot_prefill_chunk(self, tokens: np.ndarray, pos: np.ndarray,

@@ -389,22 +389,30 @@ def test_step_programs_keep_no_cache_sized_copy(topo, model, t):
     assert not copies, copies
 
 
-@pytest.mark.parametrize("model", ["mistral_7b", "mixtral_8x7b_12l"])
+@pytest.mark.parametrize("model", ["mistral_7b", "mixtral_8x7b_12l",
+                                   "granite_4_h_small_ep2"])
 def test_the_chunk_with_the_slot_map_holds_its_kernels_and_no_cache_copy(
         topo, served_moe_step, model):
-    """The chunk program of the two configurations whose engines chain a
-    slot's segments (`mistral-7b`: two layers; `mixtral-8x7b-12l` AS SERVED;
-    B=8, S=4096, the Q80 round trip on) takes the slot map as a sixth
-    argument, as `abstract_step` decides with the engine's rule: the chip's
-    compiler accepts `kv_cache_write` and `flash_attention` with the second
-    prefetched scalar, every kernel the configuration lists is in the
-    program, and it holds NO `copy` of a cache leaf (PRs 27 and 30 each
-    found one round an update of a few rows). The map-less chunk, which
-    olmo's, sarvam's and granite's engines keep, compiles beside it."""
+    """The chunk program of the three configurations whose engines chain a
+    slot's segments (`mistral-7b`: two layers; `mixtral-8x7b-12l` AS
+    SERVED; `granite-4.0-h-small-ep2`: one whole period of its layers, nine
+    SSM and one attention, each with its 36 held experts; B=8, S=4096 or
+    8192, the Q80 round trip on) takes the slot map as a sixth argument, as
+    `abstract_step` decides with the engine's rule: the chip's compiler
+    accepts `kv_cache_write` and `flash_attention` with the second
+    prefetched scalar and `ssd_chunk` with its grid head blocks outermost
+    and the state block addressed by slot, every kernel the configuration
+    lists is in the program, and it holds NO `copy` of a cache leaf (PRs 27
+    and 30 each found one round an update of a few rows) nor of a state
+    leaf, as it stands (8, 128, 64, 128) or as the kernel takes it (8,
+    8192, 128): 4 MB a slot a layer that nothing may gather or re-lay. The
+    map-less chunk, which olmo's and sarvam's engines keep, compiles
+    beside it."""
     import rehearse_chip_compile as r
 
     from distributed_llama_tpu.runtime.profiler import kernel_call_sites
 
+    leaves = []
     if model == "mistral_7b":
         spec = dataclasses.replace(r.MISTRAL_7B, n_layers=2)
         fn, args = r.abstract_step(spec, topo.devices, batch=8, t=32,
@@ -417,6 +425,16 @@ def test_the_chunk_with_the_slot_map_holds_its_kernels_and_no_cache_copy(
                                           slot_map=False)
         assert len(bare_args) == 5
         assert _has_kernel(bare.lower(*bare_args).compile())
+    elif model == "granite_4_h_small_ep2":
+        spec = r.hybrid_layers(r.GRANITE_4_H_SMALL_EP2, 1, 10)
+        fn, args = r.abstract_step(spec, topo.devices, batch=8, t=32,
+                                   seq_len=8192, q80=True)
+        lowered = fn.lower(*args)
+        compiled = lowered.compile()
+        kernels = {"ssd_chunk", "q40_matmul", "q40_expert_matmul",
+                   "flash_attention", "kv_cache_write"}
+        assert len(_cache_of(args).s) == 9
+        leaves = [(8, 128, 64, 128), (8, 8192, 128)]
     else:
         spec, args, lowered, compiled = served_moe_step(model, 32)
         kernels = {"q40_matmul", "q40_expert_matmul", "flash_attention",
@@ -424,14 +442,19 @@ def test_the_chunk_with_the_slot_map_holds_its_kernels_and_no_cache_copy(
     assert len(args) == 6 and args[5].shape == (8,)         # the map
     sites = kernel_call_sites(lowered.as_text())
     assert kernels <= set(sites), sites
-    assert not r.cache_shaped_copies(compiled.as_text(),
-                                     _cache_of(args).k[0].shape)
-    # and the models whose engines do not chain get no map
-    for other in (r.OLMO_HYBRID_7B, r.SARVAM_105B_EP8,
-                  r.GRANITE_4_H_SMALL_EP2):
-        assert len(r.abstract_step(
-            dataclasses.replace(other, n_layers=1, mixers=other.mixers[:1]),
-            topo.devices, batch=8, t=32, seq_len=8192)[1]) == 5
+    for shape in [_cache_of(args).k[0].shape] + leaves:
+        assert not r.cache_shaped_copies(compiled.as_text(), shape)
+    # who gets a map is the kinds' and the mesh's: not a delta-rule state,
+    # not the latent cache, not sharded rows
+    def one(s):
+        return dataclasses.replace(s, n_layers=1, mixers=s.mixers[:1])
+
+    for other, tp, n_args in ((r.OLMO_HYBRID_7B, 1, 5),
+                              (r.SARVAM_105B_EP8, 1, 5),
+                              (r.GRANITE_4_H_SMALL_EP2, 1, 6),
+                              (r.MISTRAL_7B, 4, 5)):
+        assert len(r.abstract_step(one(other), topo.devices, tp=tp, batch=8,
+                                   t=32, seq_len=4096)[1]) == n_args
 
 
 def test_served_mixtral_prefill_chunk_fits_scoped_vmem(served_moe_step):
@@ -482,7 +505,7 @@ def test_served_sarvam_mla_step_programs_hold_their_kernels(served_moe_step,
     from distributed_llama_tpu.runtime.profiler import kernel_call_sites
 
     _, args, lowered, compiled = served_moe_step("sarvam_105b_ep8", t)
-    cache = args[-1]
+    cache = _cache_of(args)
     assert cache.v == () and cache.k[0].shape == (8, 1, 8192, 576)
     sites = kernel_call_sites(lowered.as_text())
     assert sites.get("mla_attention", 0) >= 1, sites
@@ -507,7 +530,7 @@ def test_served_olmo_hybrid_step_programs_hold_their_kernels(topo, t):
     spec = r.hybrid_layers(r.OLMO_HYBRID_7B, 1)
     fn, args = r.abstract_step(spec, topo.devices, batch=8, t=t,
                                seq_len=8192, q80=True)
-    cache = args[-1]
+    cache = _cache_of(args)
     assert (len(cache.k), len(cache.v), len(cache.s), len(cache.conv)) == (
         1, 1, 3, 3)
     assert cache.k[0].shape == (8, 30, 8192, 128)
@@ -563,7 +586,7 @@ def test_granite_hybrid_steps_compile_at_published_widths(topo, t):
                                mixers=(3, 0))
     fn, args = r.abstract_step(spec, topo.devices, batch=8, t=t,
                                seq_len=8192, q80=True)
-    cache = args[-1]
+    cache = _cache_of(args)
     assert (len(cache.k), len(cache.v), len(cache.s), len(cache.conv)) == (
         1, 1, 1, 1)
     assert cache.k[0].shape == (8, 8, 8192, 128)

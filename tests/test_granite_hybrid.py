@@ -118,15 +118,22 @@ def test_cache_holds_one_leaf_set_a_layer_kind(tiny):
     assert spec.state_bytes_per_slot(4) == 4 * (8 * 16 * 32 * 4 + 3 * 192 * 4)
 
 
-@pytest.mark.parametrize("chunk", [8, 16, 32])
-def test_chunk_widths_agree(tiny, chunk):
+@pytest.mark.parametrize("chunk,n_prompt,kernels", [
+    (8, 50, False), (16, 50, False), (32, 50, False),
+    (2, 11, True), (1, 6, True)],     # the SLO ladder's last rungs
+    ids=["8", "16", "32", "2-interpret", "1-interpret"])
+def test_chunk_widths_agree(tiny, chunk, n_prompt, kernels):
     """50 prompt tokens in chunks of 8, 16 or 32 (each with a padded tail),
     then 3 decode steps: the reference's logits whatever the width, so the
-    served segment is no parameter of the function computed."""
+    served segment is no parameter of the function computed. Chunks of 2
+    and of 1, narrower than the convolution's tail and than anything
+    `ssd_chunk` takes, run the mapped twin under the identity map, with
+    the other kernels on."""
     _, spec, params, tokens, want = tiny
-    got = slot_run(engine(spec, params), tokens[:53], 50, chunk, row=0)
+    got = slot_run(engine(spec, params, kernels=kernels),
+                   tokens[:n_prompt + 3], n_prompt, chunk, row=0)
     for at, lg in got.items():
-        assert rel_l2(lg, want[at]) < 1e-4, (chunk, at)
+        assert rel_l2(lg, want[at]) < (2e-4 if kernels else 1e-4), (chunk, at)
 
 
 def test_a_whole_segment_runs_as_chunks_of_32(tiny):
@@ -265,6 +272,166 @@ def test_the_twin_takes_groups_and_long_segments():
     np.testing.assert_allclose(np.asarray(y)[1, :33], want_y[1, :33],
                                rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(np.asarray(s), want_s, rtol=1e-4, atol=1e-5)
+
+
+def _bits(x):
+    return np.asarray(x).view(np.uint32)
+
+
+@pytest.mark.parametrize("kernels", [False, True],
+                         ids=["xla", "pallas-interpret"])
+@pytest.mark.parametrize("heads", [4, 16],
+                         ids=["one-head-block", "two-head-blocks"])
+@pytest.mark.parametrize("k,fresh", [(2, True), (5, False), (8, True)])
+def test_chained_rows_of_a_slot_are_the_same_segments_one_a_program(
+        k, fresh, heads, kernels):
+    """`ssd_scan` under a slot map: rows 0..k-1 are consecutive segments of
+    slot 3 (the last a tail of 20 tokens, the first fresh or not), the rows
+    left over gated and naming the other slots (`chain_map`). Each row
+    starts from the final state of the row before it INSIDE the call, and
+    the outputs, slot 3's final state and every other slot's state are BIT
+    for bit those of the same k segments one a call (float32 handed over is
+    the same float32 written and read back); and the recurrence's, token by
+    token, within rounding. With one head block a row (4 heads: no whole
+    block of 8, so all heads at once) and with two."""
+    from distributed_llama_tpu.ops.pallas_ssd import chained_rows
+    from distributed_llama_tpu.runtime.scheduler import chain_map
+
+    b, t, slot = 8, 32, 3
+    x, dt, a, bm, cm, state = _scan_inputs(np.random.default_rng(k), b, t,
+                                           h=heads)
+    nv = np.asarray([t] * (k - 1) + [20] + [0] * (b - k), np.int32)
+    fr = np.asarray([fresh] + [False] * (b - 1))
+
+    def scan(rows, slots, n_valid, fr, state):
+        slots, n_valid = jnp.asarray(slots), jnp.asarray(n_valid, jnp.int32)
+        return ssd_scan(x[rows], dt[rows], a, bm[rows], cm[rows], state,
+                        n_valid, jnp.asarray(fr), slots,
+                        chained_rows(slots, n_valid), use_pallas=kernels,
+                        interpret=kernels)
+
+    y, s = scan(np.arange(b), chain_map(slot, k, b), nv, fr, state)
+    s_seq = state
+    for i in range(k):      # segment i alone, in row 0 of a call of its own
+        rows = np.asarray([i] + [r for r in range(b) if r != i])
+        y_i, s_seq = scan(rows, chain_map(slot, 1, b),
+                          [nv[i]] + [0] * (b - 1),
+                          [bool(fr[i])] + [False] * (b - 1), s_seq)
+        np.testing.assert_array_equal(_bits(y[i, :nv[i]]),
+                                      _bits(y_i[0, :nv[i]]))
+    np.testing.assert_array_equal(_bits(s), _bits(s_seq))
+    others = np.asarray([i for i in range(b) if i != slot])
+    np.testing.assert_array_equal(_bits(s)[others], _bits(state)[others])
+    assert not np.asarray(y[k:]).any()
+    # and it is the recurrence over the slot's tokens in their order
+    n_tok = int(nv.sum())
+    flat = lambda v: v[:k].reshape(1, k * t, *v.shape[2:])[:, :n_tok]  # noqa: E731
+    want_y, want_s = _token_by_token(
+        flat(x), flat(dt), a, flat(bm), flat(cm), state[slot:slot + 1],
+        [n_tok], [fresh])
+    np.testing.assert_allclose(np.asarray(flat(y)), want_y, rtol=1e-4,
+                               atol=1e-4)
+    np.testing.assert_allclose(np.asarray(s[slot]), want_s[0], rtol=1e-4,
+                               atol=1e-5)
+
+
+def test_live_slots_side_by_side_keep_their_own_states():
+    """The identity map, as several slots that prefill pass it: three live
+    rows of three slots and gated rows between them advance their own
+    states and nobody else's, as the map-less call does: the kernel's body
+    is the same, so bit for bit; the twin runs a row at a time where it ran
+    them side by side, so within float32's rounding, the gated rows' states
+    to the bit."""
+    from distributed_llama_tpu.ops.pallas_ssd import chained_rows
+
+    args = _scan_inputs(np.random.default_rng(9), 6, 32, h=16)
+    nv = jnp.asarray([0, 32, 7, 0, 32, 0], jnp.int32)
+    fr = jnp.asarray([False, True, False, False, False, False])
+    slots = jnp.arange(6, dtype=jnp.int32)
+    for kernels in (False, True):
+        want = ssd_scan(*args, nv, fr, use_pallas=kernels, interpret=kernels)
+        got = ssd_scan(*args, nv, fr, slots, chained_rows(slots, nv),
+                       use_pallas=kernels, interpret=kernels)
+        for w, g in zip(want, got):
+            if kernels:
+                np.testing.assert_array_equal(_bits(w), _bits(g))
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       rtol=1e-5, atol=1e-6)
+        gated = np.asarray(nv) == 0
+        np.testing.assert_array_equal(_bits(got[1])[gated],
+                                      _bits(args[5])[gated])
+
+
+def _todays_short_conv(xin, tail, lw, rows, taps):
+    """`_short_conv` as it stood before a slot map could reach it."""
+    import jax
+    from jax import lax
+
+    t = xin.shape[1]
+    tail = jnp.where(rows.fresh[:, None, None], 0, tail)
+    xcat = jnp.concatenate([tail.astype(xin.dtype), xin], axis=1)
+    conv_w = lw["conv_w"]
+    y = sum(conv_w[j] * xcat[:, j:j + t].astype(jnp.float32)
+            for j in range(taps))
+    if "conv_b" in lw:
+        y = y + lw["conv_b"]
+    y = jax.nn.silu(y)
+    tail = jax.vmap(
+        lambda xc, n: lax.dynamic_slice_in_dim(xc, n, taps - 1, 0))(
+            xcat, rows.n_valid).astype(tail.dtype)
+    return y, tail
+
+
+@pytest.mark.parametrize("t", [32, 2], ids=["chunk32", "chunk2"])
+def test_the_convolutions_tail_chains_and_the_mapless_trace_is_todays(t):
+    """`_short_conv` under a slot map: a chained row's tail is what the row
+    before it leaves (its last taps - 1 inputs; at a chunk of 2 tokens,
+    shorter than the tail, the last of [its tail ; its inputs]), the output
+    and the slot's new tail are bit for bit those of the same segments one
+    a call, the last live row alone writes the leaf, and no other slot's
+    tail moves. Without a map the function traces to what it traced before
+    the map existed (olmo's layers and every decode step)."""
+    import jax
+
+    import distributed_llama_tpu.models.transformer as tr
+    from distributed_llama_tpu.ops.pallas_ssd import chained_rows, last_rows
+    from distributed_llama_tpu.runtime.scheduler import chain_map
+
+    rng = np.random.default_rng(t)
+    b, k, ch, taps, slot = 8, 5, 24, 4, 6
+    xin = jnp.asarray(rng.standard_normal((b, t, ch)), F32)
+    conv = jnp.asarray(rng.standard_normal((b, taps - 1, ch)), F32)
+    lw = {"conv_w": jnp.asarray(rng.uniform(-.5, .5, (taps, ch)), F32),
+          "conv_b": jnp.asarray(0.1 * rng.standard_normal(ch), F32)}
+
+    def rows_of(slots, nv, fresh=False):
+        slots, nv = jnp.asarray(slots), jnp.asarray(nv, jnp.int32)
+        chained = chained_rows(slots, nv)
+        return tr.SegmentRows(nv, jnp.asarray([fresh] + [False] * (b - 1)),
+                              slots, chained, last_rows(chained, nv))
+
+    nv = [t] * (k - 1) + [max(t - 1, 1)] + [0] * (b - k)
+    y, leaf = tr._short_conv(xin, conv, lw, rows_of(chain_map(slot, k, b),
+                                                    nv), taps)
+    seq = conv
+    for i in range(k):
+        order = np.asarray([i] + [r for r in range(b) if r != i])
+        y_i, seq = tr._short_conv(
+            xin[order], seq, lw,
+            rows_of(chain_map(slot, 1, b), [nv[i]] + [0] * (b - 1)), taps)
+        np.testing.assert_array_equal(_bits(y[i, :nv[i]]),
+                                      _bits(y_i[0, :nv[i]]))
+    np.testing.assert_array_equal(_bits(leaf), _bits(seq))
+    keep = np.asarray([i for i in range(b) if i != slot])
+    np.testing.assert_array_equal(_bits(leaf)[keep], _bits(conv)[keep])
+    assert not np.array_equal(_bits(leaf)[slot], _bits(conv)[slot])
+
+    plain = tr.SegmentRows(jnp.asarray(nv, jnp.int32),
+                           jnp.asarray([True] + [False] * (b - 1)))
+    assert str(jax.make_jaxpr(
+        lambda x, c: tr._short_conv(x, c, lw, plain, taps))(xin, conv)) == str(
+        jax.make_jaxpr(lambda x, c: _todays_short_conv(
+            x, c, lw, plain, taps))(xin, conv))
 
 
 @pytest.mark.parametrize("t", [1, 8])
@@ -522,3 +689,44 @@ def test_the_checks_controls_break_what_they_name(tiny, name, least):
     assert (ssd.ssd_scan, tr._segment_rows, jax.lax.top_k) == before
     worst = max(rel_l2(lg, want[at]) for at, lg in got.items())
     assert worst < 1e-4 if least is None else worst > least, worst
+
+
+def test_the_chip_side_proof_of_chaining_runs_at_tiny_size(tmp_path, capsys):
+    """`tools/granite_hybrid_controls.py --chained`, what the chip runs at
+    full depth before any cell is timed, at the bench test's tiny
+    configuration on the CPU (B=4, chunks of 8: 32 tokens are one 4-row
+    program, 30 end on a tail, 70 are 3 + 3 + 3 against 9): every leaf and
+    both logits bit-equal, the other slots' noise untouched, and a verdict
+    that fails where one leaf differs."""
+    import json
+
+    import granite_hybrid_controls as tool
+    from test_granite_hybrid_bench import TINY
+
+    cfg = tmp_path / "tiny-granite-test.json"
+    cfg.write_text(json.dumps(TINY))
+    argv = ["--chained", "--config", str(cfg), "--cache", str(tmp_path),
+            "--lengths", "32", "30", "70", "--slot", "2",
+            "--out", str(tmp_path / "out" / "bits.json"),
+            "--engine-flags", "--compute-dtype", "f32", "--cache-dtype",
+            "f32", "--buffer-float-type", "f32"]
+    assert tool.chained_against_one_a_program(argv) == 0
+    verdict = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert verdict == json.loads((tmp_path / "out" / "bits.json").read_text())
+    assert verdict["bit_equal"] and sorted(verdict["lengths"]) == [
+        "30", "32", "70"]
+    assert verdict["lengths"]["70"]["programs"] == [3, 9]
+    for v in verdict["lengths"].values():
+        # 2 K + 2 V + 4 states + 4 tails
+        assert v["leaves_compared"] == 12 and v["state_norm"] > 0
+        assert not v["differ"] and not v["other_slots_moved"]
+    # the comparison can fail: with the state zeroed at every program's
+    # start, three programs and nine do not leave the same bits
+    import distributed_llama_tpu.models.transformer as tr
+
+    with tool.swapped(tr, "_segment_rows",
+                      tool.rows_zeroed(tr._segment_rows)):
+        assert tool.chained_against_one_a_program(
+            argv[:6] + ["70"] + argv[9:11] + argv[13:]) == 1
+    broken = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert {"logits", "s[0]"} <= set(broken["lengths"]["70"]["differ"])

@@ -253,18 +253,24 @@ class Unchained(Engine):
 
 
 def _wide(arch=ArchType.LLAMA):
-    moe = dict(n_experts=4, n_active_experts=2) if arch == ArchType.MIXTRAL \
-        else {}
-    spec = ModelSpec(arch=arch, dim=64, hidden_dim=128, n_layers=2,
-                     n_heads=4, n_kv_heads=2, vocab_size=128, seq_len=LONG,
-                     hidden_act=HiddenAct.SILU, **moe)
+    if arch == ArchType.GRANITE_HYBRID:     # SSM x 2, attention, twice over
+        from distributed_llama_tpu.testing import tiny_granite_spec
+
+        spec = tiny_granite_spec(seq_len=LONG, vocab_size=128)
+    else:
+        moe = dict(n_experts=4, n_active_experts=2) \
+            if arch == ArchType.MIXTRAL else {}
+        spec = ModelSpec(arch=arch, dim=64, hidden_dim=128, n_layers=2,
+                         n_heads=4, n_kv_heads=2, vocab_size=128,
+                         seq_len=LONG, hidden_act=HiddenAct.SILU, **moe)
     host = random_tensors(spec, seed=5, scale=0.05)
     return spec, load_params(spec, host, mode="dense", dtype=jnp.float32)
 
 
 @pytest.fixture(scope="module")
 def wide():
-    return {a: _wide(a) for a in (ArchType.LLAMA, ArchType.MIXTRAL)}
+    return {a: _wide(a) for a in (ArchType.LLAMA, ArchType.MIXTRAL,
+                                  ArchType.GRANITE_HYBRID)}
 
 
 def _prompt(n, seed=0):
@@ -386,15 +392,17 @@ def test_prefill_packing(wide, case):
     sched.close()
 
 
-def _serve(engine, spec, params, prompt, *, shared=0, n_out=4):
+def _serve(engine, spec, params, prompt, *, shared=0, n_out=4, kernels=False):
     """Serve `prompt` greedily on slot 0 of a fresh engine (after a request
     that publishes its first `shared` tokens, where shared > 0): the tokens,
     the logits behind each of them (the first: the last prompt token's), the
-    counters, and the bits of every OTHER slot's cache before and after."""
+    counters, and the bits of every OTHER slot's cache leaves (rows, states
+    and tails) before and after."""
     from distributed_llama_tpu.runtime.prefix_cache import PrefixCache
 
     eng = engine(spec, params, batch=ROWS, compute_dtype=jnp.float32,
-                 cache_dtype=jnp.float32)
+                 cache_dtype=jnp.float32, use_pallas=kernels,
+                 pallas_interpret=kernels)
     pc = (PrefixCache(eng, num_blocks=32, block_len=CHUNK) if shared
           else None)
     sched = Scheduler(eng, chunk=CHUNK, prefix_cache=pc)
@@ -403,13 +411,11 @@ def _serve(engine, spec, params, prompt, *, shared=0, n_out=4):
                              _greedy(spec))
         _run_until_done(sched, [first])
     # the other slots hold something to lose
-    leaves = eng.cache.k + eng.cache.v
-    noise = [jnp.asarray(np.random.default_rng(i).standard_normal(
+    noise = [tuple(jnp.asarray(np.random.default_rng(i).standard_normal(
         x.shape).astype(np.float32)).at[0].set(x[0])
-        for i, x in enumerate(leaves)]
-    n = len(eng.cache.k)
-    eng.cache = eng.cache._replace(k=tuple(noise[:n]), v=tuple(noise[n:]))
-    before = [np.asarray(x[1:]) for x in noise]
+        for i, x in enumerate(leaf)) for leaf in eng.cache]
+    eng.cache = type(eng.cache)(*noise)
+    before = [np.asarray(x[1:]) for leaf in noise for x in leaf]
     logits = []
     view = sched._sample_view
 
@@ -422,7 +428,7 @@ def _serve(engine, spec, params, prompt, *, shared=0, n_out=4):
     req = sched.submit(prompt, n_out, _greedy(spec))
     _run_until_done(sched, [req])
     assert req.stats.n_out == n_out
-    after = [np.asarray(x[1:]) for x in eng.cache.k + eng.cache.v]
+    after = [np.asarray(x[1:]) for leaf in eng.cache for x in leaf]
     s = sched.stats.summary()
     moved = {k: s[k] - base[k] for k in ("prefill_steps", "prefill_rows",
                                          "prefill_segments",
@@ -431,24 +437,36 @@ def _serve(engine, spec, params, prompt, *, shared=0, n_out=4):
     return _drain(req), logits, moved, before, after
 
 
-@pytest.mark.parametrize("n,shared", [
-    (31, 0), (32, 0), (33, 0), (33, 32), (256, 0), (256, 64), (300, 0),
-    (300, 64), (700, 0), (700, 64)])     # shared: a prefix hit of as many
-@pytest.mark.parametrize("arch", [ArchType.LLAMA, ArchType.MIXTRAL],
-                         ids=["llama", "mixtral"])
-def test_chained_prefill_is_bit_equal_to_a_segment_an_iteration(wide, arch, n,
-                                                                shared):
+_LENGTHS = [(31, 0), (32, 0), (33, 0), (33, 32), (256, 0), (256, 64),
+            (300, 0), (300, 64), (700, 0), (700, 64)]   # (n, a prefix hit of)
+
+
+@pytest.mark.parametrize("arch,n,shared,kernels", [
+    *((a, n, sh, False) for a in (ArchType.LLAMA, ArchType.MIXTRAL)
+      for n, sh in _LENGTHS),
+    # a state has no prefix arena; 210: seven segments, the last a tail
+    *((ArchType.GRANITE_HYBRID, n, 0, False)
+      for n in (31, 33, 210, 256, 300, 700)),
+    (ArchType.GRANITE_HYBRID, 210, 0, True),
+], ids=lambda v: v.name.lower() if isinstance(v, ArchType) else
+    "interpret" if v is True else "" if v is False else str(v))
+def test_chained_prefill_is_bit_equal_to_a_segment_an_iteration(
+        wide, arch, n, shared, kernels):
     """Every op of the chunk program is independent across its token rows
     but attention over the cache, and a chained row attends exactly what
-    the rows before it wrote: a prompt prefilled up to eight segments a
-    program gives, BIT FOR BIT (float32, CPU), the logits at its last
-    token and after every decode step, and so the tokens, of the same
-    prompt prefilled one segment an iteration; and no other slot's cache
-    changes by a bit."""
+    the rows before it wrote; in a state layer (granite's SSM mixers) it
+    starts from the final state and the convolution's tail of the row it
+    continues. A prompt prefilled up to eight segments a program gives, BIT
+    FOR BIT (float32, CPU; the XLA twins, and for one length the kernels
+    interpreted), the logits at its last token and after every decode
+    step, and so the tokens, of the same prompt prefilled one segment an
+    iteration, with the same `/stats` `prefill_segments`; and no other
+    slot's cache, state or tail changes by a bit."""
     spec, params = wide[arch]
     prompt = _prompt(n, seed=n + shared)
-    got = _serve(Engine, spec, params, prompt, shared=shared)
-    want = _serve(Unchained, spec, params, prompt, shared=shared)
+    got = _serve(Engine, spec, params, prompt, shared=shared, kernels=kernels)
+    want = _serve(Unchained, spec, params, prompt, shared=shared,
+                  kernels=kernels)
     assert got[0] == want[0]
     assert len(got[1]) == len(want[1]) == 4
     for a, b in zip(got[1], want[1]):
@@ -468,16 +486,18 @@ def test_chained_prefill_is_bit_equal_to_a_segment_an_iteration(wide, arch, n,
 
 @pytest.mark.parametrize("model,mesh,rows", [
     ("llama", None, ROWS), ("mixtral", None, ROWS),
-    ("olmo_hybrid", None, 1), ("granite_hybrid", None, 1),
+    ("granite_hybrid", None, ROWS), ("olmo_hybrid", None, 1),
     ("sarvam_mla", None, 1), ("llama", {"dp": 2}, 1), ("llama", {"tp": 2}, 1),
 ], ids=lambda v: "-".join(f"{k}{n}" for k, n in v.items())
     if isinstance(v, dict) else None)
 def test_who_may_chain_is_decided_from_the_model_and_the_mesh(model, mesh,
                                                               rows):
-    """`Engine.prefill_rows_per_slot`: the whole batch where every layer's
-    cache is the dense K/V cache and the rows are not sharded; 1 for a model
-    with a state layer (delta rule, SSM) or the latent cache and on any
-    mesh, whose chunk program takes no map at all."""
+    """`Engine.prefill_rows_per_slot`: the whole batch where the rows are
+    not sharded, every cache leaf is the dense K/V cache and every state
+    layer is of a kind whose mixer chains (SSM: granite); 1 for a model
+    with a delta-rule layer or the latent cache and on any mesh, whose
+    chunk program takes no map at all
+    (`models/transformer.takes_slot_map`)."""
     from distributed_llama_tpu import testing
     from distributed_llama_tpu.parallel.mesh import make_mesh
 

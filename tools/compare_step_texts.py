@@ -5,10 +5,10 @@ same on two trees? For a DESCRIBED v5e (no chip attached, nothing runs):
     JAX_PLATFORMS=cpu python tools/compare_step_texts.py diff <dir_a> <dir_b>
 
 `write` compiles, from the checkout at <tree>, both slot step programs of
-the five benchmark configurations (published widths, depth cut to a few
-layers or one period, B=8, the Q80 round trip on; the chunk WITHOUT a slot
-map, which every tree has) and keeps each program's text with its
-`metadata={...}` taken out. One process a tree: a process imports one
+the five benchmark configurations AS THAT TREE'S ENGINE SERVES THEM
+(published widths, depth cut to a few layers or one period, B=8, the Q80
+round trip on; the chunk with a slot map where the tree's own rule gives
+it one) and keeps each program's text with its `metadata={...}` taken out. One process a tree: a process imports one
 `distributed_llama_tpu`. `diff` compares two such directories program by
 program: the text past its tables of source locations with every Pallas
 kernel's serialized body taken out, and the bodies themselves, decoded and
@@ -46,15 +46,11 @@ def write(tree: str, out: str) -> None:
         "olmo-hybrid-7b": (r.hybrid_layers(r.OLMO_HYBRID_7B, 1), 8192),
         "granite-4.0-h-small-ep2": (
             r.hybrid_layers(r.GRANITE_4_H_SMALL_EP2, 1, 10), 8192)}
-    # a tree from before the slot map has no such argument, and no map
-    no_map = ({"slot_map": False} if "slot_map" in
-              r.abstract_step.__code__.co_varnames else {})
     os.makedirs(out, exist_ok=True)
     for name, (spec, seq_len) in configs.items():
         for t in (1, 32):
             fn, args = r.abstract_step(spec, devices, batch=8, t=t,
-                                       seq_len=seq_len, q80=True,
-                                       **(no_map if t > 1 else {}))
+                                       seq_len=seq_len, q80=True)
             text = fn.lower(*args).compile().as_text()
             text = re.sub(r",? ?metadata=\{[^}]*\}", "", text)
             key = f"{name}.{'decode' if t == 1 else 'chunk32'}"

@@ -85,20 +85,37 @@ def controls(cfg: dict) -> dict:
 
 def rows_zeroed(rows):
     """_segment_rows whose every chunk program starts its rows from zeros."""
-    import distributed_llama_tpu.models.transformer as tr
-
-    def zeroed(spec, cache, pos0, b, t, li, lfa):
-        r = rows(spec, cache, pos0, b, t, li, lfa)
-        return tr.SegmentRows(r.n_valid,
-                              (r.n_valid > 0) if t > 1 else r.fresh)
+    def zeroed(spec, cache, pos0, b, t, *rest):
+        r = rows(spec, cache, pos0, b, t, *rest)
+        return r._replace(fresh=(r.n_valid > 0) if t > 1 else r.fresh)
     return zeroed
 
 
 def rows_pad(rows):
     """_segment_rows whose tail chunk's pad tokens advance the state."""
-    def pad(spec, cache, pos0, b, t, li, lfa):
-        return rows(spec, cache, pos0, b, t, None, lfa)
+    def pad(spec, cache, pos0, b, t, li, *rest):
+        return rows(spec, cache, pos0, b, t, None, *rest)
     return pad
+
+
+def config_and_files(config: str, model=None, tokenizer=None, cache=None):
+    """(the configuration of the file `config`, its model file, its
+    tokenizer): the files given, else the benchmark's own under `cache`
+    (benchmark/.cache), written with the benchmark's draw where missing."""
+    with open(config) as f:
+        cfg = json.load(f)
+    cfg.setdefault("name", os.path.basename(config)[:-5])
+    if not model:
+        import children
+
+        d = os.path.join(cache or os.path.join(BENCH, ".cache"),
+                         f"{cfg['name']}-{cfg['weights_seed']}")
+        os.makedirs(d, exist_ok=True)
+        model, tokenizer = d + "/model.m", d + "/tok.t"
+        if not os.path.exists(model):
+            print(children.synth({"config": cfg, "model": model,
+                                  "tokenizer": tokenizer}), flush=True)
+    return cfg, model, tokenizer
 
 
 def run(doc: str, config: str, controls, argv=None) -> int:
@@ -114,25 +131,15 @@ def run(doc: str, config: str, controls, argv=None) -> int:
                          "run's own check uses 1)")
     ap.add_argument("--out")
     args = ap.parse_args(argv)
-    with open(args.config) as f:
-        cfg = json.load(f)
-    cfg.setdefault("name", os.path.basename(args.config)[:-5])
-
-    import children
-    if not args.model:
-        d = os.path.join(BENCH, ".cache",
-                         f"{cfg['name']}-{cfg['weights_seed']}")
-        os.makedirs(d, exist_ok=True)
-        args.model, args.tokenizer = d + "/model.m", d + "/tok.t"
-        if not os.path.exists(args.model):
-            print(children.synth({"config": cfg, "model": args.model,
-                                  "tokenizer": args.tokenizer}), flush=True)
+    cfg, args.model, args.tokenizer = config_and_files(
+        args.config, args.model, args.tokenizer)
 
     import importlib
 
     import jax
     import jax.numpy as jnp
 
+    import children
     import distributed_llama_tpu.apps.dllama as cli
     ref = importlib.import_module(cfg["reference"][:-3].replace("/", "."))
     forward, build, memo = ref.forward, cli.build_engine, {}

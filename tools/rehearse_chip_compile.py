@@ -204,7 +204,8 @@ def abstract_step(spec: ModelSpec, devices, *, tp: int = 1, batch: int,
     `slots`); None decides as `Engine.__init__` does, from the layer kinds
     and the mesh."""
     from distributed_llama_tpu.models.params import fuse_layer_weights
-    from distributed_llama_tpu.models.transformer import KVCache, forward
+    from distributed_llama_tpu.models.transformer import (KVCache, forward,
+                                                          takes_slot_map)
     from distributed_llama_tpu.ops.sharded_vocab import vocab_shard_axes
     from distributed_llama_tpu.parallel.mesh import make_mesh
     from distributed_llama_tpu.parallel.sharding import (
@@ -258,7 +259,7 @@ def abstract_step(spec: ModelSpec, devices, *, tp: int = 1, batch: int,
                 (params, tokens, pos, cache))
 
     if slot_map is None:    # Engine._chunk_slot_map
-        slot_map = not spec.has_state and not spec.is_mla and tp == 1
+        slot_map = takes_slot_map(spec, meshed=tp > 1)
 
     def slot_prefill_chunk(params, tokens, pos0, logit_index, cache, *slots):
         return forward(params, spec, tokens, pos0, cache,
