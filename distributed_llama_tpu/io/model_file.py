@@ -91,6 +91,10 @@ _SSM_KEYS = {
 }
 _SSM_SHARED_KEYS = ("n_shared_experts", "n_routed_experts", "expert_offset",
                     "rms_eps")
+# KIMI_LINEAR's header: SARVAM_MLA's keys (the latent layers, the dense
+# lead, the held share), OLMO_HYBRID's (the DELTA layer's sizes), the width
+# of a head's decay, and the layer kinds as data.
+_KDA_KEYS = {"lin_decay_dim": 46}
 _FLOAT_KEYS = _MLA_FLOAT_KEYS | frozenset((
     "embedding_scale", "residual_scale", "attn_scale", "logit_scale"))
 _MIXER_KEY0 = 1000
@@ -137,13 +141,14 @@ def model_tensor_plan(spec: ModelSpec) -> Iterator[tuple[str, tuple[int, ...], F
     Shapes are (d, n) = (out_dim, in_dim) for matmul weights.
     """
     wt = spec.weights_float_type
+    kinds = set(spec.layer_kinds)
     if spec.is_mla:
-        yield from _mla_tensor_plan(spec)
+        yield from _latent_tensor_plan(spec)
         return
-    if spec.arch == ArchType.OLMO_HYBRID:
+    if LayerKind.DELTA in kinds:
         yield from _hybrid_tensor_plan(spec)
         return
-    if spec.arch == ArchType.GRANITE_HYBRID:
+    if LayerKind.SSM in kinds:
         yield from _granite_tensor_plan(spec)
         return
     yield "tok_emb", (spec.vocab_size, spec.dim), FloatType.F32
@@ -172,43 +177,85 @@ def model_tensor_plan(spec: ModelSpec) -> Iterator[tuple[str, tuple[int, ...], F
     yield "wcls", (spec.vocab_size, spec.dim), wt
 
 
-def _mla_tensor_plan(spec: ModelSpec):
-    """SARVAM_MLA's file order. Per layer: wq (H x (d_n + d_r) rows), wkva
-    (the latent's r rows, then the rope key's d_r), wkvb (per head d_n key
-    rows then d_v value rows, over the latent), wo (over H x d_v); then w1
-    w2 w3 of the dense width (the leading n_dense_layers) or moe_router
-    (router_width rows), moe_bias (f32, used for the choice only), the HELD
-    experts' up gate down, and the shared expert's sh_w1 (gate) sh_w2
-    (down) sh_w3 (up) at n_shared_experts x hidden_dim; then rms_att,
-    rms_ffn and rms_kv (the latent's norm), f32."""
-    wt, d, h = spec.weights_float_type, spec.dim, spec.n_heads
-    r, hid = spec.kv_lora_rank, spec.hidden_dim
+def _latent_mixer_plan(spec: ModelSpec, p: str):
+    """A latent-attention mixer's four projections (_latent_tensor_plan's
+    docstring says what their rows are)."""
+    wt, d, h, r = (spec.weights_float_type, spec.dim, spec.n_heads,
+                   spec.kv_lora_rank)
+    yield p + "wq", (h * spec.head_size, d), wt
+    yield p + "wkva", (r + spec.qk_rope_head_dim, d), wt
+    yield p + "wkvb", (h * (spec.qk_nope_head_dim + spec.v_head_dim), r), wt
+    yield p + "wo", (d, h * spec.v_head_dim), wt
+
+
+def _latent_ffn_plan(spec: ModelSpec, l: int, p: str):
+    """What follows the mixer in a layer of SARVAM_MLA's block: the dense
+    FFN or the router, its bias, the held experts and the shared one."""
+    wt, d, hid = spec.weights_float_type, spec.dim, spec.hidden_dim
+    if spec.is_dense_layer(l):
+        yield p + "w1", (spec.dense_hidden_dim, d), wt
+        yield p + "w2", (d, spec.dense_hidden_dim), wt
+        yield p + "w3", (spec.dense_hidden_dim, d), wt
+        return
+    yield p + "moe_router", (spec.router_width, d), wt
+    yield p + "moe_bias", (spec.router_width,), FloatType.F32
+    for e in range(spec.n_experts):
+        yield p + f"experts.{e}.up", (hid, d), wt
+        yield p + f"experts.{e}.gate", (hid, d), wt
+        yield p + f"experts.{e}.down", (d, hid), wt
+    if spec.n_shared_experts:
+        sh = spec.n_shared_experts * hid
+        yield p + "sh_w1", (sh, d), wt
+        yield p + "sh_w2", (d, sh), wt
+        yield p + "sh_w3", (sh, d), wt
+
+
+def _latent_tensor_plan(spec: ModelSpec):
+    """The file order of a model whose attending layers are LATENT
+    (SARVAM_MLA: every layer; KIMI_LINEAR: beside DELTA layers of the KDA
+    mixer). A LATENT layer's mixer: wq (H x (d_n + d_r) rows), wkva (the
+    latent's r rows, then the rope key's d_r), wkvb (per head d_n key rows
+    then d_v value rows, over the latent), wo (over H x d_v). A DELTA (KDA)
+    layer's: wq wk (H x d_k rows), wv (H x d_v), the decay's low-rank pair
+    wf_a (d_k rows over the stream) and wf_b (H x d_k rows over those d_k),
+    wbeta (H rows), the output gate's pair wg_a (d_v rows) and wg_b (H x d_v
+    rows over them), wo (over H x d_v), then f32: conv_w (taps x [q ; k ; v]
+    channels: the three published convolutions side by side, tap j weighs
+    the row `taps - 1 - j` tokens back), a_log (H), dt_bias (H x d_k: a
+    channel) and rms_o (d_v). Both: w1 w2 w3 of the dense width (the leading
+    n_dense_layers) or moe_router (router_width rows), moe_bias (f32, used
+    for the choice only), the HELD experts' up gate down, and the shared
+    expert's sh_w1 (gate) sh_w2 (down) sh_w3 (up) at n_shared_experts x
+    hidden_dim; then rms_att and rms_ffn, and in a LATENT layer rms_kv (the
+    latent's norm), f32."""
+    wt, d = spec.weights_float_type, spec.dim
+    h, dk, dv = spec.lin_heads, spec.lin_k_head_dim, spec.lin_v_head_dim
     yield "tok_emb", (spec.vocab_size, d), FloatType.F32
-    for l in range(spec.n_layers):
+    for l, kind in enumerate(spec.layer_kinds):
         p = f"layers.{l}."
-        yield p + "wq", (h * spec.head_size, d), wt
-        yield p + "wkva", (r + spec.qk_rope_head_dim, d), wt
-        yield p + "wkvb", (h * (spec.qk_nope_head_dim + spec.v_head_dim), r), wt
-        yield p + "wo", (d, h * spec.v_head_dim), wt
-        if spec.is_dense_layer(l):
-            yield p + "w1", (spec.dense_hidden_dim, d), wt
-            yield p + "w2", (d, spec.dense_hidden_dim), wt
-            yield p + "w3", (spec.dense_hidden_dim, d), wt
+        if kind == LayerKind.DELTA:
+            assert spec.lin_vector_decay, "the KDA mixer's tensors"
+            yield p + "wq", (h * dk, d), wt
+            yield p + "wk", (h * dk, d), wt
+            yield p + "wv", (h * dv, d), wt
+            yield p + "wf_a", (dk, d), wt
+            yield p + "wf_b", (h * dk, dk), wt
+            yield p + "wbeta", (h, d), wt
+            yield p + "wg_a", (dv, d), wt
+            yield p + "wg_b", (h * dv, dv), wt
+            yield p + "wo", (d, h * dv), wt
+            yield p + "conv_w", (spec.lin_conv_width,
+                                 spec.lin_conv_dim), FloatType.F32
+            yield p + "a_log", (h,), FloatType.F32
+            yield p + "dt_bias", (h * dk,), FloatType.F32
+            yield p + "rms_o", (dv,), FloatType.F32
         else:
-            yield p + "moe_router", (spec.router_width, d), wt
-            yield p + "moe_bias", (spec.router_width,), FloatType.F32
-            for e in range(spec.n_experts):
-                yield p + f"experts.{e}.up", (hid, d), wt
-                yield p + f"experts.{e}.gate", (hid, d), wt
-                yield p + f"experts.{e}.down", (d, hid), wt
-            if spec.n_shared_experts:
-                sh = spec.n_shared_experts * hid
-                yield p + "sh_w1", (sh, d), wt
-                yield p + "sh_w2", (d, sh), wt
-                yield p + "sh_w3", (sh, d), wt
+            yield from _latent_mixer_plan(spec, p)
+        yield from _latent_ffn_plan(spec, l, p)
         yield p + "rms_att", (d,), FloatType.F32
         yield p + "rms_ffn", (d,), FloatType.F32
-        yield p + "rms_kv", (r,), FloatType.F32
+        if kind == LayerKind.LATENT:
+            yield p + "rms_kv", (spec.kv_lora_rank,), FloatType.F32
     yield "rms_final", (d,), FloatType.F32
     yield "wcls", (spec.vocab_size, d), wt
 
@@ -336,7 +383,7 @@ def read_spec(path: str, weights_float_type: FloatType | None = None) -> ModelSp
             n_kv = len(data) // 8
             inv = {v: k for k, v in
                    {**_KEYS, **_MLA_KEYS, **_HYBRID_KEYS,
-                    **_SSM_KEYS}.items()}
+                    **_SSM_KEYS, **_KDA_KEYS}.items()}
             mixers: dict[int, int] = {}
             for i in range(n_kv):
                 k, v = struct.unpack_from("<ii", data, i * 8)
@@ -379,7 +426,8 @@ def read_spec(path: str, weights_float_type: FloatType | None = None) -> ModelSp
         weights_float_type=wt,
         version=version,
         **{k: (_bits_f32(fields[k]) if k in _FLOAT_KEYS else fields[k])
-           for k in (*_MLA_KEYS, *_HYBRID_KEYS, *_SSM_KEYS, "mixers")
+           for k in (*_MLA_KEYS, *_HYBRID_KEYS, *_SSM_KEYS, *_KDA_KEYS,
+                     "mixers")
            if k in fields},
     )
     spec.validate()
@@ -468,25 +516,30 @@ def write_header(f, spec: ModelSpec) -> None:
     data = b""
     for key, value in params.items():
         data += struct.pack("<ii", _KEYS[key], value)
+    # which groups of keys follow is read off the layers the spec
+    # describes, not off the architecture's name: the next hybrid writes
+    # the groups its kinds need
+    kinds = set(spec.layer_kinds)
+    keys: dict = {}
     if spec.is_mla:
-        for key, k in _MLA_KEYS.items():
-            value = getattr(spec, key)
-            data += struct.pack("<ii", k, _f32_bits(value)
-                                if key in _MLA_FLOAT_KEYS else value)
-    if spec.arch == ArchType.OLMO_HYBRID:
-        data += struct.pack("<ii", _MLA_KEYS["rms_eps"],
-                            _f32_bits(spec.rms_eps))
-        for key, k in _HYBRID_KEYS.items():
-            data += struct.pack("<ii", k, getattr(spec, key))
-    if spec.arch == ArchType.GRANITE_HYBRID:
-        keys = {**{k: _MLA_KEYS[k] for k in _SSM_SHARED_KEYS}, **_SSM_KEYS}
-        for key, k in keys.items():
-            value = getattr(spec, key)
-            data += struct.pack("<ii", k, _f32_bits(value)
-                                if key in _FLOAT_KEYS else value)
-    if spec.arch in (ArchType.OLMO_HYBRID, ArchType.GRANITE_HYBRID):
-        for l, kind in enumerate(spec.layer_kinds):   # the kinds as data
-            data += struct.pack("<ii", _MIXER_KEY0 + l, int(kind))
+        keys.update(_MLA_KEYS)
+    elif spec.mixers and LayerKind.SSM not in kinds:
+        # the norms' eps alone (the latent group above and the SSM group
+        # below hold it at their own place: the files' bytes stay)
+        keys["rms_eps"] = _MLA_KEYS["rms_eps"]
+    if LayerKind.DELTA in kinds:
+        keys.update(_HYBRID_KEYS)
+        if spec.lin_vector_decay:
+            keys.update(_KDA_KEYS)
+    if LayerKind.SSM in kinds:
+        keys.update({k: _MLA_KEYS[k] for k in _SSM_SHARED_KEYS})
+        keys.update(_SSM_KEYS)
+    for key, k in keys.items():
+        value = getattr(spec, key)
+        data += struct.pack("<ii", k, _f32_bits(value)
+                            if key in _FLOAT_KEYS else value)
+    for l, kind in enumerate(spec.mixers and spec.layer_kinds):
+        data += struct.pack("<ii", _MIXER_KEY0 + l, int(kind))  # as data
     f.write(struct.pack("<i", MAGIC_KV))
     f.write(struct.pack("<i", 8 + len(data)))
     f.write(data)

@@ -32,7 +32,7 @@ from ..quants.numpy_codec import quantize_q40
 from ..quants.types import FloatType
 from ..parallel.sharding import COL_SPLIT_NAMES, _pspec_for
 from ..parallel.mesh import EP_AXIS, PP_AXIS, TP_AXIS
-from .spec import ArchType, ModelSpec
+from .spec import ArchType, LayerKind, ModelSpec
 
 _MOE_EP_KEYS = ("moe_up", "moe_gate", "moe_down")
 
@@ -357,10 +357,13 @@ def _fuse_group(key: str) -> str | None:
     return None
 
 
-# thin projections kept as ONE dense leaf of the compute dtype, their file
+# thin projections kept as ONE dense leaf (a pair or a triple of file
+# tensors) of the compute dtype, their file
 # tensors' rows in file order (models/params.load_params): the DELTA
 # layer's decay and beta rows, the SSM layer's B | C and dt rows
-_DENSE_PAIRS = {"wa": "w_ab", "wb": "w_ab", "wbc": "w_bcdt", "wdt": "w_bcdt"}
+_DENSE_GROUPS = {"wa": "w_ab", "wb": "w_ab", "wbc": "w_bcdt", "wdt": "w_bcdt",
+                # a KDA layer's three (models/params.KDA_THIN_ROWS)
+                "wf_a": "w_fgb", "wbeta": "w_fgb", "wg_a": "w_fgb"}
 
 
 def _concat_host(ts: list[HostTensor], mode: str) -> list[HostTensor]:
@@ -468,7 +471,8 @@ def load_params_streamed(
         peak = max(peak, live)
         dest, stage = target(t.name)
         group = _fuse_group(key) if fuse else None
-        if group == "wqkv" and spec.is_mla:
+        if group == "wqkv" and spec.layer_kinds[
+                int(t.name.split(".")[1])] == LayerKind.LATENT:
             group = None  # wq stands alone: no wk/wv to fuse it with
 
         if group is not None:
@@ -498,11 +502,11 @@ def load_params_streamed(
                 live -= sum(_host_bytes(x) for x in ts)
             continue
 
-        if key in _DENSE_PAIRS:
-            leaf = _DENSE_PAIRS[key]
+        if key in _DENSE_GROUPS:
+            leaf = _DENSE_GROUPS[key]
             gk = f"{t.name.rsplit('.', 1)[0]}.{leaf}"
             pending.setdefault(gk, []).append(t)
-            if len(pending[gk]) == 2:
+            if len(pending[gk]) == list(_DENSE_GROUPS.values()).count(leaf):
                 ts = pending.pop(gk)
                 arr = placer.dense(leaf, np.concatenate(
                     [x.to_f32() for x in ts]))
@@ -516,7 +520,7 @@ def load_params_streamed(
             # dense per-head operands (models/params.split_wkvb)
             from .params import split_wkvb
 
-            assert stage is None, "SARVAM_MLA does not support --pp"
+            assert stage is None, "the latent cache does not support --pp"
             for name, half in zip(("w_uk", "w_uv"),
                                   split_wkvb(spec, t.to_f32())):
                 arr = placer.dense(name, half)
@@ -529,7 +533,7 @@ def load_params_streamed(
                              keep_f32=True)
             else:
                 dest[key] = placer.dense(key, t.to_f32())  # norms stay f32
-        elif key in ("tok_emb", "moe_router"):
+        elif key in ("tok_emb", "moe_router", "wf_b", "wg_b"):
             if stage is not None:  # moe_router is a per-layer dense leaf
                 pp_stack.add(dest, key, stage, "dense", dtype, [t])
             else:

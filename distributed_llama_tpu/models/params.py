@@ -34,6 +34,11 @@ from ..quants.types import FloatType
 from .spec import ArchType, LayerKind, ModelSpec
 
 
+# a KDA layer's thin projections of the stream, in the (file) order of their
+# one dense leaf `w_fgb`: the decay's first half, the step's rows, the gate's
+KDA_THIN_ROWS = ("wf_a", "wbeta", "wg_a")
+
+
 def _to_q40_host(x: np.ndarray) -> HostTensor:
     scales, packed = quantize_q40(x.reshape(-1, x.shape[-1]))
     t = HostTensor("", FloatType.Q40, x.shape, scales=scales, packed=packed)
@@ -99,12 +104,21 @@ def load_params(
             lw["rms_moe"] = dev(f"layers.{l}.rms_moe", tensors[f"layers.{l}.rms_moe"].to_f32())
             lw["rms_ffn2"] = dev(f"layers.{l}.rms_ffn2", tensors[f"layers.{l}.rms_ffn2"].to_f32())
         if spec.layer_kinds[l] == LayerKind.DELTA:
-            for w in ("wq", "wk", "wv", "wg", "wo"):
+            kda = spec.lin_vector_decay
+            for w in ("wq", "wk", "wv", "wo") + (() if kda else ("wg",)):
                 lw[w] = weight(tensors[f"layers.{l}.{w}"], f"layers.{l}.{w}")
-            # decay and beta rows: two thin projections, dense on the device
-            lw["w_ab"] = dev(f"layers.{l}.w_ab", np.concatenate(
+            # the thin projections, dense on the device, ONE leaf: decay
+            # and beta rows; for KDA the decay's and the gate's first
+            # halves with the step's rows, and their second halves (d_k
+            # columns: four Q40 blocks a row) a leaf each
+            thin, rows = (("w_fgb", KDA_THIN_ROWS) if kda
+                          else ("w_ab", ("wa", "wb")))
+            lw[thin] = dev(f"layers.{l}.{thin}", np.concatenate(
                 [tensors[f"layers.{l}.{w}"].to_f32()
-                 for w in ("wa", "wb")]).astype(dtype))
+                 for w in rows]).astype(dtype))
+            for w in ("wf_b", "wg_b") if kda else ():
+                lw[w] = dev(f"layers.{l}.{w}",
+                            tensors[f"layers.{l}.{w}"].to_f32().astype(dtype))
             for w in ("conv_w", "a_log", "dt_bias", "rms_o"):
                 lw[w] = dev(f"layers.{l}.{w}",
                             tensors[f"layers.{l}.{w}"].to_f32())
@@ -120,7 +134,7 @@ def load_params(
                 if f"layers.{l}.{w}" in tensors:    # conv_b: with a bias
                     lw[w] = dev(f"layers.{l}.{w}",
                                 tensors[f"layers.{l}.{w}"].to_f32())
-        elif spec.is_mla:
+        elif spec.layer_kinds[l] == LayerKind.LATENT:
             lw["rms_kv"] = dev(f"layers.{l}.rms_kv",
                                tensors[f"layers.{l}.rms_kv"].to_f32())
             for w in ("wq", "wkva", "wo"):

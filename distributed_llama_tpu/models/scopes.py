@@ -26,6 +26,7 @@ DEVICE_SCOPES = (
     "moe_shared",   # the shared expert (a dense ffn nests under it)
     "gdn_proj", "gdn_conv", "gdn_rule", "gdn_out",    # gated delta rule
     "ssm_proj", "ssm_conv", "ssm_scan", "ssm_out",    # Mamba-2 mixer
+    "kda_proj", "kda_conv", "kda_rule", "kda_out",    # Kimi Delta Attention
     "block_tail",   # residual adds and output norms of no sublayer
     "head",         # final norm, row pick, wcls, logit scales
     "act_q80",      # the Q80 round trip of a matmul's input (ops/matmul),

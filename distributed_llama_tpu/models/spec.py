@@ -33,6 +33,12 @@ class ArchType(enum.IntEnum):
     # under a pre-norm block, four published multipliers (embedding,
     # residual, attention, logits); not a reference-engine architecture
     GRANITE_HYBRID = 0xABCD05
+    # Kimi Delta Attention layers (the gated delta rule with a decay that is
+    # a VECTOR over a head's key channels, low-rank decay and gate
+    # projections) beside latent-attention layers WITHOUT positions, a
+    # leading dense layer, then sigmoid-bias-routed experts and a shared
+    # expert in every layer; not a reference-engine architecture
+    KIMI_LINEAR = 0xABCD06
 
 
 class LayerKind(enum.IntEnum):
@@ -124,6 +130,10 @@ class ModelSpec:
     lin_v_head_dim: int = 0        # d_v: v, gate and output of a head
     lin_conv_width: int = 0        # taps of the causal depthwise convolution
     lin_beta_scale: int = 1        # 2: beta in (0, 2), negative eigenvalues
+    lin_decay_dim: int = 1         # channels of a head's decay: 1, a scalar
+    #                                a token, or d_k, one a key channel (the
+    #                                KDA mixer, with its low-rank decay and
+    #                                gate projections); KIMI_LINEAR's header
     # -- GRANITE_HYBRID only (header keys of their own) --------------------
     ssm_heads: int = 0             # H: heads of an SSM layer
     ssm_head_dim: int = 0          # P: d_inner = H x P
@@ -144,7 +154,8 @@ class ModelSpec:
         architecture's one kind everywhere else."""
         if self.mixers:
             return tuple(LayerKind(m) for m in self.mixers)
-        kind = LayerKind.LATENT if self.is_mla else LayerKind.ATTENTION
+        kind = (LayerKind.LATENT if self.arch == ArchType.SARVAM_MLA
+                else LayerKind.ATTENTION)
         return (kind,) * self.n_layers
 
     @property
@@ -240,7 +251,17 @@ class ModelSpec:
 
     @property
     def is_mla(self) -> bool:
-        return self.arch == ArchType.SARVAM_MLA
+        """The layers that attend keep ONE latent row a token (heads of
+        d_n + d_r, a cache row of r + d_r, no V leaf); a state layer may
+        stand beside them. Read off the layers' kinds, not the
+        architecture's name."""
+        return LayerKind.LATENT in self.layer_kinds
+
+    @property
+    def lin_vector_decay(self) -> bool:
+        """A DELTA layer's decay is a vector over the key channels: the KDA
+        mixer and its kernels (ops/pallas_kda.py), not the scalar rule's."""
+        return self.lin_decay_dim > 1
 
     @property
     def head_size(self) -> int:
@@ -318,15 +339,18 @@ class ModelSpec:
                        self.qk_rope_head_dim, self.v_head_dim) > 0
             assert self.qk_rope_head_dim % 2 == 0
             assert self.n_dense_layers == 0 or self.dense_hidden_dim > 0
-            return
         if self.mixers:
             assert len(self.mixers) == self.n_layers, "one kind a layer"
         kinds = set(self.layer_kinds)
+        # a model's attending layers are all of one kind: latent rows or
+        # K / V rows (one K-leaf width a model, cache_head_size)
+        assert not (self.is_mla and LayerKind.ATTENTION in kinds), kinds
         if LayerKind.DELTA in kinds:
             assert min(self.lin_heads, self.lin_k_head_dim,
                        self.lin_v_head_dim) > 0
             assert self.lin_conv_width >= 2
             assert self.lin_beta_scale in (1, 2)
+            assert self.lin_decay_dim in (1, self.lin_k_head_dim)
         if LayerKind.SSM in kinds:
             assert min(self.ssm_heads, self.ssm_head_dim, self.ssm_d_state,
                        self.ssm_groups) > 0

@@ -1,5 +1,5 @@
 """Unified transformer forward for LLAMA / MIXTRAL / GROK1 / SARVAM_MLA /
-OLMO_HYBRID / GRANITE_HYBRID.
+OLMO_HYBRID / GRANITE_HYBRID / KIMI_LINEAR.
 
 One jittable segment-forward covers both prefill (T tokens at once — net-new
 vs the reference, which feeds the prompt token-by-token) and decode (T=1).
@@ -180,8 +180,9 @@ def _mla_attention_block(x, lw, spec: ModelSpec, cache, q_pos, cfg,
     """Latent attention in the absorbed form, for decode and chunks alike.
 
     u = norm(x); q = Wq u, per head [q_n ; q_r]; [c ; k_r] = Wkva u;
-    c~ = norm(c; g_kv); q_r, k_r rotated (ONE k_r for all heads). The cache
-    row is [c~ ; rot(k_r)]. Absorbed: q^_h = W_uk,h^T q_n,h, score =
+    c~ = norm(c; g_kv); q_r, k_r rotated (ONE k_r for all heads; not
+    rotated where the header's rope_theta is 0). The cache row is
+    [c~ ; rot(k_r)]. Absorbed: q^_h = W_uk,h^T q_n,h, score =
     (q^_h . c~ + q_r,h . k_r) * scale, o^_h = sum a c~, o_h = W_uv,h o^_h —
     the per-head keys and values W_kvb c~ are never built, so a cached
     token costs its 2 * (r + d_r + r) FLOPs a head and no up-projection.
@@ -199,8 +200,13 @@ def _mla_attention_block(x, lw, spec: ModelSpec, cache, q_pos, cfg,
         q = matmul(u, lw["wq"], **cfg).reshape(b, t, h, d_n + d_r)
         kva = matmul(u, lw["wkva"], **cfg)                   # (B, T, r + d_r)
         c = rmsnorm(kva[..., :r], lw["rms_kv"], eps)
-        q_r = rope_yarn(q[..., d_n:], q_pos, spec)
-        k_r = rope_yarn(kva[..., None, r:], q_pos, spec)[..., 0, :]
+        if spec.rope_theta > 0:
+            q_r = rope_yarn(q[..., d_n:], q_pos, spec)
+            k_r = rope_yarn(kva[..., None, r:], q_pos, spec)[..., 0, :]
+        else:   # 0: no rotation; the d_r columns are plain shared keys
+            # (position reaches such a model's attention through its
+            # recurrent layers)
+            q_r, k_r = q[..., d_n:], kva[..., r:]
         new = jnp.concatenate([c, k_r.astype(c.dtype)],
                               axis=-1)[:, :, None, :]
 
@@ -513,6 +519,11 @@ def _short_conv(xin, conv, lw, rows: SegmentRows, taps: int):
         tail, mode="drop")
 
 
+def _unit(u):
+    """A head's q or k scaled to unit length (the delta rules' keys)."""
+    return u * lax.rsqrt(jnp.sum(u * u, -1, keepdims=True) + 1e-6)
+
+
 def _delta_block(x, lw, spec: ModelSpec, state, tail, rows: SegmentRows,
                  cfg):
     """Gated delta rule mixer: projections -> causal depthwise convolution
@@ -535,9 +546,6 @@ def _delta_block(x, lw, spec: ModelSpec, state, tail, rows: SegmentRows,
     with jax.named_scope("gdn_conv"):
         y, tail = _short_conv(qkv, tail, lw, rows, taps)
 
-    def unit(u):
-        return u * lax.rsqrt(jnp.sum(u * u, -1, keepdims=True) + 1e-6)
-
     with jax.named_scope("gdn_rule"):
         q = y[..., :h * dk].reshape(b, t, h, dk)
         k = y[..., h * dk:2 * h * dk].reshape(b, t, h, dk)
@@ -546,12 +554,58 @@ def _delta_block(x, lw, spec: ModelSpec, state, tail, rows: SegmentRows,
                                                     + lw["dt_bias"])
         beta = spec.lin_beta_scale * jax.nn.sigmoid(ab[..., h:])
         o, state = delta_rule(
-            unit(q) * dk ** -0.5, unit(k), v, g, beta, state, rows.n_valid,
+            _unit(q) * dk ** -0.5, _unit(k), v, g, beta, state, rows.n_valid,
             rows.fresh, use_pallas=bool(cfg.get("use_pallas")),
             interpret=cfg.get("pallas_interpret", False))
     with jax.named_scope("gdn_out"):
         o = (rmsnorm(o, lw["rms_o"], spec.norm_eps)
              * jax.nn.silu(z.reshape(b, t, h, dv).astype(f32)))
+        out = matmul(o.astype(x.dtype).reshape(b, t, h * dv), lw["wo"],
+                     **cfg)
+    return out, state, tail
+
+
+def _kda_block(x, lw, spec: ModelSpec, state, tail, rows: SegmentRows, cfg):
+    """Kimi Delta Attention mixer under a norm on its INPUT: q, k, v and
+    the thin projections (the decay's and the gate's first halves, the
+    step's rows) -> causal depthwise convolution and SiLU on [q ; k ; v] ->
+    the decay a key CHANNEL through the second half of its low-rank pair ->
+    the rule on unit-length q, k (ops/pallas_kda.py) -> per-head RMS norm
+    gated by a SIGMOID of the gate's low-rank pair -> output projection.
+    _delta_block's contract."""
+    from ..ops.pallas_kda import kda_rule
+
+    b, t, _ = x.shape
+    h, dk, dv = spec.lin_heads, spec.lin_k_head_dim, spec.lin_v_head_dim
+    f32 = jnp.float32
+    with jax.named_scope("kda_proj"):
+        u = rmsnorm(x, lw["rms_att"], spec.norm_eps)
+        if "wqkv" in lw:
+            qkv = matmul(u, lw["wqkv"], **cfg)
+        else:
+            qkv = jnp.concatenate(
+                [matmul(u, lw[w], **cfg) for w in ("wq", "wk", "wv")], -1)
+        thin = matmul(u, lw["w_fgb"], **cfg)           # (B, T, d_k + H + d_v)
+        f = matmul(thin[..., :dk], lw["wf_b"], **cfg).astype(f32)
+        z = matmul(thin[..., dk + h:], lw["wg_b"], **cfg)
+    with jax.named_scope("kda_conv"):
+        y, tail = _short_conv(qkv, tail, lw, rows, spec.lin_conv_width)
+
+    with jax.named_scope("kda_rule"):
+        q = y[..., :h * dk].reshape(b, t, h, dk)
+        k = y[..., h * dk:2 * h * dk].reshape(b, t, h, dk)
+        v = y[..., 2 * h * dk:].reshape(b, t, h, dv)
+        g = (-jnp.exp(lw["a_log"])[:, None]
+             * jax.nn.softplus(f + lw["dt_bias"]).reshape(b, t, h, dk))
+        beta = spec.lin_beta_scale * jax.nn.sigmoid(
+            thin[..., dk:dk + h].astype(f32))
+        o, state = kda_rule(
+            _unit(q) * dk ** -0.5, _unit(k), v, g, beta, state, rows.n_valid,
+            rows.fresh, use_pallas=bool(cfg.get("use_pallas")),
+            interpret=cfg.get("pallas_interpret", False))
+    with jax.named_scope("kda_out"):
+        o = (rmsnorm(o, lw["rms_o"], spec.norm_eps)
+             * jax.nn.sigmoid(z.reshape(b, t, h, dv).astype(f32)))
         out = matmul(o.astype(x.dtype).reshape(b, t, h * dv), lw["wo"],
                      **cfg)
     return out, state, tail
@@ -600,8 +654,15 @@ def _ssm_block(x, lw, spec: ModelSpec, state, tail, rows: SegmentRows, cfg):
     return out, state, tail
 
 
-# a state layer's mixer by its kind; both keep _delta_block's contract
-_STATE_MIXERS = {LayerKind.DELTA: _delta_block, LayerKind.SSM: _ssm_block}
+def _delta_mixer(x, lw, spec: ModelSpec, *rest):
+    """A DELTA layer's mixer by the width of its decay (the spec's data):
+    a scalar a head, or a vector over the key channels (KDA)."""
+    block = _kda_block if spec.lin_vector_decay else _delta_block
+    return block(x, lw, spec, *rest)
+
+
+# a state layer's mixer by its kind; all keep _delta_block's contract
+_STATE_MIXERS = {LayerKind.DELTA: _delta_mixer, LayerKind.SSM: _ssm_block}
 # the kinds whose mixer follows a slot map: its kernel hands a row's final
 # state to the row that continues it (ssd_chunk does; delta_rule_chunk not)
 _CHAINING_MIXERS = frozenset({LayerKind.SSM})

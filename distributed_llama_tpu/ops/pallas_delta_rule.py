@@ -183,13 +183,14 @@ def _gated_blocks(live, n_j: int):
     return jnp.where(live, idx, row), blk
 
 
-def _call(body, name, operands, state, out_tail, n_valid, fresh, interpret):
-    """The pallas_call both kernels share: grid (rows, head blocks), every
-    operand (B, H, . , .) in blocks of HEAD_BLOCK heads, the state last and
-    aliased onto the second output; a gated row's blocks as _gated_blocks
-    says."""
+def _call(body, name, operands, state, out_tail, n_valid, fresh, interpret,
+          head_block: int = HEAD_BLOCK):
+    """The pallas_call the kernels share (ops/pallas_kda.py's too): grid
+    (rows, head blocks), every operand (B, H, . , .) in blocks of
+    `head_block` heads, the state last and aliased onto the second output;
+    a gated row's blocks as _gated_blocks says."""
     b, h = state.shape[:2]
-    hb = HEAD_BLOCK if h % HEAD_BLOCK == 0 else 1
+    hb = head_block if h % head_block == 0 else 1
     n_j = h // hb
     row, blk = _gated_blocks(n_valid > 0, n_j)
 

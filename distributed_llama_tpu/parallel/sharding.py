@@ -74,6 +74,10 @@ _SPLIT = {
     "rms_o": None,
     "rms_q": None,
     "rms_k": None,
+    # KIMI_LINEAR (likewise): a KDA layer's thin projections
+    "w_fgb": None,
+    "wf_b": None,
+    "wg_b": None,
     # GRANITE_HYBRID (likewise)
     "wz": None,
     "wx": None,

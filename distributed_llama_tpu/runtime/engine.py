@@ -196,7 +196,8 @@ class Engine:
             # the latent block; dp replicas are the way to more chips
             assert mesh is None or all(
                 mesh.shape.get(a, 1) == 1 for a in ("tp", "pp", "sp", "ep")), (
-                "SARVAM_MLA runs on one shard (dp only)")
+                f"{spec.arch.name} keeps a latent cache and runs on one "
+                "shard (dp only)")
         if mesh is not None and any(
                 mesh.shape.get(a, 1) > 1 for a in ("tp", "pp", "sp", "ep")):
             spec.refuse("parallel")

@@ -89,6 +89,32 @@ def tiny_granite_spec(weights_float_type: FloatType = FloatType.Q40,
     return ModelSpec(**base)
 
 
+def tiny_kimi_spec(weights_float_type: FloatType = FloatType.Q40,
+                   **overrides) -> ModelSpec:
+    """KIMI_LINEAR at a size a CPU holds: a period of (DELTA x 3, LATENT)
+    and the published tail (DELTA x 2, LATENT), 2 KDA heads of 32 / 32 with
+    a decay a key channel over a 4-tap convolution, 4 latent-attention
+    heads over a 32-wide latent WITHOUT rotation, a leading dense layer,
+    then in every layer 4 held experts (of 8 routed over, top 4) and a
+    shared expert."""
+    kinds = ((LayerKind.DELTA,) * 3 + (LayerKind.LATENT,)
+             + (LayerKind.DELTA,) * 2 + (LayerKind.LATENT,))
+    base = dict(
+        arch=ArchType.KIMI_LINEAR, dim=64, hidden_dim=32, n_layers=7,
+        n_heads=4, n_kv_heads=1, vocab_size=288, seq_len=160,
+        hidden_act=HiddenAct.SILU, rope_theta=0.0, n_experts=4,
+        n_active_experts=4, weights_float_type=weights_float_type,
+        kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        v_head_dim=16, n_dense_layers=1, dense_hidden_dim=128,
+        n_shared_experts=1, n_routed_experts=8, expert_offset=0,
+        routed_scaling=2.446, rms_eps=1e-5,
+        mixers=tuple(int(k) for k in kinds), lin_heads=2,
+        lin_k_head_dim=32, lin_v_head_dim=32, lin_conv_width=4,
+        lin_beta_scale=1, lin_decay_dim=32)
+    base.update(overrides)
+    return ModelSpec(**base)
+
+
 def free_port() -> int:
     """An OS-assigned free TCP port (shared by the cluster tests and the
     chaos harness spawners — one home for the bind-port-0 idiom)."""

@@ -35,9 +35,18 @@ BOOTS_A_SERVER = {"test_traced_rehearsal_reports_the_counter_metrics"}
 # benchmark may put nowhere else; test_granite_hybrid.py's two tests of the
 # LAST five entries run unedited on the manifest without the four, from
 # test_capture_report_metrics.py, so only "nothing comes after" is lost
+# PR 48 appends an eighth cell, a sixth configuration and two `per_layer`
+# entries (`kda_*_roofline`): test_capture_report_metrics.py's pin of the
+# LAST four entries and its two runs of granite's tests on "the manifest
+# without the four" cannot hold any more. tests/test_manifest_tail.py runs
+# the same three, every assertion kept, on the manifest without what PR 48
+# appended; only "nothing comes after the four" is lost (PERF.md section 7
+# names the edit benchmark/tests needs from a later `benchmark` PR)
 SUPERSEDED = {"test_the_manifest_has_six_cells_and_the_new_entries_come_last",
               "test_the_manifest_has_seven_cells_and_the_new_entries_come_last",
-              "test_olmos_entries_keep_their_places_and_their_keys"}
+              "test_olmos_entries_keep_their_places_and_their_keys",
+              "test_the_four_entries_come_last_with_their_files",
+              "test_what_came_before_the_four_is_what_granites_tests_hold"}
 
 
 def _load(name):

@@ -702,3 +702,60 @@ def test_whole_tp4_q80_steps_compile(topo):
         assert rep["kernels"].get("flash_attention", 0) >= 1, rep
         assert rep["kernels"].get("kv_cache_write", 0) >= 1, rep
         assert rep["collectives"]["all-to-all"] > 0, rep
+
+
+@pytest.mark.parametrize("t", [1, 32])
+def test_kda_kernels_compile_at_published_widths(one_chip, t):
+    """kda_decode (t = 1) and kda_chunk at Kimi-Linear-48B-A3B's sizes: 32
+    heads of 128 x 128, a decay a key channel, 8 rows; the state (8, 32,
+    128, 128) float32 donated and aliased."""
+    from distributed_llama_tpu.ops.pallas_kda import kda_rule
+    from distributed_llama_tpu.runtime.profiler import kernel_call_sites
+
+    f32 = jnp.float32
+    args = [_struct(s, d, one_chip) for s, d in (
+        ((8, t, 32, 128), f32), ((8, t, 32, 128), f32),
+        ((8, t, 32, 128), f32), ((8, t, 32, 128), f32), ((8, t, 32), f32),
+        ((8, 32, 128, 128), f32), ((8,), jnp.int32), ((8,), jnp.bool_))]
+    lowered = jax.jit(lambda *a: kda_rule(*a, use_pallas=True),
+                      donate_argnums=5).lower(*args)
+    mine = "kda_decode" if t == 1 else "kda_chunk"
+    assert kernel_call_sites(lowered.as_text()) == {mine: 1}
+    assert _has_kernel(lowered.compile())
+
+
+@pytest.mark.parametrize("t", [1, 32], ids=["decode", "chunk32"])
+def test_served_kimi_linear_step_programs_hold_their_kernels(topo, t):
+    """`kimi-linear-48b-a3b-ep4`'s two step programs at published widths
+    (the dense first layer, a KDA layer and a latent layer with their 64
+    held experts of 256 and the shared expert; B=8, S=8192, the Q80 round
+    trip on, the 40960-row head): the rule runs in its kernel of that
+    program, the latent layer attends through `mla_attention` and writes its
+    one 576-wide leaf through `kv_cache_write`, the 1024-wide experts tile,
+    and the program holds no copy of a state or a cache leaf."""
+    import rehearse_chip_compile as r
+
+    from distributed_llama_tpu.runtime.profiler import kernel_call_sites
+
+    spec = dataclasses.replace(r.KIMI_LINEAR_48B_EP4, n_layers=3,
+                               mixers=(2, 2, 1))
+    fn, args = r.abstract_step(spec, topo.devices, batch=8, t=t,
+                               seq_len=8192, q80=True)
+    cache = _cache_of(args)
+    assert (len(cache.k), len(cache.v), len(cache.s), len(cache.conv)) == (
+        1, 0, 2, 2)
+    assert cache.k[0].shape == (8, 1, 8192, 576)
+    assert cache.s[0].shape == (8, 32, 128, 128)
+    assert cache.conv[0].shape == (8, 3, 12288)
+    lowered = fn.lower(*args)
+    sites = kernel_call_sites(lowered.as_text())
+    mine, other = (("kda_decode", "kda_chunk") if t == 1
+                   else ("kda_chunk", "kda_decode"))
+    assert sites.get(mine, 0) >= 1 and other not in sites, sites
+    for k in ("mla_attention", "kv_cache_write", "q40_expert_matmul",
+              "q40_matmul"):
+        assert sites.get(k, 0) >= 1, sites
+    assert "flash_attention" not in sites and "delta_rule_chunk" not in sites
+    text = lowered.compile().as_text()
+    for leaf in (cache.k[0], cache.s[0]):
+        assert not r.cache_shaped_copies(text, leaf.shape)

@@ -5,7 +5,7 @@ same on two trees? For a DESCRIBED v5e (no chip attached, nothing runs):
     JAX_PLATFORMS=cpu python tools/compare_step_texts.py diff <dir_a> <dir_b>
 
 `write` compiles, from the checkout at <tree>, both slot step programs of
-the five benchmark configurations AS THAT TREE'S ENGINE SERVES THEM
+the benchmark's configurations (six since PR 48) AS THAT TREE'S ENGINE SERVES THEM
 (published widths, depth cut to a few layers or one period, B=8, the Q80
 round trip on; the chunk with a slot map where the tree's own rule gives
 it one) and keeps each program's text with its `metadata={...}` taken out. One process a tree: a process imports one
@@ -46,6 +46,9 @@ def write(tree: str, out: str) -> None:
         "olmo-hybrid-7b": (r.hybrid_layers(r.OLMO_HYBRID_7B, 1), 8192),
         "granite-4.0-h-small-ep2": (
             r.hybrid_layers(r.GRANITE_4_H_SMALL_EP2, 1, 10), 8192)}
+    if hasattr(r, "KIMI_LINEAR_48B_EP4"):     # a tree from PR 48 on
+        configs["kimi-linear-48b-a3b-ep4"] = (
+            r.hybrid_layers(r.KIMI_LINEAR_48B_EP4, 1), 8192)
     os.makedirs(out, exist_ok=True)
     for name, (spec, seq_len) in configs.items():
         for t in (1, 32):

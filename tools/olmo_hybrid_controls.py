@@ -7,7 +7,7 @@ cannot see.
 
     <chip tool> --chips 1 -- python tools/olmo_hybrid_controls.py \
         [--model M --tokenizer T] [--only served rows_fp8 ...] \
-        [--seed-offsets 1 2 3] [--out FILE]
+        [--once NAME ...] [--seed-offsets 1 2 3] [--out FILE]
 
 Without --model the file is written first (the synth child's function, 49 s
 at real size). The weights are loaded once and the reference computed once a
@@ -129,6 +129,9 @@ def run(doc: str, config: str, controls, argv=None) -> int:
     ap.add_argument("--seed-offsets", type=int, nargs="+", default=[1],
                     help="token seeds, as offsets from weights_seed (the "
                          "run's own check uses 1)")
+    ap.add_argument("--once", nargs="*", default=[],
+                    help="controls read on the first token seed only (the "
+                         "mechanisms: a wrong step reads far over any limit)")
     ap.add_argument("--out")
     args = ap.parse_args(argv)
     cfg, args.model, args.tokenizer = config_and_files(
@@ -177,7 +180,9 @@ def run(doc: str, config: str, controls, argv=None) -> int:
                    "decode_steps": check.get("decode_steps", 4)}
         for name, (flags, spec_change, param_change,
                    patch) in controls(cfg).items():
-            if args.only and name not in args.only:
+            if args.only and name not in args.only + args.once:
+                continue
+            if name in args.once and offset != args.seed_offsets[0]:
                 continue
             memo["change"] = (spec_change, param_change)
             with patch():

@@ -92,6 +92,23 @@ GRANITE_4_H_SMALL_EP2 = ModelSpec(
     residual_scale=0.22, attn_scale=0.0078125, logit_scale=0.0625)
 
 
+# Kimi-Linear-48B-A3B as benchmark/configs/kimi-linear-48b-a3b-ep4.json
+# serves it: one chip's share of 4 (64 of 256 routed experts, a quarter of
+# the vocabulary), (KDA x 3, LATENT) x 6 and the tail KDA x 2, LATENT
+KIMI_LINEAR_48B_EP4 = ModelSpec(
+    arch=ArchType.KIMI_LINEAR, dim=2304, hidden_dim=1024, n_layers=27,
+    n_heads=32, n_kv_heads=1, vocab_size=40960, seq_len=8192,
+    hidden_act=HiddenAct.SILU, rope_theta=0.0, rms_eps=1e-5, n_experts=64,
+    n_active_experts=8, kv_lora_rank=512, qk_nope_head_dim=128,
+    qk_rope_head_dim=64, v_head_dim=128, n_dense_layers=1,
+    dense_hidden_dim=9216, n_shared_experts=1, n_routed_experts=256,
+    routed_scaling=2.446,
+    mixers=((int(LayerKind.DELTA),) * 3 + (int(LayerKind.LATENT),)) * 6
+    + (int(LayerKind.DELTA),) * 2 + (int(LayerKind.LATENT),),
+    lin_heads=32, lin_k_head_dim=128, lin_v_head_dim=128, lin_conv_width=4,
+    lin_beta_scale=1, lin_decay_dim=128)
+
+
 def hybrid_layers(spec: ModelSpec, periods: int, period: int = 4) -> ModelSpec:
     """The first `periods` periods of a hybrid's layer pattern."""
     n = period * periods
@@ -130,16 +147,22 @@ def _loaded_params(spec: ModelSpec, dtype) -> dict:
         if spec.layer_kinds[l] == LayerKind.DELTA:
             nh, dk, dv = (spec.lin_heads, spec.lin_k_head_dim,
                           spec.lin_v_head_dim)
+            kda = spec.lin_vector_decay
             lw.update(
                 wq=_zeros_q40(nh * dk, d), wk=_zeros_q40(nh * dk, d),
-                wv=_zeros_q40(nh * dv, d), wg=_zeros_q40(nh * dv, d),
-                wo=_zeros_q40(d, nh * dv),
-                w_ab=jnp.zeros((2 * nh, d), dtype),
+                wv=_zeros_q40(nh * dv, d), wo=_zeros_q40(d, nh * dv),
                 conv_w=jnp.zeros((spec.lin_conv_width, spec.lin_conv_dim),
                                  jnp.float32),
                 a_log=jnp.zeros((nh,), jnp.float32),
-                dt_bias=jnp.zeros((nh,), jnp.float32),
+                dt_bias=jnp.zeros((nh * spec.lin_decay_dim,), jnp.float32),
                 rms_o=jnp.ones((dv,), jnp.float32))
+            if kda:     # the thin projections of models/params.load_params
+                lw.update(w_fgb=jnp.zeros((dk + nh + dv, d), dtype),
+                          wf_b=jnp.zeros((nh * dk, dk), dtype),
+                          wg_b=jnp.zeros((nh * dv, dv), dtype))
+            else:
+                lw.update(wg=_zeros_q40(nh * dv, d),
+                          w_ab=jnp.zeros((2 * nh, d), dtype))
         elif spec.layer_kinds[l] == LayerKind.SSM:
             nh, inner = spec.ssm_heads, spec.ssm_inner
             lw.update(
