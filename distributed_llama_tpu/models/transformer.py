@@ -768,9 +768,16 @@ def _moe_ffn(xb, lw, spec: ModelSpec, cfg, n_valid=None, counts=None):
     experts, every other shape all held experts for every row, masked.
     All compile to static shapes and give a live token the same bits.
 
-    counts: a list that receives this layer's (expert_reads, expert_pairs)
-    — distinct held experts with a live pair, and live pairs — as int32
-    scalars (forward sums them for the step programs' counters).
+    counts: a list that receives this layer's (expert_reads, expert_pairs,
+    expert_tiles) — distinct held experts with a live pair, live pairs, and
+    the row tiles the grouped call ran over (_pair_tiles' `used`, 0 where
+    no grouped call runs: tiles over reads is the row tiles one unpack of
+    an expert serves where the kernel runs stationary,
+    ops/pallas_q40._unpacks_once) — as int32 scalars (forward sums them for
+    the step programs' counters). A program whose token rows fit ONE row
+    tile (the served decode step) sends no third count: a group there is
+    one tile, the tiles ARE the reads, and the program keeps the text it
+    had.
     """
     b, t, d = xb.shape
     k_active = spec.n_active_experts
@@ -808,8 +815,12 @@ def _moe_ffn(xb, lw, spec: ModelSpec, cfg, n_valid=None, counts=None):
                       & live[..., None]).reshape(-1, spec.n_experts)
             sizes = member.sum(axis=0, dtype=jnp.int32)           # (E,)
             if counts is not None:
-                counts.append((jnp.sum(sizes > 0, dtype=jnp.int32),
-                               sizes.sum()))
+                counts.append([jnp.sum(sizes > 0, dtype=jnp.int32),
+                               sizes.sum()])
+
+    def count_tiles(tiles):
+        if counts is not None and b * t > _pair_layout(spec, b * t)[0]:
+            counts[-1].append(tiles)
 
     def scatter_weights():
         # (B, T, E) dense scatter of the normalized top-k weights (0 for
@@ -858,6 +869,7 @@ def _moe_ffn(xb, lw, spec: ModelSpec, cfg, n_valid=None, counts=None):
 
     if isinstance(lw["moe_up"], EpRowWeight):
         with jax.named_scope("moe_routed"):
+            count_tiles(jnp.int32(0))
             return ep_experts()
 
     def expert_apply(e):
@@ -894,8 +906,10 @@ def _moe_ffn(xb, lw, spec: ModelSpec, cfg, n_valid=None, counts=None):
         return acc
 
     with jax.named_scope("moe_routed"):
-        acc = (_grouped_experts(xb, lw, spec, cfg, held, live, member, sizes,
-                                weights) if in_place else sliced_experts())
+        acc, tiles = (_grouped_experts(xb, lw, spec, cfg, held, live, member,
+                                       sizes, weights)
+                      if in_place else (sliced_experts(), jnp.int32(0)))
+        count_tiles(tiles)
     return with_shared(acc)
 
 
@@ -905,7 +919,8 @@ def _grouped_experts(xb, lw, spec: ModelSpec, cfg, held, live, member, sizes,
     expert) pairs alone, one ops/pallas_q40.q40_expert_matmul call a
     projection. held, live (B, T, K): each pair's expert among those held
     here and whether it counts; member (B T K, E) one-hot of the live
-    pairs; sizes (E,) their count an expert.
+    pairs; sizes (E,) their count an expert. Returns the sum and the row
+    tiles it ran over (_pair_tiles' `used`).
 
     The pairs are laid out by expert in row tiles (_pair_tiles;
     expert_row_tile sizes the tile): an expert's group starts on a tile and
@@ -965,8 +980,8 @@ def _grouped_experts(xb, lw, spec: ModelSpec, cfg, held, live, member, sizes,
 
     acc = jnp.zeros((b, t, d), xb.dtype)
     if wave == n_tiles:
-        return run_wave(0, acc)
-    return lax.fori_loop(0, -(-used // wave), run_wave, acc)
+        return run_wave(0, acc), used
+    return lax.fori_loop(0, -(-used // wave), run_wave, acc), used
 
 
 def _pair_layout(spec: ModelSpec, rows: int) -> tuple[int, int, int]:
@@ -1133,9 +1148,11 @@ def forward(
     gather + all-reduce, bit-identical to the replicated gather (zeros +
     one real contribution add exactly). The head (wcls) is row-split by
     its PartitionSpec independently of this knob.
-    expert_counts: also return, third, int32 (2,): the distinct held experts
-    some real token chose and the live (token, expert) pairs, summed over
-    the MoE layers (_moe_ffn; the served step programs' window counters).
+    expert_counts: also return, third, int32 (3,): the distinct held experts
+    some real token chose, the live (token, expert) pairs and the row tiles
+    the grouped call lays them out in, summed over the MoE layers (_moe_ffn;
+    the served step programs' window counters); (2,), without the tiles,
+    from a program whose token rows fit one row tile.
     slots: (B,) int32, the cache slot row b reads and writes at pos0[b]
     (per-row positions, where takes_slot_map allows it). Rows of one slot
     at consecutive segments prefill that slot several segments a program:
@@ -1252,7 +1269,7 @@ def forward(
         # (a pp region's layers, traced elsewhere, are not counted)
         with jax.named_scope("moe_routed"):
             return logits, cache, jnp.asarray(
-                [sum(c[i] for c in moe_counts) for i in range(2)], jnp.int32)
+                [sum(c) for c in zip(*moe_counts)], jnp.int32)
     return logits, cache
 
 

@@ -161,11 +161,11 @@ def _spread_scales(s, spread):
     return pltpu.repeat(one, copies, axis=1) if copies > 1 else one
 
 
-def _dequant_dot(x_lo, x_hi, xsum, pk_u8, s_raw, spread,
-                 *, out_dtype, scales_u16, mxu_bf16):
-    """The kernel math on loaded blocks: dequantize a (TD, M) packed tile in
-    registers and contract with the pre-split activations. Activations must
-    already be in the contraction dtype (bf16 when mxu_bf16).
+def _dequant(pk_u8, s_raw, spread, *, scales_u16, mxu_bf16):
+    """Dequantise a (TD, M) packed tile: (wl, wh, s), the low- and
+    high-nibble halves times their block scales (bf16 under mxu_bf16) and
+    the decoded (TD, NB) scales, what _contract multiplies the pre-split
+    activations by.
 
     (A round-5 re-try of the pk-substitution — fold lo = pk - 16*hi into
     the contraction to drop the `& 0xF` — was REJECTED twice over: timing
@@ -182,7 +182,21 @@ def _dequant_dot(x_lo, x_hi, xsum, pk_u8, s_raw, spread,
     else:
         s = s_raw                                        # f32 (hand-built)
     s16 = _spread_scales(s, spread)                      # (TD, NB) -> (TD, M)
+    wl, wh = lo * s16, hi * s16
+    if mxu_bf16:
+        # multi-token (prefill) chunks are MXU-bound: f32 feeds cap the MXU
+        # at 1/4 of its bf16 rate (v5e 49 vs 197 TFLOP/s), so cast the
+        # dequantized tiles down. 4-bit weight levels and bf16 engine
+        # activations fit bf16 exactly; only requested when the caller's
+        # out_dtype is bf16 (decode t=1 stays f32/VPU-bound)
+        wl, wh = wl.astype(jnp.bfloat16), wh.astype(jnp.bfloat16)
+    return wl, wh, s
 
+
+def _contract(x_lo, x_hi, xsum, wl, wh, s, *, out_dtype):
+    """The pre-split activations against a dequantised tile (_dequant).
+    Activations must already be in the contraction dtype (bf16 when the
+    tile is)."""
     # DEFAULT precision: single-pass MXU feed (HIGHEST = multi-pass f32
     # decomposition, measured ~5x slower for the whole kernel); operands are
     # engine-bf16 activations and 4-bit weights, so nothing real is lost
@@ -192,14 +206,6 @@ def _dequant_dot(x_lo, x_hi, xsum, pk_u8, s_raw, spread,
         preferred_element_type=jnp.float32,
         precision=jax.lax.Precision.DEFAULT,
     )
-    wl, wh = lo * s16, hi * s16
-    if mxu_bf16:
-        # multi-token (prefill) chunks are MXU-bound: f32 feeds cap the MXU
-        # at 1/4 of its bf16 rate (v5e 49 vs 197 TFLOP/s), so cast the
-        # dequantized tiles down. 4-bit weight levels and bf16 engine
-        # activations fit bf16 exactly; only requested when the caller's
-        # out_dtype is bf16 (decode t=1 stays f32/VPU-bound)
-        wl, wh = wl.astype(jnp.bfloat16), wh.astype(jnp.bfloat16)
     acc = dot(x_lo, wl)                                  # (T, TD)
     acc += dot(x_hi, wh)
     acc += dot(xsum, s) * jnp.float32(-8.0)              # fold every (nib-8) offset
@@ -230,13 +236,20 @@ def _n_sub(td: int, m: int, mxu_bf16: bool) -> int:
 
 
 def _subtiled_write(x_lo, x_hi, xsum, load_packed, load_scales, rest,
-                    *, out_dtype, scales_u16, mxu_bf16):
-    """Run _dequant_dot per 1/n_sub row slice of the packed tile, writing
-    each output column slice as soon as its dot is issued. load_packed /
-    load_scales map a row slice -> loaded sub-block (ref slicing stays at
-    the call site because the expert kernel's refs carry a leading dim);
-    `rest` is the call's last refs: the spread matrix where the shape takes
-    one (_spread_matrix), and the output."""
+                    *, out_dtype, scales_u16, mxu_bf16, held=None):
+    """Dequantise and contract per 1/n_sub row slice of the packed tile,
+    writing each output column slice as soon as its dot is issued.
+    load_packed / load_scales map a row slice -> loaded sub-block (ref
+    slicing stays at the call site because the expert kernel's refs carry a
+    leading dim); `rest` is the call's last refs: the spread matrix where
+    the shape takes one (_spread_matrix), and the output.
+
+    `held`, the stationary expert call's (first, followed, wl, wh, s):
+    scratch that keeps the tile DEQUANTISED from one grid step to the
+    next. The FIRST row tile of an expert's run does what every tile does
+    without it, and leaves what it unpacked in the scratch if another tile
+    of its expert FOLLOWS; every other tile contracts against what it finds
+    there: the same sub-slices, the same dots, the same bits."""
     *spread, out_ref = rest
     spread = spread[0][:] if spread else None
     td = out_ref.shape[-1]
@@ -244,11 +257,44 @@ def _subtiled_write(x_lo, x_hi, xsum, load_packed, load_scales, rest,
     if mxu_bf16:
         x_lo, x_hi = x_lo.astype(jnp.bfloat16), x_hi.astype(jnp.bfloat16)
     h = td // n_sub
-    for i in range(n_sub):
-        sl = slice(i * h, (i + 1) * h)
-        out_ref[:, sl] = _dequant_dot(
-            x_lo, x_hi, xsum, load_packed(sl), load_scales(sl), spread,
-            out_dtype=out_dtype, scales_u16=scales_u16, mxu_bf16=mxu_bf16)
+    slices = [slice(i * h, (i + 1) * h) for i in range(n_sub)]
+    first, followed, *kept = held or (None, None)
+
+    def unpack_and_contract():
+        made = []
+        for sl in slices:
+            w = _dequant(load_packed(sl), load_scales(sl), spread,
+                         scales_u16=scales_u16, mxu_bf16=mxu_bf16)
+            out_ref[:, sl] = _contract(x_lo, x_hi, xsum, *w,
+                                       out_dtype=out_dtype)
+            made.append((sl, w))
+        return made
+
+    if held is None:
+        unpack_and_contract()
+        return
+
+    # three bodies, one a grid step: a first tile that no tile of its
+    # expert follows runs the very text every tile runs without the
+    # scratch (a group of one tile pays nothing for the order); read on the
+    # chip, a store under a condition of its own inside ONE first-tile body
+    # cost a tile of three 0.6 us more than this (PERF.md section 6, PR 49)
+    @pl.when(first & jnp.logical_not(followed))
+    def _():
+        unpack_and_contract()
+
+    @pl.when(first & followed)
+    def _():
+        for sl, w in unpack_and_contract():
+            for ref, part in zip(kept, w):
+                ref[sl, :] = part
+
+    @pl.when(jnp.logical_not(first))
+    def _():
+        for sl in slices:
+            out_ref[:, sl] = _contract(
+                x_lo, x_hi, xsum, *(ref[sl, :] for ref in kept),
+                out_dtype=out_dtype)
 
 
 def _kernel(x_lo_ref, x_hi_ref, xsum_ref, packed_ref, scales_ref, *rest,
@@ -261,17 +307,30 @@ def _kernel(x_lo_ref, x_hi_ref, xsum_ref, packed_ref, scales_ref, *rest,
 
 def _expert_kernel(tiles_ref, used_ref, x_lo_ref, x_hi_ref, xsum_ref,
                    packed_ref, scales_ref, *rest, nb, out_dtype,
-                   scales_u16, mxu_bf16):
-    del tiles_ref  # consumed by the index maps (each row tile's expert)
+                   scales_u16, mxu_bf16, stationary):
+    # the row tile of this grid step (_q40_call's two orders); its expert
+    # is consumed by the index maps
+    j = pl.program_id(1 if stationary else 0)
+    held = None
+    if stationary:
+        # the scratch comes last. Row tiles run innermost and a group's
+        # tiles are consecutive: a tile whose expert is the one of the tile
+        # before it finds that expert's block i dequantised there
+        *rest, wl_ref, wh_ref, s_ref = rest
+        e_j = tiles_ref[j]
+        first = (j == 0) | (e_j != tiles_ref[jnp.maximum(j - 1, 0)])
+        after = jnp.minimum(j + 1, pl.num_programs(1) - 1)
+        followed = (j + 1 < used_ref[0]) & (e_j == tiles_ref[after])
+        held = (first, followed, wl_ref, wh_ref, s_ref)
 
     # a row tile past the used ones holds no live pair: its index maps name
     # the blocks already resident (_q40_call), and its body is skipped
-    @pl.when(pl.program_id(0) < used_ref[0])
+    @pl.when(j < used_ref[0])
     def _():
         _subtiled_write(
             x_lo_ref[:], x_hi_ref[:], xsum_ref[:],
             lambda sl: packed_ref[0, sl, :], lambda sl: scales_ref[0, sl, :],
-            rest,
+            rest, held=held,
             out_dtype=out_dtype, scales_u16=scales_u16, mxu_bf16=mxu_bf16)
 
 
@@ -358,6 +417,58 @@ def _split_activation(x: jnp.ndarray, nb: int) -> tuple[jnp.ndarray, jnp.ndarray
     return x_lo, x_hi
 
 
+# Rows of the row tile whose grouped expert call runs stationary
+# (_unpacks_once): the sublane tile, the smallest tile expert_row_tile gives
+_STATIONARY_ROW_TILE = 8
+
+
+def _unpacks_once(tm: int, token_rows: int) -> bool:
+    """Whether a grouped expert call runs STATIONARY (_q40_call): weight
+    blocks outermost, so that the consecutive row tiles of one expert are
+    contracted against ONE dequantised copy of its block, where row tiles
+    outermost unpack the expert once a tile. Decided from the call's shapes
+    alone: a group can span tiles at all (the program's token rows exceed
+    the tile's `tm`: never in a decode step, whose row tile holds every row
+    and whose rows bring distinct experts a token), and the tile is the
+    sublane tile's 8 rows.
+
+    What the order gains or costs goes with the row tiles an expert's group
+    fills, which is the TRAFFIC's and no shape's (`tools/microbench.py
+    q40_orders`, PERF.md section 6, PR 49 after review: us a used tile on
+    the v5e under the bf16 feed of a 256-row chunk, row tiles outermost ->
+    stationary, at 1 / 2 / 3 / 5 tiles an expert | at the ~1.4 an EVEN
+    router's Poisson groups fill): kimi-linear's gate (8-row tiles, one
+    1024 x 1152 block, 72 scale blocks a row) 5.2 -> 5.1 / 5.4 -> 3.6 /
+    5.4 -> 3.0 / 5.5 -> 2.5 | 5.4 -> 4.3; its down projection (nine 256-row
+    blocks, a grid step each) 4.6 -> 5.1 / 4.4 -> 4.9 / 4.3 -> 4.1 / 4.4 ->
+    3.6 | 4.3 -> 4.5; sarvam's gate and down (16-row tiles, two and four
+    1024-row blocks) 7.0 -> 7.7 / 7.7 -> 7.6 / 7.6 -> 7.1 / 7.9 -> 6.8 |
+    7.8 -> 7.8 and 9.9 -> 10.4 / 9.0 -> 9.8 / 9.1 -> 8.7 / 9.2 -> 7.6 | 9.3
+    -> 9.4; Mixtral's gate (64-row tiles) 58 -> 69 / 58 -> 63 / 57 -> 57 /
+    57 -> 50 | 57 -> 62; granite's down 6.8 -> 8.0 / 7.3 -> 5.7 / 7.1 -> 5.0
+    / 7.0 -> 4.4 | 7.1 -> 7.3 (its 64-row tile holds 1.8 even shares: one
+    tile an expert). A following tile saves the fetch and the unpack and
+    still pays the MXU's pass over the block, so it saves most where the
+    unpack bounds a tile (kimi's 72 blocks a row, which `pltpu.repeat` lays
+    out in shifted pieces) and little where that pass does (sarvam's whole
+    lane tiles); a first tile pays up to 12-18 % for the order at one or two
+    tiles an expert.
+
+    So the rule is as narrow as what a cell has shown END TO END: the 8-row
+    tile is kimi-linear's chunk (a router four times as wide as the held
+    share, 8 rows an expert when even), whose cell's file routes a chunk to
+    8 of 64 held experts, 5.2 tiles an expert: `itl_p50_ms` -8 %,
+    `ttft_p50_ms` -11 %. THAT GAIN IS THE CELL'S SKEW: at an even router
+    the three projections together read 15.1 -> 13.1 us a tile (-13 % of
+    the kernel, a few per cent of a chunk). A wider tile keeps row tiles
+    outermost and the kernel text it had: sarvam's 16-row tiles ran
+    stationary in this PR's first hand-in and moved nothing end to end at
+    its cell's 3.1 tiles an expert (the kernel -8 %, 0.9 ms of a 69 ms
+    chunk); Mixtral's and granite's chunks hold 1.0-1.2. A cell that shows
+    a gain there is what widens this."""
+    return token_rows > tm == _STATIONARY_ROW_TILE
+
+
 def _q40_call(x, w: QuantizedTensor, e, out_dtype, interpret, used=None,
               token_rows=None):
     """The pallas_call both entry points share: `w` is one (d, m) packed
@@ -383,7 +494,7 @@ def _q40_call(x, w: QuantizedTensor, e, out_dtype, interpret, used=None,
     scales_u16 = w.scales.dtype == jnp.uint16
     scales = w.scales if scales_u16 else w.scales.astype(jnp.float32)
     # multi-token chunks with a bf16 consumer take the bf16 MXU feed (see
-    # _dequant_dot); single-token decode and f32 consumers keep exact f32.
+    # _dequant); single-token decode and f32 consumers keep exact f32.
     # The PROGRAM's token rows decide, not the pair rows a grouped call
     # lays them out in (token_rows)
     mxu_bf16 = (jnp.dtype(out_dtype) == jnp.bfloat16
@@ -394,39 +505,55 @@ def _q40_call(x, w: QuantizedTensor, e, out_dtype, interpret, used=None,
     # consumed in place, and so is the stack: block (e, i, 0) of it
     block = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
     n_i = pl.cdiv(d, td)
+    scratch = []
     if e is None:
         kernel, name, prefetched = _kernel, "q40_matmul", ()
         tm, grid = t, (n_i,)
         w_block, w_at = (td,), lambda i: (i, 0)
         x_at, out_at = (lambda i, *_: (0, 0)), (lambda i, *_: (0, i))
     else:
-        kernel, name = _expert_kernel, "q40_expert_matmul"
+        name = "q40_expert_matmul"
         tiles = jnp.atleast_1d(e).astype(jnp.int32)
         n_tiles = tiles.shape[0]
         prefetched = (tiles, jnp.atleast_1d(
             n_tiles if used is None else used).astype(jnp.int32))
-        tm, grid = t // n_tiles, (n_tiles, n_i)
+        tm = t // n_tiles
+        stationary = _unpacks_once(tm, t if token_rows is None else token_rows)
+        kernel = functools.partial(_expert_kernel, stationary=stationary)
 
-        # row tiles outermost: a tile's activations are fetched once and
+        # Row tiles outermost: a tile's activations are fetched once and
         # its expert's weight blocks stream past them. A tile past the used
         # ones names the LAST block of the last used tile, which is what
         # the step before it left resident, so it moves nothing (the rule
-        # ops/pallas_attention._last_attended gives a gated row)
-        def at(j, i, tiles_ref, used_ref):
-            live = j < used_ref[0]
+        # ops/pallas_attention._last_attended gives a gated row).
+        # STATIONARY (_unpacks_once), weight blocks outermost: steps (i, j)
+        # and (i, j + 1) of one expert name the same block (e_j, i), which
+        # is fetched once and kept dequantised in scratch (_expert_kernel),
+        # and a tile's activations are fetched once a block; a tile past the
+        # used ones names block i of the last used tile
+        def at(*step):
+            *step, tiles_ref, used_ref = step
+            j, i = reversed(step) if stationary else step
+            live = used_ref[0] > 0 if stationary else j < used_ref[0]
             j = jnp.maximum(jnp.minimum(j, used_ref[0] - 1), 0)
             return j, jnp.where(live, i, n_i - 1), tiles_ref[j]
 
-        def w_at(j, i, *refs):
-            j, i, e_j = at(j, i, *refs)
+        def w_at(*step):
+            j, i, e_j = at(*step)
             return e_j, i, 0
 
-        def x_at(j, i, *refs):
-            return at(j, i, *refs)[0], 0
+        def x_at(*step):
+            return at(*step)[0], 0
 
-        def out_at(j, i, *refs):
-            return at(j, i, *refs)[:2]
+        def out_at(*step):
+            return at(*step)[:2]
 
+        grid = (n_tiles, n_i)
+        if stationary:
+            grid = grid[::-1]
+            wide = jnp.bfloat16 if mxu_bf16 else jnp.float32
+            scratch = [pltpu.VMEM((td, m), wide), pltpu.VMEM((td, m), wide),
+                       pltpu.VMEM((td, nb), jnp.float32)]
         w_block = (1, td)
     spread = _spread_matrix(nb, 2 if scales_u16 else 3)
     consts = () if spread is None else (spread,)
@@ -442,13 +569,18 @@ def _q40_call(x, w: QuantizedTensor, e, out_dtype, interpret, used=None,
         ],
         out_specs=block((tm, td), out_at),
     )
+    if scratch:
+        specs["scratch_shapes"] = scratch
     if prefetched:
         specs = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetched), **specs))
 
     # the activation panels: whole and fetched once in the dense call, one
-    # row tile double-buffered in the expert call
+    # row tile double-buffered in the expert call; and the stationary
+    # call's dequantised block (a scale row fills a lane tile)
     panels = 4 * tm * (2 * m + nb) * (1 if e is None else 2)
+    held = sum(math.prod((*b.shape[:-1], -(-b.shape[-1] // LANES) * LANES))
+               * b.dtype.itemsize for b in scratch)
     out = pl.pallas_call(
         functools.partial(kernel, nb=nb, out_dtype=out_dtype,
                           scales_u16=scales_u16, mxu_bf16=mxu_bf16),
@@ -460,7 +592,7 @@ def _q40_call(x, w: QuantizedTensor, e, out_dtype, interpret, used=None,
             transcendentals=0,
         ),
         compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=_SCOPED_VMEM_DEFAULT + panels),
+            vmem_limit_bytes=_SCOPED_VMEM_DEFAULT + panels + held),
         interpret=interpret,
         name=name,
     )(*prefetched, x_lo, x_hi, xsum, w.packed, scales, *consts)

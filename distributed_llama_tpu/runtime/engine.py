@@ -1502,15 +1502,18 @@ class Engine:
                 and not self._multihost)
 
     def _note_expert_counts(self, program: str, counts=None) -> None:
-        """Keep a slot step program's (expert_reads, expert_pairs) on the
-        device, its copy to the host started, until take_expert_counts."""
+        """Keep a slot step program's (expert_reads, expert_pairs,
+        expert_tiles) on the device, its copy to the host started, until
+        take_expert_counts."""
         if counts is not None:
             counts.copy_to_host_async()
             self._expert_counts.append((program, counts))
 
-    def take_expert_counts(self) -> list[tuple[str, int, int]]:
-        """(program, expert_reads, expert_pairs) of the slot step programs
-        dispatched since the last call that HAVE RUN, oldest first; one
+    def take_expert_counts(self) -> list[tuple[str, int, ...]]:
+        """(program, expert_reads, expert_pairs, expert_tiles) of the slot
+        step programs dispatched since the last call that HAVE RUN, oldest
+        first (no tiles from a program whose token rows fit one row tile,
+        forward's expert_counts); one
         still running, and those behind it, wait for the next call, so this
         never blocks. After the scheduler has fetched a step's logits every
         program dispatched before the fetch has run and its counts' copy,

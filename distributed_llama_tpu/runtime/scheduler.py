@@ -838,13 +838,19 @@ class Scheduler:
         every logits fetch, and after a mid-prompt chunk, which fetches
         nothing."""
         take = getattr(self.engine, "take_expert_counts", None)
-        for program, reads, pairs in take() if take is not None else ():
+        for program, reads, pairs, *tiles in (take() if take is not None
+                                              else ()):
+            # a program whose rows fit one row tile counts no tiles: a
+            # group is one tile there, the tiles are the reads
+            tiles = tiles[0] if tiles else reads
             if program == "decode":
                 self.stats.expert_reads_decode += reads
                 self.stats.expert_pairs_decode += pairs
+                self.stats.expert_tiles_decode += tiles
             else:
                 self.stats.expert_reads_prefill += reads
                 self.stats.expert_pairs_prefill += pairs
+                self.stats.expert_tiles_prefill += tiles
 
     def _prefill_chunk(self, rows: list[_Slot],
                        width: int | None = None) -> int:

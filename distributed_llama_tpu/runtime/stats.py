@@ -237,7 +237,8 @@ WINDOW_COUNTERS = ("admitted", "queue_wait_ms_sum", "prefill_steps",
                    "attn_pairs_decode", "attn_pairs_prefill",
                    "prefill_cached_tokens",
                    "expert_reads_decode", "expert_reads_prefill",
-                   "expert_pairs_decode", "expert_pairs_prefill")
+                   "expert_pairs_decode", "expert_pairs_prefill",
+                   "expert_tiles_decode", "expert_tiles_prefill")
 
 
 class FrontDoorStats:
@@ -318,12 +319,16 @@ class ServeStats:
     # (models/transformer._moe_ffn) and added once a program has run
     # (Scheduler._count_experts): a READ is a held expert some real token
     # chose in a MoE layer of a program, a PAIR a (real token, chosen held
-    # expert); both summed over layers and programs; 0 for a model without
+    # expert), a TILE a row tile of the grouped expert call that holds a
+    # pair (an expert's group of n pairs is ceil(n / the program's tile)
+    # tiles); all summed over layers and programs; 0 for a model without
     # experts
     expert_reads_decode: int = 0
     expert_reads_prefill: int = 0
     expert_pairs_decode: int = 0
     expert_pairs_prefill: int = 0
+    expert_tiles_decode: int = 0
+    expert_tiles_prefill: int = 0
     # gauges, set by the Scheduler: cache bytes one token holds over the
     # layers that HAVE a cache (K and V leaves, or the latent cache's one
     # leaf), and the bytes a slot holds whatever its context (the DELTA

@@ -601,6 +601,12 @@ _COUNTERS = (
     ("expert_pairs_prefill", "dllama_expert_pairs_prefill_total",
      "(real prompt token, chosen held expert) pairs, summed over MoE "
      "layers"),
+    ("expert_tiles_decode", "dllama_expert_tiles_decode_total",
+     "Row tiles of the grouped expert call that hold a decoding row's "
+     "pair, summed over MoE layers"),
+    ("expert_tiles_prefill", "dllama_expert_tiles_prefill_total",
+     "Row tiles of the grouped expert call that hold a real prompt "
+     "token's pair, summed over MoE layers"),
 )
 
 _GAUGES = (

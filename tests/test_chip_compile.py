@@ -148,22 +148,30 @@ def test_q40_expert_matmul_compiles_at_mixtral_widths(one_chip, d, n, t):
 @pytest.mark.parametrize("rows", [8, 256], ids=["decode8", "chunk256"])
 @pytest.mark.parametrize("model,d,n", [
     ("mixtral_8x7b", 14336, 4096), ("mixtral_8x7b", 4096, 14336),
-    ("sarvam_105b_ep8", 2048, 4096), ("sarvam_105b_ep8", 4096, 2048)])
+    ("sarvam_105b_ep8", 2048, 4096), ("sarvam_105b_ep8", 4096, 2048),
+    ("kimi_linear_48b_ep4", 1024, 2304), ("kimi_linear_48b_ep4", 2304, 1024)])
 def test_grouped_expert_tiles_compile(one_chip, model, d, n, rows):
-    """`q40_expert_matmul` over the row tiles `_pair_layout` gives both MoE
+    """`q40_expert_matmul` over the row tiles `_pair_layout` gives three MoE
     configurations' two step programs (Mixtral: 8 tiles of 8 rows and 16
-    of 64; sarvam-105b-ep8: 16 of 8 and a wave of 32 of 16), each tile's
+    of 64; sarvam-105b-ep8: 16 of 8 and a wave of 32 of 16;
+    kimi-linear-48b-a3b-ep4: 64 of 8 and a wave of 128 of 8), each tile's
     expert and the used count prefetched, under the operand feed the
-    program's token rows decide."""
+    program's token rows decide. kimi's chunk calls (8-row tiles) run
+    STATIONARY (`_unpacks_once`): their dequantised block, 5.2 MB of
+    scratch at its gate's (1024, 1152) tile, compiles within the scoped
+    VMEM `_q40_call` asks for."""
     import rehearse_chip_compile as r
     from rehearse_chip_compile import q40_struct
 
     from distributed_llama_tpu.models.transformer import _pair_layout
-    from distributed_llama_tpu.ops.pallas_q40 import q40_expert_matmul
+    from distributed_llama_tpu.ops.pallas_q40 import (_unpacks_once,
+                                                      q40_expert_matmul)
 
     spec = getattr(r, model.upper())
     assert {d, n} == {spec.dim, spec.hidden_dim}
     tile, _, wave = _pair_layout(spec, rows)
+    assert _unpacks_once(tile, rows) == (
+        rows == 256 and model == "kimi_linear_48b_ep4")
     w = _placed(q40_struct(spec.n_experts, d, n), one_chip)
     x = _struct((wave * tile, n), BF16, one_chip)
     e = _struct((wave,), jnp.int32, one_chip)
@@ -288,6 +296,52 @@ def test_only_granites_step_programs_take_the_mxu_spread(
     own, forced = _with_repeat_forced(monkeypatch, lower)
     assert "q40_matmul" in own
     assert (own == forced) == (model != "granite_4_h_small_ep2")
+
+
+@pytest.mark.parametrize("t", [1, 32], ids=["decode", "chunk32"])
+@pytest.mark.parametrize("model", ["mixtral_8x7b_12l", "sarvam_105b_ep8",
+                                   "granite_4_h_small_ep2",
+                                   "kimi_linear_48b_ep4"])
+def test_only_kimis_chunk_unpacks_an_expert_once(
+        topo, monkeypatch, model, t):
+    """The four MoE configurations' step programs AS SERVED, with the order
+    of the grouped expert call as `_unpacks_once` decides it and with row
+    tiles outermost forced, which is the parent's kernel: Mixtral's,
+    granite's (64-row tiles) and sarvam's (16) chunk programs and all four
+    decode programs lower to the SAME TEXT either way;
+    `kimi-linear-48b-a3b-ep4`'s chunk program (8-row tiles) does not, and
+    it compiles for the described v5e with the dequantised block in scratch
+    (`test_served_kimi_linear_step_programs_hold_their_kernels`).
+    `tools/compare_step_texts.py` is the same comparison against a parent
+    checkout's compiled text."""
+    import rehearse_chip_compile as r
+
+    from distributed_llama_tpu.ops import pallas_q40 as q
+
+    spec, seq_len = {
+        "mixtral_8x7b_12l": (dataclasses.replace(r.MIXTRAL_8X7B,
+                                                 n_layers=2), 4096),
+        "sarvam_105b_ep8": (dataclasses.replace(r.SARVAM_105B_EP8,
+                                                n_layers=3), 8192),
+        "granite_4_h_small_ep2": (dataclasses.replace(
+            r.GRANITE_4_H_SMALL_EP2, n_layers=2, mixers=(3, 0)), 8192),
+        "kimi_linear_48b_ep4": (dataclasses.replace(
+            r.KIMI_LINEAR_48B_EP4, n_layers=3, mixers=(2, 2, 1)), 8192),
+    }[model]
+
+    def lower():
+        q.q40_expert_matmul.clear_cache()  # a trace is cached by shape
+        fn, args = r.abstract_step(spec, topo.devices, batch=8, t=t,
+                                   seq_len=seq_len, q80=True)
+        text = fn.lower(*args).as_text()
+        q.q40_expert_matmul.clear_cache()
+        return text
+
+    own = lower()
+    monkeypatch.setattr(q, "_unpacks_once", lambda *a: False)
+    forced = lower()
+    assert "q40_expert_matmul" in own
+    assert (own != forced) == (t == 32 and model == "kimi_linear_48b_ep4")
 
 
 @pytest.mark.parametrize("b,t,cache_dtype", [

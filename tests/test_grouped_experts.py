@@ -27,6 +27,7 @@ from distributed_llama_tpu.io.model_file import read_model  # noqa: E402
 from distributed_llama_tpu.models.params import (load_params,  # noqa: E402
                                                  random_tensors)
 from distributed_llama_tpu.models.spec import ArchType  # noqa: E402
+from distributed_llama_tpu.models.transformer import _pair_layout  # noqa: E402
 from distributed_llama_tpu.ops import pallas_q40  # noqa: E402
 from distributed_llama_tpu.runtime.engine import Engine  # noqa: E402
 from distributed_llama_tpu.runtime.scheduler import Scheduler  # noqa: E402
@@ -143,14 +144,16 @@ def test_a_whole_prompt_reads_and_writes_what_the_all_experts_loop_does(
         np.testing.assert_allclose(a, b, rtol=0, atol=2e-5)
 
 
-def test_the_four_counters_count_the_references_routing(tiny_moe, tmp_path):
+def test_the_six_counters_count_the_references_routing(tiny_moe, tmp_path):
     """Two requests through the Scheduler (chunks of 8, so tails are padded
     and rows gated; then both decode, one longer than the other): between a
-    capture's two ends `/stats` `capture` carries `expert_reads_*` and
-    `expert_pairs_*`, and they equal a NumPy count over the reference's own
-    `top_i` (benchmark/reference/mixtral.py) of the tokens each dispatched
-    program really held: a read is an expert some real token of a program
-    chose in a layer, a pair a (real token, chosen expert)."""
+    capture's two ends `/stats` `capture` carries `expert_reads_*`,
+    `expert_pairs_*` and `expert_tiles_*`, and they equal a NumPy count
+    over the reference's own `top_i` (benchmark/reference/mixtral.py) of
+    the tokens each dispatched program really held: a read is an expert
+    some real token of a program chose in a layer, a pair a (real token,
+    chosen expert), the tiles `sum(ceil(group / row tile))` over the
+    experts of a layer."""
     from distributed_llama_tpu.runtime.profiler import PROFILER
 
     path, spec, params, toks = tiny_moe
@@ -204,14 +207,26 @@ def test_the_four_counters_count_the_references_routing(tiny_moe, tmp_path):
         assert min(r["margin"].min() for r in routing) > 1e-5
         top_i[row] = [r["top_i"] for r in routing]      # a layer: (T, 2)
     want = dict.fromkeys(("expert_reads_prefill", "expert_pairs_prefill",
-                          "expert_reads_decode", "expert_pairs_decode"), 0)
+                          "expert_tiles_prefill", "expert_reads_decode",
+                          "expert_pairs_decode", "expert_tiles_decode"), 0)
+    # the row tile of each program's grouped call: 64 x 2 / 8 = 16 rows in
+    # a chunk of 8 x 8, the sublane tile's 8 in a decode step
+    tile = {"prefill": _pair_layout(spec, B * CHUNK)[0],
+            "decode": _pair_layout(spec, B)[0]}
+    assert tile == {"prefill": 16, "decode": 8}
     for program, held in dispatched:
         assert set(held) <= set(seqs)
         for layer in range(spec.n_layers):
             chosen = np.concatenate([top_i[r][layer][list(at)]
                                      for r, at in held.items()])
+            sizes = np.bincount(chosen.reshape(-1), minlength=spec.n_experts)
             want[f"expert_reads_{program}"] += len(np.unique(chosen))
             want[f"expert_pairs_{program}"] += chosen.size
+            want[f"expert_tiles_{program}"] += int(
+                (-(-sizes // tile[program])).sum())
+    assert (want["expert_reads_prefill"] <= want["expert_tiles_prefill"]
+            < want["expert_pairs_prefill"])
+    assert want["expert_tiles_decode"] == want["expert_reads_decode"]
     assert want["expert_pairs_prefill"] == (21 + 13) * 2 * spec.n_layers
     assert 0 < want["expert_reads_prefill"] < want["expert_pairs_prefill"]
     assert 0 < want["expert_reads_decode"] <= want["expert_pairs_decode"]
@@ -256,6 +271,102 @@ def test_the_counters_keep_up_through_chunks_that_fetch_nothing(tiny_moe):
     assert sched.stats.expert_pairs_decode == 2 * 1 * 2 * spec.n_layers
 
 
+def _skewed_share(rng, width, t, d, h):
+    """One MoE block of a SARVAM_MLA-shaped held share (sigmoid scores, a
+    bias that picks, top-8, 16 experts held from `width` routed, a shared
+    expert) at test size, with a SKEWED router: the bias sends most tokens
+    to three of the held experts, as the benchmark's sarvam and kimi files
+    do, so that a group is several row tiles."""
+    from test_pallas_q40 import _qt, _stack
+
+    from distributed_llama_tpu.models.spec import HiddenAct, ModelSpec
+
+    spec = ModelSpec(arch=ArchType.SARVAM_MLA, dim=d, hidden_dim=h,
+                     n_layers=1, n_heads=4, n_kv_heads=4, vocab_size=64,
+                     seq_len=64, n_experts=16, n_active_experts=8,
+                     n_routed_experts=width, expert_offset=width // 4,
+                     routed_scaling=2.5, n_shared_experts=1, kv_lora_rank=32,
+                     qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+                     hidden_act=HiddenAct.SILU)
+    bias = 0.5 * rng.standard_normal(width, dtype=np.float32)
+    bias[spec.expert_offset + np.asarray([1, 7, 10])] += 3.0
+    lw = {"moe_up": _stack(rng, 16, h, d)[1],
+          "moe_gate": _stack(rng, 16, h, d)[1],
+          "moe_down": _stack(rng, 16, d, h)[1],
+          "moe_router": jnp.asarray(rng.standard_normal(
+              (width, d), dtype=np.float32) / np.sqrt(d)),
+          "moe_bias": jnp.asarray(bias),
+          "sh_w1": _qt(rng, h, d), "sh_w2": _qt(rng, d, h),
+          "sh_w3": _qt(rng, h, d)}
+    xb = jnp.asarray(rng.standard_normal((B, t, d), dtype=np.float32),
+                     jnp.bfloat16)
+    return spec, lw, xb
+
+
+# (router width, tokens a row, dim, hidden): a chunk's row tile is 8 where
+# 8 x 8 tokens choose 8 of 64 (kimi-linear's 256 x 8 / 256) and 16 where
+# 8 x 32 choose 8 of 128 (sarvam's): the first sends all three projections
+# down the stationary order, the second keeps row tiles outermost, as the
+# two configurations' own chunks do
+SKEWED = {"kimi_cut": (64, 8, 256, 512), "sarvam_cut": (128, 32, 512, 512)}
+
+
+@pytest.mark.parametrize("case", sorted(SKEWED))
+def test_a_skewed_router_is_unpacked_once_an_expert_to_the_same_bits(
+        rng, monkeypatch, case):
+    """`_moe_ffn` under a router that sends most of a chunk's tokens to
+    three of the 16 held experts: groups of many row tiles, so the grouped
+    call runs stationary at the 8-row tile (every projection:
+    `_unpacks_once` says so and is what decides) and row tiles outermost at
+    the 16-row one, with gated rows and a right-padded tail. Every LIVE
+    token's output is BIT-equal to the all-experts loop over slices
+    (`sliced_experts`), operation by operation as in
+    tests/test_pallas_q40.py; and the layer's third count is the used row
+    tiles, `sum(ceil(group / tile))` over the held experts."""
+    import distributed_llama_tpu.models.transformer as tr
+    from test_pallas_q40 import _CFG
+
+    width, t, d, h = SKEWED[case]
+    spec, lw, xb = _skewed_share(rng, width, t, d, h)
+    tile = _pair_layout(spec, B * t)[0]
+    assert tile == {"kimi_cut": 8, "sarvam_cut": 16}[case]
+    n_valid = jnp.asarray([t, 0, t - 3, t, 0, t, 1, t], jnp.int32)
+    real = np.arange(t)[None, :] < np.asarray(n_valid)[:, None]
+
+    orders, groups = [], []
+    decide, lay_out = pallas_q40._unpacks_once, tr._pair_tiles
+    monkeypatch.setattr(pallas_q40, "_unpacks_once", lambda *a: (
+        orders.append(decide(*a)) or orders[-1]))
+
+    def pair_tiles(held, live, member, sizes, tile, n_tiles):
+        out = lay_out(held, live, member, sizes, tile, n_tiles)
+        groups.append((np.asarray(sizes), int(out[-1])))
+        return out
+
+    monkeypatch.setattr(tr, "_pair_tiles", pair_tiles)
+
+    def run(nv):
+        counts = []
+        return tr._moe_ffn(xb, lw, spec, _CFG, nv, counts), counts[0]
+
+    pallas_q40.q40_expert_matmul.clear_cache()
+    with jax.disable_jit():
+        got, (reads, pairs, tiles) = run(n_valid)
+    pallas_q40.q40_expert_matmul.clear_cache()
+    assert orders and set(orders) == {case == "kimi_cut"}
+    (sizes, used), = groups
+    assert int(tiles) == used == int((-(-sizes // tile)).sum())
+    assert int(reads) == (sizes > 0).sum() and int(pairs) == sizes.sum()
+    assert np.sort(sizes)[-3] > 2 * tile and int(tiles) > 2 * int(reads)
+
+    _all_experts_loop(monkeypatch)
+    with jax.disable_jit():
+        loop = np.asarray(run(None)[0], np.float32)
+    got = np.asarray(got, np.float32)
+    assert np.abs(loop[real]).max() > 0
+    np.testing.assert_array_equal(got[real], loop[real])
+
+
 # sha256 (16 hex digits) of the text the slot step programs of the tiny
 # specs lower to, read on the tree BEFORE the grouped path and its counters
 # existed (commit 5e2fe7c, this container's jax): a model without experts
@@ -278,21 +389,32 @@ PARENT_TEXT = {
     ("OLMO_HYBRID", True, "decode"): "049f19dac0dd4149",
     ("OLMO_HYBRID", "repeat", "prefill"): "2b2ee5b53568d61c",
     ("OLMO_HYBRID", True, "prefill"): "259a2aa37dfcc092",
-    # the two architectures WITH experts that the benchmark runs, read on
-    # commit 26394a7 (before GRANITE_HYBRID's block kinds and multipliers):
-    # a pre-norm block whose multipliers are 1 compiles what it compiled
+    # the two architectures WITH experts that the benchmark runs. Their
+    # DECODE programs keep the text of commit 26394a7 (before
+    # GRANITE_HYBRID's block kinds and multipliers); their CHUNK programs
+    # kept it until PR 49, whose third counter (the grouped call's used row
+    # tiles, `expert_tiles_prefill`: an int32 (3,) beside the logits where
+    # it was (2,); a program whose rows fit one row tile sends none) leaves
+    # them: re-pinned there. That PR 49's KERNEL change enters no program of
+    # these shapes is shown by the parent's tree with that PR's kernel file
+    # alone, which passes the parent's pins, all 24 of this file's:
+    #   git archive <PR 48's commit> | tar -x -C /root/scratch/kernel_only
+    #   cp distributed_llama_tpu/ops/pallas_q40.py \
+    #      /root/scratch/kernel_only/distributed_llama_tpu/ops/
+    #   cd /root/scratch/kernel_only && JAX_PLATFORMS=cpu python -m pytest \
+    #      tests/test_grouped_experts.py -q -k lowers   # 24 passed
     ("MIXTRAL", False, "decode"): "2abb1048c22a432a",
-    ("MIXTRAL", False, "prefill"): "826812d1438131aa",
+    ("MIXTRAL", False, "prefill"): "7ad217f81ca8a97f",
     ("MIXTRAL", "repeat", "decode"): "f14b9fd15f1fdd5c",
     ("MIXTRAL", True, "decode"): "c8b878c4c7e736a5",
-    ("MIXTRAL", "repeat", "prefill"): "d5b0b94719fac3e7",
-    ("MIXTRAL", True, "prefill"): "ab6e88d04010e6c9",
+    ("MIXTRAL", "repeat", "prefill"): "7a2f39da71cb55c3",
+    ("MIXTRAL", True, "prefill"): "715b745902641e35",
     ("SARVAM_MLA", False, "decode"): "7870fd495c410b5b",
-    ("SARVAM_MLA", False, "prefill"): "260025de90698f07",
+    ("SARVAM_MLA", False, "prefill"): "f48f7070111d603d",
     ("SARVAM_MLA", "repeat", "decode"): "b48b5b664e87e75b",
     ("SARVAM_MLA", True, "decode"): "816d518e01fac15d",
-    ("SARVAM_MLA", "repeat", "prefill"): "8aa08d0ea1b55faf",
-    ("SARVAM_MLA", True, "prefill"): "b7d4b1922cffb988",
+    ("SARVAM_MLA", "repeat", "prefill"): "8f5099986a1338e6",
+    ("SARVAM_MLA", True, "prefill"): "a85e68be762199a8",
 }
 TINY_SPECS = {
     "LLAMA": tiny_spec, "OLMO_HYBRID": tiny_hybrid_spec,
@@ -373,7 +495,9 @@ def test_a_model_with_experts_lowers_to_the_parents_step_programs(
     """MIXTRAL and SARVAM_MLA engines, the benchmark's other two
     architectures, lower to the SAME TEXT as before the block of a layer
     was chosen by what the spec says (norm placement, FFN kind, the four
-    multipliers): at multipliers of 1 nothing enters their programs."""
+    multipliers): at multipliers of 1 nothing enters their programs. (One
+    thing has since, on purpose, in the chunk programs: PR 49's third
+    counter, PARENT_TEXT.)"""
     text = lowered_steps(arch, kernels)[program].as_text()
     assert (hashlib.sha256(text.encode()).hexdigest()[:16]
             == PARENT_TEXT[arch, kernels, program]), (arch, kernels, program)

@@ -125,6 +125,10 @@ def test_the_whole_command_at_tiny_size_on_cpu(monkeypatch, tmp_path):
     # no device plane on a CPU: the trace readers leave their metrics out
     assert "kda_decode_roofline" not in out["metrics"]
     assert "prefill_tokens_per_chunk" in out["metrics"]
+    # a count, not a time: row tiles a read of an expert serves in a chunk;
+    # the CPU's server runs no kernel, so no grouped call lays out a tile
+    assert out["metrics"]["prefill_tiles_per_expert_read"]["value"] == 0
+    assert end["expert_reads_prefill"] > end["expert_tiles_prefill"] == 0
 
 
 @pytest.fixture(scope="module")
@@ -205,7 +209,8 @@ def test_the_manifest_ends_with_this_cell_and_its_two_metrics(real):
     """What `benchmark/tests`' pins of "the last entries" would say of this
     PR's: one configuration, one one-chip cell under long-doc and two
     per-layer metrics over the reader the benchmark has, each LAST in its
-    list; the cell's file is olmo's."""
+    list (PR 49's counter metric has come after them since); the cell's
+    file is olmo's."""
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         m = json.load(f)
     assert len(m["workloads"]) == 8 and len(m["configs"]) == 6
@@ -216,9 +221,9 @@ def test_the_manifest_ends_with_this_cell_and_its_two_metrics(real):
         "name": "kimi-linear-48b-a3b-ep4.long-doc",
         "config": "kimi-linear-48b-a3b-ep4", "traffic": "long-doc",
         "chips": 1, "why": m["workloads"][-1]["why"]}
-    assert [x["name"] for x in m["per_layer"][-2:]] == [
+    assert [x["name"] for x in m["per_layer"][-3:-1]] == [
         "kda_decode_roofline", "kda_prefill_roofline"]
-    for x, moves in zip(m["per_layer"][-2:], ("itl_p50_ms", "ttft_p50_ms")):
+    for x, moves in zip(m["per_layer"][-3:-1], ("itl_p50_ms", "ttft_p50_ms")):
         assert x["workloads"] == ["kimi-linear-48b-a3b-ep4.long-doc"]
         assert x["moves"] == moves and x["source"] == "device_trace"
         spec = traffic.load_json("layer_metrics", x["name"] + ".json")

@@ -1,6 +1,7 @@
 """What `benchmark/tests` pins of the END of the manifest, held on the
 manifest WITHOUT what PR 48 appended (an eighth cell, a sixth configuration,
-`kda_decode_roofline` and `kda_prefill_roofline`): a `model_config` PR puts
+`kda_decode_roofline` and `kda_prefill_roofline`) and PR 49 after it (one
+per-layer metric, `prefill_tiles_per_expert_read`): a `model_config` PR puts
 its entries last and may edit no file under benchmark/, so
 test_capture_report_metrics.py's pin of the last four `per_layer` entries
 and its two runs of test_granite_hybrid.py's tests stand in
@@ -21,7 +22,7 @@ for p in (BENCH, os.path.join(BENCH, "tests"), REPO):
     if p not in sys.path:
         sys.path.insert(0, p)
 
-APPENDED = {"workloads": 1, "configs": 1, "per_layer": 2}      # by PR 48
+APPENDED = {"workloads": 1, "configs": 1, "per_layer": 3}   # by PRs 48, 49
 
 with open(os.path.join(REPO, "BENCHMARK.json")) as f:
     M = json.load(f)
@@ -46,12 +47,40 @@ def test_what_pr_48_appended_is_one_cell_one_configuration_two_metrics():
         "kimi-linear-48b-a3b-ep4.long-doc"]
     assert [c["name"] for c in M["configs"][-1:]] == [
         "kimi-linear-48b-a3b-ep4"]
-    assert [m["name"] for m in M["per_layer"][-2:]] == [
+    assert [m["name"] for m in M["per_layer"][-3:-1]] == [
         "kda_decode_roofline", "kda_prefill_roofline"]
     # no accepted entry lists the new cell (a model_config PR edits none)
     assert all("kimi-linear-48b-a3b-ep4.long-doc"
                not in (m.get("workloads") or ())
                for m in BEFORE["per_layer"] + M["end_to_end"])
+
+
+def test_what_pr_49_appended_is_one_counter_metric_as_data():
+    """`prefill_tiles_per_expert_read`, last in `per_layer`: the row tiles
+    one read of an expert serves in a chunk program, over the reader the
+    benchmark had (`stats_delta_opt`: nothing to read, and no error, from a
+    program without the counter), in the five cells whose model routes."""
+    import traffic
+
+    last = M["per_layer"][-1]
+    moe = [w["name"] for w in M["workloads"] if w["config"].split("-")[0] in (
+        "mixtral", "sarvam", "granite", "kimi")]
+    assert last == {
+        "name": "prefill_tiles_per_expert_read", "unit": "tiles",
+        "better": "lower", "source": "program_counter",
+        "layer": "kernels (ops/pallas_q40.py)", "moves": "ttft_p50_ms",
+        "workloads": moe}
+    assert len(moe) == 5
+    spec = traffic.load_json("layer_metrics", last["name"] + ".json")
+    assert spec["reader"] == "stats_delta_opt" and spec["args"] == {
+        "num": "expert_tiles_prefill", "den": "expert_reads_prefill"}
+    assert {k: spec[k] for k in last if k != "workloads"} == {
+        k: v for k, v in last.items() if k != "workloads"}
+    from readers import stats_delta_opt
+
+    older = {"stats": {"window_start": {"expert_reads_prefill": 1},
+                       "window_end": {"expert_reads_prefill": 9}}}
+    assert stats_delta_opt.read(older, **spec["args"]) is None
 
 
 def test_the_four_entries_came_last_with_their_files(capture, monkeypatch):

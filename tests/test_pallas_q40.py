@@ -192,7 +192,8 @@ def test_grouped_experts_bit_equal_to_all_experts_loop(rng, monkeypatch,
     output is zero (what is left of it is the shared expert's)."""
     import jax
 
-    from distributed_llama_tpu.models.transformer import _moe_ffn
+    from distributed_llama_tpu.models.transformer import (_moe_ffn,
+                                                          _pair_layout)
 
     spec, lw = _moe_case(rng, kind, unchosen=3)
     xb = jnp.asarray(rng.standard_normal((8, t, spec.dim), dtype=np.float32),
@@ -210,9 +211,16 @@ def test_grouped_experts_bit_equal_to_all_experts_loop(rng, monkeypatch,
     shared = {"q40_matmul": 3} if kind == "held" else {}
     assert _jit_calls(run(n_valid)) == {"q40_expert_matmul": 3, **shared}
     with jax.disable_jit():
-        got, (reads, pairs) = run(n_valid)()
+        got, (reads, pairs, *tiles) = run(n_valid)()
     got = np.asarray(got, np.float32)
     assert 0 < reads <= spec.n_experts - 1          # expert 3: never read
+    # a program whose rows fit one row tile (the decode step) counts no
+    # tiles; in a chunk a read is at least one row tile, and a tile holds
+    # a pair
+    tile = _pair_layout(spec, 8 * t)[0]
+    assert len(tiles) == (8 * t > tile)
+    assert all(reads <= n <= min(pairs, reads + pairs // tile)
+               for n in tiles)
     if kind == "held":
         assert reads <= pairs < real.sum() * spec.n_active_experts
     else:
@@ -480,6 +488,13 @@ TILES = {
         (6144, 4096): 1024, (6144, 1024): 1024, (1024, 4096): 1024,
         (4096, 1024): 1024, (50176, 4096): 1024, (12544, 4096): 256,
         (50176, 1024): 1024},
+    # expert gate / up and down (1024-wide experts over a 2304-wide
+    # stream: 72 scale blocks a row, not whole lane tiles), the dense first
+    # layer's FFN (9216), the 40960-row head; the experts' tp = 4 shards
+    "kimi-linear-48b-a3b-ep4": {
+        (1024, 2304): 1024, (256, 2304): 256, (1024, 576): 1024,
+        (2304, 1024): 256, (576, 1024): 576, (2304, 256): 256,
+        (9216, 2304): 1024, (2304, 9216): 256, (40960, 2304): 1024},
 }
 
 # The pinned shapes whose block scales are spread over the lanes on the MXU
@@ -500,6 +515,7 @@ MXU_SPREAD = {
         (22016, 960), (100352, 960)},
     "granite-4.0-h-small-ep2": {
         (4096, 768), (1024, 768), (4096, 192), (4096, 384)},
+    "kimi-linear-48b-a3b-ep4": {(1024, 576), (2304, 256)},
 }
 
 
@@ -515,6 +531,135 @@ def test_which_pinned_shapes_spread_their_scales_on_the_mxu(config):
 
     got = {(d, n) for d, n in TILES[config] if _spreads_on_mxu(n // 32)}
     assert got == MXU_SPREAD[config]
+
+
+# Which order the grouped expert call of each (configuration, step program,
+# projection) takes (`_unpacks_once`, decided from the call's shapes when the
+# program is traced): True where weight blocks run outermost and an expert's
+# consecutive row tiles share ONE dequantised block. Only the chunk program
+# whose row tile is the sublane tile's 8 rows takes it, kimi-linear's, for
+# all three projections; every decode step, and sarvam's (16-row tiles),
+# Mixtral's and granite's chunks (64-row tiles), keep row tiles outermost
+# and the parent's kernel text (PERF.md section 6, PR 49).
+# Values: (row tile, tiles a wave, stationary).
+ORDERS = {
+    ("MIXTRAL_8X7B", 8): (8, 8, False),
+    ("MIXTRAL_8X7B", 256): (64, 16, False),
+    ("SARVAM_105B_EP8", 8): (8, 16, False),
+    ("SARVAM_105B_EP8", 256): (16, 32, False),
+    ("GRANITE_4_H_SMALL_EP2", 8): (8, 36, False),
+    ("GRANITE_4_H_SMALL_EP2", 256): (64, 56, False),
+    ("KIMI_LINEAR_48B_EP4", 8): (8, 64, False),
+    ("KIMI_LINEAR_48B_EP4", 256): (8, 128, True),
+}
+
+
+@pytest.mark.parametrize("config,rows", sorted(ORDERS))
+def test_which_grouped_calls_unpack_an_expert_once(config, rows):
+    import os
+    import sys
+
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools"))
+    import rehearse_chip_compile as r
+
+    from distributed_llama_tpu.models.transformer import _pair_layout
+    from distributed_llama_tpu.ops.pallas_q40 import _unpacks_once
+
+    spec = getattr(r, config)
+    tile, _, wave = _pair_layout(spec, rows)
+
+    assert (tile, wave, _unpacks_once(tile, rows)) == ORDERS[config, rows]
+    # a scalar expert (one tile of all rows) never does
+    assert not _unpacks_once(rows, rows)
+
+
+def _runs(*groups):
+    """Tile experts of consecutive runs: (expert, tiles) pairs."""
+    return [e for e, n in groups for _ in range(n)]
+
+
+# (weights (E, d, n), rows a tile, the tiles' experts, used, out dtype):
+# routings that differ in what the stationary order sees. The shapes are
+# kimi-linear's expert projections (gate: one 1024-row block, 72 scale
+# blocks a row; down: nine 256-row blocks, sub-tiled 8-way under the bf16
+# feed) with four experts held, and a small one for the rest
+_KIMI_GATE, _KIMI_DOWN, _SMALL = (4, 1024, 2304), (4, 2304, 1024), (4, 256, 512)
+STATIONARY_CASES = {
+    "one_tile_an_expert": (_SMALL, 8, [0, 1, 2, 3], 4, "bf16"),
+    "five_tiles_kimi_gate_bf16": (
+        _KIMI_GATE, 8, _runs((0, 5), (2, 5), (3, 2)), 12, "bf16"),
+    "five_tiles_kimi_gate_f32": (
+        _KIMI_GATE, 8, _runs((1, 5), (3, 1)), 6, "f32"),
+    "five_tiles_kimi_down_bf16": (
+        _KIMI_DOWN, 8, _runs((0, 5), (1, 1), (3, 5)), 11, "bf16"),
+    "five_tiles_kimi_down_f32": (
+        _KIMI_DOWN, 8, _runs((2, 5), (3, 1)), 6, "f32"),
+    "every_pair_in_one_expert": (_SMALL, 8, [2] * 12, 12, "bf16"),
+    "nothing_used": (_SMALL, 8, [0, 0, 1, 3], 0, "bf16"),
+    # the used tiles end inside an expert's run (its last tile ragged in
+    # the layout: rows computed that mean nothing), dead tiles behind them
+    "used_short_of_the_tiles": (
+        _SMALL, 8, _runs((0, 3), (1, 2), (3, 3)), 6, "bf16"),
+    # an expert's run cut by the end of a wave: _grouped_experts runs the
+    # call again over the next tiles, whose first finds nothing kept
+    "two_waves": (_SMALL, 8, _runs((0, 3), (1, 4), (3, 5)), 11, "bf16"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(STATIONARY_CASES))
+def test_stationary_order_is_bit_equal_to_rows_outermost(rng, monkeypatch,
+                                                         case):
+    """The grouped call with weight blocks outermost and an expert's block
+    kept dequantised from one row tile to the next (`_unpacks_once`)
+    against the same call with row tiles outermost, forced, and against a
+    call of its own on each tile's expert (`q40_matmul` on the expert's
+    slice where the feed is the same, float32; the scalar-expert call,
+    which shares its body, under the bf16 feed an 8-row call of its own
+    would not take): every used row to the last bit; the rows past `used`
+    never written (the interpreter marks memory nobody wrote with NaN)."""
+    from distributed_llama_tpu.ops import pallas_q40 as q
+
+    (n_e, d, n), tm, tiles, used, feed = STATIONARY_CASES[case]
+    out_dtype = jnp.bfloat16 if feed == "bf16" else jnp.float32
+    w = QuantizedTensor(
+        jnp.asarray(rng.integers(0, 256, (n_e, d, n // 2), dtype=np.uint8)),
+        jnp.asarray(_wide_scales(rng, "u16", False, n_e, d, n // 32)))
+    x = jnp.asarray(rng.standard_normal((len(tiles) * tm, n),
+                                        dtype=np.float32), jnp.bfloat16)
+    waves = 2 if case == "two_waves" else 1
+    wave = len(tiles) // waves
+    assert q._unpacks_once(tm, 256)
+
+    def call(stationary):
+        monkeypatch.setattr(q, "_unpacks_once", lambda *a: stationary)
+        q.q40_expert_matmul.clear_cache()
+        out = [q.q40_expert_matmul(
+            x[k * wave * tm:(k + 1) * wave * tm], w,
+            jnp.asarray(tiles[k * wave:(k + 1) * wave], jnp.int32),
+            jnp.int32(min(max(used - k * wave, 0), wave)),
+            out_dtype=out_dtype, interpret=True, token_rows=256)
+            for k in range(waves)]
+        q.q40_expert_matmul.clear_cache()
+        return np.concatenate([np.asarray(o, np.float32) for o in out])
+
+    got, want = call(True), call(False)
+    live = used * tm
+    assert np.isnan(got[live:]).all() and np.isnan(want[live:]).all()
+    assert np.isfinite(want[:live]).all()
+    np.testing.assert_array_equal(got[:live], want[:live])
+    for j in sorted({0, used - 1} & set(range(used))):
+        rows = slice(j * tm, (j + 1) * tm)
+        e = tiles[j]
+        if feed == "f32":
+            own = q40_matmul(x[rows], QuantizedTensor(
+                w.packed[e], w.scales[e]), out_dtype=out_dtype,
+                interpret=True)
+        else:
+            own = q.q40_expert_matmul(x[rows], w, e, out_dtype=out_dtype,
+                                      interpret=True, token_rows=256)
+        np.testing.assert_array_equal(got[rows],
+                                      np.asarray(own, np.float32))
 
 
 def _wide_scales(rng, scales, one_exponent, *shape):

@@ -11,7 +11,13 @@ Q40 kernels at the narrow contractions the configurations hold, each with
 the block scales spread by `pltpu.repeat` and on the MXU, on one chip in
 one process (PERF.md section 6, PR 40).
 
-Usage: python tools/microbench.py [all|gemv|gemv_q40|gemv_pallas|attn|cache|q40_shapes]
+`q40_orders` (run by `q40_shapes` too) is the table that sets
+`ops/pallas_q40._unpacks_once`: the grouped expert call at the expert shapes
+of the configurations, row tiles outermost against weight blocks outermost
+with the block kept dequantised, at 1, 2, 3 and 5 row tiles an expert and
+at the tiles an even router gives (PERF.md section 6, PR 49).
+
+Usage: python tools/microbench.py [all|gemv|gemv_q40|gemv_pallas|attn|cache|q40_shapes|q40_orders]
 """
 
 from __future__ import annotations
@@ -34,7 +40,7 @@ SEQ, KVH, HS = 2048, 32, 128
 R1, R2 = 4, 32  # wide spread: run-to-run jitter swamps small slopes
 
 
-def slope_time(make_run, *args, reps=(R1, R2)):
+def slope_time(make_run, *args, reps=(R1, R2), tries=3):
     """make_run(reps) -> jitted fn; returns per-rep seconds via slope."""
     times = {}
     for r in reps:
@@ -42,7 +48,7 @@ def slope_time(make_run, *args, reps=(R1, R2)):
         out = fn(*args)
         np.asarray(jax.tree.leaves(out)[0])  # warm/compile
         best = 1e9
-        for _ in range(3):
+        for _ in range(tries):
             t0 = time.perf_counter()
             out = fn(*args)
             np.asarray(jax.tree.leaves(out)[0])
@@ -265,6 +271,100 @@ def bench_q40_shapes():
         pq.q40_expert_matmul.clear_cache()
 
 
+# (E, d, n) of an expert projection, rows a tile, tiles a wave, distinct
+# experts used, rows an expert's group holds under an EVEN router (the
+# chunk's 256 rows x top-k / the router's width): the grouped call of a
+# 256-row chunk (bf16 feed) as `_pair_layout` lays it out. Which order
+# each takes is `_unpacks_once`'s to say (the table's `own order`)
+Q40_ORDER_SHAPES = {
+    "kimi gate": ((64, 1024, 2304), 8, 128, 8, 8),
+    "kimi down": ((64, 2304, 1024), 8, 128, 8, 8),
+    "sarvam gate": ((16, 2048, 4096), 16, 32, 4, 16),
+    "sarvam down": ((16, 4096, 2048), 16, 32, 4, 16),
+    "granite gate": ((36, 768, 4096), 64, 56, 8, 36),
+    "granite down": ((36, 4096, 768), 64, 56, 8, 36),
+    "mixtral gate": ((8, 14336, 4096), 64, 16, 4, 64),
+}
+# row tiles an expert's group holds; "even": EVERY expert used, its group
+# a Poisson draw round the even router's share (at a share of one tile:
+# 59 % of the groups one tile, 40 % two), which is what a deployment's
+# trained router is nearest to
+Q40_ORDER_TILES = (1, 2, 3, 5, "even")
+
+
+def bench_q40_orders():
+    """us a call of `q40_expert_matmul` with row tiles outermost | with
+    weight blocks outermost and the block kept dequantised (stationary), at
+    1, 2, 3 and 5 row tiles an expert and at the tiles an even router
+    gives: the table `ops/pallas_q40._unpacks_once` was set from (PERF.md
+    section 6, PR 49). Same method as bench_q40_shapes: calls chained in one program, the same program with
+    NO tile used subtracted; the tiles' experts and the used count are
+    arguments, so one executable serves a shape's every row."""
+    from distributed_llama_tpu.ops import pallas_q40 as pq
+
+    own = pq._unpacks_once
+    rng = np.random.default_rng(0)
+    bf16 = jnp.bfloat16
+    print(f"{jax.devices()[0].device_kind}")
+    print("shape | E, d, n | tile rows | refetch / expert bytes | own order | "
+          "tiles an expert | used tiles | rows-outer us | stationary us | "
+          "us a used tile | bit-equal")
+    try:
+        for name, ((n_e, d, n), tile, n_tiles, groups, even) in (
+                Q40_ORDER_SHAPES.items()):
+            w = _q40_wide_scales(rng, n_e, d, n)
+            x = jnp.asarray(rng.standard_normal((n_tiles * tile, n)), bf16)
+            m = n // 2
+            td = pq._tile_d(d, m)
+            # what the stationary order re-fetches, a tile's activations
+            # once a weight block, over the expert's own packed bytes
+            ratio = (-(-d // td) * tile * (2 * m + m // 16) * 4
+                     / (d * (m + 2 * (m // 16))))
+
+            def call(x, w, e, u):
+                return pq.q40_expert_matmul(x, w, e, u, out_dtype=bf16,
+                                            token_rows=256)
+
+            def body(x, weu):
+                y = call(x, *weu)[:1, :1]
+                return x + jnp.where(jnp.isfinite(y), y, 0) * bf16(1e-9)
+
+            for per in Q40_ORDER_TILES:
+                if per == "even":
+                    of = np.repeat(np.arange(n_e),
+                                   -(-rng.poisson(even, n_e) // tile))
+                    used = min(len(of), n_tiles)
+                    per = f"even ({used / len(np.unique(of[:used])):.2f})"
+                else:
+                    used = min(groups * per, n_tiles)
+                    of = np.repeat(np.sort(rng.choice(
+                        n_e, -(-used // per), replace=False)), per)
+                e = np.full(n_tiles, n_e - 1, np.int32)
+                e[:used] = of[:used]
+                e = jnp.asarray(e)
+                us, out = {}, {}
+                for stationary in (False, True):
+                    pq._unpacks_once = lambda *a, s=stationary: s
+                    pq.q40_expert_matmul.clear_cache()
+                    # 20-900 us a call: many repetitions, the best of many
+                    t = [slope_time(lambda r: _outer(body, r),
+                                    (w, e, jnp.int32(u)), x,
+                                    reps=(16, 128), tries=7)
+                         for u in (used, 0)]
+                    us[stationary] = (t[0] - t[1]) * 1e6
+                    out[stationary] = np.asarray(
+                        call(x, w, e, jnp.int32(used))[:used * tile],
+                        np.float32)
+                print(f"{name} | {n_e}, {d}, {n} | {tile} | {ratio:.2f} | "
+                      f"{'stationary' if own(tile, 256) else 'rows-outer'}"
+                      f" | {per} | {used} | {us[False]:.1f} | {us[True]:.1f} | "
+                      f"{us[False] / used:.2f} -> {us[True] / used:.2f} | "
+                      f"{np.array_equal(out[False], out[True])}", flush=True)
+    finally:
+        pq._unpacks_once = own
+        pq.q40_expert_matmul.clear_cache()
+
+
 ALL = {
     "gemv": bench_gemv_dense,
     "gemv_q40": bench_gemv_q40,
@@ -272,10 +372,12 @@ ALL = {
     "attn": bench_attn,
     "cache": bench_cache,
     "q40_shapes": bench_q40_shapes,
+    "q40_orders": bench_q40_orders,
 }
 
 if __name__ == "__main__":
     which = sys.argv[1] if len(sys.argv) > 1 else "all"
     for name, fn in ALL.items():
-        if which in ("all", name):
+        if which in ("all", name) or (which, name) == ("q40_shapes",
+                                                       "q40_orders"):
             fn()
