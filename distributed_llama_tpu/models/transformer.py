@@ -931,8 +931,12 @@ def _grouped_experts(xb, lw, spec: ModelSpec, cfg, held, live, member, sizes,
     (`wave`), and a step whose routing needs more runs again over the next
     tiles: one wave where every expert is held (Mixtral, Grok-1: the two
     numbers are one), seldom a second where a share of a wide router is (a
-    wave is then an eighth of what any routing needs, and everything
-    outside the kernel costs by the buffer's rows).
+    wave is then an eighth of what any routing needs, and what XLA does to
+    the buffer costs by its rows). The gate and up projections never see
+    the buffer filled: they take the TOKEN rows and the wave's slice of
+    `src`, and the kernel gathers a used tile's rows itself
+    (q40_expert_matmul's `src`), so their input is prepared once a token
+    row; `hb` is born in the layout and the down projection reads it so.
 
     A pair's three projections and its Q80 round trips are a row's own, so
     a live token's contributions carry the bits the all-experts loop gives
@@ -962,10 +966,12 @@ def _grouped_experts(xb, lw, spec: ModelSpec, cfg, held, live, member, sizes,
         grouped = dict(cfg, used=jnp.clip(used - first, 0, wave),
                        token_rows=rows)
         experts = lax.dynamic_slice(tile_expert, (first,), (wave,))
-        x_w = x[lax.dynamic_slice(src, (first * tile,), (wave * tile,))]
-        once = dict(grouped, activation_q80=False)
-        gate = fused_expert_matmul(x_w, lw["moe_gate"], experts, **once)
-        up = fused_expert_matmul(x_w, lw["moe_up"], experts, **once)
+        # gate and up read the TOKEN rows: the call gathers a used tile's
+        # rows itself, by the wave's slice of src
+        once = dict(grouped, activation_q80=False, src=lax.dynamic_slice(
+            src, (first * tile,), (wave * tile,)))
+        gate = fused_expert_matmul(x, lw["moe_gate"], experts, **once)
+        up = fused_expert_matmul(x, lw["moe_up"], experts, **once)
         hb = apply_hidden_act(gate, spec.hidden_act) * up
         out = fused_expert_matmul(hb, lw["moe_down"], experts, **grouped)
         # a pair outside this wave, or dead, adds nothing: its row may

@@ -162,6 +162,7 @@ def fused_expert_matmul(
     *,
     used=None,              # i32 leading row tiles that hold a live row
     token_rows: int | None = None,
+    src=None,               # i32 row of x each tile row is gathered from
     activation_q80: bool = False,
     compute_dtype=jnp.float32,
     use_pallas: bool = False,
@@ -174,10 +175,11 @@ def fused_expert_matmul(
 ):
     """Expert-indexed matmul against a stacked (E, d, n) Q40 weight without
     materializing any expert's slice (ops/pallas_q40.q40_expert_matmul,
-    which says what `e`, `used` and `token_rows` are): the grouped path of
-    models/transformer._moe_ffn comes here once a projection with the
-    step's pair rows laid out in row tiles; a scalar `e` is one expert for
-    every row of x.
+    which says what `e`, `used`, `token_rows` and `src` are): the grouped
+    path of models/transformer._moe_ffn comes here once a projection, with
+    the step's token rows and the index that lays them out in row tiles
+    (gate, up) or with pair rows already laid out (down); a scalar `e` is
+    one expert for every row of x.
 
     Returns None when reads_experts_in_place says the stack is not the
     kernel's (`token_rows`, else x's own rows, against MAX_T)."""
@@ -196,4 +198,4 @@ def fused_expert_matmul(
     return q40_expert_matmul(x.astype(compute_dtype), w, e, used,
                              out_dtype=compute_dtype,
                              interpret=pallas_interpret,
-                             token_rows=token_rows)
+                             token_rows=token_rows, src=src)

@@ -305,12 +305,28 @@ def _kernel(x_lo_ref, x_hi_ref, xsum_ref, packed_ref, scales_ref, *rest,
         out_dtype=out_dtype, scales_u16=scales_u16, mxu_bf16=mxu_bf16)
 
 
-def _expert_kernel(tiles_ref, used_ref, x_lo_ref, x_hi_ref, xsum_ref,
-                   packed_ref, scales_ref, *rest, nb, out_dtype,
-                   scales_u16, mxu_bf16, stationary):
+def _gather_rows(src_ref, first_row, panels, gathered):
+    """Row r of each `gathered` scratch <- row src[first_row + r] of its
+    whole-array panel: a tile's activations of the gathered expert call
+    (_q40_call), one dynamic sublane load and one store a lane tile of a
+    row, the values `x[src]` would have laid out."""
+    for r in range(gathered[0].shape[0]):
+        row = pl.ds(src_ref[first_row + r], 1)
+        for panel, out in zip(panels, gathered):
+            out[r:r + 1, :] = panel[row, :]
+
+
+def _expert_kernel(tiles_ref, used_ref, *rest, nb, out_dtype, scales_u16,
+                   mxu_bf16, stationary, gathers):
     # the row tile of this grid step (_q40_call's two orders); its expert
     # is consumed by the index maps
     j = pl.program_id(1 if stationary else 0)
+    if gathers:
+        src_ref, *rest = rest
+        # read here: the interpreter has no program_id under a pl.when
+        first_block = pl.program_id(1) == 0
+    *x_refs, packed_ref, scales_ref = rest[:5]
+    rest = rest[5:]
     held = None
     if stationary:
         # the scratch comes last. Row tiles run innermost and a group's
@@ -322,13 +338,30 @@ def _expert_kernel(tiles_ref, used_ref, x_lo_ref, x_hi_ref, xsum_ref,
         after = jnp.minimum(j + 1, pl.num_programs(1) - 1)
         followed = (j + 1 < used_ref[0]) & (e_j == tiles_ref[after])
         held = (first, followed, wl_ref, wh_ref, s_ref)
+    if gathers:
+        *rest, g_lo, g_hi, g_sum = rest
 
     # a row tile past the used ones holds no live pair: its index maps name
     # the blocks already resident (_q40_call), and its body is skipped
     @pl.when(j < used_ref[0])
     def _():
+        feed = x_refs
+        if gathers:
+            # the panels hold the TOKEN rows, whole; the tile's rows are
+            # gathered from them when the grid reaches the tile: once a
+            # tile where row tiles run outermost (its weight blocks stream
+            # past what block 0 gathered), once a step where they run
+            # innermost (one tile after the other against one block)
+            feed = (g_lo, g_hi, g_sum)
+            gather = functools.partial(
+                _gather_rows, src_ref, j * g_lo.shape[0], x_refs, feed)
+            if stationary:
+                gather()
+            else:
+                pl.when(first_block)(gather)
+
         _subtiled_write(
-            x_lo_ref[:], x_hi_ref[:], xsum_ref[:],
+            *(ref[:] for ref in feed),
             lambda sl: packed_ref[0, sl, :], lambda sl: scales_ref[0, sl, :],
             rest, held=held,
             out_dtype=out_dtype, scales_u16=scales_u16, mxu_bf16=mxu_bf16)
@@ -470,7 +503,7 @@ def _unpacks_once(tm: int, token_rows: int) -> bool:
 
 
 def _q40_call(x, w: QuantizedTensor, e, out_dtype, interpret, used=None,
-              token_rows=None):
+              token_rows=None, src=None):
     """The pallas_call both entry points share: `w` is one (d, m) packed
     weight (e None, the `_kernel` body) or an (E, d, m) stack read through
     the scalar-prefetch operands of the blocks' index maps
@@ -478,7 +511,15 @@ def _q40_call(x, w: QuantizedTensor, e, out_dtype, interpret, used=None,
     tile of all rows where it is a scalar), `used` how many leading tiles
     hold a live row. Everything else — the activation split, the output
     tile, the blocks' memory space, the scoped-VMEM request — is one
-    decision for both."""
+    decision for both.
+
+    `src` (an expert call's alone) makes the call GATHER: x holds the
+    step's token rows and row r of the call is x[src[r]]. The split below
+    runs over the token rows, once; the panels stay whole in VMEM under a
+    constant index map, src rides in with the prefetched scalars, and a
+    used tile copies its rows out of the panels into scratch
+    (_expert_kernel): the values, and so the bits, of x[src] laid out
+    beforehand (rounded to x's dtype, as an array in HBM is)."""
     d, m = w.packed.shape[-2:]
     nb = m // 16
     n = nb * 32
@@ -487,7 +528,17 @@ def _q40_call(x, w: QuantizedTensor, e, out_dtype, interpret, used=None,
     t = 1
     for s in lead:
         t *= s
-    x_lo, x_hi = _split_activation(x.reshape(t, n).astype(jnp.float32), nb)
+    xf = x.reshape(t, n).astype(jnp.float32)
+    if src is not None and x.dtype != jnp.float32:
+        # x[src] was an array of x's dtype in HBM. Without it the TPU
+        # compiler fuses x's producer (the Q80 round trip's bf16 product)
+        # into the cast above and keeps the product's float32 bits
+        # (xla_allow_excess_precision): read on the chip, granite's check
+        # moved from 0.062543589 to 0.061926940 (PERF.md section 6, PR 50).
+        # The panels hold the values the gathered rows held
+        kind = jnp.finfo(x.dtype)
+        xf = jax.lax.reduce_precision(xf, kind.nexp, kind.nmant)
+    x_lo, x_hi = _split_activation(xf, nb)
     xsum = (x_lo + x_hi).reshape(t, 16, nb).sum(axis=1)  # (t, nb) per-block sums
 
     td = _tile_d(d, m)
@@ -506,6 +557,7 @@ def _q40_call(x, w: QuantizedTensor, e, out_dtype, interpret, used=None,
     block = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
     n_i = pl.cdiv(d, td)
     scratch = []
+    out_rows = t if src is None else src.shape[0]
     if e is None:
         kernel, name, prefetched = _kernel, "q40_matmul", ()
         tm, grid = t, (n_i,)
@@ -517,9 +569,12 @@ def _q40_call(x, w: QuantizedTensor, e, out_dtype, interpret, used=None,
         n_tiles = tiles.shape[0]
         prefetched = (tiles, jnp.atleast_1d(
             n_tiles if used is None else used).astype(jnp.int32))
-        tm = t // n_tiles
+        if src is not None:
+            prefetched += (src.astype(jnp.int32),)
+        tm = out_rows // n_tiles
         stationary = _unpacks_once(tm, t if token_rows is None else token_rows)
-        kernel = functools.partial(_expert_kernel, stationary=stationary)
+        kernel = functools.partial(_expert_kernel, stationary=stationary,
+                                   gathers=src is not None)
 
         # Row tiles outermost: a tile's activations are fetched once and
         # its expert's weight blocks stream past them. A tile past the used
@@ -532,7 +587,7 @@ def _q40_call(x, w: QuantizedTensor, e, out_dtype, interpret, used=None,
         # and a tile's activations are fetched once a block; a tile past the
         # used ones names block i of the last used tile
         def at(*step):
-            *step, tiles_ref, used_ref = step
+            step, (tiles_ref, used_ref) = step[:2], step[2:4]
             j, i = reversed(step) if stationary else step
             live = used_ref[0] > 0 if stationary else j < used_ref[0]
             j = jnp.maximum(jnp.minimum(j, used_ref[0] - 1), 0)
@@ -543,26 +598,33 @@ def _q40_call(x, w: QuantizedTensor, e, out_dtype, interpret, used=None,
             return e_j, i, 0
 
         def x_at(*step):
-            return at(*step)[0], 0
+            return (at(*step)[0] if src is None else 0), 0
 
         def out_at(*step):
             return at(*step)[:2]
 
         grid = (n_tiles, n_i)
+        if src is not None:
+            scratch = [pltpu.VMEM((tm, m), jnp.float32),
+                       pltpu.VMEM((tm, m), jnp.float32),
+                       pltpu.VMEM((tm, nb), jnp.float32)]
         if stationary:
             grid = grid[::-1]
             wide = jnp.bfloat16 if mxu_bf16 else jnp.float32
-            scratch = [pltpu.VMEM((td, m), wide), pltpu.VMEM((td, m), wide),
-                       pltpu.VMEM((td, nb), jnp.float32)]
+            scratch += [pltpu.VMEM((td, m), wide), pltpu.VMEM((td, m), wide),
+                        pltpu.VMEM((td, nb), jnp.float32)]
         w_block = (1, td)
     spread = _spread_matrix(nb, 2 if scales_u16 else 3)
     consts = () if spread is None else (spread,)
+    # rows of an activation block: the call's row tile, or every token row
+    # where the tile's rows are gathered in the kernel
+    xm = tm if src is None else t
     specs = dict(
         grid=grid,
         in_specs=[
-            block((tm, m), x_at),
-            block((tm, m), x_at),
-            block((tm, nb), x_at),
+            block((xm, m), x_at),
+            block((xm, m), x_at),
+            block((xm, nb), x_at),
             block((*w_block, m), w_at),
             block((*w_block, nb), w_at),
             *(block(c.shape, lambda *_: (0, 0)) for c in consts),
@@ -576,19 +638,21 @@ def _q40_call(x, w: QuantizedTensor, e, out_dtype, interpret, used=None,
             num_scalar_prefetch=len(prefetched), **specs))
 
     # the activation panels: whole and fetched once in the dense call, one
-    # row tile double-buffered in the expert call; and the stationary
+    # row tile (every token row where the call gathers) double-buffered in
+    # the expert call; and the scratch: a gathered tile, the stationary
     # call's dequantised block (a scale row fills a lane tile)
-    panels = 4 * tm * (2 * m + nb) * (1 if e is None else 2)
+    panels = 4 * xm * (2 * m + nb) * (1 if e is None else 2)
     held = sum(math.prod((*b.shape[:-1], -(-b.shape[-1] // LANES) * LANES))
                * b.dtype.itemsize for b in scratch)
     out = pl.pallas_call(
         functools.partial(kernel, nb=nb, out_dtype=out_dtype,
                           scales_u16=scales_u16, mxu_bf16=mxu_bf16),
         **specs,
-        out_shape=jax.ShapeDtypeStruct((t, d), out_dtype),
+        out_shape=jax.ShapeDtypeStruct((out_rows, d), out_dtype),
         cost_estimate=pl.CostEstimate(
-            flops=2 * t * d * n,
-            bytes_accessed=d * m + d * nb * 2 + 2 * t * m * 4 + t * d * 4,
+            flops=2 * out_rows * d * n,
+            bytes_accessed=(d * m + d * nb * 2 + 2 * t * m * 4
+                            + out_rows * d * 4),
             transcendentals=0,
         ),
         compiler_params=pltpu.CompilerParams(
@@ -597,7 +661,7 @@ def _q40_call(x, w: QuantizedTensor, e, out_dtype, interpret, used=None,
         name=name,
     )(*prefetched, x_lo, x_hi, xsum, w.packed, scales, *consts)
 
-    return out.reshape(*lead, d)
+    return out if src is not None else out.reshape(*lead, d)
 
 
 @functools.partial(jax.jit, static_argnames=("out_dtype", "interpret"))
@@ -649,6 +713,7 @@ def q40_expert_matmul(
     out_dtype=jnp.float32,
     interpret: bool = False,
     token_rows: int | None = None,
+    src: jnp.ndarray | None = None,   # i32 row of x each row is gathered from
 ) -> jnp.ndarray:
     """y[r, d] = sum_n x[r, n] * W[e[r // tile], d, n]: every routed expert
     matmul of models/transformer._moe_ffn on a plain single-shard stack,
@@ -668,6 +733,15 @@ def q40_expert_matmul(
     `token_rows`, the PROGRAM's token rows, decides the operand feed
     (_q40_call) whatever the pair rows number.
 
+    With `src` (len(e) x tile,) the call lays the rows out ITSELF: x is
+    the step's token rows (rows, n), row r of the layout above is x[src[r]]
+    and y has src's rows. What XLA does to the activations before the
+    kernel (the float32 split into the packed lane order, the block sums)
+    then runs over the token rows once, not over a pair buffer whose size
+    is the program's and not the traffic's, and a tile past `used` gathers
+    nothing. The gate and up projections, whose input is a token's own
+    row, come so; the down projection's input is born in the layout.
+
     The tiles' experts ride in as scalar-prefetch operands and the block
     index maps offset straight into the (E, d, m) HBM stack, so the kernel
     reads the expert's packed bytes IN PLACE. The alternative —
@@ -677,4 +751,4 @@ def q40_expert_matmul(
     the matmul reads it (36 % of mixtral-8x7b-12l's device time, PERF.md
     section 6, PR 31).
     """
-    return _q40_call(x, w, e, out_dtype, interpret, used, token_rows)
+    return _q40_call(x, w, e, out_dtype, interpret, used, token_rows, src)

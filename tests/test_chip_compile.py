@@ -149,17 +149,24 @@ def test_q40_expert_matmul_compiles_at_mixtral_widths(one_chip, d, n, t):
 @pytest.mark.parametrize("model,d,n", [
     ("mixtral_8x7b", 14336, 4096), ("mixtral_8x7b", 4096, 14336),
     ("sarvam_105b_ep8", 2048, 4096), ("sarvam_105b_ep8", 4096, 2048),
-    ("kimi_linear_48b_ep4", 1024, 2304), ("kimi_linear_48b_ep4", 2304, 1024)])
+    ("kimi_linear_48b_ep4", 1024, 2304), ("kimi_linear_48b_ep4", 2304, 1024),
+    ("granite_4_h_small_ep2", 768, 4096),
+    ("granite_4_h_small_ep2", 4096, 768)])
 def test_grouped_expert_tiles_compile(one_chip, model, d, n, rows):
-    """`q40_expert_matmul` over the row tiles `_pair_layout` gives three MoE
+    """`q40_expert_matmul` over the row tiles `_pair_layout` gives four MoE
     configurations' two step programs (Mixtral: 8 tiles of 8 rows and 16
     of 64; sarvam-105b-ep8: 16 of 8 and a wave of 32 of 16;
-    kimi-linear-48b-a3b-ep4: 64 of 8 and a wave of 128 of 8), each tile's
+    kimi-linear-48b-a3b-ep4: 64 of 8 and a wave of 128 of 8;
+    granite-4.0-h-small-ep2: 36 of 8 and a wave of 56 of 64), each tile's
     expert and the used count prefetched, under the operand feed the
     program's token rows decide. kimi's chunk calls (8-row tiles) run
     STATIONARY (`_unpacks_once`): their dequantised block, 5.2 MB of
     scratch at its gate's (1024, 1152) tile, compiles within the scoped
-    VMEM `_q40_call` asks for."""
+    VMEM `_q40_call` asks for. The gate / up shape (a `dim`-wide input)
+    is called as `_grouped_experts` calls it since PR 50: the program's
+    TOKEN rows and the row index `src` prefetched, the two whole float32
+    panels (2 x 2 MB at 256 rows of 4096) resident beside the tile the
+    kernel gathers into scratch; the down shape with its rows laid out."""
     import rehearse_chip_compile as r
     from rehearse_chip_compile import q40_struct
 
@@ -173,12 +180,14 @@ def test_grouped_expert_tiles_compile(one_chip, model, d, n, rows):
     assert _unpacks_once(tile, rows) == (
         rows == 256 and model == "kimi_linear_48b_ep4")
     w = _placed(q40_struct(spec.n_experts, d, n), one_chip)
-    x = _struct((wave * tile, n), BF16, one_chip)
+    gathers = n == spec.dim
+    x = _struct((rows if gathers else wave * tile, n), BF16, one_chip)
     e = _struct((wave,), jnp.int32, one_chip)
     used = _struct((), jnp.int32, one_chip)
-    c = jax.jit(lambda x, w, e, used: q40_expert_matmul(
-        x, w, e, used, out_dtype=BF16, token_rows=rows)).lower(
-            x, w, e, used).compile()
+    src = _struct((wave * tile,), jnp.int32, one_chip)
+    c = jax.jit(lambda x, w, e, used, src: q40_expert_matmul(
+        x, w, e, used, out_dtype=BF16, token_rows=rows,
+        src=src if gathers else None)).lower(x, w, e, used, src).compile()
     assert _has_kernel(c)
 
 

@@ -9,6 +9,7 @@ the kernel's skipped tiles) is tests/test_pallas_q40.py's.
 
 import hashlib
 import os
+import re
 import sys
 
 import jax
@@ -403,18 +404,25 @@ PARENT_TEXT = {
     #      /root/scratch/kernel_only/distributed_llama_tpu/ops/
     #   cd /root/scratch/kernel_only && JAX_PLATFORMS=cpu python -m pytest \
     #      tests/test_grouped_experts.py -q -k lowers   # 24 passed
+    # PR 50 re-pinned the eight WITH kernels, both programs: gate and up
+    # hand `q40_expert_matmul` the token rows and the wave's slice of `src`
+    # (a third prefetched scalar), the XLA gather `x[src]` and the float32
+    # split over the pair buffer's rows left, and the kernel's body (in the
+    # text here: interpret mode) copies a used tile's rows out of the
+    # token rows' panels. The four without kernels kept theirs, and
+    # test_gate_and_up_read_token_rows_not_a_pair_buffer reads what entered.
     ("MIXTRAL", False, "decode"): "2abb1048c22a432a",
     ("MIXTRAL", False, "prefill"): "7ad217f81ca8a97f",
-    ("MIXTRAL", "repeat", "decode"): "f14b9fd15f1fdd5c",
-    ("MIXTRAL", True, "decode"): "c8b878c4c7e736a5",
-    ("MIXTRAL", "repeat", "prefill"): "7a2f39da71cb55c3",
-    ("MIXTRAL", True, "prefill"): "715b745902641e35",
+    ("MIXTRAL", "repeat", "decode"): "80a5d40f59d0f525",
+    ("MIXTRAL", True, "decode"): "9f262997318c5350",
+    ("MIXTRAL", "repeat", "prefill"): "79ab96f8f232e842",
+    ("MIXTRAL", True, "prefill"): "17df985e8555ceba",
     ("SARVAM_MLA", False, "decode"): "7870fd495c410b5b",
     ("SARVAM_MLA", False, "prefill"): "f48f7070111d603d",
-    ("SARVAM_MLA", "repeat", "decode"): "b48b5b664e87e75b",
-    ("SARVAM_MLA", True, "decode"): "816d518e01fac15d",
-    ("SARVAM_MLA", "repeat", "prefill"): "8f5099986a1338e6",
-    ("SARVAM_MLA", True, "prefill"): "a85e68be762199a8",
+    ("SARVAM_MLA", "repeat", "decode"): "7810bc13f07aa5f3",
+    ("SARVAM_MLA", True, "decode"): "caed4b59d21809c1",
+    ("SARVAM_MLA", "repeat", "prefill"): "af5dbb03f6b73cf9",
+    ("SARVAM_MLA", True, "prefill"): "2e8a9270a790e031",
 }
 TINY_SPECS = {
     "LLAMA": tiny_spec, "OLMO_HYBRID": tiny_hybrid_spec,
@@ -495,9 +503,52 @@ def test_a_model_with_experts_lowers_to_the_parents_step_programs(
     """MIXTRAL and SARVAM_MLA engines, the benchmark's other two
     architectures, lower to the SAME TEXT as before the block of a layer
     was chosen by what the spec says (norm placement, FFN kind, the four
-    multipliers): at multipliers of 1 nothing enters their programs. (One
-    thing has since, on purpose, in the chunk programs: PR 49's third
-    counter, PARENT_TEXT.)"""
+    multipliers): at multipliers of 1 nothing enters their programs. (Two
+    things have since, on purpose: PR 49's third counter in the chunk
+    programs, PR 50's gathered gate and up in both programs with kernels,
+    PARENT_TEXT.)"""
     text = lowered_steps(arch, kernels)[program].as_text()
     assert (hashlib.sha256(text.encode()).hexdigest()[:16]
             == PARENT_TEXT[arch, kernels, program]), (arch, kernels, program)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("arch", ["MIXTRAL", "SARVAM_MLA"])
+def test_gate_and_up_read_token_rows_not_a_pair_buffer(lowered_steps, arch,
+                                                       program):
+    """In the lowered step programs of an engine with experts and kernels
+    no XLA op lays the token rows out in the pair buffer (PR 50): no gather
+    yields `wave x tile` rows of `d` values, no float32 split into the
+    packed lane order runs over that many `d`-wide rows. Gate and up call
+    ONE function on the layer's token rows and the wave's slice of `src`
+    (the row index rides in with the prefetched scalars), whose body splits
+    `rows x d` once: the two calls of a layer name the same rows, so the
+    compiler keeps one split a layer. The down projection's input is born
+    in the buffer and keeps its split over the buffer's rows."""
+    spec = TINY_SPECS[arch]()
+    rows = B * (1 if program == "decode" else CHUNK)
+    tile, _, wave = _pair_layout(spec, rows)
+    pairs, d, h = wave * tile, spec.dim, spec.hidden_dim
+    assert pairs > rows
+    text = lowered_steps(arch, True)[program].as_text()
+
+    gathered = re.findall(r'"stablehlo\.gather".*-> tensor<([0-9x]+)xf32>',
+                          text)
+    assert gathered and f"{pairs}x{d}" not in gathered
+    split = r"stablehlo\.reshape.*-> tensor<%dx%dx2x16xf32>"
+    assert not re.findall(split % (pairs, d // 32), text)
+
+    takes = (rf"\(tensor<{rows}x{d}xf32>, [^)]*tensor<{wave}xi32>, "
+             rf"tensor<i32>, tensor<{pairs}xi32>\) -> tensor<{pairs}x{h}xf32>")
+    calls = re.findall(rf"call @(q40_expert_matmul\w*)\((%\w+),.*: {takes}",
+                       text)
+    moe_layers = text.count("call @q40_expert_matmul") // 3
+    assert moe_layers >= 2 and len(calls) == 2 * moe_layers
+    assert len({name for name, _ in calls}) == 1
+    # gate and up of a layer: the same function of the same token rows
+    assert all(a == b for a, b in zip(calls[::2], calls[1::2]))
+    body = text.split(f"func.func private @{calls[0][0]}(")[1].split(
+        "func.func")[0]
+    assert len(re.findall(split % (rows, d // 32), body)) == 1
+    # what this leaves: the down projection's split, a buffer row at a time
+    assert len(re.findall(split % (pairs, h // 32), text)) == 1

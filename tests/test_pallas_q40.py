@@ -662,6 +662,126 @@ def test_stationary_order_is_bit_equal_to_rows_outermost(rng, monkeypatch,
                                       np.asarray(own, np.float32))
 
 
+# (E, d, n) at tiny widths: three 128-row weight blocks, so that with row
+# tiles outermost a tile's gathered rows serve the blocks after the first
+_GATHER_W = (4, 384, 64)
+# experts of the five tiles: two runs of two tiles (the stationary order
+# keeps a run's block dequantised) and one of one
+_GATHER_TILES = [0, 0, 2, 3, 3]
+GATHER_FEEDS = {"f32": (12, jnp.float32), "bf16": (256, jnp.bfloat16)}
+
+
+@pytest.fixture(scope="module")
+def gathered_calls():
+    """(x, src, pre_laid, gathered) by (row tile, order, feed): the token
+    rows, the row index, and the grouped call traced ONCE in the forced
+    order with its rows laid out by XLA (`x[src]` handed to the call the
+    parent had) and gathered by the kernel (`src=`), both taking `used`."""
+    from distributed_llama_tpu.ops import pallas_q40 as q
+
+    made = {}
+
+    def calls(tm, stationary, feed):
+        key = tm, stationary, feed
+        if key in made:
+            return made[key]
+        rng = np.random.default_rng([tm, stationary, feed == "bf16"])
+        rows, dtype = GATHER_FEEDS[feed]
+        n_e, d, n = _GATHER_W
+        w = QuantizedTensor(
+            jnp.asarray(rng.integers(0, 256, (n_e, d, n // 2),
+                                     dtype=np.uint8)),
+            jnp.asarray(_wide_scales(rng, "u16", False, n_e, d, n // 32)))
+        x = jnp.asarray(rng.standard_normal((rows, n), dtype=np.float32),
+                        dtype)
+        src = rng.integers(0, rows, len(_GATHER_TILES) * tm)
+        # a ragged last tile as _pair_tiles lays one out (token 0 where no
+        # pair lands), and one token's row in two tiles
+        src[-tm // 2:] = 0
+        src[1] = src[2 * tm + 3] = 5
+        src = jnp.asarray(src, jnp.int32)
+        e = jnp.asarray(_GATHER_TILES, jnp.int32)
+
+        def call(gathers, used):
+            return q.q40_expert_matmul(
+                x if gathers else x[src], w, e, used, out_dtype=dtype,
+                interpret=True, token_rows=rows,
+                src=src if gathers else None)
+
+        fns = [jax.jit(lambda used, g=g: call(g, used)) for g in (0, 1)]
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(q, "_unpacks_once", lambda *a: stationary)
+            q.q40_expert_matmul.clear_cache()  # traced by shape alone
+            for fn in fns:
+                fn(jnp.int32(0))
+            q.q40_expert_matmul.clear_cache()
+        made[key] = (x, src, *fns)
+        return made[key]
+
+    yield calls
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("used", [0, 1, 3, 5],
+                         ids=["none", "one", "some", "all"])
+@pytest.mark.parametrize("feed", sorted(GATHER_FEEDS))
+@pytest.mark.parametrize("stationary", [False, True],
+                         ids=["rows_outermost", "stationary"])
+@pytest.mark.parametrize("tm", [8, 16, 64])
+def test_gathered_call_is_bit_equal_to_rows_laid_out_beforehand(
+        gathered_calls, tm, stationary, feed, used):
+    """`q40_expert_matmul(x, ..., src=src)`, which copies a used tile's
+    rows out of the token rows' panels inside the kernel, against the call
+    the parent had on `x[src]` laid out by XLA: every row of the used tiles
+    to the last bit, in both orders of the grid (three weight blocks: with
+    row tiles outermost what block 0 gathered serves the other two), under
+    the float32 feed (12 token rows) and the bf16 one (256), with a ragged
+    last tile, a token whose row two tiles hold and runs of two tiles an
+    expert; rows past `used` are never written (the interpreter marks
+    memory nobody wrote with NaN), and with none used nothing is."""
+    x, src, pre_laid, gathered = gathered_calls(tm, stationary, feed)
+    want = np.asarray(pre_laid(jnp.int32(used)), np.float32)
+    got = np.asarray(gathered(jnp.int32(used)), np.float32)
+    assert got.shape == want.shape == (len(_GATHER_TILES) * tm,
+                                       _GATHER_W[1])
+    live = used * tm
+    assert np.isnan(got[live:]).all() and np.isnan(want[live:]).all()
+    assert np.isfinite(want[:live]).all()
+    np.testing.assert_array_equal(got[:live], want[:live])
+    if used:
+        assert np.abs(want[:live]).max() > 0.5
+
+
+@pytest.mark.parametrize("dtype,bits", [(jnp.bfloat16, (8, 7)),
+                                        (jnp.float32, None)],
+                         ids=["bf16", "f32"])
+def test_gathered_panels_hold_the_rows_own_precision(rng, dtype, bits):
+    """`x[src]` was an array of x's dtype in HBM; the gathered call's
+    float32 panels must hold those values. On the chip the compiler fuses
+    x's producer (the Q80 round trip's bf16 product) into the cast to
+    float32 and keeps the product's float32 bits unless the call rounds
+    them itself (granite's check read 0.061926940 for the parent's
+    0.062543589, PERF.md section 6, PR 50): the traced call rounds bf16
+    rows with `reduce_precision`, which no compiler removes, adds nothing
+    for float32 rows, and nothing to a call that is handed its rows."""
+    from distributed_llama_tpu.ops.pallas_q40 import q40_expert_matmul
+
+    _, w = _stack(rng, 4, 128, 64)
+    x = jnp.zeros((8, 64), dtype)
+    e, src = jnp.zeros((2,), jnp.int32), jnp.zeros((16,), jnp.int32)
+
+    def rounds(x, src):
+        jaxpr = jax.make_jaxpr(lambda x, src: q40_expert_matmul(
+            x, w, e, None, out_dtype=dtype, interpret=True, token_rows=8,
+            src=src))(x, src)
+        return [(q.params["exponent_bits"], q.params["mantissa_bits"])
+                for q in jaxpr.eqns[0].params["jaxpr"].eqns
+                if q.primitive.name == "reduce_precision"]
+
+    assert rounds(x, src) == ([bits] if bits else [])
+    assert rounds(jnp.zeros((16, 64), dtype), None) == []
+
+
 def _wide_scales(rng, scales, one_exponent, *shape):
     """Scales over everything the spread has to place exactly: as f16 bits
     every value but inf / nan (negatives, subnormals, the largest normal,

@@ -17,7 +17,13 @@ of the configurations, row tiles outermost against weight blocks outermost
 with the block kept dequantised, at 1, 2, 3 and 5 row tiles an expert and
 at the tiles an even router gives (PERF.md section 6, PR 49).
 
-Usage: python tools/microbench.py [all|gemv|gemv_q40|gemv_pallas|attn|cache|q40_shapes|q40_orders]
+`q40_gather` (run by `q40_shapes` too) reads what the grouped call's own
+row gather costs a used tile and what it takes out of XLA: the gate
+projection of the five MoE cells' two step programs, token rows gathered in
+the kernel by `src` against `x[src]` laid out by XLA and handed over
+(PERF.md section 6, PR 50).
+
+Usage: python tools/microbench.py [all|gemv|gemv_q40|gemv_pallas|attn|cache|q40_shapes|q40_orders|q40_gather]
 """
 
 from __future__ import annotations
@@ -365,6 +371,75 @@ def bench_q40_orders():
         pq.q40_expert_matmul.clear_cache()
 
 
+# (E, d, n) of a gate projection, the program's token rows, rows a tile,
+# tiles a wave (`_pair_layout`), tiles used (a full decode batch whose rows
+# choose mostly distinct experts; a chunk under an even router): the
+# grouped gate call of the five MoE cells' decode step (float32 feed) and
+# 256-row chunk (bf16 feed)
+Q40_GATHER_SHAPES = {
+    "kimi decode": ((64, 1024, 2304), 8, 8, 64, 14),
+    "kimi chunk": ((64, 1024, 2304), 256, 8, 128, 96),
+    "granite decode": ((36, 768, 4096), 8, 8, 36, 24),
+    "granite chunk": ((36, 768, 4096), 256, 64, 56, 38),
+    "sarvam decode": ((16, 2048, 4096), 8, 8, 16, 7),
+    "sarvam chunk": ((16, 2048, 4096), 256, 16, 32, 24),
+    "mixtral decode": ((8, 14336, 4096), 8, 8, 8, 7),
+    "mixtral chunk": ((8, 14336, 4096), 256, 64, 16, 11),
+}
+
+
+def bench_q40_gather():
+    """us a used tile of the grouped gate call with its rows laid out by
+    XLA beforehand (`x[src]`, then the float32 split over the BUFFER's rows)
+    | gathered in the kernel from the token rows' panels, and what is left
+    of a call with NO tile used (XLA's part and the skipped grid steps) in
+    both: the second pair's difference is the XLA gather and split the
+    in-kernel gather replaces, the first pair's what it costs (PERF.md
+    section 6, PR 50). Same method as bench_q40_shapes: calls chained in
+    one program, tiles' experts, used count and row index as arguments."""
+    from distributed_llama_tpu.ops import pallas_q40 as pq
+
+    rng = np.random.default_rng(0)
+    bf16 = jnp.bfloat16
+    print(f"{jax.devices()[0].device_kind}")
+    print("shape | E, d, n | token rows | tile rows | tiles | used | "
+          "pre-laid us a used tile | gathered us a used tile | "
+          "pre-laid us a call, none used | gathered us a call, none used | "
+          "bit-equal")
+    for name, ((n_e, d, n), rows, tile, n_tiles, used) in (
+            Q40_GATHER_SHAPES.items()):
+        w = _q40_wide_scales(rng, n_e, d, n)
+        x = jnp.asarray(rng.standard_normal((rows, n)), bf16)
+        src = jnp.asarray(rng.integers(0, rows, n_tiles * tile), jnp.int32)
+        e = np.full(n_tiles, n_e - 1, np.int32)
+        e[:used] = np.sort(rng.choice(n_e, used, replace=used > n_e))
+        e = jnp.asarray(e)
+
+        def call(gathers, x, w, e, u, src):
+            return pq.q40_expert_matmul(
+                x if gathers else x[src], w, e, u, out_dtype=bf16,
+                token_rows=rows, src=src if gathers else None)
+
+        us, idle, out = {}, {}, {}
+        for gathers in (False, True):
+            def body(x, weus):
+                y = call(gathers, x, *weus)[:1, :1]
+                return x + jnp.where(jnp.isfinite(y), y, 0) * bf16(1e-9)
+
+            t = [slope_time(lambda r: _outer(body, r),
+                            (w, e, jnp.int32(u), src), x,
+                            reps=(16, 128), tries=7) for u in (used, 0)]
+            us[gathers] = (t[0] - t[1]) / used * 1e6
+            idle[gathers] = t[1] * 1e6
+            out[gathers] = np.asarray(call(
+                gathers, x, w, e, jnp.int32(used), src)[:used * tile],
+                np.float32)
+        print(f"{name} | {n_e}, {d}, {n} | {rows} | {tile} | {n_tiles} | "
+              f"{used} | {us[False]:.2f} | {us[True]:.2f} | "
+              f"{idle[False]:.1f} | {idle[True]:.1f} | "
+              f"{np.array_equal(out[False], out[True])}", flush=True)
+
+
 ALL = {
     "gemv": bench_gemv_dense,
     "gemv_q40": bench_gemv_q40,
@@ -373,11 +448,13 @@ ALL = {
     "cache": bench_cache,
     "q40_shapes": bench_q40_shapes,
     "q40_orders": bench_q40_orders,
+    "q40_gather": bench_q40_gather,
 }
 
 if __name__ == "__main__":
     which = sys.argv[1] if len(sys.argv) > 1 else "all"
     for name, fn in ALL.items():
-        if which in ("all", name) or (which, name) == ("q40_shapes",
-                                                       "q40_orders"):
+        if which in ("all", name) or (
+                which == "q40_shapes" and name in ("q40_orders",
+                                                   "q40_gather")):
             fn()
