@@ -26,6 +26,7 @@ from typing import Iterator
 import numpy as np
 
 from ..models.spec import ArchType, HiddenAct, LayerKind, ModelSpec
+from ..models.tensors import model_tensors
 from ..quants.types import BLOCK_SIZE, FloatType, batch_bytes
 from ..quants.numpy_codec import (
     dequantize_q40,
@@ -135,224 +136,22 @@ class HostTensor:
         raise ValueError(self.ftype)
 
 
+def to_q40_host(x: np.ndarray) -> HostTensor:
+    """A dense tensor quantised to Q40 on the host (a float file loaded in
+    q40 mode, synthetic weights)."""
+    scales, packed = quantize_q40(x.reshape(-1, x.shape[-1]))
+    return HostTensor("", FloatType.Q40, x.shape, scales=scales, packed=packed)
+
+
 def model_tensor_plan(spec: ModelSpec) -> Iterator[tuple[str, tuple[int, ...], FloatType]]:
-    """Yield (name, shape, ftype) in exact file order (ref: src/transformer.cpp:623-683).
+    """Yield (name, shape, ftype) in exact file order (ref:
+    src/transformer.cpp:623-683), walking the one declaration of what a
+    layer's tensors are (models/tensors.py).
 
     Shapes are (d, n) = (out_dim, in_dim) for matmul weights.
     """
-    wt = spec.weights_float_type
-    kinds = set(spec.layer_kinds)
-    if spec.is_mla:
-        yield from _latent_tensor_plan(spec)
-        return
-    if LayerKind.DELTA in kinds:
-        yield from _hybrid_tensor_plan(spec)
-        return
-    if LayerKind.SSM in kinds:
-        yield from _granite_tensor_plan(spec)
-        return
-    yield "tok_emb", (spec.vocab_size, spec.dim), FloatType.F32
-    for l in range(spec.n_layers):
-        p = f"layers.{l}."
-        yield p + "wq", (spec.dim, spec.dim), wt
-        yield p + "wk", (spec.kv_dim, spec.dim), wt
-        yield p + "wv", (spec.kv_dim, spec.dim), wt
-        yield p + "wo", (spec.dim, spec.dim), wt
-        if spec.is_moe:
-            yield p + "moe_router", (spec.n_experts, spec.dim), wt
-            for e in range(spec.n_experts):
-                yield p + f"experts.{e}.up", (spec.hidden_dim, spec.dim), wt
-                yield p + f"experts.{e}.gate", (spec.hidden_dim, spec.dim), wt
-                yield p + f"experts.{e}.down", (spec.dim, spec.hidden_dim), wt
-        else:
-            yield p + "w1", (spec.hidden_dim, spec.dim), wt
-            yield p + "w2", (spec.dim, spec.hidden_dim), wt
-            yield p + "w3", (spec.hidden_dim, spec.dim), wt
-        yield p + "rms_att", (spec.dim,), FloatType.F32
-        yield p + "rms_ffn", (spec.dim,), FloatType.F32
-        if spec.arch == ArchType.GROK1:
-            yield p + "rms_moe", (spec.dim,), FloatType.F32
-            yield p + "rms_ffn2", (spec.dim,), FloatType.F32
-    yield "rms_final", (spec.dim,), FloatType.F32
-    yield "wcls", (spec.vocab_size, spec.dim), wt
-
-
-def _latent_mixer_plan(spec: ModelSpec, p: str):
-    """A latent-attention mixer's four projections (_latent_tensor_plan's
-    docstring says what their rows are)."""
-    wt, d, h, r = (spec.weights_float_type, spec.dim, spec.n_heads,
-                   spec.kv_lora_rank)
-    yield p + "wq", (h * spec.head_size, d), wt
-    yield p + "wkva", (r + spec.qk_rope_head_dim, d), wt
-    yield p + "wkvb", (h * (spec.qk_nope_head_dim + spec.v_head_dim), r), wt
-    yield p + "wo", (d, h * spec.v_head_dim), wt
-
-
-def _latent_ffn_plan(spec: ModelSpec, l: int, p: str):
-    """What follows the mixer in a layer of SARVAM_MLA's block: the dense
-    FFN or the router, its bias, the held experts and the shared one."""
-    wt, d, hid = spec.weights_float_type, spec.dim, spec.hidden_dim
-    if spec.is_dense_layer(l):
-        yield p + "w1", (spec.dense_hidden_dim, d), wt
-        yield p + "w2", (d, spec.dense_hidden_dim), wt
-        yield p + "w3", (spec.dense_hidden_dim, d), wt
-        return
-    yield p + "moe_router", (spec.router_width, d), wt
-    yield p + "moe_bias", (spec.router_width,), FloatType.F32
-    for e in range(spec.n_experts):
-        yield p + f"experts.{e}.up", (hid, d), wt
-        yield p + f"experts.{e}.gate", (hid, d), wt
-        yield p + f"experts.{e}.down", (d, hid), wt
-    if spec.n_shared_experts:
-        sh = spec.n_shared_experts * hid
-        yield p + "sh_w1", (sh, d), wt
-        yield p + "sh_w2", (d, sh), wt
-        yield p + "sh_w3", (sh, d), wt
-
-
-def _latent_tensor_plan(spec: ModelSpec):
-    """The file order of a model whose attending layers are LATENT
-    (SARVAM_MLA: every layer; KIMI_LINEAR: beside DELTA layers of the KDA
-    mixer). A LATENT layer's mixer: wq (H x (d_n + d_r) rows), wkva (the
-    latent's r rows, then the rope key's d_r), wkvb (per head d_n key rows
-    then d_v value rows, over the latent), wo (over H x d_v). A DELTA (KDA)
-    layer's: wq wk (H x d_k rows), wv (H x d_v), the decay's low-rank pair
-    wf_a (d_k rows over the stream) and wf_b (H x d_k rows over those d_k),
-    wbeta (H rows), the output gate's pair wg_a (d_v rows) and wg_b (H x d_v
-    rows over them), wo (over H x d_v), then f32: conv_w (taps x [q ; k ; v]
-    channels: the three published convolutions side by side, tap j weighs
-    the row `taps - 1 - j` tokens back), a_log (H), dt_bias (H x d_k: a
-    channel) and rms_o (d_v). Both: w1 w2 w3 of the dense width (the leading
-    n_dense_layers) or moe_router (router_width rows), moe_bias (f32, used
-    for the choice only), the HELD experts' up gate down, and the shared
-    expert's sh_w1 (gate) sh_w2 (down) sh_w3 (up) at n_shared_experts x
-    hidden_dim; then rms_att and rms_ffn, and in a LATENT layer rms_kv (the
-    latent's norm), f32."""
-    wt, d = spec.weights_float_type, spec.dim
-    h, dk, dv = spec.lin_heads, spec.lin_k_head_dim, spec.lin_v_head_dim
-    yield "tok_emb", (spec.vocab_size, d), FloatType.F32
-    for l, kind in enumerate(spec.layer_kinds):
-        p = f"layers.{l}."
-        if kind == LayerKind.DELTA:
-            assert spec.lin_vector_decay, "the KDA mixer's tensors"
-            yield p + "wq", (h * dk, d), wt
-            yield p + "wk", (h * dk, d), wt
-            yield p + "wv", (h * dv, d), wt
-            yield p + "wf_a", (dk, d), wt
-            yield p + "wf_b", (h * dk, dk), wt
-            yield p + "wbeta", (h, d), wt
-            yield p + "wg_a", (dv, d), wt
-            yield p + "wg_b", (h * dv, dv), wt
-            yield p + "wo", (d, h * dv), wt
-            yield p + "conv_w", (spec.lin_conv_width,
-                                 spec.lin_conv_dim), FloatType.F32
-            yield p + "a_log", (h,), FloatType.F32
-            yield p + "dt_bias", (h * dk,), FloatType.F32
-            yield p + "rms_o", (dv,), FloatType.F32
-        else:
-            yield from _latent_mixer_plan(spec, p)
-        yield from _latent_ffn_plan(spec, l, p)
-        yield p + "rms_att", (d,), FloatType.F32
-        yield p + "rms_ffn", (d,), FloatType.F32
-        if kind == LayerKind.LATENT:
-            yield p + "rms_kv", (spec.kv_lora_rank,), FloatType.F32
-    yield "rms_final", (d,), FloatType.F32
-    yield "wcls", (spec.vocab_size, d), wt
-
-
-def _hybrid_tensor_plan(spec: ModelSpec):
-    """OLMO_HYBRID's file order. A DELTA layer: wq wk (H x d_k rows), wv
-    and wg (the output gate; H x d_v), wa and wb (H rows: decay and beta),
-    wo (over H x d_v), then f32: conv_w (taps x [q ; k ; v] channels, tap
-    j weighs the row `taps - 1 - j` tokens back), a_log, dt_bias (H) and
-    rms_o (d_v, the gated output norm of a head). An ATTENTION layer: wq
-    wk wv wo, then rms_q and rms_k (f32, the full projected width). Both:
-    w1 w2 w3, then rms_att and rms_ffn, the norms on the two sublayers'
-    OUTPUTS."""
-    wt, d, hid = spec.weights_float_type, spec.dim, spec.hidden_dim
-    h, dk, dv = spec.lin_heads, spec.lin_k_head_dim, spec.lin_v_head_dim
-    yield "tok_emb", (spec.vocab_size, d), FloatType.F32
-    for l, kind in enumerate(spec.layer_kinds):
-        p = f"layers.{l}."
-        if kind == LayerKind.DELTA:
-            yield p + "wq", (h * dk, d), wt
-            yield p + "wk", (h * dk, d), wt
-            yield p + "wv", (h * dv, d), wt
-            yield p + "wg", (h * dv, d), wt
-            yield p + "wa", (h, d), wt
-            yield p + "wb", (h, d), wt
-            yield p + "wo", (d, h * dv), wt
-            yield p + "conv_w", (spec.lin_conv_width,
-                                 spec.lin_conv_dim), FloatType.F32
-            yield p + "a_log", (h,), FloatType.F32
-            yield p + "dt_bias", (h,), FloatType.F32
-            yield p + "rms_o", (dv,), FloatType.F32
-        else:
-            yield p + "wq", (d, d), wt
-            yield p + "wk", (spec.kv_dim, d), wt
-            yield p + "wv", (spec.kv_dim, d), wt
-            yield p + "wo", (d, d), wt
-            yield p + "rms_q", (d,), FloatType.F32
-            yield p + "rms_k", (spec.kv_dim,), FloatType.F32
-        yield p + "w1", (hid, d), wt
-        yield p + "w2", (d, hid), wt
-        yield p + "w3", (hid, d), wt
-        yield p + "rms_att", (d,), FloatType.F32
-        yield p + "rms_ffn", (d,), FloatType.F32
-    yield "rms_final", (d,), FloatType.F32
-    yield "wcls", (spec.vocab_size, d), wt
-
-
-def _granite_tensor_plan(spec: ModelSpec):
-    """GRANITE_HYBRID's file order. An SSM layer: the input projection in
-    leaves whose rows tile, wz (the gate; d_inner rows), wx (d_inner), wbc
-    (B's G x N rows, then C's) and wdt (H rows), then wo (over d_inner),
-    then f32: conv_w (taps x [x ; B ; C] channels, tap j weighs the row
-    `taps - 1 - j` tokens back), conv_b (a channel; with ssm_conv_bias),
-    a_log, dt_bias and ssm_d (H: the decay, the step's bias and the skip)
-    and rms_o (d_inner, the gated output norm). An ATTENTION layer: wq wk
-    wv wo. Both: moe_router (router_width rows), the HELD experts' up gate
-    down, the shared expert's sh_w1 (gate) sh_w2 (down) sh_w3 (up) at
-    n_shared_experts x hidden_dim, then rms_att and rms_ffn, the norms on
-    the two sublayers' INPUTS."""
-    wt, d, hid = spec.weights_float_type, spec.dim, spec.hidden_dim
-    inner, h = spec.ssm_inner, spec.ssm_heads
-    yield "tok_emb", (spec.vocab_size, d), FloatType.F32
-    for l, kind in enumerate(spec.layer_kinds):
-        p = f"layers.{l}."
-        if kind == LayerKind.SSM:
-            yield p + "wz", (inner, d), wt
-            yield p + "wx", (inner, d), wt
-            yield p + "wbc", (2 * spec.ssm_groups * spec.ssm_d_state, d), wt
-            yield p + "wdt", (h, d), wt
-            yield p + "wo", (d, inner), wt
-            yield p + "conv_w", (spec.ssm_conv_width,
-                                 spec.ssm_conv_dim), FloatType.F32
-            if spec.ssm_conv_bias:
-                yield p + "conv_b", (spec.ssm_conv_dim,), FloatType.F32
-            yield p + "a_log", (h,), FloatType.F32
-            yield p + "dt_bias", (h,), FloatType.F32
-            yield p + "ssm_d", (h,), FloatType.F32
-            yield p + "rms_o", (inner,), FloatType.F32
-        else:
-            yield p + "wq", (d, d), wt
-            yield p + "wk", (spec.kv_dim, d), wt
-            yield p + "wv", (spec.kv_dim, d), wt
-            yield p + "wo", (d, d), wt
-        yield p + "moe_router", (spec.router_width, d), wt
-        for e in range(spec.n_experts):
-            yield p + f"experts.{e}.up", (hid, d), wt
-            yield p + f"experts.{e}.gate", (hid, d), wt
-            yield p + f"experts.{e}.down", (d, hid), wt
-        if spec.n_shared_experts:
-            sh = spec.n_shared_experts * hid
-            yield p + "sh_w1", (sh, d), wt
-            yield p + "sh_w2", (d, sh), wt
-            yield p + "sh_w3", (sh, d), wt
-        yield p + "rms_att", (d,), FloatType.F32
-        yield p + "rms_ffn", (d,), FloatType.F32
-    yield "rms_final", (d,), FloatType.F32
-    yield "wcls", (spec.vocab_size, d), wt
+    for name, _, t in model_tensors(spec):
+        yield name, t.shape(spec), t.ftype(spec)
 
 
 def _tensor_bytes(shape: tuple[int, ...], ftype: FloatType) -> int:

@@ -26,15 +26,14 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..io.model_file import HostTensor, iter_model_tensors
+from ..io.model_file import HostTensor, iter_model_tensors, to_q40_host
 from ..quants.jax_codec import QuantizedTensor
-from ..quants.numpy_codec import quantize_q40
 from ..quants.types import FloatType
-from ..parallel.sharding import COL_SPLIT_NAMES, _pspec_for
+from ..parallel.sharding import leaf_pspec
 from ..parallel.mesh import EP_AXIS, PP_AXIS, TP_AXIS
-from .spec import ArchType, LayerKind, ModelSpec
-
-_MOE_EP_KEYS = ("moe_up", "moe_gate", "moe_down")
+from .params import kv_replication, split_wkvb
+from .spec import ModelSpec
+from .tensors import LEAF_SPLIT, Leaf, group_members, model_tensors
 
 
 class LoadStats(NamedTuple):
@@ -48,21 +47,6 @@ def _host_bytes(t: HostTensor) -> int:
         if a is not None:
             n += a.nbytes
     return n
-
-
-def _leaf_key(plan_name: str) -> str:
-    """'layers.3.wq' -> 'wq'; 'layers.0.experts.2.up' -> 'moe_up'."""
-    parts = plan_name.split(".")
-    if parts[0] != "layers":
-        return plan_name
-    if parts[2] == "experts":
-        return "moe_" + parts[4]
-    return parts[2]
-
-
-def _to_q40_host(x: np.ndarray) -> HostTensor:
-    scales, packed = quantize_q40(x.reshape(-1, x.shape[-1]))
-    return HostTensor("", FloatType.Q40, x.shape, scales=scales, packed=packed)
 
 
 def _replicate_kv_host(t: HostTensor, kvh: int, r: int) -> HostTensor:
@@ -86,7 +70,7 @@ def _replicate_kv_host(t: HostTensor, kvh: int, r: int) -> HostTensor:
 def _q40_raw_stack(ts: list[HostTensor]) -> tuple[np.ndarray, np.ndarray]:
     """(packed, scales) in raw block layout for one tensor or an E-stacked
     expert list — the single host-side Q40 pipeline every load path uses."""
-    qs = [t if t.ftype == FloatType.Q40 else _to_q40_host(t.to_f32())
+    qs = [t if t.ftype == FloatType.Q40 else to_q40_host(t.to_f32())
           for t in ts]
     packed = np.stack([q.packed for q in qs]) if len(ts) > 1 else qs[0].packed
     scales = np.stack([q.scales for q in qs]) if len(ts) > 1 else qs[0].scales
@@ -131,19 +115,18 @@ class _Placer:
         return jax.device_put(x, NamedSharding(self.mesh, pspec))
 
     def dense(self, key: str, x: np.ndarray):
-        return self._put(x, _pspec_for(key, x.ndim, False, "dense",
-                                       self.vocab_axes))
+        return self._put(x, leaf_pspec(key, x.ndim, self.vocab_axes))
 
-    def weight(self, key: str, ts: list[HostTensor]):
-        """A matmul weight: single tensor, or an E-stacked expert list.
-        Applies mode (dense/q40), col repack for q80 collectives, ep
+    def weight(self, key: str, ts: list[HostTensor], expert: bool = False):
+        """A matmul weight: single tensor, or (expert) an E-stacked expert
+        list. Applies mode (dense/q40), col repack for q80 collectives, ep
         placement for MoE expert stacks, sharding."""
-        moe_ep = self.ep > 1 and key in _MOE_EP_KEYS
+        moe_ep = self.ep > 1 and expert
         if self.mode != "q40":
             x = _dense_host_stack(ts)
             x = x.astype(np.dtype(self.dtype) if self.dtype != jnp.bfloat16
                          else np.float32)
-            if (self.q80 or moe_ep) and key in COL_SPLIT_NAMES:
+            if (self.q80 or moe_ep) and LEAF_SPLIT[key] == "col":
                 n = x.shape[-1]
                 xs = x.reshape(*x.shape[:-1], self.tp, n // self.tp)
                 xs = np.moveaxis(xs, -2, 0)
@@ -165,12 +148,11 @@ class _Placer:
                 return EpRowWeight(
                     arr.astype(self.dtype) if self.dtype == jnp.bfloat16
                     else arr)
-            arr = self._put(x, _pspec_for(key, x.ndim, False, "dense",
-                                          self.vocab_axes))
+            arr = self._put(x, leaf_pspec(key, x.ndim, self.vocab_axes))
             return arr.astype(self.dtype) if self.dtype == jnp.bfloat16 else arr
 
         packed, scales = _q40_raw_stack(ts)
-        if (self.q80 or moe_ep) and key in COL_SPLIT_NAMES:
+        if (self.q80 or moe_ep) and LEAF_SPLIT[key] == "col":
             return self._col_q40(packed, scales, ep=moe_ep)
         pk, sc = QuantizedTensor.host_layout(scales, packed)
         if moe_ep:
@@ -181,10 +163,8 @@ class _Placer:
                 self._put(sc, ep_row_pspec(sc.ndim)),
             ))
         return QuantizedTensor(
-            self._put(pk, _pspec_for(key, pk.ndim, True, "packed",
-                                     self.vocab_axes)),
-            self._put(sc, _pspec_for(key, sc.ndim, True, "scales",
-                                     self.vocab_axes)),
+            self._put(pk, leaf_pspec(key, pk.ndim, self.vocab_axes)),
+            self._put(sc, leaf_pspec(key, sc.ndim, self.vocab_axes)),
         )
 
     def _col_q40(self, packed: np.ndarray, scales: np.ndarray,
@@ -261,7 +241,8 @@ class _PpStacker:
         return self._update(buf, jnp.asarray(arr), stage, sh)
 
     def add(self, slot: dict, key: str, stage: int, mode: str, dtype,
-            ts: list[HostTensor], *, keep_f32: bool = False):
+            ts: list[HostTensor], *, keep_f32: bool = False,
+            expert: bool = False):
         """Fold one layer tensor (or fused/expert-stacked group) into the
         slot's stage-stacked leaf."""
         from ..parallel.ep_moe import (EpColWeight, EpRowWeight, ep_col_pspec,
@@ -270,11 +251,11 @@ class _PpStacker:
         from ..parallel.tp_q80 import TpColWeight
 
         cur = slot.get(key)
-        moe_ep = self.ep > 1 and key in _MOE_EP_KEYS
+        moe_ep = self.ep > 1 and expert
         if mode != "q40" or keep_f32:
             x = _dense_host_stack(ts)
             leaf_dtype = jnp.float32 if keep_f32 else dtype
-            if moe_ep and key in COL_SPLIT_NAMES:
+            if moe_ep and LEAF_SPLIT[key] == "col":
                 # ep x pp dense moe_down: (tp, E, d, n/tp) col stack per
                 # stage — PpWeight(EpColWeight(...)), mirroring _Placer
                 n = x.shape[-1]
@@ -289,12 +270,12 @@ class _PpStacker:
                 slot[key] = PpWeight(EpRowWeight(self._row(
                     old, x, stage, ep_row_pspec(x.ndim), leaf_dtype)))
                 return
-            spec = _pspec_for(key, x.ndim, False, "dense")
+            spec = leaf_pspec(key, x.ndim)
             slot[key] = PpWeight(self._row(
                 cur.w if cur is not None else None, x, stage, spec,
                 leaf_dtype))
             return
-        if moe_ep and key in COL_SPLIT_NAMES:
+        if moe_ep and LEAF_SPLIT[key] == "col":
             # ep x pp q40 moe_down: block-aligned (tp, E, d, ...) col
             # stack, stage-stacked — PpWeight(EpColWeight(QuantizedTensor))
             packed, scales = _q40_raw_stack(ts)
@@ -319,7 +300,7 @@ class _PpStacker:
                           stage, ep_row_pspec(sc.ndim), sc.dtype),
             )))
             return
-        if key in COL_SPLIT_NAMES and self.tp > 1:
+        if LEAF_SPLIT[key] == "col" and self.tp > 1:
             # pp's fully-manual region slices weights at placement: q40 col
             # shards must be block-aligned TpColWeight stacks, stage-stacked
             # to (pp, tp, ..., d, m/tp) — PpWeight(TpColWeight(...))
@@ -339,37 +320,16 @@ class _PpStacker:
         old = cur.w if cur is not None else None
         slot[key] = PpWeight(QuantizedTensor(
             self._row(old.packed if old is not None else None, pk, stage,
-                      _pspec_for(key, pk.ndim, True, "packed"), pk.dtype),
+                      leaf_pspec(key, pk.ndim), pk.dtype),
             self._row(old.scales if old is not None else None, sc, stage,
-                      _pspec_for(key, sc.ndim, True, "scales"), sc.dtype),
+                      leaf_pspec(key, sc.ndim), sc.dtype),
         ))
-
-
-def _fuse_group(key: str) -> str | None:
-    """Which single-shard fusion group a leaf belongs to (models/params.py:
-    fuse_layer_weights semantics, streamed)."""
-    if key in ("wq", "wk", "wv"):
-        return "wqkv"
-    if key in ("w1", "w3"):
-        return "w13"
-    if key in ("wz", "wx"):
-        return "wzx"
-    return None
-
-
-# thin projections kept as ONE dense leaf (a pair or a triple of file
-# tensors) of the compute dtype, their file
-# tensors' rows in file order (models/params.load_params): the DELTA
-# layer's decay and beta rows, the SSM layer's B | C and dt rows
-_DENSE_GROUPS = {"wa": "w_ab", "wb": "w_ab", "wbc": "w_bcdt", "wdt": "w_bcdt",
-                # a KDA layer's three (models/params.KDA_THIN_ROWS)
-                "wf_a": "w_fgb", "wbeta": "w_fgb", "wg_a": "w_fgb"}
 
 
 def _concat_host(ts: list[HostTensor], mode: str) -> list[HostTensor]:
     """Concatenate a fusion group along the output dim on the host."""
     if mode == "q40":
-        qs = [t if t.ftype == FloatType.Q40 else _to_q40_host(t.to_f32())
+        qs = [t if t.ftype == FloatType.Q40 else to_q40_host(t.to_f32())
               for t in ts]
         return [HostTensor("", FloatType.Q40,
                            (sum(t.shape[0] for t in ts), ts[0].shape[1]),
@@ -411,8 +371,6 @@ def load_params_streamed(
         # tp beyond the kv-head count: wk/wv rows replicate host-side into
         # tp virtual heads BEFORE placement, so each device still receives
         # exactly its shard (models/params.kv_replication)
-        from .params import kv_replication
-
         kv_rep = kv_replication(spec, tp)
     if fuse is None:
         fuse = tp == 1
@@ -441,27 +399,21 @@ def load_params_streamed(
 
     p: dict = {"layers": [dict() for _ in range(n_slot if pp > 1
                                                 else spec.n_layers)]}
-    pending: dict[str, list[HostTensor]] = {}
+    pending: dict[tuple, list[HostTensor]] = {}
     peak = 0
     total = 0
     live = 0
 
-    def target(plan_name: str):
-        """(dest dict, stage) — stage is None for non-layer tensors; under
-        pp layer l maps to slot l % n_slot at stage l // n_slot."""
-        parts = plan_name.split(".")
-        if parts[0] != "layers":
-            return p, None
-        l = int(parts[1])
-        if pp > 1:
-            return p["layers"][l % n_slot], l // n_slot
-        return p["layers"][l], None
+    def compute(arr):
+        return arr.astype(dtype) if dtype != jnp.float32 else arr
 
     if tensors is None:
         tensors = iter_model_tensors(path, spec)
-    for t in tensors:
-        key = _leaf_key(t.name)
-        if kv_rep > 1 and key in ("wk", "wv"):
+    # the declaration (models/tensors.py) walked beside the stream: an
+    # entry says what leaf its tensor becomes
+    for (name, l, entry), t in zip(model_tensors(spec), tensors, strict=True):
+        assert t.name == name, f"tensor {t.name!r} where the plan has {name!r}"
+        if kv_rep > 1 and entry.name in ("wk", "wv"):
             # replicate BEFORE accounting so live/peak measure the r-fold
             # bytes actually resident during placement
             t = _replicate_kv_host(t, spec.n_kv_heads, kv_rep)
@@ -469,82 +421,60 @@ def load_params_streamed(
         total += b
         live += b
         peak = max(peak, live)
-        dest, stage = target(t.name)
-        group = _fuse_group(key) if fuse else None
-        if group == "wqkv" and spec.layer_kinds[
-                int(t.name.split(".")[1])] == LayerKind.LATENT:
-            group = None  # wq stands alone: no wk/wv to fuse it with
+        # under pp layer l maps to slot l % n_slot at stage l // n_slot
+        dest, stage = p, None
+        if l is not None:
+            dest = p["layers"][l % n_slot if pp > 1 else l]
+            stage = l // n_slot if pp > 1 else None
 
-        if group is not None:
-            gk = f"{t.name.rsplit('.', 1)[0]}.{group}"
-            pending.setdefault(gk, []).append(t)
-            want = 3 if group == "wqkv" else 2
-            if len(pending[gk]) == want:
-                ts = pending.pop(gk)
-                cts = _concat_host(ts, mode)
-                if stage is not None:
-                    pp_stack.add(dest, group, stage, mode, dtype, cts)
-                else:
-                    dest[group] = placer.weight(group, cts)
-                live -= sum(_host_bytes(x) for x in ts)
+        # the leaf, and how many file tensors make it: an expert stack
+        # (experts stream in (up, gate, down) x E order), the rows of a
+        # thin leaf, a single-shard fusion group, or the tensor alone
+        leaf, want = entry.name, 1
+        if entry.leaf is Leaf.EXPERT:
+            leaf, want = entry.into, spec.n_experts
+        elif entry.leaf is Leaf.ROWS or (fuse and entry.fuse):
+            leaf = entry.into if entry.leaf is Leaf.ROWS else entry.fuse
+            want = len(group_members(spec, l, leaf))
+        ts = pending.setdefault((l, leaf), [])
+        ts.append(t)
+        if len(ts) < want:
             continue
+        del pending[l, leaf]
+        if want > 1:
+            # a finished group's host buffers outlive the next group's first
+            # reads. Freed at once (a layer's three expert stacks: 280 MB),
+            # the allocator hands the next layer cold pages and the file
+            # read's copies take 14.0 s for 8.0 (kimi-linear-48b-a3b-ep4 on
+            # the v5e's host, 37-40 s a load for 29-30; PERF.md section 6,
+            # PR 51). The bytes are counted out of `live` below all the same.
+            held = ts  # noqa: F841
 
-        if key.startswith("moe_") and key not in ("moe_router", "moe_bias"):
-            # experts stream in (up, gate, down) x E order; stack per role
-            gk = f"{t.name.rsplit('.', 2)[0]}.{key}"
-            pending.setdefault(gk, []).append(t)
-            if len(pending[gk]) == spec.n_experts:
-                ts = pending.pop(gk)
-                if stage is not None:
-                    pp_stack.add(dest, key, stage, mode, dtype, ts)
-                else:
-                    dest[key] = placer.weight(key, ts)
-                live -= sum(_host_bytes(x) for x in ts)
-            continue
-
-        if key in _DENSE_GROUPS:
-            leaf = _DENSE_GROUPS[key]
-            gk = f"{t.name.rsplit('.', 1)[0]}.{leaf}"
-            pending.setdefault(gk, []).append(t)
-            if len(pending[gk]) == list(_DENSE_GROUPS.values()).count(leaf):
-                ts = pending.pop(gk)
-                arr = placer.dense(leaf, np.concatenate(
-                    [x.to_f32() for x in ts]))
-                dest[leaf] = (arr.astype(dtype) if dtype != jnp.float32
-                              else arr)
-                live -= sum(_host_bytes(x) for x in ts)
-            continue
-
-        if key == "wkvb":
-            # the latent's up-projection serves absorbed attention as two
-            # dense per-head operands (models/params.split_wkvb)
-            from .params import split_wkvb
-
+        if entry.leaf is Leaf.HALVES:
             assert stage is None, "the latent cache does not support --pp"
-            for name, half in zip(("w_uk", "w_uv"),
-                                  split_wkvb(spec, t.to_f32())):
-                arr = placer.dense(name, half)
-                dest[name] = arr.astype(dtype) if dtype != jnp.float32 else arr
-        elif key in ("rms_att", "rms_ffn", "rms_moe", "rms_ffn2", "rms_final",
-                     "rms_kv", "moe_bias", "rms_q", "rms_k", "rms_o",
-                     "conv_w", "a_log", "dt_bias", "conv_b", "ssm_d"):
-            if stage is not None:  # per-layer norms stack too, kept f32
-                pp_stack.add(dest, key, stage, "dense", dtype, [t],
-                             keep_f32=True)
-            else:
-                dest[key] = placer.dense(key, t.to_f32())  # norms stay f32
-        elif key in ("tok_emb", "moe_router", "wf_b", "wg_b"):
-            if stage is not None:  # moe_router is a per-layer dense leaf
-                pp_stack.add(dest, key, stage, "dense", dtype, [t])
-            else:
-                arr = placer.dense(key, t.to_f32())
-                dest[key] = arr.astype(dtype) if dtype != jnp.float32 else arr
-        else:
+            for half_name, half in zip(entry.into,
+                                       split_wkvb(spec, t.to_f32())):
+                dest[half_name] = compute(placer.dense(half_name, half))
+        elif entry.leaf is Leaf.ROWS:
+            dest[leaf] = compute(placer.dense(leaf, np.concatenate(
+                [x.to_f32() for x in ts])))
+        elif entry.leaf in (Leaf.F32, Leaf.COMPUTE):
+            f32 = entry.leaf is Leaf.F32   # norms stay f32, stacked or not
             if stage is not None:
-                pp_stack.add(dest, key, stage, mode, dtype, [t])
+                pp_stack.add(dest, leaf, stage, "dense", dtype, ts,
+                             keep_f32=f32)
             else:
-                dest[key] = placer.weight(key, [t])
-        live -= b
+                arr = placer.dense(leaf, t.to_f32())
+                dest[leaf] = arr if f32 else compute(arr)
+        else:
+            expert = entry.leaf is Leaf.EXPERT
+            cts = ts if expert or want == 1 else _concat_host(ts, mode)
+            if stage is not None:
+                pp_stack.add(dest, leaf, stage, mode, dtype, cts,
+                             expert=expert)
+            else:
+                dest[leaf] = placer.weight(leaf, cts, expert=expert)
+        live -= sum(_host_bytes(x) for x in ts)
 
-    assert not pending, f"incomplete fusion groups: {list(pending)}"
+    assert not pending, f"incomplete groups: {list(pending)}"
     return p, LoadStats(peak_host_bytes=peak, total_bytes=total)

@@ -32,12 +32,12 @@ chunks (t=256, bf16 MXU feeds) the kernel also wins decisively: 7B
 dequant-einsum path (2.7x) — the round-3 kernel measured 5771 tok/s with
 the nibble unpack (VPU) fully serialized against the MXU contraction;
 sub-tiling the td=256 tile (see _n_sub) overlaps the two for +9.5%
-whole-model (+41% on the w1/w3 matmul alone). Cutting ops/byte
-further means int8 MXU dots — measured and REJECTED: an int4-unpack ->
-int8 dot_general variant runs 4x slower at t=1 (82 vs 331 GB/s packed,
-tools/exp_int8_dot.py) because Mosaic has no efficient int8 gemv path;
-Q80 weights would unpack cheaper (~2.5 ops/byte) but carry 1.9x the
-bytes, a net loss. 7B Q40 decode lands at ~9.5 ms/token accordingly.
+whole-model (+41% on the w1/w3 matmul alone). Cutting ops/byte further
+means int8 MXU dots — measured and REJECTED: an int4-unpack -> int8
+dot_general variant runs 4x slower at t=1 (82 vs 331 GB/s packed; PERF.md
+section 6, "Kernel experiments not taken") because Mosaic has no efficient
+int8 gemv path; Q80 weights would unpack cheaper (~2.5 ops/byte) but carry
+1.9x the bytes, a net loss. 7B Q40 decode lands at ~9.5 ms/token accordingly.
 
 Layout: QuantizedTensor packed is nibble-position-major, stored flattened
 (d, m) uint8 with lane order m = j*nb + b (see quants/jax_codec.py) — the
@@ -83,7 +83,7 @@ def _f16_bits_to_f32(u: jnp.ndarray) -> jnp.ndarray:
     """Decode f16 bit patterns (int32-widened uint16) to f32 exactly with
     integer ops + bitcast — Mosaic has no f16 arithmetic, and keeping the
     scales 2 bytes wide in HBM saves ~10% of the kernel's traffic (measured
-    1.19x, tools/exp_scale_f16.py). Handles normals and subnormals; inf/nan
+    1.19x; PERF.md section 6, that table). Handles normals and subnormals; inf/nan
     cannot occur in Q40 scales."""
     sign = (u & 0x8000) << 16
     e = (u >> 10) & 0x1F
@@ -172,8 +172,8 @@ def _dequant(pk_u8, s_raw, spread, *, scales_u16, mxu_bf16):
     FLAT at 1.000x (the and-op co-issues off the critical path) and 6.4%
     relative error (DEFAULT-precision dots pass f32 operands through the
     MXU as bf16; pk's 8 value bits fill the mantissa and the 16x
-    cancellation amplifies the truncation). Full record:
-    tools/exp_pk_decode.py.)"""
+    cancellation amplifies the truncation). Full record: PERF.md section
+    6, "Kernel experiments not taken".)"""
     pk = pk_u8.astype(jnp.int32)                         # (TD, M=16*nb)
     lo = (pk & 0xF).astype(jnp.float32)
     hi = (pk >> 4).astype(jnp.float32)
@@ -218,7 +218,7 @@ def _n_sub(td: int, m: int, mxu_bf16: bool) -> int:
     Splitting the (td, m) packed tile into n_sub row sub-tiles and issuing
     each sub-tile's dot right after its unpack lets the MXU chew on sub-tile
     i while the VPU unpacks i+1. Measured on v5e at t=256
-    (tools/exp_unpack_overlap.py + the w2-shape probe):
+    (PERF.md section 6, "Kernel experiments not taken"; the w2-shape probe):
       * w1/w3 shape (d=11008, m=2048, td=256): n_sub=8 wins 1.41x
         (n_sub=2: 1.37x, n_sub=4: 1.38x)
       * w2 shape (d=4096, m=5504, td=256): n_sub=2 wins 2.26x

@@ -73,7 +73,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..quants.jax_codec import QuantizedTensor
 from .mesh import PP_AXIS, TP_AXIS
-from .sharding import _SPLIT
+from ..models.tensors import LEAF_SPLIT
 from .tp_q80 import TpColWeight, TpRowWeight, manual_psum
 from .wrappers import WeightWrapper, weight_marker
 
@@ -134,7 +134,7 @@ def _unwrap0(key: str, w, tp: int):
     """Strip the local (1,)-length stage axis (and, for Tp-wrapped leaves,
     the (1,)-length local tp stack axis) off a PpWeight leaf inside the
     manual region, yielding this device's local layer weight. Plain split
-    leaves are re-marked TpRowWeight/TpColWeight by their _SPLIT role so
+    leaves are re-marked TpRowWeight/TpColWeight by their LEAF_SPLIT role so
     matmul(manual_tp=...) knows whether a psum is owed."""
     from .ep_moe import EpColWeight, EpRowWeight
 
@@ -160,7 +160,7 @@ def _unwrap0(key: str, w, tp: int):
     if isinstance(inner, TpRowWeight):
         return TpRowWeight(strip(inner.w, 1))
     v = strip(inner, 1)
-    split = _SPLIT.get(key)
+    split = LEAF_SPLIT.get(key)
     if tp > 1 and split == "col":
         return TpColWeight(v)
     if tp > 1 and split == "row":
@@ -199,7 +199,7 @@ def _leaf_in_spec(key: str, w, tp_ax):
             return PpWeight(TpColWeight(QuantizedTensor(
                 cspec(inner.w.packed.ndim), cspec(inner.w.scales.ndim))))
         return PpWeight(TpColWeight(cspec(inner.w.ndim)))
-    role = _SPLIT.get(key)
+    role = LEAF_SPLIT.get(key)
     if isinstance(inner, TpRowWeight):
         if isinstance(inner.w, QuantizedTensor):
             return PpWeight(TpRowWeight(QuantizedTensor(

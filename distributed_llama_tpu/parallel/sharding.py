@@ -29,67 +29,12 @@ import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..models.spec import ModelSpec
+from ..models.tensors import LEAF_SPLIT
 from ..quants.jax_codec import QuantizedTensor
 from .mesh import DP_AXIS, TP_AXIS
 
-# per-param logical split: 'row' = shard output dim, 'col' = shard input dim,
-# None = replicate. Axis positions account for leading stacking dims (the
-# per-expert E axis on MoE weights; layers are a pytree list, not an axis).
-_SPLIT = {
-    "tok_emb": None,
-    "rms_att": None,
-    "rms_ffn": None,
-    "rms_moe": None,
-    "rms_ffn2": None,
-    "rms_final": None,
-    "moe_router": None,
-    "wq": "row",
-    "wk": "row",
-    "wv": "row",
-    "wqkv": "row",  # fused single-shard variants (models/params.py)
-    "w1": "row",
-    "w3": "row",
-    "w13": "row",
-    "moe_up": "row",
-    "moe_gate": "row",
-    "moe_down": "col",
-    "wo": "col",
-    "w2": "col",
-    "wcls": "row",  # vocab-sharded logits (net-new vs reference root-only wcls)
-    # SARVAM_MLA (single shard or dp only: Engine refuses it under tp/pp/sp)
-    "rms_kv": None,
-    "moe_bias": None,
-    "wkva": None,
-    "w_uk": None,
-    "w_uv": None,
-    "sh_w1": None,
-    "sh_w2": None,
-    "sh_w3": None,
-    # OLMO_HYBRID (one shard or dp only, refused under tp/pp/sp/ep)
-    "wg": None,
-    "w_ab": None,
-    "conv_w": None,
-    "a_log": None,
-    "dt_bias": None,
-    "rms_o": None,
-    "rms_q": None,
-    "rms_k": None,
-    # KIMI_LINEAR (likewise): a KDA layer's thin projections
-    "w_fgb": None,
-    "wf_b": None,
-    "wg_b": None,
-    # GRANITE_HYBRID (likewise)
-    "wz": None,
-    "wx": None,
-    "wzx": None,
-    "w_bcdt": None,
-    "conv_b": None,
-    "ssm_d": None,
-}
 
-
-def _pspec_for(name: str, ndim: int, quantized: bool, which: str,
-               vocab_axes: tuple | None = None) -> P:
+def leaf_pspec(name: str, ndim: int, vocab_axes: tuple | None = None) -> P:
     """PartitionSpec for one array leaf.
 
     Dense weights are (lead..., d, n). Q40 leaves are packed (lead..., d, m)
@@ -99,8 +44,14 @@ def _pspec_for(name: str, ndim: int, quantized: bool, which: str,
     stripe rather than a block stripe, which GSPMD handles transparently
     (the dequant reshape introduces a resharding); the shard_map TP path
     slices at the logical-tensor level instead and stays block-aligned.
+
+    Which way a leaf splits ('row': the output dim, 'col': the input dim,
+    None: replicated) is declared beside the leaf (models/tensors.py); the
+    axis positions account for leading stacking dims (the per-expert E axis
+    on MoE weights; layers are a pytree list, not an axis). A leaf that is
+    not declared is a KeyError.
     """
-    split = _SPLIT[name]
+    split = LEAF_SPLIT[name]
     axes: list = [None] * ndim
     if name in ("tok_emb", "wcls") and vocab_axes is not None:
         # vocab sharding (ops/sharded_vocab.py): the embedding table
@@ -143,10 +94,10 @@ def _leaf_spec(name: str, w, vocab_axes: tuple | None = None):
         return tp_row_pspec(w)
     if isinstance(w, QuantizedTensor):
         return QuantizedTensor(  # pytree-shaped specs
-            _pspec_for(name, w.packed.ndim, True, "packed", vocab_axes),
-            _pspec_for(name, w.scales.ndim, True, "scales", vocab_axes),
+            leaf_pspec(name, w.packed.ndim, vocab_axes),
+            leaf_pspec(name, w.scales.ndim, vocab_axes),
         )
-    return _pspec_for(name, w.ndim, False, "dense", vocab_axes)
+    return leaf_pspec(name, w.ndim, vocab_axes)
 
 
 def param_pspecs(params: dict, vocab_axes: tuple | None = None) -> dict:
@@ -199,9 +150,6 @@ def check_tp_constraints(spec: ModelSpec, tp: int, q40: bool = False) -> None:
         assert spec.dim % (32 * tp) == 0
 
 
-COL_SPLIT_NAMES = tuple(k for k, v in _SPLIT.items() if v == "col")
-
-
 def repack_col_weights(params: dict, tp: int) -> dict:
     """Repack every col-split weight into the TpColWeight stacked form used
     by the q80-collective shard_map path (parallel/tp_q80.py). Non-mutating
@@ -226,7 +174,8 @@ def repack_col_weights(params: dict, tp: int) -> dict:
 
     out = dict(params)
     out["layers"] = [
-        {k: (repack(v) if k in COL_SPLIT_NAMES else v) for k, v in lw.items()}
+        {k: (repack(v) if LEAF_SPLIT.get(k) == "col" else v)
+         for k, v in lw.items()}
         for lw in params["layers"]
     ]
     return out
@@ -241,7 +190,7 @@ def wrap_row_weights(params: dict) -> dict:
     from .tp_q80 import TpRowWeight
 
     def wrap(name, v):
-        if (name in _SPLIT and _SPLIT[name] is not None
+        if (LEAF_SPLIT.get(name) is not None
                 and isinstance(v, QuantizedTensor)):
             return TpRowWeight(v)
         return v
