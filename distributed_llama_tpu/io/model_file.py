@@ -96,6 +96,10 @@ _SSM_SHARED_KEYS = ("n_shared_experts", "n_routed_experts", "expert_offset",
 # lead, the held share), OLMO_HYBRID's (the DELTA layer's sizes), the width
 # of a head's decay, and the layer kinds as data.
 _KDA_KEYS = {"lin_decay_dim": 46}
+# JAMBA's header: GRANITE_HYBRID's keys (the SSM layer's sizes, with every
+# channel a head of width 1) and the rank of the step's projection, which
+# says that the SSM layers are selective scans.
+_SELECTIVE_KEYS = {"ssm_dt_rank": 47}
 _FLOAT_KEYS = _MLA_FLOAT_KEYS | frozenset((
     "embedding_scale", "residual_scale", "attn_scale", "logit_scale"))
 _MIXER_KEY0 = 1000
@@ -181,8 +185,8 @@ def read_spec(path: str, weights_float_type: FloatType | None = None) -> ModelSp
             data = f.read(header_size - 8)
             n_kv = len(data) // 8
             inv = {v: k for k, v in
-                   {**_KEYS, **_MLA_KEYS, **_HYBRID_KEYS,
-                    **_SSM_KEYS, **_KDA_KEYS}.items()}
+                   {**_KEYS, **_MLA_KEYS, **_HYBRID_KEYS, **_SSM_KEYS,
+                    **_KDA_KEYS, **_SELECTIVE_KEYS}.items()}
             mixers: dict[int, int] = {}
             for i in range(n_kv):
                 k, v = struct.unpack_from("<ii", data, i * 8)
@@ -226,7 +230,7 @@ def read_spec(path: str, weights_float_type: FloatType | None = None) -> ModelSp
         version=version,
         **{k: (_bits_f32(fields[k]) if k in _FLOAT_KEYS else fields[k])
            for k in (*_MLA_KEYS, *_HYBRID_KEYS, *_SSM_KEYS, *_KDA_KEYS,
-                     "mixers")
+                     *_SELECTIVE_KEYS, "mixers")
            if k in fields},
     )
     spec.validate()
@@ -333,6 +337,8 @@ def write_header(f, spec: ModelSpec) -> None:
     if LayerKind.SSM in kinds:
         keys.update({k: _MLA_KEYS[k] for k in _SSM_SHARED_KEYS})
         keys.update(_SSM_KEYS)
+        if spec.ssm_selective:
+            keys.update(_SELECTIVE_KEYS)
     for key, k in keys.items():
         value = getattr(spec, key)
         data += struct.pack("<ii", k, _f32_bits(value)

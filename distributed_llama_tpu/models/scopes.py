@@ -25,7 +25,9 @@ DEVICE_SCOPES = (
     #                 kernel calls, the wave loop, the weighted sum
     "moe_shared",   # the shared expert (a dense ffn nests under it)
     "gdn_proj", "gdn_conv", "gdn_rule", "gdn_out",    # gated delta rule
-    "ssm_proj", "ssm_conv", "ssm_scan", "ssm_out",    # Mamba-2 mixer
+    "ssm_proj", "ssm_conv", "ssm_scan", "ssm_out",    # state-space mixers
+    "ssm_dt",       # Mamba-1 alone: x_proj, the norms on dt, B and C,
+    #                 dt_proj and the softplus, between convolution and scan
     "kda_proj", "kda_conv", "kda_rule", "kda_out",    # Kimi Delta Attention
     "block_tail",   # residual adds and output norms of no sublayer
     "head",         # final norm, row pick, wcls, logit scales

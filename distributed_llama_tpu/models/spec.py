@@ -39,14 +39,21 @@ class ArchType(enum.IntEnum):
     # leading dense layer, then sigmoid-bias-routed experts and a shared
     # expert in every layer; not a reference-engine architecture
     KIMI_LINEAR = 0xABCD06
+    # selective-scan (Mamba-1) layers, whose decay is a number a (channel,
+    # state index) pair and whose step is a data-dependent low-rank
+    # projection with norms on dt, B and C, beside full-attention layers
+    # WITHOUT positions that share ONE KV head, a dense SwiGLU in every
+    # layer under a pre-norm block; not a reference-engine architecture
+    JAMBA = 0xABCD07
 
 
 class LayerKind(enum.IntEnum):
     """What a layer's mixer is, and so what a slot remembers in it: K/V
     rows (ATTENTION), one latent row (LATENT), or a recurrent state and
-    the convolution's tail (DELTA: the gated delta rule; SSM: a Mamba-2
-    state-space mixer). The per-layer description every cache maker,
-    loader plan, forward and byte ledger reads."""
+    the convolution's tail (DELTA: the gated delta rule; SSM: a state-space
+    mixer, Mamba-2's or, where the spec's ssm_dt_rank says so, Mamba-1's
+    selective scan). The per-layer description every cache maker, loader
+    plan, forward and byte ledger reads."""
 
     ATTENTION = 0
     LATENT = 1
@@ -141,6 +148,14 @@ class ModelSpec:
     ssm_groups: int = 0            # G: B and C are shared by H / G heads
     ssm_conv_width: int = 0        # taps of the causal depthwise convolution
     ssm_conv_bias: int = 0         # 1: the convolution adds a bias a channel
+    ssm_dt_rank: int = 0           # R > 0: the SELECTIVE scan (Mamba-1): the
+    #                                step is a rank-R projection of the
+    #                                convolved x, the decay a number a
+    #                                (state index, channel) pair, every
+    #                                channel a head of its own (H = d_inner,
+    #                                P = 1) and the state a slot (N, d_inner);
+    #                                JAMBA's header (0: Mamba-2, a scalar
+    #                                decay a head)
     # published multipliers, data and not `if arch ==` in forward; 1 (or,
     # for the softmax scale, 0) leaves the program's text as it was
     embedding_scale: float = 1.0   # x0 = scale x E[token]
@@ -215,8 +230,17 @@ class ModelSpec:
         return self.ssm_heads * self.ssm_head_dim
 
     @property
+    def ssm_selective(self) -> bool:
+        """An SSM layer is Mamba-1's selective scan and its kernels
+        (ops/pallas_selective_scan.py), not Mamba-2's (ops/pallas_ssd.py)."""
+        return self.ssm_dt_rank > 0
+
+    @property
     def ssm_conv_dim(self) -> int:
-        """Channels of an SSM layer's convolution: [x ; B ; C]."""
+        """Channels of an SSM layer's convolution: [x ; B ; C], or x alone
+        where B and C are read from the CONVOLVED x (the selective scan)."""
+        if self.ssm_selective:
+            return self.ssm_inner
         return self.ssm_inner + 2 * self.ssm_groups * self.ssm_d_state
 
     def state_leaves(self, kind: LayerKind) -> tuple:
@@ -229,8 +253,11 @@ class ModelSpec:
                      self.lin_v_head_dim),
                     (max(self.lin_conv_width - 1, 0), self.lin_conv_dim))
         assert kind == LayerKind.SSM, kind
-        return ((self.ssm_heads, self.ssm_head_dim, self.ssm_d_state),
-                (max(self.ssm_conv_width - 1, 0), self.ssm_conv_dim))
+        # the selective scan keeps the state index outermost, so that the
+        # channels fill the lanes
+        state = ((self.ssm_d_state, self.ssm_inner) if self.ssm_selective
+                 else (self.ssm_heads, self.ssm_head_dim, self.ssm_d_state))
+        return (state, (max(self.ssm_conv_width - 1, 0), self.ssm_conv_dim))
 
     def state_bytes_per_slot(self, cache_itemsize: int) -> int:
         """Bytes a slot holds over all state layers, whatever its context:
@@ -357,6 +384,10 @@ class ModelSpec:
             assert self.ssm_heads % self.ssm_groups == 0
             assert self.ssm_conv_width >= 2
             assert self.ssm_conv_bias in (0, 1)
+            if self.ssm_selective:
+                assert self.ssm_head_dim == 1 and self.ssm_groups == 1, (
+                    "the selective scan: every channel a head of its own")
+                assert self.ssm_dt_rank % 32 == 0, "whole Q80 blocks of r"
         if self.arch in (ArchType.GROK1, ArchType.MIXTRAL):
             # MoE archs without experts would fail deep inside the forward
             # (missing moe_router); reject at spec level instead

@@ -191,6 +191,32 @@ SSM = (
     _vec(lambda s: s.ssm_inner, "rms_o"),
 )
 
+# a Mamba-1 mixer (the selective scan; ssm_dt_rank = R > 0): ONE input
+# projection as its halves wz (the gate) and wx, fused on one shard as
+# Mamba-2's are; wxp, x_proj, R + 2N rows over the CONVOLVED x ([r ; B ;
+# C]), and wdt, dt_proj, d_inner rows over those R: thin (192 rows; an
+# R-wide contraction, five Q40 blocks a row), a dense leaf each as KDA's
+# low-rank pairs are; wo; then float32: conv_w (taps x d_inner: x ALONE),
+# conv_b, a_log (N x d_inner: the state index outermost, so that the
+# channels fill the lanes of the scan), dt_bias and ssm_d (a channel) and
+# the three inner norms rms_dt (R), rms_b and rms_c (N). NO norm before wo.
+SSM_SELECTIVE = (
+    Tensor("wz", lambda s: (s.ssm_inner, s.dim), fuse="wzx"),
+    Tensor("wx", lambda s: (s.ssm_inner, s.dim), fuse="wzx"),
+    Tensor("wxp", lambda s: (s.ssm_dt_rank + 2 * s.ssm_d_state, s.ssm_inner),
+           Leaf.COMPUTE),
+    Tensor("wdt", lambda s: (s.ssm_inner, s.ssm_dt_rank), Leaf.COMPUTE),
+    Tensor("wo", lambda s: (s.dim, s.ssm_inner), split="col"),
+    Tensor("conv_w", lambda s: (s.ssm_conv_width, s.ssm_conv_dim), Leaf.F32),
+    _vec(lambda s: s.ssm_conv_dim, "conv_b", when=lambda s: s.ssm_conv_bias),
+    Tensor("a_log", lambda s: (s.ssm_d_state, s.ssm_inner), Leaf.F32),
+    _vec(lambda s: s.ssm_inner, "dt_bias"),
+    _vec(lambda s: s.ssm_inner, "ssm_d"),
+    _vec(lambda s: s.ssm_dt_rank, "rms_dt"),
+    _vec(lambda s: s.ssm_d_state, "rms_b"),
+    _vec(lambda s: s.ssm_d_state, "rms_c"),
+)
+
 # -- the FFN -------------------------------------------------------------------
 
 DENSE_FFN = (
@@ -238,8 +264,8 @@ TAIL = (_vec(_dim, "rms_final"),
 
 _MIXERS = {LayerKind.ATTENTION: ATTENTION, LayerKind.LATENT: LATENT,
            LayerKind.DELTA: DELTA, LayerKind.SSM: SSM}
-_GROUPS = (HEAD, *_MIXERS.values(), DELTA_VECTOR, DENSE_FFN, MOE_FFN, NORMS,
-           LATENT_NORMS, TAIL)
+_GROUPS = (HEAD, *_MIXERS.values(), DELTA_VECTOR, SSM_SELECTIVE, DENSE_FFN,
+           MOE_FFN, NORMS, LATENT_NORMS, TAIL)
 
 
 def layer_tensors(spec: ModelSpec, l: int) -> list[Tensor]:
@@ -247,6 +273,7 @@ def layer_tensors(spec: ModelSpec, l: int) -> list[Tensor]:
     the norms."""
     kind = spec.layer_kinds[l]
     mixer = (DELTA_VECTOR if kind == LayerKind.DELTA and spec.lin_vector_decay
+             else SSM_SELECTIVE if kind == LayerKind.SSM and spec.ssm_selective
              else _MIXERS[kind])
     ffn = DENSE_FFN if spec.is_dense_layer(l) else MOE_FFN
     tail = LATENT_NORMS if kind == LayerKind.LATENT else ()

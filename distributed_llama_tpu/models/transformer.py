@@ -1,5 +1,5 @@
 """Unified transformer forward for LLAMA / MIXTRAL / GROK1 / SARVAM_MLA /
-OLMO_HYBRID / GRANITE_HYBRID / KIMI_LINEAR.
+OLMO_HYBRID / GRANITE_HYBRID / KIMI_LINEAR / JAMBA.
 
 One jittable segment-forward covers both prefill (T tokens at once — net-new
 vs the reference, which feeds the prompt token-by-token) and decode (T=1).
@@ -611,6 +611,15 @@ def _kda_block(x, lw, spec: ModelSpec, state, tail, rows: SegmentRows, cfg):
     return out, state, tail
 
 
+def _gate_and_x(u, lw, inner: int, cfg):
+    """Both state-space mixers' input projection [z ; x] of the normed
+    input: one fused call on one shard, its two halves elsewhere."""
+    if "wzx" in lw:
+        zx = matmul(u, lw["wzx"], **cfg)
+        return zx[..., :inner], zx[..., inner:]
+    return matmul(u, lw["wz"], **cfg), matmul(u, lw["wx"], **cfg)
+
+
 def _ssm_block(x, lw, spec: ModelSpec, state, tail, rows: SegmentRows, cfg):
     """State-space (Mamba-2) mixer under a norm on its INPUT: projections
     [z ; x ; B | C | dt] -> causal depthwise convolution (+ bias) and SiLU
@@ -626,11 +635,7 @@ def _ssm_block(x, lw, spec: ModelSpec, state, tail, rows: SegmentRows, cfg):
     f32 = jnp.float32
     with jax.named_scope("ssm_proj"):
         u = rmsnorm(x, lw["rms_att"], spec.norm_eps)
-        if "wzx" in lw:
-            zx = matmul(u, lw["wzx"], **cfg)
-            z, xs = zx[..., :inner], zx[..., inner:]
-        else:
-            z, xs = matmul(u, lw["wz"], **cfg), matmul(u, lw["wx"], **cfg)
+        z, xs = _gate_and_x(u, lw, inner, cfg)
         bcdt = matmul(u, lw["w_bcdt"], **cfg)              # (B, T, 2GN + H)
     with jax.named_scope("ssm_conv"):
         y, tail = _short_conv(
@@ -654,6 +659,51 @@ def _ssm_block(x, lw, spec: ModelSpec, state, tail, rows: SegmentRows, cfg):
     return out, state, tail
 
 
+def _inner_norms(rbc, lw, spec: ModelSpec):
+    """The selective scan's three inner RMS norms, each with its weights:
+    [r ; B ; C] (..., R + 2N) float32 -> (r, B, C)."""
+    n, r, eps = spec.ssm_d_state, spec.ssm_dt_rank, spec.norm_eps
+    return (rmsnorm(rbc[..., :r], lw["rms_dt"], eps),
+            rmsnorm(rbc[..., r:r + n], lw["rms_b"], eps),
+            rmsnorm(rbc[..., r + n:], lw["rms_c"], eps))
+
+
+def _selective_block(x, lw, spec: ModelSpec, state, tail, rows: SegmentRows,
+                     cfg):
+    """Selective-scan (Mamba-1) mixer under a norm on its INPUT: ONE input
+    projection [z ; x] -> causal depthwise convolution (+ bias) and SiLU on
+    x ALONE -> from the CONVOLVED x the thin projection [r ; B ; C], an RMS
+    norm with weights on each of the three, the step dt = softplus(W_dt r +
+    dt_bias) a channel -> the scan, whose decay exp(dt A) is a number a
+    (state index, channel) pair -> the skip D x -> gate -> output projection
+    (NO norm before it). _delta_block's contract."""
+    from ..ops.pallas_selective_scan import selective_scan
+
+    inner, f32 = spec.ssm_inner, jnp.float32
+    with jax.named_scope("ssm_proj"):
+        z, xs = _gate_and_x(rmsnorm(x, lw["rms_att"], spec.norm_eps), lw,
+                            inner, cfg)
+    with jax.named_scope("ssm_conv"):
+        y, tail = _short_conv(xs, tail, lw, rows, spec.ssm_conv_width)
+    with jax.named_scope("ssm_dt"):
+        low, bm, cm = _inner_norms(
+            matmul(y.astype(x.dtype), lw["wxp"], **cfg).astype(f32), lw, spec)
+        dt = jax.nn.softplus(
+            matmul(low.astype(x.dtype), lw["wdt"], **cfg).astype(f32)
+            + lw["dt_bias"])
+    with jax.named_scope("ssm_scan"):
+        o, state = selective_scan(
+            y, dt, -jnp.exp(lw["a_log"]), bm, cm, state, rows.n_valid,
+            rows.fresh, rows.slots, rows.chained,
+            use_pallas=bool(cfg.get("use_pallas")),
+            interpret=cfg.get("pallas_interpret", False))
+        o = o + lw["ssm_d"] * y
+    with jax.named_scope("ssm_out"):
+        out = matmul((o * jax.nn.silu(z.astype(f32))).astype(x.dtype),
+                     lw["wo"], **cfg)
+    return out, state, tail
+
+
 def _delta_mixer(x, lw, spec: ModelSpec, *rest):
     """A DELTA layer's mixer by the width of its decay (the spec's data):
     a scalar a head, or a vector over the key channels (KDA)."""
@@ -661,10 +711,18 @@ def _delta_mixer(x, lw, spec: ModelSpec, *rest):
     return block(x, lw, spec, *rest)
 
 
+def _ssm_mixer(x, lw, spec: ModelSpec, *rest):
+    """An SSM layer's mixer by the rank of its step (the spec's data): 0,
+    Mamba-2 (a scalar decay a head); R > 0, Mamba-1's selective scan."""
+    block = _selective_block if spec.ssm_selective else _ssm_block
+    return block(x, lw, spec, *rest)
+
+
 # a state layer's mixer by its kind; all keep _delta_block's contract
-_STATE_MIXERS = {LayerKind.DELTA: _delta_mixer, LayerKind.SSM: _ssm_block}
+_STATE_MIXERS = {LayerKind.DELTA: _delta_mixer, LayerKind.SSM: _ssm_mixer}
 # the kinds whose mixer follows a slot map: its kernel hands a row's final
-# state to the row that continues it (ssd_chunk does; delta_rule_chunk not)
+# state to the row that continues it (ssd_chunk and selective_scan_chunk
+# do; delta_rule_chunk not)
 _CHAINING_MIXERS = frozenset({LayerKind.SSM})
 
 

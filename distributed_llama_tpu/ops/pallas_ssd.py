@@ -187,11 +187,19 @@ def last_rows(chained, n_valid):
                                              jnp.zeros((1,), bool)])
 
 
+# what a block's first dimension counts (`_call`): a program row's operand,
+# the state of the row's slot, or an operand every row shares (block 0)
+ROW, SLOT, SHARED = "row", "slot", "shared"
+
+
 def _call(body, name, operands, per_row, state, state_block, out_shape,
-          out_block, n_j, n_valid, fresh, interpret, chain=None):
-    """The pallas_call both kernels share: each operand's block with the
-    dimension its head block counts along (None: one block a row); the
-    state last and aliased onto the second output.
+          out_block, n_j, n_valid, fresh, interpret, chain=None,
+          state_dim: int = 1):
+    """The pallas_call both kernels share (ops/pallas_selective_scan.py's
+    too): each operand's block with the dimension its head block counts
+    along (None: one block a row) and, for an operand every row shares, a
+    third entry SHARED (block 0 along its first dimension); the state last, its blocks counted along `state_dim`, and aliased onto the
+    second output.
 
     chain None (ssd_decode): grid (rows, head blocks), row r is slot r, a
     gated row's blocks as _gated_blocks says.
@@ -213,25 +221,28 @@ def _call(body, name, operands, per_row, state, state_block, out_shape,
                    chained.astype(jnp.int32), row, slots[row])
         grid = (n_j, n_valid.shape[0])
 
-    def spec(block, head_dim, of_state=False):
+    def spec(block, head_dim, first=ROW):
         def at(i, j, nv, fr, rw, bk):
             on = nv[i] > 0
             idx = [0] * len(block)
-            idx[0] = jnp.where(on, i, rw[i])
+            if first != SHARED:     # row r is slot r: the state's too
+                idx[0] = jnp.where(on, i, rw[i])
             if head_dim is not None:
                 idx[head_dim] = jnp.where(on, j, bk[i])
             return tuple(idx)
 
         def at_slot(j, i, nv, fr, ch, rw, sl):
             idx = [0] * len(block)
-            idx[0] = (sl if of_state else rw)[i]
+            if first != SHARED:
+                idx[0] = (sl if first == SLOT else rw)[i]
             if head_dim is not None:
                 idx[head_dim] = j
             return tuple(idx)
         return pl.BlockSpec(block, at if chain is None else at_slot)
 
     operands = (*operands, state)
-    in_specs = [spec(b, d) for b, d in per_row] + [spec(state_block, 1, True)]
+    in_specs = [spec(*e) for e in per_row] + [spec(state_block, state_dim,
+                                                  SLOT)]
     out = jax.ShapeDtypeStruct(out_shape, jnp.float32)
     return pl.pallas_call(
         body,
@@ -239,7 +250,7 @@ def _call(body, name, operands, per_row, state, state_block, out_shape,
             num_scalar_prefetch=len(scalars),
             grid=grid,
             in_specs=in_specs,
-            out_specs=[spec(*out_block), spec(state_block, 1, True)],
+            out_specs=[spec(*out_block), spec(state_block, state_dim, SLOT)],
         ),
         out_shape=[out, jax.ShapeDtypeStruct(state.shape, state.dtype)],
         # the scalars come first; the state is written where it stands
@@ -334,24 +345,25 @@ def ssd_scan(x, dt, a, bm, cm, state, n_valid, fresh, slots=None,
     elif slots is None:
         y, state = _ssd_xla(x, dt, g, bm, cm, state, fresh)
     else:   # ssd_decode takes no map: a one-token CHUNK under one, too
-        y, state = _ssd_xla_mapped(x, dt, g, bm, cm, state, fresh, slots,
-                                   chained, last_rows(chained, n_valid))
+        y, state = _xla_mapped(_ssd_xla, (x, dt, g, bm, cm), state, fresh,
+                               slots, chained, last_rows(chained, n_valid))
     return jnp.where(live, y, 0.0), state
 
 
-def _ssd_xla_mapped(x, dt, g, bm, cm, state, fresh, slots, chained, last):
-    """The XLA twin under a slot map: the rows one after another, each
-    _ssd_xla's algebra on its own, from its slot's state (gathered) or,
-    where it continues the row before, from that row's end; a slot's last
-    live row writes its state back."""
+def _xla_mapped(twin, ops, state, fresh, slots, chained, last):
+    """An XLA twin under a slot map (ops/pallas_selective_scan.py's too):
+    the rows one after another, each `twin(*row's ops, its state, fresh)`
+    on its own, from its slot's state (gathered) or, where it continues the
+    row before, from that row's end; a slot's last live row writes its
+    state back."""
     def row(prev, xs):
         *ops, s0, fr, ch = xs
-        y, s1 = _ssd_xla(*(v[None] for v in ops),
-                         jnp.where(ch, prev, s0)[None], fr[None])
+        y, s1 = twin(*(v[None] for v in ops), jnp.where(ch, prev, s0)[None],
+                     fr[None])
         return s1[0], (y[0], s1[0])
 
     _, (y, ends) = lax.scan(row, jnp.zeros_like(state[0]),
-                            (x, dt, g, bm, cm, state[slots], fresh, chained))
+                            (*ops, state[slots], fresh, chained))
     return y, state.at[jnp.where(last, slots, state.shape[0])].set(
         ends, mode="drop")
 

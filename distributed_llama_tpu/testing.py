@@ -115,6 +115,26 @@ def tiny_kimi_spec(weights_float_type: FloatType = FloatType.Q40,
     return ModelSpec(**base)
 
 
+def tiny_jamba_spec(weights_float_type: FloatType = FloatType.Q40,
+                    **overrides) -> ModelSpec:
+    """JAMBA at a size a CPU holds: two periods of (SSM x 2, ATTENTION, SSM)
+    whose SSM layers are selective scans (Mamba-1) over 128 channels, every
+    channel a head of its own, with the state, the step's rank and the
+    convolution at their PUBLISHED sizes (16, 160, 4 taps with a bias); 4
+    query heads on ONE KV head without rotation; a dense SwiGLU in every
+    layer."""
+    period = (LayerKind.SSM,) * 2 + (LayerKind.ATTENTION, LayerKind.SSM)
+    base = dict(
+        arch=ArchType.JAMBA, dim=64, hidden_dim=128, n_layers=8, n_heads=4,
+        n_kv_heads=1, vocab_size=288, seq_len=160, hidden_act=HiddenAct.SILU,
+        rope_theta=0.0, weights_float_type=weights_float_type, rms_eps=1e-6,
+        mixers=tuple(int(k) for k in period * 2), ssm_heads=128,
+        ssm_head_dim=1, ssm_d_state=16, ssm_groups=1, ssm_conv_width=4,
+        ssm_conv_bias=1, ssm_dt_rank=160)
+    base.update(overrides)
+    return ModelSpec(**base)
+
+
 def free_port() -> int:
     """An OS-assigned free TCP port (shared by the cluster tests and the
     chaos harness spawners — one home for the bind-port-0 idiom)."""
@@ -215,9 +235,12 @@ def write_synthetic_model(path: str, spec: ModelSpec, seed: int) -> int:
                     # a depthwise convolution's default: uniform within
                     # 1 / sqrt(taps)
                     x = rng.uniform(-0.5, 0.5, n).astype(np.float32)
-                elif name.endswith("ssm_d"):
+                elif granite and name.endswith("ssm_d"):
                     # the skip term's weight, initialised to ones as
-                    # published (conv_b keeps the small gaussian)
+                    # published (conv_b keeps the small gaussian). JAMBA's
+                    # keeps the gaussian too: its file is the benchmark's
+                    # draw byte for byte (benchmark/weights.py has no rule
+                    # for ssm_d), and its tests draw D themselves
                     x += 1.0
                 elif name.endswith("moe_bias"):
                     # a router with preferences (std 0.5 beside scores in

@@ -822,3 +822,42 @@ def test_served_kimi_linear_step_programs_hold_their_kernels(topo, t):
     text = lowered.compile().as_text()
     for leaf in (cache.k[0], cache.s[0]):
         assert not r.cache_shaped_copies(text, leaf.shape)
+
+
+@pytest.mark.parametrize("t", [1, 16], ids=["decode", "chunk16"])
+def test_served_jamba_step_programs_hold_their_kernels(topo, t):
+    """`jamba2-3b`'s two step programs at published widths (two selective
+    scan layers and an attention layer of 20 query heads on ONE KV head;
+    B=16, S=8,192 as the cell serves it, the Q80 round trip on, the 65,536-row
+    head): the scan runs in its kernel of that program over a (16, 5120)
+    state a slot, the attention layer attends through `flash_attention`
+    (320 query rows a panel in a chunk) and writes its one-head rows through
+    `kv_cache_write`, the 10,240-row input projection and the 5120-wide
+    output projection tile, and the program holds no copy of a state or a
+    cache leaf."""
+    import rehearse_chip_compile as r
+
+    from distributed_llama_tpu.runtime.profiler import kernel_call_sites
+
+    spec = dataclasses.replace(r.JAMBA2_3B, n_layers=3, mixers=(3, 3, 0))
+    fn, args = r.abstract_step(spec, topo.devices, batch=16, t=t,
+                               seq_len=8192, q80=True)
+    assert (t == 1) == (len(args) == 4)    # the chunk's rows follow a map
+    cache = _cache_of(args)
+    assert (len(cache.k), len(cache.v), len(cache.s), len(cache.conv)) == (
+        1, 1, 2, 2)
+    assert cache.k[0].shape == cache.v[0].shape == (16, 1, 8192, 128)
+    assert cache.s[0].shape == (16, 16, 5120)
+    assert cache.conv[0].shape == (16, 3, 5120)
+    lowered = fn.lower(*args)
+    sites = kernel_call_sites(lowered.as_text())
+    mine, other = (("selective_scan_decode", "selective_scan_chunk")
+                   if t == 1 else
+                   ("selective_scan_chunk", "selective_scan_decode"))
+    assert sites.get(mine, 0) >= 1 and other not in sites, sites
+    for k in ("flash_attention", "kv_cache_write", "q40_matmul"):
+        assert sites.get(k, 0) >= 1, sites
+    assert "ssd_chunk" not in sites and "ssd_decode" not in sites
+    text = lowered.compile().as_text()
+    for leaf in (cache.k[0], cache.s[0]):
+        assert not r.cache_shaped_copies(text, leaf.shape)

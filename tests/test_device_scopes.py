@@ -1,5 +1,5 @@
 """Every op of the two served step programs under one name of the closed
-vocabulary models/scopes.DEVICE_SCOPES: the seven architectures' tiny models
+vocabulary models/scopes.DEVICE_SCOPES: the eight architectures' tiny models
 are compiled (on this CPU) and the compiled module's text read.
 
 Counted are the instructions that compute or move data: not parameters,
@@ -22,8 +22,8 @@ from distributed_llama_tpu.models.scopes import DEVICE_SCOPES
 from distributed_llama_tpu.models.spec import ArchType
 from distributed_llama_tpu.runtime.engine import Engine
 from distributed_llama_tpu.testing import (tiny_granite_spec, tiny_hybrid_spec,
-                                           tiny_kimi_spec, tiny_mla_spec,
-                                           tiny_spec)
+                                           tiny_jamba_spec, tiny_kimi_spec,
+                                           tiny_mla_spec, tiny_spec)
 
 B, CHUNK = 4, 8
 SPECS = {
@@ -36,6 +36,7 @@ SPECS = {
     "OLMO_HYBRID": tiny_hybrid_spec,
     "GRANITE_HYBRID": tiny_granite_spec,
     "KIMI_LINEAR": tiny_kimi_spec,
+    "JAMBA": tiny_jamba_spec,
 }
 # what each architecture's programs must show, beside the dense names
 OWN = {"MIXTRAL": {"moe_router", "moe_routed"},
@@ -44,6 +45,7 @@ OWN = {"MIXTRAL": {"moe_router", "moe_routed"},
        "OLMO_HYBRID": {"gdn_proj", "gdn_conv", "gdn_rule", "gdn_out"},
        "GRANITE_HYBRID": {"ssm_proj", "ssm_conv", "ssm_scan", "ssm_out",
                           "moe_router", "moe_routed", "moe_shared"},
+       "JAMBA": {"ssm_proj", "ssm_conv", "ssm_dt", "ssm_scan", "ssm_out"},
        "KIMI_LINEAR": {"kda_proj", "kda_conv", "kda_rule", "kda_out",
                        "mla_absorb", "moe_router", "moe_routed",
                        "moe_shared"}}
@@ -114,7 +116,7 @@ def test_every_op_of_a_served_step_program_is_under_a_device_scope(
 
 @pytest.mark.limit_s(30)
 def test_the_vocabulary_is_closed_and_no_name_holds_a_layer_index():
-    assert len(DEVICE_SCOPES) == len(set(DEVICE_SCOPES)) == 25
+    assert len(DEVICE_SCOPES) == len(set(DEVICE_SCOPES)) == 26
     assert all(re.fullmatch(r"[a-z]+(_[a-z0-9]+)*", s) and
                not re.search(r"\d+$", s.replace("q80", ""))
                for s in DEVICE_SCOPES)

@@ -1,7 +1,11 @@
 """What `benchmark/tests` pins of the END of the manifest, held on the
 manifest WITHOUT what PR 48 appended (an eighth cell, a sixth configuration,
-`kda_decode_roofline` and `kda_prefill_roofline`) and PR 49 after it (one
-per-layer metric, `prefill_tiles_per_expert_read`): a `model_config` PR puts
+`kda_decode_roofline` and `kda_prefill_roofline`), PR 49 after it (one
+per-layer metric, `prefill_tiles_per_expert_read`) and PR 52 after that (a
+ninth cell, a seventh configuration, `selscan_decode_roofline` and
+`selscan_prefill_roofline`, held by tests/test_jamba_bench.py; PR 48's and
+PR 49's entries are found by NAME here, whatever comes after them): a
+`model_config` PR puts
 its entries last and may edit no file under benchmark/, so
 test_capture_report_metrics.py's pin of the last four `per_layer` entries
 and its two runs of test_granite_hybrid.py's tests stand in
@@ -22,11 +26,17 @@ for p in (BENCH, os.path.join(BENCH, "tests"), REPO):
     if p not in sys.path:
         sys.path.insert(0, p)
 
-APPENDED = {"workloads": 1, "configs": 1, "per_layer": 3}   # by PRs 48, 49
+# by PRs 48, 49 and 52
+APPENDED = {"workloads": 2, "configs": 2, "per_layer": 5}
 
 with open(os.path.join(REPO, "BENCHMARK.json")) as f:
     M = json.load(f)
 BEFORE = {**M, **{k: M[k][:-n] for k, n in APPENDED.items()}}
+
+
+def _at(entries, name):
+    """Index of the entry of that name."""
+    return [e["name"] for e in entries].index(name)
 
 
 def _load(name):
@@ -43,11 +53,14 @@ def capture():
 
 
 def test_what_pr_48_appended_is_one_cell_one_configuration_two_metrics():
-    assert [w["name"] for w in M["workloads"][-1:]] == [
-        "kimi-linear-48b-a3b-ep4.long-doc"]
-    assert [c["name"] for c in M["configs"][-1:]] == [
-        "kimi-linear-48b-a3b-ep4"]
-    assert [m["name"] for m in M["per_layer"][-3:-1]] == [
+    # each FIRST after what stood before it, and the two metrics together
+    assert _at(M["workloads"], "kimi-linear-48b-a3b-ep4.long-doc") == len(
+        BEFORE["workloads"])
+    assert _at(M["configs"], "kimi-linear-48b-a3b-ep4") == len(
+        BEFORE["configs"])
+    at = _at(M["per_layer"], "kda_decode_roofline")
+    assert at == len(BEFORE["per_layer"])
+    assert [m["name"] for m in M["per_layer"][at:at + 2]] == [
         "kda_decode_roofline", "kda_prefill_roofline"]
     # no accepted entry lists the new cell (a model_config PR edits none)
     assert all("kimi-linear-48b-a3b-ep4.long-doc"
@@ -56,13 +69,15 @@ def test_what_pr_48_appended_is_one_cell_one_configuration_two_metrics():
 
 
 def test_what_pr_49_appended_is_one_counter_metric_as_data():
-    """`prefill_tiles_per_expert_read`, last in `per_layer`: the row tiles
+    """`prefill_tiles_per_expert_read`, next after PR 48's two: the row tiles
     one read of an expert serves in a chunk program, over the reader the
     benchmark had (`stats_delta_opt`: nothing to read, and no error, from a
     program without the counter), in the five cells whose model routes."""
     import traffic
 
-    last = M["per_layer"][-1]
+    last = M["per_layer"][_at(M["per_layer"],
+                              "prefill_tiles_per_expert_read")]
+    assert last is M["per_layer"][len(BEFORE["per_layer"]) + 2]
     moe = [w["name"] for w in M["workloads"] if w["config"].split("-")[0] in (
         "mixtral", "sarvam", "granite", "kimi")]
     assert last == {

@@ -29,9 +29,9 @@ from distributed_llama_tpu.models.params import load_params
 from distributed_llama_tpu.parallel import make_mesh
 from distributed_llama_tpu.quants.types import FloatType
 from distributed_llama_tpu.testing import (tiny_granite_spec,
-                                           tiny_hybrid_spec, tiny_kimi_spec,
-                                           tiny_mla_spec, tiny_spec,
-                                           write_synthetic_model)
+                                           tiny_hybrid_spec, tiny_jamba_spec,
+                                           tiny_kimi_spec, tiny_mla_spec,
+                                           tiny_spec, write_synthetic_model)
 
 from test_model_forward import make_spec
 
@@ -49,6 +49,7 @@ TINY = {
     "olmo_hybrid": tiny_hybrid_spec,
     "granite_hybrid": tiny_granite_spec,
     "kimi_linear": tiny_kimi_spec,
+    "jamba": tiny_jamba_spec,       # PR 52: taken on the tree that added it
 }
 # for the meshes only: wide enough that a column split over 4 keeps whole
 # Q40 blocks (2 kv heads, so tp=4 replicates them)
@@ -87,7 +88,7 @@ def tree_digest(params) -> str:
     return h.hexdigest()[:16]
 
 
-# -- (a) the plan, for the seven tiny specs and the six configurations -----
+# -- (a) the plan, for the eight tiny specs and the seven configurations ---
 
 PLAN = {
     "llama": "7faf3c30223224a7",
@@ -97,6 +98,8 @@ PLAN = {
     "olmo_hybrid": "ffa219fbfa0923de",
     "granite_hybrid": "1a3b5a22c9542aaf",
     "kimi_linear": "07f9b34584691917",
+    "jamba": "371d75fc34ab8ea9",
+    "jamba2-3b": "961b63c311d8e692",
     "granite-4.0-h-small-ep2": "05fe47507df5d413",
     "kimi-linear-48b-a3b-ep4": "baf2da44967312bf",
     "mistral-7b": "2170aa8622708951",
@@ -122,6 +125,7 @@ FILE = {
     "olmo_hybrid": 1186703354,
     "granite_hybrid": 1167398874,
     "kimi_linear": 1662979427,
+    "jamba": 2845572666,
 }
 
 
@@ -197,6 +201,12 @@ TREE = {
     "kimi_linear/streamed/q40/plain": "561b04dc1a703735",
     "kimi_linear/streamed/dense/fused": "0c43f0a9341ffea8",
     "kimi_linear/streamed/q40/fused": "f7d42c97c4c45166",
+    "jamba/bulk/dense/plain": "2b6b9c9a572f6e47",
+    "jamba/bulk/q40/plain": "6215afb5dd241b41",
+    "jamba/streamed/dense/plain": "2b6b9c9a572f6e47",
+    "jamba/streamed/q40/plain": "6215afb5dd241b41",
+    "jamba/streamed/dense/fused": "06dc4f503de97738",
+    "jamba/streamed/q40/fused": "24ac62055f8b031b",
 }
 
 
@@ -302,7 +312,8 @@ DECLARED_ONCE = (
     "rms_kv", "moe_bias", "wkva", "w_uk", "w_uv", "sh_w1", "sh_w2", "sh_w3",
     "wg", "w_ab", "conv_w", "a_log", "dt_bias", "rms_o", "rms_q", "rms_k",
     "w_fgb", "wf_b", "wg_b", "wz", "wx", "wzx", "w_bcdt", "conv_b", "ssm_d",
-    "wkvb", "wa", "wb", "wbc", "wdt", "wf_a", "wbeta", "wg_a")
+    "wkvb", "wa", "wb", "wbc", "wdt", "wf_a", "wbeta", "wg_a", "wxp",
+    "rms_dt", "rms_b", "rms_c")
 WALKERS = ("io/model_file.py", "models/loader.py", "models/params.py",
            "parallel/sharding.py")
 

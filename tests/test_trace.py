@@ -151,6 +151,40 @@ def test_sink_sampling_drops_unsampled_spans(tmp_path):
     assert len(t.by_id(7)) == 0  # reset cleared the ring too
 
 
+def test_step_timeline_tool_reads_what_the_sink_writes(tmp_path):
+    """tools/step_timeline.py over a sink's files (sample 0: the step
+    records stay): medians and means a span over the iterations that ran
+    both programs inside the window, the host's ms (wall less sched.wait)
+    and its median by decoding rows; decode-only iterations and those
+    before `skip_s` are left out."""
+    import os
+    import sys
+
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools"))
+    import step_timeline
+
+    t = Tracer()
+    t.configure(capacity=64, sink_dir=str(tmp_path / "tr"), sample=0.0)
+    t.event("enqueue", 9, n_prompt=4)               # sampled out
+    for i in range(60):
+        dec = 0 if i % 10 == 9 else 1 + i % 2       # every tenth: chunk only
+        t.step(decode_rows=dec, prefill_rows=3, chunk=16, queue_depth=0,
+               wall_ms=30.0 + dec, n=i, ts0=0.0,
+               phases={"sched.wait": 24.0, "sched.sample_emit": 2.0 * dec,
+                       "sched.dispatch.prefill": 3.0})
+    t.reset()
+    steps = step_timeline.steps_of(str(tmp_path / "tr"))
+    assert len(steps) == 54 and all(r["dec"] and r["pre"] for r in steps)
+    row = step_timeline.summary(steps, skip_s=0.0, window_s=60.0)
+    assert row["n"] == 54 and row["wait"] == [24.0, 24.0]
+    assert row["dispatch.prefill"] == [3.0, 3.0]
+    assert row["host_by_dec"] == {1: 7.0, 2: 8.0}
+    assert row["host"][0] in (7.0, 7.5, 8.0) and row["pre_mean"] == 3.0
+    assert step_timeline.summary(steps, skip_s=3600.0, window_s=1.0) == {
+        "n": 0}
+
+
 def test_span_reads_survive_concurrent_appends():
     """by_id/export_span run on pump/HTTP threads while step threads
     append lock-free: they must snapshot the deque first — iterating it

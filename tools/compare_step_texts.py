@@ -5,9 +5,10 @@ same on two trees? For a DESCRIBED v5e (no chip attached, nothing runs):
     JAX_PLATFORMS=cpu python tools/compare_step_texts.py diff <dir_a> <dir_b>
 
 `write` compiles, from the checkout at <tree>, both slot step programs of
-the benchmark's configurations (six since PR 48) AS THAT TREE'S ENGINE SERVES THEM
-(published widths, depth cut to a few layers or one period, B=8, the Q80
-round trip on; the chunk with a slot map where the tree's own rule gives
+the benchmark's configurations (six since PR 48, seven since PR 52) AS
+THAT TREE'S ENGINE SERVES THEM (published widths, depth cut to a few layers
+or one period, B=8 and chunks of 32, jamba2-3b's B=16 and chunks of 16, the
+Q80 round trip on; the chunk with a slot map where the tree's own rule gives
 it one) and keeps each program's text with its `metadata={...}` taken out. One process a tree: a process imports one
 `distributed_llama_tpu`. `diff` compares two such directories program by
 program: the text past its tables of source locations with every Pallas
@@ -49,14 +50,18 @@ def write(tree: str, out: str) -> None:
     if hasattr(r, "KIMI_LINEAR_48B_EP4"):     # a tree from PR 48 on
         configs["kimi-linear-48b-a3b-ep4"] = (
             r.hybrid_layers(r.KIMI_LINEAR_48B_EP4, 1), 8192)
+    if hasattr(r, "JAMBA2_3B"):               # a tree from PR 52 on
+        configs["jamba2-3b"] = (          # m m a m, 16 slots, chunks of 16
+            cut(r.JAMBA2_3B, n_layers=4, mixers=(3, 3, 0, 3)), 8192, 16, 16)
     os.makedirs(out, exist_ok=True)
-    for name, (spec, seq_len) in configs.items():
-        for t in (1, 32):
-            fn, args = r.abstract_step(spec, devices, batch=8, t=t,
+    for name, (spec, seq_len, *served) in configs.items():
+        batch, chunk = served or (8, 32)
+        for t in (1, chunk):
+            fn, args = r.abstract_step(spec, devices, batch=batch, t=t,
                                        seq_len=seq_len, q80=True)
             text = fn.lower(*args).compile().as_text()
             text = re.sub(r",? ?metadata=\{[^}]*\}", "", text)
-            key = f"{name}.{'decode' if t == 1 else 'chunk32'}"
+            key = f"{name}.{'decode' if t == 1 else f'chunk{chunk}'}"
             with open(os.path.join(out, key + ".txt"), "w") as f:
                 f.write(text)
             print(key, len(text), "bytes", flush=True)

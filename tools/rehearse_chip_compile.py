@@ -109,6 +109,19 @@ KIMI_LINEAR_48B_EP4 = ModelSpec(
     lin_beta_scale=1, lin_decay_dim=128)
 
 
+# AI21-Jamba2-3B as benchmark/configs/jamba2-3b.json serves it: whole on one
+# chip, 8,192 positions a slot, selective scans (Mamba-1) with layers 7
+# and 21 attending through 20 query heads on ONE KV head
+JAMBA2_3B = ModelSpec(
+    arch=ArchType.JAMBA, dim=2560, hidden_dim=8192, n_layers=28, n_heads=20,
+    n_kv_heads=1, vocab_size=65536, seq_len=8192,
+    hidden_act=HiddenAct.SILU, rope_theta=0.0, rms_eps=1e-6,
+    mixers=tuple(int(LayerKind.ATTENTION if l % 14 == 7 else LayerKind.SSM)
+                 for l in range(28)),
+    ssm_heads=5120, ssm_head_dim=1, ssm_d_state=16, ssm_groups=1,
+    ssm_conv_width=4, ssm_conv_bias=1, ssm_dt_rank=160)
+
+
 def hybrid_layers(spec: ModelSpec, periods: int, period: int = 4) -> ModelSpec:
     """The first `periods` periods of a hybrid's layer pattern."""
     n = period * periods
@@ -163,6 +176,21 @@ def _loaded_params(spec: ModelSpec, dtype) -> dict:
             else:
                 lw.update(wg=_zeros_q40(nh * dv, d),
                           w_ab=jnp.zeros((2 * nh, d), dtype))
+        elif spec.layer_kinds[l] == LayerKind.SSM and spec.ssm_selective:
+            inner, n, r = spec.ssm_inner, spec.ssm_d_state, spec.ssm_dt_rank
+            lw.update(
+                wz=_zeros_q40(inner, d), wx=_zeros_q40(inner, d),
+                wo=_zeros_q40(d, inner),
+                wxp=jnp.zeros((r + 2 * n, inner), dtype),
+                wdt=jnp.zeros((inner, r), dtype),
+                conv_w=jnp.zeros((spec.ssm_conv_width, inner), jnp.float32),
+                conv_b=jnp.zeros((inner,), jnp.float32),
+                a_log=jnp.zeros((n, inner), jnp.float32),
+                dt_bias=jnp.zeros((inner,), jnp.float32),
+                ssm_d=jnp.ones((inner,), jnp.float32),
+                rms_dt=jnp.ones((r,), jnp.float32),
+                rms_b=jnp.ones((n,), jnp.float32),
+                rms_c=jnp.ones((n,), jnp.float32))
         elif spec.layer_kinds[l] == LayerKind.SSM:
             nh, inner = spec.ssm_heads, spec.ssm_inner
             lw.update(
