@@ -121,17 +121,27 @@ def entry_points(max_devices: int | None = None,
     # write, bit-equal by tests/test_pallas_kv_write.py); gated rows pass
     # pos == seq_len. Any host callback
     # or f64 traced into this program stalls EVERY serving step — the
-    # audit is the CI gate the scheduler rides on.
+    # audit is the CI gate the scheduler rides on. Both slot programs of
+    # an engine without a mesh end with the sampling summary of their own
+    # logits (ops/sharded_vocab.step_summary; Engine._with_summary): one
+    # traced operand more (the temperatures, the vocabulary, whether to
+    # compute it), one small leaf out.
+    from ..ops.sharded_vocab import step_summary
+
     spec_s, params_s, tok_s, _, cache_s = build_forward_inputs(batch=4, t=1)
     pos_s = jnp.zeros((4,), jnp.int32)
+    sample_s = (np.ones((4 + 2,), np.float32),)
 
-    def slot_decode_step(params, tok, pos, cache):
-        return forward(params, spec_s, tok, pos, cache,
-                       compute_dtype=jnp.float32)
+    def summarised(outs, sample):
+        return (*outs, step_summary(outs[0], sample))
+
+    def slot_decode_step(params, tok, pos, cache, *sample):
+        return summarised(forward(params, spec_s, tok, pos, cache,
+                                  compute_dtype=jnp.float32), *sample)
 
     out.append(EntryPoint(
         "slot_decode_step", slot_decode_step,
-        (params_s, tok_s, pos_s, cache_s),
+        (params_s, tok_s, pos_s, cache_s, *sample_s),
         {"activation_elems": 4 * 1 * spec_s.dim, "dim": spec_s.dim}))
 
     # slot_prefill_chunk: (B, C) chunk at per-row offsets with per-row
@@ -141,13 +151,15 @@ def entry_points(max_devices: int | None = None,
     pos_c = jnp.zeros((4,), jnp.int32)
     lidx_c = jnp.full((4,), 7, jnp.int32)
 
-    def slot_prefill_chunk(params, tok, pos, logit_index, cache):
-        return forward(params, spec_c, tok, pos, cache,
-                       logit_index=logit_index, compute_dtype=jnp.float32)
+    def slot_prefill_chunk(params, tok, pos, logit_index, cache, *sample):
+        return summarised(
+            forward(params, spec_c, tok, pos, cache,
+                    logit_index=logit_index, compute_dtype=jnp.float32),
+            *sample)
 
     out.append(EntryPoint(
         "slot_prefill_chunk", slot_prefill_chunk,
-        (params_c, tok_c, pos_c, lidx_c, cache_c),
+        (params_c, tok_c, pos_c, lidx_c, cache_c, *sample_s),
         {"activation_elems": 4 * 8 * spec_c.dim, "dim": spec_c.dim}))
 
     # slot_prefill_chunk_mapped: the same chunk with the slot map, as an
@@ -158,14 +170,15 @@ def entry_points(max_devices: int | None = None,
     slots_c = jnp.arange(4, dtype=jnp.int32)
 
     def slot_prefill_chunk_mapped(params, tok, pos, logit_index, cache,
-                                  slots):
-        return forward(params, spec_c, tok, pos, cache,
-                       logit_index=logit_index, compute_dtype=jnp.float32,
-                       slots=slots)
+                                  slots, *sample):
+        return summarised(
+            forward(params, spec_c, tok, pos, cache,
+                    logit_index=logit_index, compute_dtype=jnp.float32,
+                    slots=slots), *sample)
 
     out.append(EntryPoint(
         "slot_prefill_chunk_mapped", slot_prefill_chunk_mapped,
-        (params_c, tok_c, pos_c, lidx_c, cache_c, slots_c),
+        (params_c, tok_c, pos_c, lidx_c, cache_c, slots_c, *sample_s),
         {"activation_elems": 4 * 8 * spec_c.dim, "dim": spec_c.dim}))
 
     # slot_prefill_chunk_state_mapped: the chunk with the slot map over
@@ -179,14 +192,15 @@ def entry_points(max_devices: int | None = None,
         tiny_granite_spec(seq_len=32), batch=4, t=8)
 
     def slot_prefill_chunk_state_mapped(params, tok, pos, logit_index, cache,
-                                        slots):
-        return forward(params, spec_g, tok, pos, cache,
-                       logit_index=logit_index, compute_dtype=jnp.float32,
-                       slots=slots)
+                                        slots, *sample):
+        return summarised(
+            forward(params, spec_g, tok, pos, cache,
+                    logit_index=logit_index, compute_dtype=jnp.float32,
+                    slots=slots), *sample)
 
     out.append(EntryPoint(
         "slot_prefill_chunk_state_mapped", slot_prefill_chunk_state_mapped,
-        (params_g, tok_c, pos_c, lidx_c, cache_g, slots_c),
+        (params_g, tok_c, pos_c, lidx_c, cache_g, slots_c, *sample_s),
         {"activation_elems": 4 * 8 * spec_g.dim, "dim": spec_g.dim}))
 
     # slot_seed_prefix: the radix prefix cache's admission-time seeding

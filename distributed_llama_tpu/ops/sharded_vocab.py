@@ -48,6 +48,7 @@ seed_rows_from_blocks discipline). Docs: docs/parallelism.md
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
@@ -131,6 +132,56 @@ def embed_tokens_sharded(emb, tokens, mesh, axes: tuple[str, ...],
 
 # -- sharded sampling prep ---------------------------------------------------
 
+# consecutive ids a block of top_candidates: 16 read fastest on a v5e of 8,
+# 16 and strided blocks, at 8 x 32,000 to 16 x 65,536 (PERF.md section 6,
+# PR 53)
+_BLOCK = 16
+
+
+def top_candidates(p, k: int):
+    """The k largest of each row of p (B, V), EXACT, in (value descending,
+    id ascending) order: (values (B, k), ids (B, k) int32). What
+    `lax.top_k(p, k)` returns, by two sorts of a few thousand values a row
+    where the TPU's TopK makes k passes over the vocabulary or sorts it
+    whole, whichever its compiler picks (11 ms and 0.4 ms at k = 512 over
+    8 x 50,176; this 0.1 ms: PERF.md section 6, PR 53):
+
+      1. the maximum of every block of _BLOCK consecutive ids; the k blocks
+         of the largest maxima, ties to the lower block;
+      2. those blocks' k * _BLOCK values, sorted by (value, id).
+
+    Every one of the k largest lies in those blocks: a value v at id i in a
+    block b that was not taken has k blocks before b in (maximum
+    descending, block ascending) order, each with a maximum >= b's >= v,
+    and one that only equals v is a LOWER block, all of whose ids are under
+    i; so k values precede (v, i) and it is not among the k largest. A row
+    too short for that to prune (under 2k blocks) is sorted whole. Both
+    keys are compared and no two pairs are equal, so the sorts need not be
+    stable (a stable sort of these widths takes the TPU's compiler twice
+    as long: 27 s against 12-16 s, and its time is a cold boot's)."""
+    b, v = p.shape
+    ids = jnp.arange(v, dtype=jnp.int32)
+
+    def largest(vals, idx):
+        neg, idx = lax.sort((-vals, idx), dimension=1, is_stable=False,
+                            num_keys=2)
+        return -neg[:, :k], idx[:, :k]
+
+    n_blocks = -(-v // _BLOCK)
+    if n_blocks < 2 * k:
+        return largest(p, jnp.broadcast_to(ids, (b, v)))
+    pad = n_blocks * _BLOCK - v          # under every probability
+    blocks = jnp.pad(p, ((0, 0), (0, pad)), constant_values=-1.0).reshape(
+        b, n_blocks, _BLOCK)
+    _, taken = largest(
+        jnp.max(blocks, axis=-1),
+        jnp.broadcast_to(jnp.arange(n_blocks, dtype=jnp.int32),
+                         (b, n_blocks)))
+    held = jnp.take_along_axis(blocks, taken[:, :, None], axis=1)
+    held_ids = taken[:, :, None] * _BLOCK + ids[:_BLOCK]
+    return largest(held.reshape(b, k * _BLOCK),
+                   held_ids.reshape(b, k * _BLOCK))
+
 
 def sample_prep_local(l_local, temps, base, n_vocab, k, axes):
     """Per-shard sampling summary over a (B, vocab/S) logits shard:
@@ -145,7 +196,10 @@ def sample_prep_local(l_local, temps, base, n_vocab, k, axes):
 
     temps is a traced (B,) float32 (per-row temperature — requests in a
     batch sample at different temperatures without new compile keys);
-    rows with temperature 0 pass 1.0 and ignore the sampled half."""
+    rows with temperature 0 pass 1.0 and ignore the sampled half.
+    n_vocab may be traced too. axes == () is the ONE-shard form
+    (sample_summary below): the collectives over no axis are the
+    identity, and the body is the same."""
     vloc = l_local.shape[-1]
     gid = base + jnp.arange(vloc, dtype=jnp.int32)
     valid = gid < n_vocab
@@ -161,8 +215,8 @@ def sample_prep_local(l_local, temps, base, n_vocab, k, axes):
     e = jnp.where(valid[None, :], jnp.exp(x - gmax[:, None]), 0.0)
     z = lax.psum(jnp.sum(e, axis=-1), axes)               # (B,)
     p = e / z[:, None]
-    top_p, top_i = lax.top_k(p, k)                        # (B, k) desc
-    top_id = base + top_i.astype(jnp.int32)
+    top_p, top_i = top_candidates(p, k)                   # (B, k) desc
+    top_id = base + top_i
     guard = top_p[:, k - 1]                               # k-th largest
     return (loc_max[:, None], loc_arg[:, None], top_p, top_id,
             guard[:, None])
@@ -202,3 +256,51 @@ def sharded_sample_prep(logits, temps, mesh, axes: tuple[str, ...],
     amax = jnp.min(jnp.where(lmax == best, larg, jnp.int32(2**31 - 1)),
                    axis=1).astype(jnp.int32)
     return amax, cand_p, cand_id, guard
+
+
+# -- the summary every served step returns -----------------------------------
+
+# candidates a row of the one-shard summary: the 0.9 nucleus of a head with
+# a trained model's ~3-nat logits holds 50-70 tokens at the median and under
+# 300 at the most over a vocabulary of 32k-65k (PERF.md section 6, PR 53)
+SUMMARY_TOPK = 512
+
+
+def step_summary(logits, sample):
+    """The LAST lines of a slot step program of an engine without a mesh
+    (runtime/engine.py; the audit's entry points and the compile
+    rehearsal trace this same body): the step's sampling summary, or
+    zeros of its shape from a step no row of which will be sampled (a
+    mid-prompt chunk, the benchmark's check; a real conditional: such a
+    step pays none of the device work), under the `head` scope
+    (models/scopes.py), whose logits it reads.
+
+    sample (B + 2,) float32, ONE traced operand and so one small transfer
+    a dispatch: the rows' temperatures, then the tokenizer's vocabulary
+    (exact in float32 under 2**24) and whether to compute the summary
+    (Engine._sample_operands: whether the caller gave temperatures)."""
+    b, v = logits.shape
+    k = min(SUMMARY_TOPK, v)
+    with jax.named_scope("head"):
+        return lax.cond(
+            sample[b + 1] > 0,
+            lambda: sample_summary(logits, sample[:b],
+                                   sample[b].astype(jnp.int32), k),
+            lambda: jnp.zeros((b, 1 + 2 * k), jnp.int32))
+
+
+def sample_summary(logits, temps, n_vocab, k: int):
+    """The one-shard form of the sampling summary, as step_summary above
+    puts it at the end of both slot step programs: (B, vocab) logits ->
+    (B, 1 + 2k) int32, one leaf and one transfer:
+
+      [:, 0]        the argmax over the tokenizer's vocabulary
+      [:, 1:1+k]    the bits of the top-k float32 probabilities at each
+                    row's temperature, descending (the k-th is the guard)
+      [:, 1+k:]     their token ids
+
+    runtime/sampling.unpack_summary is the host's half."""
+    _, amax, top_p, top_id, _ = sample_prep_local(
+        logits, temps, jnp.int32(0), n_vocab, k, ())
+    return jnp.concatenate(
+        [amax, lax.bitcast_convert_type(top_p, jnp.int32), top_id], axis=1)

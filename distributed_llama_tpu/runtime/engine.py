@@ -296,6 +296,9 @@ class Engine:
         # counters /stats surfaces: how often the sharded
         # fast path served a sample vs the replicated-row parity fallback
         self.vocab_sample_stats = {"sharded": 0, "fallback": 0}
+        # the newest slot step's own summary (_note_summary), for
+        # sample_view: (logits, packed summary, temps, n_vocab)
+        self._step_summary = None
 
         if tp == 1:
             # single-shard fast path: fused QKV / w1|w3 kernel calls
@@ -799,8 +802,15 @@ class Engine:
         return self.shard_vocab and not self._multihost
 
     def sample_view(self, logits, temps: np.ndarray | None, n_vocab: int):
-        """Sampling access to one step's (B, vocab) logits. Replicated
-        engines return a FullLogitsView (the fetch_logits + host-Sampler
+        """Sampling access to one step's (B, vocab) logits. Logits of a
+        slot step that computed its own summary (an engine without a
+        mesh: _summarises) are served from it: ONE small fetch, greedy
+        rows BIT-IDENTICAL to np.argmax, sampled rows through the
+        candidate scheme at the temperatures the step was dispatched
+        with, and the FIRST row that cannot be proven fetches the whole
+        array once for all of the step's unproven rows (what every step
+        paid before; no dispatch a row). Other logits of a replicated
+        engine return a FullLogitsView (the fetch_logits + host-Sampler
         oracle, exactly the pre-sharding path). Vocab-sharded engines
         run the sharded_sample_prep executable — device argmax +
         per-shard top-k candidates — and fetch ~(B, S·k) floats instead
@@ -812,10 +822,24 @@ class Engine:
         temps: (B,) float32 per-row temperatures (greedy rows pass 1.0 —
         a traced input, never a compile key). n_vocab: the tokenizer
         vocab the candidates/argmax truncate at (one compile key per
-        distinct value; rows whose sampler vocab differs fall back)."""
+        distinct value on a sharded vocab; rows whose sampler vocab
+        differs fall back)."""
         from ..ops.sharded_vocab import sharded_sample_prep
-        from .sampling import FullLogitsView, ShardedLogitsView
+        from .sampling import (FullLogitsView, ShardedLogitsView,
+                               unpack_summary)
 
+        own = self._step_summary
+        if own is not None and own[0] is logits and own[3] == n_vocab:
+            whole = []
+
+            def fetch_row(row: int) -> np.ndarray:
+                if not whole:
+                    whole.append(self.fetch_logits(logits))
+                return whole[0][row]
+
+            return ShardedLogitsView(
+                *unpack_summary(np.asarray(own[1])), int(n_vocab),
+                fetch_row, stats=self.vocab_sample_stats, temps=own[2])
         if not self.shard_sampling:
             return FullLogitsView(self.fetch_logits(logits))
         b = logits.shape[0]
@@ -864,14 +888,15 @@ class Engine:
         return fetch
 
     def warm_sample_ops(self, logits, n_vocab: int) -> None:
-        """Compile the sharded-sampling executables (prep + row gather)
-        against one step's logits — Scheduler.warmup calls this so
-        sampled traffic mints ZERO post-warmup keys (the vprep key set
-        is bounded: one per (batch, k, vocab))."""
-        if not self.shard_sampling:
-            return
+        """Run what sampling from one step's logits runs, fallback
+        included, against the warmed decode step's — Scheduler.warmup
+        calls this so sampled traffic mints ZERO post-warmup keys: the
+        sharded-sampling executables (prep + row gather; the vprep key
+        set is bounded: one per (batch, k, vocab)), or the whole-array
+        fetch behind a step's own summary."""
         view = self.sample_view(logits, None, n_vocab)
-        view.row(0)  # warms the "vrow" fallback executable too
+        if view.sharded:
+            view.row(0)  # the "vrow" executable / the whole fetch
 
     # -- generation -------------------------------------------------------
 
@@ -1542,9 +1567,62 @@ class Engine:
         an engine that takes no map are what they were."""
         return self.batch if self._chunk_slot_map else 1
 
+    @property
+    def _summarises(self) -> bool:
+        """Whether the two slot step programs end with the sampling
+        summary of their own logits (ops/sharded_vocab.step_summary):
+        an engine without a mesh. A vocab-sharded engine keeps its
+        separate prep executable, any other mesh the replicated fetch."""
+        return self.mesh is None
+
+    def _sample_operands(self, temps, n_vocab) -> tuple:
+        """The summary's ONE traced operand of a slot step program (none
+        where the engine does not summarise), as
+        ops/sharded_vocab.step_summary takes it: the (B,) temperatures,
+        the tokenizer's vocabulary (the model's unless given) and whether
+        this step computes its summary at all: a caller that gives no
+        temperatures samples no row of the step (a mid-prompt chunk, the
+        benchmark's check), and the step skips the summary's device work
+        and its fetch. Never a compile key: the check's call, the
+        warm-up's and the scheduler's enter one executable."""
+        if not self._summarises:
+            return ()
+        sample = np.ones((self.batch + 2,), np.float32)
+        if temps is not None:
+            sample[:self.batch] = temps
+        sample[self.batch:] = (n_vocab or self.spec.vocab_size,
+                               temps is not None)
+        return (sample,)
+
+    def _with_summary(self, out: tuple, sample: tuple) -> tuple:
+        """The last lines of a slot step program: forward's outputs and,
+        where the engine summarises, the packed sampling summary of the
+        logits (the program's LAST output; the logits stay an output and
+        stay on the device)."""
+        if not sample:
+            return out
+        from ..ops.sharded_vocab import step_summary
+
+        return (*out, step_summary(out[0], *sample))
+
+    def _note_summary(self, logits, rest: list, sample: tuple) -> None:
+        """Keep the step's summary beside its logits for sample_view, its
+        copy to the host started (one small leaf, one transfer); none of
+        a step that skipped it."""
+        if sample:
+            packed = rest.pop()
+            self._step_summary = None
+            operand, = sample
+            if operand[-1]:
+                packed.copy_to_host_async()
+                self._step_summary = (logits, packed, operand[:-2],
+                                      int(operand[-2]))
+
     def slot_prefill_chunk(self, tokens: np.ndarray, pos: np.ndarray,
                            logit_index: np.ndarray,
-                           slots: np.ndarray | None = None) -> jax.Array:
+                           slots: np.ndarray | None = None, *,
+                           temps: np.ndarray | None = None,
+                           n_vocab: int | None = None) -> jax.Array:
         """One chunked-prefill forward over the batched cache: row r writes
         its (B, C) chunk's K/V at absolute offsets pos[r]..pos[r]+C-1 via
         the per-row write path, without disturbing any other row. Rows
@@ -1568,6 +1646,13 @@ class Engine:
         enters the SAME program as an arange: one chunk executable an
         engine, with or without chaining.
 
+        temps (B,) float32 and n_vocab, where the engine summarises
+        (_summarises): the temperature a program row's candidates are
+        computed at (greedy and gated rows pass 1.0) and the tokenizer's
+        vocabulary; both traced. The summary stays inside the engine
+        (sample_view finds it by these logits); without temps no row of
+        the step will be sampled and the step computes none.
+
         The chunk width C is the ONLY compilation key
         (slot_prefill_chunk_C): the scheduler pads every tail chunk to a
         fixed C, so admission order/prompt lengths never mint new
@@ -1587,10 +1672,13 @@ class Engine:
             common = dict(self._forward_kwargs(),
                           expert_counts=self._counts_experts)
 
-            def run(params, tokens, pos0, logit_index, cache, *slots):
-                return forward(params, self.spec, tokens, pos0, cache,
-                               logit_index=logit_index, **common,
-                               slots=slots[0] if slots else None)
+            def run(params, tokens, pos0, logit_index, cache, *rest):
+                slots, sample = ((rest[0], rest[1:]) if mapped
+                                 else (None, rest))
+                return self._with_summary(
+                    forward(params, self.spec, tokens, pos0, cache,
+                            logit_index=logit_index, **common, slots=slots),
+                    sample)
 
             run.__name__ = f"slot_prefill_chunk_{c}"
             self._mint(key, jax.jit(run, donate_argnums=(4,)))
@@ -1606,18 +1694,23 @@ class Engine:
                 self._identity_map = jnp.asarray(np.arange(b, dtype=np.int32))
             the_map = (self._identity_map if slots is None
                        else jnp.asarray(slots, jnp.int32),)
-        logits, self.cache, *counts = self._steps[key](
+        sample = self._sample_operands(temps, n_vocab)
+        logits, self.cache, *rest = self._steps[key](
             self.params, tok, posv, jnp.asarray(logit_index, jnp.int32),
-            self.cache, *the_map)
-        self._note_expert_counts("prefill", *counts)
+            self.cache, *the_map, *sample)
+        self._note_summary(logits, rest, sample)
+        self._note_expert_counts("prefill", *rest)
         return logits
 
-    def slot_decode_step(self, tokens: np.ndarray, pos: np.ndarray) -> jax.Array:
+    def slot_decode_step(self, tokens: np.ndarray, pos: np.ndarray, *,
+                         temps: np.ndarray | None = None,
+                         n_vocab: int | None = None) -> jax.Array:
         """One decode step for the slot scheduler: row r feeds tokens[r]
         at its own absolute position pos[r] (per-row write, in place in
         the donated cache). Rows without a decode token this step pass pos[r]
         == seq_len — their write drops out of bounds and their logits row
-        is ignored. One compilation key total ("slot_decode"); self.pos is
+        is ignored. temps and n_vocab: as slot_prefill_chunk's. One
+        compilation key total ("slot_decode"); self.pos is
         untouched (per-slot positions are the scheduler's)."""
         b, t = tokens.shape
         assert b == self.batch and t == 1, (tokens.shape, self.batch)
@@ -1626,9 +1719,10 @@ class Engine:
             common = dict(self._forward_kwargs(),
                           expert_counts=self._counts_experts)
 
-            def run(params, tokens, pos0, cache):
-                return forward(params, self.spec, tokens, pos0, cache,
-                               **common)
+            def run(params, tokens, pos0, cache, *sample):
+                return self._with_summary(
+                    forward(params, self.spec, tokens, pos0, cache,
+                            **common), sample)
 
             run.__name__ = "slot_decode_step"
             self._mint(key, jax.jit(run, donate_argnums=(3,)))
@@ -1638,9 +1732,11 @@ class Engine:
             tok = jax.device_put(tok, self._token_sharding)
             posv = jax.device_put(posv,
                                   NamedSharding(self.mesh, P(DP_AXIS)))
-        logits, self.cache, *counts = self._steps[key](
-            self.params, tok, posv, self.cache)
-        self._note_expert_counts("decode", *counts)
+        sample = self._sample_operands(temps, n_vocab)
+        logits, self.cache, *rest = self._steps[key](
+            self.params, tok, posv, self.cache, *sample)
+        self._note_summary(logits, rest, sample)
+        self._note_expert_counts("decode", *rest)
         return logits
 
     def slot_verify_step(self, tokens: np.ndarray, pos: np.ndarray,

@@ -1,8 +1,10 @@
-"""Host side of vocab-sharded sampling (ops/sharded_vocab.py).
+"""Host side of summary sampling (ops/sharded_vocab.py).
 
-The device half ships tiny per-shard summaries — the global argmax and
-k candidates per shard with an exactness guard; this module turns them
-into tokens with the host Sampler's exact semantics:
+The device half ships a tiny summary of a step's logits: the global
+argmax and k candidates per vocab shard with an exactness guard (one
+shard on an engine without a mesh, whose two slot step programs compute
+the summary themselves: ops/sharded_vocab.sample_summary); this module
+turns it into tokens with the host Sampler's exact semantics:
 
   * :func:`sample_candidates` — the oracle's top-p nucleus walk run on
     the merged candidates, EXACT whenever the truncation point provably
@@ -15,8 +17,11 @@ into tokens with the host Sampler's exact semantics:
     is the replicated parity oracle (host Sampler on fetched logits,
     exactly the pre-sharding path); the sharded view serves greedy rows
     BIT-IDENTICALLY from the device argmax, sampled rows from the
-    candidate scheme, and falls back to ONE replicated (vocab,) row
-    fetch — never the (B, vocab) array — for anything unprovable.
+    candidate scheme, and falls back for anything unprovable: to ONE
+    replicated (vocab,) row fetch — never the (B, vocab) array — where
+    the vocab is sharded, to ONE fetch of the whole array for all of a
+    step's unproven rows on one shard (what every step paid before the
+    summary).
 
 Docs: docs/parallelism.md ("Vocab sharding") carries the exactness
 argument in full.
@@ -40,7 +45,8 @@ def draw_coin(sampler) -> float:
 
 
 def sample_candidates(sampler, cand_p: np.ndarray, cand_id: np.ndarray,
-                      guard: np.ndarray, argmax_tok: int) -> int | None:
+                      guard: np.ndarray, argmax_tok: int,
+                      ordered: bool = False) -> int | None:
     """Sample one token from the per-shard top-k candidate summary,
     EXACTLY distributed as ``sampler.sample`` on the full logits — or
     return None when exactness cannot be proven from the candidates
@@ -64,16 +70,26 @@ def sample_candidates(sampler, cand_p: np.ndarray, cand_id: np.ndarray,
     Only the nucleus mode (0 < topp < 1) is candidate-exact; pure
     multinomial (topp <= 0 or >= 1) needs the full CDF and always
     falls back. Temperature 0 never lands here (the caller returns the
-    sharded argmax, bit-identical to np.argmax)."""
+    sharded argmax, bit-identical to np.argmax).
+
+    ordered: the candidates arrive in the oracle's (prob desc, id asc)
+    order already (one shard's: ops/sharded_vocab.top_candidates sorts
+    them so), and the sort here, most of a walk's time, is left out."""
     topp = float(sampler.topp)
     if topp <= 0.0 or topp >= 1.0:
         return None
     n = int(sampler.vocab_size)
-    v_guard = float(np.max(guard))
+    v_guard = float(guard.max())
     cutoff = (1.0 - topp) / (n - 1)
     keep = cand_p >= cutoff
-    p = cand_p[keep]
-    ids = cand_id[keep]
+    if ordered:     # the kept ones are a prefix: views, no copies, no sort
+        n_keep = int(np.count_nonzero(keep))
+        p, ids = cand_p[:n_keep], cand_id[:n_keep]
+    else:
+        p, ids = cand_p[keep], cand_id[keep]
+        # the oracle's stable descending sort == (prob desc, id asc)
+        order = np.lexsort((ids, -p))
+        p, ids = p[order], ids[order]
     if p.size == 0:
         # the oracle's empty-nucleus branch keeps the (first) argmax —
         # which the sharded argmax already pinned; exact only when no
@@ -82,15 +98,11 @@ def sample_candidates(sampler, cand_p: np.ndarray, cand_id: np.ndarray,
             return None
         draw_coin(sampler)  # the oracle consumes its coin here too
         return int(argmax_tok)
-    # the oracle's stable descending sort == (prob desc, id asc)
-    order = np.lexsort((ids, -p))
-    p = p[order]
-    ids = ids[order]
-    cum = np.cumsum(p.astype(np.float64))
-    over = np.nonzero(cum > topp)[0]
+    cum = np.cumsum(p, dtype=np.float64)
+    # the first index whose cumulative mass is over topp (cum never falls)
+    last = int(np.searchsorted(cum, topp, side="right"))
     exact_all = v_guard < cutoff
-    if over.size:
-        last = int(over[0])
+    if last < len(cum):
         if not exact_all and not (p[last] > v_guard):
             return None  # truncation point at/below the guard: a hidden
             # token could belong above it
@@ -106,7 +118,32 @@ def sample_candidates(sampler, cand_p: np.ndarray, cand_id: np.ndarray,
     return int(ids[idx])
 
 
-class FullLogitsView:
+def unpack_summary(packed: np.ndarray):
+    """(argmax (B,), cand_p (B, k), cand_id (B, k), guard (B, 1)) from the
+    one leaf ops/sharded_vocab.sample_summary packs: the k-th candidate is
+    the guard (one shard: every token that is no candidate lies at or
+    below it)."""
+    k = (packed.shape[1] - 1) // 2
+    cand_p = packed[:, 1:1 + k].view(np.float32)
+    return packed[:, 0], cand_p, packed[:, 1 + k:], cand_p[:, k - 1:]
+
+
+class _CountedView:
+    """What both views count: `window` is the scheduler's ServeStats (or
+    None), whose `sampled_rows` counts every row a view sampled or took
+    the argmax for and `sampled_rows_summary` those served from the
+    summary alone."""
+
+    window = None
+
+    def _count_row(self, summary: bool) -> None:
+        w = self.window
+        if w is not None:
+            w.sampled_rows += 1
+            w.sampled_rows_summary += summary
+
+
+class FullLogitsView(_CountedView):
     """The replicated parity oracle: full (B, vocab) logits on host,
     every row sampled by the host Sampler exactly as before vocab
     sharding existed."""
@@ -117,29 +154,38 @@ class FullLogitsView:
         self.lg = logits_np
 
     def argmax(self, row: int, n_vocab: int) -> int:
+        self._count_row(False)
         return int(np.argmax(self.lg[row, :n_vocab]))
 
     def sample(self, sampler, row: int) -> int:
+        self._count_row(False)
         return int(sampler.sample(self.lg[row]))
 
     def row(self, row: int) -> np.ndarray:
         return self.lg[row]
 
 
-class ShardedLogitsView:
+class ShardedLogitsView(_CountedView):
     """Sampling access to one step's logits WITHOUT the (B, vocab)
     fetch: greedy rows read the device argmax, sampled rows run the
     candidate scheme, and anything the candidates cannot prove exact —
-    guard failures, pure-multinomial requests, foreign sampler vocabs —
-    fetches ONE replicated (vocab,) row through `fetch_row` (the warmed
-    parity-oracle executable) and samples the oracle way. `stats` is a
-    plain dict the engine owns: {"sharded", "fallback"} counters."""
+    guard failures, pure-multinomial requests, foreign sampler vocabs, a
+    temperature other than the one the candidates were computed at —
+    reads the row through `fetch_row` (the parity oracle: a warmed
+    (vocab,) row gather on a sharded vocab, one cached fetch of the
+    whole array on one shard) and samples the oracle way. `stats` is a
+    plain dict the engine owns: {"sharded", "fallback"} counters.
+    `temps`: the (B,) float32 temperatures the candidates were computed
+    at, where the caller of `sample` did not choose them itself (the
+    step programs' own summary, computed at the dispatch); such
+    candidates, one shard's, also arrive in the oracle's order."""
 
     sharded = True
 
     def __init__(self, amax: np.ndarray, cand_p: np.ndarray,
                  cand_id: np.ndarray, guard: np.ndarray, n_vocab: int,
-                 fetch_row, stats: dict | None = None):
+                 fetch_row, stats: dict | None = None,
+                 temps: np.ndarray | None = None):
         self.amax = amax
         self.cand_p = cand_p
         self.cand_id = cand_id
@@ -147,9 +193,11 @@ class ShardedLogitsView:
         self.n_vocab = int(n_vocab)
         self._fetch_row = fetch_row
         self.stats = stats if stats is not None else {}
+        self.temps = temps
 
     def _count(self, key: str) -> None:
         self.stats[key] = self.stats.get(key, 0) + 1
+        self._count_row(key == "sharded")
 
     def argmax(self, row: int, n_vocab: int) -> int:
         if n_vocab == self.n_vocab:
@@ -168,9 +216,13 @@ class ShardedLogitsView:
                 # same vocab and tie-breaks to the lowest index (ONE
                 # greedy implementation — argmax() above)
                 return self.argmax(row, self.n_vocab)
-            tok = sample_candidates(sampler, self.cand_p[row],
-                                    self.cand_id[row], self.guard[row],
-                                    int(self.amax[row]))
+            tok = None
+            if (self.temps is None or self.temps[row]
+                    == np.float32(sampler.temperature)):
+                tok = sample_candidates(sampler, self.cand_p[row],
+                                        self.cand_id[row], self.guard[row],
+                                        int(self.amax[row]),
+                                        ordered=self.temps is not None)
             if tok is not None:
                 self._count("sharded")
                 return tok

@@ -784,31 +784,47 @@ class Scheduler:
                 # overstate the denominator for requests cancelled or
                 # expired mid-prefill)
 
+    def _row_temps(self, rows: list[_Slot], at=None) -> np.ndarray:
+        """Each sampling row's temperature, a traced input of whatever
+        computes the row's candidates (greedy and gated rows pass 1.0).
+        `at`: the program row each slot's logits stand in (its own index
+        unless a chunk's rows were chained)."""
+        temps = np.ones((self.engine.batch,), np.float32)
+        for s, r in zip(rows, at or [s.idx for s in rows]):
+            t = getattr(s.req.sampler, "temperature", 0.0)
+            if t:
+                temps[r] = t
+        return temps
+
+    def _sample_operands(self, rows: list[_Slot], at=None) -> dict:
+        """What a slot step program is dispatched with so that it can end
+        with the sampling summary of `rows` (Engine.slot_decode_step);
+        nothing for a duck-typed test engine, whose logits are fetched."""
+        if not hasattr(self.engine, "sample_view"):
+            return {}
+        return {"temps": self._row_temps(rows, at),
+                "n_vocab": self.sample_vocab}
+
     def _sample_view(self, logits, rows: list[_Slot], at=None):
         """Wrap one forward's on-device logits for host sampling
-        (Engine.sample_view): vocab-sharded engines serve the rows from
-        the tiny argmax/candidate summary instead of a (B, vocab)
-        fetch; replicated engines (and duck-typed test engines) get the
-        classic full-logits view. temps carries each sampling row's
-        temperature as a traced input (greedy rows pass 1.0). `at`: the
-        program row each slot's logits stand in (its own index unless a
-        chunk's rows were chained)."""
+        (Engine.sample_view): the rows are served from the tiny
+        argmax/candidate summary (the step program's own, computed at the
+        dispatch with _sample_operands, or a vocab-sharded engine's
+        separate prep at these rows' temperatures) instead of a
+        (B, vocab) fetch; logits without one (a verify step's position
+        0 on one chip, a duck-typed test engine's) get the classic
+        full-logits view."""
         eng = self.engine
         sv = getattr(eng, "sample_view", None)
-        if sv is not None:
-            temps = np.ones((eng.batch,), np.float32)
-            for s, r in zip(rows, at or [s.idx for s in rows]):
-                t = getattr(s.req.sampler, "temperature", 0.0)
-                if t:
-                    temps[r] = t
         t0 = self._wait_begin()
         if sv is None:
             from .sampling import FullLogitsView
 
             view = FullLogitsView(eng.fetch_logits(logits))
         else:
-            view = sv(logits, temps, self.sample_vocab)
+            view = sv(logits, self._row_temps(rows, at), self.sample_vocab)
         self._wait_end(t0)
+        view.window = self.stats
         return view
 
     def _wait_begin(self) -> float:
@@ -908,13 +924,16 @@ class Scheduler:
                 TRACER.event("prefill", s.req.trace_id, off=off,
                              n=s.off - off, slot=s.idx,
                              step=self.stats.steps)
+        done = ([s for s, _ in finishing], [r for _, r in finishing])
+        # a mid-prompt chunk samples no row: it is dispatched without
+        # temperatures, and the step computes no summary
         logits = eng.slot_prefill_chunk(
-            tok, pos, lidx, *(() if slots is None else (slots,)))
+            tok, pos, lidx, *(() if slots is None else (slots,)),
+            **(self._sample_operands(*done) if finishing else {}))
         if not finishing:
             self._count_experts()
             return len(live)  # mid-prompt chunk: no D2H fetch at all
-        view = self._sample_view(logits, [s for s, _ in finishing],
-                                 at=[r for _, r in finishing])
+        view = self._sample_view(logits, *done)
         for s, r in finishing:
             s.pos = len(s.req.prompt)
             if self.prefix_cache is not None:
@@ -953,7 +972,8 @@ class Scheduler:
             tok[s.idx, 0] = s.last
             pos[s.idx] = s.pos
             self.stats.attn_pairs_decode += s.pos + 1
-        logits = eng.slot_decode_step(tok, pos)
+        logits = eng.slot_decode_step(tok, pos,
+                                      **self._sample_operands(live))
         view = self._sample_view(logits, live)
         for s in live:
             s.pos += 1
@@ -1215,13 +1235,15 @@ class Scheduler:
             for w in widths:
                 eng.slot_prefill_chunk(np.zeros((eng.batch, w), np.int32),
                                        gate, np.zeros((eng.batch,), np.int32))
-            lg = eng.slot_decode_step(np.zeros((eng.batch, 1), np.int32),
-                                      gate)
-            # vocab-sharded engines: compile the sharded sample-prep +
-            # per-row fallback executables against the warmed decode
-            # step's logits — sampled traffic then mints ZERO
-            # post-warmup keys (the prefill/verify paths share the same
-            # batch-shaped keys)
+            lg = eng.slot_decode_step(
+                np.zeros((eng.batch, 1), np.int32), gate,
+                **self._sample_operands([]))
+            # run what sampling runs against the warmed decode step's
+            # logits, fallback included (the step's own summary and the
+            # whole fetch behind it; a vocab-sharded engine's sample-prep
+            # + per-row fallback executables) — sampled traffic then
+            # mints ZERO post-warmup keys (the prefill/verify paths share
+            # the same batch-shaped keys)
             warm_sample = getattr(eng, "warm_sample_ops", None)
             if warm_sample is not None:
                 warm_sample(lg, self.sample_vocab)

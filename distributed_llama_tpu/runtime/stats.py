@@ -238,7 +238,8 @@ WINDOW_COUNTERS = ("admitted", "queue_wait_ms_sum", "prefill_steps",
                    "prefill_cached_tokens",
                    "expert_reads_decode", "expert_reads_prefill",
                    "expert_pairs_decode", "expert_pairs_prefill",
-                   "expert_tiles_decode", "expert_tiles_prefill")
+                   "expert_tiles_decode", "expert_tiles_prefill",
+                   "sampled_rows", "sampled_rows_summary")
 
 
 class FrontDoorStats:
@@ -329,6 +330,12 @@ class ServeStats:
     expert_pairs_prefill: int = 0
     expert_tiles_decode: int = 0
     expert_tiles_prefill: int = 0
+    # counted by the sampling views (runtime/sampling._CountedView): every
+    # row a view sampled or took the argmax for, and of them the rows
+    # served from the step's summary alone (the device argmax, or a
+    # candidate walk the guard proved; the rest read the fetched logits)
+    sampled_rows: int = 0
+    sampled_rows_summary: int = 0
     # gauges, set by the Scheduler: cache bytes one token holds over the
     # layers that HAVE a cache (K and V leaves, or the latent cache's one
     # leaf), and the bytes a slot holds whatever its context (the DELTA

@@ -607,6 +607,11 @@ _COUNTERS = (
     ("expert_tiles_prefill", "dllama_expert_tiles_prefill_total",
      "Row tiles of the grouped expert call that hold a real prompt "
      "token's pair, summed over MoE layers"),
+    ("sampled_rows", "dllama_sampled_rows_total",
+     "Rows a sampling view sampled or took the argmax for"),
+    ("sampled_rows_summary", "dllama_sampled_rows_summary_total",
+     "Of the sampled rows: served from the step's sampling summary alone "
+     "(the rest read the fetched logits)"),
 )
 
 _GAUGES = (

@@ -452,6 +452,47 @@ def test_step_programs_keep_no_cache_sized_copy(topo, model, t):
     assert not copies, copies
 
 
+@pytest.mark.parametrize("model,t", [("granite_4_h_small_ep2", 1),
+                                     ("mistral_7b", 32)],
+                         ids=["granite-decode", "mistral-chunk32"])
+def test_step_programs_return_the_logits_and_the_sampling_summary(topo, model,
+                                                                  t):
+    """Both step programs of an engine without a mesh (PR 53; the chunk of
+    `mistral-7b` cut to one layer at 32,000 words, the decode step of
+    `granite-4.0-h-small-ep2` cut to one SSM layer at 50,176: the TPU's
+    compiler takes ~15 s a sort, so one program a vocabulary; B=8) compile
+    for the described v5e with the sampling
+    summary as their last lines: the (B, vocab) float32 logits stay the
+    FIRST output, the packed (B, 1 + 2 x 512) int32 summary is the LAST,
+    and the compiled text holds no `copy` of a vocabulary-sized array that
+    the same program without the summary lacks (the candidates are found
+    by two sorts of a few thousand values a row, under a conditional the
+    step may skip: nothing of the logits is re-laid for them)."""
+    import re
+
+    import rehearse_chip_compile as r
+
+    spec = (dataclasses.replace(r.MISTRAL_7B, n_layers=1)
+            if model == "mistral_7b"
+            else r.hybrid_layers(r.GRANITE_4_H_SMALL_EP2, 1, 1))
+    texts = {}
+    for summary in (True, False):
+        fn, args = r.abstract_step(spec, topo.devices, batch=8, t=t,
+                                   seq_len=4096, q80=True, summary=summary)
+        lowered = fn.lower(*args)
+        outs = jax.tree_util.tree_leaves(lowered.out_info)
+        assert (outs[0].shape, outs[0].dtype) == ((8, spec.vocab_size),
+                                                  jnp.float32)
+        if summary:
+            assert (outs[-1].shape, outs[-1].dtype) == ((8, 1025), jnp.int32)
+            assert args[-1].shape == (8 + 2,)
+        texts[summary] = lowered.compile().as_text()
+    wide = re.compile(r"= \w+\[(?:\d+,)*%d\]\S* copy\(" % spec.vocab_size)
+    copies = {k: len(wide.findall(text)) for k, text in texts.items()}
+    assert copies[True] <= copies[False], copies
+    assert "sort(" in texts[True] and "conditional(" in texts[True]
+
+
 @pytest.mark.parametrize("model", ["mistral_7b", "mixtral_8x7b_12l",
                                    "granite_4_h_small_ep2"])
 def test_the_chunk_with_the_slot_map_holds_its_kernels_and_no_cache_copy(
@@ -460,7 +501,9 @@ def test_the_chunk_with_the_slot_map_holds_its_kernels_and_no_cache_copy(
     slot's segments (`mistral-7b`: two layers; `mixtral-8x7b-12l` AS
     SERVED; `granite-4.0-h-small-ep2`: one whole period of its layers, nine
     SSM and one attention, each with its 36 held experts; B=8, S=4096 or
-    8192, the Q80 round trip on) takes the slot map as a sixth argument, as
+    8192, the Q80 round trip on) takes the slot map as a sixth argument
+    (before the sampling summary's one, which every program without a mesh
+    takes), as
     `abstract_step` decides with the engine's rule: the chip's compiler
     accepts `kv_cache_write` and `flash_attention` with the second
     prefetched scalar and `ssd_chunk` with its grid head blocks outermost
@@ -486,7 +529,7 @@ def test_the_chunk_with_the_slot_map_holds_its_kernels_and_no_cache_copy(
         bare, bare_args = r.abstract_step(spec, topo.devices, batch=8, t=32,
                                           seq_len=4096, q80=True,
                                           slot_map=False)
-        assert len(bare_args) == 5
+        assert len(bare_args) == 6      # no map; the summary's operand
         assert _has_kernel(bare.lower(*bare_args).compile())
     elif model == "granite_4_h_small_ep2":
         spec = r.hybrid_layers(r.GRANITE_4_H_SMALL_EP2, 1, 10)
@@ -502,7 +545,8 @@ def test_the_chunk_with_the_slot_map_holds_its_kernels_and_no_cache_copy(
         spec, args, lowered, compiled = served_moe_step(model, 32)
         kernels = {"q40_matmul", "q40_expert_matmul", "flash_attention",
                    "kv_cache_write"}
-    assert len(args) == 6 and args[5].shape == (8,)         # the map
+    # the map, then the summary's operand (PR 53)
+    assert len(args) == 7 and args[5].shape == (8,)
     sites = kernel_call_sites(lowered.as_text())
     assert kernels <= set(sites), sites
     for shape in [_cache_of(args).k[0].shape] + leaves:
@@ -512,9 +556,9 @@ def test_the_chunk_with_the_slot_map_holds_its_kernels_and_no_cache_copy(
     def one(s):
         return dataclasses.replace(s, n_layers=1, mixers=s.mixers[:1])
 
-    for other, tp, n_args in ((r.OLMO_HYBRID_7B, 1, 5),
-                              (r.SARVAM_105B_EP8, 1, 5),
-                              (r.GRANITE_4_H_SMALL_EP2, 1, 6),
+    for other, tp, n_args in ((r.OLMO_HYBRID_7B, 1, 6),
+                              (r.SARVAM_105B_EP8, 1, 6),
+                              (r.GRANITE_4_H_SMALL_EP2, 1, 7),
                               (r.MISTRAL_7B, 4, 5)):
         assert len(r.abstract_step(one(other), topo.devices, tp=tp, batch=8,
                                    t=32, seq_len=4096)[1]) == n_args
@@ -842,7 +886,8 @@ def test_served_jamba_step_programs_hold_their_kernels(topo, t):
     spec = dataclasses.replace(r.JAMBA2_3B, n_layers=3, mixers=(3, 3, 0))
     fn, args = r.abstract_step(spec, topo.devices, batch=16, t=t,
                                seq_len=8192, q80=True)
-    assert (t == 1) == (len(args) == 4)    # the chunk's rows follow a map
+    # the chunk's rows follow a map; both take the summary's operand
+    assert len(args) == (5 if t == 1 else 7)
     cache = _cache_of(args)
     assert (len(cache.k), len(cache.v), len(cache.s), len(cache.conv)) == (
         1, 1, 2, 2)

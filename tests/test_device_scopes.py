@@ -77,12 +77,16 @@ def compiled_text():
             one, chunk = np.zeros((B, 1)), np.zeros((B, CHUNK))
             eng.slot_decode_step(one, pos)             # mint both programs
             eng.slot_prefill_chunk(chunk, pos, np.zeros(B))
+            # as the engine calls them: the chunk's slot map where it
+            # takes one, the summary's operand (PR 53)
+            served = (*((eng._identity_map,) if eng._chunk_slot_map else ()),
+                      *eng._sample_operands(None, None))
             made[arch] = {
                 "decode": eng._steps["slot_decode"].lower(
-                    eng.params, i32(one), i32(pos), eng.cache),
+                    eng.params, i32(one), i32(pos), eng.cache, *served[-1:]),
                 "prefill": eng._steps["slot_prefill", CHUNK].lower(
                     eng.params, i32(chunk), i32(pos), i32(np.zeros(B)),
-                    eng.cache)}
+                    eng.cache, *served)}
         return made[arch][program].compile().as_text()
 
     return text

@@ -162,12 +162,12 @@ def test_the_six_counters_count_the_references_routing(tiny_moe, tmp_path):
     dispatched = []  # (program, {row: positions}) of every step program
 
     def logged(program, fn):
-        def call(tokens, pos, *lidx):
+        def call(tokens, pos, *lidx, **sample):
             width = np.asarray(lidx[0]) + 1 if lidx else np.ones(B, int)
             dispatched.append((program, {
                 r: range(int(pos[r]), int(pos[r]) + int(width[r]))
                 for r in range(B) if pos[r] < eng.seq_len}))
-            return fn(tokens, pos, *lidx)
+            return fn(tokens, pos, *lidx, **sample)
         return call
 
     eng.slot_prefill_chunk = logged("prefill", eng.slot_prefill_chunk)
@@ -377,19 +377,28 @@ def test_a_skewed_router_is_unpacked_once_an_expert_to_the_same_bits(
 # since PR 40: the pins under "repeat" are the PARENT's, read with
 # `pltpu.repeat` forced (nothing else in the text moved), those under True
 # are PR 40's own, of the MXU spread.
+# PR 53 re-pinned all 24: both slot step programs of an engine without a
+# mesh end with the sampling summary of their own logits (one traced
+# operand more, one packed int32 leaf out, the candidates' two sorts under a
+# conditional; `ops/sharded_vocab.sample_summary`), and the chunk programs
+# are lowered WITH their slot map, as the engine calls them. That nothing
+# else of a program moved is `tools/compare_step_texts.py`'s to show (the
+# compiled text with and without the summary differs in the tail alone) and
+# `tests/test_chip_compile.py::
+# test_step_programs_return_the_logits_and_the_sampling_summary`'s.
 PARENT_TEXT = {
-    ("LLAMA", False, "decode"): "ca9dbfd3d9d530cf",
-    ("LLAMA", False, "prefill"): "6f6b41e521545ef5",
-    ("LLAMA", "repeat", "decode"): "4fb0a8bc402724e2",
-    ("LLAMA", True, "decode"): "29e5980851adfca2",
-    ("LLAMA", "repeat", "prefill"): "d760fddbff3e07d6",
-    ("LLAMA", True, "prefill"): "84b9fa0239ef69df",
-    ("OLMO_HYBRID", False, "decode"): "52f9fb7000fdeb40",
-    ("OLMO_HYBRID", False, "prefill"): "c8040defc4f2b4aa",
-    ("OLMO_HYBRID", "repeat", "decode"): "06323757d9c50a83",
-    ("OLMO_HYBRID", True, "decode"): "049f19dac0dd4149",
-    ("OLMO_HYBRID", "repeat", "prefill"): "2b2ee5b53568d61c",
-    ("OLMO_HYBRID", True, "prefill"): "259a2aa37dfcc092",
+    ("LLAMA", False, "decode"): "511015bfcdfc9f6e",
+    ("LLAMA", False, "prefill"): "a90a541ae8f11b93",
+    ("LLAMA", "repeat", "decode"): "d2112b4323875223",
+    ("LLAMA", True, "decode"): "94725a6622a876b7",
+    ("LLAMA", "repeat", "prefill"): "cee16b0088467d72",
+    ("LLAMA", True, "prefill"): "a5e6f436b18eae73",
+    ("OLMO_HYBRID", False, "decode"): "185c19cf7c5b20d5",
+    ("OLMO_HYBRID", False, "prefill"): "37f474c4294dd864",
+    ("OLMO_HYBRID", "repeat", "decode"): "07f58393f8602ec1",
+    ("OLMO_HYBRID", True, "decode"): "2f3ddda8d1e05819",
+    ("OLMO_HYBRID", "repeat", "prefill"): "3c968924ba18ea3d",
+    ("OLMO_HYBRID", True, "prefill"): "08252e7c204a89e8",
     # the two architectures WITH experts that the benchmark runs. Their
     # DECODE programs keep the text of commit 26394a7 (before
     # GRANITE_HYBRID's block kinds and multipliers); their CHUNK programs
@@ -411,18 +420,18 @@ PARENT_TEXT = {
     # text here: interpret mode) copies a used tile's rows out of the
     # token rows' panels. The four without kernels kept theirs, and
     # test_gate_and_up_read_token_rows_not_a_pair_buffer reads what entered.
-    ("MIXTRAL", False, "decode"): "2abb1048c22a432a",
-    ("MIXTRAL", False, "prefill"): "7ad217f81ca8a97f",
-    ("MIXTRAL", "repeat", "decode"): "80a5d40f59d0f525",
-    ("MIXTRAL", True, "decode"): "9f262997318c5350",
-    ("MIXTRAL", "repeat", "prefill"): "79ab96f8f232e842",
-    ("MIXTRAL", True, "prefill"): "17df985e8555ceba",
-    ("SARVAM_MLA", False, "decode"): "7870fd495c410b5b",
-    ("SARVAM_MLA", False, "prefill"): "f48f7070111d603d",
-    ("SARVAM_MLA", "repeat", "decode"): "7810bc13f07aa5f3",
-    ("SARVAM_MLA", True, "decode"): "caed4b59d21809c1",
-    ("SARVAM_MLA", "repeat", "prefill"): "af5dbb03f6b73cf9",
-    ("SARVAM_MLA", True, "prefill"): "2e8a9270a790e031",
+    ("MIXTRAL", False, "decode"): "b9c624763508c2d7",
+    ("MIXTRAL", False, "prefill"): "8f3ecd436bd00510",
+    ("MIXTRAL", "repeat", "decode"): "5721ddb93da5d767",
+    ("MIXTRAL", True, "decode"): "ce53661a2d8a2c6f",
+    ("MIXTRAL", "repeat", "prefill"): "6bb0ec0148511ce7",
+    ("MIXTRAL", True, "prefill"): "49fe4e651b58945e",
+    ("SARVAM_MLA", False, "decode"): "d15a2f64eb679fc0",
+    ("SARVAM_MLA", False, "prefill"): "b2401b4131bb8cb4",
+    ("SARVAM_MLA", "repeat", "decode"): "987de3976c6a341f",
+    ("SARVAM_MLA", True, "decode"): "8b3caf303d830a37",
+    ("SARVAM_MLA", "repeat", "prefill"): "f2cc5b60cbf1e91b",
+    ("SARVAM_MLA", True, "prefill"): "94b886bde05b6567",
 }
 TINY_SPECS = {
     "LLAMA": tiny_spec, "OLMO_HYBRID": tiny_hybrid_spec,
@@ -466,12 +475,16 @@ def lowered_steps():
         eng.slot_decode_step(one, pos)             # mint both programs
         eng.slot_prefill_chunk(chunk, pos, np.zeros(B))
         assert bool(eng.take_expert_counts()) == spec.is_moe
+        # as the engine calls them: the chunk's slot map where it takes
+        # one, the summary's operand (PR 53)
+        served = (*((eng._identity_map,) if eng._chunk_slot_map else ()),
+                  *eng._sample_operands(None, None))
         return {
             "decode": eng._steps["slot_decode"].lower(
-                eng.params, i32(one), i32(pos), eng.cache),
+                eng.params, i32(one), i32(pos), eng.cache, *served[-1:]),
             "prefill": eng._steps["slot_prefill", CHUNK].lower(
                 eng.params, i32(chunk), i32(pos), i32(np.zeros(B)),
-                eng.cache)}
+                eng.cache, *served)}
 
     return steps
 
@@ -483,15 +496,17 @@ def lowered_steps():
 def test_a_model_without_experts_lowers_to_the_parents_step_programs(
         lowered_steps, arch, kernels, program):
     """The two slot step programs of LLAMA and OLMO_HYBRID engines lower to
-    the SAME TEXT as before the grouped path: no counter leaves them (their
-    outputs are the logits and the cache's leaves), nothing is sorted or
-    counted in them."""
+    the SAME TEXT as before the grouped path but for the sampling summary at
+    their end (PR 53): no counter leaves them (their outputs are the logits,
+    the cache's leaves and the summary), no pair is sorted or counted in
+    them."""
     low = lowered_steps(arch, kernels)[program]
     n_cache = 4 if arch == "LLAMA" else 16
-    assert len(jax.tree_util.tree_leaves(low.out_info)) == 1 + n_cache
-    text = low.as_text()
-    assert (hashlib.sha256(text.encode()).hexdigest()[:16]
-            == PARENT_TEXT[arch, kernels, program]), (arch, kernels, program)
+    # the logits, the cache's leaves and the sampling summary (PR 53)
+    assert len(jax.tree_util.tree_leaves(low.out_info)) == 2 + n_cache
+    got = hashlib.sha256(low.as_text().encode()).hexdigest()[:16]
+    assert got == PARENT_TEXT[arch, kernels, program], (
+        arch, kernels, program, got)
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
@@ -503,13 +518,14 @@ def test_a_model_with_experts_lowers_to_the_parents_step_programs(
     """MIXTRAL and SARVAM_MLA engines, the benchmark's other two
     architectures, lower to the SAME TEXT as before the block of a layer
     was chosen by what the spec says (norm placement, FFN kind, the four
-    multipliers): at multipliers of 1 nothing enters their programs. (Two
+    multipliers): at multipliers of 1 nothing enters their programs. (Three
     things have since, on purpose: PR 49's third counter in the chunk
     programs, PR 50's gathered gate and up in both programs with kernels,
-    PARENT_TEXT.)"""
+    PR 53's sampling summary at the end of both, PARENT_TEXT.)"""
     text = lowered_steps(arch, kernels)[program].as_text()
-    assert (hashlib.sha256(text.encode()).hexdigest()[:16]
-            == PARENT_TEXT[arch, kernels, program]), (arch, kernels, program)
+    got = hashlib.sha256(text.encode()).hexdigest()[:16]
+    assert got == PARENT_TEXT[arch, kernels, program], (
+        arch, kernels, program, got)
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])

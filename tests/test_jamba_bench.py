@@ -222,10 +222,14 @@ def test_the_manifest_ends_with_this_cell_and_its_two_metrics(real):
         "chips": 1, "why": m["workloads"][-1]["why"]}
     assert all(len(x["why"]) <= 200 for x in (m["configs"][-1],
                                               m["workloads"][-1]))
-    assert [x["name"] for x in m["per_layer"][-2:]] == [
+    # (what PR 53 appended after them, one metric that every cell reports,
+    # is tests/test_sample_summary.py's to hold)
+    assert m["per_layer"][-1]["name"] == "sample_summary_share"
+    per_layer = m["per_layer"][:-1]
+    assert [x["name"] for x in per_layer[-2:]] == [
         "selscan_decode_roofline", "selscan_prefill_roofline"]
     for x, moves, kernel in zip(
-            m["per_layer"][-2:], ("itl_p50_ms", "ttft_p50_ms"),
+            per_layer[-2:], ("itl_p50_ms", "ttft_p50_ms"),
             ("selective_scan_decode", "selective_scan_chunk")):
         assert x["workloads"] == [CELL]
         assert x["moves"] == moves and x["source"] == "device_trace"
@@ -237,7 +241,7 @@ def test_the_manifest_ends_with_this_cell_and_its_two_metrics(real):
             k: v for k, v in x.items() if k != "workloads"}
     # no accepted entry lists the new cell (a model_config PR edits none)
     assert all(CELL not in (x.get("workloads") or ())
-               for x in m["per_layer"][:-2] + m["end_to_end"])
+               for x in per_layer[:-2] + m["end_to_end"])
     assert traffic.load_json("cells", CELL + ".json") == traffic.load_json(
         "cells", "olmo-hybrid-7b.long-doc.json")
     mix = traffic.load_json("traffic", "long-doc-16.json")

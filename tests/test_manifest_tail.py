@@ -26,8 +26,8 @@ for p in (BENCH, os.path.join(BENCH, "tests"), REPO):
     if p not in sys.path:
         sys.path.insert(0, p)
 
-# by PRs 48, 49 and 52
-APPENDED = {"workloads": 2, "configs": 2, "per_layer": 5}
+# by PRs 48, 49, 52 and 53
+APPENDED = {"workloads": 2, "configs": 2, "per_layer": 6}
 
 with open(os.path.join(REPO, "BENCHMARK.json")) as f:
     M = json.load(f)

@@ -331,9 +331,9 @@ def test_prefill_packing(wide, case):
     calls = []
     real = eng.slot_prefill_chunk
 
-    def spy(tok, pos, lidx, slots=None):
+    def spy(tok, pos, lidx, slots=None, **sample):
         calls.append((tok.copy(), pos.copy(), lidx.copy(), slots))
-        return real(tok, pos, lidx, slots)
+        return real(tok, pos, lidx, slots, **sample)
 
     eng.slot_prefill_chunk = spy
     prompts = [_prompt(n, seed=n) for n in lens]

@@ -401,7 +401,7 @@ def test_spans_on_and_off_make_the_same_calls_on_the_logits(tiny):
 
     eng = _engine(tiny)
     real = eng.slot_decode_step
-    eng.slot_decode_step = lambda tok, pos: Probe(real(tok, pos))
+    eng.slot_decode_step = lambda tok, pos, **kw: Probe(real(tok, pos, **kw))
     sched = Scheduler(eng, chunk=8)
     sched.warmup()
     calls.clear()

@@ -183,6 +183,17 @@ def test_step_timeline_tool_reads_what_the_sink_writes(tmp_path):
     assert row["host"][0] in (7.0, 7.5, 8.0) and row["pre_mean"] == 3.0
     assert step_timeline.summary(steps, skip_s=3600.0, window_s=1.0) == {
         "n": 0}
+    # --decode-only: the iterations that ran a decode step and no chunk
+    t.configure(capacity=64, sink_dir=str(tmp_path / "dec"), sample=0.0)
+    for i in range(30):
+        t.step(decode_rows=8, prefill_rows=i % 3 == 0, chunk=32,
+               queue_depth=0, wall_ms=36.0, n=i, ts0=0.0,
+               phases={"sched.wait": 29.0, "sched.sample_emit": 3.0})
+    t.reset()
+    alone = step_timeline.steps_of(str(tmp_path / "dec"), decode_only=True)
+    assert len(alone) == 20 and not any(r["pre"] for r in alone)
+    row = step_timeline.summary(alone, skip_s=0.0, window_s=60.0)
+    assert row["host"] == [7.0, 7.0] and row["host_by_dec"] == {8: 7.0}
 
 
 def test_span_reads_survive_concurrent_appends():

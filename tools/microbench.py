@@ -23,7 +23,16 @@ projection of the five MoE cells' two step programs, token rows gathered in
 the kernel by `src` against `x[src]` laid out by XLA and handed over
 (PERF.md section 6, PR 50).
 
-Usage: python tools/microbench.py [all|gemv|gemv_q40|gemv_pallas|attn|cache|q40_shapes|q40_orders|q40_gather]
+`sample_prep` reads what the sampling summary costs at the end of a step
+program (`ops/sharded_vocab.sample_summary`: masked argmax, float32 softmax
+at a temperature a row, the two sorts of `top_candidates`, one packed leaf)
+and what the host
+then fetches, at the rows and vocabularies of the configurations;
+`sample_view` what a decode step's HOST costs with the summary serving every
+row, with none proven (the whole-fetch fallback) and without it (PERF.md
+section 6, PR 53).
+
+Usage: python tools/microbench.py [all|gemv|gemv_q40|gemv_pallas|attn|cache|q40_shapes|q40_orders|q40_gather|sample_prep|sample_view]
 """
 
 from __future__ import annotations
@@ -440,6 +449,115 @@ def bench_q40_gather():
               f"{np.array_equal(out[False], out[True])}", flush=True)
 
 
+def bench_sample_prep():
+    """us a call of the summary as the step programs run it, at k
+    candidates a row; of it the softmax and argmax alone (k = 1); and the
+    host's fetch of the packed leaf against the fetch of the logits it
+    replaces. Calls chained in one program through the temperatures, so
+    that no call folds into the one before."""
+    from distributed_llama_tpu.ops.sharded_vocab import sample_summary
+
+    rng = np.random.default_rng(0)
+    print(f"{jax.devices()[0].device_kind}")
+    print("rows | vocab | "
+          + " | ".join(f"summary k={k} us" for k in SAMPLE_PREP_KS)
+          + " | fetch summary k=512 us | fetch logits us")
+    for rows, vocab in SAMPLE_PREP_SHAPES:
+        lg = jnp.asarray(rng.standard_normal((rows, vocab)) * 3.0,
+                         jnp.float32)
+        temps = jnp.full((rows,), 0.8, jnp.float32)
+        us = []
+        for k in SAMPLE_PREP_KS:
+            def body(t, lg):
+                out = sample_summary(lg, t, jnp.int32(vocab - 7), k)
+                return t + (out[:, 1] & 1).astype(jnp.float32) * 1e-7
+
+            us.append(slope_time(lambda r: _outer(body, r), lg, temps,
+                                 reps=(8, 64), tries=5) * 1e6)
+        prep = jax.jit(lambda lg, t: sample_summary(
+            lg, t, jnp.int32(vocab - 7), 512))
+        bump = jax.jit(lambda lg: lg + 1.0)
+        fetch = []
+        for make in (lambda: prep(lg, temps), lambda: bump(lg)):
+            best = 1e9
+            for _ in range(7):
+                a = make()
+                a.block_until_ready()
+                t0 = time.perf_counter()
+                np.asarray(a)
+                best = min(best, time.perf_counter() - t0)
+            fetch.append(best * 1e6)
+        print(f"{rows} | {vocab} | "
+              + " | ".join(f"{u:.1f}" for u in us)
+              + f" | {fetch[0]:.1f} | {fetch[1]:.1f}", flush=True)
+
+
+def bench_sample_view():
+    """ms of HOST a decode step of a tiny engine (its device step is a few
+    tenths of a ms: what is read is the dispatch, the fetches and the
+    sampling) and the D2H fetches a step, 8 rows sampled at 0.8 / 0.9,
+    three ways: the step's own summary on a head that proves every row
+    (logits std 4), the same on a head that proves none (std 1.5: every
+    row reads the ONE whole fetch behind the summary's small one), and
+    that flat head dispatched without temperatures (no summary, the full
+    view: what every step paid before PR 53). The last two differ by what
+    the summary costs a head it cannot serve (PERF.md section 6, PR 53)."""
+    from distributed_llama_tpu.models import ArchType, HiddenAct, ModelSpec
+    from distributed_llama_tpu.models.params import load_params, random_tensors
+    from distributed_llama_tpu.runtime.engine import Engine
+    from distributed_llama_tpu.sampler import Sampler
+
+    rows, steps = 8, 300
+    print(f"{jax.devices()[0].device_kind}")
+    print("vocab | head | view | host ms a step (median) | fetches a step "
+          "| rows from the summary")
+    for vocab in (50176, 100352):
+        spec = ModelSpec(arch=ArchType.LLAMA, dim=256, hidden_dim=512,
+                         n_layers=2, n_heads=4, n_kv_heads=2,
+                         vocab_size=vocab, seq_len=512,
+                         hidden_act=HiddenAct.SILU)
+        params = load_params(spec, random_tensors(spec, seed=5, scale=0.05),
+                             mode="dense", dtype=jnp.float32)
+        eng = Engine(spec, dict(params), batch=rows,
+                     compute_dtype=jnp.float32, cache_dtype=jnp.float32)
+        tok = np.arange(rows, dtype=np.int32)[:, None] + 1
+        lg = eng.fetch_logits(eng.slot_decode_step(
+            tok, np.zeros((rows,), np.int32)))
+        wcls = np.asarray(params["wcls"]) / lg.std()
+        temps = np.full((rows,), 0.8, np.float32)
+        fetches = [0]
+        real = eng.fetch_logits
+        eng.fetch_logits = lambda a: fetches.__setitem__(
+            0, fetches[0] + 1) or real(a)
+        for head, std, summary in (("peaked", 4.0, True),
+                                   ("flat", 1.5, True),
+                                   ("flat", 1.5, False)):
+            eng.params = dict(eng.params, wcls=jnp.asarray(wcls * std))
+            smp = [Sampler(vocab, 0.8, 0.9, seed=r) for r in range(rows)]
+            took = []
+            fetches[0] = 0
+            served = eng.vocab_sample_stats["sharded"]
+            for i in range(steps):
+                pos = np.full((rows,), i, np.int32)
+                t0 = time.perf_counter()
+                lg = eng.slot_decode_step(
+                    tok, pos, **({"temps": temps} if summary else {}))
+                view = eng.sample_view(lg, temps, vocab)
+                for r in range(rows):
+                    tok[r, 0] = view.sample(smp[r], r)
+                took.append(time.perf_counter() - t0)
+            served = eng.vocab_sample_stats["sharded"] - served
+            print(f"{vocab} | {head} | "
+                  f"{'summary' if summary else 'full'} | "
+                  f"{np.median(took[20:]) * 1e3:.3f} | "
+                  f"{fetches[0] / steps + summary:.2f} | "
+                  f"{served} of {steps * rows}", flush=True)
+
+
+SAMPLE_PREP_KS = (1, 256, 512)
+SAMPLE_PREP_SHAPES = [(rows, vocab) for rows in (8, 16)
+                      for vocab in (32000, 50176, 65536, 100352)]
+
 ALL = {
     "gemv": bench_gemv_dense,
     "gemv_q40": bench_gemv_q40,
@@ -449,6 +567,8 @@ ALL = {
     "q40_shapes": bench_q40_shapes,
     "q40_orders": bench_q40_orders,
     "q40_gather": bench_q40_gather,
+    "sample_prep": bench_sample_prep,
+    "sample_view": bench_sample_view,
 }
 
 if __name__ == "__main__":

@@ -247,17 +247,23 @@ def _loaded_params(spec: ModelSpec, dtype) -> dict:
 
 def abstract_step(spec: ModelSpec, devices, *, tp: int = 1, batch: int,
                   t: int, seq_len: int, q80: bool = False,
-                  dtype=jnp.bfloat16, slot_map: bool | None = None):
+                  dtype=jnp.bfloat16, slot_map: bool | None = None,
+                  summary: bool | None = None):
     """(jitted step, abstract args) for the slot program of shape (B, T):
     T == 1 is `slot_decode_step`, T > 1 `slot_prefill_chunk_T`. `devices`
     are described devices; tp > 1 lays them out as the engine's mesh.
-    slot_map: whether the chunk's rows follow a slot map (a last argument
-    `slots`); None decides as `Engine.__init__` does, from the layer kinds
-    and the mesh."""
+    slot_map: whether the chunk's rows follow a slot map (an argument
+    `slots` after the cache); None decides as `Engine.__init__` does, from
+    the layer kinds and the mesh. Without a mesh both programs end, as the
+    engine's do (Engine._with_summary), with the sampling summary of their
+    logits: the last argument holds its temperatures, its vocabulary and
+    whether the step computes it, the last output is its packed leaf
+    (summary=False: the program without, to compare with)."""
     from distributed_llama_tpu.models.params import fuse_layer_weights
     from distributed_llama_tpu.models.transformer import (KVCache, forward,
                                                           takes_slot_map)
-    from distributed_llama_tpu.ops.sharded_vocab import vocab_shard_axes
+    from distributed_llama_tpu.ops.sharded_vocab import (step_summary,
+                                                         vocab_shard_axes)
     from distributed_llama_tpu.parallel.mesh import make_mesh
     from distributed_llama_tpu.parallel.sharding import (
         cache_pspec, check_tp_constraints, param_pspecs, repack_col_weights,
@@ -302,23 +308,34 @@ def abstract_step(spec: ModelSpec, devices, *, tp: int = 1, batch: int,
         vocab_axes=vocab_axes or ("tp",),
         expert_counts=spec.is_moe)  # Engine._counts_experts
 
+    sample = ()             # Engine._sample_operands
+    if mesh is None if summary is None else summary:
+        sample = (jax.ShapeDtypeStruct((batch + 2,), jnp.float32,
+                                       sharding=rep),)
+
+    def with_summary(out, sample):      # Engine._with_summary
+        return (*out, step_summary(out[0], *sample)) if sample else out
+
     if t == 1:
-        def slot_decode_step(params, tokens, pos0, cache):
-            return forward(params, spec, tokens, pos0, cache, **common)
+        def slot_decode_step(params, tokens, pos0, cache, *sample):
+            return with_summary(
+                forward(params, spec, tokens, pos0, cache, **common), sample)
 
         return (jax.jit(slot_decode_step, donate_argnums=(3,)),
-                (params, tokens, pos, cache))
+                (params, tokens, pos, cache, *sample))
 
     if slot_map is None:    # Engine._chunk_slot_map
         slot_map = takes_slot_map(spec, meshed=tp > 1)
 
-    def slot_prefill_chunk(params, tokens, pos0, logit_index, cache, *slots):
-        return forward(params, spec, tokens, pos0, cache,
-                       logit_index=logit_index, **common,
-                       slots=slots[0] if slots else None)
+    def slot_prefill_chunk(params, tokens, pos0, logit_index, cache, *rest):
+        slots, sample = (rest[0], rest[1:]) if slot_map else (None, rest)
+        return with_summary(
+            forward(params, spec, tokens, pos0, cache,
+                    logit_index=logit_index, **common, slots=slots), sample)
 
     return (jax.jit(slot_prefill_chunk, donate_argnums=(4,)),
-            (params, tokens, pos, pos, cache) + ((pos,) if slot_map else ()))
+            (params, tokens, pos, pos, cache, *((pos,) if slot_map else ()),
+             *sample))
 
 
 def cache_shaped_copies(compiled_text: str, leaf_shape) -> list[str]:

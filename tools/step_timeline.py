@@ -8,7 +8,11 @@ directory, the medians and means of the iterations that ran BOTH a chunk
 program and a decode step between `--skip-s` and `--skip-s + --window-s`
 seconds after the first of them:
 
-    python tools/step_timeline.py <dir> [<dir> ...]
+    python tools/step_timeline.py [--decode-only] <dir> [<dir> ...]
+
+`--decode-only` takes the iterations that ran a decode step and NO chunk
+program instead (94 % of `granite-4.0-h-small-ep2.decode-batch`'s: what its
+`itl_p50_ms` is made of, PERF.md section 5, PR 53).
 
 `host` is an iteration's wall less its `sched.wait`; `host_by_dec` its median
 by the number of decoding rows. How PR 52 compared eight runs of one cell on
@@ -24,7 +28,7 @@ import os
 import statistics
 
 
-def steps_of(directory: str) -> list[dict]:
+def steps_of(directory: str, decode_only: bool = False) -> list[dict]:
     out = []
     for path in sorted(glob.glob(os.path.join(directory, "trace-*.jsonl"))):
         with open(path) as f:
@@ -33,8 +37,8 @@ def steps_of(directory: str) -> list[dict]:
                     rec = json.loads(line)
                 except ValueError:      # a line cut by a rotation
                     continue
-                if rec.get("kind") == "step" and rec.get("pre") and rec.get(
-                        "dec"):
+                if (rec.get("kind") == "step" and rec.get("dec")
+                        and bool(rec.get("pre")) != decode_only):
                     out.append(rec)
     return out
 
@@ -72,10 +76,12 @@ def main() -> None:
     ap.add_argument("dirs", nargs="+")
     ap.add_argument("--skip-s", type=float, default=20.0)
     ap.add_argument("--window-s", type=float, default=46.0)
+    ap.add_argument("--decode-only", action="store_true")
     args = ap.parse_args()
     for d in args.dirs:
         print(json.dumps({"run": os.path.basename(os.path.normpath(d)),
-                          **summary(steps_of(d), args.skip_s, args.window_s)}))
+                          **summary(steps_of(d, args.decode_only),
+                                    args.skip_s, args.window_s)}))
 
 
 if __name__ == "__main__":
