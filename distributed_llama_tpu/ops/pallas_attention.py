@@ -7,17 +7,28 @@ assigning the KV cache a head-minor layout (32 kv heads in the 128-lane
 dim -> 4x lane waste, ~75 GB/s effective on v5e); and for prefill chunks the
 dense path materializes the full (B, T, KVH, G, S) score tensor in HBM
 (ops/attention.py:56-63 — 67 MB per layer at T=256/S=2048). This kernel
-fixes both by construction: each grid step streams one head's (SB, hs)
-key/value panel — hs=128 exactly fills the lanes — against the head's
-(T*G, hs) query panel, and keeps the running softmax state in VMEM scratch,
-so scores never touch HBM.
+fixes both by construction: each grid step streams the (SB, hs)
+key/value panels of a TILE of KV heads — hs=128 exactly fills the lanes —
+against those heads' (T*G, hs) query panels, and keeps the running softmax
+state in VMEM scratch, so scores never touch HBM.
 
 Shapes: q (B, T, H, hs) with H = KVH * G (GQA group, ref kvMul:
-src/llama2-tasks.cpp:60), reshaped here to (B*KVH, T*G, hs) row panels;
-k/v cache (B, KVH, S, hs). Grid is (B*KVH, S/SB) with the sequence
-dimension innermost: scratch acc/m/l carry the online-softmax state across
-S blocks of the same head (flash decomposition), reset at block 0 and
-finalized at the last block.
+src/llama2-tasks.cpp:60), laid out here as (B, KVH, T*G, hs) row panels;
+k/v cache (B, KVH, S, hs), read as it stands. Grid is
+(B, KVH/kh, S/SB) with the sequence dimension innermost: blocks
+(1, kh, SB, hs) of K and V and (1, kh, T*G, hs) of q and out, scratch
+acc (kh, T*G, hs), m and l (kh, T*G, 1) carry the online-softmax state
+across S blocks of the same heads (flash decomposition), reset at block 0
+and finalized at the last block. Every KV head of a row shares the row's
+position, so the clamp, the skip and the gate below decide once a tile
+what they decided once a head; a head's arithmetic is what it was, one dot
+batched over the tile's heads. `head_tile` cuts kh from the call's shapes:
+the largest divisor of KVH whose step fits FLASH_VMEM_BYTES (8 of 8 at
+7B-class GQA shapes, decode and 32-token chunk; all 30 of an MHA model's
+30 in bf16; 1 for one KV head, which is the grid of a head a step;
+a few where T*G is 1,024 and the score tile sets it). `flash_grid` is the
+grid, for the call and for whoever counts its steps
+(/stats attn_grid_steps_*).
 
 Causality: query row r (= token t*G + g) attends to cache positions
 s <= pos0[b] + r//G — the cache is already updated at the chunk's
@@ -38,7 +49,12 @@ block: `_last_attended` sends it to block 0 in the index map and the
 pl.when, unmasked, so its softmax state stays finite. The plain clamp
 would put pos == S in the LAST block, and every empty slot would stream
 the whole cache in every layer. What such a row still costs is its grid
-steps.
+steps, B x KVH/kh x S/SB a call whatever the positions: ~0.18 us each past
+a row's last block (DMA elided, compute skipped), which at a head a step
+(16,384 a decode step of a 32-layer model at 8 slots of 4,096) was most of
+a short-context decode step's attention and at a tile of 8 heads is 2,048;
+and block 0's K and V of every head, read and attended for nobody (left:
+skipping it too needs `_done` to write zeros for such a row).
 """
 
 from __future__ import annotations
@@ -103,11 +119,20 @@ def saturate_f8_nan_codes(x):
     fixed = jnp.where(mag == jnp.uint8(0x7F),
                       (bits & jnp.uint8(0x80)) | jnp.uint8(0x7E), bits)
     return jax.lax.bitcast_convert_type(fixed.astype(jnp.uint8), F8_DTYPE)
+
+
 # cap on T*G query rows per head panel: bounds the (rows, SB) f32 score tile
 # in VMEM (1024x512x4 = 2 MB; acc another 512 KB). Prefill chunks above it
 # fall back to the dense path — the engine's default chunk (256) stays under
 # for G <= 4
 MAX_Q_ROWS = 1024
+# what the blocks, score tile and scratch of ONE grid step may hold
+# (_tile_bytes): head_tile fits the most KV heads a step under it. Over the
+# compiler's default scoped limit (16 MiB), so the call states its own,
+# with room above the budget for what _tile_bytes does not count (of v5e's
+# 128 MiB; mla_attention's is the same)
+FLASH_VMEM_BYTES = 24 * 2**20
+_FLASH_VMEM_LIMIT = 48 * 2**20
 
 
 def _last_attended(pos, last_tok, s):
@@ -123,8 +148,8 @@ def _last_attended(pos, last_tok, s):
 
 
 def _kernel(pos_ref, q_ref, k_ref, v_ref, out_ref, acc_ref, m_ref, l_ref,
-            *, sb, n_sb, kvh, t, g, scale, out_dtype):
-    j = pl.program_id(1)
+            *, sb, n_sb, t, g, scale, out_dtype):
+    j = pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
@@ -132,15 +157,14 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, out_ref, acc_ref, m_ref, l_ref,
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    b = pl.program_id(0) // kvh
-    pos = pos_ref[b]  # first query row's absolute position
+    pos = pos_ref[pl.program_id(0)]  # first query row's absolute position
 
     # blocks entirely past the last query position are fully masked: their
     # K/V DMA was clamped away (see index maps) and their compute is skipped
     @pl.when(j * sb <= _last_attended(pos, t - 1, sb * n_sb))
     def _accumulate():
-        q = q_ref[0]                               # (T*G, hs)
-        k = k_ref[0]                               # (SB, hs)
+        q = q_ref[0]                               # (kh, T*G, hs)
+        k = k_ref[0]                               # (kh, SB, hs)
         v = v_ref[0]
         if k.dtype == F8_DTYPE:
             # e4m3 cache: HBM/VMEM/DMA stay narrow; reinterpret the block's
@@ -157,25 +181,31 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, out_ref, acc_ref, m_ref, l_ref,
             k = k.astype(q.dtype)
             v = v.astype(q.dtype)
 
+        # one dot batched over the tile's heads: a head's arithmetic is
+        # what its own 2-D dot gave (bit for bit on the chip), and Mosaic
+        # runs it faster than a loop over the heads, unrolled or not
+        # (tools/microbench.py flash_grid; PERF.md section 6, PR 54)
         dot = functools.partial(
             jax.lax.dot_general,
             preferred_element_type=jnp.float32,
             precision=jax.lax.Precision.DEFAULT,
         )
-        scores = dot(q, k, dimension_numbers=(((1,), (1,)), ((), ()))) * scale  # (T*G, SB)
+        scores = dot(q, k, dimension_numbers=(
+            ((2,), (2,)), ((0,), (0,)))) * scale   # (kh, T*G, SB)
 
         # causal: row r is query token r//G at absolute position pos + r//G
         row_pos = pos + jax.lax.broadcasted_iota(
-            jnp.int32, scores.shape, 0) // g
-        s_pos = j * sb + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+            jnp.int32, scores.shape, 1) // g
+        s_pos = j * sb + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 2)
         scores = jnp.where(s_pos <= row_pos, scores, NEG_INF)
 
-        m_prev = m_ref[:]                          # (T*G, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
+        m_prev = m_ref[:]                          # (kh, T*G, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=2, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(scores - m_new)                # (T*G, SB); masked cols underflow to 0
-        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        pv = dot(p.astype(v.dtype), v, dimension_numbers=(((1,), (0,)), ((), ())))
+        p = jnp.exp(scores - m_new)    # masked cols underflow to 0
+        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=2, keepdims=True)
+        pv = dot(p.astype(v.dtype), v, dimension_numbers=(
+            ((2,), (1,)), ((0,), (0,))))           # (kh, T*G, hs)
         acc_ref[:] = acc_ref[:] * alpha + pv
         m_ref[:] = m_new
 
@@ -198,6 +228,52 @@ def flash_supported(t: int, h: int, kvh: int) -> bool:
     """Kernel precondition: the (T*G, SB) score tile must fit the VMEM
     budget. T == 1 (decode) always qualifies."""
     return t * (h // kvh) <= MAX_Q_ROWS
+
+
+def _tile_bytes(kh: int, rows: int, sb: int, hs: int, cache_bytes: int,
+                q_bytes: int) -> int:
+    """VMEM a grid step of `kh` heads holds: K and V blocks and the q and
+    out panels, each double-buffered; the float32 score tile and its p;
+    acc, m and l (a (rows, 1) float32 leaf pads to whole (8, 128) tiles);
+    and, where the cache is narrower than q, K's and V's blocks on their
+    way up through 32-bit lanes (_f8_bits_to: three planes in flight)."""
+    pad8 = -(-rows // 8) * 8
+    blocks = 2 * 2 * kh * (sb * hs * cache_bytes + pad8 * hs * q_bytes)
+    scores = 2 * kh * pad8 * sb * 4
+    scratch = kh * pad8 * (hs + 2 * 128) * 4
+    upcast = 2 * kh * sb * hs * 3 * 4 if cache_bytes < q_bytes else 0
+    return blocks + scores + scratch + upcast
+
+
+def head_tile(kvh: int, rows: int, sb: int, hs: int, cache_bytes: int,
+              q_bytes: int) -> int:
+    """KV heads one grid step holds: the largest divisor of `kvh` whose
+    blocks, score tile and scratch (_tile_bytes) fit FLASH_VMEM_BYTES, for
+    `rows` = T*G query rows a head, sequence blocks of `sb` and a cache of
+    `cache_bytes` an element. 1 where nothing larger fits (or kvh is 1):
+    the grid and the blocks of a head a step."""
+    for kh in range(kvh, 1, -1):
+        if kvh % kh == 0 and _tile_bytes(
+                kh, rows, sb, hs, cache_bytes, q_bytes) <= FLASH_VMEM_BYTES:
+            return kh
+    return 1
+
+
+def flash_grid(b: int, t: int, h: int, kvh: int, s: int, hs: int,
+               cache_dtype, q_dtype=jnp.bfloat16) -> tuple[int, int, int]:
+    """The grid of one flash_attention call on q (b, t, h, hs) of
+    `q_dtype` and caches (b, kvh, s, hs) of `cache_dtype`: (rows, head
+    tiles, sequence blocks). The call runs it and the scheduler counts it
+    (/stats attn_grid_steps_*, Engine.attn_grid_steps)."""
+    from .attention import is_narrow_cache
+
+    sb = _block_s(s)
+    cache_dtype = jnp.dtype(cache_dtype)
+    if not is_narrow_cache(cache_dtype):
+        q_dtype = cache_dtype  # flash_attention lifts q to a wider cache
+    kh = head_tile(kvh, t * (h // kvh), sb, hs, cache_dtype.itemsize,
+                   jnp.dtype(q_dtype).itemsize)
+    return b, kvh // kh, s // sb
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "scale"))
@@ -224,8 +300,6 @@ def flash_attention(
     kvh, s = k_cache.shape[1], k_cache.shape[2]
     g = h // kvh
     assert flash_supported(t, h, kvh), (t, g)
-    sb = _block_s(s)
-    n_sb = s // sb
 
     # kernel dots need matching operand dtypes (lax.dot_general does not
     # promote); compute dtype and cache dtype may differ. Wider caches
@@ -233,53 +307,55 @@ def flash_attention(
     # q and the softmax state never drop below the compute dtype
     from .attention import is_narrow_cache
 
+    grid = flash_grid(b, t, h, kvh, s, hs, k_cache.dtype, q.dtype)
     if not is_narrow_cache(k_cache.dtype):
         q = q.astype(k_cache.dtype)
-    # (B, T, KVH, G, hs) -> (B*KVH, T*G, hs) row panels, one per kv head
+    kh, n_sb = kvh // grid[1], grid[2]
+    sb = s // n_sb
+    # (B, T, KVH, G, hs) -> (B, KVH, T*G, hs) row panels, one per kv head
     qh = (q.reshape(b, t, kvh, g, hs).transpose(0, 2, 1, 3, 4)
-          .reshape(b * kvh, t * g, hs))
-    kh = k_cache.reshape(b * kvh, s, hs)
-    vh = v_cache.reshape(b * kvh, s, hs)
+          .reshape(b, kvh, t * g, hs))
     pos = q_pos[:, 0].astype(jnp.int32)
 
     # index maps take the prefetched scalars last: (pos,), or (pos, slots)
-    def kv_index(i, j, pos_ref, *sl):
+    def kv_index(i, ht, j, pos_ref, *sl):
         # clamp at the block containing the chunk's last query position:
         # steps past it re-map to the same block, so Mosaic elides their HBM
         # copy (the dead-read fix)
-        last = _last_attended(pos_ref[i // kvh], t - 1, s)
-        head = sl[0][i // kvh] * kvh + i % kvh if sl else i
-        return (head, jnp.minimum(j, last // sb), 0)
+        last = _last_attended(pos_ref[i], t - 1, s)
+        return (sl[0][i] if sl else i, ht, jnp.minimum(j, last // sb), 0)
 
     kernel, scalars = _kernel, (pos,)
     if slots is not None:
         kernel, scalars = _mapped_kernel, (pos, slots.astype(jnp.int32))
+    panel = pl.BlockSpec((1, kh, t * g, hs),
+                         lambda i, ht, j, *_: (i, ht, 0, 0))
     out = pl.pallas_call(
         functools.partial(
-            kernel, sb=sb, n_sb=n_sb, kvh=kvh, t=t, g=g,
+            kernel, sb=sb, n_sb=n_sb, t=t, g=g,
             scale=scale or 1.0 / (hs ** 0.5), out_dtype=q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars),
-            grid=(b * kvh, n_sb),
+            grid=grid,
             in_specs=[
-                pl.BlockSpec((1, t * g, hs), lambda i, j, *_: (i, 0, 0)),
-                pl.BlockSpec((1, sb, hs), kv_index),
-                pl.BlockSpec((1, sb, hs), kv_index),
+                panel,
+                pl.BlockSpec((1, kh, sb, hs), kv_index),
+                pl.BlockSpec((1, kh, sb, hs), kv_index),
             ],
-            out_specs=pl.BlockSpec((1, t * g, hs),
-                                   lambda i, j, *_: (i, 0, 0)),
+            out_specs=panel,
             scratch_shapes=[
-                pltpu.VMEM((t * g, hs), jnp.float32),
-                pltpu.VMEM((t * g, 1), jnp.float32),
-                pltpu.VMEM((t * g, 1), jnp.float32),
+                pltpu.VMEM((kh, t * g, hs), jnp.float32),
+                pltpu.VMEM((kh, t * g, 1), jnp.float32),
+                pltpu.VMEM((kh, t * g, 1), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b * kvh, t * g, hs), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, kvh, t * g, hs), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_FLASH_VMEM_LIMIT),
         interpret=interpret,
         name="flash_attention",
-    )(*scalars, qh, kh, vh)
+    )(*scalars, qh, k_cache, v_cache)
 
     return (out.reshape(b, kvh, t, g, hs).transpose(0, 2, 1, 3, 4)
             .reshape(b, t, h, hs))

@@ -15,6 +15,7 @@ loops (ref: src/tasks.cpp:184-256, src/apps/dllama/dllama.cpp:14-91):
 
 from __future__ import annotations
 
+import math
 import time
 from functools import partial
 from typing import Callable, Iterator, NamedTuple
@@ -26,9 +27,9 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.params import fuse_layer_weights
-from ..models.spec import ModelSpec
+from ..models.spec import LayerKind, ModelSpec
 from ..models.transformer import KVCache, forward, takes_slot_map
-from ..parallel.mesh import DP_AXIS, SP_AXIS
+from ..parallel.mesh import DP_AXIS, SP_AXIS, TP_AXIS
 from ..parallel.sharding import cache_pspec, check_tp_constraints, shard_params
 from ..sampler import Sampler
 from .stats import RunStats, StepStats
@@ -1566,6 +1567,32 @@ class Engine:
         the layer kinds and the mesh, once, at boot: both step programs of
         an engine that takes no map are what they were."""
         return self.batch if self._chunk_slot_map else 1
+
+    def attn_grid_steps(self, t: int) -> int:
+        """Grid steps `flash_attention` takes in ONE step program of `t`
+        tokens a row: the kernel's own grid (ops/pallas_attention.flash_grid,
+        which the call itself runs: rows x head tiles x sequence blocks;
+        one shard's rows and heads under a dp / tp mesh) times the layers
+        that attend a K/V cache. Static: whatever the rows' positions.
+        0 where the program holds no such kernel (the XLA path, the latent
+        or the sp-sharded cache, a chunk too wide for it). What the
+        scheduler adds to /stats attn_grid_steps_* at a dispatch."""
+        from ..ops.pallas_attention import flash_grid, flash_supported
+
+        spec = self.spec
+        b, h, kvh = self.batch, spec.n_heads, spec.n_kv_heads
+        if (not self.use_pallas or spec.is_mla
+                or self._sp_cache_mesh is not None
+                or not flash_supported(t, h, kvh)):
+            return 0
+        if self._tp_mesh is not None:
+            dp = self._tp_mesh.shape.get(DP_AXIS, 1)
+            tp = self._tp_mesh.shape.get(TP_AXIS, 1)
+            b, h, kvh = b // dp if b % dp == 0 else b, h // tp, kvh // tp
+        layers = sum(k == LayerKind.ATTENTION for k in spec.layer_kinds)
+        return layers * math.prod(flash_grid(
+            b, t, h, kvh, self.seq_len, spec.head_size, self.cache_dtype,
+            self.compute_dtype))
 
     @property
     def _summarises(self) -> bool:

@@ -899,6 +899,7 @@ class Scheduler:
         finishing = []
         first = [s.off for s in rows]
         self.stats.prefill_steps += 1
+        self.stats.attn_grid_steps_prefill += eng.attn_grid_steps(c)
         self.stats.prefill_rows += len(rows)
         self.stats.prefill_segments += len(live)
         self.stats.gated_rows += b - len(live)
@@ -964,6 +965,7 @@ class Scheduler:
         if self._span is not None:
             TRACER.phase(self._span, "sched.dispatch.decode")
         self.stats.decode_steps += 1
+        self.stats.attn_grid_steps_decode += eng.attn_grid_steps(1)
         self.stats.decode_rows += len(live)
         self.stats.gated_rows += eng.batch - len(live)
         tok = np.zeros((eng.batch, 1), np.int32)
@@ -1052,6 +1054,7 @@ class Scheduler:
         if self._span is not None:
             TRACER.phase(self._span, "sched.dispatch.decode")
         self.stats.decode_steps += 1
+        self.stats.attn_grid_steps_decode += eng.attn_grid_steps(1 + k)
         self.stats.decode_rows += len(rows)
         self.stats.gated_rows += eng.batch - len(rows)
         spec_rows = [s for s in rows if self._spec_capable(s)]

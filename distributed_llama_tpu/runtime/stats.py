@@ -235,6 +235,7 @@ WINDOW_COUNTERS = ("admitted", "queue_wait_ms_sum", "prefill_steps",
                    "decode_steps", "decode_rows",
                    "gated_rows", "busy_ms", "wait_ms", "host_ms",
                    "attn_pairs_decode", "attn_pairs_prefill",
+                   "attn_grid_steps_decode", "attn_grid_steps_prefill",
                    "prefill_cached_tokens",
                    "expert_reads_decode", "expert_reads_prefill",
                    "expert_pairs_decode", "expert_pairs_prefill",
@@ -314,6 +315,13 @@ class ServeStats:
     # (query token, cached position) pairs, position + 1 a real row or token
     attn_pairs_decode: int = 0
     attn_pairs_prefill: int = 0
+    # and what the programs ran for it whatever the positions: the static
+    # grid of flash_attention (rows x head tiles x sequence blocks,
+    # ops/pallas_attention.flash_grid) times the layers that call it,
+    # added a dispatch (Engine.attn_grid_steps); 0 where no program holds
+    # the kernel (the latent cache, the XLA path)
+    attn_grid_steps_decode: int = 0
+    attn_grid_steps_prefill: int = 0
     prefill_cached_tokens: int = 0  # cache rows a chunk's real rows attend
     #                                 (offset + tokens, summed over rows)
     # the routed experts' work, counted ON THE DEVICE by the step programs

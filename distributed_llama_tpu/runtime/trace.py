@@ -588,6 +588,12 @@ _COUNTERS = (
      "Cached positions attended, summed over decoding rows"),
     ("attn_pairs_prefill", "dllama_attn_pairs_prefill_total",
      "Cached positions attended, summed over real prefill tokens"),
+    ("attn_grid_steps_decode", "dllama_attn_grid_steps_decode_total",
+     "Grid steps of flash_attention, summed over attention layers and "
+     "decode or verify programs"),
+    ("attn_grid_steps_prefill", "dllama_attn_grid_steps_prefill_total",
+     "Grid steps of flash_attention, summed over attention layers and "
+     "prefill-chunk programs"),
     ("prefill_cached_tokens", "dllama_prefill_cached_tokens_total",
      "Cache rows read by prefill chunks, summed over their real rows"),
     ("expert_reads_decode", "dllama_expert_reads_decode_total",

@@ -353,19 +353,40 @@ def test_only_kimis_chunk_unpacks_an_expert_once(
     assert (own != forced) == (t == 32 and model == "kimi_linear_48b_ep4")
 
 
-@pytest.mark.parametrize("b,t,cache_dtype", [
-    (8, 1, BF16),                   # batched decode
-    (1, 256, BF16),                 # 256-token prefill chunk
-    (8, 1, jnp.float8_e4m3fn),      # fp8 cache
+@pytest.mark.parametrize("b,t,h,kvh,s,cache_dtype,kh", [
+    (8, 1, 32, 32, 1024, BF16, 32),   # batched decode
+    (1, 256, 32, 32, 1024, BF16, 8),  # 256-token prefill chunk
+    (8, 1, 32, 32, 1024, jnp.float8_e4m3fn, 8),  # fp8 cache
+    # the tiles of KV heads the cells run (PR 54): Mistral's, Mixtral's and
+    # granite's step programs, their f8 decode, olmo-hybrid's 30 heads,
+    # jamba's one, a tp = 4 shard's two, and the offline 256-token prefill
+    # under GQA, whose score tile leaves room for two heads
+    (8, 1, 32, 8, 4096, BF16, 8),
+    (8, 32, 32, 8, 4096, BF16, 8),
+    (8, 1, 32, 8, 4096, jnp.float8_e4m3fn, 8),
+    (8, 1, 30, 30, 8192, BF16, 30),
+    (8, 32, 30, 30, 8192, BF16, 30),
+    (8, 1, 30, 30, 8192, jnp.float8_e4m3fn, 10),
+    (16, 1, 32, 1, 8192, BF16, 1),
+    (16, 16, 32, 1, 8192, BF16, 1),
+    (8, 1, 8, 2, 4096, BF16, 2),
+    (1, 256, 32, 8, 4096, BF16, 2),
 ])
-def test_flash_attention_compiles(one_chip, b, t, cache_dtype):
-    from distributed_llama_tpu.ops.pallas_attention import flash_attention
+def test_flash_attention_compiles(one_chip, b, t, h, kvh, s, cache_dtype, kh):
+    """The kernel at the tile `head_tile` cuts for the shapes, under the
+    slot map where the served step programs pass one."""
+    from distributed_llama_tpu.ops.pallas_attention import (flash_attention,
+                                                            flash_grid)
 
-    h = kvh = 32
+    assert flash_grid(b, t, h, kvh, s, 128, cache_dtype, BF16) == (
+        b, kvh // kh, s // 512)
     q = _struct((b, t, h, 128), BF16, one_chip)
-    kv = _struct((b, kvh, 1024, 128), cache_dtype, one_chip)
+    kv = _struct((b, kvh, s, 128), cache_dtype, one_chip)
     pos = _struct((b, t), jnp.int32, one_chip)
-    c = jax.jit(flash_attention).lower(q, kv, kv, pos).compile()
+    slots = _struct((b,), jnp.int32, one_chip)
+    c = jax.jit(lambda q, k, v, pos, slots: flash_attention(
+        q, k, v, pos, slots=slots if s > 1024 else None)).lower(
+            q, kv, kv, pos, slots).compile()
     assert _has_kernel(c)
 
 

@@ -222,10 +222,12 @@ def test_the_manifest_ends_with_this_cell_and_its_two_metrics(real):
         "chips": 1, "why": m["workloads"][-1]["why"]}
     assert all(len(x["why"]) <= 200 for x in (m["configs"][-1],
                                               m["workloads"][-1]))
-    # (what PR 53 appended after them, one metric that every cell reports,
-    # is tests/test_sample_summary.py's to hold)
-    assert m["per_layer"][-1]["name"] == "sample_summary_share"
-    per_layer = m["per_layer"][:-1]
+    # (what PRs 53 and 54 appended after them, a counter metric each, is
+    # tests/test_sample_summary.py's and tests/test_manifest_tail.py's to
+    # hold)
+    assert [x["name"] for x in m["per_layer"][-2:]] == [
+        "sample_summary_share", "decode_attention_grid_steps"]
+    per_layer = m["per_layer"][:-2]
     assert [x["name"] for x in per_layer[-2:]] == [
         "selscan_decode_roofline", "selscan_prefill_roofline"]
     for x, moves, kernel in zip(

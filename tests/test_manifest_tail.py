@@ -3,8 +3,11 @@ manifest WITHOUT what PR 48 appended (an eighth cell, a sixth configuration,
 `kda_decode_roofline` and `kda_prefill_roofline`), PR 49 after it (one
 per-layer metric, `prefill_tiles_per_expert_read`) and PR 52 after that (a
 ninth cell, a seventh configuration, `selscan_decode_roofline` and
-`selscan_prefill_roofline`, held by tests/test_jamba_bench.py; PR 48's and
-PR 49's entries are found by NAME here, whatever comes after them): a
+`selscan_prefill_roofline`, held by tests/test_jamba_bench.py), PR 53 (one
+per-layer metric, `sample_summary_share`, held by
+tests/test_sample_summary.py) and PR 54 (one, `decode_attention_grid_steps`,
+held here; PR 48's and PR 49's entries are found by NAME here, whatever
+comes after them): a
 `model_config` PR puts
 its entries last and may edit no file under benchmark/, so
 test_capture_report_metrics.py's pin of the last four `per_layer` entries
@@ -26,8 +29,8 @@ for p in (BENCH, os.path.join(BENCH, "tests"), REPO):
     if p not in sys.path:
         sys.path.insert(0, p)
 
-# by PRs 48, 49, 52 and 53
-APPENDED = {"workloads": 2, "configs": 2, "per_layer": 6}
+# by PRs 48, 49, 52, 53 and 54
+APPENDED = {"workloads": 2, "configs": 2, "per_layer": 7}
 
 with open(os.path.join(REPO, "BENCHMARK.json")) as f:
     M = json.load(f)
@@ -96,6 +99,42 @@ def test_what_pr_49_appended_is_one_counter_metric_as_data():
     older = {"stats": {"window_start": {"expert_reads_prefill": 1},
                        "window_end": {"expert_reads_prefill": 9}}}
     assert stats_delta_opt.read(older, **spec["args"]) is None
+
+
+def test_what_pr_54_appended_is_one_counter_metric_as_data():
+    """`decode_attention_grid_steps`, the manifest's last entry: the grid
+    steps `flash_attention` takes a decode program, over the reader the
+    benchmark had (`stats_delta_opt`: nothing to read, and no error, from a
+    program without the counter), in the seven cells whose programs hold
+    the kernel (the two latent-cache cells run `mla_attention`)."""
+    import traffic
+
+    from distributed_llama_tpu.runtime.stats import WINDOW_COUNTERS
+
+    last = M["per_layer"][-1]
+    holds = [w["name"] for w in M["workloads"] if w["config"].split("-")[0]
+             not in ("sarvam", "kimi")]
+    assert last == {
+        "name": "decode_attention_grid_steps", "unit": "steps",
+        "better": "lower", "source": "program_counter",
+        "layer": "kernels (ops/pallas_attention.py)", "moves": "itl_p50_ms",
+        "workloads": holds}
+    assert len(holds) == 7
+    spec = traffic.load_json("layer_metrics", last["name"] + ".json")
+    assert spec["reader"] == "stats_delta_opt" and spec["args"] == {
+        "num": "attn_grid_steps_decode", "den": "decode_steps"}
+    assert set(spec["args"].values()) <= set(WINDOW_COUNTERS)
+    assert {k: spec[k] for k in last if k != "workloads"} == {
+        k: v for k, v in last.items() if k != "workloads"}
+    from readers import stats_delta_opt
+
+    older = {"stats": {"window_start": {"decode_steps": 1},
+                       "window_end": {"decode_steps": 9}}}
+    assert stats_delta_opt.read(older, **spec["args"]) is None
+    newer = {"stats": {
+        "window_start": {"decode_steps": 1, "attn_grid_steps_decode": 2048},
+        "window_end": {"decode_steps": 9, "attn_grid_steps_decode": 18432}}}
+    assert stats_delta_opt.read(newer, **spec["args"]) == 2048
 
 
 def test_the_four_entries_came_last_with_their_files(capture, monkeypatch):

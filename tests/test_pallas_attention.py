@@ -7,8 +7,9 @@ import pytest
 
 from distributed_llama_tpu.ops.attention import decode_attention
 from distributed_llama_tpu.ops.pallas_attention import (
-    F8_DTYPE, _last_attended, _mla_last, flash_attention,
-    flash_decode_attention, flash_supported)
+    F8_DTYPE, FLASH_VMEM_BYTES, _block_s, _last_attended, _mla_last,
+    _tile_bytes, flash_attention, flash_decode_attention, flash_grid,
+    flash_supported, head_tile)
 
 
 @pytest.mark.parametrize("b,h,kvh,s,pos", [
@@ -17,6 +18,9 @@ from distributed_llama_tpu.ops.pallas_attention import (
     (1, 8, 8, 256, 0),      # only position 0 visible
     (2, 8, 4, 512, 100),    # batch, partial cache, multiple s-blocks
     (1, 4, 4, 384, 300),    # s = 384 -> 128-wide blocks
+    (2, 32, 1, 512, 300),   # one KV head: a tile of one, the grid of a head
+    (2, 30, 30, 1024, 700),  # G = 1, 30 heads in float32: several tiles
+    (2, 120, 30, 1024, 700),  # ... and G = 4
 ])
 def test_flash_decode_matches_oracle(b, h, kvh, s, pos):
     hs = 128
@@ -37,6 +41,9 @@ def test_flash_decode_matches_oracle(b, h, kvh, s, pos):
     (1, 8, 2, 256, 16, 100),   # GQA group 4, mid-session chunk
     (2, 8, 4, 512, 32, 37),    # batch, multiple s-blocks
     (1, 4, 4, 384, 8, 300),    # 128-wide blocks, chunk near the cache edge
+    (2, 4, 1, 512, 32, 300),   # one KV head: a tile of one
+    (1, 30, 30, 1024, 32, 600),  # G = 1, 30 heads in float32: several tiles
+    (1, 120, 30, 1024, 32, 600),  # ... and G = 4
 ])
 def test_flash_prefill_matches_oracle(b, h, kvh, s, t, pos0):
     """T>1 chunks: per-row causal limits must match the dense masked path.
@@ -99,18 +106,30 @@ GATED_LAYOUTS = {"first": (True, True, False, False),
                  "interleaved": (True, False, True, False)}
 
 
-@pytest.mark.parametrize("layout", GATED_LAYOUTS)
+# (KV heads, GQA group) a grid step's tile is cut from: one head (a tile of
+# one: the grid of a head a step), two and eight (one tile), thirty (bf16:
+# tiles of 15 at G = 1, t = 1 and of 30 in f8; of 6 and 10 at 32 tokens)
+HEADS = [(kvh, g) for kvh in (1, 2, 8, 30) for g in (1, 4)]
+_ids = lambda kvh_g: "kvh%d-g%d" % kvh_g  # noqa: E731
+
+
+@pytest.mark.parametrize("kvh_g,layout", [
+    *(((2, 4), layout) for layout in GATED_LAYOUTS),
+    *((kvh_g, "interleaved") for kvh_g in HEADS if kvh_g != (2, 4))],
+    ids=lambda v: v if isinstance(v, str) else _ids(v))
 @pytest.mark.parametrize("cache_dtype", [jnp.bfloat16, F8_DTYPE],
                          ids=["bf16", "f8"])
 @pytest.mark.parametrize("t", [1, 32])
 def test_flash_gated_rows_cost_block_zero_and_leave_live_rows(
-        t, cache_dtype, layout):
+        t, cache_dtype, kvh_g, layout):
     """The scheduler parks a slot that takes no part in a call at
     pos == S. Such a row attends block 0 alone, so it stays finite
     whatever the rest of its cache holds; a live row's panel is its own,
     so it equals the oracle and, bit for bit, the same call with the
-    gated slots given a live position."""
-    b, h, kvh, s, hs = 4, 8, 2, 1536, 128  # three 512-blocks
+    gated slots given a live position. Every head of a tile, whatever the
+    tile: the poison below lies in every head."""
+    kvh, g = kvh_g
+    b, h, s, hs = 4, kvh * g, 1536, 128  # three 512-blocks
     gated = np.asarray(GATED_LAYOUTS[layout])
     live = ~gated
     rng = np.random.default_rng(t + len(layout))
@@ -182,15 +201,20 @@ def _bits(x):
     return x.view({2: np.uint16, 4: np.uint32}[x.dtype.itemsize])
 
 
-@pytest.mark.parametrize("cache_dtype", [jnp.float32, jnp.bfloat16, F8_DTYPE],
-                         ids=["f32", "bf16", "f8"])
+@pytest.mark.parametrize("kvh_g,cache_dtype", [
+    *(((2, 4), dt) for dt in (jnp.float32, jnp.bfloat16, F8_DTYPE)),
+    *((kvh_g, dt) for kvh_g in HEADS if kvh_g != (2, 4)
+      for dt in (jnp.bfloat16, F8_DTYPE))],
+    ids=lambda v: _ids(v) if isinstance(v, tuple) else jnp.dtype(v).name)
 @pytest.mark.parametrize("t", [1, 32])
 def test_a_slot_mapped_call_equals_the_call_on_the_gathered_slots(
-        t, cache_dtype):
+        t, kvh_g, cache_dtype):
     """The K/V index map reads the map: bit for bit the map-less call on
     caches gathered by it, gated rows (block 0 of the slot they name)
-    included, and the XLA twin's values."""
-    b, h, kvh, s, hs = 4, 8, 2, 1024, 128
+    included, and the XLA twin's values; a tile's heads all follow the
+    row's slot."""
+    kvh, g = kvh_g
+    b, h, s, hs = 4, kvh * g, 1024, 128
     rng = np.random.default_rng(t)
     q = jnp.asarray(rng.standard_normal((b, t, h, hs)), jnp.bfloat16)
     k = jnp.asarray(rng.standard_normal((b, kvh, s, hs)), cache_dtype)
@@ -210,17 +234,25 @@ def test_a_slot_mapped_call_equals_the_call_on_the_gathered_slots(
         atol=5e-2, rtol=5e-2)
 
 
-@pytest.mark.parametrize("cache_dtype", [jnp.float32, jnp.bfloat16, F8_DTYPE],
-                         ids=["f32", "bf16", "f8"])
-@pytest.mark.parametrize("start,live", [(0, 8), (480, 5), (1024 - 256, 8)])
+@pytest.mark.parametrize("kvh_g,cache_dtype,start,live", [
+    *(((2, 4), dt, start, live)
+      for dt in (jnp.float32, jnp.bfloat16, F8_DTYPE)
+      for start, live in ((0, 8), (480, 5), (1024 - 256, 8))),
+    # a tile of one head, one of eight and several of thirty's, across a
+    # block's edge
+    *((kvh_g, dt, 480, 3) for kvh_g in ((1, 4), (8, 4), (30, 1))
+      for dt in (jnp.bfloat16, F8_DTYPE))],
+    ids=lambda v: (_ids(v) if isinstance(v, tuple) else
+                   str(v) if isinstance(v, int) else jnp.dtype(v).name))
 def test_rows_chained_on_one_slot_equal_as_many_calls_in_a_row(
-        start, live, cache_dtype):
+        kvh_g, cache_dtype, start, live):
     """Eight rows as consecutive 32-token segments of ONE slot, whose cache
     already holds all of them (the write precedes the attention inside a
     layer), attend what each would attend in a call of its own, in which
     the later segments are not written yet: the mask hides them, so the
     outputs are equal bit for bit."""
-    b, t, h, kvh, s, hs, slot = 8, 32, 8, 2, 1024, 128, 5
+    kvh, g = kvh_g
+    b, t, h, s, hs, slot = 8, 32, kvh * g, 1024, 128, 5
     rng = np.random.default_rng(start + live)
     q = jnp.asarray(rng.standard_normal((b, t, h, hs)), jnp.bfloat16)
     k = jnp.asarray(rng.standard_normal((b, kvh, s, hs)), cache_dtype)
@@ -245,3 +277,69 @@ def test_rows_chained_on_one_slot_equal_as_many_calls_in_a_row(
             jnp.asarray(one)[:, None] + jnp.arange(t, dtype=jnp.int32)[None],
             interpret=True)
         assert np.array_equal(_bits(got[r]), _bits(alone[slot])), r
+
+
+# -- the tile of KV heads a grid step holds ------------------------------------
+
+BF16, HS = jnp.bfloat16, 128
+
+
+@pytest.mark.parametrize("kvh,g,t,s,cache_dtype,want", [
+    (8, 4, 1, 4096, BF16, 8),       # Mistral's, Mixtral's, granite's decode
+    (8, 4, 32, 4096, BF16, 8),      # ... and their 32-token chunk
+    (8, 4, 1, 4096, F8_DTYPE, 8),   # the f8 cache's decode
+    (30, 1, 1, 8192, BF16, ">1"),   # olmo-hybrid's 30 heads, G = 1
+    (30, 1, 32, 8192, BF16, ">1"),
+    (30, 1, 1, 8192, F8_DTYPE, ">1"),
+    (1, 32, 1, 8192, BF16, 1),      # jamba's one KV head: today's grid
+    (1, 32, 16, 8192, BF16, 1),
+    (2, 4, 1, 4096, BF16, 2),       # a tp = 4 shard of Mistral's heads
+    (8, 4, 256, 4096, BF16, "<8"),  # T*G = 1,024: the score tile sets it
+    (32, 1, 256, 1024, BF16, "<32"),
+    (8, 4, 256, 4096, jnp.float32, "<8"),
+])
+def test_head_tile_divides_the_heads_and_fits_the_budget(
+        kvh, g, t, s, cache_dtype, want):
+    """The one function that chooses the tile: a divisor of KVH, the
+    largest whose step fits the stated VMEM budget (the next divisor up
+    does not), 1 where none above 1 does; flash_grid is that tile's grid."""
+    sb, rows = _block_s(s), t * g
+    cb = jnp.dtype(cache_dtype).itemsize
+    qb = max(cb, 2)
+    kh = head_tile(kvh, rows, sb, HS, cb, qb)
+    assert kvh % kh == 0
+    fits = lambda n: _tile_bytes(n, rows, sb, HS, cb, qb) <= FLASH_VMEM_BYTES  # noqa: E731
+    assert fits(kh) or kh == 1
+    assert not any(fits(n) for n in range(kh + 1, kvh + 1) if kvh % n == 0)
+    if isinstance(want, int):
+        assert kh == want
+    else:
+        assert {">1": kh > 1, "<8": kh < 8, "<32": kh < 32}[want]
+    assert flash_grid(3, t, kvh * g, kvh, s, HS, cache_dtype, BF16) == (
+        3, kvh // kh, s // sb)
+
+
+def test_the_call_runs_the_grid_flash_grid_gives():
+    """The count cannot drift from the kernel: the pallas_call in the
+    traced program has the grid flash_grid computes from the same shapes
+    (a float32 cache lifts q, and the tile is cut for that)."""
+    import jax
+
+    for (b, t, h, kvh, s), dt in [((4, 1, 8, 2, 1536), BF16),
+                                  ((2, 32, 30, 30, 1024), jnp.float32),
+                                  ((2, 1, 30, 30, 1024), F8_DTYPE),
+                                  ((3, 16, 32, 1, 1024), BF16)]:
+        q = jax.ShapeDtypeStruct((b, t, h, HS), BF16)
+        kv = jax.ShapeDtypeStruct((b, kvh, s, HS), dt)
+        pos = jax.ShapeDtypeStruct((b, t), jnp.int32)
+        jaxpr = jax.make_jaxpr(
+            lambda *a: flash_attention(*a, interpret=True))(q, kv, kv, pos)
+        grids = [e.params["grid_mapping"].grid
+                 for e in jax.tree.leaves(
+                     [jaxpr.jaxpr.eqns] + [
+                         j.jaxpr.eqns for eq in jaxpr.jaxpr.eqns
+                         for j in eq.params.values()
+                         if hasattr(j, "jaxpr")])
+                 if e.primitive.name == "pallas_call"]
+        assert grids == [flash_grid(b, t, h, kvh, s, HS, dt, BF16)], (
+            grids, b, t, h, kvh, s, dt)

@@ -17,6 +17,7 @@ sample lines parse, labels quoted) instead of eyeballing strings.
 
 import http.client
 import json
+import math
 import re
 import threading
 import time
@@ -295,6 +296,52 @@ def test_scheduler_records_span_and_step_timeline(tiny):
     assert any(k[1] > 0 for k in tl)  # a prefill composition
     assert any(k[0] > 0 and k[1] == 0 for k in tl)  # a pure-decode one
     assert all(v["p50_ms"] >= 0 and v["n"] > 0 for v in tl.values())
+
+
+def test_attn_grid_steps_are_exported_and_a_dispatch_adds_the_kernels_grid(
+        tiny, monkeypatch):
+    """/stats attn_grid_steps_decode and _prefill: a dispatch adds the grid
+    the program's flash_attention calls RUN (read off the pallas_calls the
+    programs trace), times the layers that attend; both reach /metrics."""
+    from distributed_llama_tpu.ops import pallas_attention as pa
+    from distributed_llama_tpu.runtime.stats import WINDOW_COUNTERS
+
+    spec, params = tiny
+    seen, real = [], pa.pl.pallas_call
+
+    def spy(kernel, *a, **kw):
+        if kw.get("name") == "flash_attention":
+            seen.append(tuple(kw["grid_spec"].grid))
+        return real(kernel, *a, **kw)
+
+    monkeypatch.setattr(pa.pl, "pallas_call", spy)
+    pa.flash_attention.clear_cache()  # the programs below trace it anew
+    eng = Engine(spec, params, batch=2, compute_dtype=jnp.float32,
+                 cache_dtype=jnp.float32, pallas_interpret=True)
+    sched = Scheduler(eng, chunk=8)
+    req = sched.submit(list(range(1, 18)), 6, _greedy(spec))
+    while not req.finished.is_set():
+        sched.step()
+    sched.close()
+    stats = sched.stats
+    hs = spec.dim // spec.n_heads
+    grid = {t: pa.flash_grid(2, t, spec.n_heads, spec.n_kv_heads, SEQ, hs,
+                             jnp.float32, jnp.float32) for t in (1, 8)}
+    assert grid[1] == grid[8] == (2, 1, 1)  # both KV heads in one tile
+    assert set(seen) == set(grid.values()) and len(seen) >= 2
+    steps = {t: spec.n_layers * math.prod(g) for t, g in grid.items()}
+    assert [eng.attn_grid_steps(t) for t in (1, 8)] == [steps[1], steps[8]]
+    assert stats.decode_steps > 0 and stats.prefill_steps == 2
+    assert stats.attn_grid_steps_decode == stats.decode_steps * steps[1]
+    assert stats.attn_grid_steps_prefill == stats.prefill_steps * steps[8]
+    # an engine on the XLA path holds no such kernel and counts none
+    assert _engine(tiny).attn_grid_steps(1) == 0
+    assert {"attn_grid_steps_decode",
+            "attn_grid_steps_prefill"} <= set(WINDOW_COUNTERS)
+    m = _parse_prometheus(render_prometheus(stats.summary(), model="x"))
+    for program in ("decode", "prefill"):
+        (_, v), = m[f"dllama_attn_grid_steps_{program}_total"]
+        assert v == getattr(stats, f"attn_grid_steps_{program}")
 
 
 def test_prefix_seed_event_records_hit_length(tiny):

@@ -23,6 +23,12 @@ projection of the five MoE cells' two step programs, token rows gathered in
 the kernel by `src` against `x[src]` laid out by XLA and handed over
 (PERF.md section 6, PR 50).
 
+`flash_grid` reads one call of `flash_attention` at Mistral's and
+olmo-hybrid's decode and 32-token-chunk shapes, with the rows a cell's step
+holds (a few live rows at their positions, the rest gated), another tree's
+kernel file (a head a grid step, the parent of PR 54) against this tree's
+(a tile of heads a step), bit for bit (PERF.md section 6, PR 54).
+
 `sample_prep` reads what the sampling summary costs at the end of a step
 program (`ops/sharded_vocab.sample_summary`: masked argmax, float32 softmax
 at a temperature a row, the two sorts of `top_candidates`, one packed leaf)
@@ -32,7 +38,7 @@ then fetches, at the rows and vocabularies of the configurations;
 row, with none proven (the whole-fetch fallback) and without it (PERF.md
 section 6, PR 53).
 
-Usage: python tools/microbench.py [all|gemv|gemv_q40|gemv_pallas|attn|cache|q40_shapes|q40_orders|q40_gather|sample_prep|sample_view]
+Usage: python tools/microbench.py [all|gemv|gemv_q40|gemv_pallas|attn|cache|q40_shapes|q40_orders|q40_gather|flash_grid [<another tree's ops/pallas_attention.py>]|sample_prep|sample_view]
 """
 
 from __future__ import annotations
@@ -449,6 +455,85 @@ def bench_q40_gather():
               f"{np.array_equal(out[False], out[True])}", flush=True)
 
 
+# name: (b, t, h, kvh, s, live rows' first positions a reading, chained).
+# A decode reading of Mistral's chat cell is the mean of one and two live
+# rows (`decode_rows_per_step` 1.48); a chained chunk puts its rows on one
+# slot as consecutive segments (the slot map), the others each on its own
+FLASH_GRID_SHAPES = {
+    "mistral-7b decode": (8, 1, 32, 8, 4096, ((400,), (400, 400)), True),
+    "mistral-7b chunk32": (8, 32, 32, 8, 4096,
+                           (tuple(1024 + 32 * r for r in range(8)),), True),
+    "olmo-hybrid-7b decode": (8, 1, 30, 30, 8192, ((4200,) * 3,), False),
+    "olmo-hybrid-7b chunk32": (8, 32, 30, 30, 8192, ((2100,) * 3,), False),
+}
+
+
+def _kernel_file(path):
+    """`flash_attention` of another tree's ops/pallas_attention.py, loaded
+    beside this tree's (its relative imports resolve here)."""
+    import importlib.util
+
+    name = "distributed_llama_tpu.ops._other_pallas_attention"
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod
+    spec.loader.exec_module(mod)
+    return mod.flash_attention
+
+
+def bench_flash_grid():
+    """us a call of `flash_attention` alone at the served shapes with the
+    rows a cell's step holds (live rows at their positions, the rest gated
+    at pos == S), the kernel of another tree's file (argv[2], the parent's
+    `ops/pallas_attention.py`: a head a grid step) against this tree's (a
+    tile of heads a step), with this tree's grid and whether the outputs
+    are equal bit for bit (else: whose differ from the first's; PERF.md
+    section 6, PR 54). Calls chained in one program, as bench_q40_gather's."""
+    from distributed_llama_tpu.ops import pallas_attention as pa
+
+    candidates = {"this tree": pa.flash_attention}
+    if len(sys.argv) > 2:
+        candidates = {"other tree": _kernel_file(sys.argv[2]), **candidates}
+    rng = np.random.default_rng(0)
+    bf16, hs = jnp.bfloat16, 128
+    print(f"{jax.devices()[0].device_kind}")
+    print("shape | b, t, h, kvh, s | live rows | this tree's grid | "
+          + " | ".join(f"{c} us a call" for c in candidates)
+          + " | bit-equal")
+    for name, (b, t, h, kvh, s, lives, chained) in FLASH_GRID_SHAPES.items():
+        q = jnp.asarray(rng.standard_normal((b, t, h, hs)), bf16)
+        k, v = (jnp.asarray(rng.standard_normal((b, kvh, s, hs)), bf16)
+                for _ in range(2))
+        us, out = {c: [] for c in candidates}, {}
+        for live in lives:
+            pos0 = np.full((b,), s, np.int32)
+            pos0[:len(live)] = live
+            slots = np.arange(b, dtype=np.int32)
+            if chained and t > 1:
+                slots[:len(live)] = 0
+                slots[len(live):] = np.arange(1, b - len(live) + 1)
+            q_pos = jnp.asarray(pos0[:, None] + np.arange(t)[None, :],
+                                jnp.int32)
+            sl = jnp.asarray(slots) if chained else None
+            for c, fn in candidates.items():
+                def body(q, kvps, fn=fn):
+                    y = fn(q, *kvps[:3], slots=kvps[3])
+                    return q + jnp.where(jnp.isfinite(y), y, 0) * bf16(1e-9)
+
+                us[c].append(slope_time(
+                    lambda r: _outer(body, r), (k, v, q_pos, sl), q,
+                    reps=(16, 128), tries=7) * 1e6)
+                out[c] = np.asarray(fn(q, k, v, q_pos, slots=sl), np.float32)
+        first = next(iter(out.values()))
+        print(f"{name} | {b}, {t}, {h}, {kvh}, {s} | "
+              f"{' or '.join(str(len(x)) for x in lives)} at {lives[0][0]} | "
+              f"{pa.flash_grid(b, t, h, kvh, s, hs, bf16)} | "
+              + " | ".join(f"{np.mean(us[c]):.1f}" for c in candidates)
+              + " | " + (", ".join(c for c, o in out.items()
+                                   if not np.array_equal(first, o))
+                         or "True"), flush=True)
+
+
 def bench_sample_prep():
     """us a call of the summary as the step programs run it, at k
     candidates a row; of it the softmax and argmax alone (k = 1); and the
@@ -567,6 +652,7 @@ ALL = {
     "q40_shapes": bench_q40_shapes,
     "q40_orders": bench_q40_orders,
     "q40_gather": bench_q40_gather,
+    "flash_grid": bench_flash_grid,
     "sample_prep": bench_sample_prep,
     "sample_view": bench_sample_view,
 }
